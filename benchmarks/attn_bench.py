@@ -17,24 +17,23 @@ import jax.numpy as jnp
 
 
 def timeit(f, *args, iters=20):
-    out = f(*args)
-    jax.tree.map(lambda x: x.block_until_ready(), out)
-    # hard sync for remote platforms where block_until_ready is a no-op
-    jax.tree.leaves(out)[0].addressable_data(0)
-    float(jax.tree.leaves(out)[0].ravel()[0])
+    jax.block_until_ready(f(*args))
     t0 = time.perf_counter()
     for _ in range(iters):
         out = f(*args)
-    float(jax.tree.leaves(out)[0].ravel()[0])
+    jax.block_until_ready(out)
     return (time.perf_counter() - t0) / iters * 1e3
 
 
 def main():
+    from ray_tpu._private import accelerators
     from ray_tpu.ops.flash_attention import flash_attention, _reference_bhtd
+
+    accelerators.require_tpu()
 
     B, H, D = 8, 16, 128
     seqs = [int(a) for a in sys.argv[1:]] or [2048]
-    print("backend:", jax.default_backend())
+    print("device:", accelerators.device_report())
     for T in seqs:
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
         q = jax.random.normal(ks[0], (B, H, T, D), jnp.bfloat16)

@@ -10,9 +10,7 @@ prefetch window; we record images/s end-to-end and the DEVICE-WAIT
 FRACTION — the share of wall time the train loop blocks on the data plane
 instead of stepping. Bar: device_wait_frac < 0.10.)
 
-Same capture hardening as bench.py: the TPU measurement runs in a child
-with a hard deadline, a CPU child still records the pipeline shape when
-the pool is wedged, and the last-known-good TPU result is cached. Writes
+Measures in this process on the TPU or raises (no CPU stand-in). Writes
 DATA_BENCH.json at the repo root.
 """
 
@@ -24,9 +22,10 @@ import os
 import sys
 import time
 
-_LKG_PATH = "/tmp/ray_tpu_data_bench_last_good.json"
-_BUDGET_S = float(os.environ.get("RAY_TPU_DATA_BENCH_BUDGET_S", "540"))
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _ROOT)  # run as a script: benchmarks/ is sys.path[0]
+
+from ray_tpu._private import accelerators  # noqa: E402
 
 
 def _make_corpus(d: str, n: int, size: int) -> list[str]:
@@ -46,7 +45,7 @@ def _make_corpus(d: str, n: int, size: int) -> list[str]:
     return paths
 
 
-def _measure(platform: str) -> dict:
+def _measure() -> dict:
     import numpy as np
 
     os.environ.setdefault("RAY_TPU_WARM_POOL_SIZE", "2")
@@ -58,25 +57,18 @@ def _measure(platform: str) -> dict:
     import ray_tpu.data as rdata
     from ray_tpu.models import vit
 
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
-        # ViT-L/16: the step must be heavy enough that ONE host core's
-        # JPEG decode (~200 img/s) can keep the chip fed — the release
-        # gate's criterion is overlap, and a too-small model on a 1-core
-        # host measures the host, not the pipeline
-        img, batch, n_imgs, epochs = 224, 32, 512, 3
-        cfg = vit.vit_config("l16", image_size=img, num_classes=1000,
-                             dtype=jnp.bfloat16)
-    else:
-        img, batch, n_imgs, epochs = 64, 16, 96, 2
-        cfg = vit.vit_config("s16", image_size=img, num_classes=16,
-                             d_model=128, n_layers=2, n_heads=4, d_ff=256,
-                             dtype=jnp.float32)
+    # ViT-L/16: the step must be heavy enough that ONE host core's
+    # JPEG decode (~200 img/s) can keep the chip fed — the release
+    # gate's criterion is overlap, and a too-small model on a 1-core
+    # host measures the host, not the pipeline
+    img, batch, n_imgs, epochs = 224, 32, 512, 3
+    cfg = vit.vit_config("l16", image_size=img, num_classes=1000,
+                         dtype=jnp.bfloat16)
 
     corpus = _make_corpus(f"/tmp/ray_tpu_imgbench_{img}", n_imgs, 256)
-    # worker processes must NOT touch the chip: the driver owns it, the
-    # decode/augment tasks are host-side (the Node spawner injects
-    # JAX_PLATFORMS=cpu into workers — ray_tpu/_private/node.py)
+    # worker processes must NOT touch the chip: this process owns it, the
+    # decode/augment tasks are host-side (the Node spawner pins
+    # JAX_PLATFORMS=cpu on workers it bound no chip to)
     ray_tpu.init(num_cpus=4, num_workers=3, max_workers=4)
 
     def augment(b):
@@ -157,7 +149,7 @@ def _measure(platform: str) -> dict:
     total = time.perf_counter() - t_run0
     ray_tpu.shutdown()
     return {
-        "backend": jax.default_backend(),
+        "device": accelerators.device_report(),
         "images_per_sec": round(images_seen / total, 1),
         "device_wait_frac": round(wait_s / total, 4),
         "step_frac": round(step_s / total, 4),
@@ -172,21 +164,11 @@ def _measure(platform: str) -> dict:
 
 
 def main():
-    sys.path.insert(0, os.path.join(_ROOT, "benchmarks"))
-    import _capture
-
-    child = os.environ.get("RAY_TPU_DATA_BENCH_CHILD")
-    if child:
-        _capture.child_guard("RAY_TPU_DATA_BENCH_CHILD", child)
-        _capture.emit(_measure(child))
-        return 0
-
-    out = _capture.orchestrate(
-        os.path.abspath(__file__), "RAY_TPU_DATA_BENCH_CHILD", _BUDGET_S,
-        _LKG_PATH, ["images_per_sec", "device_wait_frac"], _ROOT)
+    accelerators.export_compile_cache_env()  # before jax is imported
+    accelerators.require_tpu()
+    out = {"ts": time.strftime("%Y-%m-%d %H:%M"), **_measure()}
     # merge discipline: DATA_BENCH.json is shared with data_bench.py's
     # `fault_tolerance` A/B section — a rerun here must not clobber it
-    sys.path.insert(0, _ROOT)
     from ray_tpu.scripts._artifacts import merge_artifact
 
     merge_artifact("DATA_BENCH.json", "results", out)

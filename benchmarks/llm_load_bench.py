@@ -19,10 +19,9 @@ decode engine admits pages AS THEY ARRIVE through the shared
 BatchedKVPuller + streamed submit_prefilled(kv_stream=...). No serve
 control plane — the handoff and the slots are what's under test.
 
-Writes the ``pd`` section of LLM_BENCH.json (merging, not clobbering, the
-serving bench's fields). Capture hardening identical to
-llm_serving_bench.py: self-terminating alarm child, CPU fallback row,
-last-known-good TPU cache.
+Measures in this process on the TPU or raises (no CPU stand-in); writes
+the ``pd`` section of LLM_BENCH.json (merging, not clobbering, the serving
+bench's fields).
 """
 
 from __future__ import annotations
@@ -33,10 +32,10 @@ import sys
 import threading
 import time
 
-_LKG_PATH = "/tmp/ray_tpu_llm_load_bench_last_good.json"
-_BUDGET_S = float(os.environ.get("RAY_TPU_LLM_LOAD_BENCH_BUDGET_S", "540"))
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, _ROOT)  # children run with benchmarks/ as sys.path[0]
+sys.path.insert(0, _ROOT)  # run as a script: benchmarks/ is sys.path[0]
+
+from ray_tpu._private import accelerators  # noqa: E402
 
 
 # ---------------------------------------------------------------- stacks
@@ -398,7 +397,6 @@ def _decode_step_bench(cfg, params, *, page_size, max_len, batch,
             state, slot, kv, jnp.int32(n),
             jnp.asarray(int(jnp.argmax(logits)), jnp.int32),
             jnp.asarray(row), cfg)
-    on_tpu = jax.default_backend() == "tpu"
     bound = 1
     while bound * P < max(lengths) + iters + 1:
         bound *= 2
@@ -416,13 +414,13 @@ def _decode_step_bench(cfg, params, *, page_size, max_len, batch,
 
     ms_gather = run(lambda st: dp.decode_step_paged(params, st, cfg))
     ms_ragged = run(lambda st: dp.decode_step_paged_ragged(
-        params, st, cfg, bound, on_tpu))
+        params, st, cfg, bound, True))
     return {
         "batch": batch,
         "lengths": list(map(int, lengths)),
         "pages_bound": bound,
         "max_pages_per_seq": MP,
-        "impl": "kernel" if on_tpu else "reference",
+        "impl": "kernel",
         "ms_per_step_gather": round(ms_gather, 4),
         "ms_per_step_ragged": round(ms_ragged, 4),
         "speedup": round(ms_gather / max(ms_ragged, 1e-9), 3),
@@ -432,28 +430,19 @@ def _decode_step_bench(cfg, params, *, page_size, max_len, batch,
 # ---------------------------------------------------------------- measure
 
 
-def _measure(platform: str) -> dict:
+def _measure() -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from ray_tpu.models import llama_config, transformer
 
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
-        cfg_kw = dict(vocab_size=32000, max_seq_len=2048, d_model=2048,
-                      n_layers=8, n_heads=16, n_kv_heads=8, d_ff=8192,
-                      dtype=jnp.bfloat16, remat=False)
-        page_size, prompt_len, gen_len, conc = 64, 512, 128, 8
-        rates, open_duration_s = [2.0, 4.0, 8.0], 10.0
-        n_ab = 2 * conc
-    else:
-        cfg_kw = dict(vocab_size=512, max_seq_len=256, d_model=128,
-                      n_layers=2, n_heads=4, n_kv_heads=4, d_ff=256,
-                      dtype=jnp.float32, remat=False)
-        page_size, prompt_len, gen_len, conc = 32, 64, 32, 8
-        rates, open_duration_s = [4.0, 8.0, 16.0], 6.0
-        n_ab = 6 * conc
+    cfg_kw = dict(vocab_size=32000, max_seq_len=2048, d_model=2048,
+                  n_layers=8, n_heads=16, n_kv_heads=8, d_ff=8192,
+                  dtype=jnp.bfloat16, remat=False)
+    page_size, prompt_len, gen_len, conc = 64, 512, 128, 8
+    rates, open_duration_s = [2.0, 4.0, 8.0], 10.0
+    n_ab = 2 * conc
 
     cfg = llama_config("tiny", **cfg_kw)
     params = transformer.init(jax.random.PRNGKey(0), cfg)
@@ -463,7 +452,7 @@ def _measure(platform: str) -> dict:
     stack_kw = dict(page_size=page_size, max_slots=conc,
                     max_len=cfg_kw["max_seq_len"],
                     min_bucket=max(32, page_size))
-    results: dict = {"backend": jax.default_backend(),
+    results: dict = {"device": accelerators.device_report(),
                      "page_size": page_size, "prompt_len": prompt_len,
                      "gen_len": gen_len}
 
@@ -542,33 +531,17 @@ def _measure(platform: str) -> dict:
         mono.shutdown()
 
     # ---- decode-step microbench: ragged vs gather-per-slot ------------
-    if on_tpu:
-        ds_kw = dict(page_size=64, max_len=2048, batch=8,
-                     lengths=[130, 260, 390, 140, 520, 180, 300, 450])
-    else:
-        ds_kw = dict(page_size=32, max_len=512, batch=8,
-                     lengths=[40, 33, 60, 45, 90, 38, 75, 64])
-    results["decode_step"] = _decode_step_bench(cfg, params, **ds_kw)
+    results["decode_step"] = _decode_step_bench(
+        cfg, params, page_size=64, max_len=2048, batch=8,
+        lengths=[130, 260, 390, 140, 520, 180, 300, 450])
     results["config"] = {k: str(v) for k, v in cfg_kw.items()}
     return results
 
 
 def main():
-    sys.path.insert(0, os.path.join(_ROOT, "benchmarks"))
-    import _capture
-
-    child = os.environ.get("RAY_TPU_LLM_LOAD_BENCH_CHILD")
-    if child:
-        _capture.child_guard("RAY_TPU_LLM_LOAD_BENCH_CHILD", child)
-        _capture.emit(_measure(child))
-        return 0
-
-    out = _capture.orchestrate(
-        os.path.abspath(__file__), "RAY_TPU_LLM_LOAD_BENCH_CHILD",
-        _BUDGET_S, _LKG_PATH,
-        ["ab", "arrival_sweep", "pd_token_exact", "phase_breakdown",
-         "decode_step", "overload"],
-        _ROOT)
+    accelerators.export_compile_cache_env()  # before jax is imported
+    accelerators.require_tpu()
+    out = {"ts": time.strftime("%Y-%m-%d %H:%M"), **_measure()}
     # merge INTO LLM_BENCH.json as the `pd` section — the serving bench
     # owns the file's top level and preserves this key on rewrite
     path = os.path.join(_ROOT, "LLM_BENCH.json")

@@ -4,7 +4,8 @@ Workload: N requests sharing one long prompt prefix with short distinct
 tails (the serve prefix router's steady state). Measures time-to-first-token
 per request after a warmup request populates the cache / compilations.
 Updates LLM_MICROBENCH.json with the prefix-cache rows
-(LLM_BENCH.json is owned by llm_serving_bench.py, flat schema).
+(LLM_BENCH.json is owned by llm_serving_bench.py, flat schema). Measures in
+this process on the TPU or raises — there is no CPU stand-in.
 """
 
 from __future__ import annotations
@@ -12,12 +13,14 @@ from __future__ import annotations
 import json
 import os
 import statistics
+import sys
 import time
 
-# force CPU unless explicitly pointed at real hardware: the host env may
-# preset a TPU platform this standalone process can't (and shouldn't) grab
-if os.environ.get("JAX_PLATFORMS") != "tpu":
-    os.environ["JAX_PLATFORMS"] = "cpu"
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from ray_tpu._private import accelerators  # noqa: E402
+
+accelerators.export_compile_cache_env()  # before jax is imported
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -58,6 +61,7 @@ def measure(enable_cache: bool, cfg, params) -> list[float]:
 
 
 def main():
+    accelerators.require_tpu()
     cfg = TransformerConfig(**CFG)
     params = transformer.init(jax.random.PRNGKey(0), cfg)
     base = measure(False, cfg, params)
@@ -72,7 +76,7 @@ def main():
     ]
     print(json.dumps({"prefix_workload": {
         "prefix_len": PREFIX_LEN, "page_size": PAGE,
-        "backend": jax.default_backend()}, "results": rows}))
+        "device": accelerators.device_report()}, "results": rows}))
     path = os.path.join(os.path.dirname(__file__), "..", "LLM_MICROBENCH.json")
     try:
         doc = json.load(open(path))
@@ -82,7 +86,7 @@ def main():
         doc["results"] = keep + rows
         doc["prefix_workload"] = {"prefix_len": PREFIX_LEN,
                                   "page_size": PAGE,
-                                  "backend": jax.default_backend()}
+                                  "device": accelerators.device_report()}
         json.dump(doc, open(path, "w"), indent=1)
     except FileNotFoundError:
         pass

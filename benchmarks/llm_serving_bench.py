@@ -5,12 +5,8 @@ bench.py (north-star row in BASELINE.md: "Serve req/s + p50 TTFT").
 (reference: python/ray/serve/_private/benchmarks/ + release/llm_tests/ —
 the serving suites the release pipeline gates on.)
 
-Writes LLM_BENCH.json with an explicit ``backend`` field. Capture
-hardening identical to bench.py: the TPU measurement runs in a child
-whose backend init is bounded by a SELF-terminating alarm (never killed
-from outside — SIGKILL mid-grant wedges the shared pool), a CPU child
-still records the workload shape when the chip is unavailable, and the
-last-known-good TPU result is cached across invocations.
+Measures in this process on the TPU or raises (no CPU stand-in); writes
+LLM_BENCH.json with the ``device`` the numbers came from.
 """
 
 from __future__ import annotations
@@ -22,10 +18,10 @@ import sys
 import threading
 import time
 
-_LKG_PATH = "/tmp/ray_tpu_llm_bench_last_good.json"
-_BUDGET_S = float(os.environ.get("RAY_TPU_LLM_BENCH_BUDGET_S", "540"))
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, _ROOT)  # children run with benchmarks/ as sys.path[0]
+sys.path.insert(0, _ROOT)  # run as a script: benchmarks/ is sys.path[0]
+
+from ray_tpu._private import accelerators  # noqa: E402
 
 
 def _build(cfg_kw: dict, engine_kw: dict):
@@ -39,33 +35,24 @@ def _build(cfg_kw: dict, engine_kw: dict):
     return cfg, params, TPUEngine(cfg, params, **engine_kw)
 
 
-def _measure(platform: str) -> dict:
-    import jax
+def _measure() -> dict:
     import jax.numpy as jnp
     import numpy as np
 
     from ray_tpu.llm.engine import SamplingParams, TPUEngine
     from ray_tpu.models import transformer
 
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
-        # serving-shaped decoder: wide like the train bench (MXU-friendly),
-        # shorter stack so 8 concurrent 1k contexts fit HBM comfortably
-        cfg_kw = dict(vocab_size=32000, max_seq_len=2048, d_model=2048,
-                      n_layers=8, n_heads=16, n_kv_heads=8, d_ff=8192,
-                      dtype=jnp.bfloat16, remat=False)
-        prompt_len, gen_len, conc = 512, 128, 8
-        prefix_len = 768
-    else:
-        cfg_kw = dict(vocab_size=512, max_seq_len=1024, d_model=128,
-                      n_layers=2, n_heads=4, n_kv_heads=4, d_ff=256,
-                      dtype=jnp.float32, remat=False)
-        prompt_len, gen_len, conc = 64, 16, 4
-        prefix_len = 256
+    # serving-shaped decoder: wide like the train bench (MXU-friendly),
+    # shorter stack so 8 concurrent 1k contexts fit HBM comfortably
+    cfg_kw = dict(vocab_size=32000, max_seq_len=2048, d_model=2048,
+                  n_layers=8, n_heads=16, n_kv_heads=8, d_ff=8192,
+                  dtype=jnp.bfloat16, remat=False)
+    prompt_len, gen_len, conc = 512, 128, 8
+    prefix_len = 768
 
     rng = np.random.default_rng(0)
     sp = SamplingParams(max_tokens=gen_len, temperature=0.0)
-    results: dict = {"backend": jax.default_backend()}
+    results: dict = {"device": accelerators.device_report()}
 
     def prompt(n):
         return [int(x) for x in rng.integers(1, cfg_kw["vocab_size"] - 1,
@@ -282,21 +269,9 @@ def _measure(platform: str) -> dict:
 
 
 def main():
-    sys.path.insert(0, os.path.join(_ROOT, "benchmarks"))
-    import _capture
-
-    child = os.environ.get("RAY_TPU_LLM_BENCH_CHILD")
-    if child:
-        _capture.child_guard("RAY_TPU_LLM_BENCH_CHILD", child)
-        _capture.emit(_measure(child))
-        return 0
-
-    out = _capture.orchestrate(
-        os.path.abspath(__file__), "RAY_TPU_LLM_BENCH_CHILD", _BUDGET_S,
-        _LKG_PATH,
-        ["ttft_ms_p50", "decode_tokens_per_s_single",
-         "aggregate_tokens_per_s", "instrumentation_ab"],
-        _ROOT)
+    accelerators.export_compile_cache_env()  # before jax is imported
+    accelerators.require_tpu()
+    out = {"ts": time.strftime("%Y-%m-%d %H:%M"), **_measure()}
     path = os.path.join(_ROOT, "LLM_BENCH.json")
     try:  # sections owned by OTHER benches (llm_load_bench's `pd`, future
         #   additions): keep every prior key this run didn't produce

@@ -117,8 +117,11 @@ CONFIGS = {
 
 
 def main():
+    from ray_tpu._private import accelerators
+
+    accelerators.require_tpu()
     names = sys.argv[1:] or ["base_ref", "flash", "flash_dots", "flash_noremat"]
-    print("backend:", jax.default_backend(), flush=True)
+    print("device:", accelerators.device_report(), flush=True)
     for n in names:
         run(n, **CONFIGS[n])
 

@@ -10,11 +10,29 @@ the reference's own test strategy — SURVEY.md §4.2.)
 
 from __future__ import annotations
 
+import collections
 import glob
 import os
+import re
 
 # The env var JAX/libtpu reads to restrict a process to a chip subset.
 TPU_VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
+# libtpu's description of a sub-host process: how many chips it drives
+# along each axis, within a one-process "slice" of its own. Without them a
+# second process on the host either fails to load libtpu (the whole host is
+# taken) or claims every chip (reference: tpu.py sets the chips-per-host
+# and host bounds next to TPU_VISIBLE_CHIPS; these are libtpu's current
+# names for the same pair).
+TPU_CHIPS_PER_PROCESS_BOUNDS_ENV = "TPU_CHIPS_PER_PROCESS_BOUNDS"
+TPU_PROCESS_BOUNDS_ENV = "TPU_PROCESS_BOUNDS"
+_CHIPS_PER_PROCESS_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+# JAX's persistent compilation cache. Placed from outside when the variable
+# is set; otherwise one fixed directory inside the checkout (the path is
+# part of the cache key, so it must not move between runs).
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 # Authoritative record of the chips the GCS bound to this worker process
 # (set alongside TPU_VISIBLE_CHIPS at spawn; read back at registration).
 WORKER_CHIPS_ENV = "RAY_TPU_WORKER_CHIPS"
@@ -30,13 +48,15 @@ def detect_num_tpu_chips() -> int:
     env = os.environ.get("RAY_TPU_CHIPS")
     if env:
         return int(env)
-    try:
-        accel = glob.glob("/dev/accel*") + glob.glob("/dev/vfio/*")
-        if accel:
-            return len(accel)
-    except OSError:
-        pass
-    return 0
+    return count_chip_nodes(glob.glob("/dev/accel*") + glob.glob("/dev/vfio/*"))
+
+
+def count_chip_nodes(dev_paths: list[str]) -> int:
+    """Chips among device-node paths: `/dev/accel<N>` (accel driver) or
+    `/dev/vfio/<N>` (one IOMMU group per chip). `/dev/vfio/vfio` is the
+    VFIO control node every vfio host has, not a chip."""
+    return sum(1 for p in dev_paths
+               if re.fullmatch(r"/dev/(accel|vfio/)\d+", p))
 
 
 def detect_tpu_labels() -> dict:
@@ -94,27 +114,101 @@ def detect_host_resources(num_cpus=None, num_tpus=None, resources=None,
     return total, merged_labels
 
 
+def export_compile_cache_env(env=os.environ) -> str:
+    """The one decision on where compiled programs are kept, for a process
+    that computes on the chip itself (call before `import jax`) and for
+    every chip worker's spawn env: wherever the variable already points,
+    else the fixed directory in the checkout. Nothing sets a cache directory
+    through jax.config."""
+    return env.setdefault(COMPILE_CACHE_ENV, DEFAULT_COMPILE_CACHE_DIR)
+
+
+_cache_events: collections.Counter | None = None
+
+
+def compile_cache_counts() -> dict:
+    """This process's traffic on JAX's persistent compilation cache since
+    the first call (so call once before compiling): programs looked up,
+    found (hits) and compiled then written (misses). A second run on a kept
+    cache directory shows hits and no misses."""
+    global _cache_events
+    if _cache_events is None:
+        import jax.monitoring
+
+        _cache_events = counts = collections.Counter()
+        prefix = "/jax/compilation_cache/"
+
+        def on_event(event: str, **_kw) -> None:
+            if event.startswith(prefix):
+                counts[event[len(prefix):]] += 1
+
+        jax.monitoring.register_event_listener(on_event)
+    return {"dir": os.environ.get(COMPILE_CACHE_ENV),
+            "requests": _cache_events["compile_requests_use_cache"],
+            "hits": _cache_events["cache_hits"],
+            "misses": _cache_events["cache_misses"]}
+
+
+def device_report() -> dict:
+    """The devices this process computes on, as JAX reports them — stamped
+    on every result a chip program prints."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+def require_tpu() -> None:
+    """For a program that measures on the chip in its own process: raise
+    unless JAX's default backend is the TPU. There is no CPU stand-in for a
+    device measurement."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f"this program measures on a TPU chip and JAX's backend here "
+            f"is {backend!r}: no chip, no result")
+
+
 def chips_required(resources: dict) -> int:
-    """Whole chips a task/actor binds. Fractional TPU (<1) shares without
-    isolation, like fractional GPU in the reference."""
+    """Whole chips a task/actor binds. A custom-resource `TPU` amount below
+    one binds no chip: that work runs in a host-platform worker."""
     v = float(resources.get("TPU", 0.0))
     return int(v) if v >= 1.0 else 0
 
 
 def validate_num_tpus(num_tpus) -> None:
-    if num_tpus is not None and float(num_tpus) > 1 and float(num_tpus) != int(num_tpus):
+    if num_tpus is not None and float(num_tpus) != int(num_tpus):
         raise ValueError(
-            f"num_tpus must be an integer when > 1 (got {num_tpus}): whole "
-            f"chips are bound to a worker via TPU_VISIBLE_CHIPS")
+            f"num_tpus must be a whole number of chips (got {num_tpus}): a "
+            f"chip belongs to one process, so a fraction of one would bind "
+            f"no chip and compute on the host CPU")
 
 
 def apply_chip_env(env: dict, chips: tuple | list) -> None:
     """Stamp a worker-spawn env with its chip binding (before any jax
-    import in the child, so backend init only sees these chips)."""
+    import in the child, so backend init only sees these chips). The
+    platform is pinned: a chip worker that cannot reach its chip dies at
+    backend init instead of carrying on on JAX's CPU backend."""
     ids = ",".join(str(c) for c in chips)
     env[WORKER_CHIPS_ENV] = ids
+    env["JAX_PLATFORMS"] = "tpu"
+    export_compile_cache_env(env)
     if os.environ.get(NOSET_VISIBLE_CHIPS_ENV) != "1":
         env[TPU_VISIBLE_CHIPS_ENV] = ids
+        bounds = _CHIPS_PER_PROCESS_BOUNDS.get(len(chips))
+        if bounds:
+            env[TPU_CHIPS_PER_PROCESS_BOUNDS_ENV] = bounds
+            env[TPU_PROCESS_BOUNDS_ENV] = "1,1,1"
+
+
+def apply_host_env(env: dict) -> None:
+    """Spawn env of a worker the GCS bound no chip to. A chip belongs to
+    one process, so such a worker must not reach for one whatever platform
+    the host env presets: hard-set, not setdefault."""
+    env["JAX_PLATFORMS"] = "cpu"
 
 
 def current_worker_chips() -> list[int]:

@@ -175,7 +175,7 @@ EVENT_SEVERITIES = (EVENT_SEVERITY_DEBUG, EVENT_SEVERITY_INFO,
 
 #: event-type vocabulary: "<entity>.<transition>". Every type a producer
 #: may emit is enumerated here — `ray_tpu events --type` completion, the
-#: README taxonomy table, and the dashboard all key on these strings.
+#: README event-type table, and the dashboard all key on these strings.
 EVENT_NODE_JOIN = "node.join"
 EVENT_NODE_LEAVE = "node.leave"
 EVENT_NODE_DRAIN = "node.drain"

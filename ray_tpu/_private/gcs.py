@@ -174,9 +174,9 @@ class _VNode:
         # TPU_VISIBLE_CHIPS isolation, _private/accelerators/tpu.py:36)
         self.chip_pool: list[int] = list(
             range(int(fp.from_fp(self.total.get("TPU", 0)))))
-        # chips held by a worker that was SIGKILLed mid-grant (OOM defense):
-        # the shared device pool may be wedged, so they are withheld from
-        # re-allocation until an operator re-enables them
+        # chips held by a worker the OOM killer SIGKILLed: they may stay
+        # unusable until the runtime releases them, so they are withheld
+        # from re-allocation until an operator re-enables them
         self.quarantined_chips: list[int] = []
 
 
@@ -700,10 +700,10 @@ class GcsServer:
         worker_killing_policy_group_by_owner.h:87). Node agents delegate
         their victim choice here too (pick_oom_victim RPC): only the GCS
         knows which pids run retriable tasks vs actors."""
-        # killing a worker mid-TPU-grant can wedge the host's shared device
-        # pool (backend init hangs for every later process), so chip-holding
-        # workers are excluded unless explicitly opted in — and even then
-        # ranked strictly after every chip-free candidate
+        # a killed chip holder may leave its chip unusable until the runtime
+        # releases it, so chip-holding workers are excluded unless explicitly
+        # opted in — and even then ranked strictly after every chip-free
+        # candidate
         allow_tpu = RayConfig.get("oom_kill_tpu_workers")
         with self.lock:
             best = None  # ((chip_free, retriable, newest_ts), worker)
@@ -1252,7 +1252,7 @@ class GcsServer:
                 self._free_objects(evicted)
         elif t == "unquarantine_chips":
             # operator re-enables chips quarantined by an OOM kill, after
-            # confirming the host device pool is healthy again
+            # confirming the chips answer again
             with self.lock:
                 node = self.nodes.get(msg.get("node_id") or self.local_node_id)
                 restored: list[int] = []
@@ -3423,7 +3423,9 @@ class GcsServer:
                                and len(node.chip_pool) < need * want)
                 if short_headroom > 0 or short_chips:
                     got = self._reclaim_mismatched_idle_locked(
-                        node_id, need, max(short_headroom, want), rh)
+                        node_id, need, max(short_headroom, want), rh,
+                        chips_short=(need * want - len(node.chip_pool)
+                                     if short_chips else 0))
                     headroom += len(got)
                     reclaim.extend(got)
                 n = max(0, min(want, headroom))
@@ -3583,27 +3585,37 @@ class GcsServer:
 
     def _reclaim_mismatched_idle_locked(self, node_id: str, need: int,
                                         max_count: int,
-                                        renv_hash: str = "") -> list[_Worker]:
+                                        renv_hash: str = "",
+                                        chips_short: int = 0) -> list[_Worker]:
         """Retire idle workers on a node whose chip binding differs from the
         wanted one (chip workers blocking CPU demand, or CPU/odd-size chip
         workers blocking chip demand). Runs after all dispatch for this
         round, so anything still idle here failed to match current demand.
-        Caller sends the exit messages."""
+        Up to `max_count` workers go for headroom; when the demand is
+        `chips_short` chips short, chip holders go first and keep going
+        until that many chips are back in the pool — nothing else wakes the
+        scheduler to take the rest later. Caller sends the exit messages."""
         out: list[_Worker] = []
         node = self.nodes.get(node_id)
-        for w in self.workers.values():
-            if len(out) >= max_count:
-                break
-            if (w.kind == "worker" and not w.dead and w.idle
+        idle = [w for w in self.workers.values()
+                if (w.kind == "worker" and not w.dead and w.idle
                     and w.actor_id is None and w.node_id == node_id
                     and w.language == "py"  # self-joined cpp workers are
                     # not respawnable: never retire them for headroom
                     and (len(w.tpu_chips) != need
-                         or w.renv_hash != renv_hash)):
-                w.dead = True
-                if w.tpu_chips and node is not None and node.alive:
-                    node.chip_pool.extend(w.tpu_chips)
-                out.append(w)
+                         or w.renv_hash != renv_hash))]
+        if chips_short > 0:
+            idle.sort(key=lambda w: not w.tpu_chips)  # stable: holders first
+        freed = 0
+        for w in idle:
+            if len(out) >= max_count and (freed >= chips_short
+                                          or not w.tpu_chips):
+                break
+            w.dead = True
+            if w.tpu_chips and node is not None and node.alive:
+                node.chip_pool.extend(w.tpu_chips)
+                freed += len(w.tpu_chips)
+            out.append(w)
         return out
 
     def _on_task_done(self, msg: dict):
@@ -4290,9 +4302,9 @@ class GcsServer:
                     # stale oom_why from a kill that never landed must not
                     # quarantine chips on an unrelated later death
                     if self._oom_fresh(w):
-                        # SIGKILLed mid-grant: the physical device pool may
-                        # be wedged — quarantine the chips instead of handing
-                        # them to the next worker (which would hang in
+                        # OOM-killed while holding chips: they may not
+                        # answer yet — quarantine them instead of handing
+                        # them to the next worker (which could fail in
                         # backend init). Re-enable via unquarantine_chips.
                         node.quarantined_chips.extend(w.tpu_chips)
                     else:

@@ -79,9 +79,6 @@ class RayConfig:
     # --- cluster / transport --------------------------------------------
     # Host interface the TCP planes bind (control + object transfer).
     bind_host: str = "127.0.0.1"
-    # Worker JAX platform ("cpu" keeps workers off the TPU plugin unless a
-    # chip is explicitly assigned; see node.py chip isolation).
-    worker_platform: str = "cpu"
     # Stream worker stdout/stderr to the driver.
     log_to_driver: bool = True
     # GCS → node-agent / worker health-check period and miss budget
@@ -133,11 +130,10 @@ class RayConfig:
     # memory_usage_threshold 0.95).
     memory_usage_threshold: float = 0.95
     # Whether the OOM killer may pick workers holding TPU chips. Off by
-    # default: SIGKILLing a process mid-TPU-grant can wedge the shared
-    # device pool for every other worker on the host, converting memory
-    # pressure into an accelerator outage. When a chip worker IS killed
-    # (opt-in), its chips are quarantined rather than returned to the
-    # allocatable pool.
+    # default: a killed process may leave its chip unusable until the
+    # runtime releases it, converting memory pressure into an accelerator
+    # outage. When a chip worker IS killed (opt-in), its chips are
+    # quarantined rather than returned to the allocatable pool.
     oom_kill_tpu_workers: bool = False
 
     # --- GCS persistence ------------------------------------------------

@@ -39,7 +39,7 @@ from ray_tpu.exceptions import (
 
 logger = logging.getLogger(__name__)
 
-# Retry taxonomy: SYSTEM errors are the runtime's fault — the task never
+# Retry classes: SYSTEM errors are the runtime's fault — the task never
 # (fully) ran because its actor/worker died or an input copy vanished —
 # and resubmission from the retained input is safe and invisible.
 # Everything else reached the UDF and is an APPLICATION error, governed by

@@ -48,6 +48,9 @@ class Processor:
         from ray_tpu._private import serialization as ser
 
         self.blob = ser.dumps(llm_config)
+        opts = llm_config.replica_actor_options()
+        self.actor_options = {k: opts[k] for k in
+                              ("num_cpus", "num_tpus", "resources") if k in opts}
         self.preprocess = preprocess
         self.postprocess = postprocess
         self.concurrency = concurrency
@@ -59,7 +62,8 @@ class Processor:
 
     def _pool(self):
         if self._workers is None:
-            self._workers = [_EngineWorker.remote(self.blob)
+            self._workers = [_EngineWorker.options(**self.actor_options)
+                             .remote(self.blob)
                              for _ in range(self.concurrency)]
         return self._workers
 

@@ -1,9 +1,9 @@
 """LLMConfig — the single config object for serve + batch LLM stacks.
 
 (reference: llm/_internal/serve/core/configs/llm_config.py LLMConfig —
-model_loading_config, engine_kwargs (tensor_parallel_size etc. forwarded to
-vLLM at vllm_models.py:215,219), accelerator_type, deployment_config. Here
-engine_kwargs drive the TPU engine and mesh axes instead of vLLM.)
+model_loading_config, engine_kwargs (forwarded to vLLM at
+vllm_models.py:215,219), accelerator_type, deployment_config. Here
+engine_kwargs drive the TPU engine instead of vLLM.)
 """
 
 from __future__ import annotations
@@ -71,13 +71,35 @@ class LLMConfig:
     # TransformerConfig kwargs for the built-in families (gpt2/llama/mixtral)
     model_family: str = "llama"
     model_kwargs: dict = field(default_factory=dict)
-    engine_kwargs: dict = field(default_factory=dict)  # max_slots, max_len, min_bucket,
-                                                       # tensor_parallel_size, seed
+    engine_kwargs: dict = field(default_factory=dict)  # TPUEngine keywords:
+                                                       # max_slots, max_len, kv_layout, ...
     deployment_config: dict = field(default_factory=dict)  # serve options
+    # "TPU": every process hosting this config's engine is bound to a chip
+    # (replica_actor_options) and the engine refuses to start on any other
+    # backend. None: host-only — the engine computes on the CPU (tests).
     accelerator_type: str | None = "TPU"
     lora_config: LoraConfig | None = None
     # PD disaggregation (build_pd_openai_app); None → PDConfig() defaults
     pd_config: PDConfig | None = None
+
+    def replica_actor_options(self) -> dict:
+        """Actor options of a process that hosts this config's engine (serve
+        replica, PD prefill/decode server, batch worker):
+        `deployment_config["ray_actor_options"]`, with a chip request when
+        `accelerator_type` is "TPU" — `num_tpus` as given there (4 for a
+        tensor-parallel mesh), else one."""
+        opts = dict(self.deployment_config.get("ray_actor_options") or {})
+        if self.accelerator_type == "TPU":
+            if not opts.setdefault("num_tpus", 1):
+                raise ValueError(
+                    "accelerator_type='TPU' needs a chip: ray_actor_options "
+                    f"num_tpus={opts['num_tpus']!r} binds none (use "
+                    "accelerator_type=None for a host-only engine)")
+        elif self.accelerator_type is not None:
+            raise ValueError(
+                f"accelerator_type must be 'TPU' or None, got "
+                f"{self.accelerator_type!r}")
+        return opts
 
     def build_model(self):
         """Returns (TransformerConfig, params). Cited families live in

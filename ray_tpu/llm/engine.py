@@ -18,6 +18,7 @@ paged CUDA kernels.)
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import logging
@@ -30,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private import accelerators
 from ray_tpu.exceptions import DeadlineExceededError, RequestCancelledError
 from ray_tpu.models import decoding
 from ray_tpu.models.transformer import TransformerConfig
@@ -203,6 +205,7 @@ class TPUEngine:
                  speculative_k: int = 0, ngram_size: int = 2,
                  mesh=None, max_loras: int = 0, lora_rank: int = 8,
                  attn_impl: str = "auto"):
+        accelerators.compile_cache_counts()  # start counting before compiling
         self.cfg = cfg
         self.max_len = max_len or cfg.max_seq_len
         if self.max_len > cfg.max_seq_len:
@@ -435,6 +438,13 @@ class TPUEngine:
     @classmethod
     def from_config(cls, llm_config) -> "TPUEngine":
         """Single construction point for server/PD/batch paths."""
+        backend = jax.default_backend()
+        if llm_config.accelerator_type == "TPU" and backend != "tpu":
+            raise RuntimeError(
+                f"LLMConfig.accelerator_type='TPU' but this process computes "
+                f"on {backend!r}: its worker was bound no chip (deploy "
+                "through build_openai_app / ray_actor_options num_tpus, or "
+                "set accelerator_type=None for a host-only engine)")
         cfg, params = llm_config.build_model()
         ek = dict(llm_config.engine_kwargs)
         lora_cfg = getattr(llm_config, "lora_config", None)
@@ -1598,7 +1608,12 @@ class TPUEngine:
 
     def _loop(self):
         try:
-            self._loop_inner()
+            # every program of a sharded engine is traced with its mesh
+            # ambient: the flash prefill kernel shard_maps itself over it
+            # (ops/attention.py), GSPMD cannot partition it
+            with (jax.set_mesh(self.mesh) if self.mesh is not None
+                  else contextlib.nullcontext()):
+                self._loop_inner()
         except BaseException as e:  # noqa: BLE001 — engine death must unblock callers
             self._error = e
             self._drain_all(e)
@@ -1681,11 +1696,25 @@ class TPUEngine:
     # ---------------------------------------------------------------- stats
 
     def stats(self) -> dict:
+        # which decode-attention code runs, not which was asked for:
+        # attn_impl "ragged" is the Pallas kernel only on an unsharded TPU
+        # engine, the pure-JAX reference everywhere else
+        decode_attn = ("ragged_" + ("kernel" if self._ragged_kernel
+                                    else "reference")
+                       if self.attn_impl == "ragged" else self.attn_impl)
+        memory = jax.local_devices()[0].memory_stats() or {}  # None on CPU
         out = {"free_slots": len(self._free), "active": len(self._by_slot),
                "waiting": self._waiting.qsize() + len(self._backlog),
                "streaming": len(self._streaming),
                "max_slots": self.max_slots, "buckets": list(self.buckets),
                "kv_layout": self.kv_layout, "attn_impl": self.attn_impl,
+               "decode_attn": decode_attn,
+               "device": accelerators.device_report(),
+               "device_memory": {
+                   k: memory.get(k) for k in (
+                       "bytes_in_use", "peak_bytes_in_use", "bytes_limit")},
+               "worker_chips": accelerators.current_worker_chips(),
+               "compile_cache": accelerators.compile_cache_counts(),
                "decode_steps": self.decode_steps,
                "aborts": self.aborts,
                "decode_occupancy": (self.decode_slot_steps

@@ -254,7 +254,7 @@ class PagedKVExporter:
                     daemon=True, name=f"pd-kv-send-{tid[:6]}")
                 # ONE thread per threaded transfer (multi-message = long
                 # prompt; spawn cost is noise next to the stream, and a
-                # shared pool would let one dead-reader transfer
+                # common thread pool would let one dead-reader transfer
                 # head-of-line-block every later export). Spawn can fail
                 # (ulimit under load); until start() succeeds the
                 # sender's finally owns nothing, so the segment (and the

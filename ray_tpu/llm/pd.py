@@ -593,10 +593,13 @@ class PDProxyServer:
 
 def build_pd_openai_app(llm_config: LLMConfig) -> serve.Application:
     pd = llm_config.pd_config or PDConfig()
+    actor_opts = llm_config.replica_actor_options()
     prefill = PrefillServer.options(
-        num_replicas=pd.num_prefill_replicas).bind(llm_config)
+        num_replicas=pd.num_prefill_replicas,
+        ray_actor_options=actor_opts).bind(llm_config)
     decode = DecodeServer.options(
-        num_replicas=pd.num_decode_replicas).bind(llm_config)
+        num_replicas=pd.num_decode_replicas,
+        ray_actor_options=actor_opts).bind(llm_config)
     return PDProxyServer.bind(
         prefill, decode,
         llm_config.model_loading_config.tokenizer or "byte")

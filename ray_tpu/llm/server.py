@@ -227,8 +227,10 @@ class LLMServer:
         return {
             "object": "text_completion",
             "model": lora or self.config.model_loading_config.model_id,
+            # token_ids: the text alone cannot show what was generated (the
+            # byte tokenizer drops every id it has no byte for)
             "choices": [{"index": 0, "text": self.tokenizer.decode(out_ids),
-                         "finish_reason": "stop"}],
+                         "token_ids": out_ids, "finish_reason": "stop"}],
             "usage": {"prompt_tokens": len(ids),
                       "completion_tokens": len(out_ids),
                       "total_time_s": round(dt, 4)},
@@ -262,7 +264,7 @@ class LLMServer:
                 "object": "text_completion.chunk",
                 "model": model,
                 "choices": [{"index": 0, "text": self.tokenizer.decode([tok]),
-                             "finish_reason": None}],
+                             "token_ids": [tok], "finish_reason": None}],
             }
         yield {"object": "text_completion.chunk", "model": model,
                "choices": [{"index": 0, "text": "", "finish_reason": "stop"}]}
@@ -308,5 +310,6 @@ def build_openai_app(llm_config: LLMConfig) -> serve.Application:
     # prefix hit the same replica for KV reuse (reference: llm request_router/
     # prefix_aware/prefix_tree.py)
     opts.setdefault("request_router", "prefix_aware")
+    opts["ray_actor_options"] = llm_config.replica_actor_options()
     dep = dep.options(**opts)
     return dep.bind(llm_config)

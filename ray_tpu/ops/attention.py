@@ -8,9 +8,14 @@ CPU mesh validate the model code that the TPU executes.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from ray_tpu._private.constants import (MESH_AXIS_DP, MESH_AXIS_FSDP,
+                                        MESH_AXIS_TP)
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.parallel.ring_attention import reference_attention, ring_attention
 
@@ -33,6 +38,38 @@ def _flash_ok(q) -> bool:
     return jax.default_backend() == "tpu" and q.shape[1] >= 1024
 
 
+def _flash(q, k, v, *, causal: bool, scale: float | None):
+    T = q.shape[1]
+    # best measured block size (benchmarks/attn_bench.py), falling back
+    # to 256 for seqs that don't tile into 512
+    blk = 512 if T % 512 == 0 else min(256, T)
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out = flash_attention(qt, kt, vt, causal, scale, blk, blk)
+    return out.transpose(0, 2, 1, 3)
+
+
+def _flash_per_shard(q, k, v, *, causal: bool, scale: float | None):
+    """GSPMD cannot partition a Mosaic kernel, so under a multi-device mesh
+    the kernel runs per shard inside shard_map: batch over the data axes,
+    heads over tp (the activation rules of parallel/mesh.py), whichever of
+    them the ambient mesh names. The caller makes the mesh ambient —
+    `train/spmd.py` and the engine do; without one the compiler refuses the
+    sharded program by name rather than replicating it."""
+    mesh = jax.sharding.get_abstract_mesh()
+    # every axis must be manual around the kernel; those an enclosing
+    # shard_map already made manual (pp, sp programs) stay as they are
+    names = frozenset(mesh.axis_names) - frozenset(mesh.manual_axes)
+    if mesh.empty or mesh.size == 1 or not names:
+        return _flash(q, k, v, causal=causal, scale=scale)
+    batch = tuple(a for a in (MESH_AXIS_DP, MESH_AXIS_FSDP) if a in names)
+    heads = MESH_AXIS_TP if MESH_AXIS_TP in names else None
+    spec = P(batch or None, None, heads, None)
+    return jax.shard_map(
+        functools.partial(_flash, causal=causal, scale=scale), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec, axis_names=names,
+        check_vma=False)(q, k, v)
+
+
 def attention(q, k, v, *, causal: bool = True, scale: float | None = None,
               sp_axis: str | None = None, impl: str | None = None):
     """q: [B, T, H, D]; k, v: [B, T, Hkv, D]. Returns [B, T, H, D].
@@ -50,13 +87,6 @@ def attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     if sp_axis is not None:
         return ring_attention(q, k, v, axis_name=sp_axis, causal=causal, scale=scale)
 
-    use_flash = impl == "flash" or (impl is None and _flash_ok(q))
-    if use_flash:
-        T = q.shape[1]
-        # best measured block size (benchmarks/attn_bench.py), falling back
-        # to 256 for seqs that don't tile into 512
-        blk = 512 if T % 512 == 0 else min(256, T)
-        qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-        out = flash_attention(qt, kt, vt, causal, scale, blk, blk)
-        return out.transpose(0, 2, 1, 3)
+    if impl == "flash" or (impl is None and _flash_ok(q)):
+        return _flash_per_shard(q, k, v, causal=causal, scale=scale)
     return reference_attention(q, k, v, causal=causal, scale=scale)

@@ -26,14 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu only resolves on TPU builds; tests run the kernel via interpret
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 256
@@ -105,11 +98,9 @@ def _fwd_call(q, k, v, *, causal: bool, scale: float, block_q: int,
     def lse_map(b, h, i, j):
         return (b, h, i, 0)
 
-    kwargs = dict(memory_space=_VMEM) if (_VMEM is not None and not interpret) else {}
+    kwargs = {} if interpret else dict(memory_space=pltpu.VMEM)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k)
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU backend unavailable; use the reference attention path")
     scratch = [
         pltpu.VMEM((block_q, 128), jnp.float32),
         pltpu.VMEM((block_q, 128), jnp.float32),
@@ -248,7 +239,7 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool,
     # XLA fuses it, no need for a kernel.
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1, keepdims=True)  # [B,H,T,1]
 
-    kwargs = dict(memory_space=_VMEM) if (_VMEM is not None and not interpret) else {}
+    kwargs = {} if interpret else dict(memory_space=pltpu.VMEM)
 
     # both backward grids are (B, H, outer, inner): blocks swept by the inner
     # loop index with `inner`, blocks fixed per outer step index with `o_idx`
@@ -281,7 +272,7 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool,
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
-        ] if pltpu is not None else [],
+        ],
         interpret=interpret,
     )
     dk, dv = dkv(q, k, v, do, lse, delta)
@@ -300,7 +291,7 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool,
             pl.BlockSpec((1, 1, block_q, 1), outer_map, **kwargs),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), outer_map, **kwargs),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)] if pltpu is not None else [],
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

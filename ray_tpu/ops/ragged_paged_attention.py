@@ -37,11 +37,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu only resolves on TPU builds; tests run the kernel via interpret
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -103,10 +99,6 @@ def _ragged_kernel_call(q, kp, vp, block_table, pos, *, scale: float,
     P = kp.shape[1]
     nb = block_table.shape[1]
     H = Hkv * G
-    if pltpu is None:  # pragma: no cover — CPU wheels lack the TPU backend
-        raise RuntimeError(
-            "pallas TPU backend unavailable; use impl='reference'")
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_table, pos
         grid=(B, nb),

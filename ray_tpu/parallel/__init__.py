@@ -1,22 +1,4 @@
-# shard_map's home moved across jax releases (top-level on new jax, under
-# jax.experimental on the 0.4.x line this image ships), and the replication-
-# check kwarg was renamed check_rep → check_vma along the way. Resolve both
-# ONCE here; library code and tests import shard_map from ray_tpu.parallel
-# instead of jax.
-try:
-    from jax import shard_map as _sm  # newer jax: function (or module)
-    _sm = getattr(_sm, "shard_map", _sm)
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _sm
-
-
-def shard_map(f, /, *args, **kwargs):
-    import inspect
-
-    if "check_vma" in kwargs and \
-            "check_vma" not in inspect.signature(_sm).parameters:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _sm(f, *args, **kwargs)
+from jax import shard_map
 
 from ray_tpu.parallel.mesh import (
     AXES,

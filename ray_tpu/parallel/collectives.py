@@ -16,15 +16,8 @@ from jax import lax
 
 
 def pvary(x, axes):
-    """Compat shim: mark x as varying over `axes` (jax pcast/pvary rename).
-    jax 0.4.x predates vma typing entirely — there it's an identity."""
-    pcast = getattr(lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axes, to="varying")
-    pv = getattr(lax, "pvary", None)
-    if pv is not None:
-        return pv(x, axes)
-    return x
+    """Mark x as varying over `axes` (shard_map's vma typing)."""
+    return lax.pcast(x, axes, to="varying")
 
 
 def zeros_varying_like(shape, dtype, ref):
@@ -71,12 +64,5 @@ def axis_index(axis_name: str):
 
 
 def axis_size(axis_name: str):
-    """STATIC size of a named mesh axis from inside shard_map. jax 0.4.x has
-    no lax.axis_size; there the axis env frame carries the size directly."""
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    from jax._src.core import axis_frame
-
-    fr = axis_frame(axis_name)
-    return fr if isinstance(fr, int) else fr.size
+    """STATIC size of a named mesh axis from inside shard_map."""
+    return lax.axis_size(axis_name)

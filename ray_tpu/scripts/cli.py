@@ -263,7 +263,7 @@ def cmd_start(args):
 
 def cmd_unquarantine(args):
     """Re-enable TPU chips quarantined by an OOM kill, once the operator
-    has confirmed the host device pool is healthy again (the GCS-side
+    has confirmed the chips answer again (the GCS-side
     recovery path for `unquarantine_chips`)."""
     sd = _pick_session(args)
     c = GcsClient(sd)

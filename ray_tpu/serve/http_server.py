@@ -115,6 +115,7 @@ class AsyncHTTPServer:
 
     async def _serve(self):
         self._conn_sem = asyncio.Semaphore(self._max_connections)
+        self._finish = asyncio.Event()
         try:
             if self._sock is not None:
                 self._sock.setblocking(False)
@@ -144,9 +145,14 @@ class AsyncHTTPServer:
         # mid-await, their responses never written (the graceful-drain bug:
         # stop() then times out waiting for an inflight count that can
         # never reach zero). Park instead: the loop stays alive until
-        # stop() has observed the drain and cancels every task, us included.
+        # stop() has observed the drain. It releases the park through an
+        # event, not through its cancel alone: closing the listener and the
+        # cancel usually land in one loop iteration, the single
+        # CancelledError is then spent on serve_forever() above, and a park
+        # that waited for a second one never ended (the loop thread leaked
+        # and every stop() sat out its 5 s join).
         try:
-            await asyncio.Event().wait()
+            await self._finish.wait()
         except asyncio.CancelledError:
             pass
 
@@ -367,6 +373,7 @@ class AsyncHTTPServer:
             self._inflight_zero.wait(self.drain_grace_s)
 
         def _cancel_all():
+            self._finish.set()
             for task in asyncio.all_tasks(loop):
                 task.cancel()
 

@@ -34,6 +34,7 @@ from ray_tpu.train.zero import (
     match_partition_rules,
 )
 from ray_tpu.train.spmd import (
+    init_opt_state,
     init_sharded,
     make_sp_pp_train_step,
     make_train_step,
@@ -65,6 +66,7 @@ __all__ = [
     "get_checkpoint",
     "get_context",
     "get_dataset_shard",
+    "init_opt_state",
     "init_sharded",
     "make_sp_pp_train_step",
     "make_train_step",

@@ -17,6 +17,7 @@ from typing import Callable
 
 import jax
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu._private.constants import MESH_AXIS_DP, MESH_AXIS_FSDP
@@ -67,7 +68,10 @@ def make_train_step(
     batch_sharding = NamedSharding(mesh, batch_spec)
 
     def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        # traced with the mesh ambient: an op GSPMD cannot partition (the
+        # Pallas flash kernel, ops/attention.py) reads it to shard_map itself
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         return params, opt_state, loss
@@ -104,6 +108,21 @@ def init_sharded(init_fn: Callable, logical_axes, mesh: Mesh, *args,
     return jax.jit(init_fn, out_shardings=shardings)(*args)
 
 
+def init_opt_state(optimizer: optax.GradientTransformation, params):
+    """`optimizer.init(params)` as one program, every moment placed where
+    its parameter lives and the rest (step counts) replicated. A bare
+    `jax.jit(optimizer.init)` leaves the whole state on device 0 — zeros
+    carry no sharding of their own — and the sharded step then keeps it
+    there."""
+    replicated = NamedSharding(jax.tree.leaves(params)[0].sharding.mesh, P())
+    shardings = optax.tree_map_params(
+        optimizer, lambda _, sharding: sharding,
+        jax.eval_shape(optimizer.init, params),
+        jax.tree.map(lambda x: x.sharding, params),
+        transform_non_params=lambda _: replicated)
+    return jax.jit(optimizer.init, out_shardings=shardings)(params)
+
+
 def _spec_axes(spec: P) -> set[str]:
     out: set[str] = set()
     for entry in spec:
@@ -130,61 +149,33 @@ def make_sp_pp_train_step(
     (axes absent from its spec) — the transpose-correct reduction: psum of the
     1/|axes| cotangent shares reconstitutes the true gradient. Axes present in
     a param's spec (e.g. 'pp' for stage-stacked layers) keep per-shard grads."""
-    from ray_tpu.parallel import shard_map  # version-compat re-export
+    def _vma(x):
+        return jax.typeof(x).vma
 
-    if hasattr(jax, "typeof"):  # vma typing available: grad INSIDE the map
+    def shard_grad_fn(params, batch):
+        def total(p, b):
+            l = shard_loss_fn(p, b)
+            axes = tuple(ax for ax in loss_axes if ax in _vma(l))
+            return jax.lax.pmean(l, axes) if axes else l
 
-        def _vma(x):
-            try:
-                return jax.typeof(x).vma
-            except AttributeError:
-                return set(loss_axes)
+        loss, grads = jax.value_and_grad(total)(params, batch)
 
-        def shard_grad_fn(params, batch):
-            def total(p, b):
-                l = shard_loss_fn(p, b)
-                axes = tuple(ax for ax in loss_axes if ax in _vma(l))
-                return jax.lax.pmean(l, axes) if axes else l
+        def reduce(g, spec):
+            axes = tuple(ax for ax in loss_axes
+                         if ax not in _spec_axes(spec) and ax in _vma(g))
+            return jax.lax.psum(g, axes) if axes else g
 
-            loss, grads = jax.value_and_grad(total)(params, batch)
+        grads = jax.tree.map(reduce, grads, param_specs)
+        return loss, grads
 
-            def reduce(g, spec):
-                axes = tuple(ax for ax in loss_axes
-                             if ax not in _spec_axes(spec) and ax in _vma(g))
-                return jax.lax.psum(g, axes) if axes else g
-
-            grads = jax.tree.map(reduce, grads, param_specs)
-            return loss, grads
-
-        smapped = shard_map(
-            shard_grad_fn, mesh=mesh,
-            in_specs=(param_specs, batch_spec),
-            out_specs=(P(), param_specs),
-        )
-
-        def step(params, opt_state, batch):
-            loss, grads = smapped(params, batch)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-            return params, opt_state, loss
-
-        return jax.jit(step, donate_argnums=(0, 1))
-
-    # jax 0.4.x: no vma typing to scope the per-param reductions, and
-    # guessing them double-counts grads that the collective transposes
-    # (ring ppermute / all_gather) already route across shards. Instead
-    # differentiate THROUGH shard_map: the mapped function returns the
-    # replicated global loss (pmean over loss_axes of the per-shard loss),
-    # and value_and_grad outside the map makes AD's transposes
-    # reconstitute exact global gradients — no manual psum at all.
-    smapped_loss = shard_map(
-        lambda p, b: jax.lax.pmean(shard_loss_fn(p, b), loss_axes),
-        mesh=mesh, in_specs=(param_specs, batch_spec), out_specs=P(),
-        check_vma=False,  # ring ppermute patterns defeat the rep checker
+    smapped = shard_map(
+        shard_grad_fn, mesh=mesh,
+        in_specs=(param_specs, batch_spec),
+        out_specs=(P(), param_specs),
     )
 
     def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(smapped_loss)(params, batch)
+        loss, grads = smapped(params, batch)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         return params, opt_state, loss

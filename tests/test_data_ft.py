@@ -1,5 +1,5 @@
 """Data-plane fault-tolerance policy: `on_block_error` accounting,
-system-vs-application retry taxonomy, datasource read retries, pool
+system-vs-application retry classes, datasource read retries, pool
 supervision units, and owned-ref teardown.
 
 Fast deterministic coverage for the machinery the `data_chaos` tier
@@ -198,7 +198,7 @@ def test_system_retry_budget_exhaustion_raises_system_kind(
         RayConfig.reset()
 
 
-def test_error_taxonomy_and_backoff_bounds():
+def test_error_classes_and_backoff_bounds():
     assert _is_system_error(ObjectLostError("x"))
     assert _is_system_error(ActorDiedError("x"))
     assert _is_system_error(RayTaskError("f", "tb", ActorDiedError("x")))

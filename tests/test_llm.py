@@ -127,7 +127,7 @@ def _tiny_llm_config(**engine_kwargs):
 
     return LLMConfig(
         model_loading_config=ModelLoadingConfig(model_id="tiny", tokenizer="byte"),
-        model_family="llama",
+        model_family="llama", accelerator_type=None,
         model_kwargs=dict(vocab_size=300, max_seq_len=128, d_model=64,
                           n_layers=2, n_heads=4, n_kv_heads=2, d_ff=128,
                           dtype=jnp.float32, remat=False),

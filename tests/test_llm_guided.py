@@ -159,7 +159,7 @@ def test_server_guided_choice_end_to_end():
         cfg = LLMConfig(
             model_loading_config=ModelLoadingConfig(model_id="tiny",
                                                     tokenizer="byte"),
-            model_family="llama",
+            model_family="llama", accelerator_type=None,
             model_kwargs=dict(vocab_size=300, max_seq_len=128, d_model=64,
                               n_layers=2, n_heads=4, n_kv_heads=2, d_ff=128,
                               dtype=jnp.float32, remat=False),
@@ -239,7 +239,7 @@ def test_server_guided_regex_end_to_end():
         cfg = LLMConfig(
             model_loading_config=ModelLoadingConfig(model_id="tiny",
                                                     tokenizer="byte"),
-            model_family="llama",
+            model_family="llama", accelerator_type=None,
             model_kwargs=dict(vocab_size=300, max_seq_len=128, d_model=64,
                               n_layers=2, n_heads=4, n_kv_heads=2, d_ff=128,
                               dtype=jnp.float32, remat=False),

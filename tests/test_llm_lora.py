@@ -210,7 +210,7 @@ def test_lora_served_through_multiplex(cluster, tmp_path):
         model_kwargs=dict(vocab_size=256, max_seq_len=128, d_model=64,
                           n_layers=2, n_heads=4, n_kv_heads=2, d_ff=128),
         engine_kwargs=dict(max_slots=4, max_len=128),
-        deployment_config=dict(num_replicas=1),
+        deployment_config=dict(num_replicas=1), accelerator_type=None,
         lora_config=LoraConfig(dynamic_lora_loading_path=str(adir),
                                max_num_adapters_per_replica=2,
                                lora_rank=RANK),

@@ -1,8 +1,8 @@
 """OOM-defense TPU safety + owner-death stub handling + fn-store pinning.
 
 Round-4 advisor fixes: the OOM killer must not SIGKILL a worker holding TPU
-chips (killing a process mid-grant wedges the shared device pool for the
-whole host — reference analogue: worker_killing_policy keeps GPU-group
+chips (a killed process may leave its chip unusable until the runtime
+releases it — reference analogue: worker_killing_policy keeps GPU-group
 workers last); chips of an OOM-killed worker are quarantined, not returned;
 a pending direct-result stub whose owner dies fails with OwnerDiedError
 (reference: ray.exceptions.OwnerDiedError) instead of blocking waiters; and

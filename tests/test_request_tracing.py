@@ -143,7 +143,7 @@ def test_sampled_pd_request_span_tree(sampled_cluster):
     cfg = LLMConfig(
         model_loading_config=ModelLoadingConfig(model_id="tiny",
                                                 tokenizer="byte"),
-        model_family="llama",
+        model_family="llama", accelerator_type=None,
         engine_kwargs=dict(max_slots=2, max_len=128, min_bucket=16,
                            page_size=16))
     serve.start(http_port=0)

@@ -166,3 +166,19 @@ def test_graceful_proxy_shutdown_drains(serve_cluster):
     serve.shutdown()  # proxy.stop(graceful=True) must let it finish
     t.join(timeout=30)
     assert out and out[0][0] == 200, out
+
+
+def test_stop_ends_the_loop_thread_at_once():
+    """stop() after a served request returns without sitting out its join
+    timeout, and the loop thread is gone (it used to park forever: the one
+    CancelledError was spent on serve_forever())."""
+    from ray_tpu.serve.http_server import AsyncHTTPServer
+
+    srv = AsyncHTTPServer(
+        lambda method, path, headers, body: (200, "application/json", b"{}"),
+        "127.0.0.1", 0).start()
+    assert _post(f"http://127.0.0.1:{srv.port}/x", {}) == (200, {})
+    t0 = time.monotonic()
+    srv.stop(graceful=True)
+    assert time.monotonic() - t0 < 2.0
+    assert not srv._thread.is_alive()

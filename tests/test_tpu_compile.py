@@ -1,0 +1,111 @@
+"""The Pallas kernels of the main path, compiled for a TPU that is described
+and not attached (on-chip-measurement guide, section 2.3): what Mosaic or the
+TPU compiler refuses at the real widths fails here, at no chip time.
+
+Nothing runs, so this says nothing about results — `chip_smoke.py` compares
+the kernels with their references on the chip. Shapes are the ones it uses:
+GPT-2 774M attention at s1024, Llama-1B decode (page 64).
+"""
+
+from __future__ import annotations
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.ragged_paged_attention import ragged_decode_attention
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip, with the persistent compilation cache off:
+    an entry compiled for an absent chip is written but cannot be read back."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+GPT2_774M_TRAIN = (8, 20, 1024, 64)    # [B, H, T, D]: 20 heads x 64 at s1024
+LLAMA_1B_PREFILL = (1, 32, 2048, 64)   # one prompt in the 2048 bucket
+
+
+def _flash_qkv(chip, shape):
+    return [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)] * 3
+
+
+@pytest.mark.parametrize("shape", [GPT2_774M_TRAIN, LLAMA_1B_PREFILL])
+def test_flash_forward_compiles(chip, shape):
+    text = _compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, True, None, 512, 512),
+        *_flash_qkv(chip, shape))
+    assert "tpu_custom_call" in text
+
+
+def test_flash_forward_and_backward_compile(chip):
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, None, 512, 512).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                          *_flash_qkv(chip, GPT2_774M_TRAIN))
+    assert text.count("tpu_custom_call") >= 3  # fwd, dkv, dq
+
+
+# (batch, kv heads, query heads per kv head, head dim, page, pages swept)
+@pytest.mark.parametrize("shape", [
+    (16, 8, 4, 64, 64, 32),    # Llama-1B decode, 16 slots x 2048
+    (8, 2, 8, 128, 16, 8),     # GQA with fewer than 8 kv heads
+])
+def test_ragged_decode_kernel_compiles(chip, shape):
+    B, Hkv, G, Dh, P, nb = shape
+
+    def sds(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=chip)
+
+    text = _compiled_text(
+        lambda *a: ragged_decode_attention(*a, impl="kernel"),
+        sds((B, Hkv, G, Dh), jnp.bfloat16),
+        sds((B * nb + 1, P, Hkv, Dh), jnp.bfloat16),
+        sds((B * nb + 1, P, Hkv, Dh), jnp.bfloat16),
+        sds((B, nb), jnp.int32), sds((B,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_paged_decode_step_with_kernel_compiles(chip):
+    """The engine's decode program at Llama-1B widths, depth cut to two
+    layers (the layer scan compiles one body whatever the depth)."""
+    from ray_tpu.models import decoding_paged, llama_config, transformer
+
+    cfg = llama_config("1b", max_seq_len=2048, n_layers=2)
+    slots, max_len, page = 16, 2048, 64
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: transformer.init(jax.random.PRNGKey(0), cfg)))
+    state = on_chip(jax.eval_shape(lambda: decoding_paged.init_paged_state(
+        cfg, slots, max_len, slots * (max_len // page) + 1, page)))
+    text = decoding_paged.decode_step_paged_ragged.lower(
+        params, state, cfg, 8, True).compile().as_text()
+    assert "tpu_custom_call" in text

@@ -149,6 +149,27 @@ def test_init_sharded_with_partition_rules():
     assert "tp" in str(params["emb"].sharding.spec)
 
 
+@pytest.mark.skipif(len(jax.devices()) < 8,
+                    reason="needs the 8-device virtual CPU mesh")
+def test_init_opt_state_follows_the_parameter_shardings():
+    from ray_tpu.train.spmd import init_opt_state
+
+    mesh = MeshSpec(fsdp=4, tp=2).build()
+    params = init_sharded(
+        lambda key: {"emb": jax.random.normal(key, (16, 8)),
+                     "bias": jnp.zeros((8,))}, None, mesh,
+        jax.random.PRNGKey(0),
+        partition_rules=[("emb", P("fsdp", "tp")), ("bias", P())])
+    adam = init_opt_state(optax.adamw(1e-3), params)[0]
+    for moment in (adam.mu, adam.nu):
+        assert moment["emb"].sharding == params["emb"].sharding
+        assert len({s.device for s in moment["emb"].addressable_shards}) == 8
+    assert adam.count.sharding.is_fully_replicated
+    # the trap it exists for: a bare jit leaves every moment on one device
+    bare = jax.jit(optax.adamw(1e-3).init)(params)[0]
+    assert len(bare.mu["emb"].sharding.device_set) == 1
+
+
 def test_make_train_step_zero_axis_requires_rules():
     mesh = MeshSpec(dp=1).build(jax.devices()[:1])
     params, batch, loss_fn = _toy_problem()

@@ -4,7 +4,7 @@ The cluster event plane (_private/events.py + the GCS ring) carries typed
 records whose `etype` strings cross process boundaries twice: once on the
 `cluster_events_report` flush from controller processes to the GCS, and
 again on every `list_events` read (CLI `--type` filters, dashboard query
-params, README taxonomy). A producer spelling "node.leave" while a filter
+params, README event-type table). A producer spelling "node.leave" while a filter
 spells "node.left" silently matches nothing — so every type a producer may
 emit is enumerated as an `EVENT_*` name in `_private/constants.py`, and
 emit sites must pass those names, never a re-spelled literal.
