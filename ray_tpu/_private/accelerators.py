@@ -14,6 +14,8 @@ import collections
 import glob
 import os
 import re
+import threading
+import time
 
 # The env var JAX/libtpu reads to restrict a process to a chip subset.
 TPU_VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
@@ -124,13 +126,26 @@ def export_compile_cache_env(env=os.environ) -> str:
 
 
 _cache_events: collections.Counter | None = None
+_recent_compiles: collections.deque = collections.deque(maxlen=16)
+_compiling = threading.local()  # .activity: what this thread is doing
+
+
+def note_thread_activity(activity) -> None:
+    """`activity()` names what the calling thread is doing (the engine
+    thread gives the loop phase that is open): stamped on every compilation
+    this thread makes from now on, in `compile_cache_counts()["recent"]`."""
+    _compiling.activity = activity
 
 
 def compile_cache_counts() -> dict:
     """This process's traffic on JAX's persistent compilation cache since
     the first call (so call once before compiling): programs looked up,
     found (hits) and compiled then written (misses). A second run on a kept
-    cache directory shows hits and no misses."""
+    cache directory shows hits and no misses. `recent` is the last 16
+    compilations: when each ended, how long it took, hit or miss, the
+    program as JAX names it (`jit(<function>)`, from the
+    `backend_compile_duration` event; the cache events carry no name) and
+    what the calling thread was doing."""
     global _cache_events
     if _cache_events is None:
         import jax.monitoring
@@ -141,12 +156,31 @@ def compile_cache_counts() -> dict:
         def on_event(event: str, **_kw) -> None:
             if event.startswith(prefix):
                 counts[event[len(prefix):]] += 1
+                # the listeners run on the compiling thread, and the
+                # duration event of the same compilation follows
+                if event.endswith("cache_hits"):
+                    _compiling.outcome = "hit"
+                elif event.endswith("cache_misses"):
+                    _compiling.outcome = "miss"
+
+        def on_duration(event: str, seconds: float, **kw) -> None:
+            if event == "/jax/core/compile/backend_compile_duration":
+                activity = getattr(_compiling, "activity", None)
+                _recent_compiles.append({
+                    "t": time.time(), "seconds": seconds,
+                    "cache": getattr(_compiling, "outcome", None),
+                    "program": kw.get("fun_name"),
+                    "thread": threading.current_thread().name,
+                    "phase": activity() if activity else None})
+                _compiling.outcome = None
 
         jax.monitoring.register_event_listener(on_event)
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
     return {"dir": os.environ.get(COMPILE_CACHE_ENV),
             "requests": _cache_events["compile_requests_use_cache"],
             "hits": _cache_events["cache_hits"],
-            "misses": _cache_events["cache_misses"]}
+            "misses": _cache_events["cache_misses"],
+            "recent": list(_recent_compiles)}
 
 
 def device_report() -> dict:

@@ -35,6 +35,7 @@ from ray_tpu._private import accelerators
 from ray_tpu.exceptions import DeadlineExceededError, RequestCancelledError
 from ray_tpu.models import decoding
 from ray_tpu.models.transformer import TransformerConfig
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -70,12 +71,22 @@ class _Request:
     pf_done: int = 0
     pf_pages: list | None = None
     pf_hashes: list | None = None
-    # request-phase stamps (wall clock): submit → decode-slot bind is the
-    # admission wait; _emit tracks the inter-token gap off last_emit_ts.
-    # Read by llm/pd.py decode_stream to emit retroactive phase spans.
+    # request-phase stamps (wall clock): submit → slot and pages granted
+    # (scheduled) is the queue wait, → first token the prefill, → release
+    # the decode; submit → decode-slot bind is the admission wait; _emit
+    # tracks the inter-token gap off last_emit_ts. Read at release for the
+    # engine's sums and spans, and by llm/pd.py decode_stream to emit
+    # retroactive phase spans.
     submitted_ts: float = 0.0
+    scheduled_ts: float = 0.0
     admitted_ts: float = 0.0
+    first_token_ts: float = 0.0
     last_emit_ts: float = 0.0
+    # the span context active at submit() (None = request not sampled):
+    # the engine:* phase spans hang under it when the request is released
+    trace_ctx: dict | None = None
+    pf_chunks: int = 0        # prefill chunks run (chunked prefill)
+    prefix_reused: int = 0    # prompt tokens served by the prefix cache
     # full token history (prompt + emitted) for the n-gram draft proposer,
     # plus an incremental index: trailing-ngram tuple → (latest, previous)
     # continuation-start positions, so proposal is O(1) per step instead of
@@ -124,6 +135,65 @@ def _iter_request(req: "_Request"):
         if isinstance(tok, _EngineError):
             raise RuntimeError("engine scheduler died mid-generation") from tok.exc
         yield tok
+
+
+# The scheduler thread's wall time, cut into phases that never overlap and
+# leave nothing out (PERF.md section 3 has the table). "host" work is every
+# phase but `parked` and the three `*_wait`, which block on a device→host
+# fetch; dispatch is asynchronous, so device time queued in one phase is
+# paid in the next wait, whichever program it belongs to.
+LOOP_PHASES = ("parked", "sweep", "admit", "admit_wait", "streams",
+               "prefill", "prefill_wait", "decode", "decode_wait", "emit",
+               "spec")
+HOST_PHASES = tuple(p for p in LOOP_PHASES
+                    if p != "parked" and not p.endswith("_wait"))
+
+
+class _PhaseClock:
+    """One `perf_counter` read per boundary: `mark(phase)` closes the open
+    phase and opens the next, on the scheduler thread alone (plain floats,
+    no lock). The same boundaries are `ray_tpu:engine:<phase>` spans on the
+    JAX profiler's timeline when a trace is being taken. `snapshot()` may
+    be called from any thread."""
+
+    def __init__(self):
+        self.seconds = dict.fromkeys(LOOP_PHASES, 0.0)
+        self._spans = {p: tracing.device_annotation("engine:" + p)
+                       for p in LOOP_PHASES}
+        self._span = None
+        self.started = time.perf_counter()
+        self._open = ("parked", self.started)  # the open phase, since when
+
+    @property
+    def phase(self) -> str:
+        return self._open[0]
+
+    def mark(self, phase: str) -> float:
+        now = time.perf_counter()
+        was, since = self._open
+        self.seconds[was] += now - since
+        self._open = (phase, now)
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+        self._span = self._spans[phase]()
+        self._span.__enter__()
+        return now
+
+    def snapshot(self) -> dict:
+        """Seconds per phase so far, the open phase's running time included,
+        with their `host_s` (HOST_PHASES) and `active_s` (all but `parked`)
+        sums and the thread's wall time. A boundary that falls inside the
+        copy leaves that one phase interval out; readers take deltas over
+        seconds."""
+        seconds = dict(self.seconds)
+        phase, since = self._open
+        now = time.perf_counter()
+        seconds[phase] += now - since
+        host = sum(seconds[p] for p in HOST_PHASES)
+        return {"seconds": seconds, "host_s": host,
+                "active_s": host + sum(seconds[p] for p in LOOP_PHASES
+                                       if p.endswith("_wait")),
+                "thread_s": now - self.started}
 
 
 def bucket_for(n: int, min_bucket: int, max_len: int) -> int:
@@ -397,22 +467,32 @@ class TPUEngine:
         self._abort_q: queue.SimpleQueue = queue.SimpleQueue()
         self._abort_pending: dict[int, float] = {}
         self.aborts = 0  # requests reclaimed via abort/deadline
-        # serving-phase instrumentation (decode-slot admission wait,
-        # inter-token gap): pre-bound histograms resolved ONCE per engine —
-        # the per-token cost is one clock read + one lock-free observe.
-        # None when RayConfig.serve_metrics is off (the bench A/B baseline).
+        # where the scheduler thread's time and each request's time go,
+        # cumulative, exported as stats()["loop"]
+        self._clock = _PhaseClock()
+        self.requests_scheduled = 0
+        self.queue_wait_s = 0.0   # Σ scheduled − submitted
+        self.first_tokens = 0
+        self.prefill_s = 0.0      # Σ first token − scheduled
+        # serving-phase instrumentation (queue wait, prefill, decode-slot
+        # admission wait, inter-token gap): pre-bound histograms resolved
+        # ONCE per engine — the per-token cost is one clock read + one
+        # lock-free observe. None when RayConfig.serve_metrics is off (the
+        # bench A/B baseline).
         try:
             from ray_tpu.serve import request_context as _rc
 
-            self._phase_admit = _rc.phase_observer(_rc.ENGINE_PHASE,
-                                                   "admission_wait")
-            self._phase_gap = _rc.phase_observer(_rc.ENGINE_PHASE,
-                                                 "inter_token")
+            (self._phase_queue, self._phase_prefill, self._phase_admit,
+             self._phase_gap) = (
+                _rc.phase_observer(_rc.ENGINE_PHASE, phase) for phase in (
+                    "queue_wait", "prefill", "admission_wait", "inter_token"))
         except Exception:  # pragma: no cover — metrics must never gate boot
+            self._phase_queue = self._phase_prefill = None
             self._phase_admit = self._phase_gap = None
-        # per-decode-step wall time (device step + sampling sync) split by
-        # attention impl: the ragged-vs-gather attribution the decode
-        # microbench and dashboards key on
+        # per-decode-step wall time (the step's dispatches + the fetch of
+        # its tokens, from the phase clock's reads) split by attention
+        # impl: the ragged-vs-gather attribution the decode microbench and
+        # dashboards key on
         self._step_obs = None
         try:
             from ray_tpu.serve import request_context as _rc2
@@ -611,6 +691,7 @@ class TPUEngine:
                        history=list(token_ids), lora_idx=lora_idx,
                        deadline_ts=float(deadline_ts or 0.0))
         req.submitted_ts = time.time()
+        req.trace_ctx = tracing.current_context()
         self._waiting.put(req)
         self._work.set()
         return req
@@ -920,8 +1001,39 @@ class TPUEngine:
         req.admitted_ts = time.time()
         if self._phase_admit is not None and req.submitted_ts:
             # decode-slot admission wait: submit → slot bind, covering the
-            # waiting queue, page-pressure backlog, and (PD) the page pull
+            # waiting queue, page-pressure backlog, the prefill up to the
+            # bind (a prefilled row is bound before its first token is
+            # fetched) and (PD) the page pull
             self._phase_admit.observe(req.admitted_ts - req.submitted_ts)
+        if req.kv_pack is not None:
+            # prefilled elsewhere: the transferred first token is here
+            self._scheduled(req, req.admitted_ts)
+            self._first_token(req, req.admitted_ts)
+
+    def _scheduled(self, req: _Request, now: float | None = None) -> None:
+        """Slot and pages are granted and the prefill is about to be
+        dispatched or staged: the request's queue wait ends. Once per
+        request (a streamed PD request is scheduled when its pages are
+        granted, long before the bind that brings its first token)."""
+        if req.scheduled_ts:
+            return
+        req.scheduled_ts = now or time.time()
+        self.requests_scheduled += 1
+        if req.submitted_ts:
+            wait = req.scheduled_ts - req.submitted_ts
+            self.queue_wait_s += wait
+            if self._phase_queue is not None:
+                self._phase_queue.observe(wait)
+
+    def _first_token(self, req: _Request, now: float | None = None) -> None:
+        """The request's first token is on the host: its prefill ends."""
+        req.first_token_ts = now or time.time()
+        self.first_tokens += 1
+        if req.scheduled_ts:
+            took = req.first_token_ts - req.scheduled_ts
+            self.prefill_s += took
+            if self._phase_prefill is not None:
+                self._phase_prefill.observe(took)
 
     def _insert(self, req: _Request, slot: int, kv, length: int, first_token):
         """Layout-dispatching sequence insertion. Returns False when the
@@ -1022,6 +1134,7 @@ class TPUEngine:
             self._slot_pages[slot] = pages
         req.slot = slot
         req.pf_done = 0
+        self._scheduled(req)
         self._streaming.append(req)
         return True
 
@@ -1218,6 +1331,7 @@ class TPUEngine:
                     return  # page pressure: stop admitting this round
                 admitted += 1
                 if first_id != -1:  # -1 = staged for chunked prefill
+                    self._first_token(req)
                     self._emit(req, first_id)
                 continue
             n = len(req.tokens)
@@ -1229,6 +1343,7 @@ class TPUEngine:
                     self._free.append(slot)
                     self._backlog.append(req)
                     return
+            t_sched = time.time()
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :n] = req.tokens
             if self.lora_bank is not None:
@@ -1240,12 +1355,18 @@ class TPUEngine:
                     self.params, jnp.asarray(padded), jnp.int32(n), self.cfg)
             self.key, sub = jax.random.split(self.key)
             first = self._sample_first(req, logits, sub)
+            self._clock.mark("admit_wait")
             first_id = int(first[0])
+            self._clock.mark("admit")
             if not self._insert(req, slot, kv, n, first[0]):
                 self._free.append(slot)
                 self._backlog.append(req)
                 return
             admitted += 1
+            # granted only now (a failed insert backlogs the request, and
+            # that wait is queue wait), as of before the prefill's dispatch
+            self._scheduled(req, t_sched)
+            self._first_token(req)
             self._emit(req, first_id)
 
     def _admit_cached(self, req: _Request, slot: int):
@@ -1296,6 +1417,8 @@ class TPUEngine:
                 self._page_refs[p] = self._page_refs.get(p, 1) - 1
             return None
         self._slot_shared[slot] = list(pre_pages)
+        self._scheduled(req)
+        req.prefix_reused = pre_len
         if self.enable_prefix_cache:
             if n_pre:
                 self.prefix_hits += 1
@@ -1342,7 +1465,10 @@ class TPUEngine:
         self._bind_slot(req, slot, n)
         if self.enable_prefix_cache:
             self._register_blocks(slot, tokens, hashes, n_pre, priv)
-        return int(first[0])
+        self._clock.mark("admit_wait")
+        first_id = int(first[0])
+        self._clock.mark("admit")
+        return first_id
 
     def _prefill_step(self):
         """Run ONE chunk of the oldest staged prefill (called between
@@ -1377,6 +1503,7 @@ class TPUEngine:
         self.state = self._dp.write_kv_pages(self.state, kv,
                                              jnp.asarray(chunk_pages))
         req.pf_done = done + len(chunk_toks)
+        req.pf_chunks += 1
         self.prefill_chunks_run += 1
         if not is_last:
             return
@@ -1394,7 +1521,11 @@ class TPUEngine:
             n_shared = len(self._slot_shared.get(req.slot, ()))
             self._register_blocks(req.slot, tokens, req.pf_hashes, n_shared,
                                   self._slot_pages[req.slot])
-        self._emit(req, int(first[0]))
+        self._clock.mark("prefill_wait")
+        first_id = int(first[0])
+        self._clock.mark("prefill")
+        self._first_token(req)
+        self._emit(req, first_id)
 
     def _index_ngram_at(self, req: _Request, end: int):
         """Record the n-gram ENDING at history position end-1; its
@@ -1446,7 +1577,9 @@ class TPUEngine:
         toks = decoding.sample_per_row(
             logits.reshape(S * K, V), sub,
             jnp.repeat(self._temps, K), jnp.repeat(self._topks, K))
+        self._clock.mark("decode_wait")
         toks_host = np.asarray(toks).reshape(S, K)
+        self._clock.mark("spec")
         counts = np.zeros((S,), np.int32)
         last = np.zeros((S,), np.int32)
         self.spec_steps += 1
@@ -1510,6 +1643,25 @@ class TPUEngine:
         self._guided_state.pop(req.slot, None)
         self._free.append(req.slot)
         del self._by_slot[req.slot]
+        if req.trace_ctx is not None:
+            self._emit_request_spans(req, time.time())
+
+    def _emit_request_spans(self, req: _Request, now: float) -> None:
+        """A sampled request's engine phases, from its stamps, under the
+        span that was active at submit()."""
+        ctx = req.trace_ctx
+        try:
+            tracing.emit_span_for(ctx, "engine:queue_wait", req.submitted_ts,
+                                  req.scheduled_ts, rid=req.rid)
+            tracing.emit_span_for(
+                ctx, "engine:prefill", req.scheduled_ts, req.first_token_ts,
+                prompt_tokens=len(req.tokens), chunks=req.pf_chunks,
+                prefix_tokens_reused=req.prefix_reused)
+            tracing.emit_span_for(ctx, "engine:decode", req.first_token_ts,
+                                  now, tokens=req.generated)
+        except Exception as e:  # pragma: no cover — spans must never kill
+            # the scheduler (every in-flight request would die)
+            logger.debug("engine span emit failed: %r", e)
 
     # -------------------------------------------------- cancellation plane
 
@@ -1607,6 +1759,7 @@ class TPUEngine:
         return True
 
     def _loop(self):
+        accelerators.note_thread_activity(lambda: self._clock.phase)
         try:
             # every program of a sharded engine is traced with its mesh
             # ambient: the flash prefill kernel shard_maps itself over it
@@ -1620,36 +1773,45 @@ class TPUEngine:
             raise
 
     def _loop_inner(self):
+        mark = self._clock.mark
         while not self._stop:
             # cancellation + deadline sweep first: an aborted/expired row's
             # slot and pages are back in the pool before this pass admits
             # or steps anything (reclaim within one decode step)
+            mark("sweep")
             self._apply_aborts()
             self._expire_deadlines()
             if (not self._by_slot and self._waiting.empty()
                     and not self._backlog and not self._prefilling
                     and not self._streaming):
+                mark("parked")
                 self._work.wait(timeout=0.1)
                 self._work.clear()
                 continue
+            mark("admit")
             self._admit()
-            stream_progress = (self._drain_streams() if self._streaming
-                               else False)
+            stream_progress = False
+            if self._streaming:
+                mark("streams")
+                stream_progress = self._drain_streams()
             if self._prefilling:
                 # one chunk per iteration: decode below keeps running
                 # requests emitting while a long prompt streams in
+                mark("prefill")
                 self._prefill_step()
             if not self._by_slot:
                 if self._streaming and not stream_progress:
                     # nothing decodable and no new pages yet: park until
                     # the transfer plane's feed() wakes us
+                    mark("parked")
                     self._work.wait(timeout=0.005)
                     self._work.clear()
                 continue
             if self.speculative_k:
+                mark("spec")
                 self._speculative_step()
                 continue
-            t_step = time.perf_counter()
+            t_step = mark("decode")
             if self.kv_layout == "paged":
                 if self.attn_impl == "ragged":
                     self.state, logits = self._dp.decode_step_paged_ragged(
@@ -1683,13 +1845,16 @@ class TPUEngine:
             # sampling params live on device, updated only at admission
             toks = decoding.sample_per_row(logits, sub, self._temps, self._topks)
             self.state = decoding.commit_tokens(self.state, toks)
+            mark("decode_wait")
             toks_host = np.asarray(toks)
+            t_emit = mark("emit")
             self.decode_steps += 1
             self.decode_slot_steps += len(self._by_slot)
             if self._step_obs is not None:
-                # device step + sampling sync: the ragged-vs-gather
-                # attribution surface (LLM_BENCH decode_step row)
-                self._step_obs.observe(time.perf_counter() - t_step)
+                # the step's dispatches + the fetch of its tokens: the
+                # ragged-vs-gather attribution surface (LLM_BENCH
+                # decode_step row)
+                self._step_obs.observe(t_emit - t_step)
             for slot, req in list(self._by_slot.items()):
                 self._emit(req, int(toks_host[slot]))
 
@@ -1716,6 +1881,11 @@ class TPUEngine:
                "worker_chips": accelerators.current_worker_chips(),
                "compile_cache": accelerators.compile_cache_counts(),
                "decode_steps": self.decode_steps,
+               "loop": {**self._clock.snapshot(), "requests": {
+                   "requests_scheduled": self.requests_scheduled,
+                   "queue_wait_s": self.queue_wait_s,
+                   "first_tokens": self.first_tokens,
+                   "prefill_s": self.prefill_s}},
                "aborts": self.aborts,
                "decode_occupancy": (self.decode_slot_steps
                                     / self.decode_steps

@@ -124,6 +124,7 @@ def _fwd_call(q, k, v, *, causal: bool, scale: float, block_q: int,
         ),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -274,6 +275,7 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )
     dk, dv = dkv(q, k, v, do, lse, delta)
 
@@ -293,6 +295,7 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool,
         out_specs=pl.BlockSpec((1, 1, block_q, D), outer_map, **kwargs),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

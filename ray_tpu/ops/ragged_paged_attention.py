@@ -126,6 +126,7 @@ def _ragged_kernel_call(q, kp, vp, block_table, pos, *, scale: float,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="ragged_paged_attention",
     )(block_table, pos, q, kp, vp)
 
 

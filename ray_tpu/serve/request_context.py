@@ -11,7 +11,7 @@ request tracing + phase attribution):
   PD per-page transfer waits.
 - **request ids + span sampling** — every request entering the HTTP proxy
   gets a 16-byte id; every Nth (`RayConfig.serve_span_sample_every`) opens
-  a `tracing.request_trace` root whose context propagates through handles
+  a `tracing.begin_request_trace` root whose context propagates through handles
   (fast-RPC frames and actor-plane specs alike) so one request id yields
   one cross-process span tree.
 - **flight recorder** — request summaries appended to the in-process ring
@@ -78,7 +78,9 @@ def _make_histograms() -> dict:
         ENGINE_PHASE: met.get_or_create(
             met.Histogram, "ray_tpu_llm_engine_phase_seconds",
             "engine request phases (admission_wait = submit->decode-slot "
-            "bind, inter_token = gap between emitted tokens)", **kw),
+            "bind: queue wait plus prefill; queue_wait = submit->slot and "
+            "pages granted; prefill = granted->first token; inter_token = "
+            "gap between emitted tokens)", **kw),
         PD_PHASE: met.get_or_create(
             met.Histogram, "ray_tpu_llm_pd_phase_seconds",
             "PD transfer-plane phases (transfer_wait = reader-side "

@@ -24,6 +24,7 @@ profile events through TaskEventBuffer instead of a live exporter).
 from __future__ import annotations
 
 import contextvars
+import functools
 import os
 import time
 from contextlib import contextmanager
@@ -56,8 +57,8 @@ def inject() -> dict | None:
     the CURRENT span becomes the remote task's parent. Gated on an ACTIVE
     context rather than the global `enable_tracing` flag: a context only
     exists when a root was opened — by :func:`trace` (which checks the
-    flag) or by per-request sampling (:func:`request_trace`, gated by
-    `serve_span_sample_every`) — so presence IS the sampling decision.
+    flag) or by per-request sampling (:func:`begin_request_trace`, gated
+    by `serve_span_sample_every`) — so presence IS the sampling decision.
     """
     ctx = _current.get()
     if ctx is None:
@@ -201,20 +202,6 @@ def finish_request_trace(handle, *, ok: bool = True,
                start=t0, end=time.time(), ok=ok, **extra)
 
 
-@contextmanager
-def request_trace(request_id: str, *, name: str = "serve:request", **extra):
-    """Context-manager form of begin/finish for same-thread request scopes."""
-    handle = begin_request_trace(request_id, **extra)
-    ok = True
-    try:
-        yield handle[1]
-    except BaseException:
-        ok = False
-        raise
-    finally:
-        finish_request_trace(handle, ok=ok, name=name)
-
-
 def emit_span_for(parent_ctx: dict | None, name: str, start: float,
                   end: float, *, ok: bool = True, kind: str = "phase",
                   **extra) -> None:
@@ -241,6 +228,21 @@ def emit_child_span(name: str, start: float, end: float, *, ok: bool = True,
     ctx = _current.get()
     if ctx is not None:
         emit_span_for(ctx, name, start, end, ok=ok, **extra)
+
+
+# ------------------------------------------------- device-trace annotations
+
+
+def device_annotation(name: str):
+    """Factory of host spans named ``ray_tpu:<name>`` on the JAX profiler's
+    own timeline, the clock the device ops of a trace are on (the one place
+    the prefix is written). Each call of the returned factory gives a fresh
+    `jax.profiler.TraceAnnotation` to enter and exit on ONE thread; with no
+    profiler session running that is a flag check in the constructor and
+    nothing is recorded."""
+    from jax.profiler import TraceAnnotation
+
+    return functools.partial(TraceAnnotation, "ray_tpu:" + name)
 
 
 # --------------------------------------------------------------- assembly

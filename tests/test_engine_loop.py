@@ -1,0 +1,372 @@
+"""The engine loop on the record (ISSUE 24): the scheduler thread's phase
+clock, the per-request sums, `stats()["loop"]`, the `ray_tpu:engine:*`
+annotations on the JAX profiler's timeline, the compile log, and the
+benchmark reader that turns two `stats()` readings into per-layer metrics.
+All on the CPU at tiny widths: no number here is a device number."""
+
+import glob
+import json
+import os
+import sys
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu._private import accelerators
+from ray_tpu.llm import engine as engine_mod
+from ray_tpu.llm.engine import (HOST_PHASES, LOOP_PHASES, SamplingParams,
+                                TPUEngine)
+from ray_tpu.models import transformer
+from ray_tpu.models.transformer import TransformerConfig
+from ray_tpu.util import tracing
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+WAITS = ("admit_wait", "prefill_wait", "decode_wait")
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_layers=1, n_heads=2,
+                            n_kv_heads=2, d_ff=64, max_seq_len=128,
+                            dtype=jnp.float32, remat=False)
+    return cfg, transformer.init(jax.random.PRNGKey(0), cfg)
+
+
+def _engine(tiny_model, **kw):
+    cfg, params = tiny_model
+    opts = dict(max_slots=4, max_len=128, min_bucket=16, kv_layout="paged",
+                page_size=16, enable_prefix_cache=True, prefill_chunk=16)
+    opts.update(kw)
+    return TPUEngine(cfg, params, **opts)
+
+
+def _prompt(i: int, n: int) -> list:
+    return [1 + (i * 7 + j * 3) % 60 for j in range(n)]
+
+
+def _burst(eng, n_requests: int, prompt_len: int, max_tokens: int) -> list:
+    reqs = [eng.submit(_prompt(i, prompt_len), SamplingParams(max_tokens=max_tokens))
+            for i in range(n_requests)]
+    return [list(r) for r in reqs]
+
+
+def _quiet_stats(eng) -> dict:
+    """A reading with the loop parked: nothing active, nothing queued."""
+    deadline = time.time() + 30.0
+    while time.time() < deadline:
+        st = eng.stats()
+        if not (st["active"] or st["waiting"] or st.get("prefilling")):
+            return st
+        time.sleep(0.01)
+    raise AssertionError("the engine did not go quiet")
+
+
+def test_phases_cover_the_thread(tiny_model):
+    """Every second of the scheduler thread is in exactly one phase: the
+    phase seconds add up to the thread's wall time between two readings, on
+    a paged engine with chunked prefill under a burst of requests."""
+    eng = _engine(tiny_model)
+    try:
+        _burst(eng, 2, 20, 3)  # compile everything outside the readings
+        s0 = _quiet_stats(eng)
+        outs = _burst(eng, 8, 40, 6)  # 40 > chunk 16: staged, three chunks
+        s1 = _quiet_stats(eng)
+    finally:
+        eng.shutdown()
+    assert all(len(o) == 6 for o in outs)
+    l0, l1 = s0["loop"], s1["loop"]
+    assert tuple(l1["seconds"]) == LOOP_PHASES
+    delta = {p: l1["seconds"][p] - l0["seconds"][p] for p in LOOP_PHASES}
+    thread = l1["thread_s"] - l0["thread_s"]
+    assert thread > 0
+    assert sum(delta.values()) == pytest.approx(thread, rel=0.01)
+    assert all(d >= 0 for d in delta.values())
+    assert s1["decode_steps"] > s0["decode_steps"]
+    # eight staged prompts (two or three chunks each: the prefix cache may
+    # serve a first block), one first-token fetch each
+    chunks = s1["prefill_chunks_run"] - s0["prefill_chunks_run"]
+    assert 16 <= chunks <= 24
+    assert delta["spec"] == 0 and delta["streams"] == 0
+    for busy in ("sweep", "admit", "prefill", "prefill_wait", "decode",
+                 "decode_wait", "emit"):
+        assert delta[busy] > 0, busy
+    r0, r1 = l0["requests"], l1["requests"]
+    assert r1["requests_scheduled"] - r0["requests_scheduled"] == 8
+    assert r1["first_tokens"] - r0["first_tokens"] == 8
+
+
+def test_snapshot_is_consistent_while_the_loop_runs(tiny_model):
+    """Readings taken from another thread while the loop marks boundaries
+    never run backwards, and each is short of the thread's time by at most
+    the one phase interval whose boundary fell inside the copy."""
+    eng = _engine(tiny_model)
+    readings = []
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # threads change places mid-boundary
+    try:
+        reqs = [eng.submit(_prompt(i, 12), SamplingParams(max_tokens=24))
+                for i in range(4)]
+        while len(readings) < 200:
+            readings.append(eng.stats()["loop"])
+        for r in reqs:
+            list(r)
+    finally:
+        sys.setswitchinterval(switch)
+        eng.shutdown()
+    for a, b in zip(readings, readings[1:]):
+        assert -0.5 < sum(a["seconds"].values()) - a["thread_s"] < 1e-6
+        assert b["thread_s"] >= a["thread_s"]
+        assert all(b["seconds"][p] >= a["seconds"][p] - 1e-9 for p in LOOP_PHASES)
+
+
+def test_queue_wait_and_prefill_split_the_admission_wait(tiny_model):
+    """submit → bind (what `admission_wait` observes) is queue wait plus
+    prefill: the two new sums cover it, and exceed it only by the fetch of
+    the first token, which follows the bind."""
+    eng = _engine(tiny_model)
+    try:
+        _burst(eng, 2, 40, 3)
+        _quiet_stats(eng)
+        admit = eng._phase_admit._st  # the bound series: {"sum", "count", ...}
+        a0 = (admit["sum"], admit["count"])
+        s0 = eng.stats()["loop"]
+        _burst(eng, 6, 40, 4)
+        s1 = _quiet_stats(eng)["loop"]
+        a1 = (admit["sum"], admit["count"])
+    finally:
+        eng.shutdown()
+    assert a1[1] - a0[1] == 6
+    observed = a1[0] - a0[0]
+    r0, r1 = s0["requests"], s1["requests"]
+    split = (r1["queue_wait_s"] - r0["queue_wait_s"]
+             + r1["prefill_s"] - r0["prefill_s"])
+    fetch = sum(s1["seconds"][p] - s0["seconds"][p]
+                for p in ("admit_wait", "prefill_wait"))
+    assert observed <= split + 1e-6
+    # per request at most the fetches of the burst and a little host work
+    assert split - observed <= fetch + 6 * 0.05
+    # the histogram carries the two new labels beside the old one
+    from ray_tpu.util import metrics as met
+
+    phases = {dict(tuple(t) for t in tags)["phase"]: st["count"]
+              for m in met.snapshot()
+              if m["name"] == "ray_tpu_llm_engine_phase_seconds"
+              for tags, st in m["series"]}
+    assert phases["queue_wait"] >= 6 and phases["prefill"] >= 6
+
+
+def test_one_slot_makes_the_third_request_wait_for_two_prefills(tiny_model):
+    """With one slot and three long prompts the third request's queue wait
+    holds the first's whole prefill (and its decode)."""
+    eng = _engine(tiny_model, max_slots=1, max_len=128)
+    try:
+        _burst(eng, 1, 100, 2)  # compile
+        _quiet_stats(eng)
+        reqs = [eng.submit(_prompt(i + 1, 100), SamplingParams(max_tokens=2))
+                for i in range(3)]
+        for r in reqs:
+            list(r)
+    finally:
+        eng.shutdown()
+    first, _, third = reqs
+    for r in reqs:
+        assert (r.submitted_ts <= r.scheduled_ts <= r.first_token_ts
+                and r.scheduled_ts <= r.admitted_ts)
+        assert r.pf_chunks == 7  # 100 tokens in chunks of 16
+    assert (third.scheduled_ts - third.submitted_ts
+            > first.first_token_ts - first.scheduled_ts)
+    assert third.scheduled_ts >= first.first_token_ts
+
+
+def test_stats_stay_json_plain(tiny_model):
+    eng = _engine(tiny_model)
+    try:
+        _burst(eng, 2, 20, 3)
+        st = _quiet_stats(eng)
+    finally:
+        eng.shutdown()
+    back = json.loads(json.dumps(st))
+    assert back["loop"] == st["loop"]
+    assert set(back["loop"]) == {"seconds", "host_s", "active_s", "thread_s",
+                                 "requests"}
+    assert len(json.dumps(back["loop"])) < 1000
+    assert set(back["loop"]["requests"]) == {
+        "requests_scheduled", "queue_wait_s", "first_tokens", "prefill_s"}
+    recent = back["compile_cache"]["recent"]
+    assert isinstance(recent, list) and len(recent) <= 16
+
+
+def test_slot_layout_and_speculative_engines_keep_the_clock(tiny_model):
+    """The slot layout's admission fetch is `admit_wait`; a speculative
+    step's fetch is `decode_wait`, the rest of it `spec`."""
+    cfg, params = tiny_model
+    eng = TPUEngine(cfg, params, max_slots=2, max_len=64, speculative_k=2)
+    try:
+        assert len(eng.generate([1, 2, 3, 1, 2, 3], SamplingParams(max_tokens=6))) == 6
+        loop = _quiet_stats(eng)["loop"]
+    finally:
+        eng.shutdown()
+    assert loop["seconds"]["admit_wait"] > 0 and loop["seconds"]["decode_wait"] > 0
+    assert loop["seconds"]["spec"] > 0 and loop["seconds"]["decode"] == 0
+    # scheduled as of before the prefill's dispatch, once it is inserted
+    assert loop["requests"]["requests_scheduled"] == 1
+    assert 0 < loop["requests"]["prefill_s"] >= loop["seconds"]["admit_wait"]
+
+
+def test_compile_log_names_the_program_and_the_phase(tiny_model):
+    """`compile_cache_counts()["recent"]`: the last compilations with the
+    program's name as JAX gives it and what the compiling thread was doing
+    (the engine thread: the loop phase that was open)."""
+    accelerators.compile_cache_counts()
+    eng = _engine(tiny_model, page_size=32, min_bucket=32, prefill_chunk=32)
+    try:
+        _burst(eng, 1, 40, 3)  # shapes no earlier test compiled
+        recent = eng.stats()["compile_cache"]["recent"]
+    finally:
+        eng.shutdown()
+    assert 0 < len(recent) <= 16
+    for e in recent:
+        assert set(e) == {"t", "seconds", "cache", "program", "thread", "phase"}
+    mine = [e for e in recent if e["thread"] == "tpu-engine"]
+    assert mine and all(e["phase"] in LOOP_PHASES for e in mine)
+    assert all(e["program"].startswith(("jit(", "pjit(")) for e in mine)
+    assert any(e["phase"] in ("decode", "prefill", "admit") for e in mine)
+
+    done = threading.Event()
+
+    def elsewhere():
+        jax.jit(lambda x: x * 3 + 1)(jnp.ones((7, 3)))
+        done.set()
+
+    t = threading.Thread(target=elsewhere, name="not-the-engine")
+    t.start()
+    t.join()
+    assert done.is_set()
+    last = accelerators.compile_cache_counts()["recent"][-1]
+    assert last["thread"] == "not-the-engine" and last["phase"] is None
+
+
+def test_device_annotation_is_the_one_prefix():
+    span = tracing.device_annotation("engine:decode")()
+    with span:
+        pass
+    assert span.__class__.__name__ == "TraceAnnotation"
+    assert not hasattr(tracing, "request_trace")  # the unused form is gone
+    src = open(engine_mod.__file__).read()
+    assert '"ray_tpu:' not in src and "'ray_tpu:" not in src
+
+
+def test_engine_phases_are_on_the_profiler_timeline(tiny_model, tmp_path):
+    """A `jax.profiler` trace taken around a few steps carries the loop's
+    phases as `ray_tpu:engine:*` events, all on the scheduler thread's line."""
+    from jax.profiler import ProfileData
+
+    eng = _engine(tiny_model)
+    try:
+        _burst(eng, 1, 20, 2)
+        _quiet_stats(eng)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            _burst(eng, 2, 20, 5)
+            _quiet_stats(eng)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.shutdown()
+    found = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    assert found
+    lines = []
+    for plane in ProfileData.from_file(found[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            names = [e.name for e in line.events if e.name.startswith("ray_tpu:")]
+            if names:
+                lines.append(names)
+    if not lines:
+        pytest.skip("this JAX writes no TraceMe events to the host plane on CPU")
+    # one thread marks the phases, so they are on one line (which the
+    # profiler names after the OS thread, `python` before Python 3.14)
+    (names,) = lines
+    assert names.count("ray_tpu:engine:decode") >= 4
+    assert {"ray_tpu:engine:decode_wait", "ray_tpu:engine:emit",
+            "ray_tpu:engine:admit", "ray_tpu:engine:sweep"} <= set(names)
+    assert {n[len("ray_tpu:engine:"):] for n in names} <= set(LOOP_PHASES)
+
+
+# ------------------------------------------- the benchmark's reader of these
+
+
+def _stats(loop_seconds: dict, requests: dict, decode_steps: int) -> dict:
+    seconds = dict.fromkeys(LOOP_PHASES, 0.0)
+    seconds.update(loop_seconds)
+    host = sum(seconds[p] for p in HOST_PHASES)
+    return {"decode_steps": decode_steps,
+            "loop": {"seconds": seconds, "host_s": host,
+                     "active_s": host + sum(seconds[p] for p in WAITS),
+                     "thread_s": sum(seconds.values()), "requests": requests}}
+
+
+S0 = _stats({"parked": 5.0, "sweep": 0.1, "admit": 0.2, "admit_wait": 0.3,
+             "prefill": 0.4, "prefill_wait": 0.5, "decode": 1.0,
+             "decode_wait": 8.0, "emit": 0.5},
+            {"requests_scheduled": 10, "queue_wait_s": 1.0, "first_tokens": 10,
+             "prefill_s": 2.0}, 100)
+S1 = _stats({"parked": 6.0, "sweep": 0.3, "admit": 0.6, "admit_wait": 0.9,
+             "prefill": 1.2, "prefill_wait": 1.5, "decode": 3.0,
+             "decode_wait": 24.0, "emit": 1.5},
+            {"requests_scheduled": 30, "queue_wait_s": 5.0, "first_tokens": 26,
+             "prefill_s": 10.0}, 300)
+# deltas: host 0.2+0.4+0.8+2.0+1.0 = 4.4; waits 0.6+1.0+16.0 = 17.6; active 22.0
+HAND = {
+    "engine_host_pct.chat": 100 * 4.4 / 22.0,
+    "engine_host_pct.doc": 100 * 4.4 / 22.0,
+    "step_host_ms.chat": 1e3 * 4.4 / 200,
+    "step_device_wait_ms.chat": 1e3 * 16.0 / 200,
+    "engine_decode_pct.doc": 100 * (2.0 + 16.0 + 1.0) / 22.0,
+    "queue_wait_ms.chat": 1e3 * 4.0 / 20,
+    "queue_wait_ms.doc": 1e3 * 4.0 / 20,
+    "prefill_latency_ms.doc": 1e3 * 8.0 / 16,
+}
+
+
+@pytest.mark.parametrize("metric", sorted(HAND))
+def test_stats_ratio_reads_the_metric(metric):
+    from chipbench.readers import stats_ratio
+
+    spec = json.load(open(os.path.join(
+        REPO, "chipbench", "layer_metrics", metric + ".json")))
+    assert spec["reader"] == "stats_ratio"
+    facts = {"stats0": S0, "stats1": S1}
+    assert stats_ratio.read(facts, spec["params"]) == pytest.approx(HAND[metric])
+    # the parent's stats() has no "loop": nothing to read, nothing raised
+    bare = {"stats0": {"decode_steps": 100}, "stats1": {"decode_steps": 300}}
+    assert stats_ratio.read(bare, spec["params"]) is None
+    assert stats_ratio.read({}, spec["params"]) is None
+    # a denominator that did not move gives no number
+    assert stats_ratio.read({"stats0": S1, "stats1": S1}, spec["params"]) is None
+    entry = [m for m in json.load(open(os.path.join(REPO, "BENCHMARK.json")))[
+        "per_layer"] if m["name"] == metric]
+    assert len(entry) == 1 and entry[0]["source"] == "program_counter"
+    assert entry[0]["better"] == "lower" and len(entry[0]["workloads"]) == 1
+
+
+def test_host_and_active_sums_partition_the_phases(tiny_model):
+    """"host" is every phase but `parked` and the waits, "active" every
+    phase but `parked`: `stats()` gives both sums, so a metric file names
+    one path and a phase added to the engine is in them."""
+    assert set(HOST_PHASES) | set(WAITS) | {"parked"} == set(LOOP_PHASES)
+    eng = _engine(tiny_model)
+    try:
+        _burst(eng, 2, 40, 3)
+        loop = _quiet_stats(eng)["loop"]
+    finally:
+        eng.shutdown()
+    sec = loop["seconds"]
+    assert loop["host_s"] == pytest.approx(sum(sec[p] for p in HOST_PHASES))
+    assert loop["active_s"] == pytest.approx(
+        sum(sec[p] for p in LOOP_PHASES if p != "parked"))
+    assert 0 < loop["host_s"] < loop["active_s"] < loop["thread_s"]

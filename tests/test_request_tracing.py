@@ -72,14 +72,18 @@ def _flat(span, acc):
 
 
 def _wait_tree(rid, want_names, timeout=30.0):
-    """Poll until the trace for `rid` contains every name in want_names."""
+    """Poll until the trace for `rid` contains every name in want_names
+    (`prefix*` = some name with that prefix: each process flushes its
+    spans on its own cycle, so they arrive in any order)."""
     deadline = time.time() + timeout
     spans = []
     while time.time() < deadline:
         tree = tracing.get_trace(rid)
         if tree is not None:
             spans = _flat(tree["root"], [])
-            if want_names <= {s.get("name") for s in spans}:
+            names = {s.get("name") or "" for s in spans}
+            if all(w in names or (w.endswith("*") and any(
+                    n.startswith(w[:-1]) for n in names)) for w in want_names):
                 return spans
         time.sleep(0.4)
     raise AssertionError(
@@ -122,9 +126,8 @@ def test_sampled_request_span_tree(sampled_cluster):
     serve.run(_Echo.bind(), name="echo", route_prefix="/echo")
     out = _http_post("/echo", {"x": 1})
     rid = out["rid"]
-    spans = _wait_tree(rid, {"serve:request", "proxy:route", "proxy:handle"})
-    names = {s.get("name") for s in spans}
-    assert any(n and n.startswith("replica:echo") for n in names), names
+    spans = _wait_tree(rid, {"serve:request", "proxy:route", "proxy:handle",
+                             "replica:echo*"})
     # every span in the tree carries the request id (chrome-trace grouping)
     assert all(s.get("request_id") == rid for s in spans
                if s.get("name") != "(root)")
@@ -353,3 +356,86 @@ def test_engine_phase_histograms(monkeypatch):
         assert totals() == base
     finally:
         RayConfig.reset()
+
+
+# ------------------------------------------------- engine spans (ISSUE 24)
+
+
+def _tiny_llm_app():
+    from ray_tpu.llm import LLMConfig, ModelLoadingConfig, build_openai_app
+
+    cfg = LLMConfig(
+        model_loading_config=ModelLoadingConfig(model_id="tiny",
+                                                tokenizer="byte"),
+        model_family="llama", accelerator_type=None,
+        engine_kwargs=dict(max_slots=2, max_len=128, min_bucket=16,
+                           kv_layout="paged", page_size=16,
+                           enable_prefix_cache=True, prefill_chunk=16))
+    serve.start(http_port=0)
+    serve.run(build_openai_app(cfg), name="llm", route_prefix="/v1")
+
+
+def _http_stream(path: str, body: dict) -> list:
+    host, port = serve.http_address()
+    req = urllib.request.Request(
+        f"http://{host}:{port}{path}",
+        data=json.dumps({**body, "stream": True}).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=60) as resp:
+        return [json.loads(line[6:]) for line in resp.read().splitlines()
+                if line.startswith(b"data: {")]
+
+
+@pytest.mark.parametrize("streamed", [False, True], ids=["unary", "sse"])
+def test_sampled_request_reaches_the_engine(sampled_cluster, streamed, capsys):
+    """One sampled request through build_openai_app → one tree whose leaves
+    include the engine's queue wait, prefill and decode, in that order and
+    inside the serve:request root; a prompt of 41 tokens (BOS) at prefill_chunk 16
+    is staged, so the prefill span counts three chunks."""
+    _tiny_llm_app()
+    body = {"prompt": "a" * 40, "max_tokens": 5}
+    if streamed:
+        chunks = _http_stream("/v1/completions", body)
+        assert sum(len(c["choices"][0].get("token_ids", [])) for c in chunks) == 5
+    else:
+        out = _http_post("/v1/completions", body)
+        assert out["usage"]["completion_tokens"] == 5
+    rows = _wait_requests(lambda r: r.get("component") == "http_proxy"
+                          and r.get("path") == "/v1/completions")
+    rid = rows[-1]["request_id"]
+    want = {"serve:request", "engine:queue_wait", "engine:prefill",
+            "engine:decode"}
+    spans = _wait_tree(rid, want, timeout=45.0)
+    by_name = {s["name"]: s for s in spans if s.get("name") in want}
+    root = by_name["serve:request"]
+    queue, prefill, decode = (by_name["engine:" + n]
+                              for n in ("queue_wait", "prefill", "decode"))
+    assert not (queue["children"] or prefill["children"] or decode["children"])
+    assert (root["start"] <= queue["start"] <= queue["end"] == prefill["start"]
+            <= prefill["end"] == decode["start"] <= decode["end"]
+            <= root["end"] + 0.05)
+    assert prefill["prompt_tokens"] == 41 and prefill["chunks"] == 3
+    assert decode["tokens"] == 5
+    assert sum(1 for s in spans if s.get("name") == "engine:decode") == 1
+    assert all(s.get("request_id") == rid for s in spans
+               if (s.get("name") or "").startswith("engine:"))
+    # and `ray_tpu trace show <request_id>` prints them under the root
+    from ray_tpu._private import api as _api
+    from ray_tpu.scripts.cli import main as cli_main
+
+    cli_main(["--session", _api._node.session_dir, "trace", "show", rid])
+    shown = capsys.readouterr().out
+    assert shown.index("serve:request") < shown.index("engine:queue_wait") \
+        < shown.index("engine:prefill") < shown.index("engine:decode")
+
+
+def test_unsampled_request_emits_no_engine_span(unsampled_cluster):
+    _tiny_llm_app()
+    out = _http_post("/v1/completions", {"prompt": "abc", "max_tokens": 4})
+    assert out["usage"]["completion_tokens"] == 4
+    stats = _http_post("/v1/stats", {})
+    assert stats["loop"]["requests"]["first_tokens"] >= 1
+    time.sleep(2.5)  # one full flusher cycle
+    events = _gcs_rpc({"type": "task_events"}).get("events", [])
+    assert [e for e in events if e.get("event") == "trace:span"
+            and (e.get("name") or "").startswith("engine:")] == []

@@ -109,3 +109,36 @@ def test_paged_decode_step_with_kernel_compiles(chip):
     text = decoding_paged.decode_step_paged_ragged.lower(
         params, state, cfg, 8, True).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_kernel_names_reach_the_compiled_program(chip):
+    """`pallas_call(name=...)` names the HLO instruction of each kernel's
+    custom call, which is what a device trace names the op by: the trace
+    reduction tells the kernels apart by name, not only by operand count."""
+    import re
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, None, 512, 512).astype(
+            jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
+                          *_flash_qkv(chip, GPT2_774M_TRAIN))
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    # under jax.grad the transforms wrap the name: jvp(flash_fwd) prints as
+    # `jvp_flash_fwd_`, the backward pair as `transpose_jvp_flash_bwd_dq__`
+    assert sorted(re.sub(r"^((jvp|transpose)_)*|_+$", "", c.split(".")[0])
+                  for c in calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+    B, Hkv, G, Dh, P, nb = 16, 8, 4, 64, 64, 32
+
+    def sds(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=chip)
+
+    text = _compiled_text(
+        lambda *a: ragged_decode_attention(*a, impl="kernel"),
+        sds((B, Hkv, G, Dh), jnp.bfloat16),
+        sds((B * nb + 1, P, Hkv, Dh), jnp.bfloat16),
+        sds((B * nb + 1, P, Hkv, Dh), jnp.bfloat16),
+        sds((B, nb), jnp.int32), sds((B,), jnp.int32))
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert [c.split(".")[0] for c in calls] == ["ragged_paged_attention"]
