@@ -65,16 +65,17 @@ def _attention(layers, i, x, *, theta, eps):
 
 
 @functools.partial(jax.jit, static_argnames=("top_k", "eps"))
-def _route(layers, i, x, swap, *, top_k, eps):
+def _route(layers, i, x, depth, *, top_k, eps):
     """(normed input, gates [T, E]: renormalised top-k weights, 0 elsewhere,
-    margin [T]: router-logit gap between the last expert taken and the first
-    one left out). Where `swap` [T] is set, the first expert left out is
-    taken instead of the last one taken: the other side of a tie."""
+    margin [T, 2]: router-logit gap between the last expert taken by plain
+    top-k and the first, and the second, one left out). `depth` [T] int: 0
+    is plain top-k; at 1 (2) the first (second) expert left out is taken
+    instead of the last one taken: the other side of a tie."""
     h = _rms_norm(x, _at(layers["norm2"]["w"], i), eps)
     logits = h @ _at(layers["mlp"]["router"], i).astype(F32)
     probs = jax.nn.softmax(logits, axis=-1)
-    top, idx = jax.lax.top_k(probs, top_k + 1)
-    last = jnp.where(swap, top_k, top_k - 1)[:, None]
+    top, idx = jax.lax.top_k(probs, top_k + 2)
+    last = (top_k - 1 + depth)[:, None]
     top = jnp.concatenate([top[:, :top_k - 1],
                            jnp.take_along_axis(top, last, axis=1)], axis=1)
     idx = jnp.concatenate([idx[:, :top_k - 1],
@@ -82,7 +83,7 @@ def _route(layers, i, x, swap, *, top_k, eps):
     top = top / top.sum(-1, keepdims=True)
     gates = jnp.zeros_like(probs).at[jnp.arange(x.shape[0])[:, None], idx].set(top)
     ranked = jnp.sort(logits, axis=-1)
-    margin = ranked[:, -top_k] - ranked[:, -top_k - 1]
+    margin = ranked[:, -top_k, None] - ranked[:, -top_k - 2:-top_k][:, ::-1]
     return h, gates, margin
 
 
@@ -95,22 +96,22 @@ def _expert(mlp, i, e, h, gate_e):
     return y * gate_e[:, None]
 
 
-def forward(params, tokens, sizes: dict, swaps=None):
-    """tokens [T] int32 -> (logits [T, V] float32, margin [L, T]: see
+def forward(params, tokens, sizes: dict, depth=None):
+    """tokens [T] int32 -> (logits [T, V] float32, margin [L, T, 2]: see
     `_route` — a token whose margin is within rounding of zero may
-    legitimately be routed otherwise by a lower-precision router). `swaps`
-    [L, T] bool routes the marked tokens the other way (None: top-k)."""
+    legitimately be routed otherwise by a lower-precision router). `depth`
+    [L, T] int routes the marked tokens the other way (None: top-k)."""
     with jax.default_matmul_precision("highest"):
-        if swaps is None:
-            swaps = jnp.zeros((sizes["n_layers"], tokens.shape[0]), bool)
-        swaps = jnp.asarray(swaps)
+        if depth is None:
+            depth = jnp.zeros((sizes["n_layers"], tokens.shape[0]), jnp.int32)
+        depth = jnp.asarray(depth, jnp.int32)
         layers = params["layers"]
         x = params["embed"].astype(F32)[tokens]
         margins = []
         for i in range(sizes["n_layers"]):
             x = _attention(layers, i, x, theta=float(sizes["rope_theta"]),
                            eps=float(sizes["norm_eps"]))
-            h, gates, margin = _route(layers, i, x, swaps[i], top_k=sizes["top_k"],
+            h, gates, margin = _route(layers, i, x, depth[i], top_k=sizes["top_k"],
                                       eps=float(sizes["norm_eps"]))
             margins.append(margin)
             for e in range(sizes["num_experts"]):

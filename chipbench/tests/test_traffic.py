@@ -140,3 +140,85 @@ def test_a_rate_is_every_token_delivered_over_all_of_the_window():
     out = traffic.summarize(records, 10.0)
     assert out["failed"] == 1 and out["ttft_p95_ms"] is None
     assert out["prompt_tokens_in_window"] == pytest.approx(3000 + 9000 * 2 / 10)
+
+
+class _EosStub(http.server.BaseHTTPRequestHandler):
+    """The server's stream by the prompt: "eos" is answered with the closing
+    chunk alone (the first greedy token was EOS), "cut" breaks before any
+    chunk, anything else gets its tokens and then the closing chunk."""
+
+    def do_POST(self):
+        body = json.loads(self.rfile.read(int(self.headers["Content-Length"])))
+        self.send_response(200)
+        self.send_header("Content-Type", "text/event-stream")
+        self.end_headers()
+        if body["prompt"] == "cut":
+            return
+        chunks = [{"token_ids": [i], "finish_reason": None}
+                  for i in range(0 if body["prompt"] == "eos" else body["max_tokens"])]
+        for choice in chunks + [{"text": "", "finish_reason": "stop"}]:
+            time.sleep(0.01)
+            self.wfile.write(b"data: " + json.dumps({"choices": [choice]}).encode() + b"\n\n")
+            self.wfile.flush()
+        self.wfile.write(b"data: [DONE]\n\n")
+
+    def log_message(self, *a):
+        pass
+
+
+def test_an_answer_that_is_empty_by_eos_is_an_answer():
+    """Only the closing chunk: answered with nothing, when that chunk came.
+    A stream that broke before its closing chunk stays failed."""
+    server = http.server.ThreadingHTTPServer(("127.0.0.1", 0), _EosStub)
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    try:
+        client = traffic.Client("127.0.0.1", server.server_address[1])
+        reqs = [traffic.Request(i, 0.05 * i, 80, 4, p)
+                for i, p in enumerate(["text", "eos", "cut", "text"])]
+        records, window_s = traffic.open_loop(client, reqs, 0.5)
+    finally:
+        server.shutdown()
+    assert [r["status"] for r in records] == ["ok", "ok", "empty", "ok"]
+    eos, cut = records[1], records[2]
+    assert eos["tokens"] == 0 and eos["tokens_in_window"] == 0
+    assert eos["first_s"] == eos["last_s"] and eos["sent_s"] < eos["first_s"] <= eos["done_s"]
+    assert cut["first_s"] is None and cut["tokens"] == 0
+    out = traffic.summarize(records, window_s)
+    assert out["attempted"] == 4 and out["failed"] == 1 and out["completed"] == 3
+    # its prompt counts like any other, it gives no gap between tokens, and
+    # its time to the answer is in the tail with the others
+    assert out["prompt_tokens_in_window"] == 3 * 80 and out["output_tokens_in_window"] == 8
+    both = [r for r in records if r["tokens"] > 1]
+    assert out["tpot_p50_ms"] == pytest.approx(np.median(
+        [(r["last_s"] - r["first_s"]) / 3 * 1e3 for r in both]))
+    assert out["ttft_p50_ms"] is not None and out["ttft_p95_ms"] is None   # the cut one
+    assert traffic.summarize([eos], window_s)["ttft_p95_ms"] == pytest.approx(
+        (eos["first_s"] - eos["due_s"]) * 1e3)
+    assert "tpot_p50_ms" not in traffic.summarize([eos], window_s)
+
+
+def test_summarize_reads_a_recorded_run_as_before():
+    """Twenty rows of a run on the chip (chat-steady, seed 2147483813, PR 24),
+    request 71 among them: its first greedy token was EOS and the client of
+    that day recorded it `empty`. `summarize` gives what it gave then, to
+    every digit; with that row as the client records it now, the one failure
+    goes and nothing else moves but the prompt it adds."""
+    with open(harness.BENCH_DIR + "/tests/data/requests_chat_2147483813.jsonl") as f:
+        rows = [json.loads(line) for line in f]
+    before = traffic.summarize(rows, 40.0)
+    assert before == {
+        "attempted": 20, "failed": 1, "completed": 19, "served_tok_s": 193.125,
+        "prompt_tokens_in_window": 5324.0, "output_tokens_in_window": 2401,
+        "completed_in_window": 19, "completed_tok_s": 193.125,
+        "ttft_p50_ms": 58.57733142606669, "ttft_p95_ms": 180.66016392090845,
+        "tpot_p50_ms": 41.253652611111924, "tpot_p95_ms": 43.30903782222195,
+        "late_p95_ms": 1.4337381543043648}
+    row, = [r for r in rows if r["status"] == "empty"]
+    assert row["index"] == 71 and row["prompt_tokens"] == 80
+    row.update(status="ok", first_s=row["done_s"], last_s=row["done_s"])
+    now = traffic.summarize(rows, 40.0)
+    assert now["failed"] == 0 and now["completed"] == now["completed_in_window"] == 20
+    assert now["prompt_tokens_in_window"] == 5324.0 + 80
+    assert now["served_tok_s"] == now["completed_tok_s"] == (5324 + 80 + 2401) / 40.0
+    for same in ("output_tokens_in_window", "tpot_p50_ms", "tpot_p95_ms", "late_p95_ms"):
+        assert now[same] == before[same], same

@@ -111,7 +111,11 @@ class Client:
 
     def post(self, path: str, body: dict, on_token=None) -> dict:
         """Returns {"status", "token_ids", "usage"}; with `on_token`, streams
-        and calls it with the number of tokens of every chunk as it arrives."""
+        and calls it with the number of tokens of every chunk as it arrives,
+        and returns the `finish_reason` of the stream's closing chunk (None:
+        the stream ended without one). An answer that is empty because its
+        first greedy token was EOS is only that closing chunk: `on_token(0)`
+        then says when the user learned the answer."""
         conn = http.client.HTTPConnection(self.host, self.port, timeout=self.timeout_s)
         try:
             conn.request("POST", path, json.dumps(body),
@@ -126,17 +130,22 @@ class Client:
                 return {"status": 200, "usage": answer.get("usage"),
                         "token_ids": choices[0].get("token_ids", []),
                         "answer": answer}
-            ids = []
+            ids, finish = [], None
             while True:
                 line = resp.readline()
                 if not line or line.strip() == b"data: [DONE]":
                     break
                 if line.startswith(b"data: "):
-                    new = json.loads(line[6:])["choices"][0].get("token_ids", [])
+                    choice = json.loads(line[6:])["choices"][0]
+                    new = choice.get("token_ids", [])
                     if new:
                         on_token(len(new))
                         ids.extend(new)
-            return {"status": 200, "token_ids": ids}
+                    if choice.get("finish_reason") is not None:
+                        finish = choice["finish_reason"]
+                        if not ids:
+                            on_token(0)
+            return {"status": 200, "token_ids": ids, "finish_reason": finish}
         finally:
             conn.close()
 
@@ -173,7 +182,10 @@ def open_loop(client: Client, requests: list, seconds: float, *,
                 "prompt": req.prompt, "max_tokens": req.max_tokens,
                 "temperature": 0.0, "stream": True}, on_token)
             rec["tokens"] = len(ans["token_ids"])
-            rec["status"] = "ok" if ans["status"] == 200 and rec["tokens"] else (
+            # answered: tokens came, or the closing chunk of an answer that is
+            # empty by EOS did; a stream that broke before either is "empty"
+            answered = rec["tokens"] or ans.get("finish_reason") is not None
+            rec["status"] = "ok" if ans["status"] == 200 and answered else (
                 f"http_{ans['status']}" if ans["status"] != 200 else "empty")
         except Exception as e:  # noqa: BLE001 — every failure is a record
             rec["status"] = f"{type(e).__name__}: {e}"[:120]
