@@ -12,6 +12,12 @@ All shapes stay static (XLA-first, like everything here): the pool is
 the page axis. Page allocation/free is host-side bookkeeping in the engine
 (a free list), mirroring how vLLM's scheduler owns its block tables.
 
+The decode steps carry the pools through the layer scan (viewed flat,
+[L*num_pages, page, Hkv, Dh], layer l's pages at l*num_pages + id) and
+scatter each layer's rows into the carry in place, because pools handed to
+the scan as inputs and stacked as its outputs are sliced, copied and
+rewritten whole every step.
+
 (reference capability: vLLM paged attention behind
 llm/_internal/serve/engines/vllm/vllm_engine.py:114; design here is
 TPU-native — dense static gathers, no custom CUDA.)
@@ -69,13 +75,16 @@ def insert_sequence_paged(state, slot, kv, length, first_token, pages,
     return state
 
 
-@functools.partial(jax.jit, donate_argnames=("state",), static_argnames=("cfg",))
-def decode_step_paged(params, state, cfg: TransformerConfig):
-    """Advance every active row one token against its paged cache."""
+def _decode_step(params, state, cfg: TransformerConfig, attend):
+    """One token for every active row; the body both decode steps share.
+    `attend(qh, kp, vp, base, pos)` is the attention core: qh [B, Hkv, G, Dh]
+    against the FLAT pools [L*num_pages, P, Hkv, Dh] the scan carries, in
+    which this layer's page `i` lies at `base + i`. With `state` donated the
+    pools alias input to output and a step writes B rows a layer."""
     dt = cfg.dtype
-    B, MP = state["block"].shape
-    P = state["kp"].shape[2]
-    S = MP * P
+    B = state["block"].shape[0]
+    L, num_pages, P = state["kp"].shape[:3]
+    flat = (L * num_pages,) + state["kp"].shape[2:]
     tokens = state["last_token"][:, None]
     pos = state["length"]                                      # [B]
     page_ids = jnp.take_along_axis(state["block"],
@@ -88,47 +97,64 @@ def decode_step_paged(params, state, cfg: TransformerConfig):
     if cfg.pos == "learned":
         x = x + params["pos_embed"].astype(dt)[pos][:, None]
     cos, sin = _rope(cfg)
+    G = cfg.n_heads // cfg.kv_heads
 
     def block(carry, layer_in):
-        h, = carry
-        layer_p, kp, vp = layer_in               # pools [num_pages, P, Hkv, Dh]
+        h, kp, vp = carry                        # pools [L*num_pages, P, Hkv, Dh]
+        layer_p, base = layer_in                 # base: this layer's first page
         normed = _norm(h, layer_p["norm1"], cfg)
         q, k, v = _attn_qkv(normed, layer_p["attn"], cfg)      # [B, 1, H, Dh]
         if cfg.pos == "rope":
             q = ops.apply_rope(q, cos, sin, positions=pos[:, None])
             k = ops.apply_rope(k, cos, sin, positions=pos[:, None])
         # scatter this step's K/V at (page, offset) per row
-        kp = kp.at[page_ids, offsets].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[page_ids, offsets].set(v[:, 0].astype(vp.dtype))
-        # gather each row's pages → a contiguous [B, S] view for attention
-        k_cache = kp[state["block"]].reshape(B, S, cfg.kv_heads, cfg.head_dim)
-        v_cache = vp[state["block"]].reshape(B, S, cfg.kv_heads, cfg.head_dim)
-        G = cfg.n_heads // cfg.kv_heads
+        kp = kp.at[base + page_ids, offsets].set(k[:, 0].astype(kp.dtype))
+        vp = vp.at[base + page_ids, offsets].set(v[:, 0].astype(vp.dtype))
         qh = q[:, 0].reshape(B, cfg.kv_heads, G, cfg.head_dim)
-        scores = jnp.einsum("bkgd,bskd->bkgs", qh, k_cache.astype(dt)) / (cfg.head_dim ** 0.5)
-        mask = jnp.arange(S)[None, :] <= pos[:, None]
-        scores = jnp.where(mask[:, None, None, :], scores.astype(jnp.float32), -1e30)
-        w = jax.nn.softmax(scores, axis=-1).astype(dt)
-        out = jnp.einsum("bkgs,bskd->bkgd", w, v_cache.astype(dt))
-        out = out.reshape(B, 1, cfg.n_heads, cfg.head_dim)
+        out = attend(qh, kp, vp, base, pos)
+        out = out.reshape(B, 1, cfg.n_heads, cfg.head_dim).astype(dt)
         out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
             out = out + layer_p["attn"]["bo"].astype(dt)
         h = h + out
         h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
-        return (h,), (kp, vp)
+        return (h, kp, vp), None
 
-    (x,), (kp_new, vp_new) = jax.lax.scan(
-        block, (x,), (params["layers"], state["kp"], state["vp"]))
+    (x, kp, vp), _ = jax.lax.scan(
+        block, (x, state["kp"].reshape(flat), state["vp"].reshape(flat)),
+        (params["layers"], jnp.arange(L, dtype=jnp.int32) * num_pages))
     x = _norm(x, params["final_norm"], cfg)
     if cfg.tie_embeddings:
         logits = x[:, 0] @ params["embed"].astype(dt).T
     else:
         logits = x[:, 0] @ params["lm_head"].astype(dt)
     state = dict(state)
-    state["kp"], state["vp"] = kp_new, vp_new
+    state["kp"] = kp.reshape(state["kp"].shape)
+    state["vp"] = vp.reshape(state["vp"].shape)
     state["length"] = jnp.where(state["active"], state["length"] + 1, state["length"])
     return state, logits.astype(jnp.float32)
+
+
+@functools.partial(jax.jit, donate_argnames=("state",), static_argnames=("cfg",))
+def decode_step_paged(params, state, cfg: TransformerConfig):
+    """Advance every active row one token against its paged cache: every
+    row's whole block table is gathered and masked."""
+    dt = cfg.dtype
+    B, MP = state["block"].shape
+    S = MP * state["kp"].shape[2]
+
+    def attend(qh, kp, vp, base, pos):
+        # gather each row's pages → a contiguous [B, S] view for attention
+        tbl = base + state["block"]
+        k_cache = kp[tbl].reshape(B, S, cfg.kv_heads, cfg.head_dim)
+        v_cache = vp[tbl].reshape(B, S, cfg.kv_heads, cfg.head_dim)
+        scores = jnp.einsum("bkgd,bskd->bkgs", qh, k_cache.astype(dt)) / (cfg.head_dim ** 0.5)
+        mask = jnp.arange(S)[None, :] <= pos[:, None]
+        scores = jnp.where(mask[:, None, None, :], scores.astype(jnp.float32), -1e30)
+        w = jax.nn.softmax(scores, axis=-1).astype(dt)
+        return jnp.einsum("bkgs,bskd->bkgd", w, v_cache.astype(dt))
+
+    return _decode_step(params, state, cfg, attend)
 
 
 @functools.partial(jax.jit, donate_argnames=("state",),
@@ -147,57 +173,16 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
     """
     from ray_tpu.ops.ragged_paged_attention import ragged_decode_attention
 
-    dt = cfg.dtype
-    B, MP = state["block"].shape
-    P = state["kp"].shape[2]
-    tokens = state["last_token"][:, None]
-    pos = state["length"]                                      # [B]
-    page_ids = jnp.take_along_axis(state["block"],
-                                   (pos // P)[:, None], axis=1)[:, 0]  # [B]
-    page_ids = jnp.where(state["active"], page_ids, 0)
-    offsets = pos % P                                          # [B]
     # the ragged sweep only walks the batch's live prefix of each table;
     # positions past a row's `pos` inside that prefix are masked in-kernel
     tbl = state["block"][:, :pages_bound]
-    x = params["embed"].astype(dt)[tokens]
-    if cfg.pos == "learned":
-        x = x + params["pos_embed"].astype(dt)[pos][:, None]
-    cos, sin = _rope(cfg)
-    G = cfg.n_heads // cfg.kv_heads
 
-    def block(carry, layer_in):
-        h, = carry
-        layer_p, kp, vp = layer_in               # pools [num_pages, P, Hkv, Dh]
-        normed = _norm(h, layer_p["norm1"], cfg)
-        q, k, v = _attn_qkv(normed, layer_p["attn"], cfg)      # [B, 1, H, Dh]
-        if cfg.pos == "rope":
-            q = ops.apply_rope(q, cos, sin, positions=pos[:, None])
-            k = ops.apply_rope(k, cos, sin, positions=pos[:, None])
-        kp = kp.at[page_ids, offsets].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[page_ids, offsets].set(v[:, 0].astype(vp.dtype))
-        qh = q[:, 0].reshape(B, cfg.kv_heads, G, cfg.head_dim)
-        out = ragged_decode_attention(
-            qh, kp, vp, tbl, pos, scale=cfg.head_dim ** -0.5,
+    def attend(qh, kp, vp, base, pos):
+        return ragged_decode_attention(
+            qh, kp, vp, base + tbl, pos, scale=cfg.head_dim ** -0.5,
             impl="kernel" if kernel else "reference")
-        out = out.reshape(B, 1, cfg.n_heads, cfg.head_dim).astype(dt)
-        out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
-        if cfg.bias:
-            out = out + layer_p["attn"]["bo"].astype(dt)
-        h = h + out
-        h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
-        return (h,), (kp, vp)
 
-    (x,), (kp_new, vp_new) = jax.lax.scan(
-        block, (x,), (params["layers"], state["kp"], state["vp"]))
-    x = _norm(x, params["final_norm"], cfg)
-    if cfg.tie_embeddings:
-        logits = x[:, 0] @ params["embed"].astype(dt).T
-    else:
-        logits = x[:, 0] @ params["lm_head"].astype(dt)
-    state = dict(state)
-    state["kp"], state["vp"] = kp_new, vp_new
-    state["length"] = jnp.where(state["active"], state["length"] + 1, state["length"])
-    return state, logits.astype(jnp.float32)
+    return _decode_step(params, state, cfg, attend)
 
 
 @functools.partial(jax.jit, donate_argnames=("state",))

@@ -79,11 +79,11 @@ def test_reference_matches_dense_masked_softmax():
                                atol=1e-5, rtol=1e-5)
 
 
-def _mixed_state(cfg, params, *, lengths, P=PAGE, max_len=MAX_LEN):
+def _mixed_state(cfg, params, *, lengths, P=PAGE, max_len=MAX_LEN, spare=0):
     """A paged state with one active row per length (full reservation,
-    like the engine's default grant)."""
+    like the engine's default grant) and `spare` inactive slots after them."""
     MP = max_len // P
-    slots = len(lengths)
+    slots = len(lengths) + spare
     state = dp.init_paged_state(cfg, slots, max_len, slots * MP + 1, P)
     free = list(range(1, slots * MP + 1))
     for slot, n in enumerate(lengths):
@@ -130,6 +130,170 @@ def test_decode_step_ragged_matches_gather(tiny_model):
         assert np.array_equal(np.argmax(np.asarray(l_g), -1),
                               np.argmax(np.asarray(l_r), -1))
         state = s_g
+
+
+# ---- the in-place step (pools as the layer scan's carry) against the form
+# it replaced: pools scanned in per layer and stacked back out
+
+
+def _gather_attention(qh, kp, vp, tbl, pos, *, scale, dt):
+    """decode_step_paged's core: every row's whole table gathered, masked."""
+    B, Hkv, _, Dh = qh.shape
+    S = tbl.shape[1] * kp.shape[1]
+    k_cache = kp[tbl].reshape(B, S, Hkv, Dh)
+    v_cache = vp[tbl].reshape(B, S, Hkv, Dh)
+    scores = jnp.einsum("bkgd,bskd->bkgs", qh, k_cache.astype(dt)) / (Dh ** 0.5)
+    mask = jnp.arange(S)[None, :] <= pos[:, None]
+    scores = jnp.where(mask[:, None, None, :], scores.astype(jnp.float32), -1e30)
+    w = jax.nn.softmax(scores, axis=-1).astype(dt)
+    return jnp.einsum("bkgs,bskd->bkgd", w, v_cache.astype(dt))
+
+
+def _stacked_step(cfg, attend, pages_bound=None):
+    """The decode step as it stood before the pools rode the scan's carry,
+    kept here as the oracle: `state["kp"]`, `state["vp"]` are scanned INPUTS,
+    each layer scatters into its own [num_pages, P, Hkv, Dh] slice and the
+    slices are stacked back as OUTPUTS. `attend` is the attention core."""
+    from ray_tpu import ops
+    from ray_tpu.models.decoding import _attn_qkv, _mlp_block, _rope
+    from ray_tpu.models.transformer import _norm
+
+    @jax.jit
+    def step(params, state):
+        dt = cfg.dtype
+        B = state["block"].shape[0]
+        P = state["kp"].shape[2]
+        tokens = state["last_token"][:, None]
+        pos = state["length"]
+        page_ids = jnp.take_along_axis(state["block"], (pos // P)[:, None], axis=1)[:, 0]
+        page_ids = jnp.where(state["active"], page_ids, 0)
+        offsets = pos % P
+        tbl = state["block"][:, :pages_bound]
+        x = params["embed"].astype(dt)[tokens]
+        if cfg.pos == "learned":
+            x = x + params["pos_embed"].astype(dt)[pos][:, None]
+        cos, sin = _rope(cfg)
+        G = cfg.n_heads // cfg.kv_heads
+
+        def block(h, layer_in):
+            layer_p, kp, vp = layer_in
+            q, k, v = _attn_qkv(_norm(h, layer_p["norm1"], cfg), layer_p["attn"], cfg)
+            if cfg.pos == "rope":
+                q = ops.apply_rope(q, cos, sin, positions=pos[:, None])
+                k = ops.apply_rope(k, cos, sin, positions=pos[:, None])
+            kp = kp.at[page_ids, offsets].set(k[:, 0].astype(kp.dtype))
+            vp = vp.at[page_ids, offsets].set(v[:, 0].astype(vp.dtype))
+            qh = q[:, 0].reshape(B, cfg.kv_heads, G, cfg.head_dim)
+            out = attend(qh, kp, vp, tbl, pos, scale=cfg.head_dim ** -0.5, dt=dt)
+            out = out.reshape(B, 1, cfg.n_heads, cfg.head_dim).astype(dt)
+            out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
+            if cfg.bias:
+                out = out + layer_p["attn"]["bo"].astype(dt)
+            h = h + out
+            h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
+            return h, (kp, vp)
+
+        x, (kp, vp) = jax.lax.scan(block, x, (params["layers"], state["kp"], state["vp"]))
+        x = _norm(x, params["final_norm"], cfg)
+        head = params["embed"].astype(dt).T if cfg.tie_embeddings else params["lm_head"].astype(dt)
+        return {**state, "kp": kp, "vp": vp,
+                "length": jnp.where(state["active"], pos + 1, pos)}, \
+            (x[:, 0] @ head).astype(jnp.float32)
+
+    return step
+
+
+def _ragged_core(impl):
+    return lambda qh, kp, vp, tbl, pos, *, scale, dt: ragged_decode_attention(
+        qh, kp, vp, tbl, pos, scale=scale, impl=impl, interpret=True)
+
+
+@pytest.fixture
+def kernel_interpreted(monkeypatch):
+    """`decode_step_paged_ragged(kernel=True)` on the CPU: the same Pallas
+    kernel, interpreted."""
+    import ray_tpu.ops.ragged_paged_attention as rpa
+
+    real = rpa._ragged_kernel_call
+    monkeypatch.setattr(rpa, "_ragged_kernel_call",
+                        lambda *a, interpret, **kw: real(*a, interpret=True, **kw))
+
+
+# the step under test, and the attention core and page bound of its oracle
+STEPS = {
+    "gather": (lambda p, s, cfg: dp.decode_step_paged(p, s, cfg),
+               _gather_attention, None),
+    "ragged-reference": (lambda p, s, cfg: dp.decode_step_paged_ragged(p, s, cfg, 4, False),
+                         _ragged_core("reference"), 4),
+    "ragged-kernel": (lambda p, s, cfg: dp.decode_step_paged_ragged(p, s, cfg, 4, True),
+                      _ragged_core("kernel"), 4),
+}
+
+
+def _copy(state):
+    return {k: jnp.array(v) for k, v in state.items()}
+
+
+@pytest.mark.parametrize("name", list(STEPS))
+def test_in_place_step_is_bit_equal_to_the_stacked_scan(tiny_model, kernel_interpreted, name):
+    """Four steps over a mixed-length batch with one INACTIVE slot, an
+    admission (`write_kv_pages` + `activate_slot`) and a release between
+    them: logits and both pools equal the oracle's to every bit."""
+    cfg, params = tiny_model
+    step, core, bound = STEPS[name]
+    oracle = _stacked_step(cfg, core, bound)
+    MP = MAX_LEN // PAGE
+    state = _mixed_state(cfg, params, lengths=[3, 17, 27], spare=1)
+    want = _copy(state)
+
+    def admit(s):
+        """Slot 3 takes a 21-token prompt into the pool's last pages."""
+        padded = np.zeros((1, 2 * PAGE), np.int32)
+        padded[0, :21] = 5 + np.arange(21)
+        logits, kv = decoding.prefill(params, jnp.asarray(padded), jnp.int32(21), cfg)
+        row = np.arange(3 * MP + 1, 4 * MP + 1, dtype=np.int32)
+        s = dp.write_kv_pages(s, kv, jnp.asarray(row))
+        return dp.activate_slot(s, 3, jnp.asarray(row), jnp.int32(21),
+                                jnp.asarray(int(jnp.argmax(logits)), jnp.int32))
+
+    between = {1: admit, 2: lambda s: dp.release_slot_paged(s, 1)}
+    for i in range(4):
+        if i in between:
+            state, want = between[i](state), between[i](want)
+        state, logits = step(params, state, cfg)
+        want, logits_want = oracle(params, want)
+        assert np.array_equal(np.asarray(logits), np.asarray(logits_want)), (name, i)
+        for key in ("kp", "vp", "length"):
+            assert np.array_equal(np.asarray(state[key]), np.asarray(want[key])), (name, i, key)
+        assert state["kp"].shape == (cfg.n_layers, 4 * MP + 1, PAGE, cfg.kv_heads, cfg.head_dim)
+        toks = np.argmax(np.asarray(logits), -1).astype(np.int32)
+        state = {**state, "last_token": jnp.asarray(toks)}     # donated: one each
+        want = {**want, "last_token": jnp.asarray(toks)}
+
+
+def test_an_inactive_row_writes_only_its_layers_scratch_page(tiny_model):
+    """Every row inactive, with a stale table and length left behind: a step
+    changes page 0 of EACH layer (the reserved scratch page, at the layer's
+    own offset in the flat pool) and no other page."""
+    cfg, params = tiny_model
+    rng = np.random.default_rng(3)
+    state = dp.init_paged_state(cfg, 2, MAX_LEN, 9, PAGE)
+    shape = state["kp"].shape
+    state = {**state,
+             "kp": jnp.asarray(rng.standard_normal(shape), jnp.float32),
+             "vp": jnp.asarray(rng.standard_normal(shape), jnp.float32),
+             "block": jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32),
+             "length": jnp.asarray([5, 20], jnp.int32),
+             "last_token": jnp.asarray([7, 9], jnp.int32)}
+    before = {k: np.asarray(state[k]) for k in ("kp", "vp")}
+    for step in (lambda s: dp.decode_step_paged(params, s, cfg),
+                 lambda s: dp.decode_step_paged_ragged(params, s, cfg, 4, False)):
+        after, _ = step(_copy(state))
+        for key in ("kp", "vp"):
+            got = np.asarray(after[key])
+            assert np.array_equal(got[:, 1:], before[key][:, 1:])
+            assert all((got[l, 0] != before[key][l, 0]).any() for l in range(cfg.n_layers))
+        assert np.array_equal(np.asarray(after["length"]), [5, 20])
 
 
 def test_engine_ragged_token_exact_vs_gather(tiny_model):

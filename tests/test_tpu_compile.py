@@ -90,25 +90,57 @@ def test_ragged_decode_kernel_compiles(chip, shape):
     assert "tpu_custom_call" in text
 
 
-def test_paged_decode_step_with_kernel_compiles(chip):
-    """The engine's decode program at Llama-1B widths, depth cut to two
-    layers (the layer scan compiles one body whatever the depth)."""
-    from ray_tpu.models import decoding_paged, llama_config, transformer
-
-    cfg = llama_config("1b", max_seq_len=2048, n_layers=2)
-    slots, max_len, page = 16, 2048, 64
+def _abstract_step_inputs(chip, cfg, slots, max_len, num_pages, page):
+    """(weights, paged decode state) of `cfg` as shapes on the described chip."""
+    from ray_tpu.models import decoding_paged, transformer
 
     def on_chip(tree):
         return jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
 
-    params = on_chip(jax.eval_shape(
-        lambda: transformer.init(jax.random.PRNGKey(0), cfg)))
-    state = on_chip(jax.eval_shape(lambda: decoding_paged.init_paged_state(
-        cfg, slots, max_len, slots * (max_len // page) + 1, page)))
+    return (on_chip(jax.eval_shape(lambda: transformer.init(jax.random.PRNGKey(0), cfg))),
+            on_chip(jax.eval_shape(lambda: decoding_paged.init_paged_state(
+                cfg, slots, max_len, num_pages, page))))
+
+
+def test_paged_decode_step_with_kernel_compiles(chip):
+    """The engine's decode program at Llama-1B widths, depth cut to two
+    layers (the layer scan compiles one body whatever the depth)."""
+    from ray_tpu.models import decoding_paged, llama_config
+
+    cfg = llama_config("1b", max_seq_len=2048, n_layers=2)
+    slots, max_len, page = 16, 2048, 64
+    params, state = _abstract_step_inputs(
+        chip, cfg, slots, max_len, slots * (max_len // page) + 1, page)
     text = decoding_paged.decode_step_paged_ragged.lower(
         params, state, cfg, 8, True).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+# (step, max_len): the gather step's own [slots, max_len] view of every row's
+# pages is a temporary whatever the pool does (0.55 GB each for K and V at the
+# cell's 8320), so it is compiled at a max_len that keeps the view small
+@pytest.mark.parametrize("step,max_len", [("ragged", 8320), ("gather", 256)])
+def test_paged_decode_step_holds_the_pool_once(chip, step, max_len):
+    """The decode program at Mixtral widths as `mixtral-8x7b.chat-steady`
+    runs it (4 layers, 1,024 pages of 64, 32 slots): the page pools are
+    updated in place. With the pools among the layer scan's inputs and
+    outputs the compiler held a second pool and half a pool of slices as
+    temporaries (1,627,396,096 bytes; as the scan's carry: 9,415,168)."""
+    from ray_tpu.models import decoding_paged, mixtral_config
+    from ray_tpu.models.transformer import MoEConfig
+
+    cfg = mixtral_config("8x7b", n_layers=4, param_dtype=jnp.bfloat16, max_seq_len=32768,
+                         moe=MoEConfig(num_experts=8, top_k=2, capacity_factor=4.0))
+    params, state = _abstract_step_inputs(chip, cfg, 32, max_len, 1024, 64)
+    if step == "ragged":
+        lowered = decoding_paged.decode_step_paged_ragged.lower(params, state, cfg, 32, True)
+    else:
+        lowered = decoding_paged.decode_step_paged.lower(params, state, cfg)
+    m = lowered.compile().memory_analysis()
+    pools = 2 * 4 * 1024 * 64 * cfg.kv_heads * cfg.head_dim * 2
+    assert m.temp_size_in_bytes < 64 * 2**20
+    assert m.alias_size_in_bytes >= pools
 
 
 def test_kernel_names_reach_the_compiled_program(chip):
