@@ -109,7 +109,8 @@ class LLMConfig:
         from ray_tpu import models
 
         factory = {"llama": models.llama_config, "gpt2": models.gpt2_config,
-                   "mixtral": models.mixtral_config}[self.model_family]
+                   "mixtral": models.mixtral_config,
+                   "kimi_vl": models.kimi_vl_config}[self.model_family]
         cfg = factory(self.model_loading_config.model_id, **self.model_kwargs)
         src = self.model_loading_config.model_source
         if src:

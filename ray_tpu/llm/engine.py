@@ -287,6 +287,22 @@ class TPUEngine:
         if kv_layout not in ("slot", "paged"):
             raise ValueError(f"kv_layout must be 'slot' or 'paged', got {kv_layout!r}")
         self.kv_layout = kv_layout
+        if cfg.mla or cfg.n_dense_layers:
+            # what is not carried to the latent cache and to two kinds of
+            # layer in one stack: refused here, not at the first request
+            kind = ("latent attention (kv_lora_rank)" if cfg.mla
+                    else "leading dense layers")
+            for on, what in ((kv_layout != "paged", "kv_layout='slot'"),
+                             (mesh is not None, "a tensor-parallel mesh"),
+                             (speculative_k, "speculative_k"),
+                             (max_loras, "max_loras")):
+                if on:
+                    raise ValueError(
+                        f"a model with {kind} is served from the paged "
+                        f"layout on one chip, without {what}: the slot "
+                        "cache, the sharding of the page pool over kv "
+                        "heads, the verify step and the LoRA bank are built "
+                        "for per-head K and V over one kind of layer")
         if kv_layout == "paged":
             if page_size <= 0 or (page_size & (page_size - 1)):
                 raise ValueError("page_size must be a positive power of two")
@@ -435,6 +451,14 @@ class TPUEngine:
             self._lora_lock = threading.Lock()
         self.decode_steps = 0
         self.decode_slot_steps = 0  # sum of active slots over decode steps
+        # the cache on the record, cumulative (stats()["cache"]): positions
+        # the decode steps attended over, prefix tokens gathered out of the
+        # pool for continuation prefills, pages held and pages in the pool
+        # summed over decode steps
+        self.context_tokens = 0
+        self.prefix_tokens_gathered = 0
+        self.page_steps_used = 0
+        self.page_steps_total = 0
         self.spec_steps = 0
         self.spec_slot_steps = 0   # sum of active slots over verify steps
         self.spec_drafted = 0
@@ -721,6 +745,12 @@ class TPUEngine:
           error; the slot and its granted pages are reclaimed.
         """
         self._check_alive()
+        if self.cfg.mla:
+            raise NotImplementedError(
+                "submit_prefilled: the PD transfer plane (llm/pd.py, "
+                "kv_transfer.py) moves per-head K and V pages; a model with "
+                "latent attention caches one row a token and is not carried "
+                "over it")
         params = params or SamplingParams()
         paged_form = k_pages is not None or v_pages is not None
         if kv_stream is not None:
@@ -1445,7 +1475,8 @@ class TPUEngine:
             padded_ids = np.zeros((npad,), np.int32)
             padded_ids[:n_pre] = pre_pages
             k_pre, v_pre = self._dp.gather_prefix_pages(
-                self.state["kp"], self.state["vp"], jnp.asarray(padded_ids))
+                self.state["kp"], self.state.get("vp"), jnp.asarray(padded_ids))
+            self.prefix_tokens_gathered += pre_len
             logits, kv = self._dp.prefill_with_prefix(
                 self.params, jnp.asarray(padded), k_pre, v_pre,
                 jnp.int32(pre_len), jnp.int32(len(suffix)), self.cfg)
@@ -1496,7 +1527,8 @@ class TPUEngine:
             padded_ids = np.zeros((npad,), np.int32)
             padded_ids[:done // P] = req.pf_pages[:done // P]
             k_pre, v_pre = self._dp.gather_prefix_pages(
-                self.state["kp"], self.state["vp"], jnp.asarray(padded_ids))
+                self.state["kp"], self.state.get("vp"), jnp.asarray(padded_ids))
+            self.prefix_tokens_gathered += done
             logits, kv = self._dp.prefill_with_prefix(
                 self.params, jnp.asarray(padded), k_pre, v_pre,
                 jnp.int32(done), jnp.int32(len(chunk_toks)), self.cfg)
@@ -1850,6 +1882,13 @@ class TPUEngine:
             t_emit = mark("emit")
             self.decode_steps += 1
             self.decode_slot_steps += len(self._by_slot)
+            self.context_tokens += sum(
+                r.length0 + max(0, r.generated - 1) + 1
+                for r in self._by_slot.values())
+            if self.kv_layout == "paged":
+                self.page_steps_used += (self.num_pages - 1
+                                         - self._available_pages())
+                self.page_steps_total += self.num_pages - 1
             if self._step_obs is not None:
                 # the step's dispatches + the fetch of its tokens: the
                 # ragged-vs-gather attribution surface (LLM_BENCH
@@ -1890,6 +1929,16 @@ class TPUEngine:
                "decode_occupancy": (self.decode_slot_steps
                                     / self.decode_steps
                                     if self.decode_steps else 0.0)}
+        pools = [self.state[k] for k in ("k", "v", "kp", "vp")
+                 if k in self.state]   # [L, slots | pages, tokens, ...]
+        out["cache"] = {
+            # as stored, all layers: a latent row, or K and V of every head
+            "bytes_per_token": sum(
+                x.nbytes // (x.shape[1] * x.shape[2]) for x in pools),
+            "context_tokens": self.context_tokens,
+            "prefix_tokens_gathered": self.prefix_tokens_gathered,
+            "page_steps_used": self.page_steps_used,
+            "page_steps_total": self.page_steps_total}
         if self.speculative_k:
             drafted = self.spec_drafted
             out["speculative"] = {

@@ -225,6 +225,11 @@ class PrefillServer:
         from ray_tpu.models import decoding
 
         self.cfg, self.params = llm_config.build_model()
+        if self.cfg.mla or self.cfg.n_dense_layers:
+            raise NotImplementedError(
+                "PD disaggregation moves per-head K and V pages of one kind "
+                "of layer (prefill_batch, kv_transfer); a model with latent "
+                "attention or leading dense layers is served by one engine")
         self._decoding = decoding
         self._jax = jax
         ek = _pd_engine_kwargs(llm_config)

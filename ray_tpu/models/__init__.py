@@ -1,5 +1,6 @@
 from ray_tpu.models import transformer, vit
 from ray_tpu.models.gpt2 import gpt2_config
+from ray_tpu.models.kimi_vl import kimi_vl_config
 from ray_tpu.models.llama import llama_config
 from ray_tpu.models.mixtral import mixtral_config
 from ray_tpu.models.transformer import MoEConfig, TransformerConfig
@@ -10,6 +11,7 @@ __all__ = [
     "TransformerConfig",
     "ViTConfig",
     "gpt2_config",
+    "kimi_vl_config",
     "llama_config",
     "mixtral_config",
     "transformer",

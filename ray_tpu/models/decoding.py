@@ -26,11 +26,22 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu import ops
-from ray_tpu.models.transformer import TransformerConfig, _dense_mlp, _moe_mlp, _norm
+from ray_tpu.models.transformer import (TransformerConfig, _dense_mlp, _mla_expand,
+                                        _mla_project, _moe_mlp, _norm, scan_layers)
+
+
+def _per_head_kv_only(cfg: TransformerConfig, what: str) -> None:
+    """The paths not carried to the latent cache or to two kinds of layer."""
+    if cfg.mla or cfg.n_dense_layers:
+        raise NotImplementedError(
+            f"{what} caches per-head K and V over one kind of layer; a model "
+            "with latent attention (kv_lora_rank) or leading dense layers is "
+            "served from the paged layout (models/decoding_paged.py)")
 
 
 def init_decode_state(cfg: TransformerConfig, max_slots: int, max_len: int) -> dict:
     """Allocate the global decode state: per-layer KV + per-row bookkeeping."""
+    _per_head_kv_only(cfg, "the slot layout")
     L, Hkv, Dh = cfg.n_layers, cfg.kv_heads, cfg.head_dim
     return {
         "k": jnp.zeros((L, max_slots, max_len, Hkv, Dh), cfg.dtype),
@@ -43,7 +54,7 @@ def init_decode_state(cfg: TransformerConfig, max_slots: int, max_len: int) -> d
 
 def _rope(cfg):
     if cfg.pos == "rope":
-        return ops.rope_frequencies(cfg.head_dim, cfg.max_seq_len, theta=cfg.rope_theta)
+        return ops.rope_frequencies(cfg.rope_dim, cfg.max_seq_len, theta=cfg.rope_theta)
     return None, None
 
 
@@ -59,6 +70,7 @@ def init_lora_bank(cfg: TransformerConfig, num_adapters: int,
     index 0 computes base + 0, bit-identical to the base model. Banks are
     LAYER-major ([L, N+1, ...]) so lax.scan consumes them directly.
     Targets q and v projections (the standard LoRA target set)."""
+    _per_head_kv_only(cfg, "the LoRA bank")
     L, E = cfg.n_layers, cfg.d_model
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     N = num_adapters + 1
@@ -105,10 +117,31 @@ def _attn_qkv(x, p, cfg, lora_l=None, lora_idx=None, lora_scale=None):
 
 
 def _mlp_block(normed, layer_p, cfg):
-    if cfg.moe:
+    if "router" in layer_p["mlp"]:
         delta, _aux = _moe_mlp(normed, layer_p["mlp"], cfg)
         return delta
     return _dense_mlp(normed, layer_p["mlp"], cfg)
+
+
+def _mla_prefill_attn(normed, attn_p, cfg, cos, sin, positions=None,
+                      prefix=None, mask=None):
+    """Latent attention in expanded form over normed [1, T, E], after an
+    optional cached `prefix` [Tp, latent_lanes] (then `mask` [T, Tp + T]
+    says what each query sees; without one: causal). Returns (the block's
+    output before the residual [1, T, E], the rows to cache [T, lanes])."""
+    dt = cfg.dtype
+    q, latent = _mla_project(normed, attn_p, cfg, cos, sin, positions)
+    rows = latent if prefix is None else jnp.concatenate(
+        [prefix[None].astype(dt), latent], axis=1)
+    k, v = _mla_expand(rows, attn_p, cfg)
+    if mask is None:
+        out = ops.attention(q, k, v, causal=True, scale=cfg.qk_dim ** -0.5,
+                            impl="reference")
+    else:
+        scores = jnp.einsum("bthd,bshd->bhts", q, k) / (cfg.qk_dim ** 0.5)
+        scores = jnp.where(mask[None, None], scores.astype(jnp.float32), -1e30)
+        out = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(scores, axis=-1).astype(dt), v)
+    return jnp.einsum("bthd,hde->bte", out, attn_p["wo"].astype(dt)), latent[0]
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -116,7 +149,8 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
             lora_bank=None, lora_idx=None):
     """Run one prompt [1, T] (T = bucket size, padded; true length `length`).
 
-    Returns (logits_at_last [V], kv {k,v: [L, T, Hkv, Dh]}).
+    Returns (logits_at_last [V], kv {k,v: [L, T, Hkv, Dh]}; with latent
+    attention kv is {k: [L, T, latent_lanes]}, the rows the cache holds).
     With `lora_bank` + scalar `lora_idx`, applies that adapter's q/v
     deltas (init_lora_bank; idx 0 = null adapter = exact base model).
     """
@@ -135,6 +169,11 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
             layer_p, aq, bq, av, bv = layer_in
             lora_l = (aq, bq, av, bv)
         normed = _norm(h, layer_p["norm1"], cfg)
+        if cfg.mla:
+            out, rows = _mla_prefill_attn(normed, layer_p["attn"], cfg, cos, sin)
+            h = h + out
+            h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
+            return h, (rows,)
         q, k, v = _attn_qkv(normed, layer_p["attn"], cfg, lora_l, lora_idx,
                             lscale)
         if cfg.pos == "rope":
@@ -148,17 +187,19 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
         h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
         return h, (k[0], v[0])
 
-    xs = (params["layers"] if lora_bank is None
-          else (params["layers"], lora_bank["A_q"], lora_bank["B_q"],
-                lora_bank["A_v"], lora_bank["B_v"]))
-    x, kv = jax.lax.scan(block, x, xs)
+    if lora_bank is None:
+        x, kv = scan_layers(block, x, params, cfg)
+    else:
+        x, kv = jax.lax.scan(block, x, (
+            params["layers"], lora_bank["A_q"], lora_bank["B_q"],
+            lora_bank["A_v"], lora_bank["B_v"]))
     x = _norm(x, params["final_norm"], cfg)
     last = x[0, length - 1]
     if cfg.tie_embeddings:
         logits = last @ params["embed"].astype(dt).T
     else:
         logits = last @ params["lm_head"].astype(dt)
-    return logits.astype(jnp.float32), {"k": kv[0], "v": kv[1]}
+    return logits.astype(jnp.float32), dict(zip("kv", kv))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -174,6 +215,7 @@ def prefill_batch(params, tokens, lengths, cfg: TransformerConfig):
     steps (llm/pd.py PrefillCoalescer). Causality keeps rows independent:
     positions past a row's length only produce KV that the consumer
     masks by length, exactly as in the single-prompt path."""
+    _per_head_kv_only(cfg, "prefill_batch (the PD prefill tier)")
     dt = cfg.dtype
     B, T = tokens.shape
     x = params["embed"].astype(dt)[tokens]

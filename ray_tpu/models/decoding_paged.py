@@ -18,6 +18,12 @@ scatter each layer's rows into the carry in place, because pools handed to
 the scan as inputs and stacked as its outputs are sliced, copied and
 rewritten whole every step.
 
+A model with latent attention (cfg.kv_lora_rank, MLA) has ONE pool, `kp`
+[L, num_pages, page, latent_lanes], and no `vp`: a token's row is (c |
+k_rope | padding to whole lanes), nothing per head. Prefill attends in
+expanded form (per-head K and V from the rows), the decode steps in absorbed
+form over the rows as they lie in the pool: the same mathematics.
+
 (reference capability: vLLM paged attention behind
 llm/_internal/serve/engines/vllm/vllm_engine.py:114; design here is
 TPU-native — dense static gathers, no custom CUDA.)
@@ -30,8 +36,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.decoding import _attn_qkv, _mlp_block, _rope
-from ray_tpu.models.transformer import TransformerConfig, _norm
+from ray_tpu.models.decoding import _attn_qkv, _mla_prefill_attn, _mlp_block, _rope
+from ray_tpu.models.transformer import (TransformerConfig, _mla_absorb_out, _mla_absorb_q,
+                                        _mla_project, _norm, scan_layers)
 from ray_tpu import ops
 
 
@@ -41,9 +48,13 @@ def init_paged_state(cfg: TransformerConfig, max_slots: int, max_len: int,
     capacity shared by all slots (oversubscribable vs max_slots*max_len)."""
     L, Hkv, Dh = cfg.n_layers, cfg.kv_heads, cfg.head_dim
     max_pages_per_seq = (max_len + page_size - 1) // page_size
+    if cfg.mla:
+        pools = {"kp": jnp.zeros((L, num_pages, page_size, cfg.latent_lanes), cfg.dtype)}
+    else:
+        pools = {"kp": jnp.zeros((L, num_pages, page_size, Hkv, Dh), cfg.dtype),
+                 "vp": jnp.zeros((L, num_pages, page_size, Hkv, Dh), cfg.dtype)}
     return {
-        "kp": jnp.zeros((L, num_pages, page_size, Hkv, Dh), cfg.dtype),
-        "vp": jnp.zeros((L, num_pages, page_size, Hkv, Dh), cfg.dtype),
+        **pools,
         # page ids per slot; unused entries point at page 0 (masked anyway)
         "block": jnp.zeros((max_slots, max_pages_per_seq), jnp.int32),
         "length": jnp.zeros((max_slots,), jnp.int32),
@@ -59,14 +70,7 @@ def insert_sequence_paged(state, slot, kv, length, first_token, pages,
     this slot's `pages` (int32 [max_pages_per_seq], padded with 0 — the
     engine grants ALL pages the sequence will ever need up front, so no
     mid-flight allocation) and activate the row."""
-    P = state["kp"].shape[2]
-    L, T = kv["k"].shape[0], kv["k"].shape[1]
-    n = T // P  # static: T is the prompt bucket
-    k_pages = kv["k"].reshape(L, n, P, kv["k"].shape[2], kv["k"].shape[3])
-    v_pages = kv["v"].reshape(L, n, P, kv["v"].shape[2], kv["v"].shape[3])
-    state = dict(state)
-    state["kp"] = state["kp"].at[:, pages[:n]].set(k_pages.astype(state["kp"].dtype))
-    state["vp"] = state["vp"].at[:, pages[:n]].set(v_pages.astype(state["vp"].dtype))
+    state = _write_pages(state, kv, pages)
     state["block"] = jax.lax.dynamic_update_slice_in_dim(
         state["block"], pages[None], slot, axis=0)
     state["length"] = state["length"].at[slot].set(length)
@@ -75,16 +79,33 @@ def insert_sequence_paged(state, slot, kv, length, first_token, pages,
     return state
 
 
+def _write_pages(state, kv, pages) -> dict:
+    """A copy of `state` with a bucketed kv ({k, v: [L, T, Hkv, Dh]}, or the
+    latent {k: [L, T, lanes]}) written into the first T/page_size of `pages`."""
+    P = state["kp"].shape[2]
+    state = dict(state)
+    for name, rows in kv.items():
+        pool = state[name + "p"]
+        n = rows.shape[1] // P  # static: T is a bucket
+        state[name + "p"] = pool.at[:, pages[:n]].set(
+            rows.reshape(rows.shape[0], n, P, *rows.shape[2:]).astype(pool.dtype))
+    return state
+
+
 def _decode_step(params, state, cfg: TransformerConfig, attend):
     """One token for every active row; the body both decode steps share.
     `attend(qh, kp, vp, base, pos)` is the attention core: qh [B, Hkv, G, Dh]
     against the FLAT pools [L*num_pages, P, Hkv, Dh] the scan carries, in
     which this layer's page `i` lies at `base + i`. With `state` donated the
-    pools alias input to output and a step writes B rows a layer."""
+    pools alias input to output and a step writes B rows a layer. With
+    latent attention qh is [B, 1, H, lanes] (the absorbed query), kp the one
+    pool [L*num_pages, P, lanes], vp None, and the result's first
+    kv_lora_rank columns are sum p c."""
     dt = cfg.dtype
     B = state["block"].shape[0]
     L, num_pages, P = state["kp"].shape[:3]
     flat = (L * num_pages,) + state["kp"].shape[2:]
+    vp0 = None if cfg.mla else state["vp"].reshape(flat)
     tokens = state["last_token"][:, None]
     pos = state["length"]                                      # [B]
     page_ids = jnp.take_along_axis(state["block"],
@@ -103,6 +124,15 @@ def _decode_step(params, state, cfg: TransformerConfig, attend):
         h, kp, vp = carry                        # pools [L*num_pages, P, Hkv, Dh]
         layer_p, base = layer_in                 # base: this layer's first page
         normed = _norm(h, layer_p["norm1"], cfg)
+        if cfg.mla:
+            ap = layer_p["attn"]
+            q, row = _mla_project(normed, ap, cfg, cos, sin, pos[:, None])
+            kp = kp.at[base + page_ids, offsets].set(row[:, 0].astype(kp.dtype))
+            o_lat = attend(_mla_absorb_q(q[:, 0], ap, cfg)[:, None], kp, None, base, pos)
+            out = _mla_absorb_out(o_lat[:, 0].astype(dt), ap, cfg)
+            h = h + jnp.einsum("bhd,hde->be", out, ap["wo"].astype(dt))[:, None]
+            h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
+            return (h, kp, vp), None
         q, k, v = _attn_qkv(normed, layer_p["attn"], cfg)      # [B, 1, H, Dh]
         if cfg.pos == "rope":
             q = ops.apply_rope(q, cos, sin, positions=pos[:, None])
@@ -120,9 +150,9 @@ def _decode_step(params, state, cfg: TransformerConfig, attend):
         h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
         return (h, kp, vp), None
 
-    (x, kp, vp), _ = jax.lax.scan(
-        block, (x, state["kp"].reshape(flat), state["vp"].reshape(flat)),
-        (params["layers"], jnp.arange(L, dtype=jnp.int32) * num_pages))
+    (x, kp, vp), _ = scan_layers(
+        block, (x, state["kp"].reshape(flat), vp0), params, cfg,
+        jnp.arange(L, dtype=jnp.int32) * num_pages)
     x = _norm(x, params["final_norm"], cfg)
     if cfg.tie_embeddings:
         logits = x[:, 0] @ params["embed"].astype(dt).T
@@ -130,7 +160,8 @@ def _decode_step(params, state, cfg: TransformerConfig, attend):
         logits = x[:, 0] @ params["lm_head"].astype(dt)
     state = dict(state)
     state["kp"] = kp.reshape(state["kp"].shape)
-    state["vp"] = vp.reshape(state["vp"].shape)
+    if vp is not None:
+        state["vp"] = vp.reshape(state["vp"].shape)
     state["length"] = jnp.where(state["active"], state["length"] + 1, state["length"])
     return state, logits.astype(jnp.float32)
 
@@ -146,9 +177,12 @@ def decode_step_paged(params, state, cfg: TransformerConfig):
     def attend(qh, kp, vp, base, pos):
         # gather each row's pages → a contiguous [B, S] view for attention
         tbl = base + state["block"]
-        k_cache = kp[tbl].reshape(B, S, cfg.kv_heads, cfg.head_dim)
-        v_cache = vp[tbl].reshape(B, S, cfg.kv_heads, cfg.head_dim)
-        scores = jnp.einsum("bkgd,bskd->bkgs", qh, k_cache.astype(dt)) / (cfg.head_dim ** 0.5)
+        if vp is None:  # latent rows: one shared head, its own value
+            k_cache = v_cache = kp[tbl].reshape(B, S, 1, -1)
+        else:
+            k_cache = kp[tbl].reshape(B, S, cfg.kv_heads, cfg.head_dim)
+            v_cache = vp[tbl].reshape(B, S, cfg.kv_heads, cfg.head_dim)
+        scores = jnp.einsum("bkgd,bskd->bkgs", qh, k_cache.astype(dt)) / (cfg.qk_dim ** 0.5)
         mask = jnp.arange(S)[None, :] <= pos[:, None]
         scores = jnp.where(mask[:, None, None, :], scores.astype(jnp.float32), -1e30)
         w = jax.nn.softmax(scores, axis=-1).astype(dt)
@@ -179,7 +213,7 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
 
     def attend(qh, kp, vp, base, pos):
         return ragged_decode_attention(
-            qh, kp, vp, base + tbl, pos, scale=cfg.head_dim ** -0.5,
+            qh, kp, vp, base + tbl, pos, scale=cfg.qk_dim ** -0.5,
             impl="kernel" if kernel else "reference")
 
     return _decode_step(params, state, cfg, attend)
@@ -204,11 +238,12 @@ def release_slot_paged(state, slot):
 def gather_prefix_pages(kp, vp, page_ids):
     """Collect cached prefix KV out of the page pool: page_ids [n] →
     k, v [L, n*P, Hkv, Dh] (n static via the id vector's shape; unused
-    tail ids point at scratch page 0 and are masked by prefix_len)."""
-    L, _, P, Hkv, Dh = kp.shape
+    tail ids point at scratch page 0 and are masked by prefix_len). A latent
+    pool has no `vp` (None): k is the rows [L, n*P, lanes], v None."""
+    L, _, P = kp.shape[:3]
     n = page_ids.shape[0]
-    k = kp[:, page_ids].reshape(L, n * P, Hkv, Dh)
-    v = vp[:, page_ids].reshape(L, n * P, Hkv, Dh)
+    k = kp[:, page_ids].reshape(L, n * P, *kp.shape[3:])
+    v = None if vp is None else vp[:, page_ids].reshape(L, n * P, *vp.shape[3:])
     return k, v
 
 
@@ -223,6 +258,8 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
     Returns (logits at the last suffix token [V],
              suffix kv {k, v: [L, Ts, Hkv, Dh]}).
     Compilation count is bounded by #prefix_buckets × #suffix_buckets.
+    With latent attention `prefix_k` is the cached rows [L, Tp, lanes],
+    `prefix_v` None: every layer expands them to per-head K and V.
     """
     dt = cfg.dtype
     B, Ts = tokens.shape
@@ -243,6 +280,12 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
     def block(h, layer_in):
         layer_p, pk, pv = layer_in                    # [Tp, Hkv, Dh] each
         normed = _norm(h, layer_p["norm1"], cfg)
+        if cfg.mla:
+            out, rows = _mla_prefill_attn(normed, layer_p["attn"], cfg, cos, sin,
+                                          pos_suffix, prefix=pk, mask=mask)
+            h = h + out
+            h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
+            return h, (rows,)
         q, k, v = _attn_qkv(normed, layer_p["attn"], cfg)  # [1, Ts, H, Dh]
         if cfg.pos == "rope":
             q = ops.apply_rope(q, cos, sin, positions=pos_suffix)
@@ -265,14 +308,14 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
         h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
         return h, (k[0], v[0])
 
-    x, kv = jax.lax.scan(block, x, (params["layers"], prefix_k, prefix_v))
+    x, kv = scan_layers(block, x, params, cfg, prefix_k, prefix_v)
     x = _norm(x, params["final_norm"], cfg)
     last = x[0, length - 1]
     if cfg.tie_embeddings:
         logits = last @ params["embed"].astype(dt).T
     else:
         logits = last @ params["lm_head"].astype(dt)
-    return logits.astype(jnp.float32), {"k": kv[0], "v": kv[1]}
+    return logits.astype(jnp.float32), dict(zip("kv", kv))
 
 
 @functools.partial(jax.jit, donate_argnames=("state",))
@@ -281,16 +324,7 @@ def write_kv_pages(state, kv, pages):
     WITHOUT touching the row bookkeeping — the chunked-prefill building
     block: chunks accumulate into the pool page by page, and the row only
     activates once the whole prompt is resident (activate_slot)."""
-    P = state["kp"].shape[2]
-    L, T = kv["k"].shape[0], kv["k"].shape[1]
-    n = T // P
-    Hkv, Dh = kv["k"].shape[2], kv["k"].shape[3]
-    state = dict(state)
-    state["kp"] = state["kp"].at[:, pages[:n]].set(
-        kv["k"].reshape(L, n, P, Hkv, Dh).astype(state["kp"].dtype))
-    state["vp"] = state["vp"].at[:, pages[:n]].set(
-        kv["v"].reshape(L, n, P, Hkv, Dh).astype(state["vp"].dtype))
-    return state
+    return _write_pages(state, kv, pages)
 
 
 @functools.partial(jax.jit, donate_argnames=("state",))
@@ -314,17 +348,7 @@ def insert_sequence_paged_prefix(state, slot, kv, suffix_pages, block_row,
     the pages receiving the suffix bucket, `block_row`
     [max_pages_per_seq] is the full table (shared prefix ids + private
     ids + 0-padding)."""
-    P = state["kp"].shape[2]
-    L, T = kv["k"].shape[0], kv["k"].shape[1]
-    n = T // P  # static: T is the suffix bucket
-    Hkv, Dh = kv["k"].shape[2], kv["k"].shape[3]
-    k_pages = kv["k"].reshape(L, n, P, Hkv, Dh)
-    v_pages = kv["v"].reshape(L, n, P, Hkv, Dh)
-    state = dict(state)
-    state["kp"] = state["kp"].at[:, suffix_pages[:n]].set(
-        k_pages.astype(state["kp"].dtype))
-    state["vp"] = state["vp"].at[:, suffix_pages[:n]].set(
-        v_pages.astype(state["vp"].dtype))
+    state = _write_pages(state, kv, suffix_pages)
     state["block"] = jax.lax.dynamic_update_slice_in_dim(
         state["block"], block_row[None], slot, axis=0)
     state["length"] = state["length"].at[slot].set(length)

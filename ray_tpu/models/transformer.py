@@ -1,5 +1,6 @@
-"""Decoder-only transformer core shared by the GPT-2 / Llama / Mixtral
-families. Pure-functional: params are pytrees (layers stacked on a leading
+"""Decoder-only transformer core shared by the GPT-2 / Llama / Mixtral /
+Kimi-VL (DeepSeek-V3-style) families.
+Pure-functional: params are pytrees (layers stacked on a leading
 dim and consumed by lax.scan — compile-fast and pipeline-ready), logical axis
 trees drive mesh sharding, compute runs in bf16 with f32 accumulators.
 
@@ -24,8 +25,20 @@ from ray_tpu import ops
 class MoEConfig:
     num_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
+    # capacity of the one-hot dispatch, C = ceil(k*N/E * capacity_factor),
+    # tokens over it dropped. None, or E/k and more (C >= N: nothing could be
+    # dropped), is dropless: the sorted grouped dispatch (ops.moe_sorted)
+    capacity_factor: float | None = 1.25
     aux_coef: float = 0.01
+    score_func: str = "softmax"            # "softmax" | "sigmoid" (+ select bias)
+    routed_scaling_factor: float = 1.0     # sigmoid routing: times the k weights
+    n_shared_experts: int = 0              # one MLP of n * d_ff, every token
+    select_bias_init_std: float = 0.0      # sigmoid routing: published init is 0
+
+    @property
+    def dropless(self) -> bool:
+        return (self.capacity_factor is None
+                or self.capacity_factor >= self.num_experts / self.top_k)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,6 +63,19 @@ class TransformerConfig:
                                            # | "pairs" (checkpoint every other layer)
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    norm_eps: float = 1e-6                 # RMSNorm epsilon of the block norms
+    # leading layers with a dense MLP of width d_ff_dense before the others
+    # (stacked apart, under params["dense_layers"])
+    n_dense_layers: int = 0
+    d_ff_dense: int | None = None
+    # multi-head latent attention (DeepSeek-V2/V3): set kv_lora_rank and the
+    # cache is ONE row of kv_lora_rank + qk_rope_head_dim values a token a
+    # layer, nothing per head; q/k heads are nope + rope wide, v heads v wide
+    kv_lora_rank: int | None = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    kv_norm_eps: float = 1e-6
 
     @property
     def kv_heads(self) -> int:
@@ -58,6 +84,25 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank is not None
+
+    @property
+    def qk_dim(self) -> int:
+        """Width the attention scores are scaled by."""
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim if self.mla
+                else self.head_dim)
+
+    @property
+    def rope_dim(self) -> int:
+        return self.qk_rope_head_dim if self.mla else self.head_dim
+
+    @property
+    def latent_lanes(self) -> int:
+        """Columns of a cached MLA row: (c | k_rope) padded to whole lanes."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
     def num_params(self) -> int:
         leaves = jax.tree.leaves(jax.eval_shape(lambda: init(jax.random.PRNGKey(0), self)))
@@ -73,8 +118,8 @@ def _norm_params(cfg, key):
     return p
 
 
-def _dense_mlp_params(cfg, key):
-    E, F = cfg.d_model, cfg.d_ff
+def _dense_mlp_params(cfg, key, d_ff=None):
+    E, F = cfg.d_model, d_ff or cfg.d_ff
     k1, k2, k3 = jax.random.split(key, 3)
     std = 0.02
     out_std = 0.02 / math.sqrt(2 * cfg.n_layers)
@@ -100,46 +145,134 @@ def _moe_params(cfg, key):
     k0, k1, k2, k3 = jax.random.split(key, 4)
     std = 0.02
     out_std = 0.02 / math.sqrt(2 * cfg.n_layers)
-    return {
+    p = {
         "router": jax.random.normal(k0, (E, X), cfg.param_dtype) * std,
         "gate": jax.random.normal(k1, (X, E, F), cfg.param_dtype) * std,
         "up": jax.random.normal(k2, (X, E, F), cfg.param_dtype) * std,
         "down": jax.random.normal(k3, (X, F, E), cfg.param_dtype) * out_std,
     }
+    if cfg.moe.score_func == "sigmoid":
+        p["router_bias"] = (jax.random.normal(jax.random.fold_in(k0, 1), (X,), jnp.float32)
+                            * cfg.moe.select_bias_init_std)
+    if cfg.moe.n_shared_experts:
+        p["shared"] = _dense_mlp_params(cfg, jax.random.fold_in(k0, 2),
+                                        cfg.moe.n_shared_experts * F)
+    return p
 
 
-def _layer_params(cfg, key):
+def _mla_params(cfg, ks):
+    E, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    std = 0.02
+    out_std = 0.02 / math.sqrt(2 * cfg.n_layers)
+    return {
+        "wq": jax.random.normal(ks[0], (E, H, dn + dr), cfg.param_dtype) * std,
+        "w_dkv": jax.random.normal(ks[1], (E, r + dr), cfg.param_dtype) * std,
+        "kv_norm": jnp.ones((r,), cfg.param_dtype),
+        "w_ukv": jax.random.normal(ks[2], (r, H, dn + dv), cfg.param_dtype) * std,
+        "wo": jax.random.normal(ks[3], (H, dv, E), cfg.param_dtype) * out_std,
+    }
+
+
+def _layer_params(cfg, key, dense: bool = False):
+    """One layer; `dense` makes it one of the leading dense layers."""
     E, H, Hkv, Dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
     ks = jax.random.split(key, 6)
     std = 0.02
     out_std = 0.02 / math.sqrt(2 * cfg.n_layers)
-    attn = {
-        "wq": jax.random.normal(ks[0], (E, H, Dh), cfg.param_dtype) * std,
-        "wk": jax.random.normal(ks[1], (E, Hkv, Dh), cfg.param_dtype) * std,
-        "wv": jax.random.normal(ks[2], (E, Hkv, Dh), cfg.param_dtype) * std,
-        "wo": jax.random.normal(ks[3], (H, Dh, E), cfg.param_dtype) * out_std,
-    }
+    if cfg.mla:
+        attn = _mla_params(cfg, ks)
+    else:
+        attn = {
+            "wq": jax.random.normal(ks[0], (E, H, Dh), cfg.param_dtype) * std,
+            "wk": jax.random.normal(ks[1], (E, Hkv, Dh), cfg.param_dtype) * std,
+            "wv": jax.random.normal(ks[2], (E, Hkv, Dh), cfg.param_dtype) * std,
+            "wo": jax.random.normal(ks[3], (H, Dh, E), cfg.param_dtype) * out_std,
+        }
     if cfg.bias:
         attn["bq"] = jnp.zeros((H, Dh), cfg.param_dtype)
         attn["bk"] = jnp.zeros((Hkv, Dh), cfg.param_dtype)
         attn["bv"] = jnp.zeros((Hkv, Dh), cfg.param_dtype)
         attn["bo"] = jnp.zeros((E,), cfg.param_dtype)
+    if dense:
+        mlp = _dense_mlp_params(cfg, ks[5], cfg.d_ff_dense)
+    else:
+        mlp = _moe_params(cfg, ks[5]) if cfg.moe else _dense_mlp_params(cfg, ks[5])
     layer = {
         "norm1": _norm_params(cfg, ks[4]),
         "attn": attn,
         "norm2": _norm_params(cfg, ks[4]),
-        "mlp": _moe_params(cfg, ks[5]) if cfg.moe else _dense_mlp_params(cfg, ks[5]),
+        "mlp": mlp,
     }
     return layer
 
 
+def _check(cfg: TransformerConfig) -> None:
+    if cfg.mla and (cfg.bias or cfg.pos != "rope" or cfg.n_kv_heads is not None):
+        raise ValueError("latent attention (kv_lora_rank) is built with rope, "
+                         "without biases and without grouped KV heads")
+    if not 0 <= cfg.n_dense_layers < cfg.n_layers:
+        raise ValueError(f"n_dense_layers {cfg.n_dense_layers} must leave at "
+                         f"least one of the {cfg.n_layers} layers")
+    if cfg.n_dense_layers and (cfg.act != "swiglu" or cfg.bias):
+        raise ValueError("leading dense layers are SwiGLU without biases")
+    if cfg.moe and cfg.moe.score_func not in ("softmax", "sigmoid"):
+        raise ValueError(f"MoEConfig.score_func {cfg.moe.score_func!r}: "
+                         "'softmax' or 'sigmoid'")
+    if cfg.moe and cfg.moe.score_func == "sigmoid" and not cfg.moe.dropless:
+        raise ValueError("sigmoid routing is dropless: capacity_factor None")
+
+
+def scan_layers(block, carry, params, cfg: TransformerConfig, *per_layer):
+    """`block(carry, layer params)`, or with `per_layer` trees (all the
+    layers on their leading dimension) `block(carry, (layer params, *their
+    slices))`, over every layer in depth order: one lax.scan a stack of
+    layers of one kind, the scans' outputs joined along the layer dimension.
+
+    The routed experts of a dropless stack are not sliced by the scan: the
+    body closes over them whole and is told its layer (`mlp["layer"]`), for
+    `ops.moe_sorted` to multiply them where they lie."""
+    outs, dense = [], cfg.n_dense_layers
+    # (stacked layer params, index of their first layer): the leading dense
+    # layers, where the model has them, then the rest
+    for stack, first in ([(params["dense_layers"], 0)] if dense else []) + [
+            (params["layers"], dense)]:
+        n = jax.tree.leaves(stack)[0].shape[0]
+        sliced = tuple(
+            t if n == cfg.n_layers else jax.tree.map(lambda a: a[first:first + n], t)
+            for t in per_layer)
+        mlp = stack["mlp"]
+        if "router" in mlp and cfg.moe.dropless:
+            whole = {k: mlp[k] for k in ("gate", "up", "down")}
+            rest = {**stack, "mlp": {k: v for k, v in mlp.items() if k not in whole}}
+
+            def body(c, xs, whole=whole):
+                layer_p, layer, *more = xs
+                layer_p = {**layer_p, "mlp": {**layer_p["mlp"], **whole, "layer": layer}}
+                return block(c, (layer_p, *more) if more else layer_p)
+
+            xs = (rest, jnp.arange(n, dtype=jnp.int32)) + sliced
+        else:
+            body, xs = block, (stack,) + sliced if sliced else stack
+        carry, out = jax.lax.scan(body, carry, xs)
+        outs.append(out)
+    if len(outs) == 1:
+        return carry, outs[0]
+    return carry, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs)
+
+
 def init(key, cfg: TransformerConfig):
+    _check(cfg)
     k_emb, k_pos, k_layers, k_head = jax.random.split(key, 4)
+    keys = jax.random.split(k_layers, cfg.n_layers)
     params = {
         "embed": jax.random.normal(k_emb, (cfg.vocab_size, cfg.d_model), cfg.param_dtype) * 0.02,
-        "layers": jax.vmap(lambda k: _layer_params(cfg, k))(jax.random.split(k_layers, cfg.n_layers)),
+        "layers": jax.vmap(lambda k: _layer_params(cfg, k))(keys[cfg.n_dense_layers:]),
         "final_norm": _norm_params(cfg, k_head),
     }
+    if cfg.n_dense_layers:
+        params["dense_layers"] = jax.vmap(lambda k: _layer_params(cfg, k, dense=True))(
+            keys[:cfg.n_dense_layers])
     if cfg.pos == "learned":
         params["pos_embed"] = jax.random.normal(k_pos, (cfg.max_seq_len, cfg.d_model), cfg.param_dtype) * 0.02
     if not cfg.tie_embeddings:
@@ -157,25 +290,40 @@ def logical_axes(cfg: TransformerConfig):
         "wv": ("embed", "kv_heads", "head_dim"),
         "wo": ("heads", "head_dim", "embed"),
     }
+    if cfg.mla:
+        attn = {"wq": ("embed", "heads", "head_dim"), "w_dkv": ("embed", None),
+                "kv_norm": (None,), "w_ukv": (None, "heads", "head_dim"),
+                "wo": ("heads", "head_dim", "embed")}
     if cfg.bias:
         attn.update({"bq": ("heads", "head_dim"), "bk": ("kv_heads", "head_dim"),
                      "bv": ("kv_heads", "head_dim"), "bo": ("embed",)})
+    swiglu = {"wi_gate": ("embed", "mlp"), "wi_up": ("embed", "mlp"), "wo": ("mlp", "embed")}
     if cfg.moe:
         mlp = {"router": ("embed", None), "gate": ("expert", "embed", "mlp"),
                "up": ("expert", "embed", "mlp"), "down": ("expert", "mlp", "embed")}
+        if cfg.moe.score_func == "sigmoid":
+            mlp["router_bias"] = (None,)
+        if cfg.moe.n_shared_experts:
+            mlp["shared"] = swiglu
     elif cfg.act == "swiglu":
-        mlp = {"wi_gate": ("embed", "mlp"), "wi_up": ("embed", "mlp"), "wo": ("mlp", "embed")}
+        mlp = swiglu
     else:
         mlp = {"wi": ("embed", "mlp"), "wo": ("mlp", "embed")}
         if cfg.bias:
             mlp.update({"bi": ("mlp",), "bo": ("embed",)})
-    layer = {"norm1": norm, "attn": attn, "norm2": norm, "mlp": mlp}
-    stacked = jax.tree.map(lambda t: ("layers",) + t, layer, is_leaf=lambda x: isinstance(x, tuple))
+
+    def stacked(mlp):
+        layer = {"norm1": norm, "attn": attn, "norm2": norm, "mlp": mlp}
+        return jax.tree.map(lambda t: ("layers",) + t, layer,
+                            is_leaf=lambda x: isinstance(x, tuple))
+
     out = {
         "embed": ("vocab", "embed"),
-        "layers": stacked,
+        "layers": stacked(mlp),
         "final_norm": norm,
     }
+    if cfg.n_dense_layers:
+        out["dense_layers"] = stacked(swiglu)
     if cfg.pos == "learned":
         out["pos_embed"] = (None, "embed")
     if not cfg.tie_embeddings:
@@ -187,12 +335,68 @@ def logical_axes(cfg: TransformerConfig):
 
 def _norm(x, p, cfg):
     if cfg.norm == "rms":
-        return ops.rms_norm(x, p["w"])
+        return ops.rms_norm(x, p["w"], eps=cfg.norm_eps)
     return ops.layer_norm(x, p["w"], p.get("b"))
+
+
+def _to_lanes(x, cfg):
+    """x [..., w] with zeros appended up to the cached row's whole lanes."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, cfg.latent_lanes - x.shape[-1])])
+
+
+def _mla_project(x, p, cfg, cos, sin, positions=None):
+    """Normed x [B, T, E] -> (q [B, T, H, nope + rope], its rope part
+    rotated; the row the cache holds [B, T, latent_lanes]: c = RMSNorm(x
+    W_dkv's first kv_lora_rank columns) | k_rope = RoPE(the rest), one head
+    shared by all query heads | zeros up to whole lanes)."""
+    dt = cfg.dtype
+    dn, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bte,ehd->bthd", x, p["wq"].astype(dt))
+    q = jnp.concatenate(
+        [q[..., :dn], ops.apply_rope(q[..., dn:], cos, sin, positions=positions)], axis=-1)
+    ckr = x @ p["w_dkv"].astype(dt)
+    c = ops.rms_norm(ckr[..., :r], p["kv_norm"], eps=cfg.kv_norm_eps)
+    k_rope = ops.apply_rope(ckr[..., None, r:], cos, sin, positions=positions)[..., 0, :]
+    return q, _to_lanes(jnp.concatenate([c, k_rope], axis=-1), cfg)
+
+
+def _mla_expand(latent, p, cfg):
+    """Cached rows [B, S, latent_lanes] -> per-head k [B, S, H, nope + rope]
+    and v [B, S, H, v]: the form prefill attends in."""
+    dn, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    kv = jnp.einsum("bsr,rhd->bshd", latent[..., :r], p["w_ukv"].astype(cfg.dtype))
+    k_rope = jnp.broadcast_to(latent[..., None, r:r + cfg.qk_rope_head_dim],
+                              kv.shape[:-1] + (cfg.qk_rope_head_dim,))
+    return jnp.concatenate([kv[..., :dn], k_rope], axis=-1), kv[..., dn:]
+
+
+def _mla_absorb_q(q, p, cfg):
+    """q [B, H, nope + rope] -> [B, H, latent_lanes] = (q_nope W_uk^T | q_rope
+    | 0): its dot with a cached row is the score of the expanded form."""
+    dn = cfg.qk_nope_head_dim
+    q_abs = jnp.einsum("bhd,rhd->bhr", q[..., :dn],
+                       p["w_ukv"][..., :dn].astype(cfg.dtype))
+    return _to_lanes(jnp.concatenate([q_abs, q[..., dn:]], axis=-1), cfg)
+
+
+def _mla_absorb_out(o_lat, p, cfg):
+    """Attention output over cached rows [B, H, latent_lanes] (a weighted sum
+    of rows: its first kv_lora_rank columns are sum p c) -> [B, H, v]."""
+    return jnp.einsum("bhr,rhd->bhd", o_lat[..., :cfg.kv_lora_rank],
+                      p["w_ukv"][..., cfg.qk_nope_head_dim:].astype(cfg.dtype))
 
 
 def _attn_block(x, p, cfg, cos, sin, sp_axis, attn_impl):
     dt = cfg.dtype
+    if cfg.mla:
+        if sp_axis is not None:
+            raise ValueError("latent attention has no sequence-parallel form")
+        q, latent = _mla_project(x, p, cfg, cos, sin)
+        k, v = _mla_expand(latent, p, cfg)
+        # v heads are narrower than q/k heads: not the flash kernel's shape
+        out = ops.attention(q, k, v, causal=True, scale=cfg.qk_dim ** -0.5,
+                            impl="reference")
+        return jnp.einsum("bthd,hde->bte", out, p["wo"].astype(dt))
     q = jnp.einsum("bte,ehd->bthd", x, p["wq"].astype(dt))
     k = jnp.einsum("bte,ehd->bthd", x, p["wk"].astype(dt))
     v = jnp.einsum("bte,ehd->bthd", x, p["wv"].astype(dt))
@@ -234,20 +438,43 @@ def _dense_mlp(x, p, cfg):
 
 
 def _moe_mlp(x, p, cfg):
-    dt = cfg.dtype
+    dt, moe = cfg.dtype, cfg.moe
     B, T, E = x.shape
     xf = x.reshape(B * T, E)
-    router_logits = (xf @ p["router"].astype(dt)).astype(jnp.float32)
-    routing = ops.topk_routing(router_logits, num_experts=cfg.moe.num_experts,
-                               k=cfg.moe.top_k, capacity_factor=cfg.moe.capacity_factor)
+    if moe.score_func == "sigmoid":
+        # the published gate computes in float32 (2048 x 64 a token is free)
+        router_logits = jnp.dot(xf.astype(jnp.float32), p["router"].astype(jnp.float32),
+                                precision=jax.lax.Precision.HIGHEST)
+    else:
+        router_logits = (xf @ p["router"].astype(dt)).astype(jnp.float32)
+    experts = {k: p[k] for k in ("gate", "up", "down")}
+    if moe.dropless:
+        if moe.score_func == "sigmoid":
+            idx, w, aux = ops.sigmoid_topk(router_logits, p["router_bias"], k=moe.top_k,
+                                           scale=moe.routed_scaling_factor)
+        else:
+            idx, w, aux = ops.softmax_topk(router_logits, k=moe.top_k)
+        routing = None if ops.sorted_pays(B * T) else ops.onehot_dispatch(
+            idx, w, moe.num_experts, B * T)
+    else:
+        routing = ops.topk_routing(router_logits, num_experts=moe.num_experts,
+                                   k=moe.top_k, capacity_factor=moe.capacity_factor)
+        aux = routing.aux_loss
+    if routing is None:
+        y = ops.moe_sorted(xf, idx, w, **experts, layer=p.get("layer"))
+    else:
+        if "layer" in p:  # the stack's experts, whole: this layer's
+            experts = jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(
+                t, p["layer"], 0, keepdims=False), experts)
 
-    def expert_fn(pe, xe):
-        h = ops.swiglu(xe @ pe["gate"].astype(dt), xe @ pe["up"].astype(dt))
-        return h @ pe["down"].astype(dt)
+        def expert_fn(pe, xe):
+            h = ops.swiglu(xe @ pe["gate"].astype(dt), xe @ pe["up"].astype(dt))
+            return h @ pe["down"].astype(dt)
 
-    expert_params = {"gate": p["gate"], "up": p["up"], "down": p["down"]}
-    y = ops.moe_apply(xf, routing, expert_fn, expert_params)
-    return y.reshape(B, T, E), routing.aux_loss
+        y = ops.moe_apply(xf, routing, expert_fn, experts)
+    if moe.n_shared_experts:  # one MLP, every token, ungated
+        y = y + _dense_mlp(xf, p["shared"], cfg)
+    return y.reshape(B, T, E), aux
 
 
 def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = None,
@@ -267,7 +494,7 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
         x = x + pos.astype(dt)
     cos = sin = None
     if cfg.pos == "rope":
-        cos, sin = ops.rope_frequencies(cfg.head_dim, cfg.max_seq_len, theta=cfg.rope_theta)
+        cos, sin = ops.rope_frequencies(cfg.rope_dim, cfg.max_seq_len, theta=cfg.rope_theta)
 
     aux_total = jnp.zeros((), jnp.float32)
 
@@ -276,15 +503,15 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
         h = h + _attn_block(_norm(h, layer_p["norm1"], cfg), layer_p["attn"], cfg,
                             cos, sin, sp_axis, attn_impl)
         normed = _norm(h, layer_p["norm2"], cfg)
-        if cfg.moe:
+        if "router" in layer_p["mlp"]:
             delta, layer_aux = _moe_mlp(normed, layer_p["mlp"], cfg)
             aux = aux + layer_aux
         else:
             delta = _dense_mlp(normed, layer_p["mlp"], cfg)
         return (h + delta, aux), None
 
-    if cfg.remat and cfg.remat_policy == "pairs" and (cfg.n_layers % 2
-                                                      or cfg.moe):
+    if cfg.remat and cfg.remat_policy == "pairs" and (cfg.n_layers % 2 or cfg.moe
+                                                      or cfg.n_dense_layers):
         raise ValueError(
             "remat_policy='pairs' needs an even n_layers and a dense (non-"
             "MoE) stack; falling back silently would misattribute benchmark "
@@ -314,7 +541,7 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
                       if cfg.remat_policy == "dots"
                       else jax.checkpoint_policies.nothing_saveable)
             block = jax.checkpoint(block, policy=policy)
-        (x, aux_total), _ = jax.lax.scan(block, (x, aux_total), params["layers"])
+        (x, aux_total), _ = scan_layers(block, (x, aux_total), params, cfg)
     x = _norm(x, params["final_norm"], cfg)
     if return_hidden:
         return x, aux_total

@@ -2,7 +2,8 @@ from ray_tpu.ops.activations import geglu, gelu, swiglu
 from ray_tpu.ops.attention import attention, repeat_kv
 from ray_tpu.ops.flash_attention import flash_attention, flash_attention_forward
 from ray_tpu.ops.losses import fused_head_cross_entropy, softmax_cross_entropy
-from ray_tpu.ops.moe import RoutingInfo, moe_apply, topk_routing
+from ray_tpu.ops.moe import (RoutingInfo, moe_apply, moe_sorted, onehot_dispatch,
+                             sigmoid_topk, softmax_topk, sorted_pays, topk_routing)
 from ray_tpu.ops.norms import layer_norm, rms_norm
 from ray_tpu.ops.ragged_paged_attention import (
     ragged_decode_attention, ragged_decode_attention_reference)
@@ -19,11 +20,16 @@ __all__ = [
     "gelu",
     "layer_norm",
     "moe_apply",
+    "moe_sorted",
+    "onehot_dispatch",
     "ragged_decode_attention",
     "ragged_decode_attention_reference",
     "repeat_kv",
     "rms_norm",
     "rope_frequencies",
+    "sigmoid_topk",
     "softmax_cross_entropy",
+    "softmax_topk",
+    "sorted_pays",
     "swiglu",
 ]

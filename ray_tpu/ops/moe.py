@@ -1,10 +1,24 @@
-"""Mixture-of-experts routing: token-choice top-k with capacity (GShard-style).
+"""Mixture-of-experts routing and dispatch, token-choice top-k.
 
-Everything is dense einsum over one-hot dispatch tensors — static shapes, no
-gather/scatter with data-dependent sizes, so XLA tiles it onto the MXU and
-the `expert` dimension shards cleanly over the `ep` mesh axis. (The reference
-has no in-repo EP — SURVEY.md §2.6 — it passes knobs to vLLM; this is the
-TPU-native implementation.)
+Two routing functions give each token its k experts and their weights:
+`softmax_topk` (Mixtral: softmax over all experts, top-k, renormalised) and
+`sigmoid_topk` (DeepSeek-V3 `noaux_tc`: sigmoid scores, selection on score +
+a per-expert bias, weights from the scores alone, renormalised and scaled).
+
+Two dispatches carry them out:
+
+- `moe_sorted`: dropless. The N*k routed slots are sorted by expert and go
+  through grouped matmuls (`jax.lax.ragged_dot`, a native grouped product on
+  the TPU), so expert FLOPs grow with k*N whatever the expert count.
+- `onehot_dispatch` + `moe_apply`: capacity (GShard-style), dense einsums
+  over one-hot `[N, E, C]` dispatch tensors — static shapes, tokens over
+  capacity dropped, the `expert` dimension shards cleanly over the `ep` mesh
+  axis, expert FLOPs grow with E*C. (The reference has no in-repo EP —
+  SURVEY.md §2.6 — it passes knobs to vLLM; this is the TPU-native
+  implementation.)
+
+A layer that may drop nothing picks between them by the tokens of the call,
+`sorted_pays(N)`: with C = N the one-hot form drops nothing either.
 """
 
 from __future__ import annotations
@@ -21,6 +35,22 @@ class RoutingInfo(NamedTuple):
     aux_loss: jax.Array       # load-balancing loss (scalar)
 
 
+# Tokens in a call from which a dropless layer sorts. Below it the one-hot
+# form with C = N is bound by reading the experts' weights once, which XLA's
+# batched matmul does at 94 % of the HBM's rate and the grouped product's
+# custom call at about 40 % (a decode step: `mixtral-8x7b.chat-steady`
+# `tpot_p50_ms` 26.3 one-hot, 31.9 sorted); above it the one-hot form
+# multiplies E*N slots where k*N are routed (a 1024-token prefill chunk:
+# `mixtral-8x7b.doc-saturated` `served_tok_s` 7,455 one-hot, 8,252 sorted).
+# A v5e turns compute-bound at about 240 rows a weight matrix; the buckets
+# on either side are 256 and 512. PERF.md section 6, PR 28.
+SORTED_MIN_TOKENS = 512
+
+
+def sorted_pays(n_tokens: int) -> bool:
+    return n_tokens >= SORTED_MIN_TOKENS
+
+
 def topk_routing(router_logits, *, num_experts: int, k: int,
                  capacity_factor: float = 1.25) -> RoutingInfo:
     """router_logits: [N, E] (N = flattened tokens). Top-k token-choice routing
@@ -29,11 +59,18 @@ def topk_routing(router_logits, *, num_experts: int, k: int,
     N, E = router_logits.shape
     assert E == num_experts
     capacity = int(max(k * N / E * capacity_factor, 1.0) + 0.9999)
+    expert_idx, gate_vals, probs = _softmax_topk(router_logits, k)
+    routing = onehot_dispatch(expert_idx, gate_vals, E, capacity)
+    # Switch-style load-balance aux loss
+    frac_tokens = jnp.mean(jax.nn.one_hot(expert_idx[:, 0], E, dtype=jnp.float32), axis=0)
+    return routing._replace(aux_loss=E * jnp.sum(frac_tokens * jnp.mean(probs, axis=0)))
 
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)  # [N, E]
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)                     # [N, k]
-    # renormalize the selected gates (Mixtral convention)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+
+def onehot_dispatch(expert_idx, gate_vals, num_experts: int, capacity: int) -> RoutingInfo:
+    """expert_idx, gate_vals [N, k] -> the `[N, E, C]` dispatch and combine
+    tensors of `moe_apply`; a token over an expert's `capacity` is dropped
+    (`capacity` N drops nothing). aux_loss 0: the caller's to set."""
+    (N, k), E = expert_idx.shape, num_experts
 
     # position of each (token, choice) in its expert's queue
     onehot = jax.nn.one_hot(expert_idx, E, dtype=jnp.int32)             # [N, k, E]
@@ -56,11 +93,71 @@ def topk_routing(router_logits, *, num_experts: int, k: int,
         dispatch = dispatch + d
         combine = combine + d * gate_vals[:, c][:, None, None]
 
-    # Switch-style load-balance aux loss
-    frac_tokens = jnp.mean(onehot[:, 0].astype(jnp.float32), axis=0)    # top-1 assignment share
-    frac_probs = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac_tokens * frac_probs)
-    return RoutingInfo(dispatch=dispatch, combine=combine, aux_loss=aux)
+    return RoutingInfo(dispatch=dispatch, combine=combine,
+                       aux_loss=jnp.zeros((), jnp.float32))
+
+
+def _softmax_topk(router_logits, k: int):
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)  # [N, E]
+    gate_vals, expert_idx = jax.lax.top_k(probs, k)                     # [N, k]
+    # renormalize the selected gates (Mixtral convention)
+    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    return expert_idx, gate_vals, probs
+
+
+def softmax_topk(router_logits, *, k: int):
+    """router_logits [N, E] -> (experts [N, k] int32, weights [N, k] float32,
+    Switch-style load-balance loss): softmax over all experts, top-k, the k
+    gates renormalised to sum to one."""
+    E = router_logits.shape[-1]
+    expert_idx, gate_vals, probs = _softmax_topk(router_logits, k)
+    frac_tokens = jnp.mean(jax.nn.one_hot(expert_idx[:, 0], E, dtype=jnp.float32), axis=0)
+    aux = E * jnp.sum(frac_tokens * jnp.mean(probs, axis=0))
+    return expert_idx, gate_vals, aux
+
+
+def sigmoid_topk(router_logits, select_bias, *, k: int, scale: float = 1.0):
+    """router_logits [N, E] float32, select_bias [E] -> (experts [N, k],
+    weights [N, k] float32, 0.0). The k experts are the top-k of
+    sigmoid(logits) + bias; a weight is the expert's score WITHOUT the bias,
+    over (the k scores' sum + 1e-20), times `scale`. No auxiliary loss: the
+    bias is what balances the load."""
+    scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    _, expert_idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(scores, expert_idx, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * scale
+    return expert_idx, w, jnp.zeros((), jnp.float32)
+
+
+def moe_sorted(x, expert_idx, weights, gate, up, down, *, layer=None):
+    """Dropless SwiGLU experts. x [N, D]; expert_idx, weights [N, k]; gate,
+    up [E, D, F], down [E, F, D]. Every one of the N*k routed slots is
+    computed: slots sorted by expert, three grouped matmuls over the group
+    sizes, unsorted, weighted and summed per token in float32.
+
+    With `layer` (an index, traced in a layer scan) the weights are those of
+    ALL layers, [L, E, ...], multiplied as L*E groups of which only this
+    layer's have rows: the grouped product is a custom call, and a layer's
+    slice of the stack handed to one is copied out whole first, every layer
+    of every step (the compiler's account, PERF.md section 4)."""
+    N, k = expert_idx.shape
+    E = gate.shape[-3]
+    flat = expert_idx.reshape(N * k)
+    order = jnp.argsort(flat, stable=True)                     # slot ids by expert
+    sizes = jnp.sum(flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :],
+                    axis=0, dtype=jnp.int32)
+    if layer is not None:
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((gate.shape[0] * E,), jnp.int32), sizes, (layer * E,))
+        gate, up, down = (w.reshape(-1, *w.shape[2:]) for w in (gate, up, down))
+    xs = x[order // k]                                         # [N*k, D]
+    dt = x.dtype
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, gate.astype(dt), sizes)) \
+        * jax.lax.ragged_dot(xs, up.astype(dt), sizes)
+    ys = jax.lax.ragged_dot(h, down.astype(dt), sizes)         # [N*k, D]
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(N * k, dtype=order.dtype))
+    y = ys[back].reshape(N, k, -1).astype(jnp.float32)
+    return jnp.sum(y * weights[..., None].astype(jnp.float32), axis=1).astype(dt)
 
 
 def moe_apply(x, routing: RoutingInfo, expert_fn, expert_params):

@@ -24,6 +24,14 @@ Two implementations with ONE accumulation order so they agree bitwise:
   kernel (interpret mode) is bit-consistent with the path the CPU engine
   actually decodes with.
 
+Latent (MLA) pools: `vp=None` and a pool of rows [num_pages, P, W] with no
+head dimension. All query heads share the one row, and the row is its own
+value (absorbed multi-head latent attention: q is (q_nope W_uk^T | q_rope |
+0), the caller keeps the result's first kv_lora_rank columns).
+``_latent_kernel`` reads each page ONCE; its two matmuls take the operands as
+stored (bfloat16 products, float32 sums: [H, W] x [W, P] on the MXU), where
+the per-head kernel multiplies in float32.
+
 The engine bounds the page sweep host-side (`pages_bound` in
 models/decoding_paged.py decode_step_paged_ragged): the block table is
 sliced to the batch's live maximum before either impl runs, so even the
@@ -130,6 +138,99 @@ def _ragged_kernel_call(q, kp, vp, block_table, pos, *, scale: float,
     )(block_table, pos, q, kp, vp)
 
 
+def _latent_kernel(tbl_ref, pos_ref, q_ref, cp_ref, o_ref,
+                   m_scr, l_scr, acc_scr, *, scale: float, page_size: int):
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    p0 = pos_ref[b]
+
+    @pl.when(j * page_size <= p0)
+    def _compute():
+        q = q_ref[0]                                  # [H, W]
+        c = cp_ref[0]                                 # [P, W]
+        s = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        kpos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos <= p0, s, _NEG_INF)
+        m_prev = m_scr[:, :1]
+        l_prev = l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
+        pv = jnp.dot(p.astype(c.dtype), c, preferred_element_type=jnp.float32)
+        acc_scr[:] = acc_scr[:] * corr + pv
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+def _latent_kernel_call(q, cp, block_table, pos, *, scale: float, interpret: bool):
+    B, H, W = q.shape
+    P = cp.shape[1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # block_table, pos
+        grid=(B, block_table.shape[1]),
+        in_specs=[
+            pl.BlockSpec((1, H, W), lambda b, j, tbl, pos: (b, 0, 0)),
+            pl.BlockSpec((1, P, W), lambda b, j, tbl, pos: (tbl[b, j], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, H, W), lambda b, j, tbl, pos: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, W), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, scale=scale, page_size=P),
+        out_shape=jax.ShapeDtypeStruct((B, H, W), q.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name="ragged_latent_attention",
+    )(block_table, pos, q, cp)
+
+
+def _latent_reference(q, cp, block_table, pos, *, scale: float):
+    """Pure-JAX mirror of `_latent_kernel`: the same page-by-page online
+    softmax, products of the operands as stored, float32 sums."""
+    B, H, W = q.shape
+    P = cp.shape[1]
+
+    def body(j, carry):
+        m, l, acc = carry
+        c = cp[block_table[:, j]]                      # [B, P, W]
+        s = jnp.einsum("bhw,bpw->bhp", q, c,
+                       preferred_element_type=jnp.float32) * scale
+        kpos = j * P + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(kpos <= pos[:, None, None], s, _NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_new = l * corr + p.sum(axis=-1, keepdims=True)
+        acc_new = acc * corr + jnp.einsum("bhp,bpw->bhw", p.astype(c.dtype), c,
+                                          preferred_element_type=jnp.float32)
+        live = (j * P <= pos)[:, None, None]
+        return (jnp.where(live, m_new, m), jnp.where(live, l_new, l),
+                jnp.where(live, acc_new, acc))
+
+    m0 = jnp.full((B, H, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((B, H, 1), jnp.float32)
+    a0 = jnp.zeros((B, H, W), jnp.float32)
+    _m, l, acc = jax.lax.fori_loop(0, block_table.shape[1], body, (m0, l0, a0))
+    return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
+
+
 def ragged_decode_attention_reference(q, kp, vp, block_table, pos, *,
                                       scale: float):
     """Pure-JAX mirror of the kernel: fori_loop over pages with the SAME
@@ -183,9 +284,21 @@ def ragged_decode_attention(q, kp, vp, block_table, pos, *,
     block_table: [B, nb] int32 page ids (pre-sliced to the batch's live
     page bound); pos: [B] int32 — row b attends cache positions <= pos[b].
     Returns [B, Hkv, G, Dh] in q's dtype.
+
+    Latent rows: kp [num_pages, P, W], vp None, q [B, 1, H, W]; returns
+    [B, 1, H, W], a weighted sum of rows.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if vp is None:
+        if impl == "kernel":
+            out = _latent_kernel_call(q[:, 0], kp, block_table, pos,
+                                      scale=scale, interpret=interpret)
+        elif impl == "reference":
+            out = _latent_reference(q[:, 0], kp, block_table, pos, scale=scale)
+        else:
+            raise ValueError(f"impl must be 'kernel' or 'reference', got {impl!r}")
+        return out[:, None]
     if impl == "kernel":
         return _ragged_kernel_call(q, kp, vp, block_table, pos,
                                    scale=scale, interpret=interpret)

@@ -143,6 +143,42 @@ def test_paged_decode_step_holds_the_pool_once(chip, step, max_len):
     assert m.alias_size_in_bytes >= pools
 
 
+def test_latent_decode_step_holds_the_pool_once(chip):
+    """The decode program of `kimi-vl-a3b.longdoc-saturated` (the leading
+    dense layer and two of its expert layers: each layer scan compiles one
+    body whatever the depth): ONE pool of latent rows, [L, pages, 64, 640],
+    carried through both layer scans and scattered in place; the absorbed
+    attention is the `ragged_latent_attention` kernel, once a scan."""
+    import re
+
+    from ray_tpu.models import decoding, decoding_paged, kimi_vl_config
+
+    cfg = kimi_vl_config("a3b", n_layers=3, param_dtype=jnp.bfloat16, max_seq_len=16640)
+    params, state = _abstract_step_inputs(chip, cfg, 32, 16640, 3328, 64)
+    assert set(state) == {"kp", "block", "length", "last_token", "active"}
+    assert state["kp"].shape == (3, 3328, 64, 640)
+    compiled = decoding_paged.decode_step_paged_ragged.lower(
+        params, state, cfg, 256, True).compile()
+    m = compiled.memory_analysis()
+    pool = 3 * 3328 * 64 * 640 * 2
+    assert m.alias_size_in_bytes >= pool
+    # nothing pool-sized, and no layer's experts copied out of the stack for
+    # the grouped products (0.37 GB when the scan sliced them; 2.3 MB now)
+    assert m.temp_size_in_bytes < 64 * 2**20
+    calls = [c.split(".")[0] for c in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", compiled.as_text())]
+    # one a layer scan; 32 rows take the one-hot dispatch: no grouped product
+    assert calls == ["ragged_latent_attention"] * 2
+    # a 1024-token prefill sorts its slots: the grouped products multiply the
+    # stack's experts where they lie (sliced by the scan, one layer's
+    # [64, 2048, 1408] was copied out for each: 369,098,752 bytes)
+    tokens = jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=chip)
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    prefill = decoding.prefill.lower(params, tokens, n, cfg).compile()
+    assert "ragged-dot" in prefill.as_text()
+    assert prefill.memory_analysis().temp_size_in_bytes < 64 * 2048 * 1408 * 2
+
+
 def test_kernel_names_reach_the_compiled_program(chip):
     """`pallas_call(name=...)` names the HLO instruction of each kernel's
     custom call, which is what a device trace names the op by: the trace
