@@ -6,7 +6,7 @@
 One chip, both halves of the system through the entry points users call:
 
 - train: `ray_tpu.init` -> `JaxTrainer(use_tpu=True)` -> a worker that holds
-  the chip checks both Pallas kernels against their references, then takes
+  the chip checks the Pallas kernels against their references, then takes
   AdamW steps of GPT-2 774M (published widths, s1024, bf16, remat) with
   `train/spmd.py`'s step on a one-device mesh, batches from `ray_tpu.data`,
   losses through `train.report`;
@@ -50,7 +50,14 @@ SERVE = dict(family="llama", model_id="1b", model_kwargs={"max_seq_len": 2048},
 # s1024, and Llama-1B decode (8 kv heads x 4 query heads, d_head 64, page 64)
 KERNELS = dict(flash=(2, 20, 1024, 64),
                ragged=dict(batch=16, kv_heads=8, group=4, head_dim=64,
-                           page=64, pages_per_seq=32))
+                           page=64, pages_per_seq=32),
+               # the sorted dispatch of a 1,024-token prefill chunk in the two
+               # document cells: the experts of all the cell's layers stacked
+               grouped=dict(
+                   kimi_vl_a3b=dict(tokens=1024, top_k=6, experts=64, layers=8,
+                                    d_model=2048, d_ff=1408),
+                   mixtral_8x7b=dict(tokens=1024, top_k=2, experts=8, layers=4,
+                                     d_model=4096, d_ff=14336)))
 
 
 class SmokeFailure(AssertionError):
@@ -97,8 +104,70 @@ def probe_sync_primitive() -> dict:
             "blocks": (t2 - t1) > 10 * (t1 - t0) and (t3 - t2) < (t2 - t1)}
 
 
-def compare_kernels(flash, ragged, interpret: bool = False) -> dict:
-    """Both Pallas kernels against their pure-JAX references at the given
+def compare_grouped_matmul(shapes: dict, interpret: bool = False) -> dict:
+    """`ops.grouped_matmul`'s kernel against `jax.lax.ragged_dot` and the
+    float32 product, bf16, for each model of `shapes` in both directions (gate
+    and up: [M, D] x [L*E, D, F]; down: [M, F] x [L*E, F, D]) with one layer's
+    groups filled: sizes as a router draws them, and all rows in one group.
+    The error is in roundings: bfloat16 spacings at the float32 product's size
+    (at no less than 1/64 of the output's rms: below it the float32 sums' own
+    order shows), so a correctly rounded output is at 0.5. Milliseconds a call
+    are of 20 calls in a row: `PERF.md` quotes them, no benchmark reads them."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.grouped_matmul import grouped_matmul_kernel
+
+    def ms_a_call(fn, *args, calls=20):
+        fn(*args).block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            y = fn(*args)
+        y.block_until_ready()
+        return (time.perf_counter() - t0) / calls * 1e3
+
+    def roundings(got, want32):
+        a = jnp.abs(want32)
+        floor = jnp.sqrt(jnp.mean(want32 * want32)) / 64
+        spacing = jnp.exp2(jnp.floor(jnp.log2(jnp.maximum(a, floor))) - 7)
+        return float((jnp.abs(got.astype(jnp.float32) - want32) / spacing).max())
+
+    ragged = jax.jit(jax.lax.ragged_dot)
+    exact = jax.jit(lambda a, b, s: jax.lax.ragged_dot(
+        a, b, s, preferred_element_type=jnp.float32))
+    out = {}
+    for model, sh in shapes.items():
+        E, L, M = sh["experts"], sh["layers"], sh["tokens"] * sh["top_k"]
+        kernel = jax.jit(lambda a, b, s, E=E: grouped_matmul_kernel(
+            a, b, s, E, interpret=interpret))
+        keys = jax.random.split(jax.random.PRNGKey(len(out)), 5)
+        # a router's draw: the top k of softmaxed normal logits with an uneven bias
+        logits = jax.random.normal(keys[0], (sh["tokens"], E)) + jax.random.normal(keys[1], (E,))
+        routed = jnp.bincount(jax.lax.top_k(logits, sh["top_k"])[1].reshape(-1), length=E)
+        cases = {"routed": routed.astype(jnp.int32),
+                 "one_group": jnp.zeros((E,), jnp.int32).at[E // 3].set(M)}
+        for proj, (K, N) in {"gate": (sh["d_model"], sh["d_ff"]),
+                             "down": (sh["d_ff"], sh["d_model"])}.items():
+            lhs = jax.random.normal(keys[2], (M, K), jnp.bfloat16)
+            rhs = jax.random.normal(keys[3], (L * E, K, N), jnp.bfloat16)
+            for case, sizes in cases.items():
+                stacked = jnp.zeros((L, E), jnp.int32).at[L // 2].set(sizes).reshape(-1)
+                got, want = kernel(lhs, rhs, stacked), ragged(lhs, rhs, stacked)
+                want32 = exact(lhs, rhs, stacked)
+                err = roundings(got, want32)
+                out[f"grouped_{model}_{proj}_{case}"] = {
+                    "shape": [M, K, N, L * E], "largest_group": int(sizes.max()),
+                    "max_abs_err": err, "tol": 1.0, "ragged_dot_err": roundings(want, want32),
+                    "equal_share": float((got == want).mean()),
+                    "ok": bool(jnp.isfinite(got.astype(jnp.float32)).all()) and err <= 1.0,
+                    "kernel_ms": ms_a_call(kernel, lhs, rhs, stacked),
+                    "ragged_dot_ms": ms_a_call(ragged, lhs, rhs, stacked)}
+            del lhs, rhs
+    return out
+
+
+def compare_kernels(flash, ragged, grouped=None, interpret: bool = False) -> dict:
+    """The Pallas kernels against their pure-JAX references at the given
     shapes, bf16. `interpret` is for the CPU rehearsal only."""
     import jax
     import jax.numpy as jnp
@@ -154,6 +223,7 @@ def compare_kernels(flash, ragged, interpret: bool = False) -> dict:
         *a, impl="reference"))(qd, kp, vp, table, pos)
     out["ragged_shape"] = dict(ragged)
     out["ragged"] = {**report(got, want), "bit_equal": bool((got == want).all())}
+    out.update(compare_grouped_matmul(grouped or {}, interpret))
     return out
 
 

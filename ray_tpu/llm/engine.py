@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu import ops
 from ray_tpu._private import accelerators
 from ray_tpu.exceptions import DeadlineExceededError, RequestCancelledError
 from ray_tpu.models import decoding
@@ -459,6 +460,10 @@ class TPUEngine:
         self.prefix_tokens_gathered = 0
         self.page_steps_used = 0
         self.page_steps_total = 0
+        # padded tokens of the dispatched calls by the form their expert
+        # layers took (stats()["experts"]); a dense model counts neither
+        self.expert_tokens_sorted = 0
+        self.expert_tokens_onehot = 0
         self.spec_steps = 0
         self.spec_slot_steps = 0   # sum of active slots over verify steps
         self.spec_drafted = 0
@@ -882,6 +887,17 @@ class TPUEngine:
 
     def _bucket(self, n: int) -> int:
         return bucket_for(n, self.buckets[0], self.max_len)
+
+    def _count_expert_tokens(self, n: int) -> None:
+        """A program of `n` padded tokens was dispatched: the form its expert
+        layers take (models/transformer.py `_moe_mlp`)."""
+        moe = self.cfg.moe
+        if moe is None:
+            return
+        if moe.dropless and ops.sorted_pays(n):
+            self.expert_tokens_sorted += n
+        else:
+            self.expert_tokens_onehot += n
 
     def _pages_needed(self, prompt_len: int, bucket: int, max_tokens: int) -> int:
         """All pages this sequence will EVER touch, granted up front (no
@@ -1376,6 +1392,7 @@ class TPUEngine:
             t_sched = time.time()
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :n] = req.tokens
+            self._count_expert_tokens(bucket)
             if self.lora_bank is not None:
                 logits, kv = decoding.prefill(
                     self.params, jnp.asarray(padded), jnp.int32(n), self.cfg,
@@ -1465,6 +1482,7 @@ class TPUEngine:
             return -1  # staged: no first token yet
         padded = np.zeros((1, suf_bucket), np.int32)
         padded[0, :len(suffix)] = suffix
+        self._count_expert_tokens(suf_bucket)
         if n_pre:
             # pad the shared-page id list to a power of two so compile
             # count stays O(log(max_pages) × buckets); tail ids point at
@@ -1514,6 +1532,7 @@ class TPUEngine:
         bucket = self._bucket(len(chunk_toks))
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :len(chunk_toks)] = chunk_toks
+        self._count_expert_tokens(bucket)
         chunk_pages = np.asarray(
             req.pf_pages[done // P:(done + bucket) // P], np.int32)
         if done == 0:
@@ -1616,6 +1635,7 @@ class TPUEngine:
         last = np.zeros((S,), np.int32)
         self.spec_steps += 1
         self.spec_slot_steps += len(self._by_slot)
+        self._count_expert_tokens(S * K)
         for slot, req in list(self._by_slot.items()):
             a = 0
             while (a < self.speculative_k
@@ -1882,6 +1902,7 @@ class TPUEngine:
             t_emit = mark("emit")
             self.decode_steps += 1
             self.decode_slot_steps += len(self._by_slot)
+            self._count_expert_tokens(self.max_slots)
             self.context_tokens += sum(
                 r.length0 + max(0, r.generated - 1) + 1
                 for r in self._by_slot.values())
@@ -1939,6 +1960,8 @@ class TPUEngine:
             "prefix_tokens_gathered": self.prefix_tokens_gathered,
             "page_steps_used": self.page_steps_used,
             "page_steps_total": self.page_steps_total}
+        out["experts"] = {"tokens_sorted": self.expert_tokens_sorted,
+                          "tokens_onehot": self.expert_tokens_onehot}
         if self.speculative_k:
             drafted = self.spec_drafted
             out["speculative"] = {

@@ -1,6 +1,7 @@
 from ray_tpu.ops.activations import geglu, gelu, swiglu
 from ray_tpu.ops.attention import attention, repeat_kv
 from ray_tpu.ops.flash_attention import flash_attention, flash_attention_forward
+from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.losses import fused_head_cross_entropy, softmax_cross_entropy
 from ray_tpu.ops.moe import (RoutingInfo, moe_apply, moe_sorted, onehot_dispatch,
                              sigmoid_topk, softmax_topk, sorted_pays, topk_routing)
@@ -18,6 +19,7 @@ __all__ = [
     "fused_head_cross_entropy",
     "geglu",
     "gelu",
+    "grouped_matmul",
     "layer_norm",
     "moe_apply",
     "moe_sorted",

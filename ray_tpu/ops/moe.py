@@ -8,8 +8,9 @@ a per-expert bias, weights from the scores alone, renormalised and scaled).
 Two dispatches carry them out:
 
 - `moe_sorted`: dropless. The N*k routed slots are sorted by expert and go
-  through grouped matmuls (`jax.lax.ragged_dot`, a native grouped product on
-  the TPU), so expert FLOPs grow with k*N whatever the expert count.
+  through grouped matmuls (`ops.grouped_matmul`: a Pallas kernel on the TPU,
+  `jax.lax.ragged_dot` elsewhere), so expert FLOPs grow with k*N whatever the
+  expert count.
 - `onehot_dispatch` + `moe_apply`: capacity (GShard-style), dense einsums
   over one-hot `[N, E, C]` dispatch tensors — static shapes, tokens over
   capacity dropped, the `expert` dimension shards cleanly over the `ep` mesh
@@ -28,6 +29,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.grouped_matmul import grouped_matmul
+
 
 class RoutingInfo(NamedTuple):
     dispatch: jax.Array       # [N, E, C] one-hot dispatch mask
@@ -37,13 +40,18 @@ class RoutingInfo(NamedTuple):
 
 # Tokens in a call from which a dropless layer sorts. Below it the one-hot
 # form with C = N is bound by reading the experts' weights once, which XLA's
-# batched matmul does at 94 % of the HBM's rate and the grouped product's
-# custom call at about 40 % (a decode step: `mixtral-8x7b.chat-steady`
-# `tpot_p50_ms` 26.3 one-hot, 31.9 sorted); above it the one-hot form
+# batched matmul does at 94 % of the HBM's rate; above it the one-hot form
 # multiplies E*N slots where k*N are routed (a 1024-token prefill chunk:
 # `mixtral-8x7b.doc-saturated` `served_tok_s` 7,455 one-hot, 8,252 sorted).
 # A v5e turns compute-bound at about 240 rows a weight matrix; the buckets
-# on either side are 256 and 512. PERF.md section 6, PR 28.
+# on either side are 256 and 512. The threshold was set in PR 28 against the
+# TPU compiler's own grouped product, which read the weights at 25-40 % of
+# that rate (a decode step: `mixtral-8x7b.chat-steady` `tpot_p50_ms` 26.3
+# one-hot, 31.9 sorted). The kernel of PR 29 (`ops.grouped_matmul`) reads
+# them at 82 % of it in a 1024-token chunk of `kimi-vl-a3b` (0.55 ms a call
+# against 0.45) and runs Mixtral's chunk, which is compute-bound, at 50 % of
+# the MXU (2.45 ms against 1.22); the threshold has not been found again
+# against it. PERF.md section 6, PRs 28 and 29.
 SORTED_MIN_TOKENS = 512
 
 
@@ -139,7 +147,8 @@ def moe_sorted(x, expert_idx, weights, gate, up, down, *, layer=None):
     ALL layers, [L, E, ...], multiplied as L*E groups of which only this
     layer's have rows: the grouped product is a custom call, and a layer's
     slice of the stack handed to one is copied out whole first, every layer
-    of every step (the compiler's account, PERF.md section 4)."""
+    of every step (the compiler's account, PERF.md section 4); the kernel's
+    steps are laid over the groups with rows, so the others cost nothing."""
     N, k = expert_idx.shape
     E = gate.shape[-3]
     flat = expert_idx.reshape(N * k)
@@ -152,9 +161,9 @@ def moe_sorted(x, expert_idx, weights, gate, up, down, *, layer=None):
         gate, up, down = (w.reshape(-1, *w.shape[2:]) for w in (gate, up, down))
     xs = x[order // k]                                         # [N*k, D]
     dt = x.dtype
-    h = jax.nn.silu(jax.lax.ragged_dot(xs, gate.astype(dt), sizes)) \
-        * jax.lax.ragged_dot(xs, up.astype(dt), sizes)
-    ys = jax.lax.ragged_dot(h, down.astype(dt), sizes)         # [N*k, D]
+    h = jax.nn.silu(grouped_matmul(xs, gate.astype(dt), sizes, E)) \
+        * grouped_matmul(xs, up.astype(dt), sizes, E)
+    ys = grouped_matmul(h, down.astype(dt), sizes, E)          # [N*k, D]
     back = jnp.zeros_like(order).at[order].set(jnp.arange(N * k, dtype=order.dtype))
     y = ys[back].reshape(N, k, -1).astype(jnp.float32)
     return jnp.sum(y * weights[..., None].astype(jnp.float32), axis=1).astype(dt)

@@ -181,6 +181,39 @@ def test_one_slot_makes_the_third_request_wait_for_two_prefills(tiny_model):
     assert third.scheduled_ts >= first.first_token_ts
 
 
+@pytest.mark.parametrize("kind", ["dense", "experts", "experts_sorting_from_16_tokens"])
+def test_experts_block_counts_the_tokens_of_each_dispatch(tiny_model, monkeypatch, kind):
+    """`stats()["experts"]`: the padded tokens of every prefill call and decode
+    step, by `ops.sorted_pays` of the call; a dense model counts neither."""
+    import dataclasses
+
+    from ray_tpu import ops
+    from ray_tpu.models.transformer import MoEConfig
+
+    cfg, params = tiny_model
+    if kind != "dense":
+        cfg = dataclasses.replace(cfg, moe=MoEConfig(num_experts=4, top_k=2,
+                                                     capacity_factor=None))
+        params = transformer.init(jax.random.PRNGKey(0), cfg)
+    if kind == "experts_sorting_from_16_tokens":
+        monkeypatch.setattr(ops.moe, "SORTED_MIN_TOKENS", 16)
+    eng = _engine((cfg, params))
+    try:
+        out = list(eng.submit(_prompt(0, 40), SamplingParams(max_tokens=4)))
+        st = _quiet_stats(eng)
+    finally:
+        eng.shutdown()
+    assert len(out) == 4
+    # three chunks of 16 (40 tokens), then three decode steps of 4 slots
+    prefill, decode = 3 * 16, st["decode_steps"] * 4
+    assert st["decode_steps"] == 3
+    assert st["experts"] == {
+        "dense": {"tokens_sorted": 0, "tokens_onehot": 0},
+        "experts": {"tokens_sorted": 0, "tokens_onehot": prefill + decode},
+        "experts_sorting_from_16_tokens": {"tokens_sorted": prefill, "tokens_onehot": decode},
+    }[kind]
+
+
 def test_stats_stay_json_plain(tiny_model):
     eng = _engine(tiny_model)
     try:

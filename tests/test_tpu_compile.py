@@ -103,6 +103,22 @@ def _abstract_step_inputs(chip, cfg, slots, max_len, num_pages, page):
                 cfg, slots, max_len, num_pages, page))))
 
 
+def _kernel_calls(text: str) -> list[str]:
+    """The Pallas kernels of a compiled program's text, by `pallas_call` name."""
+    import re
+
+    return [c.split(".")[0] for c in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)]
+
+
+def _prefill_1024(chip, params, cfg):
+    from ray_tpu.models import decoding
+
+    tokens = jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=chip)
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    return decoding.prefill.lower(params, tokens, n, cfg).compile()
+
+
 def test_paged_decode_step_with_kernel_compiles(chip):
     """The engine's decode program at Llama-1B widths, depth cut to two
     layers (the layer scan compiles one body whatever the depth)."""
@@ -149,9 +165,7 @@ def test_latent_decode_step_holds_the_pool_once(chip):
     body whatever the depth): ONE pool of latent rows, [L, pages, 64, 640],
     carried through both layer scans and scattered in place; the absorbed
     attention is the `ragged_latent_attention` kernel, once a scan."""
-    import re
-
-    from ray_tpu.models import decoding, decoding_paged, kimi_vl_config
+    from ray_tpu.models import decoding_paged, kimi_vl_config
 
     cfg = kimi_vl_config("a3b", n_layers=3, param_dtype=jnp.bfloat16, max_seq_len=16640)
     params, state = _abstract_step_inputs(chip, cfg, 32, 16640, 3328, 64)
@@ -165,18 +179,36 @@ def test_latent_decode_step_holds_the_pool_once(chip):
     # nothing pool-sized, and no layer's experts copied out of the stack for
     # the grouped products (0.37 GB when the scan sliced them; 2.3 MB now)
     assert m.temp_size_in_bytes < 64 * 2**20
-    calls = [c.split(".")[0] for c in re.findall(
-        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", compiled.as_text())]
     # one a layer scan; 32 rows take the one-hot dispatch: no grouped product
-    assert calls == ["ragged_latent_attention"] * 2
-    # a 1024-token prefill sorts its slots: the grouped products multiply the
-    # stack's experts where they lie (sliced by the scan, one layer's
-    # [64, 2048, 1408] was copied out for each: 369,098,752 bytes)
-    tokens = jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=chip)
-    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
-    prefill = decoding.prefill.lower(params, tokens, n, cfg).compile()
-    assert "ragged-dot" in prefill.as_text()
+    assert _kernel_calls(compiled.as_text()) == ["ragged_latent_attention"] * 2
+    # a 1024-token prefill sorts its slots: the `grouped_matmul` kernel (gate,
+    # up, down: three a layer scan) multiplies the stack's experts where they
+    # lie (sliced by the scan, one layer's [64, 2048, 1408] was copied out for
+    # each: 369,098,752 bytes), with no padded copy of the sorted rows
+    prefill = _prefill_1024(chip, params, cfg)
+    assert _kernel_calls(prefill.as_text()).count("grouped_matmul") == 3
+    assert "ragged-dot" not in prefill.as_text()
     assert prefill.memory_analysis().temp_size_in_bytes < 64 * 2048 * 1408 * 2
+
+
+def test_mixtral_prefill_chunk_multiplies_the_experts_where_they_lie(chip):
+    """The 1,024-token prefill of `mixtral-8x7b.doc-saturated` (4 layers,
+    published widths): three `grouped_matmul` kernels in the layer scan, at
+    K 4,096 and 14,336, and temporaries under one layer's experts (no slice of
+    the stack, no padded copy); its decode step holds no grouped product."""
+    from ray_tpu.models import decoding_paged, mixtral_config
+    from ray_tpu.models.transformer import MoEConfig
+
+    cfg = mixtral_config("8x7b", n_layers=4, param_dtype=jnp.bfloat16, max_seq_len=8320,
+                         moe=MoEConfig(num_experts=8, top_k=2, capacity_factor=4.0))
+    params, state = _abstract_step_inputs(chip, cfg, 32, 8320, 1024, 64)
+    prefill = _prefill_1024(chip, params, cfg)
+    assert _kernel_calls(prefill.as_text()).count("grouped_matmul") == 3
+    assert "ragged-dot" not in prefill.as_text()
+    assert prefill.memory_analysis().temp_size_in_bytes < 8 * 4096 * 14336 * 2
+    step = decoding_paged.decode_step_paged_ragged.lower(
+        params, state, cfg, 32, True).compile().as_text()
+    assert "grouped_matmul" not in step and "ragged-dot" not in step
 
 
 def test_kernel_names_reach_the_compiled_program(chip):
