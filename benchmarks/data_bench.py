@@ -18,7 +18,7 @@ bookkeeping, and the pool liveness sweep. None of that should be
 visible on a pipeline where nothing fails.
 
 Merges the ``fault_tolerance`` section into DATA_BENCH.json via
-``merge_artifact`` (the llm_load_bench discipline) — data_train_bench's
+``merge_artifact`` — data_train_bench's
 ``results`` section survives a rerun of this script and vice versa.
 
 Exit status is the assertion: nonzero when overhead exceeds the bar

@@ -43,8 +43,7 @@ import urllib.request
 TRAIN = dict(family="gpt2", size="774m", model_kwargs={}, batch=4, seq=1024,
              steps=6, lr=3e-4, meshes=[{}])
 SERVE = dict(family="llama", model_id="1b", model_kwargs={"max_seq_len": 2048},
-             engine_kwargs={"kv_layout": "paged", "page_size": 64,
-                            "max_slots": 16, "max_len": 2048},
+             engine_kwargs={"page_size": 64, "max_slots": 16, "max_len": 2048},
              prompt_tokens=(64, 500, 1500), max_tokens=16)
 # the shapes the two phases run the kernels at: GPT-2 774M attention at
 # s1024, and Llama-1B decode (8 kv heads x 4 query heads, d_head 64, page 64)
@@ -671,7 +670,7 @@ def smoke_one_chip(seed: int) -> dict:
     say("serve", model=f"{SERVE['family']}-{SERVE['model_id']}",
         phase_s=time.perf_counter() - t0, **served,
         **{k: engine[k] for k in (
-            "device", "worker_chips", "decode_attn", "kv_layout", "page_size",
+            "device", "worker_chips", "decode_attn", "page_size",
             "max_slots", "decode_steps", "device_memory", "compile_cache")})
     check_serve({**served, "engine": engine}, SERVE)
     check(train["device"] == engine["device"],

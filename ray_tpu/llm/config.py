@@ -72,7 +72,7 @@ class LLMConfig:
     model_family: str = "llama"
     model_kwargs: dict = field(default_factory=dict)
     engine_kwargs: dict = field(default_factory=dict)  # TPUEngine keywords:
-                                                       # max_slots, max_len, kv_layout, ...
+                                                       # max_slots, max_len, page_size, ...
     deployment_config: dict = field(default_factory=dict)  # serve options
     # "TPU": every process hosting this config's engine is bound to a chip
     # (replica_actor_options) and the engine refuses to start on any other

@@ -1,8 +1,9 @@
 """TPUEngine: continuous-batching inference on one chip/mesh.
 
-The scheduler thread owns the device state and runs the classic
-continuous-batching loop (admit → prefill into a free slot → global
-decode_step → emit/eject), all on static shapes:
+The scheduler thread owns the device state (a paged KV cache: one page pool
+all rows share, models/decoding_paged.py) and runs the classic
+continuous-batching loop (admit → prefill into a free slot's pages → global
+decode step → emit/eject), all on static shapes:
 
 - prompt lengths are padded to power-of-two buckets → a handful of prefill
   compilations, cached forever,
@@ -12,12 +13,13 @@ decode_step → emit/eject), all on static shapes:
 
 (reference capability: vLLM engine wrapped at
 llm/_internal/serve/engines/vllm/vllm_engine.py:114; TPU design is
-greenfield per SURVEY.md §7 — static-shape bucketing + slot cache instead of
-paged CUDA kernels.)
+greenfield per SURVEY.md §7 — static-shape bucketing and a ragged Pallas
+decode kernel instead of paged CUDA kernels.)
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import itertools
@@ -35,6 +37,7 @@ from ray_tpu import ops
 from ray_tpu._private import accelerators
 from ray_tpu.exceptions import DeadlineExceededError, RequestCancelledError
 from ray_tpu.models import decoding
+from ray_tpu.models import decoding_paged as dp
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.util import tracing
 
@@ -88,12 +91,6 @@ class _Request:
     trace_ctx: dict | None = None
     pf_chunks: int = 0        # prefill chunks run (chunked prefill)
     prefix_reused: int = 0    # prompt tokens served by the prefix cache
-    # full token history (prompt + emitted) for the n-gram draft proposer,
-    # plus an incremental index: trailing-ngram tuple → (latest, previous)
-    # continuation-start positions, so proposal is O(1) per step instead of
-    # rescanning the history (which is quadratic over a long generation)
-    history: list = dataclasses.field(default_factory=list)
-    ngram_index: dict | None = None
     # multi-LoRA: bank index this request decodes with (0 = base model)
     lora_idx: int = 0
     lora_released: bool = False
@@ -144,8 +141,7 @@ def _iter_request(req: "_Request"):
 # fetch; dispatch is asynchronous, so device time queued in one phase is
 # paid in the next wait, whichever program it belongs to.
 LOOP_PHASES = ("parked", "sweep", "admit", "admit_wait", "streams",
-               "prefill", "prefill_wait", "decode", "decode_wait", "emit",
-               "spec")
+               "prefill", "prefill_wait", "decode", "decode_wait", "emit")
 HOST_PHASES = tuple(p for p in LOOP_PHASES
                     if p != "parked" and not p.endswith("_wait"))
 
@@ -248,34 +244,25 @@ def _shard_params_tp(params, mesh):
 
 
 def _shard_state_tp(state, mesh):
-    """KV caches split on the kv-head dim; bookkeeping replicated."""
+    """Page pools split on the kv-head dim; bookkeeping replicated."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     axis = mesh.axis_names[0]
-    specs = {}
-    for k, v in state.items():
-        if k in ("k", "v"):          # [L, slots, S, Hkv, Dh]
-            specs[k] = P(None, None, None, axis)
-        elif k in ("kp", "vp"):      # [L, pages, P, Hkv, Dh]
-            specs[k] = P(None, None, None, axis)
-        else:
-            specs[k] = P()
-    return {k: jax.device_put(v, NamedSharding(mesh, specs[k]))
+    pool = P(None, None, None, axis)     # [L, pages, P, Hkv, Dh]
+    return {k: jax.device_put(
+                v, NamedSharding(mesh, pool if k in ("kp", "vp") else P()))
             for k, v in state.items()}
 
 
 class TPUEngine:
     def __init__(self, cfg: TransformerConfig, params: Any, *,
                  max_slots: int = 8, max_len: int | None = None,
-                 min_bucket: int = 32, seed: int = 0,
-                 kv_layout: str = "slot", page_size: int = 64,
+                 min_bucket: int = 32, seed: int = 0, page_size: int = 64,
                  num_pages: int | None = None,
                  max_prefills_per_step: int = 2,
                  enable_prefix_cache: bool = False,
                  prefill_chunk: int | None = None,
-                 speculative_k: int = 0, ngram_size: int = 2,
-                 mesh=None, max_loras: int = 0, lora_rank: int = 8,
-                 attn_impl: str = "auto"):
+                 mesh=None, max_loras: int = 0, lora_rank: int = 8):
         accelerators.compile_cache_counts()  # start counting before compiling
         self.cfg = cfg
         self.max_len = max_len or cfg.max_seq_len
@@ -285,50 +272,48 @@ class TPUEngine:
                 f"max_seq_len {cfg.max_seq_len} (rope/pos tables are sized "
                 "by the model config)")
         self.max_slots = max_slots
-        if kv_layout not in ("slot", "paged"):
-            raise ValueError(f"kv_layout must be 'slot' or 'paged', got {kv_layout!r}")
-        self.kv_layout = kv_layout
         if cfg.mla or cfg.n_dense_layers:
             # what is not carried to the latent cache and to two kinds of
             # layer in one stack: refused here, not at the first request
             kind = ("latent attention (kv_lora_rank)" if cfg.mla
                     else "leading dense layers")
-            for on, what in ((kv_layout != "paged", "kv_layout='slot'"),
-                             (mesh is not None, "a tensor-parallel mesh"),
-                             (speculative_k, "speculative_k"),
+            for on, what in ((mesh is not None, "a tensor-parallel mesh"),
                              (max_loras, "max_loras")):
                 if on:
                     raise ValueError(
-                        f"a model with {kind} is served from the paged "
-                        f"layout on one chip, without {what}: the slot "
-                        "cache, the sharding of the page pool over kv "
-                        "heads, the verify step and the LoRA bank are built "
-                        "for per-head K and V over one kind of layer")
-        if kv_layout == "paged":
-            if page_size <= 0 or (page_size & (page_size - 1)):
-                raise ValueError("page_size must be a positive power of two")
-            if self.max_len % page_size:
+                        f"a model with {kind} is served on one chip, without "
+                        f"{what}: the sharding of the page pool over kv "
+                        "heads and the LoRA bank are built for per-head K "
+                        "and V over one kind of layer")
+        if max_loras and (enable_prefix_cache or prefill_chunk is not None):
+            raise ValueError(
+                "max_loras cannot be combined with enable_prefix_cache or "
+                "prefill_chunk: a cached block's hash does not name the "
+                "adapter that wrote it, and the continuation prefill "
+                "(prefill_with_prefix) applies none")
+        if page_size <= 0 or (page_size & (page_size - 1)):
+            raise ValueError("page_size must be a positive power of two")
+        if self.max_len % page_size:
+            raise ValueError(
+                f"max_len {self.max_len} must be a multiple of "
+                f"page_size {page_size} (buckets reshape into whole pages)")
+        min_bucket = max(min_bucket, page_size)
+        if min_bucket % page_size:
+            raise ValueError(
+                f"min_bucket {min_bucket} must be a multiple of "
+                f"page_size {page_size} (every prompt bucket reshapes "
+                f"into whole pages)")
+        if prefill_chunk is not None:
+            if (prefill_chunk < min_bucket
+                    or prefill_chunk % page_size
+                    or bucket_for(prefill_chunk, min_bucket, self.max_len)
+                    != prefill_chunk):
                 raise ValueError(
-                    f"max_len {self.max_len} must be a multiple of "
-                    f"page_size {page_size} (buckets reshape into whole pages)")
-            min_bucket = max(min_bucket, page_size)
-            if min_bucket % page_size:
-                raise ValueError(
-                    f"min_bucket {min_bucket} must be a multiple of "
-                    f"page_size {page_size} (every prompt bucket reshapes "
-                    f"into whole pages)")
-            if prefill_chunk is not None:
-                if (prefill_chunk < min_bucket
-                        or prefill_chunk % page_size
-                        or bucket_for(prefill_chunk, min_bucket,
-                                      max_len or cfg.max_seq_len)
-                        != prefill_chunk):
-                    raise ValueError(
-                        f"prefill_chunk {prefill_chunk} must be one of the "
-                        f"engine's bucket sizes (min_bucket {min_bucket} "
-                        f"doublings) and a multiple of page_size "
-                        f"{page_size} — a non-bucket chunk would pad past "
-                        "its own page span and corrupt neighboring pages")
+                    f"prefill_chunk {prefill_chunk} must be one of the "
+                    f"engine's bucket sizes (min_bucket {min_bucket} "
+                    f"doublings) and a multiple of page_size "
+                    f"{page_size} — a non-bucket chunk would pad past "
+                    "its own page span and corrupt neighboring pages")
         self.buckets = []
         b = min_bucket
         while b < self.max_len:
@@ -336,7 +321,7 @@ class TPUEngine:
             b *= 2
         self.buckets.append(self.max_len)
         # multi-chip serving: tensor-parallel sharding over a 1-axis mesh —
-        # params' head/ff dims and the KV caches' kv-head dim are split
+        # params' head/ff dims and the page pools' kv-head dim are split
         # across chips; XLA inserts the collectives (reference capability:
         # vLLM tensor_parallel_size via PG bundles, vllm_models.py:215 —
         # here it's jax.sharding over ICI instead of NCCL)
@@ -344,86 +329,46 @@ class TPUEngine:
         if mesh is not None:
             params = _shard_params_tp(params, mesh)
         self.params = params
-        if kv_layout == "paged":
-            from ray_tpu.models import decoding_paged as dp
-
-            self._dp = dp
-            self.page_size = page_size
-            self.max_pages_per_seq = -(-self.max_len // page_size)
-            # default pool = full reservation (+1 scratch); pass num_pages
-            # lower to oversubscribe HBM against short real sequences
-            self.num_pages = num_pages or (max_slots * self.max_pages_per_seq + 1)
-            self.state = dp.init_paged_state(
-                cfg, max_slots, self.max_len, self.num_pages, page_size)
-            self._free_pages = list(range(1, self.num_pages))  # 0 = scratch
-            self._slot_pages: dict[int, list] = {}
-            # hash-block prefix cache over the SAME page pool (reference
-            # capability: vLLM automatic prefix caching): chain-hashed
-            # full prompt blocks map to pages still resident in HBM; a
-            # repeated prefix skips its share of prefill compute entirely.
-            self.enable_prefix_cache = bool(enable_prefix_cache)
-            import collections as _collections
-
-            self._prefix_cache: _collections.OrderedDict = \
-                _collections.OrderedDict()       # block-chain hash → page id
-            self._page_refs: dict[int, int] = {}  # shared page → live users
-            self._page_hash: dict[int, bytes] = {}  # reverse map (eviction)
-            self._slot_shared: dict[int, list] = {}  # slot → shared pages
-            self.prefix_hits = 0       # requests that reused ≥1 block
-            self.prefix_misses = 0
-            self.prefix_tokens_reused = 0
-            # chunked prefill (reference capability: vLLM chunked prefill):
-            # long prompts prefill in fixed chunks interleaved with decode
-            # steps so running requests keep emitting during a long
-            # admission instead of stalling a full prompt-bucket compile
-            self.prefill_chunk = prefill_chunk
-            self._prefilling: list = []  # requests mid-chunked-prefill
-            self.prefill_chunks_run = 0
-            # decode attention: "ragged" = one ragged-paged-attention
-            # launch over the batch's live page tables (ops/
-            # ragged_paged_attention.py — Pallas kernel on TPU, the
-            # bit-consistent pure-JAX reference elsewhere); "gather" =
-            # the legacy full-block-table gather + masked softmax
-            if attn_impl == "auto":
-                attn_impl = "ragged"
-            if attn_impl not in ("ragged", "gather"):
-                raise ValueError(
-                    f"attn_impl must be 'auto', 'ragged' or 'gather', "
-                    f"got {attn_impl!r}")
-            self.attn_impl = attn_impl
-            # the Pallas kernel needs an unsharded pool (the reference is
-            # plain XLA ops, so tp-sharded states keep the ragged path)
-            self._ragged_kernel = (attn_impl == "ragged" and mesh is None
-                                   and jax.default_backend() == "tpu")
-        else:
-            self.attn_impl = "gather"
-            self._ragged_kernel = False
-            self.enable_prefix_cache = False
-            self.prefill_chunk = None
-            self._prefilling = []
-            if enable_prefix_cache:
-                raise ValueError(
-                    "enable_prefix_cache requires kv_layout='paged'")
-            if prefill_chunk is not None:
-                raise ValueError("prefill_chunk requires kv_layout='paged'")
-            self.state = decoding.init_decode_state(cfg, max_slots, self.max_len)
+        self.page_size = page_size
+        self.max_pages_per_seq = self.max_len // page_size
+        # default pool = full reservation (+1 scratch); pass num_pages
+        # lower to oversubscribe HBM against short real sequences
+        self.num_pages = num_pages or (max_slots * self.max_pages_per_seq + 1)
+        self.state = dp.init_paged_state(
+            cfg, max_slots, self.max_len, self.num_pages, page_size)
         if mesh is not None:
             self.state = _shard_state_tp(self.state, mesh)
-        # speculative decoding (reference capability: vLLM prompt-lookup /
-        # [ngram] speculation): propose `speculative_k` draft tokens per
-        # row by matching the trailing n-gram against the request's own
-        # history, verify all of them in ONE multi-token decode step
-        # (models/decoding.py verify_step), emit the accepted prefix + one
-        # corrected token. Model-free drafts; exact sampling semantics.
-        self.speculative_k = int(speculative_k)
-        self.ngram_size = max(1, int(ngram_size))
-        if self.speculative_k:
-            if kv_layout != "slot":
-                raise ValueError(
-                    "speculative_k requires kv_layout='slot' (the paged "
-                    "verify kernel is not implemented)")
-            if self.speculative_k < 1 or self.speculative_k > 16:
-                raise ValueError("speculative_k must be in [1, 16]")
+        self._free_pages = list(range(1, self.num_pages))  # 0 = scratch
+        self._slot_pages: dict[int, list] = {}
+        # hash-block prefix cache over the SAME page pool (reference
+        # capability: vLLM automatic prefix caching): chain-hashed
+        # full prompt blocks map to pages still resident in HBM; a
+        # repeated prefix skips its share of prefill compute entirely.
+        self.enable_prefix_cache = bool(enable_prefix_cache)
+        self._prefix_cache: collections.OrderedDict = \
+            collections.OrderedDict()        # block-chain hash → page id
+        self._page_refs: dict[int, int] = {}  # shared page → live users
+        self._page_hash: dict[int, bytes] = {}  # reverse map (eviction)
+        self._slot_shared: dict[int, list] = {}  # slot → shared pages
+        self.prefix_hits = 0       # requests that reused ≥1 block
+        self.prefix_misses = 0
+        self.prefix_tokens_reused = 0
+        # chunked prefill (reference capability: vLLM chunked prefill):
+        # long prompts prefill in fixed chunks interleaved with decode
+        # steps so running requests keep emitting during a long
+        # admission instead of stalling a full prompt-bucket compile
+        self.prefill_chunk = prefill_chunk
+        self._prefilling: list = []  # requests mid-chunked-prefill
+        self.prefill_chunks_run = 0
+        # decode attention is one ragged-paged-attention launch over the
+        # batch's live page tables (ops/ragged_paged_attention.py): the
+        # Pallas kernel where the code can see a TPU and an unsharded pool,
+        # the bit-consistent pure-JAX reference elsewhere (plain XLA ops,
+        # which a tensor-parallel mesh partitions)
+        self._ragged_kernel = (mesh is None
+                               and jax.default_backend() == "tpu")
+        self._decode_attn = "ragged_" + ("kernel" if self._ragged_kernel
+                                         else "reference")
         # multi-LoRA serving (reference capability: LoRA adapters with
         # dynamic loading on serve multiplexing —
         # python/ray/llm/_internal/serve/utils/lora_serve_utils.py; here
@@ -432,15 +377,8 @@ class TPUEngine:
         self.max_loras = int(max_loras)
         self.lora_rank = int(lora_rank)
         self.lora_bank = None
+        self._slot_lora = None
         if self.max_loras:
-            if kv_layout != "slot":
-                raise ValueError(
-                    "max_loras requires kv_layout='slot' (the paged decode "
-                    "kernel has no LoRA gather yet)")
-            if self.speculative_k:
-                raise ValueError(
-                    "max_loras and speculative_k cannot be combined (the "
-                    "verify kernel has no LoRA gather)")
             self.lora_bank = decoding.init_lora_bank(cfg, self.max_loras,
                                                      self.lora_rank)
             self._lora_free = list(range(1, self.max_loras + 1))
@@ -464,10 +402,6 @@ class TPUEngine:
         # layers took (stats()["experts"]); a dense model counts neither
         self.expert_tokens_sorted = 0
         self.expert_tokens_onehot = 0
-        self.spec_steps = 0
-        self.spec_slot_steps = 0   # sum of active slots over verify steps
-        self.spec_drafted = 0
-        self.spec_accepted = 0
         # device-resident per-row sampling params: updated only on admit,
         # not rebuilt/re-uploaded every decode step
         self._temps = jnp.zeros((max_slots,), jnp.float32)
@@ -481,7 +415,7 @@ class TPUEngine:
         self._free = list(range(max_slots))
         self._by_slot: dict[int, _Request] = {}
         self._waiting: queue.SimpleQueue = queue.SimpleQueue()
-        self._backlog: list = []  # paged: admitted-later queue (page pressure)
+        self._backlog: list = []  # admitted-later queue (page pressure)
         self._streaming: list = []  # slot granted, pages still streaming in
         self._rid = itertools.count()
         self._work = threading.Event()
@@ -519,23 +453,22 @@ class TPUEngine:
             self._phase_queue = self._phase_prefill = None
             self._phase_admit = self._phase_gap = None
         # per-decode-step wall time (the step's dispatches + the fetch of
-        # its tokens, from the phase clock's reads) split by attention
-        # impl: the ragged-vs-gather attribution the decode microbench and
-        # dashboards key on
+        # its tokens, from the phase clock's reads), labelled with the
+        # attention code that runs (stats()["decode_attn"])
         self._step_obs = None
         try:
             from ray_tpu.serve import request_context as _rc2
             from ray_tpu.util import metrics as met
 
-            if self.kv_layout == "paged" and _rc2.metrics_enabled():
+            if _rc2.metrics_enabled():
                 h = met.get_or_create(
                     met.Histogram, "ray_tpu_llm_decode_step_seconds",
                     "paged decode step wall time (device step + sampling "
-                    "sync) by attention impl (ragged|gather)",
+                    "sync) by attention code (ragged_kernel|ragged_reference)",
                     boundaries=[0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
                                 0.05, 0.1, 0.25, 0.5, 1.0],
                     tag_keys=("impl",))
-                self._step_obs = h.bind({"impl": self.attn_impl})
+                self._step_obs = h.bind({"impl": self._decode_attn})
         except Exception:  # pragma: no cover — metrics must never gate boot
             self._step_obs = None
         self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -554,23 +487,27 @@ class TPUEngine:
                 f"on {backend!r}: its worker was bound no chip (deploy "
                 "through build_openai_app / ray_actor_options num_tpus, or "
                 "set accelerator_type=None for a host-only engine)")
-        cfg, params = llm_config.build_model()
         ek = dict(llm_config.engine_kwargs)
+        # options that went with the slot layout, the gather step and n-gram
+        # speculation; configuration files still say kv_layout "paged"
+        for key in ("kv_layout", "attn_impl", "speculative_k", "ngram_size"):
+            if key in ek and (key, ek[key]) != ("kv_layout", "paged"):
+                raise ValueError(
+                    f"engine_kwargs[{key!r}]={ek[key]!r}: the option was "
+                    "removed; the engine serves from the paged KV cache "
+                    "with the ragged decode step and does not speculate")
+        cfg, params = llm_config.build_model()
         lora_cfg = getattr(llm_config, "lora_config", None)
         return cls(cfg, params,
                    max_slots=ek.get("max_slots", 8),
                    max_len=ek.get("max_len", cfg.max_seq_len),
                    min_bucket=ek.get("min_bucket", 32),
                    seed=ek.get("seed", 0),
-                   kv_layout=ek.get("kv_layout", "slot"),
                    page_size=ek.get("page_size", 64),
                    num_pages=ek.get("num_pages"),
                    max_prefills_per_step=ek.get("max_prefills_per_step", 2),
                    enable_prefix_cache=ek.get("enable_prefix_cache", False),
                    prefill_chunk=ek.get("prefill_chunk"),
-                   speculative_k=ek.get("speculative_k", 0),
-                   ngram_size=ek.get("ngram_size", 2),
-                   attn_impl=ek.get("attn_impl", "auto"),
                    mesh=ek.get("mesh"),
                    max_loras=ek.get(
                        "max_loras",
@@ -677,16 +614,11 @@ class TPUEngine:
                deadline_ts: float = 0.0) -> _Request:
         self._check_alive()
         params = params or SamplingParams()
-        if params.guided is not None:
-            if self.speculative_k:
-                raise ValueError(
-                    "guided decoding and speculative decoding cannot be "
-                    "combined (drafts would have to be FSM-checked per "
-                    "position; build the engine with speculative_k=0)")
-            if params.guided.vocab_size != self.cfg.vocab_size:
-                raise ValueError(
-                    f"guided FSM vocab {params.guided.vocab_size} != model "
-                    f"vocab {self.cfg.vocab_size}")
+        if (params.guided is not None
+                and params.guided.vocab_size != self.cfg.vocab_size):
+            raise ValueError(
+                f"guided FSM vocab {params.guided.vocab_size} != model "
+                f"vocab {self.cfg.vocab_size}")
         token_ids = list(token_ids)
         if not token_ids:
             raise ValueError("empty prompt: at least one token is required")
@@ -694,15 +626,14 @@ class TPUEngine:
         if limit <= 0:
             raise ValueError("max_tokens leaves no room for the prompt")
         token_ids = token_ids[-limit:]
-        if self.kv_layout == "paged":
-            need = self._pages_needed(len(token_ids),
-                                      self._bucket(len(token_ids)),
-                                      params.max_tokens)
-            if need > self.num_pages - 1:  # page 0 is scratch
-                raise ValueError(
-                    f"request needs {need} KV pages but the pool only has "
-                    f"{self.num_pages - 1}; raise num_pages or shrink "
-                    f"prompt/max_tokens")
+        need = self._pages_needed(len(token_ids),
+                                  self._bucket(len(token_ids)),
+                                  params.max_tokens)
+        if need > self.num_pages - 1:  # page 0 is scratch
+            raise ValueError(
+                f"request needs {need} KV pages but the pool only has "
+                f"{self.num_pages - 1}; raise num_pages or shrink "
+                f"prompt/max_tokens")
         lora_idx = 0
         if lora is not None:
             if self.lora_bank is None:
@@ -716,8 +647,7 @@ class TPUEngine:
                                    f"(loaded: {sorted(self._lora_ids)})")
                 lora_idx = self._lora_ids[lora]
                 self._lora_refs[lora_idx] += 1
-        req = _Request(next(self._rid), token_ids, params,
-                       history=list(token_ids), lora_idx=lora_idx,
+        req = _Request(next(self._rid), token_ids, params, lora_idx=lora_idx,
                        deadline_ts=float(deadline_ts or 0.0))
         req.submitted_ts = time.time()
         req.trace_ctx = tracing.current_context()
@@ -739,8 +669,8 @@ class TPUEngine:
           prefix (the legacy object-plane handoff);
         - page-granular: k_pages/v_pages are ordered lists of
           [L, page_size, Hkv, Dh] pages (the shm transfer plane's unit).
-          On a paged engine each page is adopted into the slot pool
-          directly — no whole-bucket array is ever assembled;
+          Each page is adopted into the page pool directly — no
+          whole-bucket array is ever assembled;
         - streamed: kv_stream is a kv_transfer.KVPageStream the transfer
           plane is still feeding. The slot and its pages are granted NOW
           and each page is adopted the moment it arrives — the decode
@@ -763,7 +693,7 @@ class TPUEngine:
                 raise ValueError(
                     "pass kv_stream alone, not with k/v or k_pages/v_pages")
             P = int(kv_stream.page_size)
-            if self.kv_layout == "paged" and P != self.page_size:
+            if P != self.page_size:
                 raise ValueError(
                     f"streamed page size {P} != engine page_size "
                     f"{self.page_size}: prefill and decode pools must agree")
@@ -779,7 +709,7 @@ class TPUEngine:
             P = k_pages[0].shape[1]
             if any(p.shape[1] != P for p in list(k_pages) + list(v_pages)):
                 raise ValueError("transferred pages have mixed page sizes")
-            if self.kv_layout == "paged" and P != self.page_size:
+            if P != self.page_size:
                 raise ValueError(
                     f"transferred page size {P} != engine page_size "
                     f"{self.page_size}: prefill and decode pools must agree")
@@ -794,17 +724,16 @@ class TPUEngine:
             raise ValueError(
                 f"transferred prefix bucket {bucket} exceeds engine "
                 f"max_len {self.max_len}")
-        if self.kv_layout == "paged":
-            if bucket % self.page_size:
-                raise ValueError(
-                    f"transferred prefix bucket {bucket} is not a "
-                    f"multiple of page_size {self.page_size}: configure the "
-                    f"prefill server with min_bucket >= page_size")
-            need = self._pages_needed(int(length), bucket, params.max_tokens)
-            if need > self.num_pages - 1:
-                raise ValueError(
-                    f"request needs {need} KV pages but the pool only has "
-                    f"{self.num_pages - 1}")
+        if bucket % self.page_size:
+            raise ValueError(
+                f"transferred prefix bucket {bucket} is not a "
+                f"multiple of page_size {self.page_size}: configure the "
+                f"prefill server with min_bucket >= page_size")
+        need = self._pages_needed(int(length), bucket, params.max_tokens)
+        if need > self.num_pages - 1:
+            raise ValueError(
+                f"request needs {need} KV pages but the pool only has "
+                f"{self.num_pages - 1}")
         if int(length) + params.max_tokens > self.max_len:
             raise ValueError(
                 f"prefix length {int(length)} + max_tokens {params.max_tokens} "
@@ -906,7 +835,7 @@ class TPUEngine:
         last_pos = min(prompt_len + max_tokens, self.max_len - 1)
         return max(bucket // self.page_size, last_pos // self.page_size + 1)
 
-    # ---------------------------------------------------- prefix cache (paged)
+    # ---------------------------------------------------------- prefix cache
 
     def _block_hashes(self, tokens: list) -> list:
         """Chain hashes of the prompt's FULL page_size blocks: h_i commits
@@ -1082,49 +1011,32 @@ class TPUEngine:
                 self._phase_prefill.observe(took)
 
     def _insert(self, req: _Request, slot: int, kv, length: int, first_token):
-        """Layout-dispatching sequence insertion. Returns False when the
-        paged pool can't host the sequence right now (caller backlogs)."""
-        if self.kv_layout == "paged":
-            bucket = kv["k"].shape[1]
-            need = self._pages_needed(length, bucket, req.params.max_tokens)
-            pages = self._grant_pages(need)
-            if pages is None:
-                return False
-            self._slot_pages[slot] = pages
-            padded_pages = np.zeros((self.max_pages_per_seq,), np.int32)
-            padded_pages[:need] = pages
-            self.state = self._dp.insert_sequence_paged(
-                self.state, slot, kv, jnp.int32(length),
-                jnp.asarray(first_token, jnp.int32),
-                jnp.asarray(padded_pages), self.cfg)
-        else:
-            self.state = decoding.insert_sequence(
-                self.state, slot, kv, jnp.int32(length),
-                jnp.asarray(first_token, jnp.int32), self.cfg)
+        """Grant the sequence's pages, write its prefilled kv into them and
+        activate the row. Returns False when the pool can't host the
+        sequence right now (caller backlogs)."""
+        bucket = kv["k"].shape[1]
+        need = self._pages_needed(length, bucket, req.params.max_tokens)
+        pages = self._grant_pages(need)
+        if pages is None:
+            return False
+        self._slot_pages[slot] = pages
+        self.state = dp.insert_sequence_paged(
+            self.state, slot, kv, jnp.int32(length),
+            jnp.asarray(first_token, jnp.int32),
+            jnp.asarray(self._granted_block_row(slot)), self.cfg)
         self._bind_slot(req, slot, length)
         return True
 
     def _insert_transferred(self, req: _Request, slot: int) -> bool:
         """PD admission: insert a kv_pack that arrived from a prefill
-        server. Page-granular packs adopt pages straight into the paged
-        pool; whole-array packs (or pages landing on a slot-layout engine)
-        take the legacy _insert path. Returns False when the pool can't
-        host the sequence right now (caller backlogs)."""
+        server. Page-granular packs adopt pages straight into the pool;
+        whole-array packs go through _insert. Returns False when the pool
+        can't host the sequence right now (caller backlogs)."""
         pack = req.kv_pack
         if "k_pages" in pack:
-            if self.kv_layout == "paged":
-                return self._insert_pages(req, slot, pack)
-            # slot layout has no page pool: stitch the bucket back together
-            # (host copy — the paged decode pool is the production PD path)
-            kv = {"k": np.concatenate([np.asarray(p)
-                                       for p in pack["k_pages"]], axis=1),
-                  "v": np.concatenate([np.asarray(p)
-                                       for p in pack["v_pages"]], axis=1)}
-        else:
-            kv = {"k": pack["k"], "v": pack["v"]}
-        ktmpl = self.state["k" if self.kv_layout == "slot" else "kp"]
-        kv = {"k": jnp.asarray(kv["k"], ktmpl.dtype),
-              "v": jnp.asarray(kv["v"], ktmpl.dtype)}
+            return self._insert_pages(req, slot, pack)
+        dt = self.state["kp"].dtype
+        kv = {"k": jnp.asarray(pack["k"], dt), "v": jnp.asarray(pack["v"], dt)}
         return self._insert(req, slot, kv, pack["length"],
                             pack["first_token"])
 
@@ -1146,16 +1058,14 @@ class TPUEngine:
         # prefix pages land in block-table order; the tail of `pages`
         # (granted up front, like every admission) hosts the generation
         for pid, kp, vp in zip(pages, k_pages, v_pages):
-            self.state = self._dp.write_kv_pages(
+            self.state = dp.write_kv_pages(
                 self.state,
                 {"k": jnp.asarray(np.asarray(kp), dt),
                  "v": jnp.asarray(np.asarray(vp), dt)},
                 jnp.asarray(np.asarray([pid], np.int32)))
-        block_row = np.zeros((self.max_pages_per_seq,), np.int32)
-        block_row[:need] = pages
-        self.state = self._dp.activate_slot(
-            self.state, slot, jnp.asarray(block_row), jnp.int32(length),
-            jnp.asarray(pack["first_token"], jnp.int32))
+        self.state = dp.activate_slot(
+            self.state, slot, jnp.asarray(self._granted_block_row(slot)),
+            jnp.int32(length), jnp.asarray(pack["first_token"], jnp.int32))
         self._bind_slot(req, slot, length)
         return True
 
@@ -1170,14 +1080,13 @@ class TPUEngine:
         False when the page pool can't host the sequence yet (caller
         backlogs; arrived pages keep buffering host-side in the stream)."""
         st = req.kv_stream
-        if self.kv_layout == "paged":
-            need = self._pages_needed(req.kv_pack["length"],
-                                      st.n_pages * self.page_size,
-                                      req.params.max_tokens)
-            pages = self._grant_pages(need)
-            if pages is None:
-                return False
-            self._slot_pages[slot] = pages
+        need = self._pages_needed(req.kv_pack["length"],
+                                  st.n_pages * self.page_size,
+                                  req.params.max_tokens)
+        pages = self._grant_pages(need)
+        if pages is None:
+            return False
+        self._slot_pages[slot] = pages
         req.slot = slot
         req.pf_done = 0
         self._scheduled(req)
@@ -1198,8 +1107,7 @@ class TPUEngine:
         a per-REQUEST error; every other request keeps serving."""
         if req in self._streaming:
             self._streaming.remove(req)
-        if self.kv_layout == "paged":
-            self._free_pages.extend(self._slot_pages.pop(req.slot, ()))
+        self._free_pages.extend(self._slot_pages.pop(req.slot, ()))
         self._free.append(req.slot)
         self._lora_release(req)
         if not isinstance(err, BaseException):
@@ -1223,16 +1131,14 @@ class TPUEngine:
                 ready = st.take_ready()
                 if ready:
                     progressed = True
-                    if (self.kv_layout == "paged" and req.pf_done == 0
-                            and len(ready) == st.n_pages):
+                    ready.sort(key=lambda t: t[0])
+                    dt = self.state["kp"].dtype
+                    if req.pf_done == 0 and len(ready) == st.n_pages:
                         # the whole transfer beat the scheduler here (fast
                         # sender / short prompt — the common case): write
                         # + activate in the ONE dispatch the non-streamed
                         # admission pays, instead of write_kv_pages +
                         # activate_slot
-                        ready.sort(key=lambda t: t[0])
-                        dt = self.state["kp"].dtype
-                        block_row = self._granted_block_row(req.slot)
                         kv = {"k": jnp.asarray(np.concatenate(
                                   [np.asarray(t[1]) for t in ready],
                                   axis=1), dt),
@@ -1240,66 +1146,49 @@ class TPUEngine:
                                   [np.asarray(t[2]) for t in ready],
                                   axis=1), dt)}
                         length = req.kv_pack["length"]
-                        self.state = self._dp.insert_sequence_paged(
+                        self.state = dp.insert_sequence_paged(
                             self.state, req.slot, kv, jnp.int32(length),
                             jnp.asarray(req.kv_pack["first_token"],
                                         jnp.int32),
-                            jnp.asarray(block_row), self.cfg)
+                            jnp.asarray(self._granted_block_row(req.slot)),
+                            self.cfg)
                         self._streaming.remove(req)
                         self._bind_slot(req, req.slot, length)
                         continue
-                    if self.kv_layout == "paged":
-                        pages = self._slot_pages[req.slot]
-                        dt = self.state["kp"].dtype
-                        # consecutive arrivals collapse into ONE scatter
-                        # per run (pages stream in order, so a whole
-                        # prefetch window is usually one write); run
-                        # lengths are bounded by the prefetch depth, so
-                        # compile count stays small
-                        ready.sort(key=lambda t: t[0])
-                        runs: list = []
-                        for i, kp, vp in ready:
-                            if runs and runs[-1][0] + len(runs[-1][1]) == i:
-                                runs[-1][1].append(kp)
-                                runs[-1][2].append(vp)
-                            else:
-                                runs.append((i, [kp], [vp]))
-                        for start, kps, vps in runs:
-                            ids = pages[start:start + len(kps)]
-                            kcat = np.concatenate(
-                                [np.asarray(p) for p in kps], axis=1)
-                            vcat = np.concatenate(
-                                [np.asarray(p) for p in vps], axis=1)
-                            self.state = self._dp.write_kv_pages(
-                                self.state,
-                                {"k": jnp.asarray(kcat, dt),
-                                 "v": jnp.asarray(vcat, dt)},
-                                jnp.asarray(np.asarray(ids, np.int32)))
-                            req.pf_done += len(kps)
-                    else:
-                        # slot layout has no page pool: buffer, then take
-                        # the stitch fallback at completion
-                        kps = req.kv_pack.setdefault(
-                            "k_pages", [None] * st.n_pages)
-                        vps = req.kv_pack.setdefault(
-                            "v_pages", [None] * st.n_pages)
-                        for i, kp, vp in ready:
-                            kps[i], vps[i] = kp, vp
-                            req.pf_done += 1
+                    pages = self._slot_pages[req.slot]
+                    # consecutive arrivals collapse into ONE scatter
+                    # per run (pages stream in order, so a whole
+                    # prefetch window is usually one write); run
+                    # lengths are bounded by the prefetch depth, so
+                    # compile count stays small
+                    runs: list = []
+                    for i, kp, vp in ready:
+                        if runs and runs[-1][0] + len(runs[-1][1]) == i:
+                            runs[-1][1].append(kp)
+                            runs[-1][2].append(vp)
+                        else:
+                            runs.append((i, [kp], [vp]))
+                    for start, kps, vps in runs:
+                        ids = pages[start:start + len(kps)]
+                        kcat = np.concatenate(
+                            [np.asarray(p) for p in kps], axis=1)
+                        vcat = np.concatenate(
+                            [np.asarray(p) for p in vps], axis=1)
+                        self.state = dp.write_kv_pages(
+                            self.state,
+                            {"k": jnp.asarray(kcat, dt),
+                             "v": jnp.asarray(vcat, dt)},
+                            jnp.asarray(np.asarray(ids, np.int32)))
+                        req.pf_done += len(kps)
                 if req.pf_done >= st.n_pages:
                     self._streaming.remove(req)
-                    if self.kv_layout == "paged":
-                        length = req.kv_pack["length"]
-                        block_row = self._granted_block_row(req.slot)
-                        self.state = self._dp.activate_slot(
-                            self.state, req.slot, jnp.asarray(block_row),
-                            jnp.int32(length),
-                            jnp.asarray(req.kv_pack["first_token"],
-                                        jnp.int32))
-                        self._bind_slot(req, req.slot, length)
-                    else:
-                        req.kv_stream = None
-                        self._insert_transferred(req, req.slot)
+                    length = req.kv_pack["length"]
+                    self.state = dp.activate_slot(
+                        self.state, req.slot,
+                        jnp.asarray(self._granted_block_row(req.slot)),
+                        jnp.int32(length),
+                        jnp.asarray(req.kv_pack["first_token"], jnp.int32))
+                    self._bind_slot(req, req.slot, length)
                     progressed = True
             except Exception as e:  # noqa: BLE001 — a malformed page must
                 # fail THIS request, not the scheduler (engine death would
@@ -1368,8 +1257,7 @@ class TPUEngine:
                     return  # page pressure: stop admitting this round
                 admitted += 1
                 continue
-            if self.kv_layout == "paged" and (self.enable_prefix_cache
-                                              or self.prefill_chunk):
+            if self.enable_prefix_cache or self.prefill_chunk:
                 first_id = self._admit_cached(req, slot)
                 if first_id is None:
                     self._free.append(slot)
@@ -1382,13 +1270,12 @@ class TPUEngine:
                 continue
             n = len(req.tokens)
             bucket = self._bucket(n)
-            if self.kv_layout == "paged":
-                # cheap feasibility check BEFORE paying for the prefill
-                if (self._pages_needed(n, bucket, req.params.max_tokens)
-                        > len(self._free_pages)):
-                    self._free.append(slot)
-                    self._backlog.append(req)
-                    return
+            # cheap feasibility check BEFORE paying for the prefill
+            if (self._pages_needed(n, bucket, req.params.max_tokens)
+                    > len(self._free_pages)):
+                self._free.append(slot)
+                self._backlog.append(req)
+                return
             t_sched = time.time()
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :n] = req.tokens
@@ -1492,10 +1379,10 @@ class TPUEngine:
                 npad *= 2
             padded_ids = np.zeros((npad,), np.int32)
             padded_ids[:n_pre] = pre_pages
-            k_pre, v_pre = self._dp.gather_prefix_pages(
+            k_pre, v_pre = dp.gather_prefix_pages(
                 self.state["kp"], self.state.get("vp"), jnp.asarray(padded_ids))
             self.prefix_tokens_gathered += pre_len
-            logits, kv = self._dp.prefill_with_prefix(
+            logits, kv = dp.prefill_with_prefix(
                 self.params, jnp.asarray(padded), k_pre, v_pre,
                 jnp.int32(pre_len), jnp.int32(len(suffix)), self.cfg)
         else:
@@ -1508,7 +1395,7 @@ class TPUEngine:
         block_row[:n_pre] = pre_pages
         block_row[n_pre:n_pre + len(priv)] = priv
         suf_pages = np.asarray(priv[:suf_bucket // P], np.int32)
-        self.state = self._dp.insert_sequence_paged_prefix(
+        self.state = dp.insert_sequence_paged_prefix(
             self.state, slot, kv, jnp.asarray(suf_pages),
             jnp.asarray(block_row), jnp.int32(n), first[0], self.cfg)
         self._bind_slot(req, slot, n)
@@ -1545,13 +1432,13 @@ class TPUEngine:
                 npad *= 2
             padded_ids = np.zeros((npad,), np.int32)
             padded_ids[:done // P] = req.pf_pages[:done // P]
-            k_pre, v_pre = self._dp.gather_prefix_pages(
+            k_pre, v_pre = dp.gather_prefix_pages(
                 self.state["kp"], self.state.get("vp"), jnp.asarray(padded_ids))
             self.prefix_tokens_gathered += done
-            logits, kv = self._dp.prefill_with_prefix(
+            logits, kv = dp.prefill_with_prefix(
                 self.params, jnp.asarray(padded), k_pre, v_pre,
                 jnp.int32(done), jnp.int32(len(chunk_toks)), self.cfg)
-        self.state = self._dp.write_kv_pages(self.state, kv,
+        self.state = dp.write_kv_pages(self.state, kv,
                                              jnp.asarray(chunk_pages))
         req.pf_done = done + len(chunk_toks)
         req.pf_chunks += 1
@@ -1564,7 +1451,7 @@ class TPUEngine:
         first = self._sample_first(req, logits, sub)
         block_row = np.zeros((self.max_pages_per_seq,), np.int32)
         block_row[:len(req.pf_pages)] = req.pf_pages
-        self.state = self._dp.activate_slot(
+        self.state = dp.activate_slot(
             self.state, req.slot, jnp.asarray(block_row), jnp.int32(n),
             first[0])
         self._bind_slot(req, req.slot, n)
@@ -1578,82 +1465,6 @@ class TPUEngine:
         self._first_token(req)
         self._emit(req, first_id)
 
-    def _index_ngram_at(self, req: _Request, end: int):
-        """Record the n-gram ENDING at history position end-1; its
-        continuation starts at `end`."""
-        n = self.ngram_size
-        if end < n:
-            return
-        key = tuple(req.history[end - n:end])
-        latest, _prev = req.ngram_index.get(key, (None, None))
-        req.ngram_index[key] = (end, latest)
-
-    def _propose_drafts(self, req: _Request) -> list:
-        """Prompt-lookup drafts: continuation after the most recent earlier
-        occurrence of the trailing n-gram in the request's own history.
-        O(1) via the incremental index. No match → repeat the last token
-        (a cheap guess; a wrong draft costs nothing beyond the verify
-        FLOPs the step spends anyway)."""
-        k = self.speculative_k
-        h = req.history
-        n = self.ngram_size
-        if req.ngram_index is None:  # first proposal: index the prompt
-            req.ngram_index = {}
-            for end in range(n, len(h) + 1):
-                self._index_ngram_at(req, end)
-        if len(h) > n:
-            key = tuple(h[-n:])
-            latest, prev = req.ngram_index.get(key, (None, None))
-            # `latest` is the trailing occurrence itself (continuation =
-            # end of history); the draft source is the one before it
-            cs = prev if latest == len(h) else latest
-            if cs is not None:
-                cont = h[cs:cs + k]
-                if cont:
-                    return (cont + [h[-1]] * (k - len(cont)))[:k]
-        return [h[-1] if h else 0] * k
-
-    def _speculative_step(self):
-        """One multi-token decode: verify n-gram drafts for every active
-        row, emit the accepted prefix plus one corrected token."""
-        K = self.speculative_k + 1
-        S = self.max_slots
-        draft = np.zeros((S, self.speculative_k), np.int32)
-        for slot, req in self._by_slot.items():
-            draft[slot] = self._propose_drafts(req)
-        self.state, logits = decoding.verify_step(
-            self.params, self.state, jnp.asarray(draft), self.cfg, K)
-        self.key, sub = jax.random.split(self.key)
-        V = logits.shape[-1]
-        toks = decoding.sample_per_row(
-            logits.reshape(S * K, V), sub,
-            jnp.repeat(self._temps, K), jnp.repeat(self._topks, K))
-        self._clock.mark("decode_wait")
-        toks_host = np.asarray(toks).reshape(S, K)
-        self._clock.mark("spec")
-        counts = np.zeros((S,), np.int32)
-        last = np.zeros((S,), np.int32)
-        self.spec_steps += 1
-        self.spec_slot_steps += len(self._by_slot)
-        self._count_expert_tokens(S * K)
-        for slot, req in list(self._by_slot.items()):
-            a = 0
-            while (a < self.speculative_k
-                   and toks_host[slot, a] == draft[slot, a]):
-                a += 1
-            self.spec_drafted += self.speculative_k
-            self.spec_accepted += a
-            counts[slot] = a + 1
-            last[slot] = toks_host[slot, a]
-            for j in range(a + 1):
-                self._emit(req, int(toks_host[slot, j]))
-                if slot not in self._by_slot:
-                    break  # finished (EOS/max_tokens) mid-burst
-        # release (inside _emit) precedes this commit: released rows are
-        # inactive, so their length/last_token stay reset
-        self.state = decoding.commit_accepted(
-            self.state, jnp.asarray(last), jnp.asarray(counts))
-
     def _emit(self, req: _Request, token_id: int):
         if self._phase_gap is not None:
             now = time.time()
@@ -1662,9 +1473,6 @@ class TPUEngine:
                 self._phase_gap.observe(now - last)
             req.last_emit_ts = now
         req.generated += 1
-        req.history.append(token_id)
-        if self.speculative_k and req.ngram_index is not None:
-            self._index_ngram_at(req, len(req.history))
         fsm = self._guided_fsm.get(req.slot)
         if fsm is not None:
             self._guided_state[req.slot] = fsm.step(
@@ -1681,13 +1489,10 @@ class TPUEngine:
         """Return an ACTIVE row's slot, pages, LoRA ref and guided-FSM
         state to their pools — the one release path shared by normal
         completion (_emit) and mid-stream abort (_abort_one)."""
-        if self.kv_layout == "paged":
-            self.state = self._dp.release_slot_paged(self.state, req.slot)
-            self._free_pages.extend(self._slot_pages.pop(req.slot, ()))
-            if self.enable_prefix_cache:
-                self._release_shared(req.slot)
-        else:
-            self.state = decoding.release_slot(self.state, req.slot)
+        self.state = dp.release_slot_paged(self.state, req.slot)
+        self._free_pages.extend(self._slot_pages.pop(req.slot, ()))
+        if self.enable_prefix_cache:
+            self._release_shared(req.slot)
         if self.lora_bank is not None:
             self._slot_lora = self._slot_lora.at[req.slot].set(0)
         self._lora_release(req)
@@ -1742,9 +1547,8 @@ class TPUEngine:
             return True
         elif req in self._prefilling:
             self._prefilling.remove(req)
-            if self.kv_layout == "paged":
-                self._free_pages.extend(self._slot_pages.pop(req.slot, ()))
-                self._release_shared(req.slot)
+            self._free_pages.extend(self._slot_pages.pop(req.slot, ()))
+            self._release_shared(req.slot)
             self._free.append(req.slot)
             self._lora_release(req)
         elif req in self._backlog:
@@ -1859,26 +1663,10 @@ class TPUEngine:
                     self._work.wait(timeout=0.005)
                     self._work.clear()
                 continue
-            if self.speculative_k:
-                mark("spec")
-                self._speculative_step()
-                continue
             t_step = mark("decode")
-            if self.kv_layout == "paged":
-                if self.attn_impl == "ragged":
-                    self.state, logits = self._dp.decode_step_paged_ragged(
-                        self.params, self.state, self.cfg,
-                        self._pages_bound(), self._ragged_kernel)
-                else:
-                    self.state, logits = self._dp.decode_step_paged(
-                        self.params, self.state, self.cfg)
-            elif self.lora_bank is not None:
-                self.state, logits = decoding.decode_step(
-                    self.params, self.state, self.cfg,
-                    self.lora_bank, self._slot_lora)
-            else:
-                self.state, logits = decoding.decode_step(
-                    self.params, self.state, self.cfg)
+            self.state, logits = dp.decode_step_paged_ragged(
+                self.params, self.state, self.cfg, self._pages_bound(),
+                self._ragged_kernel, self.lora_bank, self._slot_lora)
             self.key, sub = jax.random.split(self.key)
             if self._guided_fsm:
                 # per-slot FSM masks as an additive bias; the sampling math
@@ -1906,14 +1694,11 @@ class TPUEngine:
             self.context_tokens += sum(
                 r.length0 + max(0, r.generated - 1) + 1
                 for r in self._by_slot.values())
-            if self.kv_layout == "paged":
-                self.page_steps_used += (self.num_pages - 1
-                                         - self._available_pages())
-                self.page_steps_total += self.num_pages - 1
+            self.page_steps_used += (self.num_pages - 1
+                                     - self._available_pages())
+            self.page_steps_total += self.num_pages - 1
             if self._step_obs is not None:
-                # the step's dispatches + the fetch of its tokens: the
-                # ragged-vs-gather attribution surface (LLM_BENCH
-                # decode_step row)
+                # the step's dispatches + the fetch of its tokens
                 self._step_obs.observe(t_emit - t_step)
             for slot, req in list(self._by_slot.items()):
                 self._emit(req, int(toks_host[slot]))
@@ -1921,19 +1706,14 @@ class TPUEngine:
     # ---------------------------------------------------------------- stats
 
     def stats(self) -> dict:
-        # which decode-attention code runs, not which was asked for:
-        # attn_impl "ragged" is the Pallas kernel only on an unsharded TPU
-        # engine, the pure-JAX reference everywhere else
-        decode_attn = ("ragged_" + ("kernel" if self._ragged_kernel
-                                    else "reference")
-                       if self.attn_impl == "ragged" else self.attn_impl)
         memory = jax.local_devices()[0].memory_stats() or {}  # None on CPU
         out = {"free_slots": len(self._free), "active": len(self._by_slot),
                "waiting": self._waiting.qsize() + len(self._backlog),
                "streaming": len(self._streaming),
                "max_slots": self.max_slots, "buckets": list(self.buckets),
-               "kv_layout": self.kv_layout, "attn_impl": self.attn_impl,
-               "decode_attn": decode_attn,
+               # which decode-attention code runs: the Pallas kernel only on
+               # an unsharded TPU engine, the pure-JAX reference elsewhere
+               "decode_attn": self._decode_attn,
                "device": accelerators.device_report(),
                "device_memory": {
                    k: memory.get(k) for k in (
@@ -1949,9 +1729,11 @@ class TPUEngine:
                "aborts": self.aborts,
                "decode_occupancy": (self.decode_slot_steps
                                     / self.decode_steps
-                                    if self.decode_steps else 0.0)}
-        pools = [self.state[k] for k in ("k", "v", "kp", "vp")
-                 if k in self.state]   # [L, slots | pages, tokens, ...]
+                                    if self.decode_steps else 0.0),
+               "free_pages": len(self._free_pages),
+               "num_pages": self.num_pages, "page_size": self.page_size}
+        pools = [self.state[k] for k in ("kp", "vp")
+                 if k in self.state]   # [L, pages, tokens, ...]
         out["cache"] = {
             # as stored, all layers: a latent row, or K and V of every head
             "bytes_per_token": sum(
@@ -1962,35 +1744,17 @@ class TPUEngine:
             "page_steps_total": self.page_steps_total}
         out["experts"] = {"tokens_sorted": self.expert_tokens_sorted,
                           "tokens_onehot": self.expert_tokens_onehot}
-        if self.speculative_k:
-            drafted = self.spec_drafted
-            out["speculative"] = {
-                "k": self.speculative_k, "steps": self.spec_steps,
-                "drafted": drafted, "accepted": self.spec_accepted,
-                "acceptance_rate": (self.spec_accepted / drafted
-                                    if drafted else 0.0),
-                # per-SEQUENCE advance per verify step: each active slot
-                # emits (accepted + 1) tokens per step
-                "tokens_per_step": ((self.spec_accepted
-                                     + self.spec_slot_steps)
-                                    / self.spec_slot_steps
-                                    if self.spec_slot_steps else 0.0),
+        if self.prefill_chunk:
+            out["prefill_chunk"] = self.prefill_chunk
+            out["prefill_chunks_run"] = self.prefill_chunks_run
+            out["prefilling"] = len(self._prefilling)
+        if self.enable_prefix_cache:
+            hits, misses = self.prefix_hits, self.prefix_misses
+            out["prefix_cache"] = {
+                "hits": hits, "misses": misses,
+                "hit_rate": hits / (hits + misses) if hits + misses else 0.0,
+                "tokens_reused": self.prefix_tokens_reused,
+                "cached_blocks": len(self._prefix_cache),
+                "reclaimable_pages": self._reclaimable_pages(),
             }
-        if self.kv_layout == "paged":
-            out["free_pages"] = len(self._free_pages)
-            out["num_pages"] = self.num_pages
-            out["page_size"] = self.page_size
-            if self.prefill_chunk:
-                out["prefill_chunk"] = self.prefill_chunk
-                out["prefill_chunks_run"] = self.prefill_chunks_run
-                out["prefilling"] = len(self._prefilling)
-            if self.enable_prefix_cache:
-                hits, misses = self.prefix_hits, self.prefix_misses
-                out["prefix_cache"] = {
-                    "hits": hits, "misses": misses,
-                    "hit_rate": hits / (hits + misses) if hits + misses else 0.0,
-                    "tokens_reused": self.prefix_tokens_reused,
-                    "cached_blocks": len(self._prefix_cache),
-                    "reclaimable_pages": self._reclaimable_pages(),
-                }
         return out

@@ -65,14 +65,12 @@ def _ttft_histogram():
 def _pd_engine_kwargs(llm_config: LLMConfig) -> dict:
     """One normalization of engine_kwargs shared by BOTH pools, so prefill
     bucketing and the decode page pool can never disagree on shapes: PD
-    defaults to the paged layout with pd_config.page_size, and min_bucket
-    is bumped so every prompt bucket slices into whole pages."""
+    defaults to pd_config.page_size, and min_bucket is bumped so every
+    prompt bucket slices into whole pages."""
     pd = llm_config.pd_config or PDConfig()
     ek = dict(llm_config.engine_kwargs)
-    ek.setdefault("kv_layout", "paged")
     ek.setdefault("page_size", pd.page_size)
-    if ek["kv_layout"] == "paged":
-        ek["min_bucket"] = max(ek.get("min_bucket", 32), ek["page_size"])
+    ek["min_bucket"] = max(ek.get("min_bucket", 32), ek["page_size"])
     return ek
 
 

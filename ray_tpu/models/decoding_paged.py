@@ -1,32 +1,32 @@
 """Paged KV-cache decoding: block-table attention with static shapes.
 
-The contiguous backend (models/decoding.py) reserves `max_len` tokens of KV
-per slot — fine for uniform sequence lengths, wasteful for mixed ones. This
-backend carves HBM into a shared **page pool**; each slot owns just the
-pages its sequence actually needs, tracked in a block table, so the same
-HBM serves many more concurrent sequences at typical length distributions.
+How the KV cache is laid out, and which code attends over it, is decided
+here and nowhere else. HBM is carved into a shared **page pool**; each slot
+owns just the pages its sequence needs, tracked in a block table, so the
+same HBM serves many more concurrent sequences at typical length
+distributions than a `max_len` reservation per slot would.
 
 All shapes stay static (XLA-first, like everything here): the pool is
 [L, num_pages, page, Hkv, Dh]; per-step writes are scatters at
-(page_id, offset) and attention gathers each row's pages with a take along
-the page axis. Page allocation/free is host-side bookkeeping in the engine
+(page_id, offset) and attention is one ragged launch over the rows' block
+tables. Page allocation/free is host-side bookkeeping in the engine
 (a free list), mirroring how vLLM's scheduler owns its block tables.
 
-The decode steps carry the pools through the layer scan (viewed flat,
+The decode step carries the pools through the layer scan (viewed flat,
 [L*num_pages, page, Hkv, Dh], layer l's pages at l*num_pages + id) and
-scatter each layer's rows into the carry in place, because pools handed to
+scatters each layer's rows into the carry in place, because pools handed to
 the scan as inputs and stacked as its outputs are sliced, copied and
 rewritten whole every step.
 
 A model with latent attention (cfg.kv_lora_rank, MLA) has ONE pool, `kp`
 [L, num_pages, page, latent_lanes], and no `vp`: a token's row is (c |
 k_rope | padding to whole lanes), nothing per head. Prefill attends in
-expanded form (per-head K and V from the rows), the decode steps in absorbed
+expanded form (per-head K and V from the rows), the decode step in absorbed
 form over the rows as they lie in the pool: the same mathematics.
 
 (reference capability: vLLM paged attention behind
 llm/_internal/serve/engines/vllm/vllm_engine.py:114; design here is
-TPU-native — dense static gathers, no custom CUDA.)
+TPU-native — static gathers and a Pallas kernel, no custom CUDA.)
 """
 
 from __future__ import annotations
@@ -92,15 +92,33 @@ def _write_pages(state, kv, pages) -> dict:
     return state
 
 
-def _decode_step(params, state, cfg: TransformerConfig, attend):
-    """One token for every active row; the body both decode steps share.
-    `attend(qh, kp, vp, base, pos)` is the attention core: qh [B, Hkv, G, Dh]
-    against the FLAT pools [L*num_pages, P, Hkv, Dh] the scan carries, in
-    which this layer's page `i` lies at `base + i`. With `state` donated the
-    pools alias input to output and a step writes B rows a layer. With
-    latent attention qh is [B, 1, H, lanes] (the absorbed query), kp the one
-    pool [L*num_pages, P, lanes], vp None, and the result's first
-    kv_lora_rank columns are sum p c."""
+@functools.partial(jax.jit, donate_argnames=("state",),
+                   static_argnames=("cfg", "pages_bound", "kernel"))
+def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
+                             pages_bound: int, kernel: bool = False,
+                             lora_bank=None, slot_lora=None):
+    """Advance every active row one token: one scatter of the step's rows a
+    layer, then ONE ragged attention launch over the batch's block tables
+    (ops/ragged_paged_attention.py): no [B, max_pages*page] gather, and the
+    sweep stops at `pages_bound` — the engine's host-side bound on the
+    batch's LIVE page count (power of two, so compile count stays
+    O(log(max_pages))). `kernel=True` runs the Pallas TPU kernel; False
+    runs the bit-consistent pure-JAX reference (the CPU path).
+
+    The attention core sees qh [B, Hkv, G, Dh] against the FLAT pools
+    [L*num_pages, P, Hkv, Dh] the scan carries, in which this layer's page
+    `i` lies at `base + i`. With `state` donated the pools alias input to
+    output and a step writes B rows a layer. With latent attention qh is
+    [B, 1, H, lanes] (the absorbed query), kp the one pool
+    [L*num_pages, P, lanes], vp None, and the result's first kv_lora_rank
+    columns are sum p c. With `lora_bank` (decoding.init_lora_bank) +
+    `slot_lora` [B], each row adds its own adapter's q/v deltas in the same
+    step (index 0 = the null adapter = the base model)."""
+    from ray_tpu.ops.ragged_paged_attention import ragged_decode_attention
+
+    # the ragged sweep only walks the batch's live prefix of each table;
+    # positions past a row's `pos` inside that prefix are masked in-kernel
+    tbl = state["block"][:, :pages_bound]
     dt = cfg.dtype
     B = state["block"].shape[0]
     L, num_pages, P = state["kp"].shape[:3]
@@ -119,21 +137,28 @@ def _decode_step(params, state, cfg: TransformerConfig, attend):
         x = x + params["pos_embed"].astype(dt)[pos][:, None]
     cos, sin = _rope(cfg)
     G = cfg.n_heads // cfg.kv_heads
+    lscale = None if lora_bank is None else lora_bank["scale"][slot_lora]
+
+    def attend(qh, kp, vp, base):
+        return ragged_decode_attention(
+            qh, kp, vp, base + tbl, pos, scale=cfg.qk_dim ** -0.5,
+            impl="kernel" if kernel else "reference")
 
     def block(carry, layer_in):
         h, kp, vp = carry                        # pools [L*num_pages, P, Hkv, Dh]
-        layer_p, base = layer_in                 # base: this layer's first page
+        layer_p, base, *lora_l = layer_in        # base: this layer's first page
         normed = _norm(h, layer_p["norm1"], cfg)
         if cfg.mla:
             ap = layer_p["attn"]
             q, row = _mla_project(normed, ap, cfg, cos, sin, pos[:, None])
             kp = kp.at[base + page_ids, offsets].set(row[:, 0].astype(kp.dtype))
-            o_lat = attend(_mla_absorb_q(q[:, 0], ap, cfg)[:, None], kp, None, base, pos)
+            o_lat = attend(_mla_absorb_q(q[:, 0], ap, cfg)[:, None], kp, None, base)
             out = _mla_absorb_out(o_lat[:, 0].astype(dt), ap, cfg)
             h = h + jnp.einsum("bhd,hde->be", out, ap["wo"].astype(dt))[:, None]
             h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
             return (h, kp, vp), None
-        q, k, v = _attn_qkv(normed, layer_p["attn"], cfg)      # [B, 1, H, Dh]
+        q, k, v = _attn_qkv(normed, layer_p["attn"], cfg, lora_l, slot_lora,
+                            lscale)                            # [B, 1, H, Dh]
         if cfg.pos == "rope":
             q = ops.apply_rope(q, cos, sin, positions=pos[:, None])
             k = ops.apply_rope(k, cos, sin, positions=pos[:, None])
@@ -141,7 +166,7 @@ def _decode_step(params, state, cfg: TransformerConfig, attend):
         kp = kp.at[base + page_ids, offsets].set(k[:, 0].astype(kp.dtype))
         vp = vp.at[base + page_ids, offsets].set(v[:, 0].astype(vp.dtype))
         qh = q[:, 0].reshape(B, cfg.kv_heads, G, cfg.head_dim)
-        out = attend(qh, kp, vp, base, pos)
+        out = attend(qh, kp, vp, base)
         out = out.reshape(B, 1, cfg.n_heads, cfg.head_dim).astype(dt)
         out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
@@ -152,7 +177,9 @@ def _decode_step(params, state, cfg: TransformerConfig, attend):
 
     (x, kp, vp), _ = scan_layers(
         block, (x, state["kp"].reshape(flat), vp0), params, cfg,
-        jnp.arange(L, dtype=jnp.int32) * num_pages)
+        jnp.arange(L, dtype=jnp.int32) * num_pages,
+        *(() if lora_bank is None else
+          (lora_bank[k] for k in ("A_q", "B_q", "A_v", "B_v"))))
     x = _norm(x, params["final_norm"], cfg)
     if cfg.tie_embeddings:
         logits = x[:, 0] @ params["embed"].astype(dt).T
@@ -164,59 +191,6 @@ def _decode_step(params, state, cfg: TransformerConfig, attend):
         state["vp"] = vp.reshape(state["vp"].shape)
     state["length"] = jnp.where(state["active"], state["length"] + 1, state["length"])
     return state, logits.astype(jnp.float32)
-
-
-@functools.partial(jax.jit, donate_argnames=("state",), static_argnames=("cfg",))
-def decode_step_paged(params, state, cfg: TransformerConfig):
-    """Advance every active row one token against its paged cache: every
-    row's whole block table is gathered and masked."""
-    dt = cfg.dtype
-    B, MP = state["block"].shape
-    S = MP * state["kp"].shape[2]
-
-    def attend(qh, kp, vp, base, pos):
-        # gather each row's pages → a contiguous [B, S] view for attention
-        tbl = base + state["block"]
-        if vp is None:  # latent rows: one shared head, its own value
-            k_cache = v_cache = kp[tbl].reshape(B, S, 1, -1)
-        else:
-            k_cache = kp[tbl].reshape(B, S, cfg.kv_heads, cfg.head_dim)
-            v_cache = vp[tbl].reshape(B, S, cfg.kv_heads, cfg.head_dim)
-        scores = jnp.einsum("bkgd,bskd->bkgs", qh, k_cache.astype(dt)) / (cfg.qk_dim ** 0.5)
-        mask = jnp.arange(S)[None, :] <= pos[:, None]
-        scores = jnp.where(mask[:, None, None, :], scores.astype(jnp.float32), -1e30)
-        w = jax.nn.softmax(scores, axis=-1).astype(dt)
-        return jnp.einsum("bkgs,bskd->bkgd", w, v_cache.astype(dt))
-
-    return _decode_step(params, state, cfg, attend)
-
-
-@functools.partial(jax.jit, donate_argnames=("state",),
-                   static_argnames=("cfg", "pages_bound", "kernel"))
-def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
-                             pages_bound: int, kernel: bool = False):
-    """Advance every active row one token — ragged paged attention.
-
-    Same per-step scatter as decode_step_paged, but the attention core is
-    ONE ragged launch over the batch's block tables (ops/
-    ragged_paged_attention.py): no [B, max_pages*page] gather, and the
-    sweep stops at `pages_bound` — the engine's host-side bound on the
-    batch's LIVE page count (power of two, so compile count stays
-    O(log(max_pages))). `kernel=True` runs the Pallas TPU kernel;
-    False runs the bit-consistent pure-JAX reference (the CPU path).
-    """
-    from ray_tpu.ops.ragged_paged_attention import ragged_decode_attention
-
-    # the ragged sweep only walks the batch's live prefix of each table;
-    # positions past a row's `pos` inside that prefix are masked in-kernel
-    tbl = state["block"][:, :pages_bound]
-
-    def attend(qh, kp, vp, base, pos):
-        return ragged_decode_attention(
-            qh, kp, vp, base + tbl, pos, scale=cfg.qk_dim ** -0.5,
-            impl="kernel" if kernel else "reference")
-
-    return _decode_step(params, state, cfg, attend)
 
 
 @functools.partial(jax.jit, donate_argnames=("state",))

@@ -23,8 +23,7 @@ def write_artifact(name: str, payload: dict) -> str:
 
 def merge_artifact(name: str, section: str, payload) -> str:
     """Write ONE top-level section of a shared artifact, preserving every
-    other section (the merge discipline llm_load_bench uses for
-    LLM_BENCH.json's ``pd`` section): SERVE_BENCH.json is shared by
+    other section: SERVE_BENCH.json is shared by
     serve_bench's baseline ``results`` and serve_shard_bench's ``sharded``
     section — a rerun of either must not clobber the other."""
     path = os.path.join(repo_root(), name)

@@ -47,7 +47,6 @@ def _paged_engine(cfg, params, **kw):
     kw.setdefault("max_slots", 4)
     kw.setdefault("max_len", MAX_LEN)
     kw.setdefault("min_bucket", PAGE)
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("page_size", PAGE)
     return TPUEngine(cfg, params, **kw)
 

@@ -29,7 +29,7 @@ TINY_SERVE = dict(family="llama", model_id="tiny",
                   model_kwargs=dict(vocab_size=300, max_seq_len=256, d_model=64,
                                     n_layers=2, n_heads=8, n_kv_heads=8, d_ff=128,
                                     dtype=jnp.float32, remat=False),
-                  engine_kwargs={"kv_layout": "paged", "page_size": 16,
+                  engine_kwargs={"page_size": 16,
                                  "max_slots": 4, "max_len": 256},
                   prompt_tokens=(16, 60, 200), max_tokens=6)
 TINY_KERNELS = dict(flash=(1, 2, 128, 64), interpret=True,

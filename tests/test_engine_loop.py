@@ -37,8 +37,8 @@ def tiny_model():
 
 def _engine(tiny_model, **kw):
     cfg, params = tiny_model
-    opts = dict(max_slots=4, max_len=128, min_bucket=16, kv_layout="paged",
-                page_size=16, enable_prefix_cache=True, prefill_chunk=16)
+    opts = dict(max_slots=4, max_len=128, min_bucket=16, page_size=16,
+                enable_prefix_cache=True, prefill_chunk=16)
     opts.update(kw)
     return TPUEngine(cfg, params, **opts)
 
@@ -89,7 +89,7 @@ def test_phases_cover_the_thread(tiny_model):
     # serve a first block), one first-token fetch each
     chunks = s1["prefill_chunks_run"] - s0["prefill_chunks_run"]
     assert 16 <= chunks <= 24
-    assert delta["spec"] == 0 and delta["streams"] == 0
+    assert delta["streams"] == 0
     for busy in ("sweep", "admit", "prefill", "prefill_wait", "decode",
                  "decode_wait", "emit"):
         assert delta[busy] > 0, busy
@@ -232,18 +232,18 @@ def test_stats_stay_json_plain(tiny_model):
     assert isinstance(recent, list) and len(recent) <= 16
 
 
-def test_slot_layout_and_speculative_engines_keep_the_clock(tiny_model):
-    """The slot layout's admission fetch is `admit_wait`; a speculative
-    step's fetch is `decode_wait`, the rest of it `spec`."""
+def test_the_plain_admission_keeps_the_clock(tiny_model):
+    """Without a prefix cache or chunks a prompt is prefilled whole by
+    `_admit`: its first token's fetch is `admit_wait`."""
     cfg, params = tiny_model
-    eng = TPUEngine(cfg, params, max_slots=2, max_len=64, speculative_k=2)
+    eng = TPUEngine(cfg, params, max_slots=2, max_len=64)
     try:
         assert len(eng.generate([1, 2, 3, 1, 2, 3], SamplingParams(max_tokens=6))) == 6
         loop = _quiet_stats(eng)["loop"]
     finally:
         eng.shutdown()
     assert loop["seconds"]["admit_wait"] > 0 and loop["seconds"]["decode_wait"] > 0
-    assert loop["seconds"]["spec"] > 0 and loop["seconds"]["decode"] == 0
+    assert loop["seconds"]["decode"] > 0 and loop["seconds"]["prefill"] == 0
     # scheduled as of before the prefill's dispatch, once it is inserted
     assert loop["requests"]["requests_scheduled"] == 1
     assert 0 < loop["requests"]["prefill_s"] >= loop["seconds"]["admit_wait"]

@@ -125,15 +125,8 @@ def _prefilled(cfg, p, tokens, n):
     return logits, kv, state, row
 
 
-STEPS = {
-    "gather": lambda p, s, cfg: dp.decode_step_paged(p, s, cfg),
-    "ragged-reference": lambda p, s, cfg: dp.decode_step_paged_ragged(p, s, cfg, 8, False),
-    "ragged-kernel": lambda p, s, cfg: dp.decode_step_paged_ragged(p, s, cfg, 8, True),
-}
-
-
-@pytest.mark.parametrize("name", sorted(STEPS))
-def test_prefill_then_paged_decode_agrees_with_the_full_forward(model, kernel_interpreted, name):
+@pytest.mark.parametrize("kernel", [False, True], ids=["ragged-reference", "ragged-kernel"])
+def test_prefill_then_paged_decode_agrees_with_the_full_forward(model, kernel_interpreted, kernel):
     """Expanded attention in prefill, absorbed attention over the latent pages
     in every decode step (across a page boundary), against the reference's
     full forward at every decoded position."""
@@ -144,7 +137,7 @@ def test_prefill_then_paged_decode_agrees_with_the_full_forward(model, kernel_in
     logits, _, state, _ = _prefilled(cfg, p, tokens, n)
     assert _close(logits, want[n - 1]) < TOL
     for i in range(steps):
-        state, step = STEPS[name](p, state, cfg)
+        state, step = dp.decode_step_paged_ragged(p, state, cfg, 8, kernel)
         assert _close(step[1], want[n + i]) < TOL, i
         state = decoding.commit_tokens(state, jnp.full((3,), tokens[n + i + 1], jnp.int32))
     assert "vp" not in state and int(state["length"][1]) == n + steps
@@ -158,7 +151,7 @@ def test_the_pages_hold_the_references_latent_rows(model):
     n = 40
     tokens = _tokens(n + 2)
     _, kv, state, row = _prefilled(cfg, p, tokens, n)
-    state, _ = dp.decode_step_paged(p, state, cfg)
+    state, _ = dp.decode_step_paged_ragged(p, state, cfg, 8, False)
     r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     assert state["kp"].shape == (cfg.n_layers, 24, PAGE, 128) and kv["k"].shape[-1] == 128
     x = p["embed"][tokens[:n + 1]]
@@ -321,10 +314,8 @@ def test_mixtral_dropless_equals_the_one_hot_form_at_full_capacity(n_tokens):
 
 
 @pytest.mark.parametrize("kwargs,what", [
-    (dict(kv_layout="slot"), "kv_layout='slot'"),
-    (dict(kv_layout="paged", speculative_k=2), "speculative_k"),
-    (dict(kv_layout="paged", max_loras=2), "max_loras"),
-    (dict(kv_layout="paged", mesh="a mesh"), "tensor-parallel mesh"),
+    (dict(max_loras=2), "max_loras"),
+    (dict(mesh="a mesh"), "tensor-parallel mesh"),
 ])
 def test_what_is_not_carried_to_the_latent_cache_raises_at_construction(model, kwargs, what):
     from ray_tpu.llm.engine import TPUEngine
@@ -332,8 +323,8 @@ def test_what_is_not_carried_to_the_latent_cache_raises_at_construction(model, k
     cfg, p = model
     with pytest.raises(ValueError, match=what):
         TPUEngine(cfg, p, max_len=MAX_LEN, **kwargs)
-    with pytest.raises(NotImplementedError, match="paged layout"):
-        decoding.init_decode_state(cfg, 2, 64)
+    with pytest.raises(NotImplementedError, match="latent attention"):
+        decoding.init_lora_bank(cfg, 2, 4)
 
 
 def test_engine_serves_the_latent_cache_and_counts_it(model):
@@ -342,7 +333,7 @@ def test_engine_serves_the_latent_cache_and_counts_it(model):
     from ray_tpu.llm.engine import SamplingParams, TPUEngine
 
     cfg, p = model
-    eng = TPUEngine(cfg, p, max_slots=2, max_len=MAX_LEN, min_bucket=32, kv_layout="paged",
+    eng = TPUEngine(cfg, p, max_slots=2, max_len=MAX_LEN, min_bucket=32,
                     page_size=PAGE, num_pages=40, prefill_chunk=32, enable_prefix_cache=True)
     try:
         with pytest.raises(NotImplementedError, match="latent"):

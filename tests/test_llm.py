@@ -136,6 +136,32 @@ def _tiny_llm_config(**engine_kwargs):
     )
 
 
+@pytest.mark.parametrize("engine_kwargs,refused", [
+    ({"kv_layout": "paged"}, None),
+    ({"kv_layout": "slot"}, "kv_layout"),
+    ({"attn_impl": "gather"}, "attn_impl"),
+    ({"speculative_k": 2}, "speculative_k"),
+])
+def test_from_config_names_the_options_that_were_removed(engine_kwargs, refused):
+    """Configuration files still say `kv_layout: paged`; every other value,
+    and the options of the gather step and of speculation, are refused by
+    name, before the model is built."""
+    from ray_tpu.llm import TPUEngine
+
+    config = _tiny_llm_config(**engine_kwargs)
+    if refused:
+        with pytest.raises(ValueError, match=f"{refused}.*removed"):
+            TPUEngine.from_config(config)
+        return
+    eng = TPUEngine.from_config(config)
+    try:
+        st = eng.stats()
+        assert st["page_size"] == 64 and st["decode_attn"] == "ragged_reference"
+        assert "kv_layout" not in st and "attn_impl" not in st
+    finally:
+        eng.shutdown()
+
+
 def test_llm_server_openai_surface(llm_cluster):
     from ray_tpu import serve
     from ray_tpu.llm import build_openai_app
@@ -154,7 +180,7 @@ def test_llm_server_openai_surface(llm_cluster):
     assert "message" in chat["choices"][0]
     # /v1/stats surfaces engine observability over the same HTTP entry
     st = handle.remote({"path": "/v1/stats"}).result(timeout_s=60)
-    assert st["max_slots"] >= 1 and "kv_layout" in st
+    assert st["max_slots"] >= 1 and "decode_attn" in st
     serve.delete("llm")
 
 

@@ -28,7 +28,6 @@ def _engine(cfg, params, **kw):
     kw.setdefault("max_slots", 4)
     kw.setdefault("max_len", 128)
     kw.setdefault("min_bucket", 8)
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("page_size", 8)
     kw.setdefault("prefill_chunk", 16)
     return TPUEngine(cfg, params, **kw)
@@ -120,7 +119,5 @@ def test_chunked_plus_prefix_cache(tiny_model):
 def test_validation(tiny_model):
     cfg, params = tiny_model
     with pytest.raises(ValueError, match="prefill_chunk"):
-        TPUEngine(cfg, params, kv_layout="paged", page_size=8,
+        TPUEngine(cfg, params, page_size=8,
                   prefill_chunk=12)  # not a power of two
-    with pytest.raises(ValueError, match="paged"):
-        TPUEngine(cfg, params, kv_layout="slot", prefill_chunk=16)

@@ -19,9 +19,17 @@ from ray_tpu.models import llama_config, transformer
 RANK = 4
 
 
-def _tiny_cfg():
+def _tiny_cfg(family="llama"):
     import jax.numpy as jnp
 
+    if family == "mixtral":  # dropless experts: the layer scan closes over them
+        from ray_tpu.models import mixtral_config
+        from ray_tpu.models.transformer import MoEConfig
+
+        return mixtral_config("tiny", vocab_size=256, max_seq_len=128,
+                              d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+                              d_ff=96, dtype=jnp.float32, moe=MoEConfig(
+                                  num_experts=4, top_k=2, capacity_factor=2.0))
     return llama_config("tiny", vocab_size=256, max_seq_len=128,
                         d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
                         d_ff=128, dtype=jnp.float32)
@@ -93,8 +101,9 @@ def test_zero_adapter_matches_base_exactly():
     eng.shutdown()
 
 
-def test_adapter_matches_dense_merge_token_exact():
-    cfg = _tiny_cfg()
+@pytest.mark.parametrize("family", ["llama", "mixtral"])
+def test_adapter_matches_dense_merge_token_exact(family):
+    cfg = _tiny_cfg(family)
     params = _params(cfg)
     w = _rand_adapter(cfg, 7)
     alpha = 2.0
@@ -148,6 +157,109 @@ def test_per_slot_isolation_mixed_batch():
     assert outs[0] == base and outs[3] == base  # base rows untouched
     assert outs[1] != base and outs[2] != base  # adapter rows differ
     assert outs[1] != outs[2]                   # per-slot, not global
+
+
+def _merged_tokens(cfg, params, w, prompt, sp, scale=1.0):
+    eng = TPUEngine(cfg, _merge(params, cfg, w, scale) if w else params,
+                    max_slots=2, max_len=128)
+    try:
+        return eng.generate(prompt, sp)
+    finally:
+        eng.shutdown()
+
+
+def test_adapter_rows_under_page_pressure_match_the_dense_merge():
+    """A pool too small for the batch: later requests wait in the backlog
+    for pages, and every row still decodes its own adapter, token-exact
+    against that adapter merged densely into the base weights."""
+    cfg = _tiny_cfg()
+    params = _params(cfg)
+    adapters = {"a": _rand_adapter(cfg, 1), "b": _rand_adapter(cfg, 2)}
+    # a row holds 2 pages of 16 (bucket 32; positions up to 6 + 12 lie inside);
+    # 5 usable pages host two rows at once, the other two wait
+    eng = TPUEngine(cfg, params, max_slots=4, max_len=128, page_size=16,
+                    num_pages=6, max_loras=2, lora_rank=RANK)
+    try:
+        for name, w in adapters.items():
+            eng.load_lora(name, w)
+        order = [None, "a", "b", "a"]
+        reqs = [eng.submit(PROMPT, SP, lora=name) for name in order]
+        got = [list(r) for r in reqs]
+        st = eng.stats()
+        assert st["free_pages"] == 5 and st["free_slots"] == 4
+    finally:
+        eng.shutdown()
+    want = {name: _merged_tokens(cfg, params, adapters.get(name), PROMPT, SP)
+            for name in set(order)}
+    assert got == [want[name] for name in order]
+    assert len({tuple(t) for t in want.values()}) == 3
+
+
+def test_adapters_on_a_tensor_parallel_mesh_match_one_chip():
+    """The bank is replicated over the mesh that splits heads and page pools;
+    an adapter row decodes what it decodes on one device."""
+    import jax
+    from jax.sharding import Mesh
+
+    devs = jax.devices()
+    if len(devs) < 2:
+        pytest.skip("needs >=2 devices")
+    cfg = _tiny_cfg()
+    params = _params(cfg)
+    w = _rand_adapter(cfg, 7)
+    outs = []
+    for mesh in (None, Mesh(devs[:2], ("tp",))):
+        eng = TPUEngine(cfg, params, max_slots=2, max_len=128, mesh=mesh,
+                        max_loras=1, lora_rank=RANK)
+        try:
+            eng.load_lora("ad", w)
+            outs.append((eng.generate(PROMPT, SP, lora="ad"),
+                         eng.generate(PROMPT, SP)))
+        finally:
+            eng.shutdown()
+    assert outs[0] == outs[1] and outs[0][0] != outs[0][1]
+    assert outs[0][0] == _merged_tokens(cfg, params, w, PROMPT, SP)
+
+
+def test_an_aborted_adapter_row_returns_its_pages_and_its_reference():
+    """Cancelled mid-stream, a row gives back slot, pages and the adapter's
+    reference in one pass: the adapter can be unloaded at once."""
+    import time
+
+    from ray_tpu.exceptions import RequestCancelledError
+
+    cfg = _tiny_cfg()
+    eng = TPUEngine(cfg, _params(cfg), max_slots=2, max_len=128, page_size=16,
+                    max_loras=1, lora_rank=RANK)
+    try:
+        eng.load_lora("x", _rand_adapter(cfg, 3))
+        req = eng.submit(PROMPT, SamplingParams(max_tokens=100), lora="x")
+        assert req.out_queue.get(timeout=60) is not None   # the row is live
+        eng.abort_request(req.rid)
+        with pytest.raises(RequestCancelledError):
+            list(req)
+        deadline = time.time() + 30
+        while eng.stats()["free_slots"] < 2 and time.time() < deadline:
+            time.sleep(0.01)
+        st = eng.stats()
+        assert st["free_slots"] == 2 and st["free_pages"] == st["num_pages"] - 1
+        eng.unload_lora("x")
+        assert eng.generate(PROMPT, SP) == _merged_tokens(cfg, _params(cfg), None,
+                                                          PROMPT, SP)
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("option", [dict(enable_prefix_cache=True),
+                                    dict(prefill_chunk=64)],
+                         ids=["enable_prefix_cache", "prefill_chunk"])
+def test_bank_is_refused_with_the_cached_admission(option):
+    """Block hashes do not name the adapter and the continuation prefill
+    applies none: refused at construction, not served wrong."""
+    cfg = _tiny_cfg()
+    with pytest.raises(ValueError, match="max_loras.*" + next(iter(option))):
+        TPUEngine(cfg, _params(cfg), max_slots=2, max_len=128, max_loras=1,
+                  lora_rank=RANK, **option)
 
 
 def test_load_unload_refcounts():

@@ -39,14 +39,50 @@ def test_paged_engine_matches_full_forward(tiny_model):
 
     cfg, params = tiny_model
     eng = TPUEngine(cfg, params, max_slots=4, max_len=64, min_bucket=8,
-                    kv_layout="paged", page_size=8)
+                    page_size=8)
     try:
         prompt = [1, 5, 9, 2, 7]
         out = eng.generate(prompt, SamplingParams(max_tokens=8, temperature=0.0))
         assert out == _naive_greedy(params, cfg, prompt, 8)
         st = eng.stats()
-        assert st["kv_layout"] == "paged"
         assert st["free_pages"] == st["num_pages"] - 1  # all returned (0=scratch)
+    finally:
+        eng.shutdown()
+
+
+def _family(name):
+    from ray_tpu.models import gpt2_config, kimi_vl_config, mixtral_config
+    from ray_tpu.models.transformer import MoEConfig
+
+    if name == "gpt2":      # dense, learned positions, biases, tied head
+        return gpt2_config("124m", vocab_size=211, max_seq_len=128, d_model=64,
+                           n_layers=2, n_heads=4, d_ff=128, dtype=jnp.float32)
+    if name == "mixtral":   # dropless sparse experts, grouped-query attention, rope
+        return mixtral_config("tiny", vocab_size=300, max_seq_len=128, d_model=64,
+                              n_layers=2, n_heads=4, n_kv_heads=2, d_ff=96,
+                              dtype=jnp.float32,
+                              moe=MoEConfig(num_experts=8, top_k=2, capacity_factor=4.0))
+    return kimi_vl_config("tiny", vocab_size=300, max_seq_len=128, dtype=jnp.float32,
+                          n_layers=3, n_dense_layers=1)   # latent cache, a dense layer
+
+
+@pytest.mark.parametrize("family", ["gpt2", "mixtral", "kimi_vl"])
+def test_default_engine_matches_full_forward(family):
+    """An engine given nothing but sizes (no option that selects a path)
+    decodes what the model's full forward decodes, for each kind of model
+    the cells serve, two prompts sharing the steps."""
+    from ray_tpu.llm import SamplingParams, TPUEngine
+
+    cfg = _family(family)
+    params = transformer.init(jax.random.PRNGKey(1), cfg)
+    eng = TPUEngine(cfg, params, max_slots=2, max_len=128)
+    try:
+        prompts = [[1, 5, 9, 2, 7], [3] * 70]
+        reqs = [eng.submit(p, SamplingParams(max_tokens=6)) for p in prompts]
+        assert [list(r) for r in reqs] == [_naive_greedy(params, cfg, p, 6)
+                                           for p in prompts]
+        st = eng.stats()
+        assert st["page_size"] == 64 and st["free_pages"] == st["num_pages"] - 1
     finally:
         eng.shutdown()
 
@@ -56,7 +92,7 @@ def test_paged_concurrent_sequences_isolated(tiny_model):
 
     cfg, params = tiny_model
     eng = TPUEngine(cfg, params, max_slots=4, max_len=64, min_bucket=8,
-                    kv_layout="paged", page_size=8)
+                    page_size=8)
     try:
         prompts = [[1, 5, 9], [3, 3, 8, 2], [7], [2, 4, 6, 8, 10]]
         want = [_naive_greedy(params, cfg, p, 6) for p in prompts]
@@ -85,7 +121,7 @@ def test_paged_pool_pressure_backlogs_then_completes(tiny_model):
     # each sequence needs ~3 pages (bucket 8 + 16 generated → pages to pos 24
     # at page 8); pool of 7 usable pages → only 2 sequences fit at once
     eng = TPUEngine(cfg, params, max_slots=4, max_len=64, min_bucket=8,
-                    kv_layout="paged", page_size=8, num_pages=8)
+                    page_size=8, num_pages=8)
     try:
         prompts = [[1, 5, 9], [3, 3, 8, 2], [7, 1], [2, 4, 6]]
         want = [_naive_greedy(params, cfg, p, 16) for p in prompts]
@@ -138,7 +174,7 @@ def test_tensor_parallel_paged_engine(tiny_model):
         pytest.skip("needs >=2 devices")
     mesh = Mesh(devs[:2], ("tp",))
     eng = TPUEngine(cfg, params, max_slots=2, max_len=64, min_bucket=8,
-                    kv_layout="paged", page_size=8, mesh=mesh)
+                    page_size=8, mesh=mesh)
     try:
         prompt = [3, 1, 4, 1, 5]
         out = eng.generate(prompt, SamplingParams(max_tokens=6, temperature=0.0))
@@ -152,7 +188,7 @@ def test_paged_infeasible_request_rejected_up_front(tiny_model):
 
     cfg, params = tiny_model
     eng = TPUEngine(cfg, params, max_slots=2, max_len=64, min_bucket=8,
-                    kv_layout="paged", page_size=8, num_pages=4)
+                    page_size=8, num_pages=4)
     try:
         with pytest.raises(ValueError, match="KV pages"):
             eng.submit(list(range(40)), SamplingParams(max_tokens=16))
@@ -170,7 +206,7 @@ def test_paged_backlog_revived_after_idle(tiny_model):
 
     cfg, params = tiny_model
     eng = TPUEngine(cfg, params, max_slots=2, max_len=64, min_bucket=8,
-                    kv_layout="paged", page_size=8, num_pages=7)
+                    page_size=8, num_pages=7)
     try:
         # first request takes most pages; second must wait, then complete
         a = eng.submit(list(range(20)), SamplingParams(max_tokens=20))
@@ -187,6 +223,6 @@ def test_paged_constructor_validation(tiny_model):
 
     cfg, params = tiny_model
     with pytest.raises(ValueError, match="power of two"):
-        TPUEngine(cfg, params, kv_layout="paged", page_size=0, max_len=64)
+        TPUEngine(cfg, params, page_size=0, max_len=64)
     with pytest.raises(ValueError, match="multiple of"):
-        TPUEngine(cfg, params, kv_layout="paged", page_size=32, max_len=72)
+        TPUEngine(cfg, params, page_size=32, max_len=72)

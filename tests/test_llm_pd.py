@@ -43,7 +43,6 @@ def _paged_engine(cfg, params, **kw):
     kw.setdefault("max_slots", 4)
     kw.setdefault("max_len", MAX_LEN)
     kw.setdefault("min_bucket", PAGE)
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("page_size", PAGE)
     return TPUEngine(cfg, params, **kw)
 
@@ -457,14 +456,12 @@ def test_submit_prefilled_kv_stream_validation(tiny_model):
         dec.shutdown()
 
 
-def test_submit_prefilled_pages_on_slot_engine(tiny_model):
-    """A slot-layout decode engine still accepts page-form packs (stitch
-    fallback) and the legacy whole-array form — both token-exact."""
+def test_submit_prefilled_whole_arrays_land_in_pages(tiny_model):
+    """The decode engine accepts page-form packs and the legacy whole-array
+    form (one write of the bucket into granted pages) — both token-exact."""
     cfg, params = tiny_model
-    slot_ref = TPUEngine(cfg, params, max_slots=2, max_len=MAX_LEN,
-                         min_bucket=PAGE)
-    dec = TPUEngine(cfg, params, max_slots=2, max_len=MAX_LEN,
-                    min_bucket=PAGE)
+    slot_ref = _paged_engine(cfg, params, max_slots=2)
+    dec = _paged_engine(cfg, params, max_slots=2)
     exporter = PagedKVExporter(send_timeout_s=10.0)
     sp = SamplingParams(max_tokens=8, temperature=0.0)
     prompt = [1, 5, 9, 2, 7]

@@ -31,7 +31,6 @@ def _engine(cfg, params, **kw):
     kw.setdefault("max_slots", 4)
     kw.setdefault("max_len", 64)
     kw.setdefault("min_bucket", 8)
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("page_size", 8)
     kw.setdefault("enable_prefix_cache", True)
     return TPUEngine(cfg, params, **kw)
@@ -138,12 +137,6 @@ def test_concurrent_mixed_prompts(tiny_model):
             assert o == _naive_greedy(params, cfg, p, 5), p
     finally:
         eng.shutdown()
-
-
-def test_prefix_cache_requires_paged_layout(tiny_model):
-    cfg, params = tiny_model
-    with pytest.raises(ValueError, match="paged"):
-        TPUEngine(cfg, params, kv_layout="slot", enable_prefix_cache=True)
 
 
 def test_stats_surface(tiny_model):

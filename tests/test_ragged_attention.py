@@ -2,9 +2,8 @@
 
 The decode step's acceptance contract (ISSUE 15): the Pallas kernel
 (interpret mode on CPU) is BIT-consistent with the pure-JAX reference the
-CPU engine decodes with, the ragged step agrees with the legacy
-gather-per-slot step, and an engine running attn_impl="ragged" is
-token-exact against one running "gather".
+CPU engine decodes with, and the ragged step agrees with the model's full
+forward over the same tokens.
 """
 
 import jax
@@ -12,7 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.llm.engine import SamplingParams, TPUEngine
 from ray_tpu.models import decoding, decoding_paged as dp, transformer
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.ops.ragged_paged_attention import (
@@ -104,62 +102,49 @@ def _mixed_state(cfg, params, *, lengths, P=PAGE, max_len=MAX_LEN, spare=0):
     return state
 
 
-def test_decode_step_ragged_matches_gather(tiny_model):
-    """Multi-step agreement on a mixed-length batch, at a tight page
-    bound AND the full table."""
+def test_decode_step_ragged_matches_the_full_forward(tiny_model):
+    """Multi-step agreement on a mixed-length batch, at a tight page bound
+    AND the full table: every row's logits are those of `transformer.forward`
+    over the row's tokens so far."""
     cfg, params = tiny_model
     # max length + steps stays inside the 2-page bound (the engine
     # recomputes the bound per step; here it is pinned)
     lengths = [3, 17, 27, 9]
     state = _mixed_state(cfg, params, lengths=lengths)
     MP = MAX_LEN // PAGE
-
-    def cp(s):
-        return {k: jnp.array(v) for k, v in s.items()}
+    rows = [list(1 + np.arange(n) % (cfg.vocab_size - 2)) + [int(t)]
+            for n, t in zip(lengths, np.asarray(state["last_token"]))]
 
     for _step in range(3):
-        s_g, l_g = dp.decode_step_paged(params, cp(state), cfg)
-        s_r, l_r = dp.decode_step_paged_ragged(params, cp(state), cfg, 2,
-                                               False)
-        s_f, l_f = dp.decode_step_paged_ragged(params, cp(state), cfg, MP,
-                                               False)
-        np.testing.assert_allclose(np.asarray(l_g), np.asarray(l_r),
-                                   atol=2e-5, rtol=1e-5)
-        np.testing.assert_allclose(np.asarray(l_g), np.asarray(l_f),
-                                   atol=2e-5, rtol=1e-5)
-        assert np.array_equal(np.argmax(np.asarray(l_g), -1),
-                              np.argmax(np.asarray(l_r), -1))
-        state = s_g
+        want = np.stack([np.asarray(transformer.forward(
+            params, jnp.asarray([toks]), cfg)[0][0, -1]) for toks in rows])
+        _, l_f = dp.decode_step_paged_ragged(params, _copy(state), cfg, MP, False)
+        state, l_r = dp.decode_step_paged_ragged(params, state, cfg, 2, False)
+        np.testing.assert_allclose(np.asarray(l_r), want, atol=2e-5, rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(l_f), want, atol=2e-5, rtol=1e-5)
+        toks = np.argmax(want, -1).astype(np.int32)
+        assert np.array_equal(np.argmax(np.asarray(l_r), -1), toks)
+        state = decoding.commit_tokens(state, jnp.asarray(toks))
+        for toks_row, t in zip(rows, toks):
+            toks_row.append(int(t))
 
 
 # ---- the in-place step (pools as the layer scan's carry) against the form
 # it replaced: pools scanned in per layer and stacked back out
 
 
-def _gather_attention(qh, kp, vp, tbl, pos, *, scale, dt):
-    """decode_step_paged's core: every row's whole table gathered, masked."""
-    B, Hkv, _, Dh = qh.shape
-    S = tbl.shape[1] * kp.shape[1]
-    k_cache = kp[tbl].reshape(B, S, Hkv, Dh)
-    v_cache = vp[tbl].reshape(B, S, Hkv, Dh)
-    scores = jnp.einsum("bkgd,bskd->bkgs", qh, k_cache.astype(dt)) / (Dh ** 0.5)
-    mask = jnp.arange(S)[None, :] <= pos[:, None]
-    scores = jnp.where(mask[:, None, None, :], scores.astype(jnp.float32), -1e30)
-    w = jax.nn.softmax(scores, axis=-1).astype(dt)
-    return jnp.einsum("bkgs,bskd->bkgd", w, v_cache.astype(dt))
-
-
 def _stacked_step(cfg, attend, pages_bound=None):
     """The decode step as it stood before the pools rode the scan's carry,
     kept here as the oracle: `state["kp"]`, `state["vp"]` are scanned INPUTS,
     each layer scatters into its own [num_pages, P, Hkv, Dh] slice and the
-    slices are stacked back as OUTPUTS. `attend` is the attention core."""
+    slices are stacked back as OUTPUTS. `attend` is the attention core. With
+    a LoRA bank the bank's layers are scanned inputs too."""
     from ray_tpu import ops
     from ray_tpu.models.decoding import _attn_qkv, _mlp_block, _rope
     from ray_tpu.models.transformer import _norm
 
     @jax.jit
-    def step(params, state):
+    def step(params, state, lora_bank=None, slot_lora=None):
         dt = cfg.dtype
         B = state["block"].shape[0]
         P = state["kp"].shape[2]
@@ -174,10 +159,12 @@ def _stacked_step(cfg, attend, pages_bound=None):
             x = x + params["pos_embed"].astype(dt)[pos][:, None]
         cos, sin = _rope(cfg)
         G = cfg.n_heads // cfg.kv_heads
+        lscale = None if lora_bank is None else lora_bank["scale"][slot_lora]
 
         def block(h, layer_in):
-            layer_p, kp, vp = layer_in
-            q, k, v = _attn_qkv(_norm(h, layer_p["norm1"], cfg), layer_p["attn"], cfg)
+            layer_p, kp, vp, *lora_l = layer_in
+            q, k, v = _attn_qkv(_norm(h, layer_p["norm1"], cfg), layer_p["attn"], cfg,
+                                lora_l, slot_lora, lscale)
             if cfg.pos == "rope":
                 q = ops.apply_rope(q, cos, sin, positions=pos[:, None])
                 k = ops.apply_rope(k, cos, sin, positions=pos[:, None])
@@ -193,7 +180,10 @@ def _stacked_step(cfg, attend, pages_bound=None):
             h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
             return h, (kp, vp)
 
-        x, (kp, vp) = jax.lax.scan(block, x, (params["layers"], state["kp"], state["vp"]))
+        bank = () if lora_bank is None else tuple(
+            lora_bank[k] for k in ("A_q", "B_q", "A_v", "B_v"))
+        x, (kp, vp) = jax.lax.scan(
+            block, x, (params["layers"], state["kp"], state["vp"]) + bank)
         x = _norm(x, params["final_norm"], cfg)
         head = params["embed"].astype(dt).T if cfg.tie_embeddings else params["lm_head"].astype(dt)
         return {**state, "kp": kp, "vp": vp,
@@ -219,29 +209,40 @@ def kernel_interpreted(monkeypatch):
                         lambda *a, interpret, **kw: real(*a, interpret=True, **kw))
 
 
-# the step under test, and the attention core and page bound of its oracle
-STEPS = {
-    "gather": (lambda p, s, cfg: dp.decode_step_paged(p, s, cfg),
-               _gather_attention, None),
-    "ragged-reference": (lambda p, s, cfg: dp.decode_step_paged_ragged(p, s, cfg, 4, False),
-                         _ragged_core("reference"), 4),
-    "ragged-kernel": (lambda p, s, cfg: dp.decode_step_paged_ragged(p, s, cfg, 4, True),
-                      _ragged_core("kernel"), 4),
-}
+# the step's attention code, and the attention core of its oracle
+STEPS = {"ragged-reference": (False, _ragged_core("reference")),
+         "ragged-kernel": (True, _ragged_core("kernel"))}
+
+
+def _lora_bank(cfg, rank=4):
+    """Two random adapters (bank rows 1 and 2; row 0 is the null adapter)."""
+    rng = np.random.default_rng(5)
+    bank = decoding.init_lora_bank(cfg, 2, rank)
+    for key in ("A_q", "B_q", "A_v", "B_v"):
+        w = rng.normal(0, 0.1, bank[key].shape).astype(np.float32)
+        w[:, 0] = 0.0
+        bank[key] = jnp.asarray(w)
+    bank["scale"] = jnp.asarray([0.0, 0.5, 1.0], jnp.float32)
+    return bank
 
 
 def _copy(state):
     return {k: jnp.array(v) for k, v in state.items()}
 
 
+@pytest.mark.parametrize("lora", [False, True], ids=["base", "lora"])
 @pytest.mark.parametrize("name", list(STEPS))
-def test_in_place_step_is_bit_equal_to_the_stacked_scan(tiny_model, kernel_interpreted, name):
+def test_in_place_step_is_bit_equal_to_the_stacked_scan(tiny_model, kernel_interpreted,
+                                                        name, lora):
     """Four steps over a mixed-length batch with one INACTIVE slot, an
     admission (`write_kv_pages` + `activate_slot`) and a release between
-    them: logits and both pools equal the oracle's to every bit."""
+    them: logits and both pools equal the oracle's to every bit. `lora`: the
+    rows carry the null adapter, two adapters and (the admitted row) the
+    first again, and the adapters move the logits."""
     cfg, params = tiny_model
-    step, core, bound = STEPS[name]
-    oracle = _stacked_step(cfg, core, bound)
+    kernel, core = STEPS[name]
+    oracle = _stacked_step(cfg, core, 4)
+    adapters = (_lora_bank(cfg), jnp.asarray([0, 1, 2, 1], jnp.int32)) if lora else ()
     MP = MAX_LEN // PAGE
     state = _mixed_state(cfg, params, lengths=[3, 17, 27], spare=1)
     want = _copy(state)
@@ -260,9 +261,14 @@ def test_in_place_step_is_bit_equal_to_the_stacked_scan(tiny_model, kernel_inter
     for i in range(4):
         if i in between:
             state, want = between[i](state), between[i](want)
-        state, logits = step(params, state, cfg)
-        want, logits_want = oracle(params, want)
+        if lora:
+            _, base_logits = dp.decode_step_paged_ragged(params, _copy(state), cfg, 4, kernel)
+        state, logits = dp.decode_step_paged_ragged(params, state, cfg, 4, kernel, *adapters)
+        want, logits_want = oracle(params, want, *adapters)
         assert np.array_equal(np.asarray(logits), np.asarray(logits_want)), (name, i)
+        if lora:  # row 0 is the base model's to the bit, the live adapter rows are not
+            moved = np.abs(np.asarray(logits) - np.asarray(base_logits)).max(-1)
+            assert moved[0] == 0 and (moved[[1, 2] if i < 2 else [2, 3]] > 1e-3).all(), moved
         for key in ("kp", "vp", "length"):
             assert np.array_equal(np.asarray(state[key]), np.asarray(want[key])), (name, i, key)
         assert state["kp"].shape == (cfg.n_layers, 4 * MP + 1, PAGE, cfg.kv_heads, cfg.head_dim)
@@ -286,42 +292,9 @@ def test_an_inactive_row_writes_only_its_layers_scratch_page(tiny_model):
              "length": jnp.asarray([5, 20], jnp.int32),
              "last_token": jnp.asarray([7, 9], jnp.int32)}
     before = {k: np.asarray(state[k]) for k in ("kp", "vp")}
-    for step in (lambda s: dp.decode_step_paged(params, s, cfg),
-                 lambda s: dp.decode_step_paged_ragged(params, s, cfg, 4, False)):
-        after, _ = step(_copy(state))
-        for key in ("kp", "vp"):
-            got = np.asarray(after[key])
-            assert np.array_equal(got[:, 1:], before[key][:, 1:])
-            assert all((got[l, 0] != before[key][l, 0]).any() for l in range(cfg.n_layers))
-        assert np.array_equal(np.asarray(after["length"]), [5, 20])
-
-
-def test_engine_ragged_token_exact_vs_gather(tiny_model):
-    """End to end: a ragged engine generates EXACTLY what the gather
-    engine does, across mixed prompt lengths in one continuous batch."""
-    cfg, params = tiny_model
-    kw = dict(max_slots=4, max_len=MAX_LEN, min_bucket=PAGE,
-              kv_layout="paged", page_size=PAGE)
-    ragged = TPUEngine(cfg, params, attn_impl="ragged", **kw)
-    gather = TPUEngine(cfg, params, attn_impl="gather", **kw)
-    sp = SamplingParams(max_tokens=8, temperature=0.0)
-    prompts = [[1, 5, 9], [3] * 20, list(range(2, 35)), [7] * 2]
-    try:
-        assert ragged.stats()["attn_impl"] == "ragged"
-        assert gather.stats()["attn_impl"] == "gather"
-        want = [gather.generate(p, sp) for p in prompts]
-        # concurrent submission: the batch really mixes lengths
-        reqs = [ragged.submit(p, sp) for p in prompts]
-        got = [list(r) for r in reqs]
-        assert got == want
-    finally:
-        ragged.shutdown()
-        gather.shutdown()
-
-
-def test_engine_attn_impl_validation(tiny_model):
-    cfg, params = tiny_model
-    with pytest.raises(ValueError, match="attn_impl"):
-        TPUEngine(cfg, params, max_slots=2, max_len=MAX_LEN,
-                  min_bucket=PAGE, kv_layout="paged", page_size=PAGE,
-                  attn_impl="blocked")
+    after, _ = dp.decode_step_paged_ragged(params, _copy(state), cfg, 4, False)
+    for key in ("kp", "vp"):
+        got = np.asarray(after[key])
+        assert np.array_equal(got[:, 1:], before[key][:, 1:])
+        assert all((got[l, 0] != before[key][l, 0]).any() for l in range(cfg.n_layers))
+    assert np.array_equal(np.asarray(after["length"]), [5, 20])

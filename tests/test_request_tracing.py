@@ -333,7 +333,7 @@ def test_engine_phase_histograms(monkeypatch):
         return {}
 
     before = totals()
-    eng = TPUEngine(tiny, params, max_slots=2, max_len=32)
+    eng = TPUEngine(tiny, params, max_slots=2, max_len=32, page_size=16)
     try:
         toks = eng.generate([1, 2, 3], SamplingParams(max_tokens=4))
         assert len(toks) == 4
@@ -348,7 +348,7 @@ def test_engine_phase_histograms(monkeypatch):
     RayConfig.reset()
     try:
         base = totals()
-        eng2 = TPUEngine(tiny, params, max_slots=2, max_len=32)
+        eng2 = TPUEngine(tiny, params, max_slots=2, max_len=32, page_size=16)
         try:
             eng2.generate([1, 2, 3], SamplingParams(max_tokens=4))
         finally:
@@ -356,6 +356,42 @@ def test_engine_phase_histograms(monkeypatch):
         assert totals() == base
     finally:
         RayConfig.reset()
+
+
+def test_decode_step_histogram_is_labelled_with_the_code_that_runs():
+    """`ray_tpu_llm_decode_step_seconds{impl}` carries stats()["decode_attn"]
+    (on the CPU: the reference form), one observation a decode step."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm.engine import SamplingParams, TPUEngine
+    from ray_tpu.models import transformer
+    from ray_tpu.models.transformer import TransformerConfig
+    from ray_tpu.util import metrics as met
+
+    tiny = TransformerConfig(vocab_size=64, d_model=32, n_layers=1,
+                             n_heads=2, n_kv_heads=2, d_ff=64,
+                             max_seq_len=64, dtype=jnp.float32, remat=False)
+
+    def counts():
+        for m in met.snapshot():
+            if m["name"] == "ray_tpu_llm_decode_step_seconds":
+                return {dict(tuple(t) for t in tags)["impl"]: st["count"]
+                        for tags, st in m["series"]}
+        return {}
+
+    before = counts()
+    eng = TPUEngine(tiny, transformer.init(jax.random.PRNGKey(0), tiny),
+                    max_slots=2, max_len=64)
+    try:
+        eng.generate([1, 2, 3], SamplingParams(max_tokens=5))
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    after = counts()
+    assert st["decode_attn"] == "ragged_reference" and set(after) == {"ragged_reference"}
+    assert (after["ragged_reference"] - before.get("ragged_reference", 0)
+            == st["decode_steps"] == 4)
 
 
 # ------------------------------------------------- engine spans (ISSUE 24)
@@ -369,7 +405,7 @@ def _tiny_llm_app():
                                                 tokenizer="byte"),
         model_family="llama", accelerator_type=None,
         engine_kwargs=dict(max_slots=2, max_len=128, min_bucket=16,
-                           kv_layout="paged", page_size=16,
+                           page_size=16,
                            enable_prefix_cache=True, prefill_chunk=16))
     serve.start(http_port=0)
     serve.run(build_openai_app(cfg), name="llm", route_prefix="/v1")

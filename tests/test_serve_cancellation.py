@@ -49,7 +49,6 @@ def _paged_engine(cfg, params, **kw):
     kw.setdefault("max_slots", 2)
     kw.setdefault("max_len", 64)
     kw.setdefault("min_bucket", 8)
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("page_size", 8)
     return TPUEngine(cfg, params, **kw)
 

@@ -32,8 +32,11 @@ pytestmark = pytest.mark.store_matrix
 BACKENDS = ("arena", "file")
 
 
-def _shm_entries() -> set:
-    return set(glob.glob(os.path.join(SHM_DIR, SHM_SESSION_PREFIX + "*")))
+def _shm_entries(session_id: str) -> set:
+    """This session's segments only: other sessions on the machine (xdist
+    workers run theirs at the same time) come and go under the same prefix."""
+    return set(glob.glob(os.path.join(
+        SHM_DIR, f"{SHM_SESSION_PREFIX}{session_id}_*")))
 
 
 @pytest.fixture(params=BACKENDS)
@@ -47,11 +50,10 @@ def backend(request, monkeypatch):
 @pytest.fixture
 def backend_session(backend):
     ray_tpu.shutdown()
-    before = _shm_entries()
-    ray_tpu.init(num_cpus=8, num_workers=1, max_workers=8)
+    session_id = ray_tpu.init(num_cpus=8, num_workers=1, max_workers=8)["session_id"]
     yield backend
     ray_tpu.shutdown()
-    leaked = _shm_entries() - before
+    leaked = _shm_entries(session_id)
     assert not leaked, f"/dev/shm leak under backend={backend}: {leaked}"
 
 
@@ -71,6 +73,8 @@ def test_object_lifecycle(backend_session):
     refs = [ray_tpu.put(np.full(20_000, i, np.float64)) for i in range(20)]
     for i, r in enumerate(refs):
         assert ray_tpu.get(r)[0] == i
+    # live objects carry the names the fixture's leak check looks for
+    assert _shm_entries(_api._worker.session_id)
 
 
 def test_spilling_past_budget(backend, monkeypatch):

@@ -133,10 +133,7 @@ def test_paged_decode_step_with_kernel_compiles(chip):
     assert "tpu_custom_call" in text
 
 
-# (step, max_len): the gather step's own [slots, max_len] view of every row's
-# pages is a temporary whatever the pool does (0.55 GB each for K and V at the
-# cell's 8320), so it is compiled at a max_len that keeps the view small
-@pytest.mark.parametrize("step,max_len", [("ragged", 8320), ("gather", 256)])
+@pytest.mark.parametrize("step,max_len", [("ragged", 8320)])
 def test_paged_decode_step_holds_the_pool_once(chip, step, max_len):
     """The decode program at Mixtral widths as `mixtral-8x7b.chat-steady`
     runs it (4 layers, 1,024 pages of 64, 32 slots): the page pools are
@@ -149,10 +146,7 @@ def test_paged_decode_step_holds_the_pool_once(chip, step, max_len):
     cfg = mixtral_config("8x7b", n_layers=4, param_dtype=jnp.bfloat16, max_seq_len=32768,
                          moe=MoEConfig(num_experts=8, top_k=2, capacity_factor=4.0))
     params, state = _abstract_step_inputs(chip, cfg, 32, max_len, 1024, 64)
-    if step == "ragged":
-        lowered = decoding_paged.decode_step_paged_ragged.lower(params, state, cfg, 32, True)
-    else:
-        lowered = decoding_paged.decode_step_paged.lower(params, state, cfg)
+    lowered = decoding_paged.decode_step_paged_ragged.lower(params, state, cfg, 32, True)
     m = lowered.compile().memory_analysis()
     pools = 2 * 4 * 1024 * 64 * cfg.kv_heads * cfg.head_dim * 2
     assert m.temp_size_in_bytes < 64 * 2**20
