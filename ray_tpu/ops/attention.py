@@ -2,8 +2,9 @@
 
 Models call `attention(q, k, v, ...)` with [B, T, H, D] activations (GQA
 allowed: fewer KV heads). On TPU the Pallas flash kernel runs; elsewhere (or
-for odd shapes) the XLA reference path does — same numerics, so tests on the
-CPU mesh validate the model code that the TPU executes.
+for odd shapes) the XLA reference path does — the same numerics to the
+rounding of the operands' dtype (flash_attention.py, "Precision"), so tests
+on the CPU mesh validate the model code that the TPU executes.
 """
 
 from __future__ import annotations
@@ -31,20 +32,19 @@ def repeat_kv(k, *, n_rep: int):
 def _flash_ok(q) -> bool:
     if q.shape[1] % 256 != 0:  # seq must tile into flash blocks
         return False
-    # measured on v5e (benchmarks/attn_bench.py, b8 h16 d128): the Pallas
-    # kernel wins from seq 1024 up once fwd AND bwd are kernels — 2.4x at
-    # s2048 (12.96 vs 31.22 ms fwd+bwd) — and is the only path that runs at
+    # measured on v5e with the kernels as they were before PR 31
+    # (benchmarks/attn_bench.py, b8 h16 d128): the Pallas kernel won from seq
+    # 1024 up once fwd AND bwd were kernels — 2.4x at s2048 (12.96 vs 31.22 ms
+    # fwd+bwd); PR 31's run 1.4 to 2.6 times faster than those and the
+    # threshold was not measured again — and it is the only path that runs at
     # s4096+ (XLA's quadratic score tensor OOMs HBM)
     return jax.default_backend() == "tpu" and q.shape[1] >= 1024
 
 
 def _flash(q, k, v, *, causal: bool, scale: float | None):
-    T = q.shape[1]
-    # best measured block size (benchmarks/attn_bench.py), falling back
-    # to 256 for seqs that don't tile into 512
-    blk = 512 if T % 512 == 0 else min(256, T)
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    out = flash_attention(qt, kt, vt, causal, scale, blk, blk)
+    # block sizes: the kernels' own choice for this T (flash_attention.py)
+    out = flash_attention(qt, kt, vt, causal, scale)
     return out.transpose(0, 2, 1, 3)
 
 

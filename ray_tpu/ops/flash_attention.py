@@ -3,17 +3,41 @@
 Blocked online-softmax attention: forward grid (B, H, nq, nk) with the kv
 dimension innermost so the f32 accumulators live in VMEM scratch across kv
 steps and the MXU sees [block_q, D] x [D, block_k] matmuls. Causal blocks
-above the diagonal are skipped via predication. The forward also emits the
-per-row logsumexp so the backward never rebuilds the softmax normalizer.
+above the diagonal are skipped via predication; only a block the diagonal
+crosses builds the mask, and a square one is computed in strips that leave
+out most of what lies above the diagonal inside it (`_diagonal_tiles`). The
+running max and sum of a row are kept on all 128 lanes of the row's vreg, in
+scratch and in flight: narrowed to a [block_q, 1] column at every kv step
+they cost the forward kernel as much again as all the rest of it (v5e, PR
+31: 573 against 312 us a call at [4, 20, 1024, 64], blocks of 512). The
+forward also emits the per-row logsumexp so the backward never rebuilds the
+softmax normalizer.
 
 Backward is the standard two-kernel flash decomposition (no [T, T] score
 tensor is ever materialized):
   - dkv kernel, grid (B, H, nk, nq): for a fixed kv block, sweep q blocks
-    accumulating dv += p^T dO and dk += ds^T q in VMEM scratch.
+    accumulating dv += p^T dO and dk += ds^T q in VMEM scratch. It computes
+    the scores already transposed (k q^T, [block_k, block_q]; lse and delta
+    arrive as rows), so both accumulating products are plain and what is
+    transposed for the MXU is a [block, D] operand, never a score block.
   - dq kernel, grid (B, H, nq, nk): for a fixed q block, sweep kv blocks
     accumulating dq += ds k.
 where p = exp(s - lse) is recomputed blockwise from the saved logsumexp and
 delta = rowsum(dO * O) folds the softmax Jacobian into ds = p * (dp - delta).
+
+Precision: every product takes its operands in the dtype they are stored in
+(q, k, v, dO as read from their refs) and accumulates in float32. The
+softmax (scores, scale, mask, running max and sum, exp, lse, delta) and the
+scratch accumulators are float32. `p` and `ds` are rounded to the operand
+dtype before their second product (p @ v, p^T dO, ds^T q, ds @ k): the one
+rounding beyond the operands' own. With float32 operands the kernels' own
+code rounds nothing; with bfloat16 operands the result agrees with a float32
+reference to bfloat16 rounding (2e-3 of its norm, 1.25 times what rounding
+the reference itself to bfloat16 costs), not bit for bit. Until PR 31 the
+kernels cast every operand to float32 first. On the chip that bought nothing
+and cost nothing: at Mosaic's default precision a float32 product is one
+bfloat16 pass of the MXU, which rounds `p` and `ds` just as the cast here
+does, so those kernels gave these results to the sixth digit in the same time.
 
 (The reference framework has no attention kernels at all — attention lives in
 vLLM/torch; this is the TPU-native compute path that replaces it.)
@@ -29,8 +53,89 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
-DEFAULT_BLOCK_Q = 256
-DEFAULT_BLOCK_K = 256
+# Block sizes in order of preference: what a caller that names none gets is
+# the first that divides T. All three kernels are fastest at 1024 on a v5e
+# (PR 31: bf16, causal, d_head 64 and 128, T 1024 to 4096): they are bound by
+# their softmax's VPU work and by fixed work a grid step, not by VMEM, so they
+# take long blocks and leave the diagonal's savings to the strips inside one
+# (_DIAG_STRIP).
+_BLOCK_CHOICES = (1024, 512, 256, 128)
+
+
+def _blocks(T: int, block_q: int | None, block_k: int | None):
+    """The caller's block sizes, or for None the best that tiles T."""
+    def fit(asked):
+        if asked is not None:
+            return min(asked, T)
+        return next((b for b in _BLOCK_CHOICES if T % b == 0), T)
+
+    block_q, block_k = fit(block_q), fit(block_k)
+    if T % block_q or T % block_k:
+        raise ValueError(f"T={T} must be divisible by block sizes {block_q},{block_k}")
+    return block_q, block_k
+
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    """Operands as stored, float32 accumulation."""
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _causal_mask(s, q0, k0, *, q_axis: int):
+    """Scores of the queries from position q0 on (along `q_axis`) against the
+    keys from k0 on (along the other axis): keys after the query masked out."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+
+
+def _lanes(x, n: int):
+    """x: [rows, 128] holding a row's value on every lane -> [rows, n]."""
+    if n <= x.shape[1]:
+        return x[:, :n]
+    return pltpu.repeat(x, n // x.shape[1], axis=1)
+
+
+_DIAG_STRIP = 256
+
+
+def _diagonal_tiles(block: int, *, strip_of: str):
+    """The (queries, keys) slices to compute of a square block whose corners
+    the diagonal runs through: strips of _DIAG_STRIP queries, each against the
+    keys up to its last query (`strip_of="q"`), or strips of keys, each against
+    the queries from its first key on ("k"). What lies above the diagonal
+    beyond a strip's own square is not computed: of a block of 1024, 5/8 is,
+    where the part under the diagonal is 4/8."""
+    if block <= _DIAG_STRIP or block % _DIAG_STRIP:
+        return [(slice(0, block), slice(0, block))]
+    edges = range(0, block, _DIAG_STRIP)
+    if strip_of == "q":
+        return [(slice(e, e + _DIAG_STRIP), slice(0, e + _DIAG_STRIP)) for e in edges]
+    return [(slice(e, block), slice(e, e + _DIAG_STRIP)) for e in edges]
+
+
+def _when_live(i, j, *, causal, block_q, block_k, strip_of="q"):
+    """Decorator running `body(tiles, masked)` for q block i and kv block j,
+    `tiles` the (queries, keys) slices of the block to compute: not at all for
+    a causal block wholly above the diagonal (its first key lies after its
+    last query); masked for a block the diagonal crosses (its last key lies
+    after its first query), in strips if it is square (`_diagonal_tiles`: then
+    i == j); whole and without the mask below the diagonal."""
+    whole = [(slice(0, block_q), slice(0, block_k))]
+
+    def run(body):
+        if not causal:
+            body(whole, False)
+            return
+        live = j * block_k <= (i + 1) * block_q - 1
+        crossed = (j + 1) * block_k - 1 > i * block_q
+        diagonal = (_diagonal_tiles(block_q, strip_of=strip_of)
+                    if block_q == block_k else whole)
+        pl.when(live & crossed)(functools.partial(body, diagonal, True))
+        pl.when(live & jnp.logical_not(crossed))(functools.partial(body, whole, False))
+    return run
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
@@ -45,47 +150,37 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal: kv block j is live iff its first key position <= last q position
-    live = (j * block_k <= (i + 1) * block_q - 1) if causal else True
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)          # [block_q, D]
-        k = k_ref[0, 0].astype(jnp.float32)          # [block_k, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                     # [block_q, block_k]
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_prev = m_scr[:, :1]                         # [block_q, 1]
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    @_when_live(i, j, causal=causal, block_q=block_q, block_k=block_k)
+    def _compute(tiles, masked):
+        for rows, keys in tiles:
+            v = v_ref[0, 0, keys, :]                      # [keys, D]
+            s = _dot(q_ref[0, 0, rows, :], k_ref[0, 0, keys, :], _NT) * scale
+            if masked:                                    # [rows, keys]
+                s = _causal_mask(s, i * block_q + rows.start,
+                                 j * block_k + keys.start, q_axis=0)
+            m_prev = m_scr[rows, :]                       # [rows, 128]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, s.shape[1]))
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[rows, :] = l_scr[rows, :] * corr + p.sum(axis=-1, keepdims=True)
+            acc_scr[rows, :] = (acc_scr[rows, :] * _lanes(corr, acc_scr.shape[1])
+                                + _dot(p.astype(v.dtype), v))
+            m_scr[rows, :] = m_new
 
     @pl.when(j == nk - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[:, :1] + jnp.log(l)
+        l = jnp.maximum(l_scr[:], 1e-30)
+        o_ref[0, 0] = (acc_scr[:] / _lanes(l, acc_scr.shape[1])).astype(o_ref.dtype)
+        lse_ref[0, 0] = (m_scr[:] + jnp.log(l))[:, :1]
 
 
-def _fwd_call(q, k, v, *, causal: bool, scale: float, block_q: int,
-              block_k: int, interpret: bool):
+def _fwd_call(q, k, v, *, causal: bool, scale: float, block_q: int | None,
+              block_k: int | None, interpret: bool):
     B, H, T, D = q.shape
-    block_q = min(block_q, T)
-    block_k = min(block_k, T)
-    if T % block_q or T % block_k:
-        raise ValueError(f"T={T} must be divisible by block sizes {block_q},{block_k}")
+    block_q, block_k = _blocks(T, block_q, block_k)
+    if any(n > 128 and n % 128 for n in (block_k, D)):
+        raise ValueError(f"block_k={block_k} and D={D} must be under 128 or "
+                         "multiples of it")
     nq, nk = T // block_q, T // block_k
     grid = (B, H, nq, nk)
 
@@ -130,10 +225,11 @@ def _fwd_call(q, k, v, *, causal: bool, scale: float, block_q: int,
 
 def flash_attention_forward(q, k, v, *, causal: bool = True,
                             scale: float | None = None,
-                            block_q: int = DEFAULT_BLOCK_Q,
-                            block_k: int = DEFAULT_BLOCK_K,
+                            block_q: int | None = None,
+                            block_k: int | None = None,
                             interpret: bool = False):
-    """q,k,v: [B, H, T, D] (heads-major). Returns [B, H, T, D]."""
+    """q,k,v: [B, H, T, D] (heads-major). Returns [B, H, T, D]. Block sizes
+    left None are chosen from T (`_BLOCK_CHOICES`)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     out, _ = _fwd_call(q, k, v, causal=causal, scale=scale,
@@ -153,32 +249,22 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    live = ((i + 1) * block_q - 1 >= j * block_k) if causal else True
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)            # [bq, D]
-        k = k_ref[0, 0].astype(jnp.float32)            # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)          # [bq, D]
-        lse = lse_ref[0, 0]                            # [bq, 1]
-        delta = delta_ref[0, 0]                        # [bq, 1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                       # [bq, bk]
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)                            # [bq, bk]
-        # dv += p^T dO
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale                   # [bq, bk]
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    @_when_live(i, j, causal=causal, block_q=block_q, block_k=block_k,
+                strip_of="k")
+    def _compute(tiles, masked):
+        for rows, keys in tiles:
+            q = q_ref[0, 0, rows, :]                       # [rows, D]
+            do = do_ref[0, 0, rows, :]                     # [rows, D]
+            st = _dot(k_ref[0, 0, keys, :], q, _NT) * scale   # [keys, rows]
+            if masked:
+                st = _causal_mask(st, i * block_q + rows.start,
+                                  j * block_k + keys.start, q_axis=1)
+            pt = jnp.exp(st - lse_ref[0, 0, :, rows])      # lse, delta: [1, rows]
+            # dv += p^T dO
+            dv_scr[keys, :] = dv_scr[keys, :] + _dot(pt.astype(do.dtype), do)
+            dpt = _dot(v_ref[0, 0, keys, :], do, _NT)
+            dst = pt * (dpt - delta_ref[0, 0, :, rows]) * scale
+            dk_scr[keys, :] = dk_scr[keys, :] + _dot(dst.astype(q.dtype), q)
 
     @pl.when(i == nq - 1)
     def _finalize():
@@ -197,29 +283,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    live = (j * block_k <= (i + 1) * block_q - 1) if causal else True
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale                   # [bq, bk]
-        dq_scr[:] = dq_scr[:] + jnp.dot(
-            ds, k, preferred_element_type=jnp.float32)
+    @_when_live(i, j, causal=causal, block_q=block_q, block_k=block_k)
+    def _compute(tiles, masked):
+        for rows, keys in tiles:
+            k = k_ref[0, 0, keys, :]
+            s = _dot(q_ref[0, 0, rows, :], k, _NT) * scale
+            if masked:
+                s = _causal_mask(s, i * block_q + rows.start,
+                                 j * block_k + keys.start, q_axis=0)
+            p = jnp.exp(s - lse_ref[0, 0, rows, :])         # lse, delta: [rows, 1]
+            dp = _dot(do_ref[0, 0, rows, :], v_ref[0, 0, keys, :], _NT)
+            ds = p * (dp - delta_ref[0, 0, rows, :]) * scale
+            dq_scr[rows, :] = dq_scr[rows, :] + _dot(ds.astype(k.dtype), k)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -228,14 +303,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool,
                              scale: float,
-                             block_q: int = DEFAULT_BLOCK_Q,
-                             block_k: int = DEFAULT_BLOCK_K,
+                             block_q: int | None = None,
+                             block_k: int | None = None,
                              interpret: bool = False):
     """Gradients (dq, dk, dv) for [B,H,T,D] flash attention."""
     B, H, T, D = q.shape
-    block_q = min(block_q, T)
-    block_k = min(block_k, T)
-    nq, nk = T // block_q, T // block_k
     # delta_t = sum_d dO * O — folds the softmax Jacobian; tiny elementwise op,
     # XLA fuses it, no need for a kernel.
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1, keepdims=True)  # [B,H,T,1]
@@ -250,6 +322,10 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool,
     def outer_map(b, h, o_idx, inner):
         return (b, h, o_idx, 0)
 
+    def inner_row_map(b, h, o_idx, inner):
+        return (b, h, 0, inner)
+
+    block_q, block_k = _blocks(T, block_q, block_k)
     dkv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
@@ -257,14 +333,14 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool,
             jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
         ),
-        grid=(B, H, nk, nq),
+        grid=(B, H, T // block_k, T // block_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), inner_map, **kwargs),
             pl.BlockSpec((1, 1, block_k, D), outer_map, **kwargs),
             pl.BlockSpec((1, 1, block_k, D), outer_map, **kwargs),
             pl.BlockSpec((1, 1, block_q, D), inner_map, **kwargs),
-            pl.BlockSpec((1, 1, block_q, 1), inner_map, **kwargs),
-            pl.BlockSpec((1, 1, block_q, 1), inner_map, **kwargs),
+            pl.BlockSpec((1, 1, 1, block_q), inner_row_map, **kwargs),
+            pl.BlockSpec((1, 1, 1, block_q), inner_row_map, **kwargs),
         ],
         out_specs=(
             pl.BlockSpec((1, 1, block_k, D), outer_map, **kwargs),
@@ -277,13 +353,14 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool,
         interpret=interpret,
         name="flash_bwd_dkv",
     )
-    dk, dv = dkv(q, k, v, do, lse, delta)
+    # its scores are transposed, so a query's lse and delta lie along a row
+    dk, dv = dkv(q, k, v, do, lse.reshape(B, H, 1, T), delta.reshape(B, H, 1, T))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
-        grid=(B, H, nq, nk),
+        grid=(B, H, T // block_q, T // block_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), outer_map, **kwargs),
             pl.BlockSpec((1, 1, block_k, D), inner_map, **kwargs),
@@ -312,10 +389,11 @@ def _reference_bhtd(q, k, v, *, causal: bool, scale: float):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
-                    block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
+                    block_q: int | None = None, block_k: int | None = None,
                     interpret: bool = False):
     """Differentiable flash attention, [B,H,T,D]. Forward and backward are
-    Pallas kernels on TPU; neither materializes the [T,T] score tensor."""
+    Pallas kernels on TPU; neither materializes the [T,T] score tensor. Block
+    sizes left None are chosen from T (`_BLOCK_CHOICES`)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     return flash_attention_forward(q, k, v, causal=causal, scale=scale,

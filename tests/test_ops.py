@@ -61,64 +61,163 @@ def test_cross_entropy_ignore_index():
     assert np.isfinite(float(loss))
 
 
+def _flash_qkv(seed, shape, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return [jax.random.normal(k, shape).astype(dtype) for k in ks]
+
+
+def _reference_f32(q, k, v, *, causal):
+    """`_reference_bhtd` on the same values, computed and returned in float32."""
+    return _reference_bhtd(*(x.astype(jnp.float32) for x in (q, k, v)),
+                           causal=causal, scale=q.shape[-1] ** -0.5)
+
+
+def _assert_flash_close(got, ref, dtype, f32_tol, err_msg=""):
+    """float32 operands: the kernel's products are float32 products, elementwise
+    tolerance. bfloat16 operands: the kernel rounds p / ds to bfloat16 before
+    their second product and its result to bfloat16 (2^-8 each), so 2e-2 of
+    the result's scale; a dropped term or a wrong mask on a block is O(1)."""
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, ref, atol=f32_tol, rtol=f32_tol,
+                                   err_msg=err_msg)
+    else:
+        np.testing.assert_allclose(got, ref, atol=2e-2 * np.abs(ref).max(),
+                                   rtol=0, err_msg=err_msg)
+        # and in the norm, where a handful of wrong rows cannot hide
+        assert np.linalg.norm(got - ref) <= 1e-2 * np.linalg.norm(ref), err_msg
+
+
+_DTYPES = pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                                  ids=["float32", "bfloat16"])
+
+
+@_DTYPES
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_interpret_matches_reference(causal):
+def test_flash_attention_interpret_matches_reference(causal, dtype):
     B, H, T, D = 2, 2, 512, 64
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (B, H, T, D))
-    k = jax.random.normal(ks[1], (B, H, T, D))
-    v = jax.random.normal(ks[2], (B, H, T, D))
+    q, k, v = _flash_qkv(0, (B, H, T, D), dtype)
     out = flash_attention_forward(q, k, v, causal=causal, interpret=True,
                                   block_q=128, block_k=128)
-    ref = _reference_bhtd(q, k, v, causal=causal, scale=D**-0.5)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+    assert out.dtype == dtype
+    ref = _reference_f32(q, k, v, causal=causal)
+    _assert_flash_close(out, ref, dtype, 2e-5)
 
 
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_backward_interpret_matches_reference(causal):
+def _flash_grads_vs_reference(shape, dtype, *, causal, block_q, block_k, seed,
+                              loss):
     from ray_tpu.ops.flash_attention import flash_attention
 
-    B, H, T, D = 1, 2, 256, 64
-    ks = jax.random.split(jax.random.PRNGKey(7), 3)
-    q = jax.random.normal(ks[0], (B, H, T, D))
-    k = jax.random.normal(ks[1], (B, H, T, D))
-    v = jax.random.normal(ks[2], (B, H, T, D))
+    q, k, v = _flash_qkv(seed, shape, dtype)
 
     def f_flash(q, k, v):
-        return (flash_attention(q, k, v, causal, None, 128, 128, True) ** 2).sum()
+        out = flash_attention(q, k, v, causal, None, block_q, block_k, True)
+        return loss(out.astype(jnp.float32))
 
     def f_ref(q, k, v):
-        return (_reference_bhtd(q, k, v, causal=causal, scale=D**-0.5) ** 2).sum()
+        return loss(_reference_f32(q, k, v, causal=causal))
 
     g_flash = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(
+        *(x.astype(jnp.float32) for x in (q, k, v)))
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
-        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
-                                   atol=5e-4, rtol=5e-4, err_msg=f"d{name}")
+        assert gf.dtype == dtype
+        _assert_flash_close(gf, gr, dtype, 5e-4, err_msg=f"d{name}")
 
 
-def test_flash_attention_backward_uneven_blocks():
+@_DTYPES
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_backward_interpret_matches_reference(causal, dtype):
+    _flash_grads_vs_reference((1, 2, 256, 64), dtype, causal=causal,
+                              block_q=128, block_k=128, seed=7,
+                              loss=lambda o: (o ** 2).sum())
+
+
+@_DTYPES
+def test_flash_attention_backward_uneven_blocks(dtype):
     # block_q != block_k exercises the causal liveness predicates on both
     # backward kernels
+    _flash_grads_vs_reference((1, 1, 256, 32), dtype, causal=True,
+                              block_q=128, block_k=64, seed=3,
+                              loss=lambda o: (o * 0.5).sum())
+
+
+@_DTYPES
+def test_flash_attention_backward_four_blocks(dtype):
+    # T = 4 blocks: blocks wholly under the diagonal (no mask built), blocks
+    # the diagonal crosses and skipped blocks above it all occur, in the
+    # forward and in both backward kernels
+    _flash_grads_vs_reference((1, 2, 512, 64), dtype, causal=True,
+                              block_q=128, block_k=128, seed=11,
+                              loss=lambda o: (o ** 2).sum())
+
+
+@_DTYPES
+def test_flash_attention_diagonal_strips(dtype):
+    # blocks of 512 at T = 1024: the two blocks on the diagonal are computed in
+    # strips of 256 with what lies above each strip left out, the block under
+    # the diagonal whole, in the forward and in both backward kernels
+    _flash_grads_vs_reference((1, 1, 1024, 64), dtype, causal=True,
+                              block_q=512, block_k=512, seed=5,
+                              loss=lambda o: (o ** 2).sum())
+
+
+def test_flash_attention_blocks_chosen_from_t():
+    # no block size named: the kernels' best, cut to what tiles T
+    from ray_tpu.ops.flash_attention import _blocks
+
+    assert _blocks(1024, None, None) == (1024, 1024)
+    assert _blocks(1280, None, None) == (256, 256)
+    assert _blocks(4096, 512, None) == (512, 1024)
+    assert _blocks(192, None, None) == (192, 192)
+    with pytest.raises(ValueError):
+        _blocks(1024, 384, None)
+    _flash_grads_vs_reference((1, 1, 256, 64), jnp.bfloat16, causal=True,
+                              block_q=None, block_k=None, seed=2,
+                              loss=lambda o: o.sum())
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list)) else (value,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk_eqns(sub)
+
+
+def test_flash_kernels_multiply_bf16_operands_as_stored():
+    """The mechanism of PR 31 engaged: given bfloat16, no product inside the
+    three kernels runs on float32 operands (a `.astype(float32)` before a
+    `dot` costs the MXU its bf16 rate), and each accumulates in float32."""
     from ray_tpu.ops.flash_attention import flash_attention
 
-    B, H, T, D = 1, 1, 256, 32
-    ks = jax.random.split(jax.random.PRNGKey(3), 3)
-    q = jax.random.normal(ks[0], (B, H, T, D))
-    k = jax.random.normal(ks[1], (B, H, T, D))
-    v = jax.random.normal(ks[2], (B, H, T, D))
+    q, k, v = _flash_qkv(0, (1, 1, 256, 64), jnp.bfloat16)
 
-    def f_flash(q, k, v):
-        return (flash_attention(q, k, v, True, None, 128, 64, True) * 0.5).sum()
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, None, 128, 128, True)
+        return out.astype(jnp.float32).sum()
 
-    def f_ref(q, k, v):
-        return (_reference_bhtd(q, k, v, causal=True, scale=D**-0.5) * 0.5).sum()
-
-    g_flash = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
-    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
-        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
-                                   atol=5e-4, rtol=5e-4, err_msg=f"d{name}")
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr
+    dots = {}
+    for eqn in _walk_eqns(jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            dots[eqn.params["name"]] = [
+                (tuple(str(x.aval.dtype) for x in d.invars),
+                 str(d.outvars[0].aval.dtype))
+                for d in _walk_eqns(eqn.params["jaxpr"])
+                if d.primitive.name == "dot_general"]
+    # forward: q k^T, p v; dk/dv: q k^T, p^T dO, dO v^T, ds^T q; dq: q k^T,
+    # dO v^T, ds k -- each traced twice, for a block the diagonal crosses and
+    # for a block under it
+    assert {n: len(d) for n, d in dots.items()} == {
+        "flash_fwd": 4, "flash_bwd_dkv": 8, "flash_bwd_dq": 6}
+    for name, found in dots.items():
+        for operands, result in found:
+            assert operands == ("bfloat16", "bfloat16"), (name, operands)
+            assert result == "float32", (name, result)
 
 
 def test_attention_dispatcher_gqa():

@@ -56,14 +56,14 @@ def _flash_qkv(chip, shape):
 @pytest.mark.parametrize("shape", [GPT2_774M_TRAIN, LLAMA_1B_PREFILL])
 def test_flash_forward_compiles(chip, shape):
     text = _compiled_text(
-        lambda q, k, v: flash_attention(q, k, v, True, None, 512, 512),
+        lambda q, k, v: flash_attention(q, k, v, True, None),
         *_flash_qkv(chip, shape))
     assert "tpu_custom_call" in text
 
 
 def test_flash_forward_and_backward_compile(chip):
     def loss(q, k, v):
-        return flash_attention(q, k, v, True, None, 512, 512).astype(jnp.float32).sum()
+        return flash_attention(q, k, v, True, None).astype(jnp.float32).sum()
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
                           *_flash_qkv(chip, GPT2_774M_TRAIN))
@@ -212,7 +212,7 @@ def test_kernel_names_reach_the_compiled_program(chip):
     import re
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, True, None, 512, 512).astype(
+        return flash_attention(q, k, v, True, None).astype(
             jnp.float32).sum()
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
