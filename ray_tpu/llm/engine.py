@@ -272,10 +272,11 @@ class TPUEngine:
                 f"max_seq_len {cfg.max_seq_len} (rope/pos tables are sized "
                 "by the model config)")
         self.max_slots = max_slots
-        if cfg.mla or cfg.n_dense_layers:
+        if cfg.mla or cfg.n_dense_layers or cfg.window:
             # what is not carried to the latent cache and to two kinds of
             # layer in one stack: refused here, not at the first request
             kind = ("latent attention (kv_lora_rank)" if cfg.mla
+                    else "window layers" if cfg.window
                     else "leading dense layers")
             for on, what in ((mesh is not None, "a tensor-parallel mesh"),
                              (max_loras, "max_loras")):
@@ -285,6 +286,12 @@ class TPUEngine:
                         f"{what}: the sharding of the page pool over kv "
                         "heads and the LoRA bank are built for per-head K "
                         "and V over one kind of layer")
+        if cfg.window and enable_prefix_cache:
+            raise ValueError(
+                "a model with window layers is served without "
+                "enable_prefix_cache: a cached block would also have to pin "
+                "the window layers' pages under it, and those lie in a ring "
+                "the row writes over as it grows")
         if max_loras and (enable_prefix_cache or prefill_chunk is not None):
             raise ValueError(
                 "max_loras cannot be combined with enable_prefix_cache or "
@@ -334,12 +341,21 @@ class TPUEngine:
         # default pool = full reservation (+1 scratch); pass num_pages
         # lower to oversubscribe HBM against short real sequences
         self.num_pages = num_pages or (max_slots * self.max_pages_per_seq + 1)
+        # window layers (models/decoding_paged.py): a pool of their own and
+        # a ring of at most `ring` of its pages a row, held for the row's life
+        self.ring = (min(dp.window_ring(cfg, page_size, prefill_chunk),
+                         self.max_pages_per_seq) if cfg.window else 0)
         self.state = dp.init_paged_state(
-            cfg, max_slots, self.max_len, self.num_pages, page_size)
+            cfg, max_slots, self.max_len, self.num_pages, page_size,
+            ring=self.ring or None)
+        # that pool's size is dp's: a ring for every slot (+ scratch)
+        self.window_pages = self.state["wkp"].shape[1] if cfg.window else 0
         if mesh is not None:
             self.state = _shard_state_tp(self.state, mesh)
         self._free_pages = list(range(1, self.num_pages))  # 0 = scratch
         self._slot_pages: dict[int, list] = {}
+        self._free_wpages = list(range(1, self.window_pages))  # 0 = scratch
+        self._slot_wpages: dict[int, list] = {}
         # hash-block prefix cache over the SAME page pool (reference
         # capability: vLLM automatic prefix caching): chain-hashed
         # full prompt blocks map to pages still resident in HBM; a
@@ -398,6 +414,25 @@ class TPUEngine:
         self.prefix_tokens_gathered = 0
         self.page_steps_used = 0
         self.page_steps_total = 0
+        # ... positions a window layer attended over (at most the window a
+        # row a step), tokens held (live rows' lengths + what staged
+        # prefills have written) and the bytes of every page granted in
+        # both pools, summed over decode steps; window pages likewise. Sums
+        # kept as rows advance, so a decode pass adds integers: the live
+        # rows' positions (sum of length0 + generated), the part of them
+        # beyond the window, the staged prefills' tokens
+        self.window_context_tokens = 0
+        self.held_token_steps = 0
+        self.held_byte_steps = 0
+        self.window_page_steps_used = 0
+        self.window_page_steps_total = 0
+        self._live_tokens = 0
+        self._live_beyond_window = 0
+        self._staged_tokens = 0
+        self._page_bytes, self._wpage_bytes = (
+            sum(self.state[k].nbytes // self.state[k].shape[1]
+                for k in names if k in self.state)
+            for names in (("kp", "vp"), ("wkp", "wvp")))
         # padded tokens of the dispatched calls by the form their expert
         # layers took (stats()["experts"]); a dense model counts neither
         self.expert_tokens_sorted = 0
@@ -680,12 +715,13 @@ class TPUEngine:
           error; the slot and its granted pages are reclaimed.
         """
         self._check_alive()
-        if self.cfg.mla:
+        if self.cfg.mla or self.cfg.window:
             raise NotImplementedError(
                 "submit_prefilled: the PD transfer plane (llm/pd.py, "
-                "kv_transfer.py) moves per-head K and V pages; a model with "
-                "latent attention caches one row a token and is not carried "
-                "over it")
+                "kv_transfer.py) moves per-head K and V pages of one kind of "
+                "layer; a model with latent attention caches one row a token, "
+                "one with window layers a ring of pages on those layers, and "
+                "neither is carried over it")
         params = params or SamplingParams()
         paged_form = k_pages is not None or v_pages is not None
         if kv_stream is not None:
@@ -961,6 +997,41 @@ class TPUEngine:
             return None
         return [self._free_pages.pop() for _ in range(need)]
 
+    def _grant_ring(self, slot: int, row_pages: int) -> None:
+        """The row's pages of the window pool, once, for its life: a ring of
+        `self.ring`, or a page for every page the row can reach if fewer.
+        That pool never runs out before the full one: it holds a ring for
+        every slot, or as many pages as the full pool, and a row never takes
+        more window pages than full ones."""
+        if self.ring:
+            self._slot_wpages[slot] = [self._free_wpages.pop()
+                                       for _ in range(min(self.ring, row_pages))]
+
+    def _return_pages(self, slot: int) -> None:
+        """The slot's private pages of both pools back to their free lists."""
+        self._free_pages.extend(self._slot_pages.pop(slot, ()))
+        self._free_wpages.extend(self._slot_wpages.pop(slot, ()))
+
+    def _ring_row(self, slot: int):
+        """The slot's ring as the device takes it: [ring] ids, 0-padded
+        (None for a model without window layers)."""
+        if not self.ring:
+            return None
+        row = np.zeros((self.ring,), np.int32)
+        held = self._slot_wpages[slot]
+        row[:len(held)] = held
+        return jnp.asarray(row)
+
+    def _count_live(self, req: _Request, sign: int) -> None:
+        """A row joins (+1) or leaves (-1) the rows the decode step advances:
+        its positions, and those of them beyond the window, in the running
+        sums the cache counters add every pass (`_emit` moves them a token
+        at a time in between)."""
+        held = req.length0 + req.generated
+        self._live_tokens += sign * held
+        if self.cfg.window:
+            self._live_beyond_window += sign * max(0, held - self.cfg.window)
+
     def _bind_slot(self, req: _Request, slot: int,
                    length: int | None = None) -> None:
         """The slot-activation bookkeeping shared by every admission path:
@@ -969,6 +1040,7 @@ class TPUEngine:
         ragged decode step can bound its page sweep without a readback."""
         if length is not None:
             req.length0 = int(length)
+        self._count_live(req, +1)
         self._set_row_sampling(slot, req.params)
         if self.lora_bank is not None:
             self._slot_lora = self._slot_lora.at[slot].set(req.lora_idx)
@@ -1020,10 +1092,12 @@ class TPUEngine:
         if pages is None:
             return False
         self._slot_pages[slot] = pages
+        self._grant_ring(slot, need)
         self.state = dp.insert_sequence_paged(
             self.state, slot, kv, jnp.int32(length),
             jnp.asarray(first_token, jnp.int32),
-            jnp.asarray(self._granted_block_row(slot)), self.cfg)
+            jnp.asarray(self._granted_block_row(slot)), self.cfg,
+            self._ring_row(slot))
         self._bind_slot(req, slot, length)
         return True
 
@@ -1107,7 +1181,7 @@ class TPUEngine:
         a per-REQUEST error; every other request keeps serving."""
         if req in self._streaming:
             self._streaming.remove(req)
-        self._free_pages.extend(self._slot_pages.pop(req.slot, ()))
+        self._return_pages(req.slot)
         self._free.append(req.slot)
         self._lora_release(req)
         if not isinstance(err, BaseException):
@@ -1350,6 +1424,8 @@ class TPUEngine:
             for p in pre_pages:  # unpin; the request is backlogged
                 self._page_refs[p] = self._page_refs.get(p, 1) - 1
             return None
+        self._slot_pages[slot] = list(priv)
+        self._grant_ring(slot, total_pages)
         self._slot_shared[slot] = list(pre_pages)
         self._scheduled(req)
         req.prefix_reused = pre_len
@@ -1364,7 +1440,7 @@ class TPUEngine:
             req.pf_done = pre_len
             req.pf_pages = pre_pages + priv
             req.pf_hashes = hashes
-            self._slot_pages[slot] = list(priv)
+            self._staged_tokens += pre_len
             self._prefilling.append(req)
             return -1  # staged: no first token yet
         padded = np.zeros((1, suf_bucket), np.int32)
@@ -1397,7 +1473,8 @@ class TPUEngine:
         suf_pages = np.asarray(priv[:suf_bucket // P], np.int32)
         self.state = dp.insert_sequence_paged_prefix(
             self.state, slot, kv, jnp.asarray(suf_pages),
-            jnp.asarray(block_row), jnp.int32(n), first[0], self.cfg)
+            jnp.asarray(block_row), jnp.int32(n), first[0], self.cfg,
+            self._ring_row(slot))
         self._bind_slot(req, slot, n)
         if self.enable_prefix_cache:
             self._register_blocks(slot, tokens, hashes, n_pre, priv)
@@ -1422,6 +1499,9 @@ class TPUEngine:
         self._count_expert_tokens(bucket)
         chunk_pages = np.asarray(
             req.pf_pages[done // P:(done + bucket) // P], np.int32)
+        # the window layers' part goes through the row's ring, whose slots
+        # models/decoding_paged.py finds from the chunk's start
+        ring, window = self._ring_row(req.slot), ()
         if done == 0:
             logits, kv = decoding.prefill(
                 self.params, jnp.asarray(padded),
@@ -1435,25 +1515,31 @@ class TPUEngine:
             k_pre, v_pre = dp.gather_prefix_pages(
                 self.state["kp"], self.state.get("vp"), jnp.asarray(padded_ids))
             self.prefix_tokens_gathered += done
+            if ring is not None:
+                window = dp.gather_window_pages(
+                    self.state, ring, jnp.int32(done), self.cfg)
             logits, kv = dp.prefill_with_prefix(
                 self.params, jnp.asarray(padded), k_pre, v_pre,
-                jnp.int32(done), jnp.int32(len(chunk_toks)), self.cfg)
-        self.state = dp.write_kv_pages(self.state, kv,
-                                             jnp.asarray(chunk_pages))
+                jnp.int32(done), jnp.int32(len(chunk_toks)), self.cfg, *window)
+        self.state = dp.write_kv_pages(
+            self.state, kv, jnp.asarray(chunk_pages),
+            *(() if ring is None else (ring, jnp.int32(done))))
         req.pf_done = done + len(chunk_toks)
         req.pf_chunks += 1
         self.prefill_chunks_run += 1
+        self._staged_tokens += len(chunk_toks)
         if not is_last:
             return
         self._prefilling.pop(0)
         n = len(tokens)
+        self._staged_tokens -= n
         self.key, sub = jax.random.split(self.key)
         first = self._sample_first(req, logits, sub)
         block_row = np.zeros((self.max_pages_per_seq,), np.int32)
         block_row[:len(req.pf_pages)] = req.pf_pages
         self.state = dp.activate_slot(
             self.state, req.slot, jnp.asarray(block_row), jnp.int32(n),
-            first[0])
+            first[0], ring)
         self._bind_slot(req, req.slot, n)
         if self.enable_prefix_cache:
             n_shared = len(self._slot_shared.get(req.slot, ()))
@@ -1473,6 +1559,9 @@ class TPUEngine:
                 self._phase_gap.observe(now - last)
             req.last_emit_ts = now
         req.generated += 1
+        self._live_tokens += 1
+        if self.cfg.window and req.length0 + req.generated > self.cfg.window:
+            self._live_beyond_window += 1
         fsm = self._guided_fsm.get(req.slot)
         if fsm is not None:
             self._guided_state[req.slot] = fsm.step(
@@ -1490,7 +1579,8 @@ class TPUEngine:
         state to their pools — the one release path shared by normal
         completion (_emit) and mid-stream abort (_abort_one)."""
         self.state = dp.release_slot_paged(self.state, req.slot)
-        self._free_pages.extend(self._slot_pages.pop(req.slot, ()))
+        self._return_pages(req.slot)
+        self._count_live(req, -1)
         if self.enable_prefix_cache:
             self._release_shared(req.slot)
         if self.lora_bank is not None:
@@ -1547,7 +1637,8 @@ class TPUEngine:
             return True
         elif req in self._prefilling:
             self._prefilling.remove(req)
-            self._free_pages.extend(self._slot_pages.pop(req.slot, ()))
+            self._staged_tokens -= req.pf_done
+            self._return_pages(req.slot)
             self._release_shared(req.slot)
             self._free.append(req.slot)
             self._lora_release(req)
@@ -1691,12 +1782,21 @@ class TPUEngine:
             self.decode_steps += 1
             self.decode_slot_steps += len(self._by_slot)
             self._count_expert_tokens(self.max_slots)
-            self.context_tokens += sum(
-                r.length0 + max(0, r.generated - 1) + 1
-                for r in self._by_slot.values())
+            # each live row attended over length0 + generated positions on
+            # a full layer, no more than the window of them on a window layer
+            self.context_tokens += self._live_tokens
+            self.window_context_tokens += (self._live_tokens
+                                           - self._live_beyond_window)
             self.page_steps_used += (self.num_pages - 1
                                      - self._available_pages())
             self.page_steps_total += self.num_pages - 1
+            granted = self.num_pages - 1 - len(self._free_pages)
+            wgranted = max(self.window_pages - 1, 0) - len(self._free_wpages)
+            self.held_token_steps += self._live_tokens + self._staged_tokens
+            self.held_byte_steps += (granted * self._page_bytes
+                                     + wgranted * self._wpage_bytes)
+            self.window_page_steps_used += wgranted
+            self.window_page_steps_total += max(self.window_pages - 1, 0)
             if self._step_obs is not None:
                 # the step's dispatches + the fetch of its tokens
                 self._step_obs.observe(t_emit - t_step)
@@ -1732,16 +1832,25 @@ class TPUEngine:
                                     if self.decode_steps else 0.0),
                "free_pages": len(self._free_pages),
                "num_pages": self.num_pages, "page_size": self.page_size}
-        pools = [self.state[k] for k in ("kp", "vp")
-                 if k in self.state]   # [L, pages, tokens, ...]
         out["cache"] = {
-            # as stored, all layers: a latent row, or K and V of every head
-            "bytes_per_token": sum(
-                x.nbytes // (x.shape[1] * x.shape[2]) for x in pools),
+            # as stored, all layers (window layers too: what a token costs a
+            # row that has not left the window): a latent row, or K and V
+            # of every head
+            "bytes_per_token": (self._page_bytes + self._wpage_bytes) // self.page_size,
             "context_tokens": self.context_tokens,
             "prefix_tokens_gathered": self.prefix_tokens_gathered,
             "page_steps_used": self.page_steps_used,
-            "page_steps_total": self.page_steps_total}
+            "page_steps_total": self.page_steps_total,
+            "held_token_steps": self.held_token_steps,
+            "held_byte_steps": self.held_byte_steps}
+        if self.ring:
+            out["cache"].update(
+                window_context_tokens=self.window_context_tokens,
+                window_page_steps_used=self.window_page_steps_used,
+                window_page_steps_total=self.window_page_steps_total)
+            out["window_pages"] = self.window_pages
+            out["free_window_pages"] = len(self._free_wpages)
+            out["ring"] = self.ring
         out["experts"] = {"tokens_sorted": self.expert_tokens_sorted,
                           "tokens_onehot": self.expert_tokens_onehot}
         if self.prefill_chunk:

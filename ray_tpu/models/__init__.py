@@ -2,6 +2,7 @@ from ray_tpu.models import transformer, vit
 from ray_tpu.models.gpt2 import gpt2_config
 from ray_tpu.models.kimi_vl import kimi_vl_config
 from ray_tpu.models.llama import llama_config
+from ray_tpu.models.mellum import mellum_config
 from ray_tpu.models.mixtral import mixtral_config
 from ray_tpu.models.transformer import MoEConfig, TransformerConfig
 from ray_tpu.models.vit import ViTConfig, vit_config
@@ -13,6 +14,7 @@ __all__ = [
     "gpt2_config",
     "kimi_vl_config",
     "llama_config",
+    "mellum_config",
     "mixtral_config",
     "transformer",
     "vit",

@@ -26,22 +26,22 @@ import jax.numpy as jnp
 
 from ray_tpu import ops
 from ray_tpu.models.transformer import (TransformerConfig, _dense_mlp, _mla_expand,
-                                        _mla_project, _moe_mlp, _norm, scan_layers)
+                                        _mla_project, _moe_mlp, _norm, rope_by_kind,
+                                        scan_layers)
 
 
 def _per_head_kv_only(cfg: TransformerConfig, what: str) -> None:
     """The paths not carried to the latent cache or to two kinds of layer."""
-    if cfg.mla or cfg.n_dense_layers:
+    if cfg.mla or cfg.n_dense_layers or cfg.window:
         raise NotImplementedError(
             f"{what} is built for per-head K and V over one kind of layer; a "
-            "model with latent attention (kv_lora_rank) or leading dense "
-            "layers is served without it")
+            "model with latent attention (kv_lora_rank), leading dense "
+            "layers or window layers is served without it")
 
 
 def _rope(cfg):
-    if cfg.pos == "rope":
-        return ops.rope_frequencies(cfg.rope_dim, cfg.max_seq_len, theta=cfg.rope_theta)
-    return None, None
+    """(cos, sin) of a stack of one kind of layer (None, None without rope)."""
+    return rope_by_kind(cfg)[False]
 
 
 def init_lora_bank(cfg: TransformerConfig, num_adapters: int,
@@ -137,6 +137,8 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
 
     Returns (logits_at_last [V], kv {k,v: [L, T, Hkv, Dh]}; with latent
     attention kv is {k: [L, T, latent_lanes]}, the rows the cache holds).
+    With window layers kv still holds every layer's T positions: which of
+    them a window layer keeps is the cache's business (decoding_paged.py).
     With `lora_bank` + scalar `lora_idx`, applies that adapter's q/v
     deltas (init_lora_bank; idx 0 = null adapter = exact base model).
     """
@@ -145,10 +147,11 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
     x = params["embed"].astype(dt)[tokens]
     if cfg.pos == "learned":
         x = x + params["pos_embed"][:T].astype(dt)
-    cos, sin = _rope(cfg)
+    rope = rope_by_kind(cfg)
     lscale = None if lora_bank is None else lora_bank["scale"][lora_idx]
 
-    def block(h, layer_in):
+    def block(h, layer_in, window=False):
+        cos, sin = rope[window]
         if lora_bank is None:
             layer_p, lora_l = layer_in, None
         else:
@@ -165,7 +168,8 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
         if cfg.pos == "rope":
             q = ops.apply_rope(q, cos, sin)
             k = ops.apply_rope(k, cos, sin)
-        out = ops.attention(q, k, v, causal=True)
+        out = ops.attention(q, k, v, causal=True,
+                            window=cfg.window if window else None)
         out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
             out = out + layer_p["attn"]["bo"].astype(dt)

@@ -1,8 +1,15 @@
 """Decoder-only transformer core shared by the GPT-2 / Llama / Mixtral /
-Kimi-VL (DeepSeek-V3-style) families.
+Kimi-VL (DeepSeek-V3-style) / Mellum families.
 Pure-functional: params are pytrees (layers stacked on a leading
 dim and consumed by lax.scan — compile-fast and pipeline-ready), logical axis
 trees drive mesh sharding, compute runs in bf16 with f32 accumulators.
+
+A stack may mix two kinds of attention layer (`TransformerConfig.window`):
+window layers, whose queries see the last `window` positions and which take
+the plain rope, and full layers (every `window_period`-th), which see
+everything and take the YaRN rope where `yarn` is set. The layers stay
+stacked [L, ...]; `scan_layers` scans whole periods and tells each block its
+kind.
 
 The reference framework contains no model code (models live in user code /
 vLLM); these families exist so the framework's train/serve/bench paths are
@@ -12,6 +19,7 @@ self-contained (BASELINE.md configs 1, 2, 4).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -76,6 +84,18 @@ class TransformerConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     kv_norm_eps: float = 1e-6
+    # window attention: query i sees key j iff 0 <= i - j < window, on every
+    # layer but the last of each `window_period` (layer l is a full layer iff
+    # l % window_period == window_period - 1). None: every layer is full
+    window: int | None = None
+    window_period: int = 4
+    # YaRN on the full layers' rope (ops/rope.py Yarn); window layers take
+    # the plain rope of the same theta
+    yarn: ops.Yarn | None = None
+
+    @property
+    def n_full_layers(self) -> int:
+        return self.n_layers // self.window_period if self.window else self.n_layers
 
     @property
     def kv_heads(self) -> int:
@@ -221,6 +241,19 @@ def _check(cfg: TransformerConfig) -> None:
                          "'softmax' or 'sigmoid'")
     if cfg.moe and cfg.moe.score_func == "sigmoid" and not cfg.moe.dropless:
         raise ValueError("sigmoid routing is dropless: capacity_factor None")
+    if cfg.yarn is not None and cfg.pos != "rope":
+        raise ValueError("yarn rescales rotary positions: pos='rope'")
+    if cfg.window is not None:
+        if cfg.mla or cfg.pos != "rope" or cfg.n_dense_layers:
+            raise ValueError(
+                "window layers are built for per-head K and V with rope in a "
+                "stack of one kind of MLP: not with latent attention "
+                "(kv_lora_rank), learned positions or leading dense layers")
+        if cfg.window < 1 or cfg.window_period < 2 or cfg.n_layers % cfg.window_period:
+            raise ValueError(
+                f"window {cfg.window} over periods of {cfg.window_period} layers "
+                f"(the last of each a full layer) needs whole periods in "
+                f"n_layers {cfg.n_layers}")
 
 
 def scan_layers(block, carry, params, cfg: TransformerConfig, *per_layer):
@@ -231,7 +264,13 @@ def scan_layers(block, carry, params, cfg: TransformerConfig, *per_layer):
 
     The routed experts of a dropless stack are not sliced by the scan: the
     body closes over them whole and is told its layer (`mlp["layer"]`), for
-    `ops.moe_sorted` to multiply them where they lie."""
+    `ops.moe_sorted` to multiply them where they lie.
+
+    With window layers (cfg.window) the scan is over whole periods and the
+    body calls `block(..., window=<bool>)`, the kind static: once for the
+    period's window layers (a scan of their own), once for its full layer."""
+    if cfg.window is not None:
+        return _scan_periods(block, carry, params, cfg, per_layer)
     outs, dense = [], cfg.n_dense_layers
     # (stacked layer params, index of their first layer): the leading dense
     # layers, where the model has them, then the rest
@@ -259,6 +298,58 @@ def scan_layers(block, carry, params, cfg: TransformerConfig, *per_layer):
     if len(outs) == 1:
         return carry, outs[0]
     return carry, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *outs)
+
+
+def _scan_periods(block, carry, params, cfg: TransformerConfig, per_layer):
+    """One scan over the periods, and inside it one over the period's window
+    layers, then its full layer: two traces of `block`, each with its kind
+    static. Every layer is taken out of the stacked trees where they lie, by
+    the scans' own counters: trees folded to [L / period, period, ...] for
+    the scan to slice made the compiler re-lay whole stacked weights on
+    every call."""
+    L, period = cfg.n_layers, cfg.window_period
+    stack, whole = params["layers"], {}
+    if "router" in stack["mlp"] and cfg.moe.dropless:
+        whole = {k: stack["mlp"][k] for k in ("gate", "up", "down")}
+        stack = {**stack, "mlp": {k: v for k, v in stack["mlp"].items() if k not in whole}}
+
+    def one(c, layer, window):
+        layer_p, *more = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+            (stack, *per_layer))
+        if whole:
+            layer_p = {**layer_p, "mlp": {**layer_p["mlp"], **whole, "layer": layer}}
+        return block(c, (layer_p, *more) if more else layer_p, window=window)
+
+    def body(c, first):
+        c, outs = jax.lax.scan(lambda c, i: one(c, first * period + i, True), c,
+                               jnp.arange(period - 1, dtype=jnp.int32))
+        c, out = one(c, first * period + period - 1, False)
+        return c, jax.tree.map(lambda a, b: jnp.concatenate([a, b[None]]), outs, out)
+
+    carry, out = jax.lax.scan(body, carry, jnp.arange(L // period, dtype=jnp.int32))
+    return carry, jax.tree.map(lambda a: a.reshape(L, *a.shape[2:]), out)
+
+
+def rope_tables(cfg: TransformerConfig, window: bool = False):
+    """(cos, sin) of one kind of layer: YaRN's (cfg.yarn) on full layers."""
+    return ops.rope_frequencies(cfg.rope_dim, cfg.max_seq_len, theta=cfg.rope_theta,
+                                yarn=None if window else cfg.yarn)
+
+
+def rope_by_kind(cfg: TransformerConfig) -> dict:
+    """{window: (cos, sin)} for the kinds of layer the stack has, keyed as
+    `scan_layers` tells a block its kind ((None, None) without rope)."""
+    kinds = (False, True) if cfg.window else (False,)
+    return {w: rope_tables(cfg, w) if cfg.pos == "rope" else (None, None) for w in kinds}
+
+
+def kind_index(cfg: TransformerConfig) -> list:
+    """Each layer's index among the layers of its own kind, in depth order:
+    where its pages, or its cached prefix, lie in the arrays of that kind."""
+    period = cfg.window_period if cfg.window else 1
+    return [l // period if l % period == period - 1 else l - l // period
+            for l in range(cfg.n_layers)]
 
 
 def init(key, cfg: TransformerConfig):
@@ -386,7 +477,7 @@ def _mla_absorb_out(o_lat, p, cfg):
                       p["w_ukv"][..., cfg.qk_nope_head_dim:].astype(cfg.dtype))
 
 
-def _attn_block(x, p, cfg, cos, sin, sp_axis, attn_impl):
+def _attn_block(x, p, cfg, cos, sin, sp_axis, attn_impl, window=None):
     dt = cfg.dtype
     if cfg.mla:
         if sp_axis is not None:
@@ -415,7 +506,8 @@ def _attn_block(x, p, cfg, cos, sin, sp_axis, attn_impl):
         else:
             q = ops.apply_rope(q, cos, sin)
             k = ops.apply_rope(k, cos, sin)
-    out = ops.attention(q, k, v, causal=True, sp_axis=sp_axis, impl=attn_impl)
+    out = ops.attention(q, k, v, causal=True, sp_axis=sp_axis, impl=attn_impl,
+                        window=window)
     out = jnp.einsum("bthd,hde->bte", out, p["wo"].astype(dt))
     if cfg.bias:
         out = out + p["bo"].astype(dt)
@@ -492,16 +584,17 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
         else:
             pos = params["pos_embed"][:T]
         x = x + pos.astype(dt)
-    cos = sin = None
-    if cfg.pos == "rope":
-        cos, sin = ops.rope_frequencies(cfg.rope_dim, cfg.max_seq_len, theta=cfg.rope_theta)
+    if cfg.window is not None and sp_axis is not None:
+        raise ValueError("window layers have no sequence-parallel form")
+    rope = rope_by_kind(cfg)
 
     aux_total = jnp.zeros((), jnp.float32)
 
-    def block(carry, layer_p):
+    def block(carry, layer_p, window=False):
         h, aux = carry
         h = h + _attn_block(_norm(h, layer_p["norm1"], cfg), layer_p["attn"], cfg,
-                            cos, sin, sp_axis, attn_impl)
+                            *rope[window], sp_axis, attn_impl,
+                            cfg.window if window else None)
         normed = _norm(h, layer_p["norm2"], cfg)
         if "router" in layer_p["mlp"]:
             delta, layer_aux = _moe_mlp(normed, layer_p["mlp"], cfg)
@@ -510,12 +603,12 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
             delta = _dense_mlp(normed, layer_p["mlp"], cfg)
         return (h + delta, aux), None
 
-    if cfg.remat and cfg.remat_policy == "pairs" and (cfg.n_layers % 2 or cfg.moe
-                                                      or cfg.n_dense_layers):
+    if cfg.remat and cfg.remat_policy == "pairs" and (
+            cfg.n_layers % 2 or cfg.moe or cfg.n_dense_layers or cfg.window):
         raise ValueError(
             "remat_policy='pairs' needs an even n_layers and a dense (non-"
-            "MoE) stack; falling back silently would misattribute benchmark "
-            "results to selective remat")
+            "MoE) stack of one kind of layer; falling back silently would "
+            "misattribute benchmark results to selective remat")
     if cfg.remat and cfg.remat_policy == "pairs":
         # selective remat: scan over layer PAIRS, checkpointing only the
         # first of each pair. Backward recomputes half the layers (full
@@ -540,7 +633,11 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
             policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                       if cfg.remat_policy == "dots"
                       else jax.checkpoint_policies.nothing_saveable)
-            block = jax.checkpoint(block, policy=policy)
+            inner = block
+
+            def block(carry, layer_p, window=False):
+                return jax.checkpoint(functools.partial(inner, window=window),
+                                      policy=policy)(carry, layer_p)
         (x, aux_total), _ = scan_layers(block, (x, aux_total), params, cfg)
     x = _norm(x, params["final_norm"], cfg)
     if return_hidden:

@@ -8,10 +8,11 @@ from ray_tpu.ops.moe import (RoutingInfo, moe_apply, moe_sorted, onehot_dispatch
 from ray_tpu.ops.norms import layer_norm, rms_norm
 from ray_tpu.ops.ragged_paged_attention import (
     ragged_decode_attention, ragged_decode_attention_reference)
-from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.ops.rope import Yarn, apply_rope, rope_frequencies
 
 __all__ = [
     "RoutingInfo",
+    "Yarn",
     "apply_rope",
     "attention",
     "flash_attention",

@@ -71,16 +71,27 @@ def _flash_per_shard(q, k, v, *, causal: bool, scale: float | None):
 
 
 def attention(q, k, v, *, causal: bool = True, scale: float | None = None,
-              sp_axis: str | None = None, impl: str | None = None):
+              sp_axis: str | None = None, impl: str | None = None,
+              window: int | None = None):
     """q: [B, T, H, D]; k, v: [B, T, Hkv, D]. Returns [B, T, H, D].
 
     impl: None=auto, "flash", "reference". sp_axis: when set, runs ring
     attention over that mesh axis (inputs must be sequence-sharded and the
-    call made inside shard_map).
+    call made inside shard_map). window: query i sees key j iff
+    0 <= i - j < window; a sequence no longer than the window is the plain
+    causal case, a longer one takes the masked XLA path (the flash kernels
+    carry no window).
     """
     H, Hkv = q.shape[2], k.shape[2]
     if H % Hkv != 0:
         raise ValueError(f"q heads {H} not a multiple of kv heads {Hkv}")
+    if window is not None and window < q.shape[1]:
+        if not causal or sp_axis is not None or impl == "flash":
+            raise ValueError("a window is causal, unsharded over the sequence "
+                             "and not in the flash kernels")
+        return reference_attention(q, repeat_kv(k, n_rep=H // Hkv),
+                                   repeat_kv(v, n_rep=H // Hkv), causal=True,
+                                   scale=scale, window=window)
     k = repeat_kv(k, n_rep=H // Hkv)
     v = repeat_kv(v, n_rep=H // Hkv)
 

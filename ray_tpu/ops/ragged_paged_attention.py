@@ -32,6 +32,15 @@ value (absorbed multi-head latent attention: q is (q_nope W_uk^T | q_rope |
 stored (bfloat16 products, float32 sums: [H, W] x [W, P] on the MXU), where
 the per-head kernel multiplies in float32.
 
+Window layers (`window=W`, a multiple of the page size): the launch sweeps
+only the W/P + 1 logical pages that hold a row's last W positions. Step j of
+row b is logical page q = pos // P - W // P + j, whose place in the pool the
+caller's table column j names (the row's ring slot q % ring, see
+models/decoding_paged.py; scratch page 0 where q < 0: dead, nothing
+computed); its keys stand at positions q * P + lane and are masked to
+pos - W < kpos <= pos. The same kernel body and the same mirror, launched as
+`ragged_window_attention` so that a device trace tells the two kinds apart.
+
 The engine bounds the page sweep host-side (`pages_bound` in
 models/decoding_paged.py decode_step_paged_ragged): the block table is
 sliced to the batch's live maximum before either impl runs, so even the
@@ -52,7 +61,7 @@ _NEG_INF = -1e30
 
 def _ragged_kernel(tbl_ref, pos_ref, q_ref, kp_ref, vp_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, scale: float, page_size: int,
-                   kv_heads: int, q_per_kv: int):
+                   kv_heads: int, q_per_kv: int, window: int | None = None):
     b = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
@@ -65,10 +74,18 @@ def _ragged_kernel(tbl_ref, pos_ref, q_ref, kp_ref, vp_ref, o_ref,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     p0 = pos_ref[b]
-    # page j holds cache positions [j*P, (j+1)*P); live iff its first
-    # position is attendable (<= the row's current position) — dead pages
-    # are skipped entirely, which is what makes the sweep ragged
-    live = j * page_size <= p0
+    if window is None:
+        # step j is the row's page j, cache positions [j*P, (j+1)*P); live
+        # iff its first position is attendable (<= the row's current
+        # position) — dead pages are skipped entirely, which is what makes
+        # the sweep ragged
+        page = j
+        live = j * page_size <= p0
+    else:
+        # step j is logical page pos//P - W//P + j: the last one holds the
+        # row's current position, those before the row's start are dead
+        page = p0 // page_size - window // page_size + j
+        live = page >= 0
 
     @pl.when(live)
     def _compute():
@@ -77,9 +94,12 @@ def _ragged_kernel(tbl_ref, pos_ref, q_ref, kp_ref, vp_ref, o_ref,
         v = vp_ref[0].astype(jnp.float32)
         s = jnp.einsum("kgd,pkd->kgp", q, k,
                        preferred_element_type=jnp.float32) * scale
-        kpos = j * page_size + jax.lax.broadcasted_iota(
+        kpos = page * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 2)
-        s = jnp.where(kpos <= p0, s, _NEG_INF)
+        seen = kpos <= p0
+        if window is not None:
+            seen &= kpos > p0 - window
+        s = jnp.where(seen, s, _NEG_INF)
         sf = s.reshape(H, page_size)
         m_prev = m_scr[:, :1]                         # [H, 1]
         l_prev = l_scr[:, :1]
@@ -102,7 +122,7 @@ def _ragged_kernel(tbl_ref, pos_ref, q_ref, kp_ref, vp_ref, o_ref,
 
 
 def _ragged_kernel_call(q, kp, vp, block_table, pos, *, scale: float,
-                        interpret: bool):
+                        interpret: bool, window: int | None = None):
     B, Hkv, G, Dh = q.shape
     P = kp.shape[1]
     nb = block_table.shape[1]
@@ -128,13 +148,13 @@ def _ragged_kernel_call(q, kp, vp, block_table, pos, *, scale: float,
         ],
     )
     kernel = functools.partial(_ragged_kernel, scale=scale, page_size=P,
-                               kv_heads=Hkv, q_per_kv=G)
+                               kv_heads=Hkv, q_per_kv=G, window=window)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-        name="ragged_paged_attention",
+        name="ragged_paged_attention" if window is None else "ragged_window_attention",
     )(block_table, pos, q, kp, vp)
 
 
@@ -232,7 +252,7 @@ def _latent_reference(q, cp, block_table, pos, *, scale: float):
 
 
 def ragged_decode_attention_reference(q, kp, vp, block_table, pos, *,
-                                      scale: float):
+                                      scale: float, window: int | None = None):
     """Pure-JAX mirror of the kernel: fori_loop over pages with the SAME
     f32 online-softmax accumulation per page, so the two are
     bit-consistent (asserted in tier-1). Dead pages keep the previous
@@ -242,6 +262,7 @@ def ragged_decode_attention_reference(q, kp, vp, block_table, pos, *,
     nb = block_table.shape[1]
     H = Hkv * G
     qf = q.astype(jnp.float32)
+    p0 = pos[:, None, None, None]
 
     def body(j, carry):
         m, l, acc = carry
@@ -250,8 +271,13 @@ def ragged_decode_attention_reference(q, kp, vp, block_table, pos, *,
         v = vp[pid].astype(jnp.float32)
         s = jnp.einsum("bkgd,bpkd->bkgp", qf, k,
                        preferred_element_type=jnp.float32) * scale
-        kpos = j * P + jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
-        s = jnp.where(kpos <= pos[:, None, None, None], s, _NEG_INF)
+        # the step's logical page: j itself, or a row's own (kernel: `page`)
+        page = j if window is None else (pos // P - window // P + j)[:, None, None, None]
+        kpos = page * P + jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
+        seen = kpos <= p0
+        if window is not None:
+            seen &= kpos > p0 - window
+        s = jnp.where(seen, s, _NEG_INF)
         sf = s.reshape(B, H, P)
         m_new = jnp.maximum(m, sf.max(axis=-1, keepdims=True))
         p = jnp.exp(sf - m_new)
@@ -261,7 +287,7 @@ def ragged_decode_attention_reference(q, kp, vp, block_table, pos, *,
                         p.reshape(B, Hkv, G, P), v,
                         preferred_element_type=jnp.float32)
         acc_new = acc * corr + pv.reshape(B, H, Dh)
-        live = (j * P <= pos)[:, None, None]           # [B, 1, 1]
+        live = (j * P <= pos)[:, None, None] if window is None else page[:, :, :, 0] >= 0
         return (jnp.where(live, m_new, m), jnp.where(live, l_new, l),
                 jnp.where(live, acc_new, acc))
 
@@ -276,7 +302,8 @@ def ragged_decode_attention_reference(q, kp, vp, block_table, pos, *,
 def ragged_decode_attention(q, kp, vp, block_table, pos, *,
                             scale: float | None = None,
                             impl: str = "reference",
-                            interpret: bool = False):
+                            interpret: bool = False,
+                            window: int | None = None):
     """One decode-attention launch over the whole continuous batch.
 
     q: [B, Hkv, G, Dh] — this step's queries (one token per row, grouped
@@ -287,9 +314,18 @@ def ragged_decode_attention(q, kp, vp, block_table, pos, *,
 
     Latent rows: kp [num_pages, P, W], vp None, q [B, 1, H, W]; returns
     [B, 1, H, W], a weighted sum of rows.
+
+    `window` (per-head pools only): row b attends positions pos[b] - window
+    < p <= pos[b], and block_table is [B, window // P + 1]: column j names
+    the pool page of logical page pos[b] // P - window // P + j.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if window is not None and (vp is None or window % kp.shape[1]
+                               or block_table.shape[1] != window // kp.shape[1] + 1):
+        raise ValueError(
+            "a window is carried by the per-head kernel alone, is a multiple "
+            "of the page size and sweeps window // page_size + 1 pages a row")
     if vp is None:
         if impl == "kernel":
             out = _latent_kernel_call(q[:, 0], kp, block_table, pos,
@@ -300,9 +336,9 @@ def ragged_decode_attention(q, kp, vp, block_table, pos, *,
             raise ValueError(f"impl must be 'kernel' or 'reference', got {impl!r}")
         return out[:, None]
     if impl == "kernel":
-        return _ragged_kernel_call(q, kp, vp, block_table, pos,
-                                   scale=scale, interpret=interpret)
+        return _ragged_kernel_call(q, kp, vp, block_table, pos, scale=scale,
+                                   interpret=interpret, window=window)
     if impl != "reference":
         raise ValueError(f"impl must be 'kernel' or 'reference', got {impl!r}")
     return ragged_decode_attention_reference(q, kp, vp, block_table, pos,
-                                             scale=scale)
+                                             scale=scale, window=window)

@@ -86,14 +86,18 @@ def _scan_steps(step, carry, n):
     return lax.scan(step, carry, jnp.arange(n))
 
 
-def reference_attention(q, k, v, *, causal: bool = True, scale: float | None = None):
-    """Unsharded reference used by tests and by the single-device path."""
+def reference_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
+                        window: int | None = None):
+    """Unsharded reference used by tests and by the single-device path.
+    `window` (causal only): query i sees key j iff 0 <= i - j < window."""
     B, T, H, D = q.shape
     if scale is None:
         scale = D ** -0.5
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
     if causal:
         mask = jnp.tril(jnp.ones((T, T), dtype=bool))
+        if window is not None and window < T:
+            mask &= ~jnp.tril(jnp.ones((T, T), dtype=bool), -window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))

@@ -185,6 +185,45 @@ def test_latent_decode_step_holds_the_pool_once(chip):
     assert prefill.memory_analysis().temp_size_in_bytes < 64 * 2048 * 1408 * 2
 
 
+def test_window_and_full_decode_step_holds_both_kinds_of_pool_once(chip):
+    """The decode program of `mellum2-12b-a2.5b.mixed-saturated` at two of
+    its periods (the scan compiles one period whatever the depth): four pools,
+    the full layers' and the window layers', carried through the scan of
+    periods (and the scan of a period's window layers inside it) and
+    scattered in place — each kind of layer is traced once and takes the
+    pools of its kind, no branch between carried pools. One window launch
+    and one full launch, each under its own name. The temporaries are the Q,
+    K and V projections of the whole stack re-laid once a call (150,994,944 +
+    2 x 18,874,368 bytes here; a plain layer scan re-lays the same bytes a
+    layer at a time), far under a pool."""
+    from ray_tpu.models import decoding_paged, mellum_config
+
+    cfg = mellum_config("12b-a2.5b", n_layers=8, param_dtype=jnp.bfloat16, max_seq_len=16640)
+    slots, page, ring = 48, 64, decoding_paged.window_ring(cfg, 64, 1024)
+    assert ring == 33
+    params, _ = _abstract_step_inputs(chip, cfg, slots, 16640, 8, page)
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        jax.eval_shape(lambda: decoding_paged.init_paged_state(
+            cfg, slots, 16640, 2688, page, slots * ring + 1, ring)))
+    assert state["kp"].shape == (2, 2688, 64, 4, 128)
+    assert state["wkp"].shape == (6, 1585, 64, 4, 128) and state["wblock"].shape == (48, 33)
+    compiled = decoding_paged.decode_step_paged_ragged.lower(
+        params, state, cfg, 256, True).compile()
+    m = compiled.memory_analysis()
+    pools = 2 * (2 * 2688 + 6 * 1585) * 64 * 4 * 128 * 2
+    assert m.alias_size_in_bytes >= pools
+    window_pool = 6 * 1585 * 64 * 4 * 128 * 2              # K, or V, of the window layers
+    assert m.temp_size_in_bytes < window_pool // 3          # 154,710,528: no pool's copy
+    assert _kernel_calls(compiled.as_text()) == [
+        "ragged_window_attention", "ragged_paged_attention"]
+    # a 1024-token prefill: flash on every layer (the window cuts nothing off
+    # a sequence of its own length), the grouped products where the experts lie
+    prefill = _prefill_1024(chip, params, cfg)
+    calls = _kernel_calls(prefill.as_text())
+    assert calls.count("grouped_matmul") == 3 * 2 and "ragged-dot" not in prefill.as_text()
+
+
 def test_mixtral_prefill_chunk_multiplies_the_experts_where_they_lie(chip):
     """The 1,024-token prefill of `mixtral-8x7b.doc-saturated` (4 layers,
     published widths): three `grouped_matmul` kernels in the layer scan, at
