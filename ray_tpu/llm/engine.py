@@ -441,6 +441,16 @@ class TPUEngine:
         # not rebuilt/re-uploaded every decode step
         self._temps = jnp.zeros((max_slots,), jnp.float32)
         self._topks = jnp.zeros((max_slots,), jnp.int32)
+        # what the LIVE rows ask of the sampler, kept on the host as rows
+        # join and leave (_count_live): how many sample at all and the
+        # `top_k` values of those, by value. A released slot's entries above
+        # stay as they were, so the step's form is chosen from these and
+        # never from the device: (sampling, k_bucket, its steps' counter)
+        self._live_sampling = 0
+        self._live_top_ks: collections.Counter = collections.Counter()
+        self._sampler_form = (False, 0, "steps_argmax")
+        self.sampler_steps = {"steps_argmax": 0, "steps_categorical": 0,
+                              "steps_top_k": 0}
         # guided decoding: per-slot host-side FSM + current state; the only
         # per-step device traffic is the additive bias rows (llm/guided.py)
         self._guided_fsm: dict[int, object] = {}
@@ -1026,11 +1036,23 @@ class TPUEngine:
         """A row joins (+1) or leaves (-1) the rows the decode step advances:
         its positions, and those of them beyond the window, in the running
         sums the cache counters add every pass (`_emit` moves them a token
-        at a time in between)."""
+        at a time in between); and, if it samples, what it asks of the
+        sampler, from which the step's form is chosen anew."""
         held = req.length0 + req.generated
         self._live_tokens += sign * held
         if self.cfg.window:
             self._live_beyond_window += sign * max(0, held - self.cfg.window)
+        if req.params.temperature > 0:
+            self._live_sampling += sign
+            if req.params.top_k > 0:
+                self._live_top_ks[req.params.top_k] += sign
+                self._live_top_ks = +self._live_top_ks  # values somebody holds
+            k_bucket = decoding.top_k_bucket(
+                max(self._live_top_ks, default=0), self.cfg.vocab_size)
+            self._sampler_form = (
+                (False, 0, "steps_argmax") if not self._live_sampling else
+                (True, k_bucket,
+                 "steps_top_k" if k_bucket else "steps_categorical"))
 
     def _bind_slot(self, req: _Request, slot: int,
                    length: int | None = None) -> None:
@@ -1773,13 +1795,17 @@ class TPUEngine:
                         fsm, self._guided_state[slot],
                         remaining=r.params.max_tokens - r.generated)
                 logits = logits + jnp.asarray(bias)
-            # sampling params live on device, updated only at admission
-            toks = decoding.sample_per_row(logits, sub, self._temps, self._topks)
+            # sampling params live on device, updated only at admission; the
+            # sampler's form follows what the live rows ask for
+            sampling, k_bucket, form = self._sampler_form
+            toks = decoding.sample_per_row(logits, sub, self._temps,
+                                           self._topks, sampling, k_bucket)
             self.state = decoding.commit_tokens(self.state, toks)
             mark("decode_wait")
             toks_host = np.asarray(toks)
             t_emit = mark("emit")
             self.decode_steps += 1
+            self.sampler_steps[form] += 1
             self.decode_slot_steps += len(self._by_slot)
             self._count_expert_tokens(self.max_slots)
             # each live row attended over length0 + generated positions on
@@ -1853,6 +1879,9 @@ class TPUEngine:
             out["ring"] = self.ring
         out["experts"] = {"tokens_sorted": self.expert_tokens_sorted,
                           "tokens_onehot": self.expert_tokens_onehot}
+        # decode steps by the form the sampler took (they add up to
+        # decode_steps): how often anything beyond an argmax is paid for
+        out["sampler"] = dict(self.sampler_steps)
         if self.prefill_chunk:
             out["prefill_chunk"] = self.prefill_chunk
             out["prefill_chunks_run"] = self.prefill_chunks_run

@@ -246,19 +246,56 @@ def commit_tokens(state, next_tokens):
     return state
 
 
-@jax.jit
-def sample_per_row(logits, key, temperatures, top_ks):
+# The largest `k_bucket` short of the whole vocabulary. On the v5e the
+# compiler's TopK costs about as many times its k = 1 cost as k is large
+# (48 x 98,304 logits: 0.30 ms at 8, 1.13 at 64, 2.07 at 128, 3.96 at 256, 7.74
+# at 512) where its sort of the vocabulary, which `lax.top_k` at k = V lowers
+# to, costs 4.95; over 32 x 32,000 logits 256 already costs more than the sort
+# (1.16 against 0.84 ms). PERF.md, section 6, PR 34.
+TOP_K_MAX_BUCKET = 128
+
+
+def top_k_bucket(k: int, vocab: int) -> int:
+    """The static `k_bucket` of `sample_per_row` for a largest live `top_k`
+    of `k`: the next power of two at or above it up to `TOP_K_MAX_BUCKET`,
+    the whole vocabulary beyond (0 stays 0: no row cuts). Powers of two keep
+    the compiled forms few."""
+    if k <= 0:
+        return 0
+    bucket = 1 << (k - 1).bit_length()
+    return bucket if bucket <= min(TOP_K_MAX_BUCKET, vocab) else vocab
+
+
+@functools.partial(jax.jit, static_argnames=("sampling", "k_bucket"))
+def sample_per_row(logits, key, temperatures, top_ks, sampling: bool,
+                   k_bucket: int):
     """Row-wise temperature + top-k sampling for the decode hot loop.
-    logits [B, V], temperatures [B] (0 → greedy), top_ks [B] int32 (0 → off)."""
-    V = logits.shape[-1]
+    logits [B, V], temperatures [B] (0 → greedy), top_ks [B] int32 (0 → off).
+
+    The two static arguments describe what the LIVE rows ask for; the engine
+    keeps them on the host as rows join and leave, no user sets them, and
+    the program does no more than they call for. `sampling` false (every
+    live temperature <= 0): an argmax and nothing else. `k_bucket` 0 (no
+    sampling live row has a `top_k`): categorical over the scaled logits,
+    no cut. Otherwise `top_k_bucket` of the largest live `top_k`: the k-th
+    largest value comes from `lax.top_k(scaled, k_bucket)`, which sorts the
+    vocabulary only where a live row asks for more than `TOP_K_MAX_BUCKET`.
+    For the same inputs every form that applies returns the same tokens for
+    the live rows. A dead row's stale entry may ask for more than the form
+    gives (its `top_k` is clipped to the bucket); `commit_tokens` drops its
+    token."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    if not sampling:
+        return greedy
     scaled = logits / jnp.maximum(temperatures, 1e-6)[:, None]
-    # per-row k-th largest as the cutoff (k=0 → cutoff -inf, i.e. no cut)
-    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    idx = jnp.clip(top_ks - 1, 0, V - 1)
-    kth = jnp.take_along_axis(sorted_desc, idx[:, None], axis=-1)
-    kth = jnp.where(top_ks[:, None] > 0, kth, -jnp.inf)
-    scaled = jnp.where(scaled < kth, -1e30, scaled)
+    if k_bucket:
+        # per-row k-th largest as the cutoff (k=0 → cutoff -inf, i.e. no cut):
+        # the number a full descending sort holds at index k - 1
+        top, _ = jax.lax.top_k(scaled, k_bucket)
+        idx = jnp.clip(top_ks - 1, 0, k_bucket - 1)
+        kth = jnp.take_along_axis(top, idx[:, None], axis=-1)
+        kth = jnp.where(top_ks[:, None] > 0, kth, -jnp.inf)
+        scaled = jnp.where(scaled < kth, -1e30, scaled)
     sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
     return jnp.where(temperatures <= 0.0, greedy, sampled)
 
@@ -270,7 +307,8 @@ def sample(logits, key, temperature: float, top_k: int = 0):
     t = jnp.maximum(temperature, 1e-6)
     scaled = logits / t
     if top_k and top_k > 0:
-        kth = jnp.sort(scaled, axis=-1)[:, -top_k][:, None]
+        k = min(top_k, logits.shape[-1])
+        kth = jax.lax.top_k(scaled, k)[0][:, -1:]
         scaled = jnp.where(scaled < kth, -1e30, scaled)
     sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
     return jnp.where(temperature <= 0.0, greedy, sampled)

@@ -330,6 +330,129 @@ def test_engine_phases_are_on_the_profiler_timeline(tiny_model, tmp_path):
     assert {n[len("ray_tpu:engine:"):] for n in names} <= set(LOOP_PHASES)
 
 
+# ------------------------------- the sampler's form follows the live rows
+
+
+def _forms_recorded(monkeypatch) -> list:
+    """Every decode step's static sampler arguments, in order."""
+    forms = []
+    real = engine_mod.decoding.sample_per_row
+
+    def recording(logits, key, temperatures, top_ks, sampling, k_bucket):
+        forms.append((sampling, k_bucket))
+        return real(logits, key, temperatures, top_ks, sampling, k_bucket)
+
+    monkeypatch.setattr(engine_mod.decoding, "sample_per_row", recording)
+    return forms
+
+
+def _runs(forms: list) -> list:
+    return [f for i, f in enumerate(forms) if i == 0 or f != forms[i - 1]]
+
+
+def _sampler_adds_up(st: dict, forms: list) -> None:
+    assert sum(st["sampler"].values()) == st["decode_steps"] == len(forms)
+    assert st["sampler"] == {
+        "steps_argmax": sum(not s for s, _ in forms),
+        "steps_categorical": sum(s and not k for s, k in forms),
+        "steps_top_k": sum(s and k > 0 for s, k in forms)}
+
+
+def test_greedy_traffic_takes_the_argmax_form_only(tiny_model, monkeypatch):
+    forms = _forms_recorded(monkeypatch)
+    eng = _engine(tiny_model)
+    try:
+        _burst(eng, 6, 20, 8)
+        st = _quiet_stats(eng)
+    finally:
+        eng.shutdown()
+    assert st["decode_steps"] > 0
+    assert st["sampler"] == {"steps_argmax": st["decode_steps"],
+                             "steps_categorical": 0, "steps_top_k": 0}
+    assert set(forms) == {(False, 0)}
+    _sampler_adds_up(st, forms)
+
+
+def test_a_sampling_row_moves_the_form_while_it_lives(tiny_model, monkeypatch):
+    """A request with a temperature and `top_k` 5 admitted beside two greedy
+    rows moves the step to the `top_k` form at bucket 8; its neighbours'
+    tokens are a greedy-only run's; once it is released the step is an
+    argmax again, although its slot's entries on the device still ask."""
+    eng = _engine(tiny_model)
+    try:
+        alone = _burst(eng, 2, 12, 40)
+    finally:
+        eng.shutdown()
+    forms = _forms_recorded(monkeypatch)
+    eng = _engine(tiny_model)
+    try:
+        greedy = [eng.submit(_prompt(i, 12), SamplingParams(max_tokens=40))
+                  for i in range(2)]
+        streams = [iter(r) for r in greedy]
+        heads = [[next(s) for _ in range(3)] for s in streams]
+        sampled = list(eng.submit(_prompt(3, 12), SamplingParams(
+            max_tokens=6, temperature=0.8, top_k=5)))
+        outs = [h + list(s) for h, s in zip(heads, streams)]
+        st = _quiet_stats(eng)
+        stale = (jax.device_get(eng._temps), jax.device_get(eng._topks))
+        form = eng._sampler_form
+    finally:
+        eng.shutdown()
+    assert outs == alone and len(sampled) == 6
+    assert _runs(forms) == [(False, 0), (True, 8), (False, 0)]
+    assert st["sampler"]["steps_top_k"] >= 5  # its first token is the prefill's
+    _sampler_adds_up(st, forms)
+    assert form == (False, 0, "steps_argmax")
+    assert stale[0].max() == pytest.approx(0.8) and stale[1].max() == 5
+
+
+def test_the_bucket_falls_when_the_largest_top_k_leaves(tiny_model, monkeypatch):
+    """Two sampling rows of `top_k` 5 and 40 beside a greedy one: bucket 64
+    while both live, 8 once the second has left, an argmax once both have;
+    a sampling row without a `top_k` takes the form that cuts nothing."""
+    forms = _forms_recorded(monkeypatch)
+    eng = _engine(tiny_model)
+    try:
+        greedy = iter(eng.submit(_prompt(0, 12), SamplingParams(max_tokens=60)))
+        head = [next(greedy) for _ in range(2)]
+        five = eng.submit(_prompt(1, 12), SamplingParams(
+            max_tokens=30, temperature=0.8, top_k=5))
+        forty = eng.submit(_prompt(2, 12), SamplingParams(
+            max_tokens=4, temperature=0.8, top_k=40))
+        assert len(list(forty)) == 4 and len(list(five)) == 30
+        assert len(head + list(greedy)) == 60
+        st = _quiet_stats(eng)
+        _sampler_adds_up(st, forms)
+        assert _runs(forms)[-3:] == [(True, 64), (True, 8), (False, 0)]
+        assert not eng._live_top_ks and not eng._live_sampling
+        before = len(forms)
+        list(eng.submit(_prompt(4, 12), SamplingParams(max_tokens=5, temperature=1.0)))
+        st = _quiet_stats(eng)
+    finally:
+        eng.shutdown()
+    assert set(forms[before:]) == {(True, 0)}
+    assert st["sampler"]["steps_categorical"] == len(forms) - before >= 4
+    _sampler_adds_up(st, forms)
+
+
+@pytest.mark.parametrize("temperature,top_k,pinned", [
+    (0.8, 5, [47, 46, 36, 53, 12, 23, 36, 47, 8, 5, 55, 1]),
+    (1.1, 0, [9, 21, 33, 6, 52, 23, 61, 33, 18, 3, 22, 1]),
+    (0.9, 40, [9, 21, 33, 6, 52, 50, 61, 33, 18, 47, 22, 1])])
+def test_a_seeded_sampled_request_draws_the_sorted_samplers_tokens(
+        tiny_model, temperature, top_k, pinned):
+    """`pinned` are the tokens of the tree before ISSUE 34 (a6c2c80), whose
+    sampler sorted the vocabulary, for this engine, seed and request: the
+    key is split once a step in every form, and the cut is the same."""
+    eng = _engine(tiny_model, seed=0)
+    try:
+        out = list(eng.submit(_prompt(3, 12), SamplingParams(
+            max_tokens=12, temperature=temperature, top_k=top_k)))
+    finally:
+        eng.shutdown()
+    assert out == pinned
+
+
 # ------------------------------------------- the benchmark's reader of these
 
 

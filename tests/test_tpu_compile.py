@@ -244,6 +244,46 @@ def test_mixtral_prefill_chunk_multiplies_the_experts_where_they_lie(chip):
     assert "grouped_matmul" not in step and "ragged-dot" not in step
 
 
+# (sampling, k_bucket) -> the program has a sort of the vocabulary
+@pytest.mark.parametrize("sampling,k_bucket,sorts", [
+    (False, 0, False),      # every live row greedy: what every cell runs
+    (True, 0, False),       # a live row samples, none cuts
+    (True, 8, False),       # the largest live top_k in (4, 8]
+    (True, 128, False),     # `decoding.TOP_K_MAX_BUCKET`
+    (True, 98304, True),    # beyond it: the whole vocabulary, as the old sampler
+])
+def test_sampler_form_sorts_no_vocabulary(chip, sampling, k_bucket, sorts):
+    """`sample_per_row` at the widths of `mellum2-12b-a2.5b.mixed-saturated`
+    (48 rows x 98,304 logits, 18,874,368 bytes): the argmax form holds no
+    sort and no temporary of the logits' size (the sampler up to PR 32: one
+    sort, temporaries 19,293,696 bytes), the categorical form neither (the
+    noise and the second argmax fuse), and `lax.top_k` lowers to the
+    compiler's `TopK` custom call at every bucket up to the largest and to
+    a sort at the whole vocabulary, which is the old sampler's cost and no
+    more (this compiler keeps `TopK` up to k = 512, where the chip shows it
+    slower than the sort: `decoding.TOP_K_MAX_BUCKET`)."""
+    import re
+
+    from ray_tpu.models import decoding
+
+    B, V = 48, 98304
+
+    def sds(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=chip)
+
+    compiled = decoding.sample_per_row.lower(
+        sds((B, V), jnp.float32), sds((2,), jnp.uint32), sds((B,), jnp.float32),
+        sds((B,), jnp.int32), sampling, k_bucket).compile()
+    text = compiled.as_text()
+    assert bool(re.search(r"\bsort\(", text)) == sorts
+    assert k_bucket in (0, V) or k_bucket == decoding.top_k_bucket(k_bucket, V)
+    assert ('custom_call_target="TopK"' in text) == (0 < k_bucket < V)
+    if not sorts:
+        assert compiled.memory_analysis().temp_size_in_bytes < B * V * 4 // 16
+    if not sampling:
+        assert "custom_call_target" not in text
+
+
 def test_kernel_names_reach_the_compiled_program(chip):
     """`pallas_call(name=...)` names the HLO instruction of each kernel's
     custom call, which is what a device trace names the op by: the trace
