@@ -69,7 +69,7 @@ class PDConfig:
 class LLMConfig:
     model_loading_config: ModelLoadingConfig = field(default_factory=ModelLoadingConfig)
     # TransformerConfig kwargs for the built-in families (gpt2/llama/mixtral/
-    # kimi_vl/mellum)
+    # kimi_vl/mellum/ouro)
     model_family: str = "llama"
     model_kwargs: dict = field(default_factory=dict)
     engine_kwargs: dict = field(default_factory=dict)  # TPUEngine keywords:
@@ -112,7 +112,8 @@ class LLMConfig:
         factory = {"llama": models.llama_config, "gpt2": models.gpt2_config,
                    "mixtral": models.mixtral_config,
                    "kimi_vl": models.kimi_vl_config,
-                   "mellum": models.mellum_config}[self.model_family]
+                   "mellum": models.mellum_config,
+                   "ouro": models.ouro_config}[self.model_family]
         cfg = factory(self.model_loading_config.model_id, **self.model_kwargs)
         src = self.model_loading_config.model_source
         if src:

@@ -272,12 +272,15 @@ class TPUEngine:
                 f"max_seq_len {cfg.max_seq_len} (rope/pos tables are sized "
                 "by the model config)")
         self.max_slots = max_slots
-        if cfg.mla or cfg.n_dense_layers or cfg.window:
-            # what is not carried to the latent cache and to two kinds of
-            # layer in one stack: refused here, not at the first request
+        if (cfg.mla or cfg.n_dense_layers or cfg.window or cfg.n_passes > 1
+                or cfg.sandwich_norms):
+            # what is not carried to the latent cache, to two kinds of layer
+            # in one stack and to a stack run several times: refused here,
+            # not at the first request
             kind = ("latent attention (kv_lora_rank)" if cfg.mla
                     else "window layers" if cfg.window
-                    else "leading dense layers")
+                    else "leading dense layers" if cfg.n_dense_layers
+                    else "a looped stack (n_passes) or sandwich norms")
             for on, what in ((mesh is not None, "a tensor-parallel mesh"),
                              (max_loras, "max_loras")):
                 if on:
@@ -285,7 +288,7 @@ class TPUEngine:
                         f"a model with {kind} is served on one chip, without "
                         f"{what}: the sharding of the page pool over kv "
                         "heads and the LoRA bank are built for per-head K "
-                        "and V over one kind of layer")
+                        "and V over one kind of layer, each applied once")
         if cfg.window and enable_prefix_cache:
             raise ValueError(
                 "a model with window layers is served without "
@@ -406,6 +409,12 @@ class TPUEngine:
             self._lora_lock = threading.Lock()
         self.decode_steps = 0
         self.decode_slot_steps = 0  # sum of active slots over decode steps
+        # a looped stack on the record (stats()["loops"]): passes over the
+        # stack the decode steps ran (n_passes a step: every row takes every
+        # pass), and live rows by the pass at which their exit CDF first
+        # reached a half: what leaving the loop early WOULD save
+        self.stack_passes = 0
+        self.exit_rows = np.zeros((cfg.n_passes,), np.int64)
         # the cache on the record, cumulative (stats()["cache"]): positions
         # the decode steps attended over, prefix tokens gathered out of the
         # pool for continuation prefills, pages held and pages in the pool
@@ -725,13 +734,14 @@ class TPUEngine:
           error; the slot and its granted pages are reclaimed.
         """
         self._check_alive()
-        if self.cfg.mla or self.cfg.window:
+        if self.cfg.mla or self.cfg.window or self.cfg.n_passes > 1:
             raise NotImplementedError(
                 "submit_prefilled: the PD transfer plane (llm/pd.py, "
                 "kv_transfer.py) moves per-head K and V pages of one kind of "
-                "layer; a model with latent attention caches one row a token, "
-                "one with window layers a ring of pages on those layers, and "
-                "neither is carried over it")
+                "layer, a plane a layer; a model with latent attention caches "
+                "one row a token, one with window layers a ring of pages on "
+                "those layers, a looped stack a plane for every pass of every "
+                "layer, and none is carried over it")
         params = params or SamplingParams()
         paged_form = k_pages is not None or v_pages is not None
         if kv_stream is not None:
@@ -1802,9 +1812,16 @@ class TPUEngine:
                                            self._topks, sampling, k_bucket)
             self.state = decoding.commit_tokens(self.state, toks)
             mark("decode_wait")
-            toks_host = np.asarray(toks)
+            if self.cfg.exit_gate:  # the step's exit CDF comes with its tokens
+                toks_host, exit_cdf = jax.device_get((toks, self.state["exit_cdf"]))
+            else:
+                toks_host, exit_cdf = np.asarray(toks), None
             t_emit = mark("emit")
             self.decode_steps += 1
+            self.stack_passes += self.cfg.n_passes
+            if exit_cdf is not None:
+                np.add.at(self.exit_rows,
+                          (exit_cdf[list(self._by_slot)] >= 0.5).argmax(axis=1), 1)
             self.sampler_steps[form] += 1
             self.decode_slot_steps += len(self._by_slot)
             self._count_expert_tokens(self.max_slots)
@@ -1877,6 +1894,13 @@ class TPUEngine:
             out["window_pages"] = self.window_pages
             out["free_window_pages"] = len(self._free_wpages)
             out["ring"] = self.ring
+        # cache planes from the state's own shapes: a layer application each
+        out["loops"] = {"passes": self.cfg.n_passes,
+                        "planes": sum(self.state[k].shape[0] for k in ("kp", "wkp")
+                                      if k in self.state),
+                        "stack_passes": self.stack_passes}
+        if self.cfg.exit_gate:
+            out["loops"]["exit_rows"] = self.exit_rows.tolist()
         out["experts"] = {"tokens_sorted": self.expert_tokens_sorted,
                           "tokens_onehot": self.expert_tokens_onehot}
         # decode steps by the form the sampler took (they add up to

@@ -4,6 +4,7 @@ from ray_tpu.models.kimi_vl import kimi_vl_config
 from ray_tpu.models.llama import llama_config
 from ray_tpu.models.mellum import mellum_config
 from ray_tpu.models.mixtral import mixtral_config
+from ray_tpu.models.ouro import ouro_config
 from ray_tpu.models.transformer import MoEConfig, TransformerConfig
 from ray_tpu.models.vit import ViTConfig, vit_config
 
@@ -16,6 +17,7 @@ __all__ = [
     "llama_config",
     "mellum_config",
     "mixtral_config",
+    "ouro_config",
     "transformer",
     "vit",
     "vit_config",

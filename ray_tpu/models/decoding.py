@@ -26,17 +26,20 @@ import jax.numpy as jnp
 
 from ray_tpu import ops
 from ray_tpu.models.transformer import (TransformerConfig, _dense_mlp, _mla_expand,
-                                        _mla_project, _moe_mlp, _norm, rope_by_kind,
-                                        scan_layers)
+                                        _mla_project, _moe_mlp, _norm, _residual,
+                                        close_pass, rope_by_kind, scan_layers)
 
 
 def _per_head_kv_only(cfg: TransformerConfig, what: str) -> None:
-    """The paths not carried to the latent cache or to two kinds of layer."""
-    if cfg.mla or cfg.n_dense_layers or cfg.window:
+    """The paths not carried to the latent cache, to two kinds of layer or
+    to a looped stack."""
+    if (cfg.mla or cfg.n_dense_layers or cfg.window or cfg.n_passes > 1
+            or cfg.sandwich_norms):
         raise NotImplementedError(
-            f"{what} is built for per-head K and V over one kind of layer; a "
-            "model with latent attention (kv_lora_rank), leading dense "
-            "layers or window layers is served without it")
+            f"{what} is built for per-head K and V over one kind of layer, "
+            "each applied once; a model with latent attention (kv_lora_rank), "
+            "leading dense layers, window layers, a looped stack (n_passes) or "
+            "sandwich norms is served without it")
 
 
 def _rope(cfg):
@@ -137,6 +140,8 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
 
     Returns (logits_at_last [V], kv {k,v: [L, T, Hkv, Dh]}; with latent
     attention kv is {k: [L, T, latent_lanes]}, the rows the cache holds).
+    A looped stack (cfg.n_passes) returns kv by plane, [n_passes * L, T, ...]:
+    every layer application's own keys and values, pass-major.
     With window layers kv still holds every layer's T positions: which of
     them a window layer keeps is the cache's business (decoding_paged.py).
     With `lora_bank` + scalar `lora_idx`, applies that adapter's q/v
@@ -173,17 +178,21 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
         out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
             out = out + layer_p["attn"]["bo"].astype(dt)
-        h = h + out
-        h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
+        h = _residual(h, out, layer_p, "post_attn_norm", cfg)
+        h = _residual(h, _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg),
+                      layer_p, "post_mlp_norm", cfg)
         return h, (k[0], v[0])
 
+    def close(h, t):  # the exit gate decides nothing about a prompt
+        return close_pass(h, None, t, params, cfg)[0]
+
     if lora_bank is None:
-        x, kv = scan_layers(block, x, params, cfg)
+        x, kv = scan_layers(block, x, params, cfg, close=close)
     else:
         x, kv = jax.lax.scan(block, x, (
             params["layers"], lora_bank["A_q"], lora_bank["B_q"],
             lora_bank["A_v"], lora_bank["B_v"]))
-    x = _norm(x, params["final_norm"], cfg)
+        x = close(x, 0)
     last = x[0, length - 1]
     if cfg.tie_embeddings:
         logits = last @ params["embed"].astype(dt).T

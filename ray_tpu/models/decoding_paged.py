@@ -8,8 +8,10 @@ sequences at typical length distributions than a `max_len` reservation per
 slot would.
 
 All shapes stay static (XLA-first, like everything here): a pool is
-[L, num_pages, page, Hkv, Dh] over the L layers of its kind; per-step
-writes are scatters at
+[planes, num_pages, page, Hkv, Dh] over the cache planes of its kind: one a
+layer, and in a looped stack (cfg.n_passes = T passes over L layers) one a
+layer APPLICATION, T * L of them, plane t * L + l holding what layer l wrote
+in pass t. Per-step writes are scatters at
 (page_id, offset) and attention is one ragged launch over the rows' block
 tables. Page allocation/free is host-side bookkeeping in the engine
 (a free list), mirroring how vLLM's scheduler owns its block tables.
@@ -54,7 +56,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models.decoding import _attn_qkv, _mla_prefill_attn, _mlp_block
 from ray_tpu.models.transformer import (TransformerConfig, _mla_absorb_out, _mla_absorb_q,
-                                        _mla_project, _norm, kind_index, rope_by_kind,
+                                        _mla_project, _norm, _residual, close_pass,
+                                        exit_distribution, kind_index, rope_by_kind,
                                         scan_layers)
 from ray_tpu import ops
 
@@ -78,8 +81,13 @@ def init_paged_state(cfg: TransformerConfig, max_slots: int, max_len: int,
     chunk, never more than a row's pages) in a pool of `window_pages`
     (default: a ring for every slot + scratch, or num_pages if that is
     fewer: no row holds more window pages than full ones)."""
-    L, Hkv, Dh = cfg.n_layers, cfg.kv_heads, cfg.head_dim
+    # cache planes: the layers, times the passes of a looped stack (which has
+    # neither latent rows nor window layers)
+    L, Hkv, Dh = cfg.n_planes, cfg.kv_heads, cfg.head_dim
     max_pages_per_seq = (max_len + page_size - 1) // page_size
+    gate = {}
+    if cfg.exit_gate:  # the last decode step's exit CDF a row, by pass
+        gate["exit_cdf"] = jnp.zeros((max_slots, cfg.n_passes), jnp.float32)
     if cfg.mla:
         pools = {"kp": jnp.zeros((L, num_pages, page_size, cfg.latent_lanes), cfg.dtype)}
     elif cfg.window:
@@ -97,7 +105,7 @@ def init_paged_state(cfg: TransformerConfig, max_slots: int, max_len: int,
         pools = {"kp": jnp.zeros((L, num_pages, page_size, Hkv, Dh), cfg.dtype),
                  "vp": jnp.zeros((L, num_pages, page_size, Hkv, Dh), cfg.dtype)}
     return {
-        **pools,
+        **pools, **gate,
         # page ids per slot; unused entries point at page 0 (masked anyway)
         "block": jnp.zeros((max_slots, max_pages_per_seq), jnp.int32),
         "length": jnp.zeros((max_slots,), jnp.int32),
@@ -248,7 +256,13 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
     of a period takes its own (the kind is static in the unrolled period: no
     branch between carried pools, which would copy them): a window layer
     scatters at ring slot (pos // P) % wring and its launch sweeps the
-    window // P + 1 logical pages that end at pos, whatever `pages_bound`."""
+    window // P + 1 logical pages that end at pos, whatever `pages_bound`.
+
+    A looped stack scatters and attends plane by plane (plane t * L + l in
+    pass t), the final norm closing every pass; with the exit gate the
+    returned state's `exit_cdf` [B, n_passes] is each row's probability of
+    having left the loop by the end of each pass (float32; the sampler does
+    not read it: the engine counts from it)."""
     from ray_tpu.ops.ragged_paged_attention import ragged_decode_attention
 
     # the ragged sweep only walks the batch's live prefix of each table;
@@ -297,7 +311,7 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
             window=cfg.window if window else None)
 
     def block(carry, layer_in, window=False):
-        h, kp, vp, *others = carry               # pools [L*num_pages, P, Hkv, Dh]
+        h, gates, kp, vp, *others = carry        # pools [L*num_pages, P, Hkv, Dh]
         if window:                               # this layer's kind of pool
             (kp, vp), others = others, [kp, vp]
         layer_p, base, *lora_l = layer_in        # base: this layer's first page
@@ -312,7 +326,7 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
             out = _mla_absorb_out(o_lat[:, 0].astype(dt), ap, cfg)
             h = h + jnp.einsum("bhd,hde->be", out, ap["wo"].astype(dt))[:, None]
             h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
-            return (h, kp, vp), None
+            return (h, gates, kp, vp), None
         q, k, v = _attn_qkv(normed, layer_p["attn"], cfg, lora_l, slot_lora,
                             lscale)                            # [B, 1, H, Dh]
         if cfg.pos == "rope":
@@ -327,17 +341,22 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
         out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
             out = out + layer_p["attn"]["bo"].astype(dt)
-        h = h + out
-        h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
+        h = _residual(h, out, layer_p, "post_attn_norm", cfg)
+        h = _residual(h, _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg),
+                      layer_p, "post_mlp_norm", cfg)
         if window:
-            return (h, *others, kp, vp), None
-        return (h, kp, vp, *others), None
+            return (h, gates, *others, kp, vp), None
+        return (h, gates, kp, vp, *others), None
 
-    (x, kp, vp, *wpools), _ = scan_layers(
-        block, (x, state["kp"].reshape(flat), vp0, *wpools), params, cfg, bases,
+    def close(carry, t):
+        h, gates, *pools = carry
+        return (*close_pass(h, gates, t, params, cfg), *pools)
+
+    gates = jnp.zeros((cfg.n_passes, B, 1), jnp.float32) if cfg.exit_gate else None
+    (x, gates, kp, vp, *wpools), _ = scan_layers(
+        block, (x, gates, state["kp"].reshape(flat), vp0, *wpools), params, cfg, bases,
         *(() if lora_bank is None else
-          (lora_bank[k] for k in ("A_q", "B_q", "A_v", "B_v"))))
-    x = _norm(x, params["final_norm"], cfg)
+          (lora_bank[k] for k in ("A_q", "B_q", "A_v", "B_v"))), close=close)
     if cfg.tie_embeddings:
         logits = x[:, 0] @ params["embed"].astype(dt).T
     else:
@@ -348,6 +367,8 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
         state["vp"] = vp.reshape(state["vp"].shape)
     for name, pool in zip(("wkp", "wvp"), wpools):
         state[name] = pool.reshape(state[name].shape)
+    if gates is not None:
+        state["exit_cdf"] = jnp.cumsum(exit_distribution(gates[..., 0]), axis=0).T
     state["length"] = jnp.where(state["active"], state["length"] + 1, state["length"])
     return state, logits.astype(jnp.float32)
 
@@ -395,7 +416,8 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
     already roped at its absolute positions) plus the causal suffix.
 
     Returns (logits at the last suffix token [V],
-             suffix kv {k, v: [L, Ts, Hkv, Dh]}).
+             suffix kv {k, v: [L, Ts, Hkv, Dh]}); a looped stack takes its
+    prefix and returns its suffix by plane, [n_passes * L, ...].
     Compilation count is bounded by #prefix_buckets × #suffix_buckets.
     With latent attention `prefix_k` is the cached rows [L, Tp, lanes],
     `prefix_v` None: every layer expands them to per-head K and V.
@@ -475,12 +497,13 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
         out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
             out = out + layer_p["attn"]["bo"].astype(dt)
-        h = h + out
-        h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
+        h = _residual(h, out, layer_p, "post_attn_norm", cfg)
+        h = _residual(h, _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg),
+                      layer_p, "post_mlp_norm", cfg)
         return h, (k[0], v[0])
 
-    x, kv = scan_layers(block, x, params, cfg, *per_layer)
-    x = _norm(x, params["final_norm"], cfg)
+    x, kv = scan_layers(block, x, params, cfg, *per_layer,
+                        close=lambda h, t: close_pass(h, None, t, params, cfg)[0])
     last = x[0, length - 1]
     if cfg.tie_embeddings:
         logits = last @ params["embed"].astype(dt).T

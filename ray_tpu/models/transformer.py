@@ -1,5 +1,5 @@
 """Decoder-only transformer core shared by the GPT-2 / Llama / Mixtral /
-Kimi-VL (DeepSeek-V3-style) / Mellum families.
+Kimi-VL (DeepSeek-V3-style) / Mellum / Ouro families.
 Pure-functional: params are pytrees (layers stacked on a leading
 dim and consumed by lax.scan — compile-fast and pipeline-ready), logical axis
 trees drive mesh sharding, compute runs in bf16 with f32 accumulators.
@@ -10,6 +10,10 @@ the plain rope, and full layers (every `window_period`-th), which see
 everything and take the YaRN rope where `yarn` is set. The layers stay
 stacked [L, ...]; `scan_layers` scans whole periods and tells each block its
 kind.
+
+A stack may be looped (`TransformerConfig.n_passes`): the same stacked
+weights applied several times, the final norm closing every pass;
+`scan_layers` scans the passes outside the layers.
 
 The reference framework contains no model code (models live in user code /
 vLLM); these families exist so the framework's train/serve/bench paths are
@@ -92,10 +96,28 @@ class TransformerConfig:
     # YaRN on the full layers' rope (ops/rope.py Yarn); window layers take
     # the plain rope of the same theta
     yarn: ops.Yarn | None = None
+    # a looped stack: the n_layers layers are applied n_passes times over the
+    # SAME weights, the final norm closes every pass and its output is the
+    # next pass's input. Each of the n_passes * n_layers layer applications
+    # has keys and values of its own: cache plane t * n_layers + l
+    n_passes: int = 1
+    # a norm on each sublayer's OUTPUT before the residual add (post_attn_norm,
+    # post_mlp_norm), beside norm1 / norm2 on its input; their weights start
+    # at sandwich_norm_init, which is the family's to choose (models/ouro.py)
+    sandwich_norms: bool = False
+    sandwich_norm_init: float = 1.0
+    # sigmoid(w . x_t + b) on every pass's closed output: the probability of
+    # leaving the loop after pass t (`exit_distribution`)
+    exit_gate: bool = False
 
     @property
     def n_full_layers(self) -> int:
         return self.n_layers // self.window_period if self.window else self.n_layers
+
+    @property
+    def n_planes(self) -> int:
+        """Layer applications of one token: each holds K and V of its own."""
+        return self.n_passes * self.n_layers
 
     @property
     def kv_heads(self) -> int:
@@ -131,8 +153,8 @@ class TransformerConfig:
 
 # ------------------------------------------------------------------ init
 
-def _norm_params(cfg, key):
-    p = {"w": jnp.ones((cfg.d_model,), cfg.param_dtype)}
+def _norm_params(cfg, key, scale: float = 1.0):
+    p = {"w": jnp.full((cfg.d_model,), scale, cfg.param_dtype)}
     if cfg.norm == "ln":
         p["b"] = jnp.zeros((cfg.d_model,), cfg.param_dtype)
     return p
@@ -224,6 +246,9 @@ def _layer_params(cfg, key, dense: bool = False):
         "norm2": _norm_params(cfg, ks[4]),
         "mlp": mlp,
     }
+    if cfg.sandwich_norms:
+        for name in ("post_attn_norm", "post_mlp_norm"):
+            layer[name] = _norm_params(cfg, ks[4], cfg.sandwich_norm_init)
     return layer
 
 
@@ -243,6 +268,15 @@ def _check(cfg: TransformerConfig) -> None:
         raise ValueError("sigmoid routing is dropless: capacity_factor None")
     if cfg.yarn is not None and cfg.pos != "rope":
         raise ValueError("yarn rescales rotary positions: pos='rope'")
+    if cfg.n_passes < 1:
+        raise ValueError(f"n_passes {cfg.n_passes}: a stack is run at least once")
+    if (cfg.n_passes > 1 or cfg.sandwich_norms or cfg.exit_gate) and (
+            cfg.mla or cfg.window is not None or cfg.n_dense_layers):
+        raise ValueError(
+            "a looped stack (n_passes), sandwich norms and the exit gate are "
+            "built for per-head K and V in a stack of one kind of layer: not "
+            "with latent attention (kv_lora_rank), window layers or leading "
+            "dense layers")
     if cfg.window is not None:
         if cfg.mla or cfg.pos != "rope" or cfg.n_dense_layers:
             raise ValueError(
@@ -256,7 +290,8 @@ def _check(cfg: TransformerConfig) -> None:
                 f"n_layers {cfg.n_layers}")
 
 
-def scan_layers(block, carry, params, cfg: TransformerConfig, *per_layer):
+def scan_layers(block, carry, params, cfg: TransformerConfig, *per_layer,
+                close=None):
     """`block(carry, layer params)`, or with `per_layer` trees (all the
     layers on their leading dimension) `block(carry, (layer params, *their
     slices))`, over every layer in depth order: one lax.scan a stack of
@@ -268,7 +303,38 @@ def scan_layers(block, carry, params, cfg: TransformerConfig, *per_layer):
 
     With window layers (cfg.window) the scan is over whole periods and the
     body calls `block(..., window=<bool>)`, the kind static: once for the
-    period's window layers (a scan of their own), once for its full layer."""
+    period's window layers (a scan of their own), once for its full layer.
+
+    `close(carry, t) -> carry` ends pass `t` over the stack (the callers'
+    final norm, `close_pass`). A looped stack (cfg.n_passes = T > 1) is an
+    outer lax.scan over the passes whose body is the scan of the stack and
+    then `close`: the weights are closed over whole, the same every pass;
+    the `per_layer` trees and the outputs lead with the T * L planes, plane
+    t * L + l being layer l's application in pass t, and the outer scan
+    hands the inner one its [L, ...] slice."""
+    T, L = cfg.n_passes, cfg.n_layers
+    if T == 1:
+        carry, out = _scan_stack(block, carry, params, cfg, per_layer)
+        return (carry if close is None else close(carry, 0)), out
+    if close is None:
+        raise ValueError(
+            f"a looped stack (n_passes {T}) needs `close`: the final norm "
+            "ends every pass and its output is the next pass's input")
+
+    def one_pass(c, xs):
+        t, *planes = xs
+        with jax.named_scope("ray_tpu:loop_pass"):
+            c, out = _scan_stack(block, c, params, cfg, tuple(planes))
+            return close(c, t), out
+
+    carry, out = jax.lax.scan(one_pass, carry, (
+        jnp.arange(T, dtype=jnp.int32),
+        *(jax.tree.map(lambda a: a.reshape(T, L, *a.shape[1:]), t) for t in per_layer)))
+    return carry, jax.tree.map(lambda a: a.reshape(T * L, *a.shape[2:]), out)
+
+
+def _scan_stack(block, carry, params, cfg: TransformerConfig, per_layer):
+    """One pass over the stack: `scan_layers` without the passes."""
     if cfg.window is not None:
         return _scan_periods(block, carry, params, cfg, per_layer)
     outs, dense = [], cfg.n_dense_layers
@@ -352,6 +418,33 @@ def kind_index(cfg: TransformerConfig) -> list:
             for l in range(cfg.n_layers)]
 
 
+def close_pass(h, gates, t, params, cfg: TransformerConfig):
+    """The end of pass `t` over the stack: (the final norm of h [..., E],
+    which in a looped stack is the next pass's input; `gates` [T, ...] with
+    the exit gate's sigmoid(w . x_t + b) of that output at `t`, float32:
+    None, untouched, for a model without the gate)."""
+    h = _norm(h, params["final_norm"], cfg)
+    if gates is not None:
+        gate = params["exit_gate"]
+        lam = jax.nn.sigmoid(jnp.einsum("...e,e->...", h.astype(jnp.float32),
+                                        gate["w"].astype(jnp.float32))
+                             + gate["b"].astype(jnp.float32))
+        gates = jax.lax.dynamic_update_index_in_dim(gates, lam, t, 0)
+    return h, gates
+
+
+def exit_distribution(gates):
+    """The gates lambda [T, ...] of the T passes -> p [T, ...], the
+    probability of leaving the loop after pass t: lambda_t times the
+    probability of having stayed so far, and for the last pass all that is
+    left. Sums to one over the passes; its running sum is the exit CDF, and
+    a token leaves after the first pass at which that reaches the model's
+    threshold (1 as published: every token takes every pass)."""
+    stay = jnp.cumprod(1.0 - gates, axis=0)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+    return jnp.concatenate([(gates * before)[:-1], before[-1:]])
+
+
 def init(key, cfg: TransformerConfig):
     _check(cfg)
     k_emb, k_pos, k_layers, k_head = jax.random.split(key, 4)
@@ -368,6 +461,11 @@ def init(key, cfg: TransformerConfig):
         params["pos_embed"] = jax.random.normal(k_pos, (cfg.max_seq_len, cfg.d_model), cfg.param_dtype) * 0.02
     if not cfg.tie_embeddings:
         params["lm_head"] = jax.random.normal(k_head, (cfg.d_model, cfg.vocab_size), cfg.param_dtype) * 0.02
+    if cfg.exit_gate:
+        params["exit_gate"] = {
+            "w": jax.random.normal(jax.random.fold_in(k_head, 1), (cfg.d_model,),
+                                   cfg.param_dtype) * 0.02,
+            "b": jnp.zeros((), cfg.param_dtype)}
     return params
 
 
@@ -405,6 +503,8 @@ def logical_axes(cfg: TransformerConfig):
 
     def stacked(mlp):
         layer = {"norm1": norm, "attn": attn, "norm2": norm, "mlp": mlp}
+        if cfg.sandwich_norms:
+            layer.update(post_attn_norm=norm, post_mlp_norm=norm)
         return jax.tree.map(lambda t: ("layers",) + t, layer,
                             is_leaf=lambda x: isinstance(x, tuple))
 
@@ -419,6 +519,8 @@ def logical_axes(cfg: TransformerConfig):
         out["pos_embed"] = (None, "embed")
     if not cfg.tie_embeddings:
         out["lm_head"] = ("embed", "vocab")
+    if cfg.exit_gate:
+        out["exit_gate"] = {"w": ("embed",), "b": ()}
     return out
 
 
@@ -428,6 +530,14 @@ def _norm(x, p, cfg):
     if cfg.norm == "rms":
         return ops.rms_norm(x, p["w"], eps=cfg.norm_eps)
     return ops.layer_norm(x, p["w"], p.get("b"))
+
+
+def _residual(h, delta, layer_p, post: str, cfg):
+    """h + delta, a sublayer's output: through the block's norm `post` first
+    where the block has sandwich norms."""
+    if cfg.sandwich_norms:
+        delta = _norm(delta, layer_p[post], cfg)
+    return h + delta
 
 
 def _to_lanes(x, cfg):
@@ -570,10 +680,12 @@ def _moe_mlp(x, p, cfg):
 
 
 def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = None,
-            attn_impl: str | None = None, return_hidden: bool = False):
+            attn_impl: str | None = None, return_hidden: bool = False,
+            return_exit: bool = False):
     """tokens [B, T] int32 → logits [B, T, V] (cfg.dtype). Returns
     (logits, aux_loss); with return_hidden=True, returns the pre-head hidden
-    states [B, T, E] instead of logits."""
+    states [B, T, E] instead of logits; with return_exit=True (a model with
+    the exit gate) a third value, `exit_distribution` [n_passes, B, T]."""
     dt = cfg.dtype
     x = params["embed"].astype(dt)[tokens]
     if cfg.pos == "learned":
@@ -591,20 +703,22 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
     aux_total = jnp.zeros((), jnp.float32)
 
     def block(carry, layer_p, window=False):
-        h, aux = carry
-        h = h + _attn_block(_norm(h, layer_p["norm1"], cfg), layer_p["attn"], cfg,
-                            *rope[window], sp_axis, attn_impl,
-                            cfg.window if window else None)
+        h, aux, gates = carry
+        h = _residual(h, _attn_block(_norm(h, layer_p["norm1"], cfg), layer_p["attn"],
+                                     cfg, *rope[window], sp_axis, attn_impl,
+                                     cfg.window if window else None),
+                      layer_p, "post_attn_norm", cfg)
         normed = _norm(h, layer_p["norm2"], cfg)
         if "router" in layer_p["mlp"]:
             delta, layer_aux = _moe_mlp(normed, layer_p["mlp"], cfg)
             aux = aux + layer_aux
         else:
             delta = _dense_mlp(normed, layer_p["mlp"], cfg)
-        return (h + delta, aux), None
+        return (_residual(h, delta, layer_p, "post_mlp_norm", cfg), aux, gates), None
 
     if cfg.remat and cfg.remat_policy == "pairs" and (
-            cfg.n_layers % 2 or cfg.moe or cfg.n_dense_layers or cfg.window):
+            cfg.n_layers % 2 or cfg.moe or cfg.n_dense_layers or cfg.window
+            or cfg.n_passes > 1 or cfg.exit_gate):
         raise ValueError(
             "remat_policy='pairs' needs an even n_layers and a dense (non-"
             "MoE) stack of one kind of layer; falling back silently would "
@@ -627,7 +741,8 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
         stacked = jax.tree.map(
             lambda t: t.reshape(t.shape[0] // 2, 2, *t.shape[1:]),
             params["layers"])
-        (x, aux_total), _ = jax.lax.scan(pair, (x, aux_total), stacked)
+        (x, aux_total, gates), _ = jax.lax.scan(pair, (x, aux_total, None), stacked)
+        x, gates = close_pass(x, gates, 0, params, cfg)
     else:
         if cfg.remat:
             policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
@@ -638,15 +753,24 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
             def block(carry, layer_p, window=False):
                 return jax.checkpoint(functools.partial(inner, window=window),
                                       policy=policy)(carry, layer_p)
-        (x, aux_total), _ = scan_layers(block, (x, aux_total), params, cfg)
-    x = _norm(x, params["final_norm"], cfg)
+        gates = (jnp.zeros((cfg.n_passes,) + tokens.shape, jnp.float32)
+                 if cfg.exit_gate else None)
+
+        def close(carry, t):
+            h, aux, gates = carry
+            h, gates = close_pass(h, gates, t, params, cfg)
+            return h, aux, gates
+
+        (x, aux_total, gates), _ = scan_layers(block, (x, aux_total, gates), params, cfg,
+                                               close=close)
+    leave = (exit_distribution(gates),) if return_exit else ()
     if return_hidden:
-        return x, aux_total
+        return (x, aux_total) + leave
     if cfg.tie_embeddings:
         logits = x @ params["embed"].astype(dt).T
     else:
         logits = x @ params["lm_head"].astype(dt)
-    return logits, aux_total
+    return (logits, aux_total) + leave
 
 
 def loss_fn(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = None,
