@@ -64,6 +64,14 @@ class _Request:
     out_queue: queue.SimpleQueue = dataclasses.field(default_factory=queue.SimpleQueue)
     slot: int = -1
     generated: int = 0
+    # tokens sampled for this row on the device, read or not: 1 at the bind
+    # (the prefill's, or the one transferred), one more a decode step
+    # dispatched. Runs ahead of `generated` by what is still unread; the
+    # host's mirror of the row's device length is length0 + dispatched - 1
+    dispatched: int = 0
+    # the client's stream is closed (end, error or abort): tokens of this
+    # request still unread are dropped
+    finished: bool = False
     kv_pack: dict | None = None  # prefilled elsewhere (PD disaggregation)
     # streamed PD admission: pages adopted as they arrive off the transfer
     # plane (kv_transfer.KVPageStream protocol); length0 mirrors the
@@ -135,11 +143,32 @@ def _iter_request(req: "_Request"):
         yield tok
 
 
+@dataclasses.dataclass
+class _Unread:
+    """Tokens sampled on the device and not yet read on the host: a decode
+    step's (one a slot) or a prefill's first. `rows` pairs an index into
+    `toks` with the REQUEST that held it when the program was dispatched: by
+    the time the tokens are read the slot may be another request's. `wait` is
+    the loop phase its fetch is timed as; a decode step's is `decode_wait`."""
+    toks: Any
+    rows: list
+    wait: str
+    t_dispatch: float = 0.0
+    exit_cdf: Any = None
+
+    @property
+    def step(self) -> bool:
+        return self.wait == "decode_wait"
+
+
 # The scheduler thread's wall time, cut into phases that never overlap and
 # leave nothing out (PERF.md section 3 has the table). "host" work is every
 # phase but `parked` and the three `*_wait`, which block on a device→host
 # fetch; dispatch is asynchronous, so device time queued in one phase is
-# paid in the next wait, whichever program it belongs to.
+# paid in the next wait, whichever program it belongs to. The loop keeps one
+# decode step in flight, so `decode_wait` is the fetch of the step BEFORE
+# the one just dispatched, and `admit_wait` / `prefill_wait` the fetch of a
+# first token after the decode step that follows its prefill has gone out.
 LOOP_PHASES = ("parked", "sweep", "admit", "admit_wait", "streams",
                "prefill", "prefill_wait", "decode", "decode_wait", "emit")
 HOST_PHASES = tuple(p for p in LOOP_PHASES
@@ -351,6 +380,9 @@ class TPUEngine:
         self.state = dp.init_paged_state(
             cfg, max_slots, self.max_len, self.num_pages, page_size,
             ring=self.ring or None)
+        # a step's exit CDF is read a step later, when the next step has been
+        # given the state: it never stays in the state the programs donate
+        self.state.pop("exit_cdf", None)
         # that pool's size is dp's: a ring for every slot (+ scratch)
         self.window_pages = self.state["wkp"].shape[1] if cfg.window else 0
         if mesh is not None:
@@ -409,6 +441,17 @@ class TPUEngine:
             self._lora_lock = threading.Lock()
         self.decode_steps = 0
         self.decode_slot_steps = 0  # sum of active slots over decode steps
+        # one decode step in flight: tokens dispatched and not yet read,
+        # oldest first (a step's, and the first tokens of the prefills
+        # dispatched since), how many steps went out while the step before
+        # them was unread, row-steps whose token was dropped because the row
+        # had stopped or been aborted by the time it was read, and rows
+        # released by count whose last tokens are still unread
+        self._unread: collections.deque = collections.deque()
+        self.steps_ahead = 0
+        self.tokens_discarded = 0
+        self._closing = 0
+        self._t_fetched = 0.0  # when the last decode step's tokens arrived
         # a looped stack on the record (stats()["loops"]): passes over the
         # stack the decode steps ran (n_passes a step: every row takes every
         # pass), and live rows by the pass at which their exit CDF first
@@ -506,9 +549,10 @@ class TPUEngine:
         except Exception:  # pragma: no cover — metrics must never gate boot
             self._phase_queue = self._phase_prefill = None
             self._phase_admit = self._phase_gap = None
-        # per-decode-step wall time (the step's dispatches + the fetch of
-        # its tokens, from the phase clock's reads), labelled with the
-        # attention code that runs (stats()["decode_attn"])
+        # per-decode-step wall time (between the arrival of two steps'
+        # tokens, or from a step's dispatch where nothing was in flight: the
+        # phase clock's reads), labelled with the attention code that runs
+        # (stats()["decode_attn"])
         self._step_obs = None
         try:
             from ray_tpu.serve import request_context as _rc2
@@ -830,9 +874,10 @@ class TPUEngine:
     def abort_request(self, rid: int) -> None:
         """Cancel an in-flight request by rid: the scheduler reclaims its
         decode slot and every granted KV page at the top of its next pass
-        (one decode step, not at max_tokens), and the caller's iterator
-        raises RequestCancelledError. Thread-safe; a rid that already
-        finished (or never existed) is a no-op that ages out."""
+        (within two decode steps: one may be in flight; not at max_tokens),
+        and the caller's iterator raises RequestCancelledError. Thread-safe;
+        a rid that already finished (or never existed) is a no-op that ages
+        out."""
         self._abort_q.put(int(rid))
         self._work.set()
 
@@ -845,9 +890,13 @@ class TPUEngine:
     def _drain_all(self, error: BaseException | None):
         """Unblock every waiting caller: end-of-stream, or the failure."""
         marker = _EngineError(error) if error is not None else _SENTINEL
-        for req in list(self._by_slot.values()):
-            self._lora_release(req)
-            req.out_queue.put(marker)
+        # rows released by count wait for their last tokens among the unread
+        for req in list(self._by_slot.values()) + [
+                req for u in list(self._unread) for _, req in u.rows]:
+            if not req.finished:
+                req.finished = True
+                self._lora_release(req)
+                req.out_queue.put(marker)
         for req in self._backlog:
             self._lora_release(req)
             req.out_queue.put(marker)
@@ -1045,10 +1094,10 @@ class TPUEngine:
     def _count_live(self, req: _Request, sign: int) -> None:
         """A row joins (+1) or leaves (-1) the rows the decode step advances:
         its positions, and those of them beyond the window, in the running
-        sums the cache counters add every pass (`_emit` moves them a token
-        at a time in between); and, if it samples, what it asks of the
+        sums the cache counters add every pass (`_dispatch_step` moves them a
+        token at a time in between); and, if it samples, what it asks of the
         sampler, from which the step's form is chosen anew."""
-        held = req.length0 + req.generated
+        held = req.length0 + req.dispatched
         self._live_tokens += sign * held
         if self.cfg.window:
             self._live_beyond_window += sign * max(0, held - self.cfg.window)
@@ -1072,6 +1121,7 @@ class TPUEngine:
         ragged decode step can bound its page sweep without a readback."""
         if length is not None:
             req.length0 = int(length)
+        req.dispatched = 1  # the prefill's token, or the one transferred
         self._count_live(req, +1)
         self._set_row_sampling(slot, req.params)
         if self.lora_bank is not None:
@@ -1312,7 +1362,8 @@ class TPUEngine:
         P = self.page_size
         need = 1
         for req in self._by_slot.values():
-            pos = req.length0 + max(0, req.generated - 1)
+            # DISPATCHED positions: a step in flight has moved the row on
+            pos = req.length0 + req.dispatched - 1
             need = max(need, pos // P + 1)
         b = 1
         while b < need:
@@ -1364,15 +1415,11 @@ class TPUEngine:
                 admitted += 1
                 continue
             if self.enable_prefix_cache or self.prefill_chunk:
-                first_id = self._admit_cached(req, slot)
-                if first_id is None:
+                if not self._admit_cached(req, slot):
                     self._free.append(slot)
                     self._backlog.append(req)
                     return  # page pressure: stop admitting this round
                 admitted += 1
-                if first_id != -1:  # -1 = staged for chunked prefill
-                    self._first_token(req)
-                    self._emit(req, first_id)
                 continue
             n = len(req.tokens)
             bucket = self._bucket(n)
@@ -1395,9 +1442,6 @@ class TPUEngine:
                     self.params, jnp.asarray(padded), jnp.int32(n), self.cfg)
             self.key, sub = jax.random.split(self.key)
             first = self._sample_first(req, logits, sub)
-            self._clock.mark("admit_wait")
-            first_id = int(first[0])
-            self._clock.mark("admit")
             if not self._insert(req, slot, kv, n, first[0]):
                 self._free.append(slot)
                 self._backlog.append(req)
@@ -1406,13 +1450,13 @@ class TPUEngine:
             # granted only now (a failed insert backlogs the request, and
             # that wait is queue wait), as of before the prefill's dispatch
             self._scheduled(req, t_sched)
-            self._first_token(req)
-            self._emit(req, first_id)
+            self._first_unread(req, first, "admit_wait")
 
     def _admit_cached(self, req: _Request, slot: int):
-        """Paged admission with hash-block prefix reuse. Returns the first
-        sampled token id, or None when the page pool can't host the
-        sequence right now (caller backlogs)."""
+        """Paged admission with hash-block prefix reuse. False when the page
+        pool can't host the sequence right now (caller backlogs); else the
+        prompt is staged for chunked prefill, or prefilled with its first
+        token among the unread."""
         tokens = req.tokens
         n = len(tokens)
         P = self.page_size
@@ -1455,7 +1499,7 @@ class TPUEngine:
         if priv is None:
             for p in pre_pages:  # unpin; the request is backlogged
                 self._page_refs[p] = self._page_refs.get(p, 1) - 1
-            return None
+            return False
         self._slot_pages[slot] = list(priv)
         self._grant_ring(slot, total_pages)
         self._slot_shared[slot] = list(pre_pages)
@@ -1474,7 +1518,7 @@ class TPUEngine:
             req.pf_hashes = hashes
             self._staged_tokens += pre_len
             self._prefilling.append(req)
-            return -1  # staged: no first token yet
+            return True  # staged: no first token yet
         padded = np.zeros((1, suf_bucket), np.int32)
         padded[0, :len(suffix)] = suffix
         self._count_expert_tokens(suf_bucket)
@@ -1510,10 +1554,8 @@ class TPUEngine:
         self._bind_slot(req, slot, n)
         if self.enable_prefix_cache:
             self._register_blocks(slot, tokens, hashes, n_pre, priv)
-        self._clock.mark("admit_wait")
-        first_id = int(first[0])
-        self._clock.mark("admit")
-        return first_id
+        self._first_unread(req, first, "admit_wait")
+        return True
 
     def _prefill_step(self):
         """Run ONE chunk of the oldest staged prefill (called between
@@ -1577,11 +1619,25 @@ class TPUEngine:
             n_shared = len(self._slot_shared.get(req.slot, ()))
             self._register_blocks(req.slot, tokens, req.pf_hashes, n_shared,
                                   self._slot_pages[req.slot])
-        self._clock.mark("prefill_wait")
-        first_id = int(first[0])
-        self._clock.mark("prefill")
-        self._first_token(req)
-        self._emit(req, first_id)
+        self._first_unread(req, first, "prefill_wait")
+
+    def _first_unread(self, req: _Request, first, wait: str) -> None:
+        """A prefill's first token (`first` [1], on the device) joins the
+        unread tokens: it is read after the decode step that follows its
+        prefill has been dispatched, so neither that step nor the tokens of
+        the step in flight wait for the prefill. A row it spends
+        (`max_tokens` 1) is released before a decode step takes it."""
+        first.copy_to_host_async()
+        self._unread.append(_Unread(first, [(0, req)], wait))
+        self._retire_if_spent(req)
+
+    def _retire_if_spent(self, req: _Request) -> None:
+        """A row whose last token has been dispatched takes part in no
+        further step: ending by count needs no token, so its slot and pages
+        go back now and its stream closes when the tokens are read."""
+        if req.dispatched >= req.params.max_tokens:
+            self._release_active(req)
+            self._closing += 1
 
     def _emit(self, req: _Request, token_id: int):
         if self._phase_gap is not None:
@@ -1591,10 +1647,10 @@ class TPUEngine:
                 self._phase_gap.observe(now - last)
             req.last_emit_ts = now
         req.generated += 1
-        self._live_tokens += 1
-        if self.cfg.window and req.length0 + req.generated > self.cfg.window:
-            self._live_beyond_window += 1
-        fsm = self._guided_fsm.get(req.slot)
+        # still the slot's row: not released by count while this was unread
+        # (the slot, and its FSM entry, may be another request's by now)
+        bound = self._by_slot.get(req.slot) is req
+        fsm = self._guided_fsm.get(req.slot) if bound else None
         if fsm is not None:
             self._guided_state[req.slot] = fsm.step(
                 self._guided_state[req.slot], token_id)
@@ -1603,13 +1659,27 @@ class TPUEngine:
         if not eos:
             req.out_queue.put(token_id)
         if eos or req.generated >= req.params.max_tokens:
-            self._release_active(req)
-            req.out_queue.put(_SENTINEL)
+            if bound:
+                self._release_active(req)
+            else:
+                self._closing -= 1
+            self._close(req, _SENTINEL)
+
+    def _close(self, req: _Request, marker) -> None:
+        """End the client's stream; what is still unread of it is dropped."""
+        req.finished = True
+        req.out_queue.put(marker)
+        if req.trace_ctx is not None and req.admitted_ts:
+            self._emit_request_spans(req, time.time())
 
     def _release_active(self, req: _Request) -> None:
         """Return an ACTIVE row's slot, pages, LoRA ref and guided-FSM
-        state to their pools — the one release path shared by normal
-        completion (_emit) and mid-stream abort (_abort_one)."""
+        state to their pools — the one release path shared by completion
+        (by count at the dispatch of the last step, `_retire_if_spent`; by a
+        stop token when it is read, `_emit`) and mid-stream abort
+        (_abort_one). A step in flight may still hold the row: it runs
+        before this release on the device, writes inside the row's own
+        pages, and its token is dropped."""
         self.state = dp.release_slot_paged(self.state, req.slot)
         self._return_pages(req.slot)
         self._count_live(req, -1)
@@ -1622,8 +1692,6 @@ class TPUEngine:
         self._guided_state.pop(req.slot, None)
         self._free.append(req.slot)
         del self._by_slot[req.slot]
-        if req.trace_ctx is not None:
-            self._emit_request_spans(req, time.time())
 
     def _emit_request_spans(self, req: _Request, now: float) -> None:
         """A sampled request's engine phases, from its stamps, under the
@@ -1632,12 +1700,13 @@ class TPUEngine:
         try:
             tracing.emit_span_for(ctx, "engine:queue_wait", req.submitted_ts,
                                   req.scheduled_ts, rid=req.rid)
+            first = req.first_token_ts or now  # aborted before it was read
             tracing.emit_span_for(
-                ctx, "engine:prefill", req.scheduled_ts, req.first_token_ts,
+                ctx, "engine:prefill", req.scheduled_ts, first,
                 prompt_tokens=len(req.tokens), chunks=req.pf_chunks,
                 prefix_tokens_reused=req.prefix_reused)
-            tracing.emit_span_for(ctx, "engine:decode", req.first_token_ts,
-                                  now, tokens=req.generated)
+            tracing.emit_span_for(ctx, "engine:decode", first, now,
+                                  tokens=req.generated)
         except Exception as e:  # pragma: no cover — spans must never kill
             # the scheduler (every in-flight request would die)
             logger.debug("engine span emit failed: %r", e)
@@ -1679,7 +1748,7 @@ class TPUEngine:
             self._lora_release(req)
         else:
             return False
-        req.out_queue.put(_RequestError(err))
+        self._close(req, _RequestError(err))
         self._count_cancel()
         return True
 
@@ -1708,8 +1777,9 @@ class TPUEngine:
 
     def _expire_deadlines(self) -> None:
         """Abort every admitted request whose deadline passed — between
-        decode steps, so an expired row never costs another step. Requests
-        still in _waiting are checked at admission instead."""
+        decode steps, so an expired row costs no step beyond the one that
+        may be in flight. Requests still in _waiting are checked at
+        admission instead."""
         now = time.time()
         for reqs in (self._by_slot.values(), self._streaming,
                      self._prefilling, self._backlog):
@@ -1752,105 +1822,159 @@ class TPUEngine:
             raise
 
     def _loop_inner(self):
+        """One loop, one decode step in flight: step N+1's programs go out
+        before step N's tokens are read, so the device has its next step
+        queued while the host fetches, emits, sweeps and admits. Nothing the
+        device needs for a step comes from the host (the sampled token is
+        committed on the device, a row's pages are all granted at admission);
+        what the host learns a step late is a stop token, and that row-step's
+        token is dropped (`_deliver`). While a guided row lives its next mask
+        is built from the token before, and the same code reads the step it
+        has just dispatched: depth 0 for depth 1."""
         mark = self._clock.mark
         while not self._stop:
             # cancellation + deadline sweep first: an aborted/expired row's
             # slot and pages are back in the pool before this pass admits
-            # or steps anything (reclaim within one decode step)
+            # or steps anything (reclaim within two decode steps: the step
+            # in flight still holds the row, and its token is dropped)
             mark("sweep")
             self._apply_aborts()
             self._expire_deadlines()
-            if (not self._by_slot and self._waiting.empty()
-                    and not self._backlog and not self._prefilling
-                    and not self._streaming):
+            if (not self._by_slot and not self._unread
+                    and self._waiting.empty() and not self._backlog
+                    and not self._prefilling and not self._streaming):
                 mark("parked")
                 self._work.wait(timeout=0.1)
                 self._work.clear()
                 continue
             mark("admit")
             self._admit()
-            stream_progress = False
+            progress = False
             if self._streaming:
                 mark("streams")
-                stream_progress = self._drain_streams()
+                progress = self._drain_streams()
             if self._prefilling:
                 # one chunk per iteration: decode below keeps running
                 # requests emitting while a long prompt streams in
                 mark("prefill")
                 self._prefill_step()
+            if self._unread and (self._guided_fsm or not self._by_slot):
+                # a guided row's mask needs every token it has been given,
+                # and with no row to step there is nothing to run ahead of
+                self._deliver(0)
+                progress = True
             if not self._by_slot:
-                if self._streaming and not stream_progress:
+                if self._streaming and not progress:
                     # nothing decodable and no new pages yet: park until
                     # the transfer plane's feed() wakes us
                     mark("parked")
                     self._work.wait(timeout=0.005)
                     self._work.clear()
                 continue
-            t_step = mark("decode")
-            self.state, logits = dp.decode_step_paged_ragged(
-                self.params, self.state, self.cfg, self._pages_bound(),
-                self._ragged_kernel, self.lora_bank, self._slot_lora)
-            self.key, sub = jax.random.split(self.key)
-            if self._guided_fsm:
-                # per-slot FSM masks as an additive bias; the sampling math
-                # itself stays in the one jitted sample_per_row program.
-                # `remaining` triggers the budget-aware closing mask so an
-                # unbounded pattern completes before max_tokens.
-                from ray_tpu.llm import guided as _g
+            self._dispatch_step()
+            self._deliver(0 if self._guided_fsm else 1)
 
-                bias = np.zeros(logits.shape, np.float32)
-                for slot, fsm in self._guided_fsm.items():
-                    r = self._by_slot[slot]
-                    bias[slot] = _g.bias_row(
-                        fsm, self._guided_state[slot],
-                        remaining=r.params.max_tokens - r.generated)
-                logits = logits + jnp.asarray(bias)
-            # sampling params live on device, updated only at admission; the
-            # sampler's form follows what the live rows ask for
-            sampling, k_bucket, form = self._sampler_form
-            toks = decoding.sample_per_row(logits, sub, self._temps,
-                                           self._topks, sampling, k_bucket)
-            self.state = decoding.commit_tokens(self.state, toks)
-            mark("decode_wait")
-            if self.cfg.exit_gate:  # the step's exit CDF comes with its tokens
-                toks_host, exit_cdf = jax.device_get((toks, self.state["exit_cdf"]))
-            else:
-                toks_host, exit_cdf = np.asarray(toks), None
-            t_emit = mark("emit")
-            self.decode_steps += 1
-            self.stack_passes += self.cfg.n_passes
-            if exit_cdf is not None:
-                np.add.at(self.exit_rows,
-                          (exit_cdf[list(self._by_slot)] >= 0.5).argmax(axis=1), 1)
-            self.sampler_steps[form] += 1
-            self.decode_slot_steps += len(self._by_slot)
-            self._count_expert_tokens(self.max_slots)
-            # each live row attended over length0 + generated positions on
-            # a full layer, no more than the window of them on a window layer
-            self.context_tokens += self._live_tokens
-            self.window_context_tokens += (self._live_tokens
-                                           - self._live_beyond_window)
-            self.page_steps_used += (self.num_pages - 1
-                                     - self._available_pages())
-            self.page_steps_total += self.num_pages - 1
-            granted = self.num_pages - 1 - len(self._free_pages)
-            wgranted = max(self.window_pages - 1, 0) - len(self._free_wpages)
-            self.held_token_steps += self._live_tokens + self._staged_tokens
-            self.held_byte_steps += (granted * self._page_bytes
-                                     + wgranted * self._wpage_bytes)
-            self.window_page_steps_used += wgranted
-            self.window_page_steps_total += max(self.window_pages - 1, 0)
-            if self._step_obs is not None:
-                # the step's dispatches + the fetch of its tokens
-                self._step_obs.observe(t_emit - t_step)
-            for slot, req in list(self._by_slot.items()):
-                self._emit(req, int(toks_host[slot]))
+    def _dispatch_step(self) -> None:
+        """One decode step's four programs go out and its tokens join the
+        unread; every counter of the step is of what was DISPATCHED, a
+        row-step whose token will be dropped included."""
+        t_step = self._clock.mark("decode")
+        rows = list(self._by_slot.items())
+        state, logits = dp.decode_step_paged_ragged(
+            self.params, self.state, self.cfg, self._pages_bound(),
+            self._ragged_kernel, self.lora_bank, self._slot_lora)
+        # out of the state before the next program donates it (a gated stack)
+        exit_cdf = state.pop("exit_cdf", None)
+        self.key, sub = jax.random.split(self.key)
+        if self._guided_fsm:
+            # per-slot FSM masks as an additive bias; the sampling math
+            # itself stays in the one jitted sample_per_row program.
+            # `remaining` triggers the budget-aware closing mask so an
+            # unbounded pattern completes before max_tokens.
+            from ray_tpu.llm import guided as _g
+
+            bias = np.zeros(logits.shape, np.float32)
+            for slot, fsm in self._guided_fsm.items():
+                r = self._by_slot[slot]
+                bias[slot] = _g.bias_row(
+                    fsm, self._guided_state[slot],
+                    remaining=r.params.max_tokens - r.generated)
+            logits = logits + jnp.asarray(bias)
+        # sampling params live on device, updated only at admission; the
+        # sampler's form follows what the live rows ask for
+        sampling, k_bucket, form = self._sampler_form
+        toks = decoding.sample_per_row(logits, sub, self._temps,
+                                       self._topks, sampling, k_bucket)
+        self.state = decoding.commit_tokens(state, toks)
+        toks.copy_to_host_async()
+        if exit_cdf is not None:
+            exit_cdf.copy_to_host_async()
+        if any(u.step for u in self._unread):
+            self.steps_ahead += 1
+        self._unread.append(_Unread(toks, rows, "decode_wait", t_step, exit_cdf))
+        self.decode_steps += 1
+        self.stack_passes += self.cfg.n_passes
+        self.sampler_steps[form] += 1
+        self.decode_slot_steps += len(rows)
+        self._count_expert_tokens(self.max_slots)
+        # each live row attended over length0 + dispatched positions on
+        # a full layer, no more than the window of them on a window layer
+        self.context_tokens += self._live_tokens
+        self.window_context_tokens += (self._live_tokens
+                                       - self._live_beyond_window)
+        self.page_steps_used += (self.num_pages - 1
+                                 - self._available_pages())
+        self.page_steps_total += self.num_pages - 1
+        granted = self.num_pages - 1 - len(self._free_pages)
+        wgranted = max(self.window_pages - 1, 0) - len(self._free_wpages)
+        self.held_token_steps += self._live_tokens + self._staged_tokens
+        self.held_byte_steps += (granted * self._page_bytes
+                                 + wgranted * self._wpage_bytes)
+        self.window_page_steps_used += wgranted
+        self.window_page_steps_total += max(self.window_pages - 1, 0)
+        for _, req in rows:
+            req.dispatched += 1
+            self._live_tokens += 1
+            if self.cfg.window and req.length0 + req.dispatched > self.cfg.window:
+                self._live_beyond_window += 1
+            self._retire_if_spent(req)
+
+    def _deliver(self, depth: int) -> None:
+        """Read the unread tokens, oldest first, until `depth` are left (the
+        step just dispatched, or none), and give each to the request that
+        held its row at dispatch. A request that has ended since (a stop
+        token in the step before, an abort) has its token dropped: never
+        queued, never counted in `generated`."""
+        mark = self._clock.mark
+        while len(self._unread) > depth:
+            u = self._unread.popleft()
+            mark(u.wait)
+            toks, exit_cdf = jax.device_get((u.toks, u.exit_cdf))
+            now = mark("emit")
+            if u.step:
+                if self._step_obs is not None:
+                    self._step_obs.observe(now - max(u.t_dispatch, self._t_fetched))
+                self._t_fetched = now
+                if exit_cdf is not None:
+                    np.add.at(self.exit_rows,
+                              (exit_cdf[[slot for slot, _ in u.rows]] >= 0.5
+                               ).argmax(axis=1), 1)
+            for i, req in u.rows:
+                if req.finished:
+                    self.tokens_discarded += u.step
+                    continue
+                if not u.step:
+                    self._first_token(req)
+                self._emit(req, int(toks[i]))
 
     # ---------------------------------------------------------------- stats
 
     def stats(self) -> dict:
         memory = jax.local_devices()[0].memory_stats() or {}  # None on CPU
-        out = {"free_slots": len(self._free), "active": len(self._by_slot),
+        out = {"free_slots": len(self._free),
+               # rows in a slot, and rows released by count whose last
+               # tokens are not read yet
+               "active": len(self._by_slot) + self._closing,
                "waiting": self._waiting.qsize() + len(self._backlog),
                "streaming": len(self._streaming),
                "max_slots": self.max_slots, "buckets": list(self.buckets),
@@ -1864,11 +1988,16 @@ class TPUEngine:
                "worker_chips": accelerators.current_worker_chips(),
                "compile_cache": accelerators.compile_cache_counts(),
                "decode_steps": self.decode_steps,
+               # steps_ahead: decode steps dispatched while the step before
+               # them was unread; tokens_discarded: row-steps whose token was
+               # dropped because the row had stopped or been aborted
                "loop": {**self._clock.snapshot(), "requests": {
                    "requests_scheduled": self.requests_scheduled,
                    "queue_wait_s": self.queue_wait_s,
                    "first_tokens": self.first_tokens,
-                   "prefill_s": self.prefill_s}},
+                   "prefill_s": self.prefill_s},
+                   "steps_ahead": self.steps_ahead,
+                   "tokens_discarded": self.tokens_discarded},
                "aborts": self.aborts,
                "decode_occupancy": (self.decode_slot_steps
                                     / self.decode_steps

@@ -13,6 +13,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from ray_tpu._private import accelerators
@@ -54,12 +55,14 @@ def _burst(eng, n_requests: int, prompt_len: int, max_tokens: int) -> list:
 
 
 def _quiet_stats(eng) -> dict:
-    """A reading with the loop parked: nothing active, nothing queued."""
+    """A reading with the loop parked: nothing active, nothing queued, no
+    step in flight still to be read (a dropped token's is read last)."""
     deadline = time.time() + 30.0
     while time.time() < deadline:
         st = eng.stats()
-        if not (st["active"] or st["waiting"] or st.get("prefilling")):
-            return st
+        if not (st["active"] or st["waiting"] or st.get("prefilling")
+                ) and eng._clock.phase == "parked":
+            return eng.stats()
         time.sleep(0.01)
     raise AssertionError("the engine did not go quiet")
 
@@ -224,7 +227,7 @@ def test_stats_stay_json_plain(tiny_model):
     back = json.loads(json.dumps(st))
     assert back["loop"] == st["loop"]
     assert set(back["loop"]) == {"seconds", "host_s", "active_s", "thread_s",
-                                 "requests"}
+                                 "requests", "steps_ahead", "tokens_discarded"}
     assert len(json.dumps(back["loop"])) < 1000
     assert set(back["loop"]["requests"]) == {
         "requests_scheduled", "queue_wait_s", "first_tokens", "prefill_s"}
@@ -451,6 +454,196 @@ def test_a_seeded_sampled_request_draws_the_sorted_samplers_tokens(
     finally:
         eng.shutdown()
     assert out == pinned
+
+
+# ------------------------------------------------ one decode step in flight
+
+
+def _plain(tiny_model, **kw):
+    """No prefix cache, no chunks: a prompt is prefilled whole by `_admit`."""
+    cfg, params = tiny_model
+    return TPUEngine(cfg, params, **{**dict(max_slots=4, max_len=128, min_bucket=16,
+                                            page_size=16), **kw})
+
+
+def _alone(eng, prompts: list, params: list) -> list:
+    """Each request served with nothing else in the engine."""
+    return [eng.generate(p, sp) for p, sp in zip(prompts, params)]
+
+
+def _first_new_token(tokens: list, start: int = 3) -> int:
+    """The first position from `start` whose token the stream has not shown
+    before: a stop token that hits there and nowhere earlier."""
+    return next(i for i in range(start, len(tokens)) if tokens[i] not in tokens[:i])
+
+
+def test_a_batch_with_a_stop_token_delivers_what_each_request_gets_alone(tiny_model):
+    """Mixed `max_tokens` and a stop token that hits mid-stream, all rows in
+    one batch with a step in flight: token for token what `generate` gives
+    one request at a time, and nothing after the stop token."""
+    prompts = [_prompt(i, 12 + i) for i in range(4)]
+    eng = _plain(tiny_model)
+    try:
+        full = _alone(eng, prompts, [SamplingParams(max_tokens=24)] * 4)
+        cut = _first_new_token(full[1])
+        params = [SamplingParams(max_tokens=24), SamplingParams(
+            max_tokens=24, stop_token_ids=(full[1][cut],)),
+            SamplingParams(max_tokens=5), SamplingParams(max_tokens=1)]
+        alone = _alone(eng, prompts, params)
+        s0 = _quiet_stats(eng)
+        reqs = [eng.submit(p, sp) for p, sp in zip(prompts, params)]
+        batch = [list(r) for r in reqs]
+        s1 = _quiet_stats(eng)
+    finally:
+        eng.shutdown()
+    assert batch == alone
+    assert batch == [full[0], full[1][:cut], full[2][:5], full[3][:1]]
+    assert [r.generated for r in reqs] == [24, cut + 1, 5, 1]  # the stop token counts
+    assert s1["loop"]["tokens_discarded"] - s0["loop"]["tokens_discarded"] == 1
+    assert s1["free_slots"] == 4 and s1["free_pages"] == s1["num_pages"] - 1
+
+
+def test_a_slot_freed_by_a_stop_token_serves_the_next_row_its_own_tokens(tiny_model):
+    """One slot: the row that stops on a stop token has taken part in one more
+    step by the time the host knows, writing into pages the next request is
+    granted in the next pass. That request reads none of it."""
+    prompts = [_prompt(0, 12), _prompt(5, 30)]
+    eng = _plain(tiny_model, max_slots=1)
+    try:
+        full = _alone(eng, prompts, [SamplingParams(max_tokens=24)] * 2)
+        cut = _first_new_token(full[0])
+        s0 = _quiet_stats(eng)
+        first = eng.submit(prompts[0], SamplingParams(
+            max_tokens=24, stop_token_ids=(full[0][cut],)))
+        second = eng.submit(prompts[1], SamplingParams(max_tokens=24))
+        outs = [list(first), list(second)]
+        s1 = _quiet_stats(eng)
+    finally:
+        eng.shutdown()
+    assert outs == [full[0][:cut], full[1]]
+    assert first.slot == second.slot == 0
+    assert first.dispatched == first.generated + 1  # the step it did not need
+    assert s1["loop"]["tokens_discarded"] - s0["loop"]["tokens_discarded"] == 1
+    assert s1["free_pages"] == s1["num_pages"] - 1
+
+
+def test_steps_ahead_counts_every_step_but_the_first_and_none_beside_a_guided_row(
+        tiny_model):
+    """Unguided: every decode step but the first after an empty engine goes
+    out while the step before it is unread, admissions in between included;
+    rows that end by count drop no token, a row that stops on a stop token
+    drops one. While a guided row lives every step is read before the next."""
+    from ray_tpu.llm.guided import GuidedFSM
+
+    eng = _plain(tiny_model)
+    try:
+        full = eng.generate(_prompt(1, 13), SamplingParams(max_tokens=24))
+        cut = _first_new_token(full)
+        s0 = _quiet_stats(eng)
+        reqs = [eng.submit(_prompt(i, 12 + i), sp) for i, sp in enumerate((
+            SamplingParams(max_tokens=40),
+            SamplingParams(max_tokens=24, stop_token_ids=(full[cut],)),
+            SamplingParams(max_tokens=6)))]
+        long, stopped, counted = (list(r) for r in reqs)
+        s1 = _quiet_stats(eng)
+        allow_all = GuidedFSM(masks=np.ones((1, 64), bool),
+                              trans=np.zeros((1, 64), np.int32))
+        guided = eng.generate(_prompt(1, 13), SamplingParams(max_tokens=24,
+                                                             guided=allow_all))
+        s2 = _quiet_stats(eng)
+    finally:
+        eng.shutdown()
+    assert len(long) == 40 and stopped == full[:cut] and len(counted) == 6
+    assert guided == full
+    steps = s1["decode_steps"] - s0["decode_steps"]
+    assert steps == 39  # the long row's; the others ride along
+    assert s1["loop"]["steps_ahead"] - s0["loop"]["steps_ahead"] == steps - 1
+    assert s1["loop"]["tokens_discarded"] - s0["loop"]["tokens_discarded"] == 1
+    assert s2["decode_steps"] - s1["decode_steps"] == 23
+    assert s2["loop"]["steps_ahead"] == s1["loop"]["steps_ahead"]
+    assert s2["loop"]["tokens_discarded"] == s1["loop"]["tokens_discarded"]
+
+
+def test_phases_cover_the_thread_with_a_step_in_flight(tiny_model):
+    """The phases still partition the thread's time when a step's tokens are
+    fetched a pass after its dispatch and first tokens after the decode step
+    that follows their prefill: stop tokens, counts and admissions mid-stream."""
+    eng = _plain(tiny_model)
+    try:
+        full = _alone(eng, [_prompt(i, 12 + i) for i in range(6)],
+                      [SamplingParams(max_tokens=16)] * 6)
+        s0 = _quiet_stats(eng)
+        cuts = [_first_new_token(f) if i % 2 else 16 for i, f in enumerate(full)]
+        reqs = [eng.submit(_prompt(i, 12 + i), SamplingParams(
+            max_tokens=16, stop_token_ids=tuple(full[i][cuts[i]:cuts[i] + 1])))
+            for i in range(6)]
+        outs = [list(r) for r in reqs]
+        s1 = _quiet_stats(eng)
+    finally:
+        eng.shutdown()
+    assert outs == [f[:cut] for f, cut in zip(full, cuts)]
+    l0, l1 = s0["loop"], s1["loop"]
+    delta = {p: l1["seconds"][p] - l0["seconds"][p] for p in LOOP_PHASES}
+    assert sum(delta.values()) == pytest.approx(l1["thread_s"] - l0["thread_s"], rel=0.01)
+    assert all(d >= 0 for d in delta.values())
+    for busy in ("sweep", "admit", "admit_wait", "decode", "decode_wait", "emit"):
+        assert delta[busy] > 0, busy
+    assert l1["host_s"] - l0["host_s"] == pytest.approx(
+        sum(delta[p] for p in HOST_PHASES), rel=0.01)
+    assert l1["tokens_discarded"] - l0["tokens_discarded"] == 3
+    assert l1["steps_ahead"] > l0["steps_ahead"]
+
+
+def test_a_gated_looped_stack_runs_twenty_steps_in_flight():
+    """The exit CDF of a step is read after the next step has been given the
+    state: it is taken out of the state before that donation, and
+    `exit_rows` counts a row a step as before."""
+    from ray_tpu.models import ouro_config
+
+    cfg = ouro_config("tiny", vocab_size=300, max_seq_len=1024, dtype=jnp.float32)
+    assert cfg.exit_gate and cfg.n_passes > 1
+    params = transformer.init(jax.random.PRNGKey(3), cfg)
+    eng = TPUEngine(cfg, params, max_slots=3, max_len=512, min_bucket=16,
+                    page_size=16, num_pages=60)
+    try:
+        reqs = [eng.submit(_prompt(i, 20 + i), SamplingParams(max_tokens=21 - 4 * i))
+                for i in range(2)]
+        outs = [list(r) for r in reqs]
+        st = _quiet_stats(eng)
+        assert "exit_cdf" not in eng.state
+    finally:
+        eng.shutdown()
+    assert [len(o) for o in outs] == [21, 17]
+    assert st["decode_steps"] == 20 and st["loop"]["steps_ahead"] == 19
+    assert st["loops"]["stack_passes"] == 20 * cfg.n_passes
+    assert sum(st["loops"]["exit_rows"]) == 20 + 16  # a row a step
+    assert st["loop"]["tokens_discarded"] == 0
+
+
+def test_an_abort_returns_slot_and_pages_within_two_steps(tiny_model):
+    """An abort mid-stream: the step in flight at the sweep still holds the
+    row, none is dispatched for it after, its token is not delivered, and
+    slot and pages are back."""
+    from ray_tpu.exceptions import RequestCancelledError
+
+    eng = _plain(tiny_model)
+    try:
+        req = eng.submit(_prompt(0, 12), SamplingParams(max_tokens=100))
+        stream = iter(req)
+        got = [next(stream) for _ in range(4)]
+        eng.abort_request(req.rid)
+        with pytest.raises(RequestCancelledError):
+            for tok in stream:
+                got.append(tok)
+        st = _quiet_stats(eng)
+    finally:
+        eng.shutdown()
+    assert req.finished and 4 <= len(got) == req.generated < 100
+    assert req.out_queue.empty()  # nothing after the error
+    # one step beyond what was delivered, the one in flight at the sweep
+    assert req.dispatched == req.generated + 1 == st["decode_steps"] + 1
+    assert st["loop"]["tokens_discarded"] == 1 and st["aborts"] == 1
+    assert st["free_slots"] == 4 and st["free_pages"] == st["num_pages"] - 1
 
 
 # ------------------------------------------- the benchmark's reader of these
