@@ -149,12 +149,16 @@ class _Unread:
     step's (one a slot) or a prefill's first. `rows` pairs an index into
     `toks` with the REQUEST that held it when the program was dispatched: by
     the time the tokens are read the slot may be another request's. `wait` is
-    the loop phase its fetch is timed as; a decode step's is `decode_wait`."""
+    the loop phase its fetch is timed as; a decode step's is `decode_wait`.
+    A step also says what its pass put on the device before it: `kind`
+    (`PASS_KINDS`) and the padded prompt tokens of those programs."""
     toks: Any
     rows: list
     wait: str
     t_dispatch: float = 0.0
     exit_cdf: Any = None
+    kind: str = "step"
+    prompt_tokens: int = 0
 
     @property
     def step(self) -> bool:
@@ -162,25 +166,75 @@ class _Unread:
 
 
 # The scheduler thread's wall time, cut into phases that never overlap and
-# leave nothing out (PERF.md section 3 has the table). "host" work is every
+# leave nothing out (PERF.md section 3 has the table). "host" is every
 # phase but `parked` and the three `*_wait`, which block on a device→host
 # fetch; dispatch is asynchronous, so device time queued in one phase is
 # paid in the next wait, whichever program it belongs to. The loop keeps one
 # decode step in flight, so `decode_wait` is the fetch of the step BEFORE
 # the one just dispatched, and `admit_wait` / `prefill_wait` the fetch of a
 # first token after the decode step that follows its prefill has gone out.
+# A host phase is WORK only while its dispatches return at once: with the
+# device's queue never empty a dispatch can stand in the runtime, so the
+# clock also times every dispatch by program (`dispatch_s`, inside its
+# phase) and reads the thread's CPU time where it enters and leaves the host
+# phases (`host_cpu_s`): host_s = work_s + dispatch_s, and host_s -
+# host_cpu_s is the time the thread stood in a host phase off the CPU
+# (blocked, or without the GIL).
 LOOP_PHASES = ("parked", "sweep", "admit", "admit_wait", "streams",
                "prefill", "prefill_wait", "decode", "decode_wait", "emit")
 HOST_PHASES = tuple(p for p in LOOP_PHASES
                     if p != "parked" and not p.endswith("_wait"))
+_HOST = frozenset(HOST_PHASES)
+# A decode step by what its pass dispatched before it: nothing, or a chunk,
+# an unstaged prompt's prefill or transferred pages.
+PASS_KINDS = ("step", "step_prefill")
+
+
+class _Dispatch:
+    """One program's entry of `_PhaseClock.dispatch`: a context manager kept
+    for the clock's life and entered around each call that hands the device
+    that program, on the scheduler thread alone (never nested)."""
+
+    __slots__ = ("row", "_annotation", "_span", "_t0")
+
+    def __init__(self, program: str):
+        self.row = {"calls": 0, "seconds": 0.0}
+        self._annotation = tracing.device_annotation("engine:dispatch:" + program)
+
+    def __enter__(self):
+        self._span = self._annotation()
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        took = time.perf_counter() - self._t0
+        self._span.__exit__(None, None, None)
+        self.row["calls"] += 1
+        self.row["seconds"] += took
 
 
 class _PhaseClock:
-    """One `perf_counter` read per boundary: `mark(phase)` closes the open
-    phase and opens the next, on the scheduler thread alone (plain floats,
-    no lock). The same boundaries are `ray_tpu:engine:<phase>` spans on the
-    JAX profiler's timeline when a trace is being taken. `snapshot()` may
-    be called from any thread."""
+    """The scheduler thread's one clock (plain floats and ints, no lock;
+    `snapshot()` may be called from any thread).
+
+    `mark(phase)` closes the open phase and opens the next: one
+    `perf_counter` read a boundary, the wall seconds to `seconds`. The same
+    boundaries are `ray_tpu:engine:<phase>` spans on the JAX profiler's
+    timeline when a trace is being taken. Where the thread enters or leaves
+    the host phases (a fetch, or parking: twice a decode pass) the boundary
+    also reads `thread_time`, a system call, and books the thread's CPU
+    seconds of the host stretch it closes to `host_cpu_s`.
+
+    `dispatch(program)` times one call that hands the device something,
+    INSIDE the open phase (whose seconds still include it): two
+    `perf_counter` reads, a `ray_tpu:engine:dispatch:<program>` span nested
+    in the phase's. A call that blocks lies over the device ops it waited
+    for.
+
+    `book_pass()` keeps the interval between the arrival of two decode
+    steps' tokens by the step's kind (`PASS_KINDS`): with a step always
+    queued behind its predecessor that interval is the device's time for
+    what the pass dispatched."""
 
     def __init__(self):
         self.seconds = dict.fromkeys(LOOP_PHASES, 0.0)
@@ -189,6 +243,13 @@ class _PhaseClock:
         self._span = None
         self.started = time.perf_counter()
         self._open = ("parked", self.started)  # the open phase, since when
+        # the thread's CPU clock where it entered the host phases it is in;
+        # None in a wait or parked
+        self._cpu_since: float | None = None
+        self.host_cpu_s = 0.0
+        self._dispatches: dict[str, _Dispatch] = {}
+        self.passes = {k: {"count": 0, "seconds": 0.0, "rows": 0,
+                           "prompt_tokens": 0} for k in PASS_KINDS}
 
     @property
     def phase(self) -> str:
@@ -199,18 +260,48 @@ class _PhaseClock:
         was, since = self._open
         self.seconds[was] += now - since
         self._open = (phase, now)
+        if (phase in _HOST) != (self._cpu_since is not None):
+            cpu = time.thread_time()
+            if self._cpu_since is None:
+                self._cpu_since = cpu
+            else:
+                self.host_cpu_s += cpu - self._cpu_since
+                self._cpu_since = None
         if self._span is not None:
             self._span.__exit__(None, None, None)
         self._span = self._spans[phase]()
         self._span.__enter__()
         return now
 
+    def dispatch(self, program: str) -> _Dispatch:
+        entry = self._dispatches.get(program)
+        if entry is None:
+            entry = self._dispatches[program] = _Dispatch(program)
+        return entry
+
+    def book_pass(self, kind: str, seconds: float, rows: int,
+                  prompt_tokens: int) -> None:
+        p = self.passes[kind]
+        p["count"] += 1
+        p["seconds"] += seconds
+        p["rows"] += rows
+        p["prompt_tokens"] += prompt_tokens
+
     def snapshot(self) -> dict:
         """Seconds per phase so far, the open phase's running time included,
         with their `host_s` (HOST_PHASES) and `active_s` (all but `parked`)
         sums and the thread's wall time. A boundary that falls inside the
         copy leaves that one phase interval out; readers take deltas over
-        seconds."""
+        seconds. `host_cpu_s` is of closed host stretches only: `thread_time`
+        is the calling thread's own, so the CPU time of the stretch the
+        thread is in (at most one pass's host phases) is left out.
+        `dispatch_s` is inside `host_s`; `work_s` is the rest of it."""
+        # the sums that lie INSIDE the phases are read before the phases,
+        # so a reading taken mid-pass never shows more of them than of those
+        dispatch = {program: dict(d.row) for program, d
+                    in list(self._dispatches.items())}
+        dispatch_s = sum(row["seconds"] for row in dispatch.values())
+        host_cpu_s = self.host_cpu_s
         seconds = dict(self.seconds)
         phase, since = self._open
         now = time.perf_counter()
@@ -219,7 +310,11 @@ class _PhaseClock:
         return {"seconds": seconds, "host_s": host,
                 "active_s": host + sum(seconds[p] for p in LOOP_PHASES
                                        if p.endswith("_wait")),
-                "thread_s": now - self.started}
+                "thread_s": now - self.started,
+                "host_cpu_s": host_cpu_s,
+                "dispatch_s": dispatch_s, "work_s": host - dispatch_s,
+                "dispatch": dispatch,
+                "passes": {k: dict(p) for k, p in self.passes.items()}}
 
 
 def bucket_for(n: int, min_bucket: int, max_len: int) -> int:
@@ -452,6 +547,10 @@ class TPUEngine:
         self.tokens_discarded = 0
         self._closing = 0
         self._t_fetched = 0.0  # when the last decode step's tokens arrived
+        # padded prompt tokens of the prefill programs (chunks, unstaged
+        # prompts; 0 for transferred pages) dispatched since the last decode
+        # step went out; None when there were none: the next step's kind
+        self._pass_prefill: int | None = None
         # a looped stack on the record (stats()["loops"]): passes over the
         # stack the decode steps ran (n_passes a step: every row takes every
         # pass), and live rows by the pass at which their exit CDF first
@@ -551,8 +650,8 @@ class TPUEngine:
             self._phase_admit = self._phase_gap = None
         # per-decode-step wall time (between the arrival of two steps'
         # tokens, or from a step's dispatch where nothing was in flight: the
-        # phase clock's reads), labelled with the attention code that runs
-        # (stats()["decode_attn"])
+        # phase clock's reads), labelled with what the step's pass put on
+        # the device before it (PASS_KINDS): the number `passes` books
         self._step_obs = None
         try:
             from ray_tpu.serve import request_context as _rc2
@@ -561,12 +660,12 @@ class TPUEngine:
             if _rc2.metrics_enabled():
                 h = met.get_or_create(
                     met.Histogram, "ray_tpu_llm_decode_step_seconds",
-                    "paged decode step wall time (device step + sampling "
-                    "sync) by attention code (ragged_kernel|ragged_reference)",
+                    "time between the arrival of two decode steps' tokens "
+                    "by what the pass dispatched (step|step_prefill)",
                     boundaries=[0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
                                 0.05, 0.1, 0.25, 0.5, 1.0],
-                    tag_keys=("impl",))
-                self._step_obs = h.bind({"impl": self._decode_attn})
+                    tag_keys=("pass",))
+                self._step_obs = {k: h.bind({"pass": k}) for k in PASS_KINDS}
         except Exception:  # pragma: no cover — metrics must never gate boot
             self._step_obs = None
         self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -1037,8 +1136,9 @@ class TPUEngine:
                 self._page_refs[p] = left
 
     def _set_row_sampling(self, slot: int, params: SamplingParams):
-        self._temps = self._temps.at[slot].set(params.temperature)
-        self._topks = self._topks.at[slot].set(params.top_k)
+        with self._clock.dispatch("bind"):
+            self._temps = self._temps.at[slot].set(params.temperature)
+            self._topks = self._topks.at[slot].set(params.top_k)
         if params.guided is not None:
             self._guided_fsm[slot] = params.guided
             # the first token was already sampled under the START state's
@@ -1047,15 +1147,20 @@ class TPUEngine:
 
     def _sample_first(self, req: _Request, logits, sub):
         """First-token sampling after a prefill, honoring the request's
-        guided FSM start state (decode steps apply per-slot biases)."""
+        guided FSM start state (decode steps apply per-slot biases): the
+        token as the unread tokens keep it ([1]) and as an insert takes it."""
+        dispatch = self._clock.dispatch
         if req.params.guided is not None:
             from ray_tpu.llm import guided as _g
 
-            logits = logits + jnp.asarray(
-                _g.bias_row(req.params.guided, req.params.guided.start,
-                            remaining=req.params.max_tokens))
-        return decoding.sample(logits[None, :], sub,
-                               req.params.temperature, req.params.top_k)
+            bias = _g.bias_row(req.params.guided, req.params.guided.start,
+                               remaining=req.params.max_tokens)
+            with dispatch("bias"):
+                logits = logits + jnp.asarray(bias)
+        with dispatch("sample_first"):
+            first = decoding.sample(logits[None, :], sub,
+                                    req.params.temperature, req.params.top_k)
+            return first, first[0]
 
     def _grant_pages(self, need: int) -> list | None:
         """Grant `need` pool pages (evicting zero-ref cached blocks when
@@ -1089,7 +1194,8 @@ class TPUEngine:
         row = np.zeros((self.ring,), np.int32)
         held = self._slot_wpages[slot]
         row[:len(held)] = held
-        return jnp.asarray(row)
+        with self._clock.dispatch("h2d"):
+            return jnp.asarray(row)
 
     def _count_live(self, req: _Request, sign: int) -> None:
         """A row joins (+1) or leaves (-1) the rows the decode step advances:
@@ -1125,7 +1231,8 @@ class TPUEngine:
         self._count_live(req, +1)
         self._set_row_sampling(slot, req.params)
         if self.lora_bank is not None:
-            self._slot_lora = self._slot_lora.at[slot].set(req.lora_idx)
+            with self._clock.dispatch("bind"):
+                self._slot_lora = self._slot_lora.at[slot].set(req.lora_idx)
         self._by_slot[slot] = req
         req.admitted_ts = time.time()
         if self._phase_admit is not None and req.submitted_ts:
@@ -1175,11 +1282,12 @@ class TPUEngine:
             return False
         self._slot_pages[slot] = pages
         self._grant_ring(slot, need)
-        self.state = dp.insert_sequence_paged(
-            self.state, slot, kv, jnp.int32(length),
-            jnp.asarray(first_token, jnp.int32),
-            jnp.asarray(self._granted_block_row(slot)), self.cfg,
-            self._ring_row(slot))
+        ring = self._ring_row(slot)
+        with self._clock.dispatch("insert"):
+            self.state = dp.insert_sequence_paged(
+                self.state, slot, kv, jnp.int32(length),
+                jnp.asarray(first_token, jnp.int32),
+                jnp.asarray(self._granted_block_row(slot)), self.cfg, ring)
         self._bind_slot(req, slot, length)
         return True
 
@@ -1192,9 +1300,12 @@ class TPUEngine:
         if "k_pages" in pack:
             return self._insert_pages(req, slot, pack)
         dt = self.state["kp"].dtype
-        kv = {"k": jnp.asarray(pack["k"], dt), "v": jnp.asarray(pack["v"], dt)}
-        return self._insert(req, slot, kv, pack["length"],
-                            pack["first_token"])
+        with self._clock.dispatch("h2d"):
+            kv = {"k": jnp.asarray(pack["k"], dt), "v": jnp.asarray(pack["v"], dt)}
+        if not self._insert(req, slot, kv, pack["length"], pack["first_token"]):
+            return False
+        self._note_prefill(0)
+        return True
 
     def _insert_pages(self, req: _Request, slot: int, pack: dict) -> bool:
         """Adopt transferred KV pages directly into the paged pool: one
@@ -1213,15 +1324,19 @@ class TPUEngine:
         dt = self.state["kp"].dtype
         # prefix pages land in block-table order; the tail of `pages`
         # (granted up front, like every admission) hosts the generation
+        dispatch = self._clock.dispatch
         for pid, kp, vp in zip(pages, k_pages, v_pages):
-            self.state = dp.write_kv_pages(
-                self.state,
-                {"k": jnp.asarray(np.asarray(kp), dt),
-                 "v": jnp.asarray(np.asarray(vp), dt)},
-                jnp.asarray(np.asarray([pid], np.int32)))
-        self.state = dp.activate_slot(
-            self.state, slot, jnp.asarray(self._granted_block_row(slot)),
-            jnp.int32(length), jnp.asarray(pack["first_token"], jnp.int32))
+            with dispatch("h2d"):
+                kv = {"k": jnp.asarray(np.asarray(kp), dt),
+                      "v": jnp.asarray(np.asarray(vp), dt)}
+                ids = jnp.asarray(np.asarray([pid], np.int32))
+            with dispatch("write_pages"):
+                self.state = dp.write_kv_pages(self.state, kv, ids)
+        with dispatch("activate"):
+            self.state = dp.activate_slot(
+                self.state, slot, jnp.asarray(self._granted_block_row(slot)),
+                jnp.int32(length), jnp.asarray(pack["first_token"], jnp.int32))
+        self._note_prefill(0)
         self._bind_slot(req, slot, length)
         return True
 
@@ -1276,6 +1391,7 @@ class TPUEngine:
         activation once all pages landed. Runs between decode steps, so
         running requests keep emitting while transfers stream in."""
         progressed = False
+        dispatch = self._clock.dispatch
         for req in list(self._streaming):
             st = req.kv_stream
             err = st.take_error()
@@ -1295,19 +1411,21 @@ class TPUEngine:
                         # + activate in the ONE dispatch the non-streamed
                         # admission pays, instead of write_kv_pages +
                         # activate_slot
-                        kv = {"k": jnp.asarray(np.concatenate(
-                                  [np.asarray(t[1]) for t in ready],
-                                  axis=1), dt),
-                              "v": jnp.asarray(np.concatenate(
-                                  [np.asarray(t[2]) for t in ready],
-                                  axis=1), dt)}
+                        kcat, vcat = (np.concatenate(
+                            [np.asarray(t[i]) for t in ready], axis=1)
+                            for i in (1, 2))
+                        with dispatch("h2d"):
+                            kv = {"k": jnp.asarray(kcat, dt),
+                                  "v": jnp.asarray(vcat, dt)}
                         length = req.kv_pack["length"]
-                        self.state = dp.insert_sequence_paged(
-                            self.state, req.slot, kv, jnp.int32(length),
-                            jnp.asarray(req.kv_pack["first_token"],
-                                        jnp.int32),
-                            jnp.asarray(self._granted_block_row(req.slot)),
-                            self.cfg)
+                        with dispatch("adopt"):
+                            self.state = dp.insert_sequence_paged(
+                                self.state, req.slot, kv, jnp.int32(length),
+                                jnp.asarray(req.kv_pack["first_token"],
+                                            jnp.int32),
+                                jnp.asarray(self._granted_block_row(req.slot)),
+                                self.cfg)
+                        self._note_prefill(0)
                         self._streaming.remove(req)
                         self._bind_slot(req, req.slot, length)
                         continue
@@ -1330,20 +1448,23 @@ class TPUEngine:
                             [np.asarray(p) for p in kps], axis=1)
                         vcat = np.concatenate(
                             [np.asarray(p) for p in vps], axis=1)
-                        self.state = dp.write_kv_pages(
-                            self.state,
-                            {"k": jnp.asarray(kcat, dt),
-                             "v": jnp.asarray(vcat, dt)},
-                            jnp.asarray(np.asarray(ids, np.int32)))
+                        with dispatch("h2d"):
+                            kv = {"k": jnp.asarray(kcat, dt),
+                                  "v": jnp.asarray(vcat, dt)}
+                            ids = jnp.asarray(np.asarray(ids, np.int32))
+                        with dispatch("adopt"):
+                            self.state = dp.write_kv_pages(self.state, kv, ids)
+                        self._note_prefill(0)
                         req.pf_done += len(kps)
                 if req.pf_done >= st.n_pages:
                     self._streaming.remove(req)
                     length = req.kv_pack["length"]
-                    self.state = dp.activate_slot(
-                        self.state, req.slot,
-                        jnp.asarray(self._granted_block_row(req.slot)),
-                        jnp.int32(length),
-                        jnp.asarray(req.kv_pack["first_token"], jnp.int32))
+                    with dispatch("adopt"):
+                        self.state = dp.activate_slot(
+                            self.state, req.slot,
+                            jnp.asarray(self._granted_block_row(req.slot)),
+                            jnp.int32(length),
+                            jnp.asarray(req.kv_pack["first_token"], jnp.int32))
                     self._bind_slot(req, req.slot, length)
                     progressed = True
             except Exception as e:  # noqa: BLE001 — a malformed page must
@@ -1433,16 +1554,22 @@ class TPUEngine:
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :n] = req.tokens
             self._count_expert_tokens(bucket)
-            if self.lora_bank is not None:
-                logits, kv = decoding.prefill(
-                    self.params, jnp.asarray(padded), jnp.int32(n), self.cfg,
-                    self.lora_bank, jnp.int32(req.lora_idx))
-            else:
-                logits, kv = decoding.prefill(
-                    self.params, jnp.asarray(padded), jnp.int32(n), self.cfg)
-            self.key, sub = jax.random.split(self.key)
-            first = self._sample_first(req, logits, sub)
-            if not self._insert(req, slot, kv, n, first[0]):
+            dispatch = self._clock.dispatch
+            with dispatch("h2d"):
+                padded = jnp.asarray(padded)
+            with dispatch("prefill"):
+                if self.lora_bank is not None:
+                    logits, kv = decoding.prefill(
+                        self.params, padded, jnp.int32(n), self.cfg,
+                        self.lora_bank, jnp.int32(req.lora_idx))
+                else:
+                    logits, kv = decoding.prefill(
+                        self.params, padded, jnp.int32(n), self.cfg)
+            self._note_prefill(bucket)
+            with dispatch("split"):
+                self.key, sub = jax.random.split(self.key)
+            first, token = self._sample_first(req, logits, sub)
+            if not self._insert(req, slot, kv, n, token):
                 self._free.append(slot)
                 self._backlog.append(req)
                 return
@@ -1522,6 +1649,9 @@ class TPUEngine:
         padded = np.zeros((1, suf_bucket), np.int32)
         padded[0, :len(suffix)] = suffix
         self._count_expert_tokens(suf_bucket)
+        dispatch = self._clock.dispatch
+        with dispatch("h2d"):
+            padded = jnp.asarray(padded)
         if n_pre:
             # pad the shared-page id list to a power of two so compile
             # count stays O(log(max_pages) × buckets); tail ids point at
@@ -1531,26 +1661,33 @@ class TPUEngine:
                 npad *= 2
             padded_ids = np.zeros((npad,), np.int32)
             padded_ids[:n_pre] = pre_pages
-            k_pre, v_pre = dp.gather_prefix_pages(
-                self.state["kp"], self.state.get("vp"), jnp.asarray(padded_ids))
+            with dispatch("h2d"):
+                padded_ids = jnp.asarray(padded_ids)
+            with dispatch("gather_prefix"):
+                k_pre, v_pre = dp.gather_prefix_pages(
+                    self.state["kp"], self.state.get("vp"), padded_ids)
             self.prefix_tokens_gathered += pre_len
-            logits, kv = dp.prefill_with_prefix(
-                self.params, jnp.asarray(padded), k_pre, v_pre,
-                jnp.int32(pre_len), jnp.int32(len(suffix)), self.cfg)
+            with dispatch("prefill_with_prefix"):
+                logits, kv = dp.prefill_with_prefix(
+                    self.params, padded, k_pre, v_pre,
+                    jnp.int32(pre_len), jnp.int32(len(suffix)), self.cfg)
         else:
-            logits, kv = decoding.prefill(
-                self.params, jnp.asarray(padded), jnp.int32(len(suffix)),
-                self.cfg)
-        self.key, sub = jax.random.split(self.key)
-        first = self._sample_first(req, logits, sub)
+            with dispatch("prefill"):
+                logits, kv = decoding.prefill(
+                    self.params, padded, jnp.int32(len(suffix)), self.cfg)
+        self._note_prefill(suf_bucket)
+        with dispatch("split"):
+            self.key, sub = jax.random.split(self.key)
+        first, token = self._sample_first(req, logits, sub)
         block_row = np.zeros((self.max_pages_per_seq,), np.int32)
         block_row[:n_pre] = pre_pages
         block_row[n_pre:n_pre + len(priv)] = priv
         suf_pages = np.asarray(priv[:suf_bucket // P], np.int32)
-        self.state = dp.insert_sequence_paged_prefix(
-            self.state, slot, kv, jnp.asarray(suf_pages),
-            jnp.asarray(block_row), jnp.int32(n), first[0], self.cfg,
-            self._ring_row(slot))
+        ring = self._ring_row(slot)
+        with dispatch("insert"):
+            self.state = dp.insert_sequence_paged_prefix(
+                self.state, slot, kv, jnp.asarray(suf_pages),
+                jnp.asarray(block_row), jnp.int32(n), token, self.cfg, ring)
         self._bind_slot(req, slot, n)
         if self.enable_prefix_cache:
             self._register_blocks(slot, tokens, hashes, n_pre, priv)
@@ -1576,28 +1713,39 @@ class TPUEngine:
         # the window layers' part goes through the row's ring, whose slots
         # models/decoding_paged.py finds from the chunk's start
         ring, window = self._ring_row(req.slot), ()
+        dispatch = self._clock.dispatch
+        with dispatch("h2d"):
+            padded = jnp.asarray(padded)
+            chunk_pages = jnp.asarray(chunk_pages)
         if done == 0:
-            logits, kv = decoding.prefill(
-                self.params, jnp.asarray(padded),
-                jnp.int32(len(chunk_toks)), self.cfg)
+            with dispatch("prefill"):
+                logits, kv = decoding.prefill(
+                    self.params, padded, jnp.int32(len(chunk_toks)), self.cfg)
         else:
             npad = 1
             while npad < done // P:
                 npad *= 2
             padded_ids = np.zeros((npad,), np.int32)
             padded_ids[:done // P] = req.pf_pages[:done // P]
-            k_pre, v_pre = dp.gather_prefix_pages(
-                self.state["kp"], self.state.get("vp"), jnp.asarray(padded_ids))
+            with dispatch("h2d"):
+                padded_ids = jnp.asarray(padded_ids)
+            with dispatch("gather_prefix"):
+                k_pre, v_pre = dp.gather_prefix_pages(
+                    self.state["kp"], self.state.get("vp"), padded_ids)
             self.prefix_tokens_gathered += done
             if ring is not None:
-                window = dp.gather_window_pages(
-                    self.state, ring, jnp.int32(done), self.cfg)
-            logits, kv = dp.prefill_with_prefix(
-                self.params, jnp.asarray(padded), k_pre, v_pre,
-                jnp.int32(done), jnp.int32(len(chunk_toks)), self.cfg, *window)
-        self.state = dp.write_kv_pages(
-            self.state, kv, jnp.asarray(chunk_pages),
-            *(() if ring is None else (ring, jnp.int32(done))))
+                with dispatch("gather_window"):
+                    window = dp.gather_window_pages(
+                        self.state, ring, jnp.int32(done), self.cfg)
+            with dispatch("prefill_with_prefix"):
+                logits, kv = dp.prefill_with_prefix(
+                    self.params, padded, k_pre, v_pre, jnp.int32(done),
+                    jnp.int32(len(chunk_toks)), self.cfg, *window)
+        with dispatch("write_pages"):
+            self.state = dp.write_kv_pages(
+                self.state, kv, chunk_pages,
+                *(() if ring is None else (ring, jnp.int32(done))))
+        self._note_prefill(bucket)
         req.pf_done = done + len(chunk_toks)
         req.pf_chunks += 1
         self.prefill_chunks_run += 1
@@ -1607,13 +1755,15 @@ class TPUEngine:
         self._prefilling.pop(0)
         n = len(tokens)
         self._staged_tokens -= n
-        self.key, sub = jax.random.split(self.key)
-        first = self._sample_first(req, logits, sub)
+        with dispatch("split"):
+            self.key, sub = jax.random.split(self.key)
+        first, token = self._sample_first(req, logits, sub)
         block_row = np.zeros((self.max_pages_per_seq,), np.int32)
         block_row[:len(req.pf_pages)] = req.pf_pages
-        self.state = dp.activate_slot(
-            self.state, req.slot, jnp.asarray(block_row), jnp.int32(n),
-            first[0], ring)
+        with dispatch("activate"):
+            self.state = dp.activate_slot(
+                self.state, req.slot, jnp.asarray(block_row), jnp.int32(n),
+                token, ring)
         self._bind_slot(req, req.slot, n)
         if self.enable_prefix_cache:
             n_shared = len(self._slot_shared.get(req.slot, ()))
@@ -1630,6 +1780,11 @@ class TPUEngine:
         first.copy_to_host_async()
         self._unread.append(_Unread(first, [(0, req)], wait))
         self._retire_if_spent(req)
+
+    def _note_prefill(self, prompt_tokens: int) -> None:
+        """The pass has put a prefill program (or transferred pages) on the
+        device ahead of its decode step: that step is a `step_prefill`."""
+        self._pass_prefill = (self._pass_prefill or 0) + prompt_tokens
 
     def _retire_if_spent(self, req: _Request) -> None:
         """A row whose last token has been dispatched takes part in no
@@ -1680,13 +1835,14 @@ class TPUEngine:
         (_abort_one). A step in flight may still hold the row: it runs
         before this release on the device, writes inside the row's own
         pages, and its token is dropped."""
-        self.state = dp.release_slot_paged(self.state, req.slot)
+        with self._clock.dispatch("release"):
+            self.state = dp.release_slot_paged(self.state, req.slot)
+            if self.lora_bank is not None:
+                self._slot_lora = self._slot_lora.at[req.slot].set(0)
         self._return_pages(req.slot)
         self._count_live(req, -1)
         if self.enable_prefix_cache:
             self._release_shared(req.slot)
-        if self.lora_bank is not None:
-            self._slot_lora = self._slot_lora.at[req.slot].set(0)
         self._lora_release(req)
         self._guided_fsm.pop(req.slot, None)
         self._guided_state.pop(req.slot, None)
@@ -1879,13 +2035,17 @@ class TPUEngine:
         unread; every counter of the step is of what was DISPATCHED, a
         row-step whose token will be dropped included."""
         t_step = self._clock.mark("decode")
+        dispatch = self._clock.dispatch
         rows = list(self._by_slot.items())
-        state, logits = dp.decode_step_paged_ragged(
-            self.params, self.state, self.cfg, self._pages_bound(),
-            self._ragged_kernel, self.lora_bank, self._slot_lora)
+        pages_bound = self._pages_bound()
+        with dispatch("decode_step"):
+            state, logits = dp.decode_step_paged_ragged(
+                self.params, self.state, self.cfg, pages_bound,
+                self._ragged_kernel, self.lora_bank, self._slot_lora)
         # out of the state before the next program donates it (a gated stack)
         exit_cdf = state.pop("exit_cdf", None)
-        self.key, sub = jax.random.split(self.key)
+        with dispatch("split"):
+            self.key, sub = jax.random.split(self.key)
         if self._guided_fsm:
             # per-slot FSM masks as an additive bias; the sampling math
             # itself stays in the one jitted sample_per_row program.
@@ -1899,19 +2059,25 @@ class TPUEngine:
                 bias[slot] = _g.bias_row(
                     fsm, self._guided_state[slot],
                     remaining=r.params.max_tokens - r.generated)
-            logits = logits + jnp.asarray(bias)
+            with dispatch("bias"):
+                logits = logits + jnp.asarray(bias)
         # sampling params live on device, updated only at admission; the
         # sampler's form follows what the live rows ask for
         sampling, k_bucket, form = self._sampler_form
-        toks = decoding.sample_per_row(logits, sub, self._temps,
-                                       self._topks, sampling, k_bucket)
-        self.state = decoding.commit_tokens(state, toks)
+        with dispatch("sample"):
+            toks = decoding.sample_per_row(logits, sub, self._temps,
+                                           self._topks, sampling, k_bucket)
+        with dispatch("commit"):
+            self.state = decoding.commit_tokens(state, toks)
         toks.copy_to_host_async()
         if exit_cdf is not None:
             exit_cdf.copy_to_host_async()
         if any(u.step for u in self._unread):
             self.steps_ahead += 1
-        self._unread.append(_Unread(toks, rows, "decode_wait", t_step, exit_cdf))
+        prefill, self._pass_prefill = self._pass_prefill, None
+        self._unread.append(_Unread(
+            toks, rows, "decode_wait", t_step, exit_cdf,
+            "step" if prefill is None else "step_prefill", prefill or 0))
         self.decode_steps += 1
         self.stack_passes += self.cfg.n_passes
         self.sampler_steps[form] += 1
@@ -1952,8 +2118,11 @@ class TPUEngine:
             toks, exit_cdf = jax.device_get((u.toks, u.exit_cdf))
             now = mark("emit")
             if u.step:
+                # one measurement, two sinks
+                took = now - max(u.t_dispatch, self._t_fetched)
+                self._clock.book_pass(u.kind, took, len(u.rows), u.prompt_tokens)
                 if self._step_obs is not None:
-                    self._step_obs.observe(now - max(u.t_dispatch, self._t_fetched))
+                    self._step_obs[u.kind].observe(took)
                 self._t_fetched = now
                 if exit_cdf is not None:
                     np.add.at(self.exit_rows,

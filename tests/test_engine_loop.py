@@ -18,8 +18,8 @@ import pytest
 
 from ray_tpu._private import accelerators
 from ray_tpu.llm import engine as engine_mod
-from ray_tpu.llm.engine import (HOST_PHASES, LOOP_PHASES, SamplingParams,
-                                TPUEngine)
+from ray_tpu.llm.engine import (HOST_PHASES, LOOP_PHASES, PASS_KINDS,
+                                SamplingParams, TPUEngine)
 from ray_tpu.models import transformer
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.util import tracing
@@ -123,6 +123,10 @@ def test_snapshot_is_consistent_while_the_loop_runs(tiny_model):
         assert -0.5 < sum(a["seconds"].values()) - a["thread_s"] < 1e-6
         assert b["thread_s"] >= a["thread_s"]
         assert all(b["seconds"][p] >= a["seconds"][p] - 1e-9 for p in LOOP_PHASES)
+        assert b["host_cpu_s"] >= a["host_cpu_s"]
+        assert b["dispatch_s"] >= a["dispatch_s"]
+        assert all(b["passes"][k]["count"] >= a["passes"][k]["count"]
+                   for k in PASS_KINDS)
 
 
 def test_queue_wait_and_prefill_split_the_admission_wait(tiny_model):
@@ -226,9 +230,17 @@ def test_stats_stay_json_plain(tiny_model):
         eng.shutdown()
     back = json.loads(json.dumps(st))
     assert back["loop"] == st["loop"]
-    assert set(back["loop"]) == {"seconds", "host_s", "active_s", "thread_s",
-                                 "requests", "steps_ahead", "tokens_discarded"}
-    assert len(json.dumps(back["loop"])) < 1000
+    assert set(back["loop"]) == {
+        "seconds", "host_s", "active_s", "thread_s", "requests", "steps_ahead",
+        "tokens_discarded", "host_cpu_s", "dispatch_s", "work_s", "dispatch",
+        "passes"}
+    # two numbers a program the engine can dispatch (17), four a kind of pass
+    assert len(json.dumps(back["loop"])) < 2500
+    assert tuple(back["loop"]["passes"]) == PASS_KINDS
+    for row in back["loop"]["passes"].values():
+        assert set(row) == {"count", "seconds", "rows", "prompt_tokens"}
+    for row in back["loop"]["dispatch"].values():
+        assert set(row) == {"calls", "seconds"}
     assert set(back["loop"]["requests"]) == {
         "requests_scheduled", "queue_wait_s", "first_tokens", "prefill_s"}
     recent = back["compile_cache"]["recent"]
@@ -297,7 +309,8 @@ def test_device_annotation_is_the_one_prefix():
 
 def test_engine_phases_are_on_the_profiler_timeline(tiny_model, tmp_path):
     """A `jax.profiler` trace taken around a few steps carries the loop's
-    phases as `ray_tpu:engine:*` events, all on the scheduler thread's line."""
+    phases as `ray_tpu:engine:*` events, all on the scheduler thread's line,
+    and every dispatch as a `ray_tpu:engine:dispatch:*` event inside one."""
     from jax.profiler import ProfileData
 
     eng = _engine(tiny_model)
@@ -319,18 +332,194 @@ def test_engine_phases_are_on_the_profiler_timeline(tiny_model, tmp_path):
         if not plane.name.startswith("/host:"):
             continue
         for line in plane.lines:
-            names = [e.name for e in line.events if e.name.startswith("ray_tpu:")]
-            if names:
-                lines.append(names)
+            mine = [(e.name, e.start_ns, e.end_ns) for e in line.events
+                    if e.name.startswith("ray_tpu:")]
+            if mine:
+                lines.append(mine)
     if not lines:
         pytest.skip("this JAX writes no TraceMe events to the host plane on CPU")
     # one thread marks the phases, so they are on one line (which the
     # profiler names after the OS thread, `python` before Python 3.14)
-    (names,) = lines
+    (events,) = lines
+    names = [n for n, _, _ in events]
     assert names.count("ray_tpu:engine:decode") >= 4
     assert {"ray_tpu:engine:decode_wait", "ray_tpu:engine:emit",
             "ray_tpu:engine:admit", "ray_tpu:engine:sweep"} <= set(names)
-    assert {n[len("ray_tpu:engine:"):] for n in names} <= set(LOOP_PHASES)
+    head = "ray_tpu:engine:dispatch:"
+    phases = [e for e in events if not e[0].startswith(head)]
+    calls = [e for e in events if e[0].startswith(head)]
+    assert {n[len("ray_tpu:engine:"):] for n, _, _ in phases} <= set(LOOP_PHASES)
+    # every dispatch is a span of its own, nested in the span of a host phase
+    # (a wait dispatches nothing), and no two dispatches overlap
+    assert {head + p for p in ("decode_step", "split", "sample", "commit",
+                               "h2d", "prefill", "write_pages", "sample_first",
+                               "activate", "bind", "release")} <= set(names)
+    assert names.count(head + "decode_step") == names.count("ray_tpu:engine:decode")
+    for name, start, end in calls:
+        around = [p for p, s, e in phases if s <= start and end <= e]
+        assert len(around) == 1, (name, around)
+        assert around[0][len("ray_tpu:engine:"):] in HOST_PHASES, (name, around)
+    calls.sort(key=lambda e: e[1])
+    assert all(a[2] <= b[1] for a, b in zip(calls, calls[1:]))
+
+
+# ------------------- dispatch seconds, CPU seconds, passes: inside the phases
+
+
+def _delta(a: dict, b: dict) -> dict:
+    """b - a, leaf by leaf, over two readings of one nested counter block (a
+    key the first reading lacks counts from 0: a program's first dispatch)."""
+    return {k: _delta(a.get(k, {}), v) if isinstance(v, dict) else v - a.get(k, 0)
+            for k, v in b.items()}
+
+
+def test_dispatch_and_cpu_seconds_lie_inside_the_host_phases(tiny_model):
+    """With the three additions in, the phases still add up to the thread's
+    time; the dispatches' seconds are a part of the host phases' and `work_s`
+    the rest; the thread is on the CPU in its host phases for no longer than
+    they lasted."""
+    eng = _engine(tiny_model)
+    try:
+        _burst(eng, 2, 20, 3)
+        s0 = _quiet_stats(eng)["loop"]
+        _burst(eng, 8, 40, 6)
+        s1 = _quiet_stats(eng)["loop"]
+    finally:
+        eng.shutdown()
+    d = _delta(s0, s1)
+    assert sum(d["seconds"].values()) == pytest.approx(d["thread_s"], rel=0.01)
+    assert 0 < d["dispatch_s"] <= d["host_s"]
+    assert d["work_s"] == pytest.approx(d["host_s"] - d["dispatch_s"])
+    assert d["work_s"] > 0
+    assert d["dispatch_s"] == pytest.approx(
+        sum(row["seconds"] for row in d["dispatch"].values()))
+    assert all(0 < row["seconds"] and 0 < row["calls"]
+               for row in s1["dispatch"].values())
+    # the two clocks are read one after the other where a host stretch ends
+    assert 0 < d["host_cpu_s"] <= d["host_s"] + 0.005
+
+
+def test_the_cpu_clock_is_read_where_the_thread_enters_or_leaves_the_host(monkeypatch):
+    """`thread_time` is a system call: a boundary between two host phases, or
+    between a wait and parking, reads `perf_counter` alone; a host stretch
+    costs two reads and books what the thread's CPU clock moved by."""
+    cpu = iter(range(100, 200))
+    reads = []
+    monkeypatch.setattr(engine_mod.time, "thread_time",
+                        lambda: reads.append(next(cpu)) or reads[-1])
+    clock = engine_mod._PhaseClock()
+    for phase in ("sweep", "admit", "streams", "prefill", "decode"):
+        clock.mark(phase)
+    assert reads == [100] and clock.snapshot()["host_cpu_s"] == 0
+    clock.mark("decode_wait")
+    assert reads == [100, 101] and clock.snapshot()["host_cpu_s"] == 1
+    clock.mark("emit")
+    clock.mark("sweep")
+    clock.mark("admit_wait")
+    clock.mark("parked")
+    assert reads == [100, 101, 102, 103] and clock.snapshot()["host_cpu_s"] == 2
+
+
+def test_a_greedy_run_books_four_dispatches_a_decode_step(tiny_model):
+    """N decode steps are N calls each of `decode_step`, `sample` and
+    `commit`, and N of `split` beside the one of every admission; no bias is
+    uploaded for unguided rows; every step read is a pass of kind `step`
+    unless a prefill went out before it."""
+    eng = _plain(tiny_model)
+    try:
+        eng.generate(_prompt(0, 12), SamplingParams(max_tokens=3))
+        s0 = _quiet_stats(eng)
+        outs = [list(r) for r in [
+            eng.submit(_prompt(i, 12 + i), SamplingParams(max_tokens=20 - 5 * i))
+            for i in range(3)]]
+        s1 = _quiet_stats(eng)
+    finally:
+        eng.shutdown()
+    assert [len(o) for o in outs] == [20, 15, 10]
+    steps = s1["decode_steps"] - s0["decode_steps"]
+    d = _delta(s0["loop"], s1["loop"])
+    assert steps >= 19
+    for program in ("decode_step", "sample", "commit"):
+        assert d["dispatch"][program]["calls"] == steps, program
+    assert d["dispatch"]["split"]["calls"] == steps + 3
+    for program in ("prefill", "sample_first", "insert", "bind", "release"):
+        assert d["dispatch"][program]["calls"] == 3, program
+    assert "bias" not in s1["loop"]["dispatch"]
+    passes = d["passes"]
+    assert passes["step"]["count"] + passes["step_prefill"]["count"] == steps
+    # three unstaged prompts of bucket 16 went out before one, two or three steps
+    assert 1 <= passes["step_prefill"]["count"] <= 3
+    assert passes["step_prefill"]["prompt_tokens"] == 3 * 16
+    assert passes["step"]["prompt_tokens"] == 0
+    assert passes["step"]["rows"] + passes["step_prefill"]["rows"] == 19 + 14 + 9
+    assert all(passes[k]["seconds"] > 0 for k in PASS_KINDS)
+    # what the histogram is given is what the passes book
+    from ray_tpu.util import metrics as met
+
+    counted = {dict(tuple(t) for t in tags)["pass"]: st for m in met.snapshot()
+               if m["name"] == "ray_tpu_llm_decode_step_seconds"
+               for tags, st in m["series"]}
+    assert set(counted) <= set(PASS_KINDS) and counted["step"]["count"] >= passes["step"]["count"]
+
+
+def test_a_chunked_prompt_books_its_padded_tokens_to_step_prefill_passes(tiny_model):
+    """Every chunk goes out ahead of a decode step of its pass (or, with no
+    row to step yet, of the first step after it): the `step_prefill` passes'
+    prompt tokens are the padded tokens of the chunks dispatched, and once
+    everything is read the passes are the decode steps."""
+    eng = _engine(tiny_model)
+    try:
+        _burst(eng, 2, 20, 3)
+        s0 = _quiet_stats(eng)
+        slot_steps0 = eng.decode_slot_steps
+        outs = _burst(eng, 6, 40, 8)  # three chunks each, bucket 16 every one
+        s1 = _quiet_stats(eng)
+        slot_steps1 = eng.decode_slot_steps
+    finally:
+        eng.shutdown()
+    assert all(len(o) == 8 for o in outs)
+    d = _delta(s0["loop"], s1["loop"])
+    chunks = s1["prefill_chunks_run"] - s0["prefill_chunks_run"]
+    passes = d["passes"]
+    assert chunks >= 12
+    assert passes["step_prefill"]["prompt_tokens"] == 16 * chunks
+    assert passes["step"]["prompt_tokens"] == 0
+    assert 0 < passes["step_prefill"]["count"] <= chunks
+    assert (passes["step"]["count"] + passes["step_prefill"]["count"]
+            == s1["decode_steps"] - s0["decode_steps"])
+    assert (passes["step"]["rows"] + passes["step_prefill"]["rows"]
+            == slot_steps1 - slot_steps0)
+    assert d["dispatch"]["write_pages"]["calls"] == chunks
+    assert (d["dispatch"]["prefill"]["calls"]
+            + d["dispatch"]["prefill_with_prefix"]["calls"]) == chunks
+    assert d["dispatch"]["gather_prefix"]["calls"] == d["dispatch"][
+        "prefill_with_prefix"]["calls"]
+    assert d["dispatch"]["activate"]["calls"] == 6
+
+
+def test_a_guided_row_still_counts_its_passes(tiny_model):
+    """Depth 0 (a guided row lives): every step is read in the pass that
+    dispatched it and booked all the same, with its mask's upload a dispatch
+    of its own."""
+    from ray_tpu.llm.guided import GuidedFSM
+
+    eng = _plain(tiny_model)
+    try:
+        eng.generate(_prompt(1, 13), SamplingParams(max_tokens=3))
+        s0 = _quiet_stats(eng)
+        allow_all = GuidedFSM(masks=np.ones((1, 64), bool),
+                              trans=np.zeros((1, 64), np.int32))
+        out = eng.generate(_prompt(1, 13), SamplingParams(max_tokens=24,
+                                                          guided=allow_all))
+        s1 = _quiet_stats(eng)
+    finally:
+        eng.shutdown()
+    assert len(out) == 24 and s1["decode_steps"] - s0["decode_steps"] == 23
+    d = _delta(s0["loop"], s1["loop"])
+    assert d["steps_ahead"] == 0
+    assert d["passes"]["step"]["count"] + d["passes"]["step_prefill"]["count"] == 23
+    assert d["passes"]["step_prefill"]["count"] == 1  # its own prefill's pass
+    assert d["dispatch"]["bias"]["calls"] == 23 + 1  # the first token's too
 
 
 # ------------------------------- the sampler's form follows the live rows
@@ -649,27 +838,40 @@ def test_an_abort_returns_slot_and_pages_within_two_steps(tiny_model):
 # ------------------------------------------- the benchmark's reader of these
 
 
-def _stats(loop_seconds: dict, requests: dict, decode_steps: int) -> dict:
+def _stats(loop_seconds: dict, requests: dict, decode_steps: int,
+           inside: dict) -> dict:
     seconds = dict.fromkeys(LOOP_PHASES, 0.0)
     seconds.update(loop_seconds)
     host = sum(seconds[p] for p in HOST_PHASES)
     return {"decode_steps": decode_steps,
             "loop": {"seconds": seconds, "host_s": host,
                      "active_s": host + sum(seconds[p] for p in WAITS),
-                     "thread_s": sum(seconds.values()), "requests": requests}}
+                     "thread_s": sum(seconds.values()), "requests": requests,
+                     **inside}}
+
+
+def _passes(step: tuple, step_prefill: tuple) -> dict:
+    return {kind: dict(zip(("count", "seconds", "rows", "prompt_tokens"), row))
+            for kind, row in (("step", step), ("step_prefill", step_prefill))}
 
 
 S0 = _stats({"parked": 5.0, "sweep": 0.1, "admit": 0.2, "admit_wait": 0.3,
              "prefill": 0.4, "prefill_wait": 0.5, "decode": 1.0,
              "decode_wait": 8.0, "emit": 0.5},
             {"requests_scheduled": 10, "queue_wait_s": 1.0, "first_tokens": 10,
-             "prefill_s": 2.0}, 100)
+             "prefill_s": 2.0}, 100,
+            {"dispatch_s": 0.5, "host_cpu_s": 1.0,
+             "passes": _passes((80, 1.6, 200, 0), (19, 1.9, 60, 19000))})
 S1 = _stats({"parked": 6.0, "sweep": 0.3, "admit": 0.6, "admit_wait": 0.9,
              "prefill": 1.2, "prefill_wait": 1.5, "decode": 3.0,
              "decode_wait": 24.0, "emit": 1.5},
             {"requests_scheduled": 30, "queue_wait_s": 5.0, "first_tokens": 26,
-             "prefill_s": 10.0}, 300)
-# deltas: host 0.2+0.4+0.8+2.0+1.0 = 4.4; waits 0.6+1.0+16.0 = 17.6; active 22.0
+             "prefill_s": 10.0}, 300,
+            {"dispatch_s": 1.6, "host_cpu_s": 3.2,
+             "passes": _passes((230, 4.6, 650, 0), (69, 8.9, 180, 69000))})
+# deltas: host 0.2+0.4+0.8+2.0+1.0 = 4.4; waits 0.6+1.0+16.0 = 17.6; active 22.0;
+# dispatch 1.1, CPU in host phases 2.2; 150 passes `step` in 3.0 s, 50
+# `step_prefill` in 7.0 s
 HAND = {
     "engine_host_pct.chat": 100 * 4.4 / 22.0,
     "engine_host_pct.doc": 100 * 4.4 / 22.0,
@@ -680,6 +882,17 @@ HAND = {
     "queue_wait_ms.doc": 1e3 * 4.0 / 20,
     "prefill_latency_ms.doc": 1e3 * 8.0 / 16,
 }
+# ISSUE 37's seven: what lies inside the phases
+HAND_INSIDE = {
+    "step_dispatch_ms.chat": 1e3 * 1.1 / 200,
+    "engine_dispatch_pct.doc": 100 * 1.1 / 22.0,
+    "engine_cpu_pct.chat": 100 * 2.2 / 22.0,
+    "engine_cpu_pct.doc": 100 * 2.2 / 22.0,
+    "decode_pass_ms.chat": 1e3 * 3.0 / 150,
+    "decode_pass_ms.doc": 1e3 * 3.0 / 150,
+    "prefill_pass_ms.doc": 1e3 * 7.0 / 50,
+}
+HAND.update(HAND_INSIDE)
 
 
 @pytest.mark.parametrize("metric", sorted(HAND))
@@ -707,6 +920,43 @@ def test_stats_ratio_reads_the_metric(metric):
         "end_to_end"] if m["name"] == "served_tok_s"][0]["workloads"]
     assert entry[0]["workloads"] == (
         ["mixtral-8x7b.chat-steady"] if metric.endswith(".chat") else served)
+
+
+def test_the_new_metric_files_read_a_real_engines_two_readings(tiny_model):
+    """The seven data files of ISSUE 37 name paths that `stats()` really has:
+    through the benchmark's own `read_layer_metrics`, from two readings of a
+    tiny engine (CPU: the values are no device numbers, only their presence
+    and their arithmetic are checked)."""
+    from chipbench import harness
+
+    eng = _engine(tiny_model)
+    try:
+        _burst(eng, 2, 20, 3)
+        s0 = _quiet_stats(eng)
+        _burst(eng, 6, 40, 8)
+        s1 = _quiet_stats(eng)
+    finally:
+        eng.shutdown()
+    new = sorted(HAND_INSIDE)
+    per_layer = [m for m in harness.load_json(harness.ROOT, "BENCHMARK.json")[
+        "per_layer"] if m["name"] in new]
+    facts = {"stats0": s0, "stats1": s1}
+    got = {k: v["value"] for k, v in harness.read_layer_metrics(
+        {"per_layer": per_layer}, facts).items()}
+    assert sorted(got) == new and all(v > 0 for v in got.values())
+    l0, l1 = s0["loop"], s1["loop"]
+    active = l1["active_s"] - l0["active_s"]
+    host_pct = 100 * (l1["host_s"] - l0["host_s"]) / active
+    assert got["engine_dispatch_pct.doc"] <= host_pct
+    assert got["engine_cpu_pct.doc"] == got["engine_cpu_pct.chat"] <= host_pct + 1
+    assert got["decode_pass_ms.doc"] == got["decode_pass_ms.chat"]
+    assert got["step_dispatch_ms.chat"] == pytest.approx(
+        1e3 * (l1["dispatch_s"] - l0["dispatch_s"])
+        / (s1["decode_steps"] - s0["decode_steps"]))
+    # the parent's program has none of the three blocks: nothing read, no raise
+    old = {k: {**s, "loop": {p: v for p, v in s["loop"].items() if p not in (
+        "dispatch_s", "host_cpu_s", "passes")}} for k, s in facts.items()}
+    assert harness.read_layer_metrics({"per_layer": per_layer}, old) == {}
 
 
 def test_host_and_active_sums_partition_the_phases(tiny_model):

@@ -358,13 +358,16 @@ def test_engine_phase_histograms(monkeypatch):
         RayConfig.reset()
 
 
-def test_decode_step_histogram_is_labelled_with_the_code_that_runs():
-    """`ray_tpu_llm_decode_step_seconds{impl}` carries stats()["decode_attn"]
-    (on the CPU: the reference form), one observation a decode step."""
+def test_decode_step_histogram_is_labelled_with_the_kind_of_pass():
+    """`ray_tpu_llm_decode_step_seconds{pass}`: one observation a decode step,
+    under what the step's pass put on the device before it (`step_prefill`:
+    the prompt's prefill went out ahead of the first step), the counts
+    `stats()["loop"]["passes"]` keeps. Which attention code runs stays in
+    `stats()["decode_attn"]`."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.llm.engine import SamplingParams, TPUEngine
+    from ray_tpu.llm.engine import PASS_KINDS, SamplingParams, TPUEngine
     from ray_tpu.models import transformer
     from ray_tpu.models.transformer import TransformerConfig
     from ray_tpu.util import metrics as met
@@ -376,7 +379,7 @@ def test_decode_step_histogram_is_labelled_with_the_code_that_runs():
     def counts():
         for m in met.snapshot():
             if m["name"] == "ray_tpu_llm_decode_step_seconds":
-                return {dict(tuple(t) for t in tags)["impl"]: st["count"]
+                return {dict(tuple(t) for t in tags)["pass"]: st["count"]
                         for tags, st in m["series"]}
         return {}
 
@@ -389,9 +392,10 @@ def test_decode_step_histogram_is_labelled_with_the_code_that_runs():
     finally:
         eng.shutdown()
     after = counts()
-    assert st["decode_attn"] == "ragged_reference" and set(after) == {"ragged_reference"}
-    assert (after["ragged_reference"] - before.get("ragged_reference", 0)
-            == st["decode_steps"] == 4)
+    assert st["decode_attn"] == "ragged_reference" and set(after) == set(PASS_KINDS)
+    seen = {k: after[k] - before.get(k, 0) for k in PASS_KINDS}
+    assert seen == {"step": 3, "step_prefill": 1} and st["decode_steps"] == 4
+    assert seen == {k: row["count"] for k, row in st["loop"]["passes"].items()}
 
 
 # ------------------------------------------------- engine spans (ISSUE 24)

@@ -343,6 +343,38 @@ def test_guarded_attr_fires(tmp_path):
     assert got == [("guarded-attr", "m.py", 12)]
 
 
+def test_a_clock_is_no_lock(tmp_path):
+    """`with self._clock.dispatch(...)` times a call: it opens no critical
+    section, so neither a sleep inside it nor a bare read elsewhere of what
+    it wrote is a finding; `self._rlock` still is a lock."""
+    (tmp_path / "m.py").write_text(
+        "import threading, time\n"
+        "class C:\n"
+        "    def __init__(self):\n"
+        "        self._rlock = threading.RLock()\n"
+        "        self.state = []\n"
+        "        self.items = []\n"
+        "    def timed(self, x):\n"
+        "        with self._clock.dispatch('insert'):\n"
+        "            time.sleep(0.1)\n"
+        "            self.state = self.state + [x]\n"
+        "    def put(self, x):\n"
+        "        with self._rlock:\n"
+        "            self.items = self.items + [x]\n"
+        "    def peek(self):\n"
+        "        return self.state[0], self.items[0]\n")  # line 15: items only
+    report = _run(tmp_path, [LockDisciplineChecker()])
+    assert [(k, line) for k, _, line in _ids(report)] == [("guarded-attr", 15)]
+    assert "C.items" in report.findings[0].message
+    from tools.graft_check.core import LOCK_NAME_RE
+
+    # only the word `clock` is let go, not every `lock` behind a `c`
+    assert [bool(LOCK_NAME_RE.search(n)) for n in (
+        "self._clock.dispatch", "clock", "phase_clock", "self._synclock",
+        "funcLock", "self._clock_lock", "_lock", "RLock")] == [
+        False, False, False, True, True, True, True, True]
+
+
 # -------------------------------------------------------------- lock-order
 
 

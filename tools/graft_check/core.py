@@ -212,7 +212,9 @@ def blocking_call_desc(node: ast.Call) -> Optional[str]:
 
 # ---------------------------------------------------- module summaries / graph
 
-LOCK_NAME_RE = re.compile(r"lock|mutex|\bmu\b", re.IGNORECASE)
+# the word `clock` alone (`clock`, `_clock`, `phase_clock`) is no lock:
+# `with self._clock.dispatch(...)` times a call; `_synclock`, `funcLock` are
+LOCK_NAME_RE = re.compile(r"(?<!\bc)(?<!_c)lock|mutex|\bmu\b", re.IGNORECASE)
 
 
 @dataclasses.dataclass
