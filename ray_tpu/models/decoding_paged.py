@@ -22,6 +22,11 @@ scatters each layer's rows into the carry in place, because pools handed to
 the scan as inputs and stacked as its outputs are sliced, copied and
 rewritten whole every step.
 
+Whole pages (a prefill's, a chunk's, transferred ones) enter a donated pool
+one `dynamic_update_slice` a page (`_set_pages`): as ONE scatter along the
+page axis, a pool whose (Hkv, Dh) tile is (4, 128) was re-laid whole, in and
+out, around the scatter (four KV heads: 18 ms a call for 25 MB written).
+
 A model with latent attention (cfg.kv_lora_rank, MLA) has ONE pool, `kp`
 [L, num_pages, page, latent_lanes], and no `vp`: a token's row is (c |
 k_rope | padding to whole lanes), nothing per head. Prefill attends in
@@ -211,8 +216,7 @@ def _write_ring(state, window_kv, slot, length) -> dict:
         m = min(n, ring)
         q = (length - 1) // P - (m - 1) + jnp.arange(m)
         src = rows.reshape(rows.shape[0], n, P, *rows.shape[2:])[:, jnp.clip(q, 0, n - 1)]
-        state[name + "p"] = pool.at[:, _ring_pages(ids, held, q)].set(
-            src.astype(pool.dtype))
+        state[name + "p"] = _set_pages(pool, _ring_pages(ids, held, q), src)
     return state
 
 
@@ -224,9 +228,25 @@ def _write_pages(state, kv, pages) -> dict:
     for name, rows in kv.items():
         pool = state[name + "p"]
         n = rows.shape[1] // P  # static: T is a bucket
-        state[name + "p"] = pool.at[:, pages[:n]].set(
-            rows.reshape(rows.shape[0], n, P, *rows.shape[2:]).astype(pool.dtype))
+        state[name + "p"] = _set_pages(
+            pool, pages[:n], rows.reshape(rows.shape[0], n, P, *rows.shape[2:]))
     return state
+
+
+def _set_pages(pool, ids, src):
+    """`pool` [planes, pages, P, ...] with page `ids[i]` of every plane set to
+    `src[:, i]` (`ids` [n], `src` [planes, n, P, ...]): the one way whole
+    pages enter a pool outside the decode step. One `dynamic_update_slice` a
+    page, in the order of `ids` (ids that repeat are scratch page 0's: the
+    last one stays), which updates the donated pool where it lies
+    (module docstring)."""
+    src = src.astype(pool.dtype)
+
+    def set_page(i, pool):
+        page = jax.lax.dynamic_slice_in_dim(src, i, 1, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(pool, page, ids[i], axis=1)
+
+    return jax.lax.fori_loop(0, ids.shape[0], set_page, pool)
 
 
 @functools.partial(jax.jit, donate_argnames=("state",),

@@ -356,6 +356,154 @@ def test_an_unchunked_prompt_longer_than_the_ring_writes_its_last_pages(model):
     assert float(jnp.abs(state["kp"][:, 0]).max()) == 0.0
 
 
+# ------------------------------------------- whole pages into a pool, in place
+
+
+def _scatter_pages(pool, ids, src):
+    """The form `_set_pages` replaced (one scatter along the page axis): the
+    oracle. Where ids repeat (scratch page 0) its order is unspecified."""
+    return pool.at[:, ids].set(src.astype(pool.dtype))
+
+
+def _ring_ids(held, first, n):
+    """Page ids of `n` consecutive logical pages from `first` on, in a ring
+    of 9 slots of which the row holds `held` (the ids a permutation)."""
+    ids = np.zeros((9,), np.int32)
+    ids[:held] = [3, 8, 1, 9, 4, 2, 7, 6, 5][:held]
+    return dp._ring_pages(jnp.asarray(ids), jnp.int32(held), first + jnp.arange(n))
+
+
+# name -> (pool shape, pool dtype, source dtype, page ids)
+F32, BF16 = jnp.float32, jnp.bfloat16
+PAGE_WRITES = {
+    "full_pool": ((2, 12, PAGE, 2, 16), F32, F32, lambda: jnp.asarray([7, 2, 11, 5])),
+    "bucket_padding": ((2, 12, PAGE, 2, 16), F32, F32, lambda: jnp.asarray([4, 9, 0, 0])),
+    "ring_wraps": ((6, 10, PAGE, 2, 16), F32, F32, lambda: _ring_ids(9, 15, 9)),
+    "ring_before_the_row": ((6, 10, PAGE, 2, 16), F32, F32, lambda: _ring_ids(4, -5, 9)),
+    "latent_rows": ((3, 9, PAGE, 40), BF16, BF16, lambda: jnp.asarray([8, 1, 3])),
+    "looped_planes": ((12, 7, PAGE, 4, 8), F32, F32, lambda: jnp.asarray([6, 1, 4, 2, 5])),
+    "cast_to_the_pool": ((2, 12, PAGE, 2, 16), BF16, F32, lambda: jnp.asarray([1, 10])),
+}
+
+
+@pytest.mark.parametrize("case", list(PAGE_WRITES))
+def test_pages_written_one_at_a_time_are_the_scatters(case):
+    """`_set_pages` against the scatter it replaced, bit for bit on every page
+    but scratch 0: a full pool, bucket padding (several ids 0), a ring across
+    its wrap-around (slot q % held, ids out of order) and one whose first
+    logical pages lie before the row's start (scratch), latent rows, a looped
+    stack's planes, a source wider than the pool's dtype; jitted with the pool
+    donated, as the writers call it."""
+    shape, pool_dtype, src_dtype, ids = PAGE_WRITES[case]
+    ids = ids()
+    k1, k2 = jax.random.split(jax.random.PRNGKey(len(case)))
+    pool = jax.random.normal(k1, shape, jnp.float32).astype(pool_dtype)
+    src = jax.random.normal(k2, (shape[0], ids.shape[0], *shape[2:]), jnp.float32).astype(src_dtype)
+    want = np.asarray(_scatter_pages(pool, ids, src).astype(jnp.float32))
+    if case.startswith("ring"):
+        assert sorted(np.asarray(ids).tolist()) != np.asarray(ids).tolist()
+    if case in ("bucket_padding", "ring_before_the_row"):
+        assert int((ids == 0).sum()) > 1
+    before = np.asarray(pool.astype(jnp.float32))
+    got = jax.jit(dp._set_pages, donate_argnums=0)(pool, ids, src)
+    assert got.dtype == pool_dtype and got.shape == shape
+    got = np.asarray(got.astype(jnp.float32))
+    assert np.array_equal(got[:, 1:], want[:, 1:])
+    untouched = sorted(set(range(1, shape[1])) - set(np.asarray(ids).tolist()))
+    assert np.array_equal(got[:, untouched], before[:, untouched])
+    if int((ids == 0).sum()) > 1:   # the last writer of scratch stays
+        last = int(np.flatnonzero(np.asarray(ids) == 0)[-1])
+        assert np.array_equal(got[:, 0], np.asarray(src[:, last].astype(pool_dtype)
+                                                    .astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("writer", ["insert", "insert_prefix", "chunk", "ring_only"])
+def test_the_writers_leave_what_the_scatter_left(model, monkeypatch, writer):
+    """The three programs (and `_write_ring` alone, a prompt whose bucket is
+    longer than the ring, `length` inside its third page from the end) with
+    `_set_pages` swapped for the scatter: the same pools on every page but
+    scratch 0, the same tables."""
+    cfg, _ = model
+    T = 24 * PAGE
+    k1, k2 = jax.random.split(jax.random.PRNGKey(11))
+    kv = {"k": jax.random.normal(k1, (cfg.n_layers, T, 2, 16)),
+          "v": jax.random.normal(k2, (cfg.n_layers, T, 2, 16))}
+    ring = jnp.asarray([3, 8, 1, 9, 4, 2, 7, 6, 5], jnp.int32)
+    row = np.zeros((MAX_LEN // PAGE,), np.int32)
+    row[:26] = 10 + np.random.default_rng(4).permutation(26)
+    row = jnp.asarray(row)
+    n = jnp.int32(T - 2 * PAGE - 3)
+
+    def run():
+        state = dp.init_paged_state(cfg, 3, MAX_LEN, 48, PAGE)
+        if writer == "insert":
+            return dp.insert_sequence_paged.__wrapped__(
+                state, 1, kv, n, jnp.int32(5), row, cfg, ring)
+        if writer == "insert_prefix":
+            return dp.insert_sequence_paged_prefix.__wrapped__(
+                state, 1, kv, row[:24], row, n, jnp.int32(5), cfg, ring)
+        if writer == "chunk":
+            for start in range(0, T, 8 * PAGE):
+                chunk = {x: t[:, start:start + 8 * PAGE] for x, t in kv.items()}
+                state = dp.write_kv_pages.__wrapped__(
+                    state, chunk, row[start // PAGE:start // PAGE + 8], ring,
+                    jnp.int32(start))
+            return state
+        state = dp._set_ring(state, 1, ring)
+        return dp._write_ring(state, dp._split_kinds(kv, state)[1], 1, n)
+
+    got = run()
+    monkeypatch.setattr(dp, "_set_pages", _scatter_pages)
+    want = run()
+    assert set(got) == set(want)
+    for name in want:
+        a, b = np.asarray(got[name]), np.asarray(want[name])
+        if name in ("kp", "vp", "wkp", "wvp"):
+            a, b = a[:, 1:], b[:, 1:]
+            assert np.abs(b).max() > 0 or (writer == "ring_only" and name[0] != "w"), name
+        assert np.array_equal(a, b), name
+
+
+@pytest.mark.parametrize("family", ["mellum", "mixtral"])
+def test_chunk_by_chunk_writes_equal_the_whole_prompts_insert(model, family):
+    """`write_kv_pages` a chunk at a time (the window layers' part through
+    the ring, later pages over earlier ones) and `activate_slot`, against
+    `insert_sequence_paged` of the whole bucket: the same full pool and the
+    same ring on every page but scratch, the same row. The prompt ends in its
+    bucket's last page, so both leave the ring's last logical pages."""
+    cfg = model[0] if family == "mellum" else mixtral_config(
+        "tiny", vocab_size=VOCAB, max_seq_len=1024, dtype=jnp.float32)
+    chunk, T = 4 * PAGE, 24 * PAGE
+    ring = dp.window_ring(cfg, PAGE, chunk) if cfg.window else None
+    k1, k2 = jax.random.split(jax.random.PRNGKey(5))
+    kv = {"k": jax.random.normal(k1, (cfg.n_layers, T, cfg.kv_heads, cfg.head_dim)),
+          "v": jax.random.normal(k2, (cfg.n_layers, T, cfg.kv_heads, cfg.head_dim))}
+    row = np.zeros((MAX_LEN // PAGE,), np.int32)
+    row[:26] = 1 + np.random.default_rng(3).permutation(40)[:26]
+    held = None if ring is None else jnp.asarray(
+        1 + np.random.default_rng(2).permutation(ring), jnp.int32)
+    n, first = jnp.int32(T - 5), jnp.int32(17)
+
+    def fresh():
+        return dp.init_paged_state(cfg, 2, MAX_LEN, 48, PAGE, ring=ring)
+
+    want = dp.insert_sequence_paged(fresh(), 1, kv, n, first, jnp.asarray(row), cfg, held)
+    got = fresh()
+    for start in range(0, T, chunk):
+        part = {x: t[:, start:start + chunk] for x, t in kv.items()}
+        ids = jnp.asarray(row[start // PAGE:(start + chunk) // PAGE])
+        got = dp.write_kv_pages(got, part, ids, held,
+                                None if held is None else jnp.int32(start))
+    got = dp.activate_slot(got, 1, jnp.asarray(row), n, first, held)
+    assert set(got) == set(want) and ("wkp" in want) == (family == "mellum")
+    for name in want:
+        a, b = np.asarray(got[name]), np.asarray(want[name])
+        if name.endswith("p"):
+            a, b = a[:, 1:], b[:, 1:]
+            assert np.abs(b).max() > 0, name
+        assert np.array_equal(a, b), name
+
+
 # ------------------------------------------------------------ the engine
 
 

@@ -224,6 +224,83 @@ def test_window_and_full_decode_step_holds_both_kinds_of_pool_once(chip):
     assert calls.count("grouped_matmul") == 3 * 2 and "ragged-dot" not in prefill.as_text()
 
 
+SERVE_CACHES = ["mellum2-12b-a2.5b", "mixtral-8x7b", "kimi-vl-a3b", "ouro-2.6b"]
+
+
+def _serve_cache(name):
+    """(cfg, the arguments of `init_paged_state` after it, the longest prompt
+    bucket a writer sees, the pools' shapes) of a serve configuration as its
+    cell runs it: only the cache's shapes matter here, so the depth is the
+    file's and nothing else of the model is built."""
+    from ray_tpu import models
+
+    bf = jnp.bfloat16
+    if name == "mellum2-12b-a2.5b":    # both kinds of pool, a ring of 33 for each of 48 slots
+        cfg = models.mellum_config("12b-a2.5b", n_layers=12, param_dtype=bf, max_seq_len=16640)
+        full, ring = (3, 2688, 64, 4, 128), (9, 1585, 64, 4, 128)
+        return cfg, (48, 16640, 2688, 64, 48 * 33 + 1, 33), 1024, {
+            "kp": full, "vp": full, "wkp": ring, "wvp": ring}
+    if name == "mixtral-8x7b":
+        cfg = models.mixtral_config("8x7b", n_layers=4, param_dtype=bf, max_seq_len=8320)
+        return cfg, (32, 8320, 1024, 64), 1024, dict.fromkeys(("kp", "vp"), (4, 1024, 64, 8, 128))
+    if name == "kimi-vl-a3b":          # one pool of latent rows
+        cfg = models.kimi_vl_config("a3b", n_layers=9, param_dtype=bf, max_seq_len=16640)
+        return cfg, (32, 16640, 3328, 64), 1024, {"kp": (9, 3328, 64, 640)}
+    cfg = models.ouro_config("2.6b", param_dtype=bf, max_seq_len=1088)   # 192 planes
+    return cfg, (16, 1088, 88, 64), 512, dict.fromkeys(("kp", "vp"), (192, 88, 64, 16, 128))
+
+
+@pytest.mark.parametrize("writer", ["write_kv_pages", "insert_sequence_paged",
+                                    "insert_sequence_paged_prefix"])
+@pytest.mark.parametrize("name", SERVE_CACHES)
+def test_page_writers_update_the_pools_where_they_lie(chip, name, writer):
+    """The three programs that put whole pages into the pools, at every serve
+    configuration's shapes (`mellum2`: both kinds of pool and a ring of 33; a
+    latent pool; a looped stack's 192 planes): every pool aliased input to
+    output, temporaries under 1 % of the smallest pool, no `copy` of a pool's
+    shape in the compiled text. As one scatter along the page axis
+    (`pool.at[:, ids].set`) the `mellum2` programs held 954,035,712 /
+    954,422,784 / 954,422,784 bytes of temporaries and eight pool-sized
+    copies: a pool whose (Hkv, Dh) tile is (4, 128) was re-laid whole, in
+    and out, around the scatter (18.0 ms a call on the chip: PERF.md
+    section 6, PR 39); `_set_pages` writes a page at a time in place."""
+    import math
+    import re
+
+    from ray_tpu.models import decoding_paged as dp
+
+    cfg, cache, bucket, shapes = _serve_cache(name)
+
+    def sds(s, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(s, dt, sharding=chip)
+
+    state = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                         jax.eval_shape(lambda: dp.init_paged_state(cfg, *cache)))
+    pools = {k: v for k, v in state.items() if k in ("kp", "vp", "wkp", "wvp")}
+    assert {k: v.shape for k, v in pools.items()} == shapes
+    rows = (cfg.latent_lanes,) if cfg.mla else (cfg.kv_heads, cfg.head_dim)
+    kv = {x: sds((cfg.n_planes, bucket, *rows), cfg.dtype) for x in ("k" if cfg.mla else "kv")}
+    ids, row = sds((bucket // 64,)), sds(state["block"].shape[1:])
+    ring = sds(state["wblock"].shape[1:]) if cfg.window else None
+    if writer == "write_kv_pages":
+        lowered = dp.write_kv_pages.lower(state, kv, ids, ring, sds(()) if cfg.window else None)
+    elif writer == "insert_sequence_paged":
+        lowered = dp.insert_sequence_paged.lower(state, sds(()), kv, sds(()), sds(()), row,
+                                                 cfg, ring)
+    else:
+        lowered = dp.insert_sequence_paged_prefix.lower(
+            state, sds(()), kv, ids, row, sds(()), sds(()), cfg, ring)
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    nbytes = [2 * math.prod(v.shape) for v in pools.values()]
+    assert m.alias_size_in_bytes >= sum(nbytes)
+    assert m.temp_size_in_bytes < min(nbytes) // 100
+    text = compiled.as_text()
+    for shape in {v.shape for v in pools.values()}:
+        dims = ",".join(map(str, shape))
+        assert not re.search(r"= bf16\[" + dims + r"\]\{[^}]*\} copy\(", text), shape
+
+
 def test_mixtral_prefill_chunk_multiplies_the_experts_where_they_lie(chip):
     """The 1,024-token prefill of `mixtral-8x7b.doc-saturated` (4 layers,
     published widths): three `grouped_matmul` kernels in the layer scan, at
