@@ -5,8 +5,10 @@ the traffic file. Latencies are timed from the instant a request was due.
 
 The replica is a process of its own and holds the chip; this process stays
 off JAX. Counters come from `TPUEngine.stats()` before and after the
-window; the device trace, in a traced run, from the hook that
-`chipbench.program.SeededLLMConfig` starts inside the replica.
+window. A traced run traces a SLICE of the window (the traffic file's
+`trace` group), through the hook that `chipbench.program.SeededLLMConfig`
+starts inside the replica, and reads the counters once more at each end of
+the slice (`TraceSlice`); it comes back with a device trace or raises.
 """
 
 from __future__ import annotations
@@ -102,6 +104,119 @@ def warm_up(client: gen.Client, warmup: list, seed: int, wave: int = 0) -> list:
     return answers
 
 
+# `stop_trace` takes about 0.14 ms a device event on a serving replica: 21-46 s
+# for the five cells' slices (PERF.md section 6, PR 41). Ten times the usual
+# 25-30 s; past it the run is an error that names the remedy
+STOP_TRACE_LIMIT_S = 300.0
+START_TRACE_LIMIT_S = 60.0
+DEVICE_SPAN_FLOOR = 0.8  # of the slice: under it the device's buffers ran full
+
+
+def trace_slice(mix: dict, seconds: float) -> tuple:
+    """(offset_s, seconds) of the traced slice: the traffic file's `trace`
+    group, cut in proportion where the window is shorter than the two
+    together (the CPU rehearsal's) so that the slice ends inside it."""
+    group = mix.get("trace")
+    if not group:
+        raise harness.BenchError(
+            "the traffic file has no `trace` group {\"offset_s\", \"seconds\"}: a "
+            "traced serve run traces a slice of its window and takes no default")
+    offset, length = float(group["offset_s"]), float(group["seconds"])
+    if offset + length > seconds:
+        scale = 0.9 * seconds / (offset + length)
+        offset, length = offset * scale, length * scale
+    return offset, length
+
+
+class TraceSlice(threading.Thread):
+    """Beside the open loop: `start` after `offset_s` of the window, the
+    counters once the hook says `started`, `stop` after `seconds` more, the
+    counters again, then the wait for the hook's `done`. `result()` gives
+    {"stats_t0", "stats_t1", how long each reading took, "trace_span_s",
+    "trace_stop_s"} or raises what went wrong here."""
+
+    def __init__(self, ctl: str, client: gen.Client, offset_s: float, seconds: float):
+        super().__init__(daemon=True, name="chipbench-trace-slice")
+        self.ctl, self.client = ctl, client
+        self.offset_s, self.seconds = offset_s, seconds
+        self.reads, self.facts, self.error = {}, None, None
+
+    def _await(self, name: str, limit_s: float, what: str, remedy: str = "") -> None:
+        deadline = time.monotonic() + limit_s
+        while not os.path.exists(os.path.join(self.ctl, name)):
+            if time.monotonic() > deadline:
+                raise harness.BenchError(
+                    f"{what} within {limit_s:.0f} s (the slice: {self.seconds:.1f} s, "
+                    f"{self.offset_s:.1f} s into the window){remedy}")
+            time.sleep(0.02)
+
+    def _read(self, key: str) -> None:
+        t = time.perf_counter()
+        self.reads[key] = self.client.post("/v1/stats", {})["answer"]
+        self.reads[key + "_took_s"] = time.perf_counter() - t
+
+    def run(self) -> None:
+        try:
+            time.sleep(self.offset_s)
+            open(os.path.join(self.ctl, "start"), "w").close()
+            self._await("started", START_TRACE_LIMIT_S,
+                        "the replica's trace hook did not start")
+            t0 = time.perf_counter()
+            # in a thread of its own: a replica with a queue answers seconds
+            # late (its `loop.thread_s` says when), and `stop` does not wait
+            first = threading.Thread(target=self._read, args=("stats_t0",), daemon=True)
+            first.start()
+            time.sleep(self.seconds)
+            open(os.path.join(self.ctl, "stop"), "w").close()
+            span, stopped = time.perf_counter() - t0, time.time()
+            self._read("stats_t1")
+            first.join(self.client.timeout_s)
+            if "stats_t0" not in self.reads:
+                raise harness.BenchError("no answer to `/v1/stats` at the slice's start")
+            self._await("done", STOP_TRACE_LIMIT_S,
+                        "`jax.profiler.stop_trace()` in the replica did not return",
+                        "; it takes about 0.14 ms a device event there: shorten "
+                        "`trace.seconds` in the traffic file")
+            with open(os.path.join(self.ctl, "done")) as f:
+                done = float(f.read())
+            self.facts = {**self.reads, "trace_span_s": span,
+                          "trace_stop_s": done - stopped}
+        except Exception as e:  # noqa: BLE001 — raised again in `result`
+            self.error = e
+
+    def result(self) -> dict:
+        self.join(self.offset_s + self.seconds + START_TRACE_LIMIT_S
+                  + STOP_TRACE_LIMIT_S + 30.0)
+        if self.error is not None:
+            raise self.error
+        if self.facts is None:
+            raise harness.BenchError("the trace slice's thread did not end")
+        return self.facts
+
+
+def reduce_trace(trace_ctl: str, out_dir: str, span_s: float, on_chip: bool):
+    """The slice's device trace as numbers, or the error that says why there
+    is none. Only the CPU rehearsal, which has no device plane, gets None."""
+    from chipbench import trace_reduce
+
+    trace = trace_reduce.reduce_dir(
+        os.path.join(trace_ctl, "trace"),
+        keep_rows=os.path.join(out_dir, "trace_rows.json"))
+    shutil.rmtree(trace_ctl, ignore_errors=True)  # tens of MB a run
+    if not on_chip:
+        return trace
+    if trace is None:
+        raise harness.BenchError(
+            "the traced slice left no device trace: no `.xplane.pb` under "
+            f"{trace_ctl}/trace, or no device plane with events in it")
+    if trace["window_s"] < DEVICE_SPAN_FLOOR * span_s:
+        raise harness.BenchError(
+            f"the device's trace was cut short: its buffers held "
+            f"{trace['device_events']:.0f} events over {trace['window_s']:.2f} s of a "
+            f"{span_s:.2f} s slice; shorten `trace.seconds` in the traffic file")
+    return trace
+
+
 def run(cell: dict, args, out_dir: str, t_start: float, *,
         on_chip: bool = True) -> dict:
     """`on_chip=False` is the CPU rehearsal of the tests: the same path with
@@ -111,10 +226,12 @@ def run(cell: dict, args, out_dir: str, t_start: float, *,
     from ray_tpu.llm.tokenizer import load_tokenizer
 
     conf, mix = cell["config_file"], cell["traffic_file"]
-    trace_ctl = None
+    trace_ctl, tracing, sliced = None, None, {}
     if args.trace:
+        offset_s, slice_s = trace_slice(mix, args.seconds)
         trace_ctl = os.path.join(out_dir, "trace_ctl")
-        os.makedirs(trace_ctl, exist_ok=True)
+        shutil.rmtree(trace_ctl, ignore_errors=True)  # a marker of an earlier run
+        os.makedirs(trace_ctl)
     requests = gen.schedule(mix, args.seed, args.seconds)
     ray_tpu.init(num_tpus=cell["chips"] if on_chip else None)
     try:
@@ -132,25 +249,15 @@ def run(cell: dict, args, out_dir: str, t_start: float, *,
                 "prompt": text, "max_tokens": check["positions"],
                 "temperature": 0.0})
             stats0, t_stats0 = client.post("/v1/stats", {})["answer"], time.perf_counter()
-            if trace_ctl:
-                open(os.path.join(trace_ctl, "start"), "w").close()
-                deadline = time.monotonic() + 60.0
-                while not os.path.exists(os.path.join(trace_ctl, "started")):
-                    if time.monotonic() > deadline:
-                        raise harness.BenchError("the replica's trace hook did not start")
-                    time.sleep(0.02)
             setup_s = time.time() - t_start
-            records, window_s = gen.open_loop(client, requests, args.seconds)
             if trace_ctl:
-                open(os.path.join(trace_ctl, "stop"), "w").close()
+                tracing = TraceSlice(trace_ctl, client, offset_s, slice_s)
+                tracing.start()
+            records, window_s = gen.open_loop(client, requests, args.seconds)
             stats1 = client.post("/v1/stats", {})["answer"]
             stats_span_s = time.perf_counter() - t_stats0  # the drain included
-            if trace_ctl:
-                deadline = time.monotonic() + 120.0
-                while not os.path.exists(os.path.join(trace_ctl, "done")):
-                    if time.monotonic() > deadline:
-                        break
-                    time.sleep(0.05)
+            if tracing:
+                sliced = tracing.result()
         finally:
             serve.shutdown()
         with open(os.path.join(out_dir, "requests.jsonl"), "w") as f:
@@ -176,16 +283,11 @@ def run(cell: dict, args, out_dir: str, t_start: float, *,
              "stats0": stats0, "stats1": stats1, "stats_span_s": stats_span_s,
              "client": summary,
              "compiles_in_window": compiles, "check": verdict,
-             "rate_rps": mix["rate_rps"], "requests": len(requests)}
+             "rate_rps": mix["rate_rps"], "requests": len(requests), **sliced}
     breakdown = None
     if trace_ctl:
-        from chipbench import trace_reduce
-
-        trace = trace_reduce.reduce_dir(
-            os.path.join(trace_ctl, "trace"),
-            keep_rows=os.path.join(out_dir, "trace_rows.json"))
-        shutil.rmtree(trace_ctl, ignore_errors=True)  # tens of MB a run
-        facts["trace"] = trace
+        trace = facts["trace"] = reduce_trace(
+            trace_ctl, out_dir, sliced["trace_span_s"], on_chip)
         if trace:
             device.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
             breakdown = trace["breakdown"]

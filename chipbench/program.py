@@ -59,7 +59,10 @@ def init_params(cfg, seed: int, shardings=None):
 def _trace_on_request(ctl_dir: str) -> None:
     """Runs in the process that holds the chip: trace while `<ctl>/start`
     exists and `<ctl>/stop` does not. Only the chip's holder can trace it,
-    and the replica is a process of its own."""
+    and the replica is a process of its own. The Python tracer is off: with
+    it on the host's time a step read 2.4-2.7 times higher and `stop_trace`
+    took minutes on a loaded replica (PERF.md section 6, PRs 24 and 41); the
+    host tracer stays on, so the engine's `TraceAnnotation`s are in the trace."""
     import jax
 
     start, stop = os.path.join(ctl_dir, "start"), os.path.join(ctl_dir, "stop")
@@ -67,14 +70,17 @@ def _trace_on_request(ctl_dir: str) -> None:
         if os.path.exists(stop):
             return
         time.sleep(0.02)
-    jax.profiler.start_trace(os.path.join(ctl_dir, "trace"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(os.path.join(ctl_dir, "trace"), profiler_options=options)
     with open(os.path.join(ctl_dir, "started"), "w") as f:
         f.write(repr(time.time()))
     while not os.path.exists(stop):
         time.sleep(0.02)
     jax.profiler.stop_trace()
-    with open(os.path.join(ctl_dir, "done"), "w") as f:
+    with open(os.path.join(ctl_dir, "done.tmp"), "w") as f:
         f.write(repr(time.time()))
+    os.replace(os.path.join(ctl_dir, "done.tmp"), os.path.join(ctl_dir, "done"))
 
 
 @dataclasses.dataclass

@@ -1,9 +1,9 @@
 """A per-head (grouped-query) decode-attention launch's share of its roofline
-over the traced window: the least time the chip could take for the positions
-the engine counted / the device time of the launch's ops, found by
-`pallas_call(name=...)` among `facts["trace"]["breakdown"]["device_ops"]`.
-None when no op of that name is there (no trace, or a program without the
-launch, as the parent of the PR that named it).
+over the traced slice: the least time the chip could take for the positions
+the engine counted between the slice's two ends / the device time of the
+launch's ops, found by `pallas_call(name=...)` among the trace's Pallas
+launches (`readers.launch_seconds`). None when no op of that name ran (no
+trace, or a program without the launch, as the parent of the PR that named it).
 
 params: {"op": the kernel's name, "work": the dotted path of the counter in
 a `stats()` reading that the positions are the delta of (positions ONE layer
@@ -16,7 +16,7 @@ inside it the scan of a period's window layers), so a launch is ONE op
 summed (one of each compiled decode program)."""
 
 from chipbench import flops, harness
-from chipbench.readers import dig
+from chipbench.readers import launch_seconds, slice_delta
 
 
 def gqa_decode_attention_cost(positions: float, heads: int, kv_heads: int,
@@ -31,13 +31,11 @@ def gqa_decode_attention_cost(positions: float, heads: int, kv_heads: int,
 
 
 def read(facts: dict, params: dict):
-    ops = dig(facts, "trace.breakdown.device_ops") or []
-    spent = [s for name, s in ops
-             if name.split(":")[-1].split(".")[0] == params["op"]]
-    ends = [dig(facts.get(k) or {}, params["work"]) for k in ("stats0", "stats1")]
-    if not spent or None in ends or not sum(spent):
+    spent = launch_seconds(facts, params["op"])
+    work = slice_delta(facts, params["work"])
+    if work is None or not sum(spent):
         return None
-    cost = gqa_decode_attention_cost((ends[1] - ends[0]) * params["layers"], params["heads"],
+    cost = gqa_decode_attention_cost(work * params["layers"], params["heads"],
                                      params["kv_heads"], params["head_dim"])
     peaks = harness.peaks_for(facts["stats1"]["device"]["kind"])
     least, bound = flops.roofline_seconds(cost["flops"], cost["bytes"], peaks)

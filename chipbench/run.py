@@ -5,7 +5,8 @@
 `--trace 0` prints the cell's end-to-end metrics, `--trace 1` its per-layer
 metrics (a run of its own: tracing slows the host). Exits non-zero and prints
 no result when the host has fewer TPU chips than the cell asks for, when JAX
-in the chip's worker finds another backend, or when the program is missing.
+in the chip's worker finds another backend, when the program is missing, or
+when the run cannot give what was asked of it (a traced run without a trace).
 This process never touches JAX's backend: a chip belongs to one process, the
 worker the runtime binds it to.
 """
@@ -61,7 +62,11 @@ def main(argv=None) -> int:
     harness.prepare_env()
 
     runner = harness.kind_runner(cell["traffic_file"]["kind"])
-    result = runner.run(cell, args, out_dir, T_START)
+    try:
+        result = runner.run(cell, args, out_dir, T_START)
+    except harness.BenchError as e:  # no result line: the reason and a code
+        print(f"chipbench: {e}", file=sys.stderr, flush=True)
+        return 4
     harness.peaks_for(result["device"]["kind"])
     if (result["device"]["platform"] != "tpu"
             or result["device"]["count"] != cell["chips"]):

@@ -151,20 +151,21 @@ def test_cell_rehearsal(tmp_path, workers_see_the_repo):
                                           (["ragged_latent_attention.10",
                                             "ragged_latent_attention.3"], 9), ([], None)])
 def test_kernel_roofline_reads_the_named_op_and_the_counter(found, layers):
-    """The share is least time / the named ops' device time; an op of the
-    smaller layer scan that is not among the ten largest takes its layers'
-    work out of the count; no op of that name (the parent's program, a run
-    without a trace): nothing to read."""
+    """The share is least time / the named ops' device time over the traced
+    slice; a layer scan whose op did not run takes its layers' work out of
+    the count; no op of that name (the parent's program, a run without a
+    trace): nothing to read."""
     from chipbench import kernel_costs
     from chipbench.readers import kernel_roofline
 
     spec = harness.load_json(harness.BENCH_DIR, "layer_metrics",
                              "ragged_latent_attention_roofline_pct.longdoc.json")
-    ops = [["ragged-dot-none", 7.0]] + [[name, 2.0] for name in found]
-    facts = {"stats0": {"cache": {"context_tokens": 1_000_000}},
-             "stats1": {"cache": {"context_tokens": 27_000_000},
-                        "device": {"kind": "TPU v5 lite"}},
-             "trace": {"breakdown": {"device_ops": ops}}}
+    calls = {"grouped_matmul.24": {"calls": 8.0, "seconds": 7.0},
+             **{name: {"calls": 4.0, "seconds": 2.0} for name in found}}
+    facts = {"stats_t0": {"cache": {"context_tokens": 1_000_000}},
+             "stats_t1": {"cache": {"context_tokens": 27_000_000}},
+             "stats1": {"device": {"kind": "TPU v5 lite"}},
+             "trace": {"kernel_calls": calls}}
     got = kernel_roofline.read(facts, spec["params"])
     if not found:
         assert got is None and kernel_roofline.read({}, spec["params"]) is None

@@ -237,10 +237,12 @@ def test_roofline_reads_the_named_ops_and_the_counter(metric, found, layers):
 
     spec = harness.load_json(harness.BENCH_DIR, "layer_metrics", metric + ".json")
     work = spec["params"]["work"].split(".")[1]
-    ops = [["fusion.12", 7.0]] + [[name, 2.0] for name in found]
-    facts = {"stats0": {"cache": {work: 1_000_000}},
-             "stats1": {"cache": {work: 27_000_000}, "device": {"kind": "TPU v5 lite"}},
-             "trace": {"breakdown": {"device_ops": ops}}}
+    calls = {"grouped_matmul.75": {"calls": 8.0, "seconds": 7.0},
+             **{name: {"calls": 4.0, "seconds": 2.0} for name in found}}
+    facts = {"stats_t0": {"cache": {work: 1_000_000}},
+             "stats_t1": {"cache": {work: 27_000_000}},
+             "stats1": {"device": {"kind": "TPU v5 lite"}},
+             "trace": {"kernel_calls": calls}}
     got = reader.read(facts, spec["params"])
     if not found:
         assert got is None and reader.read({}, spec["params"]) is None

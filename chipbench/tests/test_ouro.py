@@ -211,11 +211,11 @@ def test_roofline_reads_the_launch_of_every_plane(found):
         "op": "ragged_paged_attention", "work": "cache.context_tokens",
         "layers": sizes["n_passes"] * sizes["n_layers"], "heads": sizes["n_heads"],
         "kv_heads": sizes["n_kv_heads"], "head_dim": sizes["d_head"]}
-    ops = [["fusion.133", 3.0]] + [[name, 10.0] for name in found]
-    facts = {"stats0": {"cache": {"context_tokens": 100_000}},
-             "stats1": {"cache": {"context_tokens": 2_100_000},
-                        "device": {"kind": "TPU v5 lite"}},
-             "trace": {"breakdown": {"device_ops": ops}}}
+    calls = {name: {"calls": 192.0, "seconds": 10.0} for name in found}
+    facts = {"stats_t0": {"cache": {"context_tokens": 100_000}},
+             "stats_t1": {"cache": {"context_tokens": 2_100_000}},
+             "stats1": {"device": {"kind": "TPU v5 lite"}},
+             "trace": {"kernel_calls": calls}}
     got = reader.read(facts, spec["params"])
     if not found:
         assert got is None and reader.read({}, spec["params"]) is None

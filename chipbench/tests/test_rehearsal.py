@@ -9,7 +9,7 @@ import time
 
 import pytest
 
-from chipbench import harness
+from chipbench import harness, traffic as gen
 from chipbench.kinds import serve, train
 
 import tiny
@@ -52,15 +52,35 @@ def test_train_cell_rehearsal(tmp_path, workers_see_the_repo):
     assert -5 < got["train_stall_pct"]["value"] < 100
 
 
-def test_serve_cell_rehearsal(tmp_path, workers_see_the_repo):
+@pytest.mark.parametrize("trace", [0, 1])
+def test_serve_cell_rehearsal(tmp_path, workers_see_the_repo, monkeypatch, trace):
     cell = tiny.mixtral_cell()
+    stats_calls, timers = [], []
+    post, start = gen.Client.post, serve.TraceSlice.start
+    monkeypatch.setattr(gen.Client, "post", lambda self, path, *a, **k: (
+        stats_calls.append(path) if path == "/v1/stats" else None, post(self, path, *a, **k))[1])
+    monkeypatch.setattr(serve.TraceSlice, "start", lambda self: (
+        timers.append(self), start(self))[1])
     # the tiny warm-up covers the tiny mix: prompts of one bucket, short answers
     cell["traffic_file"]["classes"][0]["prompt"].update(median=20, min=8, max=30)
     cell["traffic_file"]["classes"][0]["output"].update(median=4, min=2, max=6)
     cell["traffic_file"]["warmup"] = [[10, 4], [20, 8], [30, 8]]
-    args = argparse.Namespace(seed=2**31 + 9, seconds=2.0, trace=0)
+    args = argparse.Namespace(seed=2**31 + 9, seconds=2.0, trace=trace)
     r = serve.run(cell, args, str(tmp_path), time.time(), on_chip=False)
     facts = r["facts"]
+    # an untraced run does what it did before there were slices: no marker
+    # directory, no timer thread, the two readings around the window
+    assert (len(stats_calls), len(timers)) == ((4, 1) if trace else (2, 0))
+    if trace:
+        # the timer, the hook, both readings at the slice's ends and the wait
+        # for `done`; the CPU has no device plane, and only it is let off that
+        assert facts["trace"] is None and "busy_s" not in r["device"]
+        assert 0 < facts["trace_span_s"] < 2.0 and 0 <= facts["trace_stop_s"] < 60
+        ends = [facts[k]["decode_steps"] for k in ("stats0", "stats_t0", "stats_t1", "stats1")]
+        assert ends == sorted(ends) and ends[0] < ends[3]
+    else:
+        assert not {"trace", "stats_t0", "stats_t1", "trace_span_s"} & set(facts)
+    assert not os.path.exists(tmp_path / "trace_ctl")
     assert facts["check"]["ok"] and facts["check"]["logits_rel_err_median"] < 1e-4
     assert facts["check"]["positions_within_tol"] == facts["check"]["positions"]
     assert facts["check"]["control_fails"]

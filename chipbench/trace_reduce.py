@@ -2,7 +2,8 @@
 
 1. `rows_from_xplane(path)`: the `.xplane.pb` file -> plain rows, with
    nothing but JAX: for every device plane the events of its op line
-   `[name, start_ns, dur_ns]`, and the host's `chipbench:*` annotations.
+   `[name, start_ns, dur_ns]`, the names among them that are Pallas launches,
+   and the host's `chipbench:*` and `ray_tpu:*` annotations.
 2. `reduce_rows(rows, ...)`: rows -> busy and window seconds, the device
    operations that took most time, the longest idle gaps named by what the
    host was doing, kernel time, exposed collective time.
@@ -20,7 +21,10 @@ import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OP_LINE = "XLA Ops"
-HOST_PREFIX = "chipbench:"
+# the benchmark's own annotations and the engine's (`util/tracing.py`
+# `device_annotation`: `ray_tpu:engine:<phase>`, `ray_tpu:engine:dispatch:<program>`)
+HOST_PREFIXES = ("chipbench:", "ray_tpu:")
+KERNEL_TARGET = "tpu_custom_call"
 COLLECTIVE = re.compile(
     r"^(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)")
 GAP_FLOOR_NS = 20_000  # a gap shorter than 20 us is launch spacing, not idling
@@ -36,19 +40,24 @@ def rows_from_xplane(path: str) -> dict:
     from jax.profiler import ProfileData
 
     rows = {"devices": {}, "host": [], "lines": {}}
+    kernels = set()
     for plane in ProfileData.from_file(path).planes:
         m = DEVICE_PLANE.match(plane.name)
         for line in plane.lines:
             if m:
                 rows["lines"].setdefault(plane.name, []).append(line.name)
                 if line.name == OP_LINE:
-                    rows["devices"][m.group(1)] = [
-                        [op_name(e.name), int(e.start_ns), int(e.duration_ns)]
-                        for e in line.events]
+                    events = rows["devices"][m.group(1)] = []
+                    for e in line.events:
+                        text = e.name  # the whole HLO instruction: read it once
+                        events.append([op_name(text), int(e.start_ns), int(e.duration_ns)])
+                        if KERNEL_TARGET in text:
+                            kernels.add(events[-1][0])
             elif plane.name.startswith("/host:"):
                 rows["host"].extend(
                     [e.name, int(e.start_ns), int(e.duration_ns)]
-                    for e in line.events if e.name.startswith(HOST_PREFIX))
+                    for e in line.events if e.name.startswith(HOST_PREFIXES))
+    rows["kernels"] = sorted(kernels)
     return rows
 
 
@@ -86,19 +95,34 @@ def _union(intervals: list) -> list:
     return out
 
 
-def _host_name_at(host: list, t: int) -> str:
-    """The innermost `chipbench:*` annotation open at time t."""
-    best = None
-    for name, s, d in host:
-        if s <= t < s + d and (best is None or d < best[1]):
-            best = (name, d)
-    return best[0][len(HOST_PREFIX):] if best else "no_annotation"
+def _name_gaps(host: list, gaps: list) -> dict:
+    """Idle nanoseconds by the innermost (shortest) host annotation open at
+    each gap's middle, its prefix taken off. One sweep over both in time
+    order: a serve slice holds tens of thousands of engine spans."""
+    spans = sorted(host, key=lambda h: h[1])
+    out: dict = {}
+    open_spans, i = [], 0
+    for length, mid in sorted(gaps, key=lambda g: g[1]):
+        while i < len(spans) and spans[i][1] <= mid:
+            open_spans.append(spans[i])
+            i += 1
+        open_spans = [h for h in open_spans if h[1] + h[2] > mid]
+        name = "no_annotation"
+        if open_spans:
+            name = min(open_spans, key=lambda h: h[2])[0]
+            name = next(name[len(p):] for p in HOST_PREFIXES if name.startswith(p))
+        out[name] = out.get(name, 0) + length
+    return out
 
 
 def reduce_rows(rows: dict, kernel_ops: dict | None = None) -> dict | None:
     """`kernel_ops` maps an op name of the compiled program to the label of
-    the Pallas kernel it calls (from the program's HLO text)."""
-    kernel_ops = kernel_ops or {}
+    the Pallas kernel it calls (from the program's HLO text). Without it the
+    kernels are the ops the trace itself marks as Pallas launches
+    (`rows["kernels"]`), each under its own name: `kernel_calls` then holds
+    every one of them whatever its rank among the device ops."""
+    if kernel_ops is None:
+        kernel_ops = {n: n for n in rows.get("kernels", [])}
     devices = {k: v for k, v in rows["devices"].items() if v}
     if not devices:
         return None
@@ -115,7 +139,8 @@ def reduce_rows(rows: dict, kernel_ops: dict | None = None) -> dict | None:
         coll_ns.append(sum(d for n, _, d in events if COLLECTIVE.match(n)))
         kernel_ns.append(sum(d for n, _, d in events if n in kernel_ops))
         for (n, _, d), own in zip(events, self_times(events)):
-            key = ("pallas:" + kernel_ops[n] + ":" + n) if n in kernel_ops else n
+            key = ("pallas:" + kernel_ops[n] + ":" + n
+                   if kernel_ops.get(n, n) != n else n)
             op_time[key] = op_time.get(key, 0) + own
             if n in kernel_ops:
                 c = kernel_calls.setdefault(kernel_ops[n], [0, 0])
@@ -125,10 +150,7 @@ def reduce_rows(rows: dict, kernel_ops: dict | None = None) -> dict | None:
             if s1 - e0 >= GAP_FLOOR_NS:
                 gaps.append((s1 - e0, (s1 + e0) // 2))
     n = len(devices)
-    gap_by_name: dict = {}
-    for length, mid in gaps:
-        name = _host_name_at(rows["host"], mid)
-        gap_by_name[name] = gap_by_name.get(name, 0) + length
+    gap_by_name = _name_gaps(rows["host"], gaps)
 
     def top(seconds_by_name: dict) -> list:
         return [[k, v / n / 1e9] for k, v in
@@ -136,6 +158,7 @@ def reduce_rows(rows: dict, kernel_ops: dict | None = None) -> dict | None:
 
     return {
         "devices": n,
+        "device_events": sum(len(events) for events in devices.values()) / n,
         "busy_s": sum(busy_ns) / n / 1e9,
         "window_s": sum(window_ns) / n / 1e9,
         "collective_s": sum(coll_ns) / n / 1e9,
@@ -173,6 +196,7 @@ def reduce_dir(trace_dir: str, kernel_ops: dict | None = None,
     rows = rows_from_xplane(path)
     if keep_rows:
         with open(keep_rows, "w") as f:
-            json.dump({"lines": rows["lines"], "host": rows["host"][:200],
+            json.dump({"lines": rows["lines"], "kernels": rows["kernels"],
+                       "host": rows["host"][:200],
                        "devices": {k: v[:2000] for k, v in rows["devices"].items()}}, f)
     return reduce_rows(rows, kernel_ops)
