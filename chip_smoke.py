@@ -29,6 +29,7 @@ No time printed here is a benchmark.
 from __future__ import annotations
 
 import argparse
+import functools
 import glob
 import json
 import os
@@ -56,7 +57,9 @@ KERNELS = dict(flash=(2, 20, 1024, 64),
                    kimi_vl_a3b=dict(tokens=1024, top_k=6, experts=64, layers=8,
                                     d_model=2048, d_ff=1408),
                    mixtral_8x7b=dict(tokens=1024, top_k=2, experts=8, layers=4,
-                                     d_model=4096, d_ff=14336)))
+                                     d_model=4096, d_ff=14336)),
+               # a row's recurrent state of granite-4.0-h-micro, 96 slots
+               state_update=dict(layers=3, slots=96, heads=64, head_dim=64, d_state=128))
 
 
 class SmokeFailure(AssertionError):
@@ -165,7 +168,67 @@ def compare_grouped_matmul(shapes: dict, interpret: bool = False) -> dict:
     return out
 
 
-def compare_kernels(flash, ragged, grouped=None, interpret: bool = False) -> dict:
+def compare_state_update(shape: dict, interpret: bool = False) -> dict:
+    """`ops.ssm_state_update`'s Pallas kernel against its `jax.numpy` form on
+    a state of several layers and many slots, both updated where they lie,
+    over steps whose live rows are scattered and change: a third of the slots,
+    others released and taken again (a taken slot starts from a fresh state,
+    as an insert leaves it), one row in the last slot, none, all. After every
+    step the WHOLE state leaf against leaf: the stepped layer's live rows
+    within float32 rounding, its other rows and every other layer bit for
+    bit; y within rounding on the live rows and 0 elsewhere."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu import ops
+
+    L, R, H, P, N = (shape[k] for k in ("layers", "slots", "heads", "head_dim", "d_state"))
+    rng = np.random.default_rng(0)
+    f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+    states = {impl: f32(L, R, H, P, N) for impl in ("kernel",)}
+    states["reference"] = states["kernel"] + 0
+    A = -jnp.asarray(rng.uniform(1.0, 16.0, (H,)), jnp.float32)
+    step = {impl: jax.jit(functools.partial(ops.ssm_state_update, impl=impl,
+                                            **({"interpret": interpret}
+                                               if impl == "kernel" else {})),
+                          donate_argnums=(0,))
+            for impl in states}
+    third = rng.permutation(R)[:R // 3]
+    again = np.concatenate([third[:len(third) // 2], rng.permutation(R)[:R // 4]])
+    lives = [third, np.unique(again), np.asarray([R - 1]), np.asarray([], int), np.arange(R)]
+    out, before = {"shape": dict(shape), "steps": []}, set()
+    for rows in lives:
+        live = np.zeros(R, bool)
+        live[rows] = True
+        taken = sorted(set(rows.tolist()) - before)
+        before = set(rows.tolist())
+        layer = jnp.int32(L // 2)
+        x, dt, B, C = f32(R, H, P), jnp.abs(f32(R, H)) * 0.05, f32(R, N), f32(R, N)
+        fresh, ys = f32(len(taken), H, P, N), {}
+        for impl in states:
+            s = states[impl].at[L // 2, jnp.asarray(taken, jnp.int32)].set(fresh)
+            states[impl], ys[impl] = step[impl](s, layer, x, dt, A, B, C, live=jnp.asarray(live))
+        got, want = (np.asarray(states[i]) for i in ("kernel", "reference"))
+        y_got, y_want = (np.asarray(ys[i]) for i in ("kernel", "reference"))
+        dead = np.ones((L, R), bool)
+        dead[L // 2, live] = False
+        tol = 8 * float(np.finfo(np.float32).eps)
+        out["steps"].append({
+            "live": int(live.sum()), "taken": len(taken),
+            "state_rel_err": float(np.abs(got - want).max() / np.abs(want).max()),
+            "y_rel_err": float(np.abs(y_got - y_want).max() / max(np.abs(y_want).max(), 1.0)),
+            "others_bit_equal": bool((got[dead] == want[dead]).all()),
+            "dead_y_zero": bool((y_got[~live] == 0).all())})
+    out["max_abs_err"] = max(max(s["state_rel_err"], s["y_rel_err"]) for s in out["steps"])
+    out["tol"] = N * tol   # y sums N products in another order
+    out["ok"] = bool(out["max_abs_err"] <= out["tol"]
+                     and all(s["others_bit_equal"] and s["dead_y_zero"] for s in out["steps"]))
+    return out
+
+
+def compare_kernels(flash, ragged, grouped=None, state_update=None,
+                    interpret: bool = False) -> dict:
     """The Pallas kernels against their pure-JAX references at the given
     shapes, bf16. `interpret` is for the CPU rehearsal only."""
     import jax
@@ -221,6 +284,8 @@ def compare_kernels(flash, ragged, grouped=None, interpret: bool = False) -> dic
     out["ragged_shape"] = dict(ragged)
     out["ragged"] = {**report(got, want), "bit_equal": bool((got == want).all())}
     out.update(compare_grouped_matmul(grouped or {}, interpret))
+    if state_update:
+        out["state_update"] = compare_state_update(state_update, interpret)
     return out
 
 

@@ -69,7 +69,7 @@ class PDConfig:
 class LLMConfig:
     model_loading_config: ModelLoadingConfig = field(default_factory=ModelLoadingConfig)
     # TransformerConfig kwargs for the built-in families (gpt2/llama/mixtral/
-    # kimi_vl/mellum/ouro)
+    # kimi_vl/mellum/ouro/granite)
     model_family: str = "llama"
     model_kwargs: dict = field(default_factory=dict)
     engine_kwargs: dict = field(default_factory=dict)  # TPUEngine keywords:
@@ -113,7 +113,8 @@ class LLMConfig:
                    "mixtral": models.mixtral_config,
                    "kimi_vl": models.kimi_vl_config,
                    "mellum": models.mellum_config,
-                   "ouro": models.ouro_config}[self.model_family]
+                   "ouro": models.ouro_config,
+                   "granite": models.granite_config}[self.model_family]
         cfg = factory(self.model_loading_config.model_id, **self.model_kwargs)
         src = self.model_loading_config.model_source
         if src:

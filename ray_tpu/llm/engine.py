@@ -83,6 +83,8 @@ class _Request:
     pf_done: int = 0
     pf_pages: list | None = None
     pf_hashes: list | None = None
+    # state-space layers: the recurrent state after the chunks run so far
+    pf_state: dict | None = None
     # request-phase stamps (wall clock): submit → slot and pages granted
     # (scheduled) is the queue wait, → first token the prefill, → release
     # the decode; submit → decode-slot bind is the admission wait; _emit
@@ -397,13 +399,15 @@ class TPUEngine:
                 "by the model config)")
         self.max_slots = max_slots
         if (cfg.mla or cfg.n_dense_layers or cfg.window or cfg.n_passes > 1
-                or cfg.sandwich_norms):
+                or cfg.sandwich_norms or cfg.ssm or cfg.kv_packed):
             # what is not carried to the latent cache, to two kinds of layer
-            # in one stack and to a stack run several times: refused here,
-            # not at the first request
+            # in one stack, to a stack run several times and to a recurrent
+            # state: refused here, not at the first request
             kind = ("latent attention (kv_lora_rank)" if cfg.mla
                     else "window layers" if cfg.window
                     else "leading dense layers" if cfg.n_dense_layers
+                    else "state-space layers (a recurrent state a row)" if cfg.ssm
+                    else "KV heads packed a row of 128 lanes (kv_packed)" if cfg.kv_packed
                     else "a looped stack (n_passes) or sandwich norms")
             for on, what in ((mesh is not None, "a tensor-parallel mesh"),
                              (max_loras, "max_loras")):
@@ -419,6 +423,12 @@ class TPUEngine:
                 "enable_prefix_cache: a cached block would also have to pin "
                 "the window layers' pages under it, and those lie in a ring "
                 "the row writes over as it grows")
+        if cfg.ssm and enable_prefix_cache:
+            raise ValueError(
+                "a model with state-space layers is served without "
+                "enable_prefix_cache: a cached block is reusable only with the "
+                "recurrent state at its end, and no snapshot of that state is "
+                "kept beside a block's pages")
         if max_loras and (enable_prefix_cache or prefill_chunk is not None):
             raise ValueError(
                 "max_loras cannot be combined with enable_prefix_cache or "
@@ -584,6 +594,12 @@ class TPUEngine:
             sum(self.state[k].nbytes // self.state[k].shape[1]
                 for k in names if k in self.state)
             for names in (("kp", "vp"), ("wkp", "wvp")))
+        # state-space layers: a slot IS a state slot (decoding_paged.py), held
+        # from admission to release whatever the row's length, so a free
+        # slot is what admits a row and pages are the small part
+        self._state_bytes_per_row = sum(
+            self.state[k].nbytes // max_slots for k in ("ssm", "conv")
+            if k in self.state)
         # padded tokens of the dispatched calls by the form their expert
         # layers took (stats()["experts"]); a dense model counts neither
         self.expert_tokens_sorted = 0
@@ -877,14 +893,17 @@ class TPUEngine:
           error; the slot and its granted pages are reclaimed.
         """
         self._check_alive()
-        if self.cfg.mla or self.cfg.window or self.cfg.n_passes > 1:
+        if (self.cfg.mla or self.cfg.window or self.cfg.n_passes > 1 or self.cfg.ssm
+                or self.cfg.kv_packed):
             raise NotImplementedError(
                 "submit_prefilled: the PD transfer plane (llm/pd.py, "
                 "kv_transfer.py) moves per-head K and V pages of one kind of "
                 "layer, a plane a layer; a model with latent attention caches "
                 "one row a token, one with window layers a ring of pages on "
                 "those layers, a looped stack a plane for every pass of every "
-                "layer, and none is carried over it")
+                "layer, one with state-space layers a recurrent state a row "
+                "beside its pages (and packed KV rows, kv_packed, are not the "
+                "plane's [L, page, Hkv, Dh]), and none is carried over it")
         params = params or SamplingParams()
         paged_form = k_pages is not None or v_pages is not None
         if kv_stream is not None:
@@ -1717,6 +1736,9 @@ class TPUEngine:
         with dispatch("h2d"):
             padded = jnp.asarray(padded)
             chunk_pages = jnp.asarray(chunk_pages)
+        # a recurrent state rides from chunk to chunk with the request and
+        # enters its slot when the row goes live
+        carried = {} if req.pf_state is None else {"row_state": req.pf_state}
         if done == 0:
             with dispatch("prefill"):
                 logits, kv = decoding.prefill(
@@ -1740,7 +1762,9 @@ class TPUEngine:
             with dispatch("prefill_with_prefix"):
                 logits, kv = dp.prefill_with_prefix(
                     self.params, padded, k_pre, v_pre, jnp.int32(done),
-                    jnp.int32(len(chunk_toks)), self.cfg, *window)
+                    jnp.int32(len(chunk_toks)), self.cfg, *window, **carried)
+        if self.cfg.ssm:
+            req.pf_state = {name: kv.pop(name) for name in ("ssm", "conv")}
         with dispatch("write_pages"):
             self.state = dp.write_kv_pages(
                 self.state, kv, chunk_pages,
@@ -1763,7 +1787,8 @@ class TPUEngine:
         with dispatch("activate"):
             self.state = dp.activate_slot(
                 self.state, req.slot, jnp.asarray(block_row), jnp.int32(n),
-                token, ring)
+                token, ring, req.pf_state)
+        req.pf_state = None
         self._bind_slot(req, req.slot, n)
         if self.enable_prefix_cache:
             n_shared = len(self._slot_shared.get(req.slot, ()))
@@ -2157,6 +2182,8 @@ class TPUEngine:
                "worker_chips": accelerators.current_worker_chips(),
                "compile_cache": accelerators.compile_cache_counts(),
                "decode_steps": self.decode_steps,
+               # live rows summed over decode steps
+               "decode_slot_steps": self.decode_slot_steps,
                # steps_ahead: decode steps dispatched while the step before
                # them was unread; tokens_discarded: row-steps whose token was
                # dropped because the row had stopped or been aborted
@@ -2184,6 +2211,8 @@ class TPUEngine:
             "page_steps_total": self.page_steps_total,
             "held_token_steps": self.held_token_steps,
             "held_byte_steps": self.held_byte_steps}
+        if self.cfg.ssm:
+            out["cache"]["state_bytes_per_row"] = self._state_bytes_per_row
         if self.ring:
             out["cache"].update(
                 window_context_tokens=self.window_context_tokens,

@@ -27,19 +27,21 @@ import jax.numpy as jnp
 from ray_tpu import ops
 from ray_tpu.models.transformer import (TransformerConfig, _dense_mlp, _mla_expand,
                                         _mla_project, _moe_mlp, _norm, _residual,
-                                        close_pass, rope_by_kind, scan_layers)
+                                        close_pass, embed_tokens, lm_logits, mamba_mixer,
+                                        rope_by_kind, scan_layers)
 
 
 def _per_head_kv_only(cfg: TransformerConfig, what: str) -> None:
-    """The paths not carried to the latent cache, to two kinds of layer or
-    to a looped stack."""
+    """The paths not carried to the latent cache, to two kinds of layer, to
+    a looped stack or to a recurrent state."""
     if (cfg.mla or cfg.n_dense_layers or cfg.window or cfg.n_passes > 1
-            or cfg.sandwich_norms):
+            or cfg.sandwich_norms or cfg.ssm or cfg.kv_packed):
         raise NotImplementedError(
             f"{what} is built for per-head K and V over one kind of layer, "
             "each applied once; a model with latent attention (kv_lora_rank), "
-            "leading dense layers, window layers, a looped stack (n_passes) or "
-            "sandwich norms is served without it")
+            "leading dense layers, window layers, a looped stack (n_passes), "
+            "sandwich norms, state-space layers (a recurrent state a row) or "
+            "packed KV rows (kv_packed) is served without it")
 
 
 def _rope(cfg):
@@ -112,6 +114,14 @@ def _mlp_block(normed, layer_p, cfg):
     return _dense_mlp(normed, layer_p["mlp"], cfg)
 
 
+def _close_block(h, mixed, layer_p, cfg):
+    """A block's second half: the mixer's (attention's, or the state-space
+    mixer's) output `mixed` joins the residual, then the MLP's does."""
+    h = _residual(h, mixed, layer_p, "post_attn_norm", cfg)
+    return _residual(h, _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg),
+                     layer_p, "post_mlp_norm", cfg)
+
+
 def _mla_prefill_attn(normed, attn_p, cfg, cos, sin, positions=None,
                       prefix=None, mask=None):
     """Latent attention in expanded form over normed [1, T, E], after an
@@ -124,10 +134,11 @@ def _mla_prefill_attn(normed, attn_p, cfg, cos, sin, positions=None,
         [prefix[None].astype(dt), latent], axis=1)
     k, v = _mla_expand(rows, attn_p, cfg)
     if mask is None:
-        out = ops.attention(q, k, v, causal=True, scale=cfg.qk_dim ** -0.5,
+        out = ops.attention(q, k, v, causal=True, scale=cfg.softmax_scale,
                             impl="reference")
     else:
-        scores = jnp.einsum("bthd,bshd->bhts", q, k) / (cfg.qk_dim ** 0.5)
+        # (a division, as this program has always had: see prefill_with_prefix)
+        scores = jnp.einsum("bthd,bshd->bhts", q, k) / (1.0 / cfg.softmax_scale)
         scores = jnp.where(mask[None, None], scores.astype(jnp.float32), -1e30)
         out = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(scores, axis=-1).astype(dt), v)
     return jnp.einsum("bthd,hde->bte", out, attn_p["wo"].astype(dt)), latent[0]
@@ -144,18 +155,23 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
     every layer application's own keys and values, pass-major.
     With window layers kv still holds every layer's T positions: which of
     them a window layer keeps is the cache's business (decoding_paged.py).
+    With state-space layers kv holds k, v [L_attn, T, Hkv, Dh] of the
+    attention layers and, of the state-space layers, `ssm` [L_ssm, H, P, N]
+    (float32) and `conv` [L_ssm, d_conv - 1, conv_dim]: the recurrent state
+    and the convolution's tail AFTER position length - 1 (the bucket's
+    padding does not advance them).
     With `lora_bank` + scalar `lora_idx`, applies that adapter's q/v
     deltas (init_lora_bank; idx 0 = null adapter = exact base model).
     """
     dt = cfg.dtype
     B, T = tokens.shape
-    x = params["embed"].astype(dt)[tokens]
+    x = embed_tokens(params, tokens, cfg)
     if cfg.pos == "learned":
         x = x + params["pos_embed"][:T].astype(dt)
     rope = rope_by_kind(cfg)
     lscale = None if lora_bank is None else lora_bank["scale"][lora_idx]
 
-    def block(h, layer_in, window=False):
+    def block(h, layer_in, window=False, ssm=False):
         cos, sin = rope[window]
         if lora_bank is None:
             layer_p, lora_l = layer_in, None
@@ -163,6 +179,9 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
             layer_p, aq, bq, av, bv = layer_in
             lora_l = (aq, bq, av, bv)
         normed = _norm(h, layer_p["norm1"], cfg)
+        if ssm:
+            out, state, tail = mamba_mixer(normed[0], layer_p["mixer"], cfg, length)
+            return _close_block(h, out[None], layer_p, cfg), (state, tail)
         if cfg.mla:
             out, rows = _mla_prefill_attn(normed, layer_p["attn"], cfg, cos, sin)
             h = h + out
@@ -173,15 +192,12 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
         if cfg.pos == "rope":
             q = ops.apply_rope(q, cos, sin)
             k = ops.apply_rope(k, cos, sin)
-        out = ops.attention(q, k, v, causal=True,
+        out = ops.attention(q, k, v, causal=True, scale=cfg.softmax_scale,
                             window=cfg.window if window else None)
         out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
             out = out + layer_p["attn"]["bo"].astype(dt)
-        h = _residual(h, out, layer_p, "post_attn_norm", cfg)
-        h = _residual(h, _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg),
-                      layer_p, "post_mlp_norm", cfg)
-        return h, (k[0], v[0])
+        return _close_block(h, out, layer_p, cfg), (k[0], v[0])
 
     def close(h, t):  # the exit gate decides nothing about a prompt
         return close_pass(h, None, t, params, cfg)[0]
@@ -193,12 +209,17 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
             params["layers"], lora_bank["A_q"], lora_bank["B_q"],
             lora_bank["A_v"], lora_bank["B_v"]))
         x = close(x, 0)
-    last = x[0, length - 1]
-    if cfg.tie_embeddings:
-        logits = last @ params["embed"].astype(dt).T
-    else:
-        logits = last @ params["lm_head"].astype(dt)
-    return logits.astype(jnp.float32), dict(zip("kv", kv))
+    logits = lm_logits(x[0, length - 1], params, cfg)
+    return logits.astype(jnp.float32), kv_tree(kv, cfg)
+
+
+def kv_tree(kv, cfg: TransformerConfig) -> dict:
+    """What `scan_layers` stacked of a prefill's blocks, by name: {k, v} (a
+    latent cache: {k}); with state-space layers also {ssm, conv}."""
+    if cfg.ssm is None:
+        return dict(zip("kv", kv))
+    (k, v), (state, tail) = kv
+    return {"k": k, "v": v, "ssm": state, "conv": tail}
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -217,7 +238,7 @@ def prefill_batch(params, tokens, lengths, cfg: TransformerConfig):
     _per_head_kv_only(cfg, "prefill_batch (the PD prefill tier)")
     dt = cfg.dtype
     B, T = tokens.shape
-    x = params["embed"].astype(dt)[tokens]
+    x = embed_tokens(params, tokens, cfg)
     if cfg.pos == "learned":
         x = x + params["pos_embed"][:T].astype(dt)
     cos, sin = _rope(cfg)
@@ -228,23 +249,17 @@ def prefill_batch(params, tokens, lengths, cfg: TransformerConfig):
         if cfg.pos == "rope":
             q = ops.apply_rope(q, cos, sin)
             k = ops.apply_rope(k, cos, sin)
-        out = ops.attention(q, k, v, causal=True)
+        out = ops.attention(q, k, v, causal=True, scale=cfg.softmax_scale)
         out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
             out = out + layer_p["attn"]["bo"].astype(dt)
-        h = h + out
-        h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
-        return h, (k, v)
+        return _close_block(h, out, layer_p, cfg), (k, v)
 
     x, kv = jax.lax.scan(block, x, params["layers"])
     x = _norm(x, params["final_norm"], cfg)
     last = jnp.take_along_axis(
         x, (lengths - 1)[:, None, None], axis=1)[:, 0]        # [B, E]
-    if cfg.tie_embeddings:
-        logits = last @ params["embed"].astype(dt).T
-    else:
-        logits = last @ params["lm_head"].astype(dt)
-    return logits.astype(jnp.float32), {"k": kv[0], "v": kv[1]}
+    return lm_logits(last, params, cfg).astype(jnp.float32), {"k": kv[0], "v": kv[1]}
 
 
 @functools.partial(jax.jit, donate_argnames=("state",))

@@ -47,6 +47,20 @@ chunk's worth of room, so that a chunk (padded to its bucket) never writes
 over a page the chunk's own queries still see. Page 0 of both pools is
 scratch. One state, one decode step, one chunked prefill for both kinds.
 
+A model with state-space layers (cfg.ssm) has two kinds of state a row as
+well, and only one of them is pages. Its attention layers keep `kp`/`vp`
+[L_attn, num_pages, page, Hkv, Dh] and the `block` table, pages that grow
+with the row. Its state-space layers keep a FIXED state a slot, whatever the
+row's length: `ssm` [L_ssm, max_slots, H, P, N] (float32: the recurrent
+state) and `conv` [L_ssm, max_slots, d_conv - 1, conv_dim] (the
+convolution's last inputs). Slot s of both belongs to the row in slot s for
+its whole life: an insert writes the prefilled row's state over whatever the
+last occupant left, the decode step updates every live row's state where it
+lies (an inactive row is stepped with dt = 0, which changes nothing), and a
+chunked prefill carries the state from chunk to chunk OUTSIDE the slots
+(`prefill_with_prefix(row_state=)`), so that it enters its slot only when
+the row goes live (`activate_slot(row_state=)`).
+
 (reference capability: vLLM paged attention behind
 llm/_internal/serve/engines/vllm/vllm_engine.py:114; design here is
 TPU-native — static gathers and a Pallas kernel, no custom CUDA.)
@@ -59,11 +73,15 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.decoding import _attn_qkv, _mla_prefill_attn, _mlp_block
+from ray_tpu.models.decoding import (_attn_qkv, _close_block, _mla_prefill_attn, _mlp_block,
+                                     kv_tree)
+# `_residual` is this module's by name for chipbench/loop_faults.py, which
+# plants a wrong one here and in decoding.py (whose `_close_block` calls it)
 from ray_tpu.models.transformer import (TransformerConfig, _mla_absorb_out, _mla_absorb_q,
-                                        _mla_project, _norm, _residual, close_pass,
-                                        exit_distribution, kind_index, rope_by_kind,
-                                        scan_layers)
+                                        _mla_project, _norm, _residual, close_pass,  # noqa: F401
+                                        embed_tokens, exit_distribution, kind_index,
+                                        lm_logits, mamba_mixer, mixer_out, mixer_project,
+                                        mixer_split, rope_by_kind, scan_layers)
 from ray_tpu import ops
 
 
@@ -86,8 +104,8 @@ def init_paged_state(cfg: TransformerConfig, max_slots: int, max_len: int,
     chunk, never more than a row's pages) in a pool of `window_pages`
     (default: a ring for every slot + scratch, or num_pages if that is
     fewer: no row holds more window pages than full ones)."""
-    # cache planes: the layers, times the passes of a looped stack (which has
-    # neither latent rows nor window layers)
+    # cache planes: the attention layers, times the passes of a looped stack
+    # (which has neither latent rows nor window layers)
     L, Hkv, Dh = cfg.n_planes, cfg.kv_heads, cfg.head_dim
     max_pages_per_seq = (max_len + page_size - 1) // page_size
     gate = {}
@@ -106,9 +124,16 @@ def init_paged_state(cfg: TransformerConfig, max_slots: int, max_len: int,
                  # ring slot -> page id of the window pool; `wring` slots held
                  "wblock": jnp.zeros((max_slots, ring), jnp.int32),
                  "wring": jnp.ones((max_slots,), jnp.int32)}
-    else:
-        pools = {"kp": jnp.zeros((L, num_pages, page_size, Hkv, Dh), cfg.dtype),
-                 "vp": jnp.zeros((L, num_pages, page_size, Hkv, Dh), cfg.dtype)}
+    else:  # a token's row (Hkv, Dh), or packed 128 lanes wide (cfg.kv_packed)
+        pools = {"kp": jnp.zeros((L, num_pages, page_size, *cfg.kv_row), cfg.dtype),
+                 "vp": jnp.zeros((L, num_pages, page_size, *cfg.kv_row), cfg.dtype)}
+    if cfg.ssm:  # a fixed state a slot beside the pages (module docstring)
+        s = cfg.ssm
+        pools.update(
+            ssm=jnp.zeros((cfg.n_ssm_layers, max_slots, s.n_heads, s.d_head, s.d_state),
+                          jnp.float32),
+            conv=jnp.zeros((cfg.n_ssm_layers, max_slots, s.d_conv - 1, s.conv_dim),
+                           cfg.dtype))
     return {
         **pools, **gate,
         # page ids per slot; unused entries point at page 0 (masked anyway)
@@ -132,11 +157,34 @@ def insert_sequence_paged(state, slot, kv, length, first_token, pages,
     pool, padded with 0; default: the leading entries of `pages`, which are
     valid ids there as long as the window pool is no smaller than the
     row): of the T/page_size pages only the last ones, those a decode step
-    can still see, and never more than the ring holds."""
+    can still see, and never more than the ring holds.
+    With state-space layers kv's `ssm` and `conv` go into slot `slot` of the
+    state of that name: the last occupant's is written over whole."""
     if cfg.window:
         state, kv = _insert_ring(state, slot, kv, length, pages, window_pages)
-    state = _write_pages(state, kv, pages)
+    kv, row_state = _split_row_state(kv)
+    state = _set_row_state(_write_pages(state, kv, pages), slot, row_state)
     return _activate(state, slot, pages, length, first_token)
+
+
+def _split_row_state(kv) -> tuple:
+    """A prefill's kv -> (its part that goes to pages, its recurrent part
+    {ssm, conv}: None for a model without state-space layers)."""
+    row = {name: kv[name] for name in ("ssm", "conv") if name in kv}
+    return {name: t for name, t in kv.items() if name not in row}, row or None
+
+
+def _set_row_state(state, slot, row_state) -> dict:
+    """`state` with slot `slot` of the recurrent state set to `row_state`
+    ({ssm [L_ssm, H, P, N], conv [L_ssm, d_conv - 1, conv_dim]}; None: as it
+    is): a `dynamic_update_slice` a kind, into the donated state where it lies."""
+    if row_state is None:
+        return state
+    state = dict(state)
+    for name, rows in row_state.items():
+        state[name] = jax.lax.dynamic_update_slice_in_dim(
+            state[name], rows[:, None].astype(state[name].dtype), slot, axis=1)
+    return state
 
 
 def _activate(state, slot, block_row, length, first_token) -> dict:
@@ -233,13 +281,39 @@ def _write_pages(state, kv, pages) -> dict:
     return state
 
 
+def _pack_queries(qh, head_dim: int):
+    """Queries [B, Hkv, G, Dh] for a pool that packs r = 128 / Dh KV heads a
+    row of 128 lanes: [B, Hkv / r, r * G, 128], a head's queries in the lanes
+    its keys lie in and zeros in the others', so that the launch is the
+    per-head one over Hkv / r heads of 128 (the zeros add nothing to a
+    score)."""
+    r = 128 // head_dim
+    B, Hkv, G, Dh = qh.shape
+    own = jnp.eye(r, dtype=qh.dtype)[None, None, :, None, :, None]
+    wide = qh.reshape(B, Hkv // r, r, G, 1, Dh) * own
+    return wide.reshape(B, Hkv // r, r * G, r * Dh)
+
+
+def _unpack_outputs(out, head_dim: int):
+    """The launch's [B, Hkv / r, r * G, 128] -> [B, Hkv, G, Dh]: of each
+    query's 128 lanes the ones its own head's values lie in."""
+    r = 128 // head_dim
+    B, rows, rG, _ = out.shape
+    own = jnp.eye(r, dtype=out.dtype)[None, None, :, None, :, None]
+    wide = out.reshape(B, rows, r, rG // r, r, head_dim)
+    return (wide * own).sum(axis=4).reshape(B, rows * r, rG // r, head_dim)
+
+
 def _set_pages(pool, ids, src):
     """`pool` [planes, pages, P, ...] with page `ids[i]` of every plane set to
     `src[:, i]` (`ids` [n], `src` [planes, n, P, ...]): the one way whole
     pages enter a pool outside the decode step. One `dynamic_update_slice` a
     page, in the order of `ids` (ids that repeat are scratch page 0's: the
     last one stays), which updates the donated pool where it lies
-    (module docstring)."""
+    (module docstring). A pool that packs its KV heads (cfg.kv_packed) takes
+    the per-head `src` [.., P, Hkv, Dh] as the rows it stores."""
+    if src.shape[3:] != pool.shape[3:]:
+        src = src.reshape(*src.shape[:3], *pool.shape[3:])
     src = src.astype(pool.dtype)
 
     def set_page(i, pool):
@@ -282,7 +356,13 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
     pass t), the final norm closing every pass; with the exit gate the
     returned state's `exit_cdf` [B, n_passes] is each row's probability of
     having left the loop by the end of each pass (float32; the sampler does
-    not read it: the engine counts from it)."""
+    not read it: the engine counts from it).
+
+    With state-space layers the scan also carries `ssm` and `conv` whole and
+    a state-space layer updates its own plane of both where it lies
+    (`ops.ssm_state_update`: the Pallas kernel with `kernel`, `jax.numpy`
+    without); an inactive row keeps its state and its tail, and the kernel
+    does not touch it."""
     from ray_tpu.ops.ragged_paged_attention import ragged_decode_attention
 
     # the ragged sweep only walks the batch's live prefix of each table;
@@ -301,7 +381,7 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
     # allocator never hands out page 0), so they can't corrupt live pages
     page_ids = jnp.where(state["active"], page_ids, 0)
     offsets = pos % P                                          # [B]
-    x = params["embed"].astype(dt)[tokens]
+    x = embed_tokens(params, tokens, cfg)
     if cfg.pos == "learned":
         x = x + params["pos_embed"].astype(dt)[pos][:, None]
     rope = rope_by_kind(cfg)
@@ -325,12 +405,43 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
         wpage_ids = jnp.where(state["active"], wtbl[:, -1], 0)
 
     def attend(qh, kp, vp, base, window=False):
-        return ragged_decode_attention(
+        if cfg.kv_packed:
+            qh = _pack_queries(qh, cfg.head_dim)
+        out = ragged_decode_attention(
             qh, kp, vp, base + (wtbl if window else tbl), pos,
-            scale=cfg.qk_dim ** -0.5, impl="kernel" if kernel else "reference",
+            scale=cfg.softmax_scale, impl="kernel" if kernel else "reference",
             window=cfg.window if window else None)
+        return _unpack_outputs(out, cfg.head_dim) if cfg.kv_packed else out
 
-    def block(carry, layer_in, window=False):
+    # the update kernel's walk over the live rows, the same for every layer
+    schedule = ops.live_rows(state["active"]) if cfg.ssm and kernel else None
+
+    def stored(t):
+        """This step's K or V [B, 1, Hkv, Dh] as the pool stores a token's row."""
+        return t[:, 0].reshape(B, *cfg.kv_row) if cfg.kv_packed else t[:, 0]
+
+    def ssm_block(carry, layer_in):
+        h, gates, kp, vp, rec, conv = carry      # the recurrent state, whole
+        layer_p, i = layer_in                    # i: this layer's plane of it
+        p = layer_p["mixer"]
+        z, xBC, dts = mixer_project(_norm(h, layer_p["norm1"], cfg)[:, 0], p, cfg)
+        tail = jax.lax.dynamic_index_in_dim(conv, i, 0, keepdims=False)  # [B, K-1, C]
+        xs, Bs, Cs = mixer_split(jax.vmap(
+            lambda x, t: ops.causal_conv(x[None], t, p["conv_w"], p["conv_b"])[0])(
+                xBC, tail), cfg)
+        rec, y = ops.ssm_state_update(
+            rec, i, xs, dts, -jnp.exp(p["A_log"].astype(jnp.float32)), Bs, Cs,
+            live=state["active"], schedule=schedule,
+            impl="kernel" if kernel else "reference")
+        moved = jnp.concatenate([tail[:, 1:], xBC[:, None].astype(tail.dtype)], axis=1)
+        conv = jax.lax.dynamic_update_index_in_dim(
+            conv, jnp.where(state["active"][:, None, None], moved, tail), i, 0)
+        h = _close_block(h, mixer_out(y, xs, z, p, cfg)[:, None], layer_p, cfg)
+        return (h, gates, kp, vp, rec, conv), None
+
+    def block(carry, layer_in, window=False, ssm=False):
+        if ssm:
+            return ssm_block(carry, layer_in)
         h, gates, kp, vp, *others = carry        # pools [L*num_pages, P, Hkv, Dh]
         if window:                               # this layer's kind of pool
             (kp, vp), others = others, [kp, vp]
@@ -353,17 +464,15 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
             q = ops.apply_rope(q, cos, sin, positions=pos[:, None])
             k = ops.apply_rope(k, cos, sin, positions=pos[:, None])
         # scatter this step's K/V at (page, offset) per row
-        kp = kp.at[base + rows, offsets].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[base + rows, offsets].set(v[:, 0].astype(vp.dtype))
+        kp = kp.at[base + rows, offsets].set(stored(k).astype(kp.dtype))
+        vp = vp.at[base + rows, offsets].set(stored(v).astype(vp.dtype))
         qh = q[:, 0].reshape(B, cfg.kv_heads, G, cfg.head_dim)
         out = attend(qh, kp, vp, base, window)
         out = out.reshape(B, 1, cfg.n_heads, cfg.head_dim).astype(dt)
         out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
             out = out + layer_p["attn"]["bo"].astype(dt)
-        h = _residual(h, out, layer_p, "post_attn_norm", cfg)
-        h = _residual(h, _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg),
-                      layer_p, "post_mlp_norm", cfg)
+        h = _close_block(h, out, layer_p, cfg)
         if window:
             return (h, gates, *others, kp, vp), None
         return (h, gates, kp, vp, *others), None
@@ -373,19 +482,23 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
         return (*close_pass(h, gates, t, params, cfg), *pools)
 
     gates = jnp.zeros((cfg.n_passes, B, 1), jnp.float32) if cfg.exit_gate else None
+    # what the scan carries beside kp and vp: the window pools, or the
+    # recurrent state
+    others = ("wkp", "wvp") if cfg.window else ("ssm", "conv") if cfg.ssm else ()
+    if cfg.ssm:
+        wpools = (state["ssm"], state["conv"])
     (x, gates, kp, vp, *wpools), _ = scan_layers(
         block, (x, gates, state["kp"].reshape(flat), vp0, *wpools), params, cfg, bases,
         *(() if lora_bank is None else
-          (lora_bank[k] for k in ("A_q", "B_q", "A_v", "B_v"))), close=close)
-    if cfg.tie_embeddings:
-        logits = x[:, 0] @ params["embed"].astype(dt).T
-    else:
-        logits = x[:, 0] @ params["lm_head"].astype(dt)
+          (lora_bank[k] for k in ("A_q", "B_q", "A_v", "B_v"))), close=close,
+        ssm_per_layer=((jnp.arange(cfg.n_ssm_layers, dtype=jnp.int32),)
+                       if cfg.ssm else ()))
+    logits = lm_logits(x[:, 0], params, cfg)
     state = dict(state)
     state["kp"] = kp.reshape(state["kp"].shape)
     if vp is not None:
         state["vp"] = vp.reshape(state["vp"].shape)
-    for name, pool in zip(("wkp", "wvp"), wpools):
+    for name, pool in zip(others, wpools):
         state[name] = pool.reshape(state[name].shape)
     if gates is not None:
         state["exit_cdf"] = jnp.cumsum(exit_distribution(gates[..., 0]), axis=0).T
@@ -429,7 +542,7 @@ _SCORES_AT_ONCE = 2 << 30
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
                         length, cfg: TransformerConfig,
-                        window_k=None, window_v=None):
+                        window_k=None, window_v=None, row_state=None):
     """Continuation prefill: run ONLY the suffix tokens [1, Ts] (padded
     bucket; true count `length`) attending over a cached prefix KV
     [L, Tp, Hkv, Dh] (valid first `prefix_len` positions — cached K is
@@ -446,11 +559,16 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
     positions that END at the prefix's end (position prefix_len - Tw + j at
     j; before the row's start: masked): a window layer attends over those
     within the window, and the suffix kv comes back for all L layers.
+    With state-space layers `prefix_k`/`prefix_v` are the attention layers'
+    [L_attn, Tp, Hkv, Dh] and `row_state` {ssm, conv} is the recurrent state
+    and the convolution's tail at the prefix's end (what the chunk before
+    returned): the suffix runs on from them, and kv's `ssm` / `conv` are
+    those at the suffix's last real position.
     """
     dt = cfg.dtype
     B, Ts = tokens.shape
     Tp = prefix_k.shape[1]
-    x = params["embed"].astype(dt)[tokens]
+    x = embed_tokens(params, tokens, cfg)
     pos_suffix = prefix_len + jnp.arange(Ts)                     # [Ts]
     if cfg.pos == "learned":
         x = x + params["pos_embed"].astype(dt)[pos_suffix][None]
@@ -473,7 +591,12 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
                        & (kpos >= -prefix_len))
         per_layer = (jnp.asarray(kind_index(cfg), jnp.int32),)
 
-    def block(h, layer_in, window=False):
+    def block(h, layer_in, window=False, ssm=False):
+        if ssm:
+            layer_p, rec, tail = layer_in
+            out, rec, tail = mamba_mixer(_norm(h, layer_p["norm1"], cfg)[0],
+                                         layer_p["mixer"], cfg, length, rec, tail)
+            return _close_block(h, out[None], layer_p, cfg), (rec, tail)
         if cfg.window:
             layer_p, i = layer_in
             pk, pv = (jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False)
@@ -481,6 +604,8 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
                                 else (prefix_k, prefix_v)))
         else:
             layer_p, pk, pv = layer_in                # [Tp, Hkv, Dh] each
+        if cfg.kv_packed:                             # as gathered out of the pool
+            pk, pv = (t.reshape(Tp, cfg.kv_heads, cfg.head_dim) for t in (pk, pv))
         mask, (cos, sin) = masks[window], rope[window]
         normed = _norm(h, layer_p["norm1"], cfg)
         if cfg.mla:
@@ -499,8 +624,10 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
         qh = q.reshape(B, Ts, cfg.kv_heads, G, cfg.head_dim)
 
         def attend(qh, k_all, v_all):
+            # a division, as this program has always had: the configurations
+            # that set no multiplier keep the program they had
             scores = jnp.einsum("btkgd,bskd->btkgs", qh,
-                                k_all.astype(dt)) / (cfg.head_dim ** 0.5)
+                                k_all.astype(dt)) / (1.0 / cfg.softmax_scale)
             scores = jnp.where(mask[None, :, None, None, :],
                                scores.astype(jnp.float32), -1e30)
             w = jax.nn.softmax(scores, axis=-1).astype(dt)
@@ -517,19 +644,14 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
         out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
             out = out + layer_p["attn"]["bo"].astype(dt)
-        h = _residual(h, out, layer_p, "post_attn_norm", cfg)
-        h = _residual(h, _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg),
-                      layer_p, "post_mlp_norm", cfg)
-        return h, (k[0], v[0])
+        return _close_block(h, out, layer_p, cfg), (k[0], v[0])
 
     x, kv = scan_layers(block, x, params, cfg, *per_layer,
-                        close=lambda h, t: close_pass(h, None, t, params, cfg)[0])
-    last = x[0, length - 1]
-    if cfg.tie_embeddings:
-        logits = last @ params["embed"].astype(dt).T
-    else:
-        logits = last @ params["lm_head"].astype(dt)
-    return logits.astype(jnp.float32), dict(zip("kv", kv))
+                        close=lambda h, t: close_pass(h, None, t, params, cfg)[0],
+                        ssm_per_layer=() if row_state is None else (
+                            row_state["ssm"], row_state["conv"]))
+    logits = lm_logits(x[0, length - 1], params, cfg)
+    return logits.astype(jnp.float32), kv_tree(kv, cfg)
 
 
 @functools.partial(jax.jit, donate_argnames=("state",))
@@ -540,7 +662,10 @@ def write_kv_pages(state, kv, pages, ring_ids=None, start=None):
     activates once the whole prompt is resident (activate_slot). With window
     layers their part goes into the row's ring `ring_ids` [ring] (the ids
     `activate_slot` will take), at the slots of the T/page_size logical
-    pages from position `start` (a multiple of the page size) on."""
+    pages from position `start` (a multiple of the page size) on. A recurrent
+    part of `kv` (`ssm`, `conv`) is no page's and is left out: it rides from
+    chunk to chunk outside the state and enters its slot at `activate_slot`."""
+    kv = _split_row_state(kv)[0]
     if ring_ids is not None:
         kv, window_kv = _split_kinds(kv, state)
         P = state["wkp"].shape[2]
@@ -566,12 +691,16 @@ def gather_window_pages(state, ring_ids, start, cfg: TransformerConfig):
 
 
 @functools.partial(jax.jit, donate_argnames=("state",))
-def activate_slot(state, slot, block_row, length, first_token, window_pages=None):
+def activate_slot(state, slot, block_row, length, first_token, window_pages=None,
+                  row_state=None):
     """Turn a fully-prefilled slot live for decode (the bookkeeping half
     of insert_sequence_paged, after write_kv_pages staged the KV); with
-    window layers `window_pages` [ring] is the row's ring."""
+    window layers `window_pages` [ring] is the row's ring; with state-space
+    layers `row_state` {ssm, conv} is what the last chunk returned, written
+    into the slot here."""
     if window_pages is not None:
         state = _set_ring(state, slot, window_pages)
+    state = _set_row_state(state, slot, row_state)
     return _activate(dict(state), slot, block_row, length, first_token)
 
 
@@ -589,5 +718,6 @@ def insert_sequence_paged_prefix(state, slot, kv, suffix_pages, block_row,
     insert_sequence_paged puts it there."""
     if cfg.window:
         state, kv = _insert_ring(state, slot, kv, length, block_row, window_pages)
-    state = _write_pages(state, kv, suffix_pages)
+    kv, row_state = _split_row_state(kv)
+    state = _set_row_state(_write_pages(state, kv, suffix_pages), slot, row_state)
     return _activate(state, slot, block_row, length, first_token)

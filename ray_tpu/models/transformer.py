@@ -1,5 +1,5 @@
 """Decoder-only transformer core shared by the GPT-2 / Llama / Mixtral /
-Kimi-VL (DeepSeek-V3-style) / Mellum / Ouro families.
+Kimi-VL (DeepSeek-V3-style) / Mellum / Ouro / Granite-hybrid families.
 Pure-functional: params are pytrees (layers stacked on a leading
 dim and consumed by lax.scan — compile-fast and pipeline-ready), logical axis
 trees drive mesh sharding, compute runs in bf16 with f32 accumulators.
@@ -10,6 +10,13 @@ the plain rope, and full layers (every `window_period`-th), which see
 everything and take the YaRN rope where `yarn` is set. The layers stay
 stacked [L, ...]; `scan_layers` scans whole periods and tells each block its
 kind.
+
+A stack may mix attention layers with state-space layers
+(`TransformerConfig.ssm`, a Mamba-2 mixer in place of attention: ops/ssm.py):
+one attention layer at a fixed place in every period of layers. The two
+kinds are stacked apart (`params["layers"]`, `params["ssm_layers"]`: no layer
+carries weights of the other kind) and `scan_layers` scans whole periods,
+each kind static in its own trace of the block.
 
 A stack may be looped (`TransformerConfig.n_passes`): the same stacked
 weights applied several times, the final norm closing every pass;
@@ -29,6 +36,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu import ops
 
@@ -54,6 +62,34 @@ class MoEConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """State-space (Mamba-2) layers in the stack: every layer but the one at
+    `attn_at` of each `period` has the mixer below in place of attention.
+    d_inner = n_heads * d_head; one group of B and C shared by the heads."""
+    n_heads: int = 64
+    d_head: int = 64
+    d_state: int = 128
+    d_conv: int = 4
+    chunk: int = 256                       # the prefill scan's chunk
+    period: int = 10
+    attn_at: int = 5                       # layer l attends iff l % period == attn_at
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.d_head
+
+    @property
+    def conv_dim(self) -> int:
+        """Columns the convolution runs over: x | B | C."""
+        return self.d_inner + 2 * self.d_state
+
+    @property
+    def in_dim(self) -> int:
+        """Columns of the published in_proj: z | x B C | dt."""
+        return self.d_inner + self.conv_dim + self.n_heads
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     d_model: int = 512
@@ -64,7 +100,7 @@ class TransformerConfig:
     d_ff: int = 2048
     norm: str = "rms"                      # "rms" | "ln"
     act: str = "swiglu"                    # "swiglu" | "gelu"
-    pos: str = "rope"                      # "rope" | "learned"
+    pos: str = "rope"                      # "rope" | "learned" | "none"
     rope_theta: float = 10000.0
     max_seq_len: int = 2048
     tie_embeddings: bool = False
@@ -109,15 +145,63 @@ class TransformerConfig:
     # sigmoid(w . x_t + b) on every pass's closed output: the probability of
     # leaving the loop after pass t (`exit_distribution`)
     exit_gate: bool = False
+    # state-space layers in place of attention on all but one layer a period
+    ssm: SSMConfig | None = None
+    # scalar multipliers, each inert at its default: the embedding's output
+    # times `embedding_multiplier`, every sublayer's output times
+    # `residual_multiplier` before the residual add, the logits DIVIDED by
+    # `logits_scaling`, and `attention_multiplier` the softmax scale in place
+    # of qk_dim ** -0.5 (`softmax_scale`)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float | None = None
+    logits_scaling: float = 1.0
+    # the paged cache keeps a token's K (and V) heads packed 128 lanes wide,
+    # [.., kv_heads * head_dim / 128, 128], instead of a row of head_dim a
+    # head: for head_dim under 128, where the TPU stores [.., Hkv, 64] pools
+    # pages-minor and the decode step re-lays both whole, padded to 128
+    # lanes, in and out of every step (models/decoding_paged.py)
+    kv_packed: bool = False
+    # the initialiser's standard deviations where a family does not take the
+    # shared ones (0.02, and 0.02 / sqrt(2 n_layers) on every projection back
+    # into the residual): `init_out_std` for those projections (a family whose
+    # `residual_multiplier` is its depth scaling), `init_attn_std` = (wq and
+    # wk, wv and wo) of the attention layers. None: the shared ones
+    init_out_std: float | None = None
+    init_attn_std: tuple | None = None
 
     @property
     def n_full_layers(self) -> int:
         return self.n_layers // self.window_period if self.window else self.n_layers
 
     @property
+    def n_attn_layers(self) -> int:
+        return self.n_layers // self.ssm.period if self.ssm else self.n_layers
+
+    @property
+    def n_ssm_layers(self) -> int:
+        return self.n_layers - self.n_attn_layers
+
+    @property
     def n_planes(self) -> int:
-        """Layer applications of one token: each holds K and V of its own."""
-        return self.n_passes * self.n_layers
+        """Layer applications of one token that hold K and V of their own."""
+        return self.n_passes * self.n_attn_layers
+
+    @property
+    def kv_row(self) -> tuple:
+        """A token's K (or V) of one layer as the paged cache stores it."""
+        if self.kv_packed:
+            return (self.kv_heads * self.head_dim // 128, 128)
+        return (self.kv_heads, self.head_dim)
+
+    @property
+    def softmax_scale(self) -> float:
+        """What the attention scores are multiplied by before the softmax:
+        the one place that says it, for the prefill, a chunk's continuation
+        and the decode step alike."""
+        if self.attention_multiplier is not None:
+            return self.attention_multiplier
+        return self.qk_dim ** -0.5
 
     @property
     def kv_heads(self) -> int:
@@ -160,11 +244,18 @@ def _norm_params(cfg, key, scale: float = 1.0):
     return p
 
 
+def _out_std(cfg) -> float:
+    """Of a projection back into the residual stream."""
+    if cfg.init_out_std is not None:
+        return cfg.init_out_std
+    return 0.02 / math.sqrt(2 * cfg.n_layers)
+
+
 def _dense_mlp_params(cfg, key, d_ff=None):
     E, F = cfg.d_model, d_ff or cfg.d_ff
     k1, k2, k3 = jax.random.split(key, 3)
     std = 0.02
-    out_std = 0.02 / math.sqrt(2 * cfg.n_layers)
+    out_std = _out_std(cfg)
     if cfg.act == "swiglu":
         p = {
             "wi_gate": jax.random.normal(k1, (E, F), cfg.param_dtype) * std,
@@ -220,15 +311,17 @@ def _layer_params(cfg, key, dense: bool = False):
     """One layer; `dense` makes it one of the leading dense layers."""
     E, H, Hkv, Dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
     ks = jax.random.split(key, 6)
-    std = 0.02
-    out_std = 0.02 / math.sqrt(2 * cfg.n_layers)
+    qk_std, v_std, out_std = 0.02, 0.02, _out_std(cfg)
+    if cfg.init_attn_std is not None:
+        qk_std, v_std = cfg.init_attn_std
+        out_std = v_std
     if cfg.mla:
         attn = _mla_params(cfg, ks)
     else:
         attn = {
-            "wq": jax.random.normal(ks[0], (E, H, Dh), cfg.param_dtype) * std,
-            "wk": jax.random.normal(ks[1], (E, Hkv, Dh), cfg.param_dtype) * std,
-            "wv": jax.random.normal(ks[2], (E, Hkv, Dh), cfg.param_dtype) * std,
+            "wq": jax.random.normal(ks[0], (E, H, Dh), cfg.param_dtype) * qk_std,
+            "wk": jax.random.normal(ks[1], (E, Hkv, Dh), cfg.param_dtype) * qk_std,
+            "wv": jax.random.normal(ks[2], (E, Hkv, Dh), cfg.param_dtype) * v_std,
             "wo": jax.random.normal(ks[3], (H, Dh, E), cfg.param_dtype) * out_std,
         }
     if cfg.bias:
@@ -252,7 +345,74 @@ def _layer_params(cfg, key, dense: bool = False):
     return layer
 
 
+def _mixer_params(cfg, key):
+    """A Mamba-2 mixer: in_proj (z | x B C | dt, no bias), the depthwise
+    convolution over x B C, a head's dt bias, A = -exp(A_log) and skip D, the
+    gated norm's weight, out_proj. The published in_proj [E, in_dim] is kept
+    as its three column blocks (`in_z`, `in_xbc`, `in_dt`): 2 d_inner + 2 N +
+    H columns are no whole number of lanes (8,512 = 66.5 x 128 at the
+    published size), and the compiler re-laid the one matrix of the whole
+    stack, transposed, at the head of every decode step (2 x 1.17 GB of
+    temporaries in the AOT account). dt_bias, A_log and D start as the
+    published initialiser has them (dt log-uniform in [1e-3, 1e-1] through
+    the inverse softplus, A uniform in [1, 16], D 1) and stay float32."""
+    s, E = cfg.ssm, cfg.d_model
+    ks = jax.random.split(key, 7)
+    dt = jnp.exp(jax.random.uniform(ks[2], (s.n_heads,), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    return {
+        "in_z": jax.random.normal(ks[0], (E, s.d_inner), cfg.param_dtype) * 0.02,
+        "in_xbc": jax.random.normal(ks[5], (E, s.conv_dim), cfg.param_dtype) * 0.02,
+        "in_dt": jax.random.normal(ks[6], (E, s.n_heads), cfg.param_dtype) * 0.02,
+        "conv_w": jax.random.normal(ks[1], (s.d_conv, s.conv_dim), cfg.param_dtype)
+                  * s.d_conv ** -0.5,
+        "conv_b": jnp.zeros((s.conv_dim,), cfg.param_dtype),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(jax.random.uniform(ks[3], (s.n_heads,), jnp.float32, 1.0, 16.0)),
+        "D": jnp.ones((s.n_heads,), jnp.float32),
+        "norm": jnp.ones((s.d_inner,), cfg.param_dtype),
+        "out_proj": jax.random.normal(ks[4], (s.d_inner, E), cfg.param_dtype) * _out_std(cfg),
+    }
+
+
+def _ssm_layer_params(cfg, key):
+    """One state-space layer: the mixer where an attention layer has `attn`."""
+    ks = jax.random.split(key, 6)
+    return {"norm1": _norm_params(cfg, ks[4]), "mixer": _mixer_params(cfg, ks[0]),
+            "norm2": _norm_params(cfg, ks[4]), "mlp": _dense_mlp_params(cfg, ks[5])}
+
+
+def is_attn_layer(cfg: TransformerConfig, l: int) -> bool:
+    return cfg.ssm is None or l % cfg.ssm.period == cfg.ssm.attn_at
+
+
 def _check(cfg: TransformerConfig) -> None:
+    if cfg.pos not in ("rope", "learned", "none"):
+        raise ValueError(f"pos {cfg.pos!r}: 'rope', 'learned' or 'none'")
+    scaled = (cfg.embedding_multiplier != 1.0 or cfg.residual_multiplier != 1.0
+              or cfg.logits_scaling != 1.0 or cfg.attention_multiplier is not None)
+    if (cfg.ssm or scaled) and (cfg.mla or cfg.window is not None or cfg.n_dense_layers
+                                or cfg.moe or cfg.n_passes > 1 or cfg.sandwich_norms
+                                or cfg.bias):
+        raise ValueError(
+            "state-space layers (ssm) and the scalar multipliers are built for "
+            "a dense stack run once with per-head K and V and no biases: not "
+            "with latent attention, window layers, leading dense layers, "
+            "experts, a looped stack or sandwich norms")
+    if cfg.kv_packed and (cfg.mla or cfg.window is not None or 128 % cfg.head_dim
+                          or cfg.kv_heads * cfg.head_dim % 128):
+        raise ValueError(
+            f"kv_packed packs whole KV heads of {cfg.head_dim} into rows of 128 "
+            f"lanes: per-head K and V of one kind of layer, a head_dim that "
+            f"divides 128 and {cfg.kv_heads} KV heads that fill whole rows")
+    if cfg.ssm is not None:
+        s = cfg.ssm
+        if (cfg.act != "swiglu" or cfg.norm != "rms" or s.period < 2
+                or not 0 <= s.attn_at < s.period or cfg.n_layers % s.period):
+            raise ValueError(
+                f"state-space layers in periods of {s.period} (attention at "
+                f"{s.attn_at}) need whole periods in n_layers {cfg.n_layers}, "
+                "RMS norms and SwiGLU")
     if cfg.mla and (cfg.bias or cfg.pos != "rope" or cfg.n_kv_heads is not None):
         raise ValueError("latent attention (kv_lora_rank) is built with rope, "
                          "without biases and without grouped KV heads")
@@ -291,7 +451,7 @@ def _check(cfg: TransformerConfig) -> None:
 
 
 def scan_layers(block, carry, params, cfg: TransformerConfig, *per_layer,
-                close=None):
+                close=None, ssm_per_layer=()):
     """`block(carry, layer params)`, or with `per_layer` trees (all the
     layers on their leading dimension) `block(carry, (layer params, *their
     slices))`, over every layer in depth order: one lax.scan a stack of
@@ -305,6 +465,14 @@ def scan_layers(block, carry, params, cfg: TransformerConfig, *per_layer,
     body calls `block(..., window=<bool>)`, the kind static: once for the
     period's window layers (a scan of their own), once for its full layer.
 
+    With state-space layers (cfg.ssm) the scan is over whole periods too and
+    the body calls `block(..., ssm=<bool>)`: a scan over the state-space
+    layers before the period's attention layer, that layer, a scan over those
+    after it. The two kinds are stacked apart, so everything is BY KIND:
+    `per_layer` leads with the attention layers, `ssm_per_layer` with the
+    state-space layers, and the outputs come back as (the attention layers',
+    the state-space layers'), each in depth order.
+
     `close(carry, t) -> carry` ends pass `t` over the stack (the callers'
     final norm, `close_pass`). A looped stack (cfg.n_passes = T > 1) is an
     outer lax.scan over the passes whose body is the scan of the stack and
@@ -313,6 +481,9 @@ def scan_layers(block, carry, params, cfg: TransformerConfig, *per_layer,
     t * L + l being layer l's application in pass t, and the outer scan
     hands the inner one its [L, ...] slice."""
     T, L = cfg.n_passes, cfg.n_layers
+    if cfg.ssm is not None:
+        carry, out = _scan_hybrid(block, carry, params, cfg, per_layer, ssm_per_layer)
+        return (carry if close is None else close(carry, 0)), out
     if T == 1:
         carry, out = _scan_stack(block, carry, params, cfg, per_layer)
         return (carry if close is None else close(carry, 0)), out
@@ -397,6 +568,38 @@ def _scan_periods(block, carry, params, cfg: TransformerConfig, per_layer):
     return carry, jax.tree.map(lambda a: a.reshape(L, *a.shape[2:]), out)
 
 
+def _scan_hybrid(block, carry, params, cfg: TransformerConfig, per_layer, ssm_per_layer):
+    """One scan over the periods; inside it the state-space layers before the
+    period's attention layer (a scan of their own), that layer, the
+    state-space layers after it: two traces of `block`, each with its kind
+    static. Layers are taken out of the stack of their kind where they lie,
+    by the scans' own counters (`_scan_periods` says why)."""
+    period, at = cfg.ssm.period, cfg.ssm.attn_at
+
+    def one(c, i, ssm):
+        stack, extra = ((params["ssm_layers"], ssm_per_layer) if ssm
+                        else (params["layers"], per_layer))
+        layer_p, *more = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            (stack, *extra))
+        return block(c, (layer_p, *more) if more else layer_p, ssm=ssm)
+
+    def run(c, first, n):
+        return jax.lax.scan(lambda c, i: one(c, first + i, True), c,
+                            jnp.arange(n, dtype=jnp.int32))
+
+    def body(c, p):
+        c, before = run(c, p * (period - 1), at)
+        c, out = one(c, p, False)
+        c, after = run(c, p * (period - 1) + at, period - 1 - at)
+        return c, (out, jax.tree.map(lambda a, b: jnp.concatenate([a, b]), before, after))
+
+    carry, (out, ssm_out) = jax.lax.scan(
+        body, carry, jnp.arange(cfg.n_layers // period, dtype=jnp.int32))
+    return carry, (out, jax.tree.map(
+        lambda a: a.reshape(cfg.n_ssm_layers, *a.shape[2:]), ssm_out))
+
+
 def rope_tables(cfg: TransformerConfig, window: bool = False):
     """(cos, sin) of one kind of layer: YaRN's (cfg.yarn) on full layers."""
     return ops.rope_frequencies(cfg.rope_dim, cfg.max_seq_len, theta=cfg.rope_theta,
@@ -449,11 +652,18 @@ def init(key, cfg: TransformerConfig):
     _check(cfg)
     k_emb, k_pos, k_layers, k_head = jax.random.split(key, 4)
     keys = jax.random.split(k_layers, cfg.n_layers)
+    if cfg.ssm is None:
+        attn_keys = keys[cfg.n_dense_layers:]
+    else:  # a layer's key is its own whatever its kind
+        attn = np.asarray([is_attn_layer(cfg, l) for l in range(cfg.n_layers)])
+        attn_keys = keys[attn]
     params = {
         "embed": jax.random.normal(k_emb, (cfg.vocab_size, cfg.d_model), cfg.param_dtype) * 0.02,
-        "layers": jax.vmap(lambda k: _layer_params(cfg, k))(keys[cfg.n_dense_layers:]),
+        "layers": jax.vmap(lambda k: _layer_params(cfg, k))(attn_keys),
         "final_norm": _norm_params(cfg, k_head),
     }
+    if cfg.ssm is not None:
+        params["ssm_layers"] = jax.vmap(lambda k: _ssm_layer_params(cfg, k))(keys[~attn])
     if cfg.n_dense_layers:
         params["dense_layers"] = jax.vmap(lambda k: _layer_params(cfg, k, dense=True))(
             keys[:cfg.n_dense_layers])
@@ -515,6 +725,17 @@ def logical_axes(cfg: TransformerConfig):
     }
     if cfg.n_dense_layers:
         out["dense_layers"] = stacked(swiglu)
+    if cfg.ssm is not None:
+        # the mixer's inner width splits like an MLP's; what x, B, C and dt
+        # share a matrix with does not
+        mixer = {"in_z": ("embed", "mlp"), "in_xbc": ("embed", None),
+                 "in_dt": ("embed", None), "conv_w": (None, None), "conv_b": (None,),
+                 "dt_bias": (None,), "A_log": (None,), "D": (None,),
+                 "norm": ("mlp",), "out_proj": ("mlp", "embed")}
+        out["ssm_layers"] = jax.tree.map(
+            lambda t: ("layers",) + t,
+            {"norm1": norm, "mixer": mixer, "norm2": norm, "mlp": swiglu},
+            is_leaf=lambda x: isinstance(x, tuple))
     if cfg.pos == "learned":
         out["pos_embed"] = (None, "embed")
     if not cfg.tie_embeddings:
@@ -537,7 +758,77 @@ def _residual(h, delta, layer_p, post: str, cfg):
     where the block has sandwich norms."""
     if cfg.sandwich_norms:
         delta = _norm(delta, layer_p[post], cfg)
+    if cfg.residual_multiplier != 1.0:
+        delta = delta * jnp.asarray(cfg.residual_multiplier, delta.dtype)
     return h + delta
+
+
+def embed_tokens(params, tokens, cfg):
+    """The embedding's rows of `tokens` in cfg.dtype, times the model's
+    `embedding_multiplier`."""
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
+def lm_logits(x, params, cfg):
+    """x [..., E] (after the final norm) -> logits [..., V] in cfg.dtype: the
+    tied embedding or the head, divided by the model's `logits_scaling`."""
+    dt = cfg.dtype
+    if cfg.tie_embeddings:
+        logits = x @ params["embed"].astype(dt).T
+    else:
+        logits = x @ params["lm_head"].astype(dt)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
+    return logits
+
+
+def mixer_project(x, p, cfg):
+    """Normed x [T, E] -> (z [T, d_inner] the gate, xBC [T, conv_dim] before
+    the convolution, dt [T, H] float32 = softplus(. + dt_bias))."""
+    dt = jax.nn.softplus((x @ p["in_dt"].astype(cfg.dtype)).astype(jnp.float32)
+                         + p["dt_bias"].astype(jnp.float32))
+    return x @ p["in_z"].astype(cfg.dtype), x @ p["in_xbc"].astype(cfg.dtype), dt
+
+
+def mixer_split(xBC, cfg):
+    """Convolved xBC [T, conv_dim] after its SiLU -> (x [T, H, P], B [T, N],
+    C [T, N])."""
+    s = cfg.ssm
+    xBC = jax.nn.silu(xBC)
+    return (xBC[..., :s.d_inner].reshape(*xBC.shape[:-1], s.n_heads, s.d_head),
+            xBC[..., s.d_inner:s.d_inner + s.d_state], xBC[..., s.d_inner + s.d_state:])
+
+
+def mixer_out(y, x, z, p, cfg):
+    """y [T, H, P] float32 (h_t C_t) + D x, gated by silu(z) FIRST, then the
+    RMS norm over all of d_inner (one group), then out_proj -> [T, E]."""
+    y = y + p["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+    y = y.reshape(*y.shape[:-2], cfg.ssm.d_inner) * jax.nn.silu(z.astype(jnp.float32))
+    y = ops.rms_norm(y, p["norm"], eps=cfg.norm_eps).astype(cfg.dtype)
+    return y @ p["out_proj"].astype(cfg.dtype)
+
+
+def mamba_mixer(x, p, cfg, length=None, state=None, tail=None):
+    """The Mamba-2 mixer over one sequence, normed x [T, E] -> (its output
+    before the residual [T, E], the state after position length - 1 [H, P, N]
+    float32, the convolution's tail there [d_conv - 1, conv_dim]). `length`:
+    the real positions (None: all T); past them dt is 0, so padding leaves
+    the state as it was. `state` / `tail`: what came before position 0 (a
+    chunk's continuation; None: a row's start)."""
+    s, T = cfg.ssm, x.shape[0]
+    z, xBC, dt = mixer_project(x, p, cfg)
+    if tail is None:
+        tail = jnp.zeros((s.d_conv - 1, s.conv_dim), xBC.dtype)
+    if length is not None:
+        dt = jnp.where((jnp.arange(T) < length)[:, None], dt, 0.0)
+    xs, B, C = mixer_split(ops.causal_conv(xBC, tail, p["conv_w"], p["conv_b"]), cfg)
+    y, state = ops.ssm_chunk_scan(xs, dt, -jnp.exp(p["A_log"].astype(jnp.float32)), B, C,
+                                  chunk=s.chunk, state=state, dtype=cfg.dtype)
+    return (mixer_out(y, xs, z, p, cfg), state,
+            ops.conv_tail(xBC, tail, T if length is None else length))
 
 
 def _to_lanes(x, cfg):
@@ -595,7 +886,7 @@ def _attn_block(x, p, cfg, cos, sin, sp_axis, attn_impl, window=None):
         q, latent = _mla_project(x, p, cfg, cos, sin)
         k, v = _mla_expand(latent, p, cfg)
         # v heads are narrower than q/k heads: not the flash kernel's shape
-        out = ops.attention(q, k, v, causal=True, scale=cfg.qk_dim ** -0.5,
+        out = ops.attention(q, k, v, causal=True, scale=cfg.softmax_scale,
                             impl="reference")
         return jnp.einsum("bthd,hde->bte", out, p["wo"].astype(dt))
     q = jnp.einsum("bte,ehd->bthd", x, p["wq"].astype(dt))
@@ -616,8 +907,8 @@ def _attn_block(x, p, cfg, cos, sin, sp_axis, attn_impl, window=None):
         else:
             q = ops.apply_rope(q, cos, sin)
             k = ops.apply_rope(k, cos, sin)
-    out = ops.attention(q, k, v, causal=True, sp_axis=sp_axis, impl=attn_impl,
-                        window=window)
+    out = ops.attention(q, k, v, causal=True, scale=cfg.softmax_scale, sp_axis=sp_axis,
+                        impl=attn_impl, window=window)
     out = jnp.einsum("bthd,hde->bte", out, p["wo"].astype(dt))
     if cfg.bias:
         out = out + p["bo"].astype(dt)
@@ -686,8 +977,10 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
     (logits, aux_loss); with return_hidden=True, returns the pre-head hidden
     states [B, T, E] instead of logits; with return_exit=True (a model with
     the exit gate) a third value, `exit_distribution` [n_passes, B, T]."""
+    x = embed_tokens(params, tokens, cfg)
     dt = cfg.dtype
-    x = params["embed"].astype(dt)[tokens]
+    if cfg.ssm is not None and sp_axis is not None:
+        raise ValueError("state-space layers have no sequence-parallel form")
     if cfg.pos == "learned":
         T = tokens.shape[1]
         if sp_axis is not None:
@@ -702,12 +995,15 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
 
     aux_total = jnp.zeros((), jnp.float32)
 
-    def block(carry, layer_p, window=False):
+    def block(carry, layer_p, window=False, ssm=False):
         h, aux, gates = carry
-        h = _residual(h, _attn_block(_norm(h, layer_p["norm1"], cfg), layer_p["attn"],
-                                     cfg, *rope[window], sp_axis, attn_impl,
-                                     cfg.window if window else None),
-                      layer_p, "post_attn_norm", cfg)
+        normed = _norm(h, layer_p["norm1"], cfg)
+        if ssm:
+            mixed = jax.vmap(lambda row: mamba_mixer(row, layer_p["mixer"], cfg)[0])(normed)
+        else:
+            mixed = _attn_block(normed, layer_p["attn"], cfg, *rope[window], sp_axis,
+                                attn_impl, cfg.window if window else None)
+        h = _residual(h, mixed, layer_p, "post_attn_norm", cfg)
         normed = _norm(h, layer_p["norm2"], cfg)
         if "router" in layer_p["mlp"]:
             delta, layer_aux = _moe_mlp(normed, layer_p["mlp"], cfg)
@@ -718,7 +1014,7 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
 
     if cfg.remat and cfg.remat_policy == "pairs" and (
             cfg.n_layers % 2 or cfg.moe or cfg.n_dense_layers or cfg.window
-            or cfg.n_passes > 1 or cfg.exit_gate):
+            or cfg.n_passes > 1 or cfg.exit_gate or cfg.ssm):
         raise ValueError(
             "remat_policy='pairs' needs an even n_layers and a dense (non-"
             "MoE) stack of one kind of layer; falling back silently would "
@@ -750,8 +1046,8 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
                       else jax.checkpoint_policies.nothing_saveable)
             inner = block
 
-            def block(carry, layer_p, window=False):
-                return jax.checkpoint(functools.partial(inner, window=window),
+            def block(carry, layer_p, **kind):
+                return jax.checkpoint(functools.partial(inner, **kind),
                                       policy=policy)(carry, layer_p)
         gates = (jnp.zeros((cfg.n_passes,) + tokens.shape, jnp.float32)
                  if cfg.exit_gate else None)
@@ -766,11 +1062,7 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
     leave = (exit_distribution(gates),) if return_exit else ()
     if return_hidden:
         return (x, aux_total) + leave
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"].astype(dt).T
-    else:
-        logits = x @ params["lm_head"].astype(dt)
-    return (logits, aux_total) + leave
+    return (lm_logits(x, params, cfg), aux_total) + leave
 
 
 def loss_fn(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = None,

@@ -9,12 +9,16 @@ from ray_tpu.ops.norms import layer_norm, rms_norm
 from ray_tpu.ops.ragged_paged_attention import (
     ragged_decode_attention, ragged_decode_attention_reference)
 from ray_tpu.ops.rope import Yarn, apply_rope, rope_frequencies
+from ray_tpu.ops.ssm import (causal_conv, conv_tail, live_rows, ssm_chunk_scan,
+                             ssm_state_update)
 
 __all__ = [
     "RoutingInfo",
     "Yarn",
     "apply_rope",
     "attention",
+    "causal_conv",
+    "conv_tail",
     "flash_attention",
     "flash_attention_forward",
     "fused_head_cross_entropy",
@@ -22,6 +26,7 @@ __all__ = [
     "gelu",
     "grouped_matmul",
     "layer_norm",
+    "live_rows",
     "moe_apply",
     "moe_sorted",
     "onehot_dispatch",
@@ -34,5 +39,7 @@ __all__ = [
     "softmax_cross_entropy",
     "softmax_topk",
     "sorted_pays",
+    "ssm_chunk_scan",
+    "ssm_state_update",
     "swiglu",
 ]

@@ -63,6 +63,17 @@ def _observe_accept(seconds: float) -> None:
         logger.debug("proxy accept-phase metric emit failed: %r", e)
 
 
+# Routes that read a deployment's own state (last path segment). They are
+# answered on two threads of their own: the request pool's threads are held
+# by streaming answers for their whole life, and a reading of the state that
+# queued behind those would say what the state was seconds later.
+STATE_ROUTES = ("stats", "health", "metrics")
+
+
+def _reads_state(path: str) -> bool:
+    return path.partition("?")[0].rstrip("/").rpartition("/")[2] in STATE_ROUTES
+
+
 class AsyncHTTPServer:
     """`handler(method, path, headers, body)` returns
     (status, content_type, payload_bytes) for plain responses or
@@ -87,6 +98,8 @@ class AsyncHTTPServer:
         self._max_connections = max_connections
         self._executor = ThreadPoolExecutor(
             max_workers=executor_workers, thread_name_prefix="serve-http")
+        self._state_executor = ThreadPoolExecutor(
+            max_workers=2, thread_name_prefix="serve-http-state")
         self._loop: asyncio.AbstractEventLoop | None = None
         self._server: asyncio.base_events.Server | None = None
         self._inflight = 0
@@ -254,7 +267,8 @@ class AsyncHTTPServer:
 
         extra: dict | None = None
         try:
-            result = await loop.run_in_executor(self._executor, _run_handler)
+            pool = self._state_executor if _reads_state(path) else self._executor
+            result = await loop.run_in_executor(pool, _run_handler)
             if len(result) == 4:  # optional extra headers (e.g. Retry-After)
                 status, ctype, payload, extra = result
             else:
@@ -379,4 +393,5 @@ class AsyncHTTPServer:
 
         loop.call_soon_threadsafe(_cancel_all)
         self._executor.shutdown(wait=False)
+        self._state_executor.shutdown(wait=False)
         self._thread.join(timeout=5.0)

@@ -182,3 +182,51 @@ def test_stop_ends_the_loop_thread_at_once():
     srv.stop(graceful=True)
     assert time.monotonic() - t0 < 2.0
     assert not srv._thread.is_alive()
+
+
+def test_a_state_route_answers_while_streams_hold_every_request_thread():
+    """A streamed answer holds one of the request pool's threads for its
+    whole life and handlers queue behind the pumps, so with the pool full a
+    request waits; a route that reads the deployment's own state (`.../stats`)
+    is answered on threads of its own, at once."""
+    from ray_tpu.serve.http_server import AsyncHTTPServer, _reads_state
+
+    assert _reads_state("/v1/stats") and _reads_state("/app/health/?x=1")
+    assert not _reads_state("/v1/completions") and not _reads_state("/stats/now")
+    release = threading.Event()
+
+    def handler(method, path, headers, body):
+        if path.endswith("/stats"):
+            return 200, "application/json", b'{"answer": 1}'
+        if path.endswith("/stream"):
+            def chunks():
+                yield b"data: 0\n\n"
+                release.wait(20)
+            return 200, "text/event-stream", chunks()
+        return 200, "application/json", b"{}"
+
+    srv = AsyncHTTPServer(handler, "127.0.0.1", 0, executor_workers=2).start()
+    base = f"http://127.0.0.1:{srv.port}"
+    streams = [threading.Thread(target=lambda: urllib.request.urlopen(
+        urllib.request.Request(base + "/stream", data=b"{}"), timeout=30).read())
+        for _ in range(2)]
+    try:
+        for t in streams:
+            t.start()
+        time.sleep(0.5)                                   # both pumps hold their thread
+        late = []
+        other = threading.Thread(target=lambda: late.append(_post(base + "/x", {})))
+        other.start()
+        t0 = time.monotonic()
+        assert _post(base + "/v1/stats", {}) == (200, {"answer": 1})
+        assert time.monotonic() - t0 < 2.0
+        other.join(1.0)
+        assert other.is_alive() and not late              # a request waits for a thread
+        release.set()
+        other.join(10)
+        assert late == [(200, {})]
+    finally:
+        release.set()
+        for t in streams:
+            t.join(10)
+        srv.stop(graceful=False)

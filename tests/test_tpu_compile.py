@@ -392,3 +392,104 @@ def test_kernel_names_reach_the_compiled_program(chip):
         sds((B, nb), jnp.int32), sds((B,), jnp.int32))
     calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
     assert [c.split(".")[0] for c in calls] == ["ragged_paged_attention"]
+
+
+# ---- granite-4.0-h-micro.chat-saturated: a recurrent state a slot beside pages
+
+GRANITE_CELL = (104, 1600, 2601, 64)    # slots, max_len, pages, page: the cell's engine
+
+
+def _granite(chip):
+    """(cfg, weights, state) of `granite-4.0-h-micro` as its cell runs it:
+    all 40 layers (a scan of 4 periods compiles one period), every width."""
+    from ray_tpu.models import granite_config
+
+    cfg = granite_config("4.0-h-micro", param_dtype=jnp.bfloat16, max_seq_len=1600)
+    params, state = _abstract_step_inputs(chip, cfg, *GRANITE_CELL)
+    assert state["ssm"].shape == (36, 104, 64, 64, 128) and state["ssm"].dtype == jnp.float32
+    assert state["conv"].shape == (36, 104, 3, 4352)
+    # KV heads of 64, two a row of 128 lanes (cfg.kv_packed)
+    assert state["kp"].shape == state["vp"].shape == (4, 2601, 64, 4, 128)
+    return cfg, params, state
+
+
+def _held_bytes(state) -> int:
+    import math
+
+    return sum(math.prod(state[k].shape) * state[k].dtype.itemsize
+               for k in ("ssm", "conv", "kp", "vp"))
+
+
+def test_granite_decode_step_updates_state_and_pools_where_they_lie(chip):
+    """The decode program of `granite-4.0-h-micro.chat-saturated` at the
+    cell's shapes: the recurrent state (7.85 GB; it cannot be held twice
+    beside 6.38 GB of weights), the convolution's tails and both pools alias
+    input to output; `ssm_state_update` once a scan of state-space layers
+    (before and after a period's attention layer), the ragged launch once.
+
+    Temporaries 1,879,040 bytes. They were 4.69 GB, which did not fit, in two
+    earlier forms of this PR: the published `in_proj` [36, 2048, 8512] as ONE
+    matrix was re-laid whole, transposed, at the head of the step (8,512
+    columns are 66.5 x 128 lanes; 2 x 1.17 GB), and pools of [.., 8, 64] were
+    copied whole into a layout padded to 128 lanes (2 x 1.17 GB for 2 x 0.63
+    GB of pool): hence the mixer's three input matrices and `kv_packed`."""
+    from ray_tpu.models import decoding_paged
+
+    cfg, params, state = _granite(chip)
+    compiled = decoding_paged.decode_step_paged_ragged.lower(
+        params, state, cfg, 32, True).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= _held_bytes(state)
+    assert m.temp_size_in_bytes < 16 * 2**20
+    assert _kernel_calls(compiled.as_text()) == [
+        "ssm_state_update", "ssm_state_update", "ragged_paged_attention"]
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+             + m.temp_size_in_bytes)
+    assert total < 15.8e9                              # of 16,909,336,064 on the chip
+
+
+@pytest.mark.parametrize("writer", ["insert_sequence_paged", "insert_sequence_paged_prefix",
+                                    "write_kv_pages", "activate_slot"])
+def test_granite_writers_update_state_and_pools_where_they_lie(chip, writer):
+    """What puts a prefilled row into the cache at the cell's shapes: a
+    1,024-token prompt's pages of the four attention layers and the row's
+    recurrent state (75.5 MB) and tail into its slot, all aliased, no
+    temporary of a pool's or the state's size."""
+    from ray_tpu.models import decoding_paged as dp
+
+    cfg, _, state = _granite(chip)
+
+    def sds(s, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(s, dt, sharding=chip)
+
+    kv = {x: sds((4, 1024, 8, 64), cfg.dtype) for x in "kv"}
+    row_state = {"ssm": sds((36, 64, 64, 128), jnp.float32),
+                 "conv": sds((36, 3, 4352), cfg.dtype)}
+    ids, row = sds((16,)), sds(state["block"].shape[1:])
+    if writer == "insert_sequence_paged":
+        lowered = dp.insert_sequence_paged.lower(
+            state, sds(()), {**kv, **row_state}, sds(()), sds(()), row, cfg)
+    elif writer == "insert_sequence_paged_prefix":
+        lowered = dp.insert_sequence_paged_prefix.lower(
+            state, sds(()), {**kv, **row_state}, ids, row, sds(()), sds(()), cfg)
+    elif writer == "write_kv_pages":
+        lowered = dp.write_kv_pages.lower(state, kv, ids)
+    else:
+        lowered = dp.activate_slot.lower(state, sds(()), row, sds(()), sds(()), None, row_state)
+    m = lowered.compile().memory_analysis()
+    assert m.alias_size_in_bytes >= _held_bytes(state)
+    assert m.temp_size_in_bytes < 2**20
+
+
+def test_granite_prefill_fits_beside_the_state(chip):
+    """The largest program the cell's mix reaches, the 1,024-token prefill
+    (the chunked scan in plain XLA, no kernel of its own; flash attention on
+    the four attention layers), beside weights, state and pools."""
+    cfg, params, state = _granite(chip)
+    prefill = _prefill_1024(chip, params, cfg)
+    m = prefill.memory_analysis()
+    assert m.temp_size_in_bytes < 256 * 2**20          # 178,619,392
+    assert m.output_size_in_bytes < 96 * 2**20         # the row's state, its K and V
+    held = _held_bytes(state) + m.argument_size_in_bytes
+    assert held + m.temp_size_in_bytes + m.output_size_in_bytes < 16.0e9
+    assert "ssm_state_update" not in prefill.as_text()
