@@ -39,6 +39,7 @@ from ray_tpu.exceptions import DeadlineExceededError, RequestCancelledError
 from ray_tpu.models import decoding
 from ray_tpu.models import decoding_paged as dp
 from ray_tpu.models.transformer import TransformerConfig
+from ray_tpu.ops.ragged_paged_attention import table_width, walked_positions
 from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
@@ -583,6 +584,11 @@ class TPUEngine:
         # rows' positions (sum of length0 + generated), the part of them
         # beyond the window, the staged prefills' tokens
         self.window_context_tokens = 0
+        # ... positions held by the blocks of pages that the per-head
+        # launch walked on a full layer (ops/ragged_paged_attention.py: a
+        # row's live pages rounded up to whole blocks), of which
+        # context_tokens were attended
+        self.ragged_block_positions = 0
         self.held_token_steps = 0
         self.held_byte_steps = 0
         self.window_page_steps_used = 0
@@ -2123,6 +2129,15 @@ class TPUEngine:
                                  + wgranted * self._wpage_bytes)
         self.window_page_steps_used += wgranted
         self.window_page_steps_total += max(self.window_pages - 1, 0)
+        if not self.cfg.mla:
+            # the blocks of pages a full layer's launch walked for these rows,
+            # through the table it was handed (ops/ragged_paged_attention.py)
+            self.ragged_block_positions += walked_positions(
+                np.array([req.length0 + req.dispatched - 1 for _, req in rows]),
+                page_size=self.page_size, kv_heads=self.cfg.kv_row[0],
+                head_dim=self.cfg.kv_row[1], itemsize=self.state["kp"].dtype.itemsize,
+                table_pages=table_width(self.max_pages_per_seq, pages_bound,
+                                        self.cfg.kv_row[1], self._ragged_kernel))
         for _, req in rows:
             req.dispatched += 1
             self._live_tokens += 1
@@ -2211,6 +2226,8 @@ class TPUEngine:
             "page_steps_total": self.page_steps_total,
             "held_token_steps": self.held_token_steps,
             "held_byte_steps": self.held_byte_steps}
+        if not self.cfg.mla:
+            out["cache"]["ragged_block_positions"] = self.ragged_block_positions
         if self.cfg.ssm:
             out["cache"]["state_bytes_per_row"] = self._state_bytes_per_row
         if self.ring:

@@ -330,10 +330,13 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
                              lora_bank=None, slot_lora=None):
     """Advance every active row one token: one scatter of the step's rows a
     layer, then ONE ragged attention launch over the batch's block tables
-    (ops/ragged_paged_attention.py): no [B, max_pages*page] gather, and the
-    sweep stops at `pages_bound` — the engine's host-side bound on the
-    batch's LIVE page count (power of two, so compile count stays
-    O(log(max_pages))). `kernel=True` runs the Pallas TPU kernel; False
+    (ops/ragged_paged_attention.py): no [B, max_pages*page] gather. For the
+    reference (and the latent kernel) the table is cut to `pages_bound`
+    columns — the engine's host-side bound on the batch's LIVE page count
+    (power of two, so compile count stays O(log(max_pages))) — which bounds
+    their walk; the per-head kernel takes the table whole and walks each
+    active row's own pages, blocks of them at a time, and no page of a row
+    that is not active. `kernel=True` runs the Pallas TPU kernel; False
     runs the bit-consistent pure-JAX reference (the CPU path).
 
     The attention core sees qh [B, Hkv, G, Dh] against the FLAT pools
@@ -363,11 +366,14 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
     (`ops.ssm_state_update`: the Pallas kernel with `kernel`, `jax.numpy`
     without); an inactive row keeps its state and its tail, and the kernel
     does not touch it."""
-    from ray_tpu.ops.ragged_paged_attention import ragged_decode_attention
+    from ray_tpu.ops.ragged_paged_attention import ragged_decode_attention, table_width
 
-    # the ragged sweep only walks the batch's live prefix of each table;
-    # positions past a row's `pos` inside that prefix are masked in-kernel
-    tbl = state["block"][:, :pages_bound]
+    # the launches whose sweep the table's width bounds get the batch's live
+    # prefix of it (the reference, the latent kernel's grid); the per-head
+    # kernel that walks each row's own pages takes it whole (`table_width`)
+    nb = pages_bound if cfg.mla else table_width(
+        state["block"].shape[1], pages_bound, state["kp"].shape[-1], kernel)
+    tbl = state["block"][:, :nb]
     dt = cfg.dtype
     B = state["block"].shape[0]
     L, num_pages, P = state["kp"].shape[:3]
@@ -404,11 +410,14 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
         wtbl = _ring_pages(state["wblock"], state["wring"], logical)
         wpage_ids = jnp.where(state["active"], wtbl[:, -1], 0)
 
+    # a row that is not active walks no page (the launch's `pos` < 0)
+    walked = jnp.where(state["active"], pos, -1)
+
     def attend(qh, kp, vp, base, window=False):
         if cfg.kv_packed:
             qh = _pack_queries(qh, cfg.head_dim)
         out = ragged_decode_attention(
-            qh, kp, vp, base + (wtbl if window else tbl), pos,
+            qh, kp, vp, base + (wtbl if window else tbl), walked,
             scale=cfg.softmax_scale, impl="kernel" if kernel else "reference",
             window=cfg.window if window else None)
         return _unpack_outputs(out, cfg.head_dim) if cfg.kv_packed else out

@@ -226,3 +226,70 @@ def test_paged_constructor_validation(tiny_model):
         TPUEngine(cfg, params, page_size=0, max_len=64)
     with pytest.raises(ValueError, match="multiple of"):
         TPUEngine(cfg, params, page_size=32, max_len=72)
+
+
+# heads of 128, whose pages the kernel copies by hand, four a block here; heads
+# of 16, whose pages the pipeline brings, one a block
+@pytest.mark.parametrize("d_head,block", [(128, 4), (16, 1)])
+def test_ragged_block_positions_counts_the_walked_blocks(monkeypatch, d_head, block):
+    """`stats()["cache"]["ragged_block_positions"]`: for each live row and
+    decode step, the positions held by the blocks of pages that the per-head
+    launch walks (ops/ragged_paged_attention.py: the row's live pages rounded
+    up to whole blocks), hand-counted here for rows of 1, 65, 254 and 700
+    positions, alone and then together; never under `context_tokens`, the
+    positions of them that are attended."""
+    import ray_tpu.ops.ragged_paged_attention as rpa
+    from ray_tpu.llm import SamplingParams, TPUEngine
+
+    P = 64
+    cfg = TransformerConfig(**{**TINY, "max_seq_len": 1024, "d_head": d_head})
+    params = transformer.init(jax.random.PRNGKey(0), cfg)
+    # a budget of two blocks of 4 pages, K and V: float32 pages of 2 heads
+    monkeypatch.setattr(rpa, "_BLOCK_VMEM_BYTES", 2 * 4 * (2 * P * 2 * d_head * 4))
+    eng = TPUEngine(cfg, params, max_slots=4, max_len=768, page_size=P)
+    try:
+        attended = walked = 0
+        for n in (1, 65, 254, 700):
+            eng.generate([1 + i % 100 for i in range(n)],
+                         SamplingParams(max_tokens=4, temperature=0.0))
+            for pos in range(n, n + 3):        # three decode steps, the row alone
+                pages = pos // P + 1           # 1; 2; 4, 4, 5; 11
+                bound = 1 << (pages - 1).bit_length()
+                blocks = -(-pages // min(block, bound))
+                attended += pos + 1
+                walked += blocks * min(block, bound) * P
+            cache = eng.stats()["cache"]
+            assert cache["context_tokens"] == attended
+            assert cache["ragged_block_positions"] == walked
+        assert walked == {4: 3 * 64 + 3 * 128 + (256 + 256 + 512) + 3 * 768,
+                          1: 3 * 64 + 3 * 128 + (256 + 256 + 320) + 3 * 704}[block]
+        threads = [threading.Thread(target=eng.generate, args=(
+            [1 + i % 100 for i in range(n)], SamplingParams(max_tokens=6, temperature=0.0)))
+            for n in (1, 65, 700)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        cache = eng.stats()["cache"]
+        assert cache["context_tokens"] == attended + sum(
+            n + i + 1 for n in (1, 65, 700) for i in range(5))
+        # a row walks whole pages: 5 steps each of at least 1, 2 and 11 pages
+        grew = cache["ragged_block_positions"] - walked
+        assert grew % P == 0 and grew >= 5 * (1 + 2 + 11) * P
+        assert cache["ragged_block_positions"] >= cache["context_tokens"]
+    finally:
+        eng.shutdown()
+
+
+def test_latent_cache_reports_no_ragged_blocks():
+    """A latent pool decodes through `_latent_kernel`, a page a step: the
+    per-head launch's counter is not in its record."""
+    from ray_tpu.llm import TPUEngine
+
+    cfg = _family("kimi_vl")
+    eng = TPUEngine(cfg, transformer.init(jax.random.PRNGKey(0), cfg),
+                    max_slots=2, max_len=64, page_size=8)
+    try:
+        assert "ragged_block_positions" not in eng.stats()["cache"]
+    finally:
+        eng.shutdown()

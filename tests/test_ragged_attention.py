@@ -42,39 +42,114 @@ def _rand_case(rng, *, B=8, Hkv=2, G=2, Dh=16, P=16, N=33, nb=4):
     return q, kp, vp, tbl, pos
 
 
-def test_kernel_bit_consistent_with_reference():
-    """The tier-1 acceptance bar: interpret-mode kernel output is BITWISE
-    equal to the reference the CPU engine decodes with."""
-    rng = np.random.default_rng(0)
-    for seed in range(3):
-        q, kp, vp, tbl, pos = _rand_case(np.random.default_rng(seed))
-        ref = ragged_decode_attention(q, kp, vp, tbl, pos, impl="reference")
-        ker = ragged_decode_attention(q, kp, vp, tbl, pos, impl="kernel",
-                                      interpret=True)
-        assert np.array_equal(np.asarray(ref), np.asarray(ker)), \
-            f"kernel diverged from reference (seed {seed}): " \
-            f"max diff {np.max(np.abs(np.asarray(ref) - np.asarray(ker)))}"
-    del rng
+# the shapes the cells run (Hkv x G x Dh, and whether the pool packs its heads)
+CELL_SHAPES = {"mixtral-8x4x128": (8, 4, 128, False), "mellum2-4x8x128": (4, 8, 128, False),
+               "ouro-16x1x128": (16, 1, 128, False),
+               # granite's 8 x 4 x 64 as `_pack_queries` hands it over: 4 heads
+               # of 128 lanes, the other head's lanes zero
+               "granite-packed-4x8x128": (8, 4, 64, True),
+               # chip_smoke's Llama-1B, heads of 64 as they are: the pages are
+               # not the kernel's to copy by hand, and a block is one page
+               "llama-1b-8x4x64": (8, 4, 64, False)}
+CELL_PAGE = 64
+BLOCKS = 4            # blocks of pages the full-attention table is wide
+WINDOW_PAGES = 4
 
 
-def test_reference_matches_dense_masked_softmax():
-    """Semantics: the online-softmax page sweep equals one dense masked
-    softmax over the gathered pages."""
-    q, kp, vp, tbl, pos = _rand_case(np.random.default_rng(7))
+def _row_mix(mix, P, T, nb):
+    """Positions of a batch of 8 that a block boundary can get wrong (T: the
+    positions of a block, nb: the table's pages; -1: an inactive row)."""
+    return {
+        # one position, a page's last lane, exactly one block, a block and a
+        # page, the first lane of the second block and of the second page
+        "edges": [0, P - 1, T - 1, T + P - 1, T, P, 2 * T - 1, 1],
+        # the longest row the table allows beside rows of one page
+        "longest-beside-short": [nb * P - 1, 3, P - 1, 0, nb * P - 1, 17, P - 2, nb * P - 2],
+        "inactive-between-live": [-1, 40, -1, -1, T + 5, -1, nb * P - 1, -1],
+    }[mix]
+
+
+def _cell_case(shape, window, mix, seed=0):
+    """bfloat16 pools as a cell stores them, a table whose pages lie
+    scattered and out of order in the pool, one of `_row_mix`'s batches."""
+    from ray_tpu.ops.ragged_paged_attention import pages_per_block
+
+    Hkv, G, Dh, packed = CELL_SHAPES[shape]
+    rng = np.random.default_rng(seed)
+    B, P = 8, CELL_PAGE
+    q = jnp.asarray(rng.standard_normal((B, Hkv, G, Dh)), jnp.bfloat16)
+    if packed:
+        q, Hkv, G, Dh = dp._pack_queries(q, Dh), Hkv * Dh // 128, G * 128 // Dh, 128
+    W = WINDOW_PAGES * P if window else None
+    n = pages_per_block(P, Hkv, Dh, 2, WINDOW_PAGES + 1 if window else 1 << 20, W)
+    nb = WINDOW_PAGES + 1 if window else BLOCKS * n
+    N = B * nb + 1
+    kp = jnp.asarray(rng.standard_normal((N, P, Hkv, Dh)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((N, P, Hkv, Dh)), jnp.bfloat16)
+    tbl = jnp.asarray(1 + rng.permutation(N - 1).reshape(B, nb), jnp.int32)
+    # a window's rows reach past it: the same mixes, two windows further on
+    pos = np.asarray(_row_mix(mix, P, n * P, BLOCKS * n if window else nb))
+    return q, kp, vp, tbl, jnp.asarray(pos, jnp.int32), W
+
+
+def _dense(q, kp, vp, tbl, pos, window=None):
+    """One dense masked softmax over the gathered pages, float32; a row at
+    pos < 0 attends nothing and reads 0."""
     B, Hkv, G, Dh = q.shape
-    P = kp.shape[1]
-    nb = tbl.shape[1]
-    S = nb * P
-    out = ragged_decode_attention_reference(q, kp, vp, tbl, pos,
-                                            scale=Dh ** -0.5)
-    k = kp[tbl].reshape(B, S, Hkv, Dh).astype(jnp.float32)
-    v = vp[tbl].reshape(B, S, Hkv, Dh).astype(jnp.float32)
+    P, nb = kp.shape[1], tbl.shape[1]
+    k = kp[tbl].reshape(B, nb * P, Hkv, Dh).astype(jnp.float32)
+    v = vp[tbl].reshape(B, nb * P, Hkv, Dh).astype(jnp.float32)
     s = jnp.einsum("bkgd,bskd->bkgs", q.astype(jnp.float32), k) * (Dh ** -0.5)
-    mask = jnp.arange(S)[None, :] <= pos[:, None]
+    kpos = jnp.arange(nb * P)[None, :]
+    if window is not None:   # column j is logical page pos // P - W // P + j
+        kpos = kpos + ((pos // P - window // P) * P)[:, None]
+    mask = (kpos >= 0) & (kpos <= pos[:, None])
+    if window is not None:
+        mask &= kpos > pos[:, None] - window
     s = jnp.where(mask[:, None, None, :], s, -1e30)
-    dense = jnp.einsum("bkgs,bskd->bkgd", jax.nn.softmax(s, axis=-1), v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
-                               atol=1e-5, rtol=1e-5)
+    out = jnp.einsum("bkgs,bskd->bkgd", jax.nn.softmax(s, axis=-1), v)
+    return jnp.where((pos >= 0)[:, None, None, None], out, 0.0)
+
+
+CASES = ([pytest.param(("small", seed), id=f"small-float32-{seed}") for seed in range(3)]
+         + [pytest.param((shape, window, mix), id=f"{shape}-{'window' if window else 'full'}-{mix}")
+            for shape in CELL_SHAPES for window in (False, True)
+            for mix in ("edges", "longest-beside-short", "inactive-between-live")])
+
+
+def _case(case):
+    if case[0] == "small":
+        return (*_rand_case(np.random.default_rng(case[1])), None)
+    return _cell_case(*case)
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_kernel_bit_consistent_with_reference(case):
+    """The tier-1 acceptance bar: interpret-mode kernel output is BITWISE
+    equal to the reference the CPU engine decodes with, at the shapes the
+    cells run, with and without a window, over rows that end on every kind
+    of boundary of a page and of a block."""
+    q, kp, vp, tbl, pos, window = _case(case)
+    ref = ragged_decode_attention(q, kp, vp, tbl, pos, impl="reference", window=window)
+    ker = ragged_decode_attention(q, kp, vp, tbl, pos, impl="kernel",
+                                  interpret=True, window=window)
+    assert np.array_equal(np.asarray(ref, np.float32), np.asarray(ker, np.float32)), \
+        f"kernel diverged from reference: max diff " \
+        f"{np.max(np.abs(np.asarray(ref, np.float32) - np.asarray(ker, np.float32)))}"
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_reference_matches_dense_masked_softmax(case):
+    """Semantics: the online-softmax sweep over blocks of pages equals one
+    dense masked softmax over the gathered pages (bfloat16 pools: the
+    probabilities enter the second product rounded to the pool's dtype)."""
+    q, kp, vp, tbl, pos, window = _case(case)
+    out = ragged_decode_attention_reference(q, kp, vp, tbl, pos,
+                                            scale=q.shape[-1] ** -0.5, window=window)
+    tol = 1e-5 if kp.dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(_dense(q, kp, vp, tbl, pos, window)),
+                               atol=tol, rtol=tol)
 
 
 def _mixed_state(cfg, params, *, lengths, P=PAGE, max_len=MAX_LEN, spare=0):
@@ -171,7 +246,9 @@ def _stacked_step(cfg, attend, pages_bound=None):
             kp = kp.at[page_ids, offsets].set(k[:, 0].astype(kp.dtype))
             vp = vp.at[page_ids, offsets].set(v[:, 0].astype(vp.dtype))
             qh = q[:, 0].reshape(B, cfg.kv_heads, G, cfg.head_dim)
-            out = attend(qh, kp, vp, tbl, pos, scale=cfg.head_dim ** -0.5, dt=dt)
+            # as the step does: a row that is not active walks no page
+            out = attend(qh, kp, vp, tbl, jnp.where(state["active"], pos, -1),
+                         scale=cfg.head_dim ** -0.5, dt=dt)
             out = out.reshape(B, 1, cfg.n_heads, cfg.head_dim).astype(dt)
             out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
             if cfg.bias:

@@ -70,24 +70,35 @@ def test_flash_forward_and_backward_compile(chip):
     assert text.count("tpu_custom_call") >= 3  # fwd, dkv, dq
 
 
-# (batch, kv heads, query heads per kv head, head dim, page, pages swept)
-@pytest.mark.parametrize("shape", [
-    (16, 8, 4, 64, 64, 32),    # Llama-1B decode, 16 slots x 2048
-    (8, 2, 8, 128, 16, 8),     # GQA with fewer than 8 kv heads
-])
-def test_ragged_decode_kernel_compiles(chip, shape):
+def _ragged_launch(chip, shape, window=None):
+    """The per-head launch lowered at (batch, kv heads, query heads per kv
+    head, head dim, page, table columns)."""
     B, Hkv, G, Dh, P, nb = shape
 
     def sds(s, dt):
         return jax.ShapeDtypeStruct(s, dt, sharding=chip)
 
-    text = _compiled_text(
-        lambda *a: ragged_decode_attention(*a, impl="kernel"),
+    return jax.jit(
+        lambda *a: ragged_decode_attention(*a, impl="kernel", window=window)).lower(
         sds((B, Hkv, G, Dh), jnp.bfloat16),
         sds((B * nb + 1, P, Hkv, Dh), jnp.bfloat16),
         sds((B * nb + 1, P, Hkv, Dh), jnp.bfloat16),
         sds((B, nb), jnp.int32), sds((B,), jnp.int32))
-    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("shape,window", [
+    ((16, 8, 4, 64, 64, 32), None),      # Llama-1B decode, 16 slots x 2048: heads of 64
+    ((16, 8, 4, 64, 64, 17), 1024),      # ... and under a window
+    ((8, 12, 1, 64, 64, 16), None),      # GPT-2 124M: 12 heads of 64, one query head each
+    ((8, 2, 8, 128, 16, 8), None),       # GQA with fewer than 8 kv heads
+    ((16, 16, 1, 128, 64, 16), None),    # ouro-2.6b: one query head a KV head
+    ((104, 4, 8, 128, 64, 32), None),    # granite-4.0-h-micro, KV heads packed two a row
+    ((32, 8, 4, 128, 64, 128), None),    # mixtral-8x7b
+    ((48, 4, 8, 128, 64, 256), None),    # mellum2-12b-a2.5b's full layers
+    ((48, 4, 8, 128, 64, 17), 1024),     # ... and its window layers
+])
+def test_ragged_decode_kernel_compiles(chip, shape, window):
+    assert "tpu_custom_call" in _ragged_launch(chip, shape, window).compile().as_text()
 
 
 def _abstract_step_inputs(chip, cfg, slots, max_len, num_pages, page):
@@ -222,6 +233,48 @@ def test_window_and_full_decode_step_holds_both_kinds_of_pool_once(chip):
     prefill = _prefill_1024(chip, params, cfg)
     calls = _kernel_calls(prefill.as_text())
     assert calls.count("grouped_matmul") == 3 * 2 and "ragged-dot" not in prefill.as_text()
+
+
+# cell -> (table columns the step sweeps, its kernels in program order, the
+# temporaries it may hold where the configuration's file records none)
+DECODE_STEPS = {
+    "ouro-2.6b.reason-saturated": (16, ["ragged_paged_attention"], None),
+    "mellum2-12b-a2.5b.mixed-saturated": (
+        256, ["ragged_window_attention", "ragged_paged_attention"], None),
+    "granite-4.0-h-micro.chat-saturated": (
+        32, ["ssm_state_update", "ssm_state_update", "ragged_paged_attention"], None),
+    # the step of PR 42's tree at these shapes: 9,511,936 bytes
+    "mixtral-8x7b.doc-saturated": (128, ["ragged_paged_attention"], 9_511_936),
+}
+
+
+@pytest.mark.parametrize("cell", DECODE_STEPS)
+def test_decode_step_reads_the_pools_where_they_lie(chip, cell):
+    """The decode program of every per-head configuration as its cell runs it
+    (the file's model, slots and pool), with the kernel that walks a row's own
+    pages: every pool aliased input to output, no more temporaries than the
+    file's `aot.decode_step_temp_bytes` (the launch leaves the pools in HBM
+    and copies blocks of pages into VMEM: nothing pool-sized is re-laid around
+    it), and its launches under the names the trace readers match, one op a
+    layer scan."""
+    import math
+
+    from chipbench import harness, program
+    from ray_tpu.models import decoding_paged
+
+    bound, kernels, temp = DECODE_STEPS[cell]
+    conf = harness.resolve_cell(cell)["config_file"]
+    cfg, eng = program.transformer_config(conf["program"]), conf["engine"]
+    params, state = _abstract_step_inputs(
+        chip, cfg, eng["max_slots"], eng["max_len"], eng["num_pages"], eng["page_size"])
+    compiled = decoding_paged.decode_step_paged_ragged.lower(
+        params, state, cfg, bound, True).compile()
+    m = compiled.memory_analysis()
+    pools = sum(math.prod(state[k].shape) * state[k].dtype.itemsize
+                for k in ("kp", "vp", "wkp", "wvp") if k in state)
+    assert m.alias_size_in_bytes >= pools
+    assert m.temp_size_in_bytes <= (temp or conf["aot"]["decode_step_temp_bytes"])
+    assert _kernel_calls(compiled.as_text()) == kernels
 
 
 SERVE_CACHES = ["mellum2-12b-a2.5b", "mixtral-8x7b", "kimi-vl-a3b", "ouro-2.6b"]
@@ -379,19 +432,9 @@ def test_kernel_names_reach_the_compiled_program(chip):
     assert sorted(re.sub(r"^((jvp|transpose)_)*|_+$", "", c.split(".")[0])
                   for c in calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
 
-    B, Hkv, G, Dh, P, nb = 16, 8, 4, 64, 64, 32
-
-    def sds(s, dt):
-        return jax.ShapeDtypeStruct(s, dt, sharding=chip)
-
-    text = _compiled_text(
-        lambda *a: ragged_decode_attention(*a, impl="kernel"),
-        sds((B, Hkv, G, Dh), jnp.bfloat16),
-        sds((B * nb + 1, P, Hkv, Dh), jnp.bfloat16),
-        sds((B * nb + 1, P, Hkv, Dh), jnp.bfloat16),
-        sds((B, nb), jnp.int32), sds((B,), jnp.int32))
-    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
-    assert [c.split(".")[0] for c in calls] == ["ragged_paged_attention"]
+    for shape in [(16, 8, 4, 64, 64, 32), (16, 16, 1, 128, 64, 16)]:   # either launch
+        assert _kernel_calls(_ragged_launch(chip, shape).compile().as_text()) == [
+            "ragged_paged_attention"]
 
 
 # ---- granite-4.0-h-micro.chat-saturated: a recurrent state a slot beside pages
