@@ -57,7 +57,11 @@ KERNELS = dict(flash=(2, 20, 1024, 64),
                    kimi_vl_a3b=dict(tokens=1024, top_k=6, experts=64, layers=8,
                                     d_model=2048, d_ff=1408),
                    mixtral_8x7b=dict(tokens=1024, top_k=2, experts=8, layers=4,
-                                     d_model=4096, d_ff=14336)),
+                                     d_model=4096, d_ff=14336),
+                   # a 2,048-token chunk of trinity-large-preview: 32 of the
+                   # router's 256 experts held, the other slots past the rows
+                   trinity_large_preview=dict(tokens=2048, top_k=4, experts=32, of=256,
+                                              layers=4, d_model=3072, d_ff=3072)),
                # a row's recurrent state of granite-4.0-h-micro, 96 slots
                state_update=dict(layers=3, slots=96, heads=64, head_dim=64, d_state=128))
 
@@ -111,6 +115,9 @@ def compare_grouped_matmul(shapes: dict, interpret: bool = False) -> dict:
     float32 product, bf16, for each model of `shapes` in both directions (gate
     and up: [M, D] x [L*E, D, F]; down: [M, F] x [L*E, F, D]) with one layer's
     groups filled: sizes as a router draws them, and all rows in one group.
+    A model with `of` holds `experts` of the `of` its router draws among: the
+    slots of the absent ones lie past the groups' rows, the kernel takes no
+    step for them (`rows_past="skip"`) and only the groups' rows are compared.
     The error is in roundings: bfloat16 spacings at the float32 product's size
     (at no less than 1/64 of the output's rms: below it the float32 sums' own
     order shows), so a correctly rounded output is at 0.5. Milliseconds a call
@@ -140,12 +147,13 @@ def compare_grouped_matmul(shapes: dict, interpret: bool = False) -> dict:
     out = {}
     for model, sh in shapes.items():
         E, L, M = sh["experts"], sh["layers"], sh["tokens"] * sh["top_k"]
-        kernel = jax.jit(lambda a, b, s, E=E: grouped_matmul_kernel(
-            a, b, s, E, interpret=interpret))
+        of, past = sh.get("of", E), "skip" if "of" in sh else "zero"
+        kernel = jax.jit(lambda a, b, s, E=E, past=past: grouped_matmul_kernel(
+            a, b, s, E, past, interpret=interpret))
         keys = jax.random.split(jax.random.PRNGKey(len(out)), 5)
         # a router's draw: the top k of softmaxed normal logits with an uneven bias
-        logits = jax.random.normal(keys[0], (sh["tokens"], E)) + jax.random.normal(keys[1], (E,))
-        routed = jnp.bincount(jax.lax.top_k(logits, sh["top_k"])[1].reshape(-1), length=E)
+        logits = jax.random.normal(keys[0], (sh["tokens"], of)) + jax.random.normal(keys[1], (of,))
+        routed = jnp.bincount(jax.lax.top_k(logits, sh["top_k"])[1].reshape(-1), length=of)[:E]
         cases = {"routed": routed.astype(jnp.int32),
                  "one_group": jnp.zeros((E,), jnp.int32).at[E // 3].set(M)}
         for proj, (K, N) in {"gate": (sh["d_model"], sh["d_ff"]),
@@ -154,8 +162,9 @@ def compare_grouped_matmul(shapes: dict, interpret: bool = False) -> dict:
             rhs = jax.random.normal(keys[3], (L * E, K, N), jnp.bfloat16)
             for case, sizes in cases.items():
                 stacked = jnp.zeros((L, E), jnp.int32).at[L // 2].set(sizes).reshape(-1)
-                got, want = kernel(lhs, rhs, stacked), ragged(lhs, rhs, stacked)
-                want32 = exact(lhs, rhs, stacked)
+                held = int(sizes.sum())    # the rows that belong to a group
+                got, want = kernel(lhs, rhs, stacked)[:held], ragged(lhs, rhs, stacked)[:held]
+                want32 = exact(lhs, rhs, stacked)[:held]
                 err = roundings(got, want32)
                 out[f"grouped_{model}_{proj}_{case}"] = {
                     "shape": [M, K, N, L * E], "largest_group": int(sizes.max()),

@@ -162,6 +162,8 @@ class _Unread:
     exit_cdf: Any = None
     kind: str = "step"
     prompt_tokens: int = 0
+    # `ops.share_counts` of the programs dispatched since the unread before
+    expert_counts: tuple = ()
 
     @property
     def step(self) -> bool:
@@ -404,12 +406,19 @@ class TPUEngine:
             # what is not carried to the latent cache, to two kinds of layer
             # in one stack, to a stack run several times and to a recurrent
             # state: refused here, not at the first request
-            kind = ("latent attention (kv_lora_rank)" if cfg.mla
-                    else "window layers" if cfg.window
-                    else "leading dense layers" if cfg.n_dense_layers
-                    else "state-space layers (a recurrent state a row)" if cfg.ssm
-                    else "KV heads packed a row of 128 lanes (kv_packed)" if cfg.kv_packed
-                    else "a looped stack (n_passes) or sandwich norms")
+            kinds = [what for on, what in (
+                (cfg.mla, "latent attention (kv_lora_rank)"),
+                (cfg.window, "window layers"),
+                (cfg.n_dense_layers, "leading dense layers"),
+                (cfg.ssm, "state-space layers (a recurrent state a row)"),
+                (cfg.kv_packed, "KV heads packed a row of 128 lanes (kv_packed)"),
+                (cfg.n_passes > 1, "a looped stack (n_passes)"),
+                (cfg.sandwich_norms, "sandwich norms")) if on]
+            # the first names the model as it always did; a stack that mixes
+            # them (window layers after leading dense ones, sandwich norms)
+            # names them all
+            kind = kinds[0] if len(kinds) == 1 else " and ".join(
+                [", ".join(kinds[:-1]), kinds[-1]])
             for on, what in ((mesh is not None, "a tensor-parallel mesh"),
                              (max_loras, "max_loras")):
                 if on:
@@ -610,6 +619,16 @@ class TPUEngine:
         # layers took (stats()["experts"]); a dense model counts neither
         self.expert_tokens_sorted = 0
         self.expert_tokens_onehot = 0
+        # a model whose expert layers hold a share of the experts
+        # (MoEConfig.experts_held): the routed slots of the dispatched calls
+        # and the expert-layer calls, counted here; and what the device counted
+        # of them (`ops.share_counts`: slots whose expert is held, held experts
+        # with a row), which every program returns beside its result and the
+        # loop reads with the step's tokens (`_Unread.expert_counts`)
+        self.expert_slots_routed = 0
+        self.expert_calls = 0
+        self.expert_counts = np.zeros((2,), np.int64)
+        self._expert_counts_unread: list = []
         # device-resident per-row sampling params: updated only on admit,
         # not rebuilt/re-uploaded every decode step
         self._temps = jnp.zeros((max_slots,), jnp.float32)
@@ -1052,10 +1071,22 @@ class TPUEngine:
         moe = self.cfg.moe
         if moe is None:
             return
-        if moe.dropless and ops.sorted_pays(n):
+        if moe.dropless and ops.sorted_pays(n, moe.slots_a_held_expert(n)):
             self.expert_tokens_sorted += n
         else:
             self.expert_tokens_onehot += n
+        if moe.share:
+            layers = self.cfg.n_layers - self.cfg.n_dense_layers
+            self.expert_slots_routed += n * moe.top_k * layers
+            self.expert_calls += layers
+
+    def _take_expert_counts(self, tree: dict) -> None:
+        """A program's `expert_counts` (a prefill's kv, a decode step's state)
+        leaves its tree for the next unread tokens to bring to the host."""
+        counts = tree.pop("expert_counts", None)
+        if counts is not None:
+            counts.copy_to_host_async()
+            self._expert_counts_unread.append(counts)
 
     def _pages_needed(self, prompt_len: int, bucket: int, max_tokens: int) -> int:
         """All pages this sequence will EVER touch, granted up front (no
@@ -1590,6 +1621,7 @@ class TPUEngine:
                 else:
                     logits, kv = decoding.prefill(
                         self.params, padded, jnp.int32(n), self.cfg)
+            self._take_expert_counts(kv)
             self._note_prefill(bucket)
             with dispatch("split"):
                 self.key, sub = jax.random.split(self.key)
@@ -1700,6 +1732,7 @@ class TPUEngine:
             with dispatch("prefill"):
                 logits, kv = decoding.prefill(
                     self.params, padded, jnp.int32(len(suffix)), self.cfg)
+        self._take_expert_counts(kv)
         self._note_prefill(suf_bucket)
         with dispatch("split"):
             self.key, sub = jax.random.split(self.key)
@@ -1771,10 +1804,12 @@ class TPUEngine:
                     jnp.int32(len(chunk_toks)), self.cfg, *window, **carried)
         if self.cfg.ssm:
             req.pf_state = {name: kv.pop(name) for name in ("ssm", "conv")}
+        self._take_expert_counts(kv)
         with dispatch("write_pages"):
             self.state = dp.write_kv_pages(
                 self.state, kv, chunk_pages,
-                *(() if ring is None else (ring, jnp.int32(done))))
+                *(() if ring is None else (ring, jnp.int32(done))),
+                dense_layers=self.cfg.n_dense_layers)
         self._note_prefill(bucket)
         req.pf_done = done + len(chunk_toks)
         req.pf_chunks += 1
@@ -2075,6 +2110,7 @@ class TPUEngine:
                 self._ragged_kernel, self.lora_bank, self._slot_lora)
         # out of the state before the next program donates it (a gated stack)
         exit_cdf = state.pop("exit_cdf", None)
+        self._take_expert_counts(state)
         with dispatch("split"):
             self.key, sub = jax.random.split(self.key)
         if self._guided_fsm:
@@ -2106,9 +2142,10 @@ class TPUEngine:
         if any(u.step for u in self._unread):
             self.steps_ahead += 1
         prefill, self._pass_prefill = self._pass_prefill, None
+        counts, self._expert_counts_unread = tuple(self._expert_counts_unread), []
         self._unread.append(_Unread(
             toks, rows, "decode_wait", t_step, exit_cdf,
-            "step" if prefill is None else "step_prefill", prefill or 0))
+            "step" if prefill is None else "step_prefill", prefill or 0, counts))
         self.decode_steps += 1
         self.stack_passes += self.cfg.n_passes
         self.sampler_steps[form] += 1
@@ -2155,7 +2192,9 @@ class TPUEngine:
         while len(self._unread) > depth:
             u = self._unread.popleft()
             mark(u.wait)
-            toks, exit_cdf = jax.device_get((u.toks, u.exit_cdf))
+            toks, exit_cdf, counts = jax.device_get((u.toks, u.exit_cdf, u.expert_counts))
+            for c in counts:
+                self.expert_counts += c
             now = mark("emit")
             if u.step:
                 # one measurement, two sinks
@@ -2247,6 +2286,13 @@ class TPUEngine:
             out["loops"]["exit_rows"] = self.exit_rows.tolist()
         out["experts"] = {"tokens_sorted": self.expert_tokens_sorted,
                           "tokens_onehot": self.expert_tokens_onehot}
+        if self.cfg.moe is not None and self.cfg.moe.share:
+            # slots_routed and calls of what was dispatched; slots_held and
+            # groups_with_rows of what has been read (a step behind)
+            out["experts"].update(
+                slots_routed=self.expert_slots_routed, calls=self.expert_calls,
+                slots_held=int(self.expert_counts[0]),
+                groups_with_rows=int(self.expert_counts[1]))
         # decode steps by the form the sampler took (they add up to
         # decode_steps): how often anything beyond an argmax is paid for
         out["sampler"] = dict(self.sampler_steps)

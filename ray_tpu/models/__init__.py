@@ -6,6 +6,7 @@ from ray_tpu.models.llama import llama_config
 from ray_tpu.models.mellum import mellum_config
 from ray_tpu.models.mixtral import mixtral_config
 from ray_tpu.models.ouro import ouro_config
+from ray_tpu.models.trinity import trinity_config
 from ray_tpu.models.transformer import MoEConfig, SSMConfig, TransformerConfig
 from ray_tpu.models.vit import ViTConfig, vit_config
 
@@ -22,6 +23,7 @@ __all__ = [
     "mixtral_config",
     "ouro_config",
     "transformer",
+    "trinity_config",
     "vit",
     "vit_config",
 ]

@@ -27,21 +27,23 @@ import jax.numpy as jnp
 from ray_tpu import ops
 from ray_tpu.models.transformer import (TransformerConfig, _dense_mlp, _mla_expand,
                                         _mla_project, _moe_mlp, _norm, _residual,
-                                        close_pass, embed_tokens, lm_logits, mamba_mixer,
-                                        rope_by_kind, scan_layers)
+                                        attn_gated, close_pass, embed_tokens, lm_logits,
+                                        mamba_mixer, qk_normed, rope_by_kind, scan_layers)
 
 
 def _per_head_kv_only(cfg: TransformerConfig, what: str) -> None:
     """The paths not carried to the latent cache, to two kinds of layer, to
     a looped stack or to a recurrent state."""
     if (cfg.mla or cfg.n_dense_layers or cfg.window or cfg.n_passes > 1
-            or cfg.sandwich_norms or cfg.ssm or cfg.kv_packed):
+            or cfg.sandwich_norms or cfg.ssm or cfg.kv_packed or cfg.attn_gate
+            or cfg.qk_norm):
         raise NotImplementedError(
             f"{what} is built for per-head K and V over one kind of layer, "
             "each applied once; a model with latent attention (kv_lora_rank), "
             "leading dense layers, window layers, a looped stack (n_passes), "
-            "sandwich norms, state-space layers (a recurrent state a row) or "
-            "packed KV rows (kv_packed) is served without it")
+            "sandwich norms, state-space layers (a recurrent state a row), "
+            "packed KV rows (kv_packed), an attention gate or q/k norms is "
+            "served without it")
 
 
 def _rope(cfg):
@@ -104,22 +106,38 @@ def _attn_qkv(x, p, cfg, lora_l=None, lora_idx=None, lora_scale=None):
         q = q + p["bq"].astype(dt)
         k = k + p["bk"].astype(dt)
         v = v + p["bv"].astype(dt)
+    q, k = qk_normed(q, k, p, cfg)
     return q, k, v
 
 
+def counts_experts(cfg: TransformerConfig) -> bool:
+    """Whether the model's expert layers hold a share of the experts: its
+    blocks then give `ops.share_counts` a layer beside what they gave."""
+    return cfg.moe is not None and cfg.moe.share
+
+
 def _mlp_block(normed, layer_p, cfg):
+    return _mlp_counted(normed, layer_p, cfg)[0]
+
+
+def _mlp_counted(normed, layer_p, cfg):
+    """The block's MLP -> (its output, a tuple that is empty, or for a model
+    that `counts_experts` holds the layer's int32 [2] `ops.share_counts`:
+    zeros for a layer without a router)."""
     if "router" in layer_p["mlp"]:
-        delta, _aux = _moe_mlp(normed, layer_p["mlp"], cfg)
-        return delta
-    return _dense_mlp(normed, layer_p["mlp"], cfg)
+        delta, _aux, *counts = _moe_mlp(normed, layer_p["mlp"], cfg)
+        return delta, tuple(counts)
+    counts = (jnp.zeros((2,), jnp.int32),) if counts_experts(cfg) else ()
+    return _dense_mlp(normed, layer_p["mlp"], cfg), counts
 
 
 def _close_block(h, mixed, layer_p, cfg):
     """A block's second half: the mixer's (attention's, or the state-space
-    mixer's) output `mixed` joins the residual, then the MLP's does."""
+    mixer's) output `mixed` joins the residual, then the MLP's does. Returns
+    (h, `_mlp_counted`'s tuple of counts)."""
     h = _residual(h, mixed, layer_p, "post_attn_norm", cfg)
-    return _residual(h, _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg),
-                     layer_p, "post_mlp_norm", cfg)
+    delta, counts = _mlp_counted(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
+    return _residual(h, delta, layer_p, "post_mlp_norm", cfg), counts
 
 
 def _mla_prefill_attn(normed, attn_p, cfg, cos, sin, positions=None,
@@ -181,7 +199,7 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
         normed = _norm(h, layer_p["norm1"], cfg)
         if ssm:
             out, state, tail = mamba_mixer(normed[0], layer_p["mixer"], cfg, length)
-            return _close_block(h, out[None], layer_p, cfg), (state, tail)
+            return _close_block(h, out[None], layer_p, cfg)[0], (state, tail)
         if cfg.mla:
             out, rows = _mla_prefill_attn(normed, layer_p["attn"], cfg, cos, sin)
             h = h + out
@@ -189,15 +207,17 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
             return h, (rows,)
         q, k, v = _attn_qkv(normed, layer_p["attn"], cfg, lora_l, lora_idx,
                             lscale)
-        if cfg.pos == "rope":
+        if cos is not None:  # this kind of layer has the rope
             q = ops.apply_rope(q, cos, sin)
             k = ops.apply_rope(k, cos, sin)
         out = ops.attention(q, k, v, causal=True, scale=cfg.softmax_scale,
                             window=cfg.window if window else None)
-        out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
+        out = jnp.einsum("bthd,hde->bte", attn_gated(out, normed, layer_p["attn"], cfg),
+                         layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
             out = out + layer_p["attn"]["bo"].astype(dt)
-        return _close_block(h, out, layer_p, cfg), (k[0], v[0])
+        h, counts = _close_block(h, out, layer_p, cfg)
+        return h, (k[0], v[0], *counts)
 
     def close(h, t):  # the exit gate decides nothing about a prompt
         return close_pass(h, None, t, params, cfg)[0]
@@ -215,7 +235,12 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
 
 def kv_tree(kv, cfg: TransformerConfig) -> dict:
     """What `scan_layers` stacked of a prefill's blocks, by name: {k, v} (a
-    latent cache: {k}); with state-space layers also {ssm, conv}."""
+    latent cache: {k}); with state-space layers also {ssm, conv}; for a model
+    that `counts_experts` also {expert_counts: int32 [2]}, the layers'
+    `ops.share_counts` summed (no page's: the writers of pages leave it out)."""
+    if counts_experts(cfg):
+        *kv, counts = kv
+        return {**dict(zip("kv", kv)), "expert_counts": counts.sum(axis=0)}
     if cfg.ssm is None:
         return dict(zip("kv", kv))
     (k, v), (state, tail) = kv
@@ -253,7 +278,7 @@ def prefill_batch(params, tokens, lengths, cfg: TransformerConfig):
         out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
             out = out + layer_p["attn"]["bo"].astype(dt)
-        return _close_block(h, out, layer_p, cfg), (k, v)
+        return _close_block(h, out, layer_p, cfg)[0], (k, v)
 
     x, kv = jax.lax.scan(block, x, params["layers"])
     x = _norm(x, params["final_norm"], cfg)

@@ -74,14 +74,15 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.decoding import (_attn_qkv, _close_block, _mla_prefill_attn, _mlp_block,
-                                     kv_tree)
+                                     counts_experts, kv_tree)
 # `_residual` is this module's by name for chipbench/loop_faults.py, which
 # plants a wrong one here and in decoding.py (whose `_close_block` calls it)
 from ray_tpu.models.transformer import (TransformerConfig, _mla_absorb_out, _mla_absorb_q,
-                                        _mla_project, _norm, _residual, close_pass,  # noqa: F401
-                                        embed_tokens, exit_distribution, kind_index,
-                                        lm_logits, mamba_mixer, mixer_out, mixer_project,
-                                        mixer_split, rope_by_kind, scan_layers)
+                                        _mla_project, _norm, _residual, attn_gated,  # noqa: F401
+                                        close_pass, embed_tokens, exit_distribution,
+                                        is_full_layer, kind_index, lm_logits, mamba_mixer,
+                                        mixer_out, mixer_project, mixer_split, rope_by_kind,
+                                        scan_layers)
 from ray_tpu import ops
 
 
@@ -160,11 +161,19 @@ def insert_sequence_paged(state, slot, kv, length, first_token, pages,
     can still see, and never more than the ring holds.
     With state-space layers kv's `ssm` and `conv` go into slot `slot` of the
     state of that name: the last occupant's is written over whole."""
+    kv = _pages_part(kv)
     if cfg.window:
-        state, kv = _insert_ring(state, slot, kv, length, pages, window_pages)
+        state, kv = _insert_ring(state, slot, kv, length, pages, window_pages,
+                                 cfg.n_dense_layers)
     kv, row_state = _split_row_state(kv)
     state = _set_row_state(_write_pages(state, kv, pages), slot, row_state)
     return _activate(state, slot, pages, length, first_token)
+
+
+def _pages_part(kv) -> dict:
+    """A prefill's kv without what is no layer's K, V or state: the counts of
+    a model whose expert layers hold a share (`decoding.kv_tree`)."""
+    return {name: t for name, t in kv.items() if name != "expert_counts"}
 
 
 def _split_row_state(kv) -> tuple:
@@ -196,15 +205,19 @@ def _activate(state, slot, block_row, length, first_token) -> dict:
     return state
 
 
-def _split_kinds(kv, state) -> tuple:
+def _split_kinds(kv, state, dense: int = 0) -> tuple:
     """A prefill's {k, v: [L, T, ...]} -> (the full layers' {k, v}, the
-    window layers' {wk, wv}): each period's last layer is its full layer."""
+    window layers' {wk, wv}): the `dense` leading dense layers are window
+    layers, and of the periods after them each one's last layer is its full
+    layer (`transformer.is_full_layer`)."""
     full, window = {}, {}
     for name, rows in kv.items():
-        L, Lf = rows.shape[0], state["kp"].shape[0]
-        folded = rows.reshape(Lf, L // Lf, *rows.shape[1:])
+        L, Lf = rows.shape[0] - dense, state["kp"].shape[0]
+        folded = rows[dense:].reshape(Lf, L // Lf, *rows.shape[1:])
         full[name] = folded[:, -1]
         window["w" + name] = folded[:, :-1].reshape(L - Lf, *rows.shape[1:])
+        if dense:
+            window["w" + name] = jnp.concatenate([rows[:dense], window["w" + name]])
     return full, window
 
 
@@ -236,11 +249,11 @@ def _ring_held(ring_ids):
     return jnp.maximum(jnp.count_nonzero(ring_ids), 1).astype(jnp.int32)
 
 
-def _insert_ring(state, slot, kv, length, pages, window_pages) -> tuple:
+def _insert_ring(state, slot, kv, length, pages, window_pages, dense: int = 0) -> tuple:
     """(`state` with row `slot`'s ring set and the window layers' part of a
     whole prefilled row in it, the full layers' part of `kv`). The ring is
     `window_pages`, or the leading entries of `pages`."""
-    kv, window_kv = _split_kinds(kv, state)
+    kv, window_kv = _split_kinds(kv, state, dense)
     ring = state["wblock"].shape[1]
     state = _set_ring(state, slot, pages[:ring] if window_pages is None
                       else window_pages)
@@ -365,7 +378,11 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
     a state-space layer updates its own plane of both where it lies
     (`ops.ssm_state_update`: the Pallas kernel with `kernel`, `jax.numpy`
     without); an inactive row keeps its state and its tail, and the kernel
-    does not touch it."""
+    does not touch it.
+
+    For a model whose expert layers hold a share of the experts
+    (`decoding.counts_experts`) the returned state's `expert_counts` int32 [2]
+    is this step's `ops.share_counts`, summed over the layers."""
     from ray_tpu.ops.ragged_paged_attention import ragged_decode_attention, table_width
 
     # the launches whose sweep the table's width bounds get the batch's live
@@ -400,9 +417,8 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
         wflat = (Lw * window_pages,) + state["wkp"].shape[2:]
         wpools = (state["wkp"].reshape(wflat), state["wvp"].reshape(wflat))
         # a layer's first page in the pool of its kind, in depth order
-        period = cfg.window_period
         bases = jnp.asarray([
-            i * (num_pages if l % period == period - 1 else window_pages)
+            i * (num_pages if is_full_layer(cfg, l) else window_pages)
             for l, i in enumerate(kind_index(cfg))], jnp.int32)
         # the window sweep's logical pages, and where each lies in the ring
         steps = cfg.window // P + 1
@@ -445,7 +461,7 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
         moved = jnp.concatenate([tail[:, 1:], xBC[:, None].astype(tail.dtype)], axis=1)
         conv = jax.lax.dynamic_update_index_in_dim(
             conv, jnp.where(state["active"][:, None, None], moved, tail), i, 0)
-        h = _close_block(h, mixer_out(y, xs, z, p, cfg)[:, None], layer_p, cfg)
+        h, _ = _close_block(h, mixer_out(y, xs, z, p, cfg)[:, None], layer_p, cfg)
         return (h, gates, kp, vp, rec, conv), None
 
     def block(carry, layer_in, window=False, ssm=False):
@@ -469,7 +485,7 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
             return (h, gates, kp, vp), None
         q, k, v = _attn_qkv(normed, layer_p["attn"], cfg, lora_l, slot_lora,
                             lscale)                            # [B, 1, H, Dh]
-        if cfg.pos == "rope":
+        if cos is not None:  # this kind of layer has the rope
             q = ops.apply_rope(q, cos, sin, positions=pos[:, None])
             k = ops.apply_rope(k, cos, sin, positions=pos[:, None])
         # scatter this step's K/V at (page, offset) per row
@@ -478,13 +494,14 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
         qh = q[:, 0].reshape(B, cfg.kv_heads, G, cfg.head_dim)
         out = attend(qh, kp, vp, base, window)
         out = out.reshape(B, 1, cfg.n_heads, cfg.head_dim).astype(dt)
-        out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
+        out = jnp.einsum("bthd,hde->bte", attn_gated(out, normed, layer_p["attn"], cfg),
+                         layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
             out = out + layer_p["attn"]["bo"].astype(dt)
-        h = _close_block(h, out, layer_p, cfg)
+        h, counts = _close_block(h, out, layer_p, cfg)
         if window:
-            return (h, gates, *others, kp, vp), None
-        return (h, gates, kp, vp, *others), None
+            return (h, gates, *others, kp, vp), (counts or None)
+        return (h, gates, kp, vp, *others), (counts or None)
 
     def close(carry, t):
         h, gates, *pools = carry
@@ -496,7 +513,7 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
     others = ("wkp", "wvp") if cfg.window else ("ssm", "conv") if cfg.ssm else ()
     if cfg.ssm:
         wpools = (state["ssm"], state["conv"])
-    (x, gates, kp, vp, *wpools), _ = scan_layers(
+    (x, gates, kp, vp, *wpools), counts = scan_layers(
         block, (x, gates, state["kp"].reshape(flat), vp0, *wpools), params, cfg, bases,
         *(() if lora_bank is None else
           (lora_bank[k] for k in ("A_q", "B_q", "A_v", "B_v"))), close=close,
@@ -511,6 +528,8 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
         state[name] = pool.reshape(state[name].shape)
     if gates is not None:
         state["exit_cdf"] = jnp.cumsum(exit_distribution(gates[..., 0]), axis=0).T
+    if counts_experts(cfg):  # this step's, for the engine to take with its tokens
+        state["expert_counts"] = counts[0].sum(axis=0)
     state["length"] = jnp.where(state["active"], state["length"] + 1, state["length"])
     return state, logits.astype(jnp.float32)
 
@@ -605,7 +624,7 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
             layer_p, rec, tail = layer_in
             out, rec, tail = mamba_mixer(_norm(h, layer_p["norm1"], cfg)[0],
                                          layer_p["mixer"], cfg, length, rec, tail)
-            return _close_block(h, out[None], layer_p, cfg), (rec, tail)
+            return _close_block(h, out[None], layer_p, cfg)[0], (rec, tail)
         if cfg.window:
             layer_p, i = layer_in
             pk, pv = (jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False)
@@ -624,7 +643,7 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
             h = h + _mlp_block(_norm(h, layer_p["norm2"], cfg), layer_p, cfg)
             return h, (rows,)
         q, k, v = _attn_qkv(normed, layer_p["attn"], cfg)  # [1, Ts, H, Dh]
-        if cfg.pos == "rope":
+        if cos is not None:  # this kind of layer has the rope
             q = ops.apply_rope(q, cos, sin, positions=pos_suffix)
             k = ops.apply_rope(k, cos, sin, positions=pos_suffix)
         k_all = jnp.concatenate([pk[None].astype(dt), k], axis=1)
@@ -650,10 +669,12 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
                 tuple(jnp.moveaxis(t, 2, 0) for t in (qh, k_all, v_all)))
             out = jnp.moveaxis(out, 0, 2)
         out = out.reshape(B, Ts, cfg.n_heads, cfg.head_dim)
-        out = jnp.einsum("bthd,hde->bte", out, layer_p["attn"]["wo"].astype(dt))
+        out = jnp.einsum("bthd,hde->bte", attn_gated(out, normed, layer_p["attn"], cfg),
+                         layer_p["attn"]["wo"].astype(dt))
         if cfg.bias:
             out = out + layer_p["attn"]["bo"].astype(dt)
-        return _close_block(h, out, layer_p, cfg), (k[0], v[0])
+        h, counts = _close_block(h, out, layer_p, cfg)
+        return h, (k[0], v[0], *counts)
 
     x, kv = scan_layers(block, x, params, cfg, *per_layer,
                         close=lambda h, t: close_pass(h, None, t, params, cfg)[0],
@@ -663,8 +684,8 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
     return logits.astype(jnp.float32), kv_tree(kv, cfg)
 
 
-@functools.partial(jax.jit, donate_argnames=("state",))
-def write_kv_pages(state, kv, pages, ring_ids=None, start=None):
+@functools.partial(jax.jit, donate_argnames=("state",), static_argnames=("dense_layers",))
+def write_kv_pages(state, kv, pages, ring_ids=None, start=None, dense_layers: int = 0):
     """Write a bucketed [L, T, Hkv, Dh] KV into `pages` (T/page_size ids)
     WITHOUT touching the row bookkeeping — the chunked-prefill building
     block: chunks accumulate into the pool page by page, and the row only
@@ -673,10 +694,12 @@ def write_kv_pages(state, kv, pages, ring_ids=None, start=None):
     `activate_slot` will take), at the slots of the T/page_size logical
     pages from position `start` (a multiple of the page size) on. A recurrent
     part of `kv` (`ssm`, `conv`) is no page's and is left out: it rides from
-    chunk to chunk outside the state and enters its slot at `activate_slot`."""
-    kv = _split_row_state(kv)[0]
+    chunk to chunk outside the state and enters its slot at `activate_slot`.
+    `dense_layers`: the model's leading dense layers, which are window layers
+    (`_split_kinds`)."""
+    kv = _split_row_state(_pages_part(kv))[0]
     if ring_ids is not None:
-        kv, window_kv = _split_kinds(kv, state)
+        kv, window_kv = _split_kinds(kv, state, dense_layers)
         P = state["wkp"].shape[2]
         logical = start // P + jnp.arange(window_kv["wk"].shape[1] // P)
         state = _write_pages(state, window_kv,
@@ -725,8 +748,10 @@ def insert_sequence_paged_prefix(state, slot, kv, suffix_pages, block_row,
     in a ring: no prefix cache over them), the suffix is the whole row, and
     its window layers' part goes to the ring `window_pages` as
     insert_sequence_paged puts it there."""
+    kv = _pages_part(kv)
     if cfg.window:
-        state, kv = _insert_ring(state, slot, kv, length, block_row, window_pages)
+        state, kv = _insert_ring(state, slot, kv, length, block_row, window_pages,
+                                 cfg.n_dense_layers)
     kv, row_state = _split_row_state(kv)
     state = _set_row_state(_write_pages(state, kv, suffix_pages), slot, row_state)
     return _activate(state, slot, block_row, length, first_token)

@@ -29,6 +29,7 @@ self-contained (BASELINE.md configs 1, 2, 4).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -54,11 +55,33 @@ class MoEConfig:
     routed_scaling_factor: float = 1.0     # sigmoid routing: times the k weights
     n_shared_experts: int = 0              # one MLP of n * d_ff, every token
     select_bias_init_std: float = 0.0      # sigmoid routing: published init is 0
+    # a layer that holds a SHARE of the experts (one chip's of a deployment
+    # that divides each layer's experts over several): experts [first_expert,
+    # first_expert + experts_held) of the num_experts the router scores. The
+    # router, its bias and top_k keep their width; gate / up / down are the
+    # held experts' alone; a routed slot whose expert is absent is not computed
+    # and adds nothing (ops/moe.py). None: every expert is held
+    experts_held: int | None = None
+    first_expert: int = 0
 
     @property
     def dropless(self) -> bool:
         return (self.capacity_factor is None
                 or self.capacity_factor >= self.num_experts / self.top_k)
+
+    @property
+    def share(self) -> bool:
+        return self.experts_held is not None
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights the layer has."""
+        return self.num_experts if self.experts_held is None else self.experts_held
+
+    def slots_a_held_expert(self, n_tokens: int) -> float | None:
+        """What `ops.sorted_pays` asks of a share beside the tokens of a call:
+        the routed slots a held expert gets in the mean (None: all are held)."""
+        return n_tokens * self.top_k / self.num_experts if self.share else None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,7 +149,9 @@ class TransformerConfig:
     kv_norm_eps: float = 1e-6
     # window attention: query i sees key j iff 0 <= i - j < window, on every
     # layer but the last of each `window_period` (layer l is a full layer iff
-    # l % window_period == window_period - 1). None: every layer is full
+    # l % window_period == window_period - 1). None: every layer is full.
+    # The periods count the layers AFTER the leading dense ones, which are
+    # window layers all (`is_full_layer`)
     window: int | None = None
     window_period: int = 4
     # YaRN on the full layers' rope (ops/rope.py Yarn); window layers take
@@ -169,10 +194,22 @@ class TransformerConfig:
     # wk, wv and wo) of the attention layers. None: the shared ones
     init_out_std: float | None = None
     init_attn_std: tuple | None = None
+    # three things in the attention sublayer, each inert at its default:
+    # `attn_gate`: the heads' output times sigmoid(x W_g), W_g [E, H, Dh] (x
+    # the sublayer's normed input), before the output projection; `qk_norm`:
+    # an RMS norm over the head dimension on q and on k (one weight of
+    # head_dim each a layer, shared by the heads; eps norm_eps), before the
+    # rope; `full_layer_rope` False: in a stack with window layers the FULL
+    # layers take no positions at all (the window layers keep the rope)
+    attn_gate: bool = False
+    qk_norm: bool = False
+    full_layer_rope: bool = True
 
     @property
     def n_full_layers(self) -> int:
-        return self.n_layers // self.window_period if self.window else self.n_layers
+        if not self.window:
+            return self.n_layers
+        return (self.n_layers - self.n_dense_layers) // self.window_period
 
     @property
     def n_attn_layers(self) -> int:
@@ -275,14 +312,15 @@ def _dense_mlp_params(cfg, key, d_ff=None):
 
 def _moe_params(cfg, key):
     E, F, X = cfg.d_model, cfg.d_ff, cfg.moe.num_experts
+    H = cfg.moe.held  # the experts whose weights are here: all, or a share
     k0, k1, k2, k3 = jax.random.split(key, 4)
     std = 0.02
     out_std = 0.02 / math.sqrt(2 * cfg.n_layers)
     p = {
         "router": jax.random.normal(k0, (E, X), cfg.param_dtype) * std,
-        "gate": jax.random.normal(k1, (X, E, F), cfg.param_dtype) * std,
-        "up": jax.random.normal(k2, (X, E, F), cfg.param_dtype) * std,
-        "down": jax.random.normal(k3, (X, F, E), cfg.param_dtype) * out_std,
+        "gate": jax.random.normal(k1, (H, E, F), cfg.param_dtype) * std,
+        "up": jax.random.normal(k2, (H, E, F), cfg.param_dtype) * std,
+        "down": jax.random.normal(k3, (H, F, E), cfg.param_dtype) * out_std,
     }
     if cfg.moe.score_func == "sigmoid":
         p["router_bias"] = (jax.random.normal(jax.random.fold_in(k0, 1), (X,), jnp.float32)
@@ -329,6 +367,12 @@ def _layer_params(cfg, key, dense: bool = False):
         attn["bk"] = jnp.zeros((Hkv, Dh), cfg.param_dtype)
         attn["bv"] = jnp.zeros((Hkv, Dh), cfg.param_dtype)
         attn["bo"] = jnp.zeros((E,), cfg.param_dtype)
+    if cfg.attn_gate:
+        attn["wg"] = jax.random.normal(jax.random.fold_in(ks[0], 1), (E, H, Dh),
+                                       cfg.param_dtype) * qk_std
+    if cfg.qk_norm:
+        attn["q_norm"] = jnp.ones((Dh,), cfg.param_dtype)
+        attn["k_norm"] = jnp.ones((Dh,), cfg.param_dtype)
     if dense:
         mlp = _dense_mlp_params(cfg, ks[5], cfg.d_ff_dense)
     else:
@@ -386,19 +430,32 @@ def is_attn_layer(cfg: TransformerConfig, l: int) -> bool:
     return cfg.ssm is None or l % cfg.ssm.period == cfg.ssm.attn_at
 
 
+def is_full_layer(cfg: TransformerConfig, l: int) -> bool:
+    """Whether layer `l` sees every earlier position: every layer of a stack
+    without a window; with one, the last of each period, the periods counted
+    from the first layer after the leading dense ones (all window layers)."""
+    if not cfg.window:
+        return True
+    j = l - cfg.n_dense_layers
+    return j >= 0 and j % cfg.window_period == cfg.window_period - 1
+
+
 def _check(cfg: TransformerConfig) -> None:
     if cfg.pos not in ("rope", "learned", "none"):
         raise ValueError(f"pos {cfg.pos!r}: 'rope', 'learned' or 'none'")
-    scaled = (cfg.embedding_multiplier != 1.0 or cfg.residual_multiplier != 1.0
-              or cfg.logits_scaling != 1.0 or cfg.attention_multiplier is not None)
+    # `embedding_multiplier` is one product in `embed_tokens`, which every path
+    # takes, and goes with any stack; the other three are built with Granite's
+    scaled = (cfg.residual_multiplier != 1.0 or cfg.logits_scaling != 1.0
+              or cfg.attention_multiplier is not None)
     if (cfg.ssm or scaled) and (cfg.mla or cfg.window is not None or cfg.n_dense_layers
                                 or cfg.moe or cfg.n_passes > 1 or cfg.sandwich_norms
-                                or cfg.bias):
+                                or cfg.bias or cfg.attn_gate or cfg.qk_norm):
         raise ValueError(
-            "state-space layers (ssm) and the scalar multipliers are built for "
-            "a dense stack run once with per-head K and V and no biases: not "
-            "with latent attention, window layers, leading dense layers, "
-            "experts, a looped stack or sandwich norms")
+            "state-space layers (ssm) and the scalar multipliers of the residual, "
+            "the logits and the softmax are built for a dense stack run once with "
+            "per-head K and V and no biases: not with latent attention, window "
+            "layers, leading dense layers, experts, a looped stack, sandwich "
+            "norms, an attention gate or q/k norms")
     if cfg.kv_packed and (cfg.mla or cfg.window is not None or 128 % cfg.head_dim
                           or cfg.kv_heads * cfg.head_dim % 128):
         raise ValueError(
@@ -430,24 +487,44 @@ def _check(cfg: TransformerConfig) -> None:
         raise ValueError("yarn rescales rotary positions: pos='rope'")
     if cfg.n_passes < 1:
         raise ValueError(f"n_passes {cfg.n_passes}: a stack is run at least once")
-    if (cfg.n_passes > 1 or cfg.sandwich_norms or cfg.exit_gate) and (
+    if (cfg.n_passes > 1 or cfg.exit_gate) and (
             cfg.mla or cfg.window is not None or cfg.n_dense_layers):
         raise ValueError(
-            "a looped stack (n_passes), sandwich norms and the exit gate are "
-            "built for per-head K and V in a stack of one kind of layer: not "
-            "with latent attention (kv_lora_rank), window layers or leading "
-            "dense layers")
+            "a looped stack (n_passes) and the exit gate are built for per-head "
+            "K and V in a stack of one kind of layer: not with latent attention "
+            "(kv_lora_rank), window layers or leading dense layers")
+    if cfg.mla and (cfg.sandwich_norms or cfg.attn_gate or cfg.qk_norm):
+        raise ValueError(
+            "sandwich norms, the attention gate and q/k norms are built for "
+            "per-head K and V: not with latent attention (kv_lora_rank), whose "
+            "blocks add their sublayers' outputs as they are")
     if cfg.window is not None:
-        if cfg.mla or cfg.pos != "rope" or cfg.n_dense_layers:
+        if cfg.mla or cfg.pos != "rope":
             raise ValueError(
-                "window layers are built for per-head K and V with rope in a "
-                "stack of one kind of MLP: not with latent attention "
-                "(kv_lora_rank), learned positions or leading dense layers")
-        if cfg.window < 1 or cfg.window_period < 2 or cfg.n_layers % cfg.window_period:
+                "window layers are built for per-head K and V with rope (on the "
+                "window layers at least: full_layer_rope): not with latent "
+                "attention (kv_lora_rank) or learned positions")
+        after_dense = cfg.n_layers - cfg.n_dense_layers
+        if cfg.window < 1 or cfg.window_period < 2 or after_dense % cfg.window_period:
             raise ValueError(
                 f"window {cfg.window} over periods of {cfg.window_period} layers "
-                f"(the last of each a full layer) needs whole periods in "
-                f"n_layers {cfg.n_layers}")
+                f"(the last of each a full layer) needs whole periods in the "
+                f"{after_dense} layers after the {cfg.n_dense_layers} leading dense "
+                f"ones (n_layers {cfg.n_layers})")
+    elif not cfg.full_layer_rope:
+        raise ValueError(
+            "full_layer_rope False takes the positions off the full layers of a "
+            "stack that has window layers too (window); a stack of full layers "
+            "without positions is pos='none'")
+    if cfg.moe and cfg.moe.share:
+        moe = cfg.moe
+        if not (moe.dropless and moe.experts_held >= 1 and moe.first_expert >= 0
+                and moe.first_expert + moe.experts_held <= moe.num_experts):
+            raise ValueError(
+                f"a share of the experts, [{moe.first_expert}, {moe.first_expert} + "
+                f"{moe.experts_held}) of {moe.num_experts}, lies within them and is "
+                "dropless (capacity_factor None): a slot over a capacity and a "
+                "slot of an absent expert are not told apart")
 
 
 def scan_layers(block, carry, params, cfg: TransformerConfig, *per_layer,
@@ -544,16 +621,21 @@ def _scan_periods(block, carry, params, cfg: TransformerConfig, per_layer):
     the scans' own counters: trees folded to [L / period, period, ...] for
     the scan to slice made the compiler re-lay whole stacked weights on
     every call."""
-    L, period = cfg.n_layers, cfg.window_period
+    dense, period = cfg.n_dense_layers, cfg.window_period
+    L = cfg.n_layers - dense  # the layers the periods count
     stack, whole = params["layers"], {}
     if "router" in stack["mlp"] and cfg.moe.dropless:
         whole = {k: stack["mlp"][k] for k in ("gate", "up", "down")}
         stack = {**stack, "mlp": {k: v for k, v in stack["mlp"].items() if k not in whole}}
 
+    def at(trees, i):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), trees)
+
     def one(c, layer, window):
-        layer_p, *more = jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
-            (stack, *per_layer))
+        # `layer` counts from the first layer after the leading dense ones;
+        # the `per_layer` trees lead with every layer of the stack
+        layer_p, more = at(stack, layer), at(per_layer, dense + layer if dense else layer)
         if whole:
             layer_p = {**layer_p, "mlp": {**layer_p["mlp"], **whole, "layer": layer}}
         return block(c, (layer_p, *more) if more else layer_p, window=window)
@@ -564,8 +646,19 @@ def _scan_periods(block, carry, params, cfg: TransformerConfig, per_layer):
         c, out = one(c, first * period + period - 1, False)
         return c, jax.tree.map(lambda a, b: jnp.concatenate([a, b[None]]), outs, out)
 
+    led = None
+    if dense:  # the leading dense layers: window layers, a scan of their own
+
+        def dense_one(c, i):
+            layer_p, more = at(params["dense_layers"], i), at(per_layer, i)
+            return block(c, (layer_p, *more) if more else layer_p, window=True)
+
+        carry, led = jax.lax.scan(dense_one, carry, jnp.arange(dense, dtype=jnp.int32))
     carry, out = jax.lax.scan(body, carry, jnp.arange(L // period, dtype=jnp.int32))
-    return carry, jax.tree.map(lambda a: a.reshape(L, *a.shape[2:]), out)
+    out = jax.tree.map(lambda a: a.reshape(L, *a.shape[2:]), out)
+    if led is not None:
+        out = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), led, out)
+    return carry, out
 
 
 def _scan_hybrid(block, carry, params, cfg: TransformerConfig, per_layer, ssm_per_layer):
@@ -608,17 +701,24 @@ def rope_tables(cfg: TransformerConfig, window: bool = False):
 
 def rope_by_kind(cfg: TransformerConfig) -> dict:
     """{window: (cos, sin)} for the kinds of layer the stack has, keyed as
-    `scan_layers` tells a block its kind ((None, None) without rope)."""
+    `scan_layers` tells a block its kind ((None, None) for a kind without
+    rope: every kind where cfg.pos is not "rope", the full layers where
+    cfg.full_layer_rope is off)."""
     kinds = (False, True) if cfg.window else (False,)
-    return {w: rope_tables(cfg, w) if cfg.pos == "rope" else (None, None) for w in kinds}
+    return {w: rope_tables(cfg, w)
+            if cfg.pos == "rope" and (w or cfg.full_layer_rope) else (None, None)
+            for w in kinds}
 
 
 def kind_index(cfg: TransformerConfig) -> list:
     """Each layer's index among the layers of its own kind, in depth order:
     where its pages, or its cached prefix, lie in the arrays of that kind."""
-    period = cfg.window_period if cfg.window else 1
-    return [l // period if l % period == period - 1 else l - l // period
-            for l in range(cfg.n_layers)]
+    index, seen = [], {False: 0, True: 0}
+    for l in range(cfg.n_layers):
+        full = is_full_layer(cfg, l)
+        index.append(seen[full])
+        seen[full] += 1
+    return index
 
 
 def close_pass(h, gates, t, params, cfg: TransformerConfig):
@@ -696,6 +796,10 @@ def logical_axes(cfg: TransformerConfig):
     if cfg.bias:
         attn.update({"bq": ("heads", "head_dim"), "bk": ("kv_heads", "head_dim"),
                      "bv": ("kv_heads", "head_dim"), "bo": ("embed",)})
+    if cfg.attn_gate:
+        attn["wg"] = ("embed", "heads", "head_dim")
+    if cfg.qk_norm:
+        attn.update({"q_norm": ("head_dim",), "k_norm": ("head_dim",)})
     swiglu = {"wi_gate": ("embed", "mlp"), "wi_up": ("embed", "mlp"), "wo": ("mlp", "embed")}
     if cfg.moe:
         mlp = {"router": ("embed", None), "gate": ("expert", "embed", "mlp"),
@@ -878,6 +982,26 @@ def _mla_absorb_out(o_lat, p, cfg):
                       p["w_ukv"][..., cfg.qk_nope_head_dim:].astype(cfg.dtype))
 
 
+def qk_normed(q, k, p, cfg):
+    """q [..., H, Dh], k [..., Hkv, Dh] through the layer's RMS norms over the
+    head dimension (cfg.qk_norm; before the rope), else as they are."""
+    if not cfg.qk_norm:
+        return q, k
+    return (ops.rms_norm(q, p["q_norm"], eps=cfg.norm_eps),
+            ops.rms_norm(k, p["k_norm"], eps=cfg.norm_eps))
+
+
+def attn_gated(out, x, p, cfg):
+    """The heads' output `out` [B, T, H, Dh] times sigmoid(x W_g), x [B, T, E]
+    the sublayer's normed input (cfg.attn_gate; before the output
+    projection), else as it is. The sigmoid in float32."""
+    if not cfg.attn_gate:
+        return out
+    with jax.named_scope("ray_tpu:attn_gate"):
+        g = jnp.einsum("bte,ehd->bthd", x, p["wg"].astype(cfg.dtype))
+        return out * jax.nn.sigmoid(g.astype(jnp.float32)).astype(out.dtype)
+
+
 def _attn_block(x, p, cfg, cos, sin, sp_axis, attn_impl, window=None):
     dt = cfg.dtype
     if cfg.mla:
@@ -896,7 +1020,8 @@ def _attn_block(x, p, cfg, cos, sin, sp_axis, attn_impl, window=None):
         q = q + p["bq"].astype(dt)
         k = k + p["bk"].astype(dt)
         v = v + p["bv"].astype(dt)
-    if cfg.pos == "rope":
+    q, k = qk_normed(q, k, p, cfg)
+    if cos is not None:  # this kind of layer has the rope (`rope_by_kind`)
         if sp_axis is not None:
             # sequence-sharded: offset positions by this shard's start
             idx = jax.lax.axis_index(sp_axis)
@@ -909,7 +1034,7 @@ def _attn_block(x, p, cfg, cos, sin, sp_axis, attn_impl, window=None):
             k = ops.apply_rope(k, cos, sin)
     out = ops.attention(q, k, v, causal=True, scale=cfg.softmax_scale, sp_axis=sp_axis,
                         impl=attn_impl, window=window)
-    out = jnp.einsum("bthd,hde->bte", out, p["wo"].astype(dt))
+    out = jnp.einsum("bthd,hde->bte", attn_gated(out, x, p, cfg), p["wo"].astype(dt))
     if cfg.bias:
         out = out + p["bo"].astype(dt)
     return out
@@ -931,6 +1056,9 @@ def _dense_mlp(x, p, cfg):
 
 
 def _moe_mlp(x, p, cfg):
+    """x [B, T, E] -> (the experts' output [B, T, E], the auxiliary loss); a
+    layer that holds a share of the experts (cfg.moe.share) returns a third
+    value, `ops.share_counts` of the call: int32 [2]."""
     dt, moe = cfg.dtype, cfg.moe
     B, T, E = x.shape
     xf = x.reshape(B * T, E)
@@ -947,14 +1075,20 @@ def _moe_mlp(x, p, cfg):
                                            scale=moe.routed_scaling_factor)
         else:
             idx, w, aux = ops.softmax_topk(router_logits, k=moe.top_k)
-        routing = None if ops.sorted_pays(B * T) else ops.onehot_dispatch(
-            idx, w, moe.num_experts, B * T)
+        # of a share the held experts' slots alone, their weights as routed
+        mine = ops.held_slots(idx, moe.first_expert, moe.held)[0] if moe.share else idx
+        routing = None if ops.sorted_pays(B * T, moe.slots_a_held_expert(B * T)) \
+            else ops.onehot_dispatch(mine, w, moe.held, B * T)
     else:
         routing = ops.topk_routing(router_logits, num_experts=moe.num_experts,
                                    k=moe.top_k, capacity_factor=moe.capacity_factor)
         aux = routing.aux_loss
+    # a share's products and its shared expert are named in the device trace
+    scope = jax.named_scope if moe.share else (lambda name: contextlib.nullcontext())
     if routing is None:
-        y = ops.moe_sorted(xf, idx, w, **experts, layer=p.get("layer"))
+        share = dict(first=moe.first_expert, of=moe.num_experts) if moe.share else {}
+        with scope("ray_tpu:experts_held"):
+            y = ops.moe_sorted(xf, idx, w, **experts, layer=p.get("layer"), **share)
     else:
         if "layer" in p:  # the stack's experts, whole: this layer's
             experts = jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(
@@ -966,7 +1100,10 @@ def _moe_mlp(x, p, cfg):
 
         y = ops.moe_apply(xf, routing, expert_fn, experts)
     if moe.n_shared_experts:  # one MLP, every token, ungated
-        y = y + _dense_mlp(xf, p["shared"], cfg)
+        with scope("ray_tpu:expert_shared"):
+            y = y + _dense_mlp(xf, p["shared"], cfg)
+    if moe.share:
+        return y.reshape(B, T, E), aux, ops.share_counts(idx, moe.first_expert, moe.held)
     return y.reshape(B, T, E), aux
 
 
@@ -1006,7 +1143,7 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
         h = _residual(h, mixed, layer_p, "post_attn_norm", cfg)
         normed = _norm(h, layer_p["norm2"], cfg)
         if "router" in layer_p["mlp"]:
-            delta, layer_aux = _moe_mlp(normed, layer_p["mlp"], cfg)
+            delta, layer_aux, *_ = _moe_mlp(normed, layer_p["mlp"], cfg)
             aux = aux + layer_aux
         else:
             delta = _dense_mlp(normed, layer_p["mlp"], cfg)

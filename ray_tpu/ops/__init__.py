@@ -3,8 +3,9 @@ from ray_tpu.ops.attention import attention, repeat_kv
 from ray_tpu.ops.flash_attention import flash_attention, flash_attention_forward
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.losses import fused_head_cross_entropy, softmax_cross_entropy
-from ray_tpu.ops.moe import (RoutingInfo, moe_apply, moe_sorted, onehot_dispatch,
-                             sigmoid_topk, softmax_topk, sorted_pays, topk_routing)
+from ray_tpu.ops.moe import (RoutingInfo, held_slots, moe_apply, moe_sorted, onehot_dispatch,
+                             share_counts, sigmoid_topk, softmax_topk, sorted_pays,
+                             topk_routing)
 from ray_tpu.ops.norms import layer_norm, rms_norm
 from ray_tpu.ops.ragged_paged_attention import (
     ragged_decode_attention, ragged_decode_attention_reference)
@@ -28,6 +29,7 @@ __all__ = [
     "layer_norm",
     "live_rows",
     "moe_apply",
+    "held_slots",
     "moe_sorted",
     "onehot_dispatch",
     "ragged_decode_attention",
@@ -38,6 +40,7 @@ __all__ = [
     "sigmoid_topk",
     "softmax_cross_entropy",
     "softmax_topk",
+    "share_counts",
     "sorted_pays",
     "ssm_chunk_scan",
     "ssm_state_update",

@@ -70,6 +70,32 @@ def _flash_per_shard(q, k, v, *, causal: bool, scale: float | None):
         check_vma=False)(q, k, v)
 
 
+# float32 scores of every head at once, [B, H, T, T], up to this many bytes;
+# over it the masked window attention takes one KV head's group of query heads
+# after another (48 heads over a bucket of 8,192: 12.9 GB at once, 1.6 GB a
+# group of 6), as a chunk's continuation does (models/decoding_paged.py)
+_SCORES_AT_ONCE = 2 << 30
+
+
+def _window_attention(q, k, v, *, scale, window: int):
+    """The masked XLA attention of a sequence longer than its window."""
+    (B, T, H, D), Hkv = q.shape, k.shape[2]
+    G = H // Hkv
+    if 4 * B * H * T * T <= _SCORES_AT_ONCE:
+        return reference_attention(q, repeat_kv(k, n_rep=G), repeat_kv(v, n_rep=G),
+                                   causal=True, scale=scale, window=window)
+
+    def group(one):
+        qg, kg, vg = one                                   # [B, T, G, D], [B, T, D] x 2
+        return reference_attention(qg, repeat_kv(kg[:, :, None], n_rep=G),
+                                   repeat_kv(vg[:, :, None], n_rep=G),
+                                   causal=True, scale=scale, window=window)
+
+    out = jax.lax.map(group, (jnp.moveaxis(q.reshape(B, T, Hkv, G, D), 2, 0),
+                              jnp.moveaxis(k, 2, 0), jnp.moveaxis(v, 2, 0)))
+    return jnp.moveaxis(out, 0, 2).reshape(B, T, H, D)
+
+
 def attention(q, k, v, *, causal: bool = True, scale: float | None = None,
               sp_axis: str | None = None, impl: str | None = None,
               window: int | None = None):
@@ -80,7 +106,8 @@ def attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     call made inside shard_map). window: query i sees key j iff
     0 <= i - j < window; a sequence no longer than the window is the plain
     causal case, a longer one takes the masked XLA path (the flash kernels
-    carry no window).
+    carry no window), a KV head's group at a time where the scores of all heads
+    at once would pass `_SCORES_AT_ONCE`.
     """
     H, Hkv = q.shape[2], k.shape[2]
     if H % Hkv != 0:
@@ -89,9 +116,7 @@ def attention(q, k, v, *, causal: bool = True, scale: float | None = None,
         if not causal or sp_axis is not None or impl == "flash":
             raise ValueError("a window is causal, unsharded over the sequence "
                              "and not in the flash kernels")
-        return reference_attention(q, repeat_kv(k, n_rep=H // Hkv),
-                                   repeat_kv(v, n_rep=H // Hkv), causal=True,
-                                   scale=scale, window=window)
+        return _window_attention(q, k, v, scale=scale, window=window)
     k = repeat_kv(k, n_rep=H // Hkv)
     v = repeat_kv(v, n_rep=H // Hkv)
 

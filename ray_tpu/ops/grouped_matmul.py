@@ -18,7 +18,10 @@ scalar prefetch: the row tile of `lhs` and of the output, and the group's
 name the same block and the pipeline fetches it once. A call therefore reads the
 experts' weights once and `lhs` once a column tile. A row tile that several
 groups share is visited by each in turn and each stores only its own rows; rows
-past the groups' sum come back zero.
+past the groups' sum come back zero, or, with `rows_past="skip"`, take no step
+at all and come back as whatever the buffer held (an expert layer that holds a
+share of the experts sorts the slots of the absent ones there and masks them:
+`ops.moe_sorted`).
 
 bfloat16 operands are multiplied as stored, summed in float32 and rounded once.
 """
@@ -68,15 +71,21 @@ def tiles_for(M: int, K: int, N: int, groups: int, itemsize: int = 2) -> tuple[i
     return tm, tk, tn
 
 
-def _schedule(group_sizes, M: int, tm: int, groups: int):
+def _schedule(group_sizes, M: int, tm: int, groups: int, tail_group: bool = True):
     """Per grid step: (group, row tile, first row, row past the last) of the
     (row tile, group) pairs that share rows, group by group, and their number.
-    Rows past the groups' sum are one more group that stores zeros."""
+    Rows past the groups' sum are one more group that stores zeros (`tail_group`),
+    or take no step (then a call none of whose groups has a row still takes
+    one, which stores zeros in the first tile: a grid is never empty)."""
     G = group_sizes.shape[0]
     sizes = group_sizes.astype(jnp.int32)
     ends = jnp.cumsum(sizes)
-    sizes = jnp.concatenate([sizes, M - ends[-1:]])
-    ends = jnp.concatenate([ends, jnp.full((1,), M, jnp.int32)])
+    if tail_group:
+        sizes = jnp.concatenate([sizes, M - ends[-1:]])
+        ends = jnp.concatenate([ends, jnp.full((1,), M, jnp.int32)])
+    else:
+        sizes = jnp.concatenate([sizes, jnp.zeros((1,), jnp.int32)])
+        ends = jnp.concatenate([ends, ends[-1:]])
     starts = ends - sizes
     first_tile = starts // tm
     n_tiles = jnp.where(sizes > 0, (ends - 1) // tm - first_tile + 1, 0)
@@ -88,7 +97,8 @@ def _schedule(group_sizes, M: int, tm: int, groups: int):
     tile = first_tile[g] + steps - (tile_ends[g] - n_tiles[g])
     tail = g == G
     lo = jnp.where(tail, M, starts[g])
-    return jnp.minimum(g, G - 1), tile, lo, jnp.where(tail, M, ends[g]), tile_ends[-1]
+    n_steps = tile_ends[-1] if tail_group else jnp.maximum(tile_ends[-1], 1)
+    return jnp.minimum(g, G - 1), tile, lo, jnp.where(tail, M, ends[g]), n_steps
 
 
 def _kernel(gid_ref, tile_ref, lo_ref, hi_ref, lhs_ref, rhs_ref, out_ref, *, tk: int):
@@ -116,15 +126,17 @@ def _kernel(gid_ref, tile_ref, lo_ref, hi_ref, lhs_ref, rhs_ref, out_ref, *, tk:
     out_ref[...] = jnp.where(mine, acc.astype(out_ref.dtype), kept)
 
 
-def grouped_matmul_kernel(lhs, rhs, group_sizes, groups_with_rows: int | None = None, *,
-                          tiles=None, interpret: bool = False):
+def grouped_matmul_kernel(lhs, rhs, group_sizes, groups_with_rows: int | None = None,
+                          rows_past: str = "zero", *, tiles=None, interpret: bool = False):
     """The Pallas kernel itself (`grouped_matmul` picks it on the TPU); `tiles`
     (tm, tk, tn) for a sweep, by default `tiles_for` the shapes."""
     (M, K), (G, _, N) = lhs.shape, rhs.shape
     groups = min(groups_with_rows or G, G)
     itemsize = jnp.dtype(lhs.dtype).itemsize
-    tm, tk, tn = tiles or tiles_for(M, K, N, groups, itemsize)
-    gid, tile, lo, hi, n_steps = _schedule(group_sizes, M, tm, groups)
+    # (a caller whose rows are spread over more groups than it holds says so
+    # by `groups_with_rows` above G: the tiles follow the rows a group)
+    tm, tk, tn = tiles or tiles_for(M, K, N, max(groups_with_rows or G, groups), itemsize)
+    gid, tile, lo, hi, n_steps = _schedule(group_sizes, M, tm, groups, rows_past != "skip")
     blocks = 2 * (tm * K + K * tn + tm * tn) * itemsize + 3 * tm * tn * 4
     return pl.pallas_call(
         functools.partial(_kernel, tk=tk),
@@ -149,22 +161,29 @@ def grouped_matmul_kernel(lhs, rhs, group_sizes, groups_with_rows: int | None = 
     )(gid, tile, lo, hi, lhs, rhs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def grouped_matmul(lhs, rhs, group_sizes, groups_with_rows: int | None = None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def grouped_matmul(lhs, rhs, group_sizes, groups_with_rows: int | None = None,
+                   rows_past: str = "zero"):
     """lhs [M, K] x rhs [G, K, N] by `group_sizes` [G] -> [M, N]: the kernel
     where the program is lowered for a TPU, `jax.lax.ragged_dot` elsewhere.
     `groups_with_rows`: at most so many groups have rows, where the caller knows
-    (of a stack's L*E groups one layer's E): the tiles follow the rows a group."""
+    (of a stack's L*E groups one layer's E): the tiles follow the rows a group.
+    A layer that holds a share of the experts gives the experts its rows were
+    routed over (more than it holds: most rows lie past its groups).
+    `rows_past`: the rows past the groups' sum come back "zero", or with "skip"
+    undefined (the kernel takes no step for them; the caller masks them)."""
     return jax.lax.platform_dependent(
         lhs, rhs, group_sizes, default=jax.lax.ragged_dot,
-        tpu=functools.partial(grouped_matmul_kernel, groups_with_rows=groups_with_rows))
+        tpu=functools.partial(grouped_matmul_kernel, groups_with_rows=groups_with_rows,
+                              rows_past=rows_past))
 
 
-def _fwd(lhs, rhs, group_sizes, groups_with_rows):
-    return grouped_matmul(lhs, rhs, group_sizes, groups_with_rows), (lhs, rhs, group_sizes)
+def _fwd(lhs, rhs, group_sizes, groups_with_rows, rows_past):
+    return (grouped_matmul(lhs, rhs, group_sizes, groups_with_rows, rows_past),
+            (lhs, rhs, group_sizes))
 
 
-def _bwd(_, res, g):
+def _bwd(_, __, res, g):
     lhs, rhs, group_sizes = res
     _, vjp = jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, group_sizes), lhs, rhs)
     return (*vjp(g), np.zeros(group_sizes.shape, jax.dtypes.float0))

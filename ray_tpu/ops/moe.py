@@ -20,6 +20,13 @@ Two dispatches carry them out:
 
 A layer that may drop nothing picks between them by the tokens of the call,
 `sorted_pays(N)`: with C = N the one-hot form drops nothing either.
+
+A layer may hold a SHARE of the experts (`first`: experts [first, first + E)
+of the router's, E the experts its weights have): the routing is over all of
+them, a slot whose expert lies outside the share is not computed and adds
+nothing, and the weights of the slots that are held are what the full routing
+gave them. What the other shares would add is theirs to add; nothing here
+stands in for them or for the exchange with them.
 """
 
 from __future__ import annotations
@@ -55,8 +62,32 @@ class RoutingInfo(NamedTuple):
 SORTED_MIN_TOKENS = 512
 
 
-def sorted_pays(n_tokens: int) -> bool:
+def sorted_pays(n_tokens: int, slots_a_held_expert: float | None = None) -> bool:
+    """Whether a dropless layer of `n_tokens` sorts. A layer that holds a share
+    of the experts also sorts where its held experts get under one routed slot
+    each in the mean (`slots_a_held_expert` = N * k / the router's experts):
+    then some surely have no row, the sorted form reads only those that have
+    one, and the one-hot form reads them all."""
+    if slots_a_held_expert is not None and slots_a_held_expert < 1.0:
+        return True
     return n_tokens >= SORTED_MIN_TOKENS
+
+
+def held_slots(expert_idx, first: int, held: int):
+    """expert_idx [N, k] over the router's experts -> (index among the `held`
+    experts [first, first + held) where the slot's expert is one of them, else
+    `held`; [N, k] bool: whether it is)."""
+    local = expert_idx - first
+    mine = (local >= 0) & (local < held)
+    return jnp.where(mine, local, held), mine
+
+
+def share_counts(expert_idx, first: int, held: int):
+    """int32 [2]: (routed slots whose expert is held here, held experts with at
+    least one such slot) of one call: what `stats()["experts"]` sums."""
+    local, mine = held_slots(expert_idx, first, held)
+    rows = jnp.any(local.reshape(-1)[:, None] == jnp.arange(held)[None, :], axis=0)
+    return jnp.stack([mine.sum(dtype=jnp.int32), rows.sum(dtype=jnp.int32)])
 
 
 def topk_routing(router_logits, *, num_experts: int, k: int,
@@ -137,7 +168,7 @@ def sigmoid_topk(router_logits, select_bias, *, k: int, scale: float = 1.0):
     return expert_idx, w, jnp.zeros((), jnp.float32)
 
 
-def moe_sorted(x, expert_idx, weights, gate, up, down, *, layer=None):
+def moe_sorted(x, expert_idx, weights, gate, up, down, *, layer=None, first=None, of=None):
     """Dropless SwiGLU experts. x [N, D]; expert_idx, weights [N, k]; gate,
     up [E, D, F], down [E, F, D]. Every one of the N*k routed slots is
     computed: slots sorted by expert, three grouped matmuls over the group
@@ -148,10 +179,19 @@ def moe_sorted(x, expert_idx, weights, gate, up, down, *, layer=None):
     layer's have rows: the grouped product is a custom call, and a layer's
     slice of the stack handed to one is copied out whole first, every layer
     of every step (the compiler's account, PERF.md section 4); the kernel's
-    steps are laid over the groups with rows, so the others cost nothing."""
+    steps are laid over the groups with rows, so the others cost nothing.
+
+    With `first` the weights are those of a SHARE of the experts, [first,
+    first + E) of the ones `expert_idx` counts: the slots of absent experts are
+    sorted behind the held groups, take no step of the grouped products
+    (`rows_past="skip"`) and are masked to zero here; `of`, the experts the
+    slots were routed over, tells the products how few rows a group has."""
     N, k = expert_idx.shape
     E = gate.shape[-3]
     flat = expert_idx.reshape(N * k)
+    share = (E,)    # the grouped products' other arguments
+    if first is not None:
+        flat, share = held_slots(flat, first, E)[0], (max(of or E, E), "skip")
     order = jnp.argsort(flat, stable=True)                     # slot ids by expert
     sizes = jnp.sum(flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :],
                     axis=0, dtype=jnp.int32)
@@ -161,9 +201,11 @@ def moe_sorted(x, expert_idx, weights, gate, up, down, *, layer=None):
         gate, up, down = (w.reshape(-1, *w.shape[2:]) for w in (gate, up, down))
     xs = x[order // k]                                         # [N*k, D]
     dt = x.dtype
-    h = jax.nn.silu(grouped_matmul(xs, gate.astype(dt), sizes, E)) \
-        * grouped_matmul(xs, up.astype(dt), sizes, E)
-    ys = grouped_matmul(h, down.astype(dt), sizes, E)          # [N*k, D]
+    h = jax.nn.silu(grouped_matmul(xs, gate.astype(dt), sizes, *share)) \
+        * grouped_matmul(xs, up.astype(dt), sizes, *share)
+    ys = grouped_matmul(h, down.astype(dt), sizes, *share)     # [N*k, D]
+    if first is not None:
+        ys = jnp.where((jnp.arange(N * k) < sizes.sum())[:, None], ys, 0)
     back = jnp.zeros_like(order).at[order].set(jnp.arange(N * k, dtype=order.dtype))
     y = ys[back].reshape(N, k, -1).astype(jnp.float32)
     return jnp.sum(y * weights[..., None].astype(jnp.float32), axis=1).astype(dt)

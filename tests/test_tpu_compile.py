@@ -96,6 +96,8 @@ def _ragged_launch(chip, shape, window=None):
     ((32, 8, 4, 128, 64, 128), None),    # mixtral-8x7b
     ((48, 4, 8, 128, 64, 256), None),    # mellum2-12b-a2.5b's full layers
     ((48, 4, 8, 128, 64, 17), 1024),     # ... and its window layers
+    ((24, 8, 6, 128, 64, 520), None),    # trinity-large-preview's full layer: a group of 6
+    ((24, 8, 6, 128, 64, 65), 4096),     # ... and its window layers, a sweep of 65 pages
 ])
 def test_ragged_decode_kernel_compiles(chip, shape, window):
     assert "tpu_custom_call" in _ragged_launch(chip, shape, window).compile().as_text()
@@ -245,6 +247,13 @@ DECODE_STEPS = {
         32, ["ssm_state_update", "ssm_state_update", "ragged_paged_attention"], None),
     # the step of PR 42's tree at these shapes: 9,511,936 bytes
     "mixtral-8x7b.doc-saturated": (128, ["ragged_paged_attention"], 9_511_936),
+    # (in the text's order) the period's three window layers with their held
+    # experts' three grouped products, the leading dense layer, the period's
+    # full layer with its own three
+    "trinity-large-preview.agent-saturated": (
+        512, ["ragged_window_attention", "grouped_matmul", "grouped_matmul", "grouped_matmul",
+              "ragged_window_attention", "ragged_paged_attention", "grouped_matmul",
+              "grouped_matmul", "grouped_matmul"], None),
 }
 
 
@@ -277,7 +286,8 @@ def test_decode_step_reads_the_pools_where_they_lie(chip, cell):
     assert _kernel_calls(compiled.as_text()) == kernels
 
 
-SERVE_CACHES = ["mellum2-12b-a2.5b", "mixtral-8x7b", "kimi-vl-a3b", "ouro-2.6b"]
+SERVE_CACHES = ["mellum2-12b-a2.5b", "mixtral-8x7b", "kimi-vl-a3b", "ouro-2.6b",
+                "trinity-large-preview"]
 
 
 def _serve_cache(name):
@@ -292,6 +302,13 @@ def _serve_cache(name):
         cfg = models.mellum_config("12b-a2.5b", n_layers=12, param_dtype=bf, max_seq_len=16640)
         full, ring = (3, 2688, 64, 4, 128), (9, 1585, 64, 4, 128)
         return cfg, (48, 16640, 2688, 64, 48 * 33 + 1, 33), 1024, {
+            "kp": full, "vp": full, "wkp": ring, "wvp": ring}
+    if name == "trinity-large-preview":   # a dense layer among the 4 window layers, a ring of 97
+        cfg = models.trinity_config("large-preview", n_layers=5, n_dense_layers=1,
+                                    vocab_size=25024, param_dtype=bf, max_seq_len=33280,
+                                    experts_held=32)
+        full, ring = (1, 9216, 64, 8, 128), (4, 2329, 64, 8, 128)
+        return cfg, (24, 33280, 9216, 64, 24 * 97 + 1, 97), 2048, {
             "kp": full, "vp": full, "wkp": ring, "wvp": ring}
     if name == "mixtral-8x7b":
         cfg = models.mixtral_config("8x7b", n_layers=4, param_dtype=bf, max_seq_len=8320)
@@ -323,6 +340,11 @@ def test_page_writers_update_the_pools_where_they_lie(chip, name, writer):
     from ray_tpu.models import decoding_paged as dp
 
     cfg, cache, bucket, shapes = _serve_cache(name)
+    # leading dense layers are window layers stacked apart from the periods':
+    # an insert joins the two stacks' K and V of the bucket once (a copy of
+    # what is written, no pool's)
+    joined = 2 * (cfg.n_planes - cfg.n_full_layers) * bucket * cfg.kv_heads * cfg.head_dim * 2 \
+        if cfg.n_dense_layers and cfg.window else 0
 
     def sds(s, dt=jnp.int32):
         return jax.ShapeDtypeStruct(s, dt, sharding=chip)
@@ -336,7 +358,8 @@ def test_page_writers_update_the_pools_where_they_lie(chip, name, writer):
     ids, row = sds((bucket // 64,)), sds(state["block"].shape[1:])
     ring = sds(state["wblock"].shape[1:]) if cfg.window else None
     if writer == "write_kv_pages":
-        lowered = dp.write_kv_pages.lower(state, kv, ids, ring, sds(()) if cfg.window else None)
+        lowered = dp.write_kv_pages.lower(state, kv, ids, ring, sds(()) if cfg.window else None,
+                                          dense_layers=cfg.n_dense_layers)
     elif writer == "insert_sequence_paged":
         lowered = dp.insert_sequence_paged.lower(state, sds(()), kv, sds(()), sds(()), row,
                                                  cfg, ring)
@@ -347,7 +370,7 @@ def test_page_writers_update_the_pools_where_they_lie(chip, name, writer):
     m = compiled.memory_analysis()
     nbytes = [2 * math.prod(v.shape) for v in pools.values()]
     assert m.alias_size_in_bytes >= sum(nbytes)
-    assert m.temp_size_in_bytes < min(nbytes) // 100
+    assert m.temp_size_in_bytes < min(nbytes) // 100 + joined
     text = compiled.as_text()
     for shape in {v.shape for v in pools.values()}:
         dims = ",".join(map(str, shape))
@@ -536,3 +559,46 @@ def test_granite_prefill_fits_beside_the_state(chip):
     held = _held_bytes(state) + m.argument_size_in_bytes
     assert held + m.temp_size_in_bytes + m.output_size_in_bytes < 16.0e9
     assert "ssm_state_update" not in prefill.as_text()
+
+
+def test_trinity_chunk_and_check_prefill_fit_beside_the_weights(chip, monkeypatch):
+    """`trinity-large-preview.agent-saturated`: the largest chunk program (2,048
+    tokens over a 32,768-token prefix, scores one KV head's group of 6 at a
+    time) beside both pools, and the check's own unchunked prefill of a
+    bucket of 8,192, twice the window: the masked window attention a KV
+    head's group at a time (1.6 GB of scores, not 12.9), the full layer in the
+    flash kernel. chipbench/tests/test_trinity.py holds the whole account."""
+    import math
+    import sys
+
+    import ray_tpu.ops.attention  # noqa: F401
+    from chipbench import harness, program
+    from ray_tpu.models import decoding, decoding_paged as dp
+
+    monkeypatch.setattr(sys.modules["ray_tpu.ops.attention"], "_flash_ok",
+                        lambda q: q.shape[1] % 256 == 0 and q.shape[1] >= 1024)
+    conf = harness.resolve_cell("trinity-large-preview.agent-saturated")["config_file"]
+    cfg, eng, aot = program.transformer_config(conf["program"]), conf["engine"], conf["aot"]
+    params, state = _abstract_step_inputs(
+        chip, cfg, eng["max_slots"], eng["max_len"], eng["num_pages"], eng["page_size"])
+
+    def sds(s, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(s, dt, sharding=chip)
+
+    def total(compiled):
+        m = compiled.memory_analysis()
+        return (m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+                + m.temp_size_in_bytes)
+
+    kv = lambda layers, tokens: sds((layers, tokens, 8, 128), jnp.bfloat16)  # noqa: E731
+    chunk = dp.prefill_with_prefix.lower(
+        params, sds((1, 2048)), kv(1, 32768), kv(1, 32768), sds(()), sds(()), cfg,
+        kv(4, 4096), kv(4, 4096)).compile()
+    assert "grouped_matmul" in _kernel_calls(chunk.as_text())
+    pools = aot["full_pool_bytes"] + aot["window_pool_bytes"]
+    assert chunk.memory_analysis().temp_size_in_bytes < 1.3e9     # 6 x 2048 x 34816 x 4 and change
+    assert total(chunk) + pools < 15.49e9
+    check = decoding.prefill.lower(params, sds((1, 8192)), sds(()), cfg).compile()
+    assert check.memory_analysis().temp_size_in_bytes < 3e9
+    assert total(check) < 15.49e9
+    assert math.isclose(total(check), aot["check_prefill_8192_bytes"], rel_tol=0.01)
