@@ -3,10 +3,16 @@
 Replaces the thread-per-request stdlib server: one event loop handles all
 connections (keep-alive, pipelined clients, slow readers) with a bounded
 connection semaphore; blocking deployment-handle calls run on a bounded
-executor so the loop never stalls; streaming responses bridge a blocking
-generator into chunked transfer frames through an asyncio queue; shutdown
-is graceful — stop accepting, drain in-flight requests up to a deadline,
-then close.
+executor so the loop never stalls; a streaming response's blocking
+generator is pulled on a thread of that stream's own and written out as
+chunked transfer frames by the loop; shutdown is graceful — stop accepting,
+drain in-flight requests up to a deadline, then close.
+
+Hand-off and delivery share no queue. The executor runs handlers only
+(short for a stream: parse, route, hand the request to the replica), so a
+new request never waits behind answers being delivered; every open stream
+is delivered at once, and what bounds them is the connection semaphore and,
+downstream, the deployment's `max_ongoing_requests`.
 
 (reference: python/ray/serve/_private/proxy.py:706 — uvicorn-based proxy
 with graceful draining; uvicorn isn't in the image, so this is a minimal
@@ -16,7 +22,6 @@ native-asyncio equivalent.)
 from __future__ import annotations
 
 import asyncio
-import concurrent.futures
 import json
 import logging
 import threading
@@ -51,23 +56,29 @@ def _max_body_bytes() -> int:
     return RayConfig.instance().serve_max_http_body_bytes
 
 
-def _observe_accept(seconds: float) -> None:
-    """Executor dispatch wait (request fully read → handler running): the
-    'accept' phase of the proxy breakdown. Queueing here means the bounded
-    executor is the bottleneck, not the downstream handle."""
+def _observe_wait(phase: str, seconds: float) -> None:
+    """The two waits a request can have for the proxy itself, as phases of
+    the proxy breakdown. 'accept': request fully read → handler running
+    (queueing here means the bounded executor is the bottleneck, not the
+    downstream handle). 'deliver_wait': response headers written → the
+    stream's first pull from its iterator."""
     try:
         from ray_tpu.serve import request_context as rc
 
-        rc.observe_phase(rc.PROXY_PHASE, "accept", seconds)
+        rc.observe_phase(rc.PROXY_PHASE, phase, seconds)
     except Exception as e:  # noqa: BLE001 — must never fail a request
-        logger.debug("proxy accept-phase metric emit failed: %r", e)
+        logger.debug("proxy %s-phase metric emit failed: %r", phase, e)
 
 
 # Routes that read a deployment's own state (last path segment). They are
-# answered on two threads of their own: the request pool's threads are held
-# by streaming answers for their whole life, and a reading of the state that
-# queued behind those would say what the state was seconds later.
+# answered on two threads of their own: a unary handler holds a thread of
+# the request pool until its result is there, and a reading of the state
+# that queued behind those would say what the state was seconds later.
 STATE_ROUTES = ("stats", "health", "metrics")
+
+# Items a stream may hold between its iterator and the socket: what a slow
+# client lets pile up before the pulls stop.
+STREAM_ITEMS_AHEAD = 16
 
 
 def _reads_state(path: str) -> bool:
@@ -102,6 +113,11 @@ class AsyncHTTPServer:
             max_workers=2, thread_name_prefix="serve-http-state")
         self._loop: asyncio.AbstractEventLoop | None = None
         self._server: asyncio.base_events.Server | None = None
+        # streams being delivered now and the most there have been, and the
+        # threads that pull their iterators
+        self._streams_open = 0
+        self._streams_open_peak = 0
+        self._deliveries: set[threading.Thread] = set()
         self._inflight = 0
         self._inflight_zero = threading.Event()
         self._inflight_zero.set()
@@ -262,7 +278,7 @@ class AsyncHTTPServer:
         _t_queued = time.perf_counter()
 
         def _run_handler():
-            _observe_accept(time.perf_counter() - _t_queued)
+            _observe_wait("accept", time.perf_counter() - _t_queued)
             return self.handler(method, path, headers, body)
 
         extra: dict | None = None
@@ -288,39 +304,55 @@ class AsyncHTTPServer:
                 f"\r\n".encode() + payload)
             await writer.drain()
             return keep
-        # streaming: a blocking iterator bridged through an asyncio queue
         writer.write(
             f"HTTP/1.1 {status} X\r\nContent-Type: {ctype}\r\n"
             "Cache-Control: no-cache\r\nTransfer-Encoding: chunked\r\n"
             "Connection: close\r\n\r\n".encode())
         await writer.drain()
-        q: asyncio.Queue = asyncio.Queue(maxsize=16)
+        await self._deliver(writer, reader, payload)
+        return False
+
+    async def _deliver(self, writer: asyncio.StreamWriter,
+                       reader: asyncio.StreamReader, payload) -> None:
+        """Stream `payload`, a blocking iterator, to the client as chunks.
+
+        The iterator is pulled on a thread of this stream's own, never on
+        the request pool: a delivery lives for seconds and mostly waits, a
+        handler is short and must start at once. `_on_connection` holds the
+        connection semaphore around the whole exchange, so there are at
+        most `max_connections` such threads. An item costs the thread one
+        `call_soon_threadsafe` and the loop one `q.get`; `credits` is the
+        back-pressure (the loop hands one back per item it takes, so a slow
+        client stops the pulls STREAM_ITEMS_AHEAD items ahead of it)."""
+        loop = asyncio.get_running_loop()
+        q: asyncio.Queue = asyncio.Queue()  # bounded by `credits`
+        credits = threading.Semaphore(STREAM_ITEMS_AHEAD)
         DONE = object()
-        aborted = threading.Event()  # consumer gone: pump must not block
+        aborted = threading.Event()  # consumer gone: the thread must end
+        t_headers = time.perf_counter()
 
-        def put_blocking(item) -> bool:
-            while not aborted.is_set():
-                fut = asyncio.run_coroutine_threadsafe(q.put(item), loop)
-                try:
-                    fut.result(timeout=0.5)
-                    return True
-                except concurrent.futures.TimeoutError:
-                    fut.cancel()  # slow/dead consumer: re-check aborted
-                    if fut.done() and not fut.cancelled():
-                        return True  # the put landed as the timeout fired
-                except Exception:
-                    return False  # loop closed
-            return False
+        def send(item) -> bool:
+            while not credits.acquire(timeout=0.5):
+                if aborted.is_set():
+                    return False
+            if aborted.is_set():
+                return False
+            try:
+                loop.call_soon_threadsafe(q.put_nowait, item)
+            except RuntimeError:
+                return False  # loop closed
+            return True
 
-        def pump():
+        def deliver():
+            _observe_wait("deliver_wait", time.perf_counter() - t_headers)
             try:
                 try:
                     for item in payload:
-                        if not put_blocking(item):
+                        if not send(item):
                             return
                 except Exception as e:  # noqa: BLE001 — surfaced as a chunk
-                    put_blocking(e)
-                put_blocking(DONE)
+                    send(e)
+                send(DONE)
             finally:
                 close = getattr(payload, "close", None)
                 if close is not None:
@@ -329,26 +361,31 @@ class AsyncHTTPServer:
                     except Exception as e:  # noqa: BLE001 — user generator
                         logger.debug("stream generator close() raised "
                                      "during teardown: %r", e)
+                self._deliveries.discard(threading.current_thread())
 
-        self._executor.submit(pump)
+        thread = threading.Thread(target=deliver, daemon=True,
+                                  name="serve-http-deliver")
+        self._count_stream(+1)
+        self._deliveries.add(thread)
+        thread.start()
         # half-closed-socket watch: an SSE client sends nothing after its
         # request, so any readability — EOF or stray bytes — means it went
         # away. Without this, a disconnect is only noticed at the next
         # chunk WRITE, which for a slow/stalled stream may be never; the
-        # abort must interrupt the wait for the next item, not ride on it.
+        # abort must interrupt the wait for the next item, not ride on it:
+        # it wakes `q.get` with an item of its own.
         disconnect = asyncio.ensure_future(reader.read(1))
-        get_task: asyncio.Task | None = None
-        item = None
+        disconnect.add_done_callback(lambda _f: q.put_nowait(DONE))
         try:
             while True:
-                get_task = asyncio.ensure_future(q.get())
-                await asyncio.wait({get_task, disconnect},
-                                   return_when=asyncio.FIRST_COMPLETED)
-                if not get_task.done():
-                    break  # client disconnected while the stream was quiet
-                item = get_task.result()
-                get_task = None
+                item = await q.get()
+                if disconnect.done():
+                    break  # hung up while the stream was quiet, or the
+                    # last write "succeeded" into a dead socket
+                credits.release()
                 if item is DONE:
+                    writer.write(b"0\r\n\r\n")
+                    await writer.drain()
                     break
                 if isinstance(item, Exception):
                     chunk = (b"data: " + json.dumps(
@@ -358,20 +395,27 @@ class AsyncHTTPServer:
                     chunk = item if isinstance(item, (bytes, bytearray)) else str(item).encode()
                 writer.write(f"{len(chunk):X}\r\n".encode() + chunk + b"\r\n")
                 await writer.drain()
-                if disconnect.done():
-                    break  # write "succeeded" into a dead socket: stop
-            if item is DONE and not disconnect.done():
-                writer.write(b"0\r\n\r\n")
-                await writer.drain()
         finally:
-            # aborted unblocks the pump; closing its queue path makes the
-            # pump's finally close the deployment generator, which carries
-            # the cancel upstream (replica → engine slot/page reclaim)
+            # aborted ends the thread at its next item (at once if it waits
+            # for a credit); its finally closes the deployment generator,
+            # which carries the cancel upstream (replica → engine slot/page
+            # reclaim)
             aborted.set()
+            credits.release()
             disconnect.cancel()
-            if get_task is not None:
-                get_task.cancel()
-        return False
+            self._count_stream(-1)
+
+    def _count_stream(self, delta: int) -> None:
+        """A delivery begins (+1) or ends (-1); on the loop only."""
+        self._streams_open += delta
+        self._streams_open_peak = max(self._streams_open_peak,
+                                      self._streams_open)
+        try:
+            from ray_tpu.serve import request_context as rc
+
+            rc.gauge_streams_open(self._streams_open, self._streams_open_peak)
+        except Exception as e:  # noqa: BLE001 — must never fail a request
+            logger.debug("proxy streams-open gauge emit failed: %r", e)
 
     # ----------------------------------------------------------------- stop
 
@@ -394,4 +438,8 @@ class AsyncHTTPServer:
         loop.call_soon_threadsafe(_cancel_all)
         self._executor.shutdown(wait=False)
         self._state_executor.shutdown(wait=False)
-        self._thread.join(timeout=5.0)
+        # the loop thread, then what its cancelled streams leave: a
+        # delivery thread ends once its iterator hands it the next item
+        deadline = time.monotonic() + 5.0
+        for t in [self._thread, *self._deliveries]:
+            t.join(timeout=max(deadline - time.monotonic(), 0.0))

@@ -6,9 +6,9 @@ request tracing + phase attribution):
 - **phase histograms** — always-on, pre-bound (`Histogram.bind`, the
   compiled-DAG fast path from PR 4) per (metric, phase) labelset, gated by
   `RayConfig.serve_metrics`. One histogram family per layer so dashboards
-  can slice the serving hot path: proxy accept/parse/route/handle, handle
-  pick/RTT, replica queue-wait/execute, engine admission-wait/inter-token,
-  PD per-page transfer waits.
+  can slice the serving hot path: proxy accept/parse/route/handle/
+  deliver_wait, handle pick/RTT, replica queue-wait/execute, engine
+  admission-wait/inter-token, PD per-page transfer waits.
 - **request ids + span sampling** — every request entering the HTTP proxy
   gets a 16-byte id; every Nth (`RayConfig.serve_span_sample_every`) opens
   a `tracing.begin_request_trace` root whose context propagates through handles
@@ -66,7 +66,8 @@ def _make_histograms() -> dict:
         PROXY_PHASE: met.get_or_create(
             met.Histogram, "ray_tpu_serve_proxy_phase_seconds",
             "serve HTTP proxy request phases (accept = executor dispatch "
-            "wait, parse, route, handle = downstream RTT)", **kw),
+            "wait, parse, route, handle = downstream RTT, deliver_wait = "
+            "stream headers written -> first pull from its iterator)", **kw),
         HANDLE_PHASE: met.get_or_create(
             met.Histogram, "ray_tpu_serve_handle_phase_seconds",
             "DeploymentHandle phases (pick = router choice incl. "
@@ -295,6 +296,25 @@ def count_shed(component: str) -> None:
         import logging
 
         logging.getLogger(__name__).debug("shed metric failed: %r", e)
+
+
+def gauge_streams_open(now: int, peak: int) -> None:
+    """Streamed answers this process's HTTP server is delivering now, and
+    the most it has delivered at once since it started (`stat` = now |
+    peak). Set where a delivery begins and ends; `proxy` is the pid, so
+    the shards of a proxy plane keep a series each."""
+    if not metrics_enabled():
+        return
+    from ray_tpu.util import metrics as met
+
+    g = met.get_or_create(
+        met.Gauge, "ray_tpu_serve_proxy_streams_open",
+        "streamed answers an HTTP proxy is delivering (stat=now) and the "
+        "most it has delivered at once since its start (stat=peak)",
+        tag_keys=("proxy", "stat"))
+    proxy = str(os.getpid())
+    g.set(now, tags={"proxy": proxy, "stat": "now"})
+    g.set(peak, tags={"proxy": proxy, "stat": "peak"})
 
 
 # --------------------------------------------------------- flight recorder

@@ -103,6 +103,9 @@ EXPECTED_METRICS = (
     # from the controller's last publish — its liveness heartbeat)
     "ray_tpu_serve_proxy_shards",
     "ray_tpu_serve_routing_table_age_seconds",
+    # streamed answers an HTTP proxy is delivering, now and at most
+    # (serve/http_server.py; a delivery holds no request-pool thread)
+    "ray_tpu_serve_proxy_streams_open",
     # scheduler decision attribution (gcs.py, unregistered — folded into
     # metrics_snapshot under the "gcs" source): decision latency by
     # kind/outcome, decisions/s counters (the scale harness's scheduler
