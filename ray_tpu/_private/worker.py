@@ -39,6 +39,9 @@ from ray_tpu._private.ray_config import RayConfig as _RayConfig
 INLINE_LIMIT = _RayConfig.get("inline_object_limit")
 ARGS_INLINE_LIMIT = 4 * INLINE_LIMIT
 MAX_RECON_ATTEMPTS = 4
+# how long a worker whose node is gone gives its main thread to leave by
+# itself (`disconnect`) before it exits under a task that does not return
+_LOST_NODE_GRACE_S = 2.0
 
 
 # the process's CoreWorker, for ObjectRef lifecycle hooks (None in local
@@ -643,6 +646,14 @@ class CoreWorker:
                 for fut in self._pending.values():
                     fut.set({"ok": False, "error": "connection to GCS lost"})
                 self._pending.clear()
+            if self.kind == "worker":
+                # the main thread leaves `exec_loop` on the None above, unless
+                # a task holds it (a plain task, an actor without a pool):
+                # nothing that task returns can be delivered any more, and a
+                # worker that outlives its node (a driver killed from outside
+                # reaps nothing) keeps its chip from whoever comes next
+                time.sleep(_LOST_NODE_GRACE_S)
+                os._exit(1)
 
     def _try_reconnect(self) -> bool:
         """Dial + re-register on a fresh connection. The register handshake

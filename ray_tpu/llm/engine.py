@@ -526,6 +526,13 @@ class TPUEngine:
         self.prefill_chunk = prefill_chunk
         self._prefilling: list = []  # requests mid-chunked-prefill
         self.prefill_chunks_run = 0
+        # continuation prefills (a chunk past the first, or a suffix behind
+        # shared pages) by the form their attention took (the flash launch or
+        # the XLA form: `dp.continuation_blocks`), and the query-key pairs
+        # their masks admit, summed over the layers
+        self.continuations_kernel = 0
+        self.continuations_xla = 0
+        self.attended_pairs = 0
         # decode attention is one ragged-paged-attention launch over the
         # batch's live page tables (ops/ragged_paged_attention.py): the
         # Pallas kernel where the code can see a TPU and an unsharded pool,
@@ -1724,10 +1731,12 @@ class TPUEngine:
                 k_pre, v_pre = dp.gather_prefix_pages(
                     self.state["kp"], self.state.get("vp"), padded_ids)
             self.prefix_tokens_gathered += pre_len
+            self._note_continuation(pre_len, npad * P, suf_bucket, len(suffix))
             with dispatch("prefill_with_prefix"):
                 logits, kv = dp.prefill_with_prefix(
                     self.params, padded, k_pre, v_pre,
-                    jnp.int32(pre_len), jnp.int32(len(suffix)), self.cfg)
+                    jnp.int32(pre_len), jnp.int32(len(suffix)), self.cfg,
+                    kernel=self._ragged_kernel)
         else:
             with dispatch("prefill"):
                 logits, kv = decoding.prefill(
@@ -1751,6 +1760,26 @@ class TPUEngine:
             self._register_blocks(slot, tokens, hashes, n_pre, priv)
         self._first_unread(req, first, "admit_wait")
         return True
+
+    def _note_continuation(self, prefix_len: int, span: int, bucket: int,
+                           length: int) -> None:
+        """A continuation's dispatch on the record: `length` tokens in a
+        bucket of `bucket` behind `prefix_len`, the full layers over a
+        gathered span of `span`, the window layers over their window's
+        worth. A query at position p sees the p + 1 keys up to itself, on a
+        window layer the last `window` of them."""
+        cfg = self.cfg
+        n_window = cfg.n_layers - cfg.n_full_layers if cfg.window else 0
+        keys = prefix_len + 1 + np.arange(length, dtype=np.int64)
+        self.attended_pairs += (cfg.n_planes - n_window) * int(keys.sum())
+        spans = [span]
+        if n_window:
+            self.attended_pairs += n_window * int(np.minimum(keys, cfg.window).sum())
+            spans.append(cfg.window)
+        took = all(dp.continuation_blocks(cfg, bucket, keys_held, self._ragged_kernel)
+                   for keys_held in spans)
+        self.continuations_kernel += took
+        self.continuations_xla += not took
 
     def _prefill_step(self):
         """Run ONE chunk of the oldest staged prefill (called between
@@ -1798,10 +1827,12 @@ class TPUEngine:
                 with dispatch("gather_window"):
                     window = dp.gather_window_pages(
                         self.state, ring, jnp.int32(done), self.cfg)
+            self._note_continuation(done, npad * P, bucket, len(chunk_toks))
             with dispatch("prefill_with_prefix"):
                 logits, kv = dp.prefill_with_prefix(
                     self.params, padded, k_pre, v_pre, jnp.int32(done),
-                    jnp.int32(len(chunk_toks)), self.cfg, *window, **carried)
+                    jnp.int32(len(chunk_toks)), self.cfg, *window, **carried,
+                    kernel=self._ragged_kernel)
         if self.cfg.ssm:
             req.pf_state = {name: kv.pop(name) for name in ("ssm", "conv")}
         self._take_expert_counts(kv)
@@ -2296,6 +2327,9 @@ class TPUEngine:
         # decode steps by the form the sampler took (they add up to
         # decode_steps): how often anything beyond an argmax is paid for
         out["sampler"] = dict(self.sampler_steps)
+        out["prefill"] = {"continuations_kernel": self.continuations_kernel,
+                          "continuations_xla": self.continuations_xla,
+                          "attended_pairs": self.attended_pairs}
         if self.prefill_chunk:
             out["prefill_chunk"] = self.prefill_chunk
             out["prefill_chunks_run"] = self.prefill_chunks_run

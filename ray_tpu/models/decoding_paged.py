@@ -545,8 +545,9 @@ def release_slot_paged(state, slot):
 # --------------------------------------------------- prefix-cache support
 # (reference capability: vLLM automatic prefix caching / hash-block reuse;
 # TPU design: cached blocks stay IN the page pool and are gathered into a
-# dense bucketed array for the continuation prefill — static shapes, no
-# custom kernels.)
+# dense bucketed array for the continuation prefill — static shapes; the
+# attention over it is one flash launch a layer where `continuation_blocks`
+# says so, since PR 47.)
 
 
 @jax.jit
@@ -567,10 +568,25 @@ def gather_prefix_pages(kp, vp, page_ids):
 _SCORES_AT_ONCE = 2 << 30
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
+def continuation_blocks(cfg: TransformerConfig, chunk: int, span: int, kernel: bool):
+    """The blocks of the flash launch that a chunk of `chunk` tokens attends
+    by over a gathered span of `span` (ops/flash_attention.py
+    `flash_prefix_attention`), or None where the continuation keeps the XLA
+    form: without `kernel` (the caller sees no unsharded TPU), with latent
+    attention, with a packed pool's heads of 64, or at shapes the launch does
+    not take (`prefix_blocks`: a tail's bucket under 1,024). From
+    what the program's shapes say, for the program and for the engine's count
+    alike."""
+    if not kernel or cfg.mla or cfg.kv_packed:
+        return None
+    return ops.prefix_blocks(chunk, span, cfg.head_dim)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "kernel"))
 def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
                         length, cfg: TransformerConfig,
-                        window_k=None, window_v=None, row_state=None):
+                        window_k=None, window_v=None, row_state=None,
+                        kernel: bool = False):
     """Continuation prefill: run ONLY the suffix tokens [1, Ts] (padded
     bucket; true count `length`) attending over a cached prefix KV
     [L, Tp, Hkv, Dh] (valid first `prefix_len` positions — cached K is
@@ -592,6 +608,9 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
     and the convolution's tail at the prefix's end (what the chunk before
     returned): the suffix runs on from them, and kv's `ssm` / `conv` are
     those at the suffix's last real position.
+    `kernel`: the attention is one flash launch a layer where
+    `continuation_blocks` says so; elsewhere, and without it, two einsums
+    around a float32 softmax over scores that are written out (`scored`).
     """
     dt = cfg.dtype
     B, Ts = tokens.shape
@@ -646,8 +665,27 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
         if cos is not None:  # this kind of layer has the rope
             q = ops.apply_rope(q, cos, sin, positions=pos_suffix)
             k = ops.apply_rope(k, cos, sin, positions=pos_suffix)
-        k_all = jnp.concatenate([pk[None].astype(dt), k], axis=1)
-        v_all = jnp.concatenate([pv[None].astype(dt), v], axis=1)
+        blocks = continuation_blocks(cfg, Ts, pk.shape[0], kernel and B == 1)
+        if blocks is not None:  # one flash launch: no score leaves VMEM
+            out = ops.flash_prefix_attention(
+                q[0], pk.astype(dt), pv.astype(dt), k[0], v[0], prefix_len,
+                scale=cfg.softmax_scale, window=cfg.window if window else None,
+                blocks=blocks)
+        else:
+            out = scored(q, jnp.concatenate([pk[None].astype(dt), k], axis=1),
+                         jnp.concatenate([pv[None].astype(dt), v], axis=1), mask)
+        out = out.reshape(B, Ts, cfg.n_heads, cfg.head_dim)
+        out = jnp.einsum("bthd,hde->bte", attn_gated(out, normed, layer_p["attn"], cfg),
+                         layer_p["attn"]["wo"].astype(dt))
+        if cfg.bias:
+            out = out + layer_p["attn"]["bo"].astype(dt)
+        h, counts = _close_block(h, out, layer_p, cfg)
+        return h, (k[0], v[0], *counts)
+
+    def scored(q, k_all, v_all, mask):
+        """The XLA form: float32 scores of the chunk's queries against the
+        span and the chunk, written out, all heads at once up to
+        `_SCORES_AT_ONCE`."""
         G = cfg.n_heads // cfg.kv_heads
         qh = q.reshape(B, Ts, cfg.kv_heads, G, cfg.head_dim)
 
@@ -662,19 +700,12 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
             return jnp.einsum("btkgs,bskd->btkgd", w, v_all.astype(dt))
 
         if 4 * B * cfg.n_heads * Ts * k_all.shape[1] <= _SCORES_AT_ONCE:
-            out = attend(qh, k_all, v_all)
-        else:  # one KV head's group of query heads at a time
-            out = jax.lax.map(
-                lambda one: attend(*(t[:, :, None] for t in one))[:, :, 0],
-                tuple(jnp.moveaxis(t, 2, 0) for t in (qh, k_all, v_all)))
-            out = jnp.moveaxis(out, 0, 2)
-        out = out.reshape(B, Ts, cfg.n_heads, cfg.head_dim)
-        out = jnp.einsum("bthd,hde->bte", attn_gated(out, normed, layer_p["attn"], cfg),
-                         layer_p["attn"]["wo"].astype(dt))
-        if cfg.bias:
-            out = out + layer_p["attn"]["bo"].astype(dt)
-        h, counts = _close_block(h, out, layer_p, cfg)
-        return h, (k[0], v[0], *counts)
+            return attend(qh, k_all, v_all)
+        # one KV head's group of query heads at a time
+        out = jax.lax.map(
+            lambda one: attend(*(t[:, :, None] for t in one))[:, :, 0],
+            tuple(jnp.moveaxis(t, 2, 0) for t in (qh, k_all, v_all)))
+        return jnp.moveaxis(out, 0, 2)
 
     x, kv = scan_layers(block, x, params, cfg, *per_layer,
                         close=lambda h, t: close_pass(h, None, t, params, cfg)[0],

@@ -1,6 +1,7 @@
 from ray_tpu.ops.activations import geglu, gelu, swiglu
 from ray_tpu.ops.attention import attention, repeat_kv
-from ray_tpu.ops.flash_attention import flash_attention, flash_attention_forward
+from ray_tpu.ops.flash_attention import (flash_attention, flash_attention_forward,
+                                         flash_prefix_attention, prefix_blocks)
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.losses import fused_head_cross_entropy, softmax_cross_entropy
 from ray_tpu.ops.moe import (RoutingInfo, held_slots, moe_apply, moe_sorted, onehot_dispatch,
@@ -22,6 +23,7 @@ __all__ = [
     "conv_tail",
     "flash_attention",
     "flash_attention_forward",
+    "flash_prefix_attention",
     "fused_head_cross_entropy",
     "geglu",
     "gelu",
@@ -32,6 +34,7 @@ __all__ = [
     "held_slots",
     "moe_sorted",
     "onehot_dispatch",
+    "prefix_blocks",
     "ragged_decode_attention",
     "ragged_decode_attention_reference",
     "repeat_kv",

@@ -39,6 +39,11 @@ and cost nothing: at Mosaic's default precision a float32 product is one
 bfloat16 pass of the MXU, which rounds `p` and `ds` just as the cast here
 does, so those kernels gave these results to the sixth digit in the same time.
 
+The serving path's sibling (PR 47, the end of this file): a chunk's
+continuation, `flash_prefix_attention`, is a forward of its own launch and
+name; the train path's three keep their programs. The same `_softmax_step`,
+the same precision.
+
 (The reference framework has no attention kernels at all — attention lives in
 vLLM/torch; this is the TPU-native compute path that replaces it.)
 """
@@ -138,6 +143,21 @@ def _when_live(i, j, *, causal, block_q, block_k, strip_of="q"):
     return run
 
 
+def _softmax_step(s, v, m_scr, l_scr, acc_scr, rows):
+    """The online softmax's update of `rows` of the scratch (a slice of its
+    rows, or the index of a [rows, ..] plane of it) by one block of keys: s
+    [rows, keys] their float32 scores, v [keys, D] the block's values as
+    stored."""
+    m_prev = m_scr[rows, :]                               # [rows, 128]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - _lanes(m_new, s.shape[1]))
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[rows, :] = l_scr[rows, :] * corr + p.sum(axis=-1, keepdims=True)
+    acc_scr[rows, :] = (acc_scr[rows, :] * _lanes(corr, acc_scr.shape[-1])
+                        + _dot(p.astype(v.dtype), v))
+    m_scr[rows, :] = m_new
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
                 scale: float, causal: bool, block_q: int, block_k: int):
     i = pl.program_id(2)
@@ -158,14 +178,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
             if masked:                                    # [rows, keys]
                 s = _causal_mask(s, i * block_q + rows.start,
                                  j * block_k + keys.start, q_axis=0)
-            m_prev = m_scr[rows, :]                       # [rows, 128]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - _lanes(m_new, s.shape[1]))
-            corr = jnp.exp(m_prev - m_new)
-            l_scr[rows, :] = l_scr[rows, :] * corr + p.sum(axis=-1, keepdims=True)
-            acc_scr[rows, :] = (acc_scr[rows, :] * _lanes(corr, acc_scr.shape[1])
-                                + _dot(p.astype(v.dtype), v))
-            m_scr[rows, :] = m_new
+            _softmax_step(s, v, m_scr, l_scr, acc_scr, rows)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -419,3 +432,219 @@ def _fa_bwd(causal, scale, block_q, block_k, interpret, res, g):
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
+
+
+# ---------------------------------------------------------------- a chunk's
+# continuation: the chunk's queries over a gathered span of earlier keys and
+# then over the chunk's own (models/decoding_paged.py prefill_with_prefix).
+# One launch a layer, the forward alone (nothing differentiates through it):
+# the online softmax of `_fwd_kernel` (`_softmax_step`), two operands of keys
+# swept one after the other, and which keys are live said ONCE, by
+# `_live_keys`, for the kernel's block test, its masks and its index maps.
+#
+# Layout: heads-major, as the train path's kernels take theirs. q [H, Ts, D]
+# and the chunk's K and V [Hkv, Ts, D] are the model's own products, whose
+# order of dimensions XLA is free to choose; the gathered span [Tspan, Hkv, D]
+# is re-laid once a layer to [Hkv, Tspan, D] (a token's KV heads lie packed
+# in one tile as the pool stores them: no DMA takes one head out of that).
+# K and V are never repeated: the G query heads of a KV head are one q block
+# [G, block_q, D], multiplied one after the other against the block of keys
+# while it is in VMEM (plane g of the scratch).
+
+# Blocks in order of preference, the first that divides: 512 queries (G * 512
+# rows of scratch a step) against 512 keys. Read on the chip (v5e, bfloat16,
+# d_head 128; PR 47, `_scratch/kbench.py`): 512 queries lead 256 by 4-14 % at
+# every shape of the cells (48 heads on 8 over a 16 k span, 9 k of it live:
+# 4.06 against 4.64 ms; a window layer 1.43 / 1.61). Blocks of 128 queries and
+# of 1,024 keys read 1.5 and 3.4 times slower in PR 46's scratch runs (VMEM)
+# and were not read again.
+_PREFIX_BLOCKS = (512, 256, 128)
+# The shortest bucket that takes the launch. Every program that holds it pays
+# for it at a replica's set-up, warm or cold: the kernel's body is traced and
+# lowered anew for each (0.2 s a program here on the CPU, 0.3 s on the chip's
+# host). With every tail's bucket in the launch `mixtral-8x7b.doc-saturated`'s
+# twelve continuation programs read a warm `setup_s` of 35.1-35.7 s against
+# the parent's 31.4-35.3 (PR 47; the bound is 10 %), for the one tail chunk a
+# prompt has, whose scores are small in proportion: buckets under this keep
+# the XLA form and the whole chunks take the launch.
+_PREFIX_MIN_CHUNK = 1024
+
+
+def prefix_blocks(chunk: int, span: int, head_dim: int):
+    """(block_q, span_block, chunk_block) of the continuation's launch for a
+    chunk of `chunk` queries over a gathered span of `span` keys, or None
+    where the launch does not tile the shapes or does not pay: heads that are
+    not whole 128-lane rows, a chunk under `_PREFIX_MIN_CHUNK` or no multiple
+    of the smallest block of queries, a span that no block of keys divides (a
+    span is pages, a power of two of them: a multiple of a block or, under the
+    smallest, one block)."""
+    def fit(n, under=None):
+        return next((b for b in _PREFIX_BLOCKS if n % b == 0), under)
+
+    blocks = (fit(chunk), fit(span, span if span < _PREFIX_BLOCKS[-1] else None),
+              fit(chunk))
+    if None in blocks or head_dim % 128 or chunk < _PREFIX_MIN_CHUNK:
+        return None
+    return blocks
+
+
+def _live_keys(q_first, q_last, *, prefix_len, span: int, window: int | None,
+               chunk: bool):
+    """[first, last] of the keys of one operand that are live for the queries
+    at chunk positions q_first .. q_last, in the sense that SOME of them sees
+    the key (one query: that query's keys; given a block's last and first
+    query in that order: the keys EVERY query of the block sees). None are
+    where first > last.
+
+    `chunk`: the chunk's own keys, key j at chunk position j, causal and
+    within `window` of the query. Else the gathered span's, `span` long, of
+    which a full layer (`window` None) holds the row's first `prefix_len`
+    positions and then padding, and a window layer the `span` positions that
+    end where the chunk starts (key j before it by span - j; before the row's
+    start, prefix_len back: dead), within `window` of the query."""
+    if chunk:
+        first = 0 if window is None else jnp.maximum(q_first - window + 1, 0)
+        return first, q_last
+    if window is None:
+        return 0, prefix_len - 1
+    return (jnp.maximum(jnp.maximum(span - prefix_len, span + q_first - window + 1), 0),
+            span - 1)
+
+
+def _prefix_kernel(plen_ref, q_ref, sk_ref, sv_ref, ck_ref, cv_ref, o_ref,
+                   m_scr, l_scr, acc_scr, *, scale: float, window: int | None,
+                   span: int, block_q: int, span_block: int, chunk_block: int):
+    i, j = pl.program_id(1), pl.program_id(2)
+    n_span = span // span_block
+    group, D = acc_scr.shape[0], acc_scr.shape[2]
+    q0 = i * block_q
+
+    def each_head(body):
+        """body(g) for the G query heads of this KV head, as a loop and not
+        unrolled: the kernel's body is lowered anew for every program that
+        holds the launch, which a replica's set-up pays."""
+        jax.lax.fori_loop(0, group, lambda g, _: body(g), None)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    def sweep(k_ref, v_ref, k0, block: int, chunk: bool):
+        """The operand's keys k0 .. k0 + block - 1 against the q block: not
+        at all where none of them is live for any of its queries, without a
+        mask where all are for all."""
+        live_keys = functools.partial(_live_keys, prefix_len=plen_ref[0], span=span,
+                                      window=window, chunk=chunk)
+        first, last = live_keys(q0, q0 + block_q - 1)
+        first_all, last_all = live_keys(q0 + block_q - 1, q0)
+        live = (k0 <= last) & (k0 + block - 1 >= first)
+        whole = (k0 >= first_all) & (k0 + block - 1 <= last_all)
+
+        def head(g, masked: bool):
+            s = _dot(q_ref[g], k_ref[...], _NT) * scale     # [block_q, block]
+            if masked:
+                q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                lo, hi = live_keys(q_pos, q_pos)
+                s = jnp.where((k_pos >= lo) & (k_pos <= hi), s, _NEG_INF)
+            _softmax_step(s, v_ref[...], m_scr, l_scr, acc_scr, g)
+
+        pl.when(live & jnp.logical_not(whole))(
+            lambda: each_head(functools.partial(head, masked=True)))
+        pl.when(whole)(lambda: each_head(functools.partial(head, masked=False)))
+
+    @pl.when(j < n_span)
+    def _span():
+        sweep(sk_ref, sv_ref, j * span_block, span_block, False)
+
+    @pl.when(j >= n_span)
+    def _chunk():
+        sweep(ck_ref, cv_ref, (j - n_span) * chunk_block, chunk_block, True)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finalize():
+        def head(g):
+            l = jnp.maximum(l_scr[g], 1e-30)
+            o_ref[g] = (acc_scr[g] / _lanes(l, D)).astype(o_ref.dtype)
+
+        each_head(head)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window", "blocks", "interpret"))
+def flash_prefix_attention(q, span_k, span_v, k, v, prefix_len, *, scale: float,
+                           window: int | None = None, blocks: tuple | None = None,
+                           interpret: bool = False):
+    """A chunk's queries q [Ts, H, D] (query i at position prefix_len + i)
+    over the gathered span `span_k`, `span_v` [Tspan, Hkv, D] and then the
+    chunk's own `k`, `v` [Ts, Hkv, D]; `prefix_len` (a traced scalar: one
+    program for every prefix of a span) and `window` say which keys are live
+    (`_live_keys`). Returns [Ts, H, D]. A block of keys with no live key for a
+    block of queries is neither multiplied nor brought (its index map stays on
+    a live block). `blocks`: (block_q, span_block, chunk_block),
+    `prefix_blocks`' where None."""
+    (Ts, H, D), (span, Hkv, _) = q.shape, span_k.shape
+    G = H // Hkv
+    blocks = blocks or prefix_blocks(Ts, span, D)
+    if blocks is None or Ts % blocks[0] or span % blocks[1] or Ts % blocks[2]:
+        raise ValueError(f"blocks {blocks} do not tile a chunk of {Ts} over a "
+                         f"span of {span} at heads of {D}")
+    block_q, span_block, chunk_block = blocks
+    n_span = span // span_block
+    live_keys = functools.partial(_live_keys, span=span, window=window)
+
+    def live_block(i, j, plen, block: int, chunk: bool):
+        """Block j of the operand, or the nearest that holds a live key for
+        q block i: a step that computes nothing brings nothing new."""
+        first, last = live_keys(i * block_q, (i + 1) * block_q - 1,
+                                prefix_len=plen[0], chunk=chunk)
+        n = (Ts if chunk else span) // block
+        return jnp.clip(j, jnp.minimum(first // block, n - 1),
+                        jnp.minimum(jnp.maximum(last, 0) // block, n - 1))
+
+    def q_map(h, i, j, plen):
+        return (h, i, 0)
+
+    def span_map(h, i, j, plen):
+        return (h, live_block(i, j, plen, span_block, False), 0)
+
+    def chunk_map(h, i, j, plen):
+        return (h, live_block(i, j - n_span, plen, chunk_block, True), 0)
+
+    kernel = functools.partial(
+        _prefix_kernel, scale=scale, window=window, span=span, block_q=block_q,
+        span_block=span_block, chunk_block=chunk_block)
+    rows = G * block_q
+    # q and o blocks and the key blocks, double-buffered, the accumulators,
+    # and the scores of a head's step a few times over
+    vmem = (4 * rows * D * q.dtype.itemsize
+            + 4 * (span_block + chunk_block) * D * k.dtype.itemsize
+            + rows * (256 + D) * 4 + 6 * block_q * max(span_block, chunk_block) * 4)
+
+    def heads_major(t):
+        return jnp.swapaxes(t, 0, 1)
+
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((H, Ts, D), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                         # prefix_len
+            grid=(Hkv, Ts // block_q, n_span + Ts // chunk_block),
+            in_specs=[pl.BlockSpec((G, block_q, D), q_map),
+                      pl.BlockSpec((None, span_block, D), span_map),
+                      pl.BlockSpec((None, span_block, D), span_map),
+                      pl.BlockSpec((None, chunk_block, D), chunk_map),
+                      pl.BlockSpec((None, chunk_block, D), chunk_map)],
+            out_specs=pl.BlockSpec((G, block_q, D), q_map),
+            scratch_shapes=[pltpu.VMEM((G, block_q, 128), jnp.float32),
+                            pltpu.VMEM((G, block_q, 128), jnp.float32),
+                            pltpu.VMEM((G, block_q, D), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=max(32 * 2**20, 2 * vmem)),
+        interpret=interpret,
+        name="flash_prefix_attention",
+    )(jnp.reshape(prefix_len, (1,)).astype(jnp.int32),
+      *(heads_major(t) for t in (q, span_k, span_v, k, v)))
+    return heads_major(out)

@@ -76,3 +76,43 @@ def ray_start_regular():
     ray_tpu.init(num_cpus=4, num_workers=2, max_workers=4)
     yield
     ray_tpu.shutdown()
+
+
+@pytest.fixture
+def flash_interpreted(monkeypatch):
+    """The continuation's flash launch at a test model's tiny shapes, in
+    interpret mode: blocks of 16 or 8 whatever the head size, for the calls of
+    `prefill_with_prefix` that pass `kernel=True`. Yields the `window` of
+    every launch traced."""
+    from ray_tpu import ops
+    from ray_tpu.models import decoding_paged as dp
+
+    real, windows = ops.flash_prefix_attention, []
+
+    def launch(*a, window, **kw):
+        windows.append(window)
+        return real(*a, window=window, interpret=True, **kw)
+
+    def blocks(chunk, span, head_dim):
+        fit = [next((b for b in (16, 8) if n % b == 0), None) for n in (chunk, span, chunk)]
+        return None if None in fit else tuple(fit)
+
+    monkeypatch.setattr(ops, "prefix_blocks", blocks)
+    monkeypatch.setattr(ops, "flash_prefix_attention", launch)
+    dp.prefill_with_prefix.clear_cache()   # traced anew: the launches are this test's
+    yield windows
+    dp.prefill_with_prefix.clear_cache()
+
+
+@pytest.fixture
+def continuations_as_on_chip(monkeypatch):
+    """An engine on the CPU chooses a continuation's form as one that sees an
+    unsharded TPU does (its decode step keeps the reference)."""
+    from ray_tpu.models import decoding_paged as dp
+
+    real = dp.continuation_blocks
+    monkeypatch.setattr(dp, "continuation_blocks",
+                        lambda cfg, chunk, span, kernel: real(cfg, chunk, span, True))
+    dp.prefill_with_prefix.clear_cache()
+    yield
+    dp.prefill_with_prefix.clear_cache()

@@ -41,22 +41,63 @@ def _naive_greedy(params, cfg, prompt, n):
     return toks[len(prompt):]
 
 
-def test_chunked_prefill_token_exact(tiny_model):
-    """Outputs of a chunk-streamed admission are EXACTLY the whole-prompt
-    prefill's outputs (greedy)."""
-    cfg, params = tiny_model
+def _chunked_exact(cfg, params):
+    """Prompts of 3-4 chunks each, ragged tails, against the whole-prompt
+    prefill's greedy outputs; returns the engine's stats."""
     eng = _engine(cfg, params)
     try:
         rng = np.random.default_rng(0)
-        for n in (33, 48, 61):  # 3-4 chunks each, ragged tails
+        for n in (33, 48, 61):
             prompt = [int(x) for x in rng.integers(1, 100, size=n)]
             got = eng.generate(prompt, SamplingParams(max_tokens=6,
                                                       temperature=0.0))
             assert got == _naive_greedy(params, cfg, prompt, 6), n
-        st = eng.stats()
-        assert st["prefill_chunks_run"] >= 9  # chunking actually engaged
+        return eng.stats()
     finally:
         eng.shutdown()
+
+
+def _attended_pairs(cfg, prompts, chunk):
+    """Query-key pairs of the chunks past a prompt's first, over the layers."""
+    return cfg.n_layers * sum(p + 1 for n in prompts for p in range(chunk, n))
+
+
+def test_chunked_prefill_token_exact(tiny_model):
+    """Outputs of a chunk-streamed admission are EXACTLY the whole-prompt
+    prefill's outputs (greedy)."""
+    cfg, params = tiny_model
+    st = _chunked_exact(cfg, params)
+    assert st["prefill_chunks_run"] >= 9  # chunking actually engaged
+    # an engine that sees no TPU keeps the XLA form
+    assert st["prefill"] == {"continuations_kernel": 0, "continuations_xla": 7,
+                             "attended_pairs": _attended_pairs(cfg, (33, 48, 61), 16)}
+
+
+def test_chunked_prefill_token_exact_in_the_flash_launch(
+        tiny_model, flash_interpreted, continuations_as_on_chip):
+    """The same through the continuation's flash launch (interpreted, blocks
+    of 16 or 8): every chunk past a prompt's first takes it and is counted."""
+    cfg, params = tiny_model
+    st = _chunked_exact(cfg, params)
+    assert flash_interpreted and set(flash_interpreted) == {None}
+    assert st["prefill"] == {"continuations_kernel": 7, "continuations_xla": 0,
+                             "attended_pairs": _attended_pairs(cfg, (33, 48, 61), 16)}
+
+
+def test_a_refused_shape_keeps_the_xla_form_and_is_counted(
+        tiny_model, continuations_as_on_chip, monkeypatch):
+    """Heads of 16 are no whole 128-lane row: on the chip too this model's
+    continuations keep the XLA form (`ops.prefix_blocks` refuses), no launch
+    is traced, and the engine counts them under `continuations_xla`."""
+    from ray_tpu import ops
+
+    def no_launch(*a, **kw):
+        raise AssertionError("a refused shape reached the flash launch")
+
+    monkeypatch.setattr(ops, "flash_prefix_attention", no_launch)
+    cfg, params = tiny_model
+    st = _chunked_exact(cfg, params)
+    assert (st["prefill"]["continuations_kernel"], st["prefill"]["continuations_xla"]) == (0, 7)
 
 
 def test_short_prompts_skip_chunking(tiny_model):

@@ -283,7 +283,7 @@ def test_a_short_row_holds_a_page_for_every_page_it_reaches(model):
         state = decoding.commit_tokens(state, jnp.full((3,), tokens[n + i + 1], jnp.int32))
 
 
-def _chunked(cfg, p, tokens, n, chunk, ring):
+def _chunked(cfg, p, tokens, n, chunk, ring, kernel=False):
     """The engine's staged prefill by hand: chunks of `chunk` (the tail
     padded to it), full layers' pages by the table, window layers' through
     the row's ring (a permutation: ring slot != page id). Returns (last
@@ -310,7 +310,8 @@ def _chunked(cfg, p, tokens, n, chunk, ring):
             pk, pv = dp.gather_prefix_pages(state["kp"], state["vp"], jnp.asarray(ids))
             wk, wv = dp.gather_window_pages(state, jnp.asarray(held), jnp.int32(done), cfg)
             logits, kv = dp.prefill_with_prefix(
-                p, jnp.asarray(padded), pk, pv, jnp.int32(done), jnp.int32(live), cfg, wk, wv)
+                p, jnp.asarray(padded), pk, pv, jnp.int32(done), jnp.int32(live), cfg, wk, wv,
+                kernel=kernel)
         pages = range(done // PAGE, (done + chunk) // PAGE)
         state = dp.write_kv_pages(state, kv, jnp.asarray(row[list(pages)]),
                                   jnp.asarray(held), jnp.int32(done))
@@ -318,7 +319,9 @@ def _chunked(cfg, p, tokens, n, chunk, ring):
 
 
 @pytest.mark.parametrize("chunks,chunk", [(2, 64), (3, 64), (5, 64), (5, 128)])
-def test_chunked_prefill_agrees_with_one_shot_prefill(model, chunks, chunk):
+@pytest.mark.parametrize("form", ["xla", "flash"])
+def test_chunked_prefill_agrees_with_one_shot_prefill(model, flash_interpreted, chunks, chunk,
+                                                      form):
     """2, 3 and 5 chunks with a padded tail chunk, the later ones past the
     window (a window layer attends over ring pages, a full layer over the
     whole prefix), then decode steps from the chunked state against the
@@ -330,7 +333,10 @@ def test_chunked_prefill_agrees_with_one_shot_prefill(model, chunks, chunk):
     padded = np.zeros((1, 640), np.int32)
     padded[0, :n] = tokens[:n]
     want_logits, _ = decoding.prefill(p, jnp.asarray(padded), jnp.int32(n), cfg)
-    logits, state, row, held = _chunked(cfg, p, tokens, n, chunk, ring)
+    logits, state, row, held = _chunked(cfg, p, tokens, n, chunk, ring, form == "flash")
+    # every continuation's attention went through the launch, on both kinds of layer
+    assert {w is not None for w in flash_interpreted} == ({True, False} if form == "flash"
+                                                          else set())
     assert _close(logits, want_logits) < TOL
     want, _ = reference.forward(p, jnp.asarray(tokens[:-1]), _sizes(cfg))
     assert _close(logits, want[n - 1]) < TOL

@@ -454,6 +454,12 @@ def test_kernel_names_reach_the_compiled_program(chip):
     # `jvp_flash_fwd_`, the backward pair as `transpose_jvp_flash_bwd_dq__`
     assert sorted(re.sub(r"^((jvp|transpose)_)*|_+$", "", c.split(".")[0])
                   for c in calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    # ... and exactly these three, by the operands and results that
+    # chipbench/layer_metrics/flash_roofline_pct.train.json finds them by
+    from chipbench import trace_reduce
+
+    assert sorted(trace_reduce.kernel_ops_from_hlo(text).values()) == [
+        "3in_2out", "6in_1out", "6in_2out"]
 
     for shape in [(16, 8, 4, 64, 64, 32), (16, 16, 1, 128, 64, 16)]:   # either launch
         assert _kernel_calls(_ragged_launch(chip, shape).compile().as_text()) == [
@@ -597,8 +603,83 @@ def test_trinity_chunk_and_check_prefill_fit_beside_the_weights(chip, monkeypatc
     assert "grouped_matmul" in _kernel_calls(chunk.as_text())
     pools = aot["full_pool_bytes"] + aot["window_pool_bytes"]
     assert chunk.memory_analysis().temp_size_in_bytes < 1.3e9     # 6 x 2048 x 34816 x 4 and change
+    # on the chip (`kernel`) every layer's attention is one flash launch, the
+    # three launches of the scans' bodies, and no temporary is a score tensor's
+    # size (a fifth of what the file's note, which a `benchmark` PR has to
+    # correct, says of the XLA form)
+    flash = dp.prefill_with_prefix.lower(
+        params, sds((1, 2048)), kv(1, 32768), kv(1, 32768), sds(()), sds(()), cfg,
+        kv(4, 4096), kv(4, 4096), kernel=True).compile()
+    assert _kernel_calls(flash.as_text()).count("flash_prefix_attention") == 3
+    assert "grouped_matmul" in _kernel_calls(flash.as_text())
+    assert flash.memory_analysis().temp_size_in_bytes < 400e6
+    assert aot["prefill_chunk_2048_prefix_32768_temp_bytes"] > 1.1e9
     assert total(chunk) + pools < 15.49e9
     check = decoding.prefill.lower(params, sds((1, 8192)), sds(()), cfg).compile()
     assert check.memory_analysis().temp_size_in_bytes < 3e9
     assert total(check) < 15.49e9
     assert math.isclose(total(check), aot["check_prefill_8192_bytes"], rel_tol=0.01)
+
+
+@pytest.mark.parametrize("cell,chunk,span,launches", [
+    ("mellum2-12b-a2.5b.mixed-saturated", 1024, 16384, 2),
+    ("mellum2-12b-a2.5b.mixed-saturated", 256, 4096, 0),     # a tail's bucket: the XLA form
+    ("mixtral-8x7b.doc-saturated", 1024, 8192, 1),
+    ("mixtral-8x7b.doc-saturated", 512, 2048, 0),
+    ("kimi-vl-a3b.longdoc-saturated", 1024, 16384, 0),       # latent: the XLA form
+])
+def test_chunk_programs_attend_in_the_flash_launch(chip, cell, chunk, span, launches):
+    """The chunking cells' continuation programs with `kernel` as the chip's
+    engine passes it: a launch in every scan's body (full and window layers)
+    of a whole chunk's program, and none where the shapes refuse it (a tail's
+    bucket under 1,024, a latent pool). `mellum2`'s chunk of 1,024 over 16,384 holds 330,524,672
+    bytes of temporaries where the XLA form holds 620,175,360 (a score tensor
+    of 32 x 1,024 x 17,408 float32 is 2.3 GB, which `_SCORES_AT_ONCE` splits)."""
+    from chipbench import harness, program
+    from ray_tpu.models import decoding_paged as dp
+
+    conf = harness.resolve_cell(cell)["config_file"]
+    cfg, eng = program.transformer_config(conf["program"]), conf["engine"]
+    params, state = _abstract_step_inputs(chip, cfg, 2, 2 * eng["page_size"], 8, eng["page_size"])
+
+    def sds(s, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(s, dt, sharding=chip)
+
+    def kv(layers, tokens):
+        return sds((layers, tokens, *state["kp"].shape[3:]), state["kp"].dtype)
+
+    n_window = cfg.n_layers - cfg.n_full_layers if cfg.window else 0
+    window = (kv(n_window, cfg.window),) * 2 if n_window else ()
+    full = kv(cfg.n_full_layers, span)
+    compiled = dp.prefill_with_prefix.lower(
+        params, sds((1, chunk)), full, None if cfg.mla else full, sds(()), sds(()), cfg,
+        *window, kernel=True).compile()
+    assert _kernel_calls(compiled.as_text()).count("flash_prefix_attention") == launches
+    if launches:
+        assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+
+
+def test_train_and_first_chunk_flash_programs_are_what_they_were():
+    """The continuation's launch is a sibling, not a variant: the train
+    step's three kernels (forward and backward at GPT-2 774M's heads) and the
+    forward a prompt's first chunk takes (trinity's 48 heads of 128 over
+    2,048) trace to the jaxprs they had before it came (PR 45's tree, read in
+    PR 47). A change to those kernels changes these digests on purpose and
+    says so here."""
+    import hashlib
+
+    from ray_tpu.ops.flash_attention import flash_attention_forward
+
+    def digest(fn, shape):
+        q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        text = str(jax.make_jaxpr(fn)(q, q, q))
+        return len(text), hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, None, None, None, False).astype(
+            jnp.float32).sum()
+
+    assert digest(jax.grad(loss, argnums=(0, 1, 2)), (4, 20, 1024, 64)) == (
+        59767, "df9f178b79978772")
+    assert digest(lambda q, k, v: flash_attention_forward(q, k, v, scale=0.1),
+                  (1, 48, 2048, 128)) == (21687, "46245d7ec9aa1222")

@@ -291,7 +291,7 @@ def test_prefill_then_paged_decode_agrees_with_the_full_forward(model, kernel_in
     assert int(state["length"][1]) == n + steps
 
 
-def _chunked(cfg, p, tokens, n, chunk, ring):
+def _chunked(cfg, p, tokens, n, chunk, ring, kernel=False):
     """The engine's staged prefill by hand (tests/test_mellum.py `_chunked`)."""
     state = dp.init_paged_state(cfg, 2, MAX_LEN, 48, PAGE, ring=ring)
     span = -(-n // chunk) * chunk
@@ -315,7 +315,8 @@ def _chunked(cfg, p, tokens, n, chunk, ring):
             pk, pv = dp.gather_prefix_pages(state["kp"], state["vp"], jnp.asarray(ids))
             wk, wv = dp.gather_window_pages(state, jnp.asarray(held), jnp.int32(done), cfg)
             logits, kv = dp.prefill_with_prefix(
-                p, jnp.asarray(padded), pk, pv, jnp.int32(done), jnp.int32(live), cfg, wk, wv)
+                p, jnp.asarray(padded), pk, pv, jnp.int32(done), jnp.int32(live), cfg, wk, wv,
+                kernel=kernel)
         counted += int(kv["expert_counts"][0])
         pages = range(done // PAGE, (done + chunk) // PAGE)
         state = dp.write_kv_pages(state, kv, jnp.asarray(row[list(pages)]),
@@ -325,7 +326,9 @@ def _chunked(cfg, p, tokens, n, chunk, ring):
 
 
 @pytest.mark.parametrize("chunks,chunk", [(2, 32), (3, 32), (5, 32), (5, 64)])
-def test_chunked_prefill_agrees_with_one_shot_prefill(model, chunks, chunk):
+@pytest.mark.parametrize("form", ["xla", "flash"])
+def test_chunked_prefill_agrees_with_one_shot_prefill(model, flash_interpreted, chunks, chunk,
+                                                      form):
     """2, 3 and 5 chunks with a padded tail chunk, the later ones past the
     window (a window layer attends over ring pages, a full layer over the
     whole prefix, without positions), then decode steps from the chunked
@@ -337,7 +340,10 @@ def test_chunked_prefill_agrees_with_one_shot_prefill(model, chunks, chunk):
     padded = np.zeros((1, 512), np.int32)
     padded[0, :n] = tokens[:n]
     want_logits, _ = decoding.prefill(p, jnp.asarray(padded), jnp.int32(n), cfg)
-    logits, state, row, held, counted = _chunked(cfg, p, tokens, n, chunk, ring)
+    logits, state, row, held, counted = _chunked(cfg, p, tokens, n, chunk, ring, form == "flash")
+    # every continuation's attention went through the launch, on both kinds of layer
+    assert {w is not None for w in flash_interpreted} == ({True, False} if form == "flash"
+                                                          else set())
     assert _close(logits, want_logits) < TOL and counted > 0
     want, _ = reference.forward(p, jnp.asarray(tokens[:-1]), _sizes(cfg))
     assert _close(logits, want[n - 1]) < TOL
