@@ -1524,11 +1524,15 @@ class GcsServer:
                     if excess > 0:
                         pinned = self._pinned_fn_keys_locked()
                         fresh = time.monotonic() - 300.0
+                        # a key without a stamp was not touched since this
+                        # GCS started: never fresh, also on a host whose
+                        # monotonic clock (time since boot) is under 300
+                        never = float("-inf")
                         for k in fn_keys:
                             if excess <= 0:
                                 break
                             if (k in pinned
-                                    or self._fn_access.get(k, 0.0) > fresh):
+                                    or self._fn_access.get(k, never) > fresh):
                                 continue
                             self.kv.pop(k, None)
                             self._fn_access.pop(k, None)

@@ -238,7 +238,7 @@ class RayConfig:
     # --- data plane fault tolerance -------------------------------------
     # Master switch for Data-plane fault handling (per-block retry, pool
     # actor replacement, lineage-backed barrier recovery). Off = legacy
-    # fail-fast behavior (the DATA_BENCH A/B baseline).
+    # fail-fast behavior (the baseline of an on/off comparison).
     data_fault_tolerance: bool = True
     # Max resubmissions per block after a SYSTEM error (actor death /
     # worker crash / lost object). Exhausting the budget raises

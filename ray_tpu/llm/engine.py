@@ -365,9 +365,7 @@ def _shard_params_tp(params, mesh):
         return P()  # replicate
 
     def place(path, x):
-        import jax as _jax
-
-        return _jax.device_put(x, NamedSharding(mesh, spec_for(path, x)))
+        return jax.device_put(x, NamedSharding(mesh, spec_for(path, x)))
 
     return jax.tree_util.tree_map_with_path(place, params)
 
@@ -742,25 +740,16 @@ class TPUEngine:
                     "removed; the engine serves from the paged KV cache "
                     "with the ragged decode step and does not speculate")
         cfg, params = llm_config.build_model()
+        # the constructor's defaults are the defaults here too
+        kw = {k: ek[k] for k in (
+            "max_slots", "max_len", "min_bucket", "seed", "page_size",
+            "num_pages", "max_prefills_per_step", "enable_prefix_cache",
+            "prefill_chunk", "mesh", "max_loras", "lora_rank") if k in ek}
         lora_cfg = getattr(llm_config, "lora_config", None)
-        return cls(cfg, params,
-                   max_slots=ek.get("max_slots", 8),
-                   max_len=ek.get("max_len", cfg.max_seq_len),
-                   min_bucket=ek.get("min_bucket", 32),
-                   seed=ek.get("seed", 0),
-                   page_size=ek.get("page_size", 64),
-                   num_pages=ek.get("num_pages"),
-                   max_prefills_per_step=ek.get("max_prefills_per_step", 2),
-                   enable_prefix_cache=ek.get("enable_prefix_cache", False),
-                   prefill_chunk=ek.get("prefill_chunk"),
-                   mesh=ek.get("mesh"),
-                   max_loras=ek.get(
-                       "max_loras",
-                       lora_cfg.max_num_adapters_per_replica
-                       if lora_cfg else 0),
-                   lora_rank=ek.get(
-                       "lora_rank",
-                       lora_cfg.lora_rank if lora_cfg else 8))
+        if lora_cfg:
+            kw.setdefault("max_loras", lora_cfg.max_num_adapters_per_replica)
+            kw.setdefault("lora_rank", lora_cfg.lora_rank)
+        return cls(cfg, params, **kw)
 
     def _check_alive(self):
         if self._error is not None:
@@ -774,8 +763,6 @@ class TPUEngine:
         layer-stacked host arrays {"A_q": [L, E, r], "B_q": [L, r, H, Dh],
         "A_v": [L, E, r], "B_v": [L, r, Hkv, Dh]} (missing targets stay
         zero). Scale defaults to alpha/r with alpha=r (i.e. 1.0)."""
-        import numpy as _np
-
         if self.lora_bank is None:
             raise ValueError("engine built without max_loras")
         with self._lora_lock:
@@ -796,17 +783,17 @@ class TPUEngine:
             for key in ("A_q", "B_q", "A_v", "B_v"):
                 if key in weights:
                     want = bank[key].shape[0:1] + bank[key].shape[2:]
-                    if _np.asarray(weights[key]).shape != want:
+                    if np.asarray(weights[key]).shape != want:
                         self._lora_free.append(idx)
                         raise ValueError(
                             f"lora {name!r} {key} shape "
-                            f"{_np.asarray(weights[key]).shape} != {want} "
+                            f"{np.asarray(weights[key]).shape} != {want} "
                             f"(rank {self.lora_rank}, layer-stacked)")
             try:
                 for key in ("A_q", "B_q", "A_v", "B_v"):
                     if key in weights:
                         bank[key] = bank[key].at[:, idx].set(
-                            jnp.asarray(_np.asarray(weights[key]),
+                            jnp.asarray(np.asarray(weights[key]),
                                         bank[key].dtype))
                 scale = 1.0 if alpha is None else float(alpha) / self.lora_rank
                 bank["scale"] = bank["scale"].at[idx].set(scale)
@@ -900,18 +887,15 @@ class TPUEngine:
         self._work.set()
         return req
 
-    def submit_prefilled(self, k=None, v=None, length: int = 0,
-                         first_token: int = 0,
-                         params: SamplingParams | None = None, *,
+    def submit_prefilled(self, *, length: int = 0, first_token: int = 0,
+                         params: SamplingParams | None = None,
                          k_pages: list | None = None,
                          v_pages: list | None = None,
                          kv_stream=None,
                          deadline_ts: float = 0.0) -> _Request:
         """Admit a sequence whose prefill ran elsewhere (PD disaggregation).
 
-        Three forms:
-        - whole-array: k/v are [L, T, Hkv, Dh] host arrays for the prompt
-          prefix (the legacy object-plane handoff);
+        Two forms:
         - page-granular: k_pages/v_pages are ordered lists of
           [L, page_size, Hkv, Dh] pages (the shm transfer plane's unit).
           Each page is adopted into the page pool directly — no
@@ -937,48 +921,28 @@ class TPUEngine:
                 "beside its pages (and packed KV rows, kv_packed, are not the "
                 "plane's [L, page, Hkv, Dh]), and none is carried over it")
         params = params or SamplingParams()
-        paged_form = k_pages is not None or v_pages is not None
         if kv_stream is not None:
-            if paged_form or k is not None or v is not None:
-                raise ValueError(
-                    "pass kv_stream alone, not with k/v or k_pages/v_pages")
-            P = int(kv_stream.page_size)
-            if P != self.page_size:
-                raise ValueError(
-                    f"streamed page size {P} != engine page_size "
-                    f"{self.page_size}: prefill and decode pools must agree")
-            bucket = int(kv_stream.n_pages) * P
-        elif paged_form:
-            if k is not None or v is not None:
-                raise ValueError(
-                    "pass either k/v arrays or k_pages/v_pages, not both")
+            if k_pages is not None or v_pages is not None:
+                raise ValueError("pass kv_stream alone, not with k_pages/v_pages")
+            P, n_pages = int(kv_stream.page_size), int(kv_stream.n_pages)
+        else:
             if not k_pages or not v_pages or len(k_pages) != len(v_pages):
                 raise ValueError(
-                    "k_pages and v_pages must be equal-length non-empty "
-                    "lists of [L, page_size, Hkv, Dh] pages")
-            P = k_pages[0].shape[1]
+                    "submit_prefilled needs kv_stream, or k_pages and v_pages: "
+                    "equal-length non-empty lists of [L, page_size, Hkv, Dh] "
+                    "pages")
+            P, n_pages = k_pages[0].shape[1], len(k_pages)
             if any(p.shape[1] != P for p in list(k_pages) + list(v_pages)):
                 raise ValueError("transferred pages have mixed page sizes")
-            if P != self.page_size:
-                raise ValueError(
-                    f"transferred page size {P} != engine page_size "
-                    f"{self.page_size}: prefill and decode pools must agree")
-            bucket = len(k_pages) * P
-        else:
-            if k is None or v is None:
-                raise ValueError(
-                    "submit_prefilled needs k/v arrays, k_pages/v_pages, "
-                    "or kv_stream")
-            bucket = k.shape[1]
+        if P != self.page_size:
+            raise ValueError(
+                f"transferred page size {P} != engine page_size "
+                f"{self.page_size}: prefill and decode pools must agree")
+        bucket = n_pages * P
         if bucket > self.max_len:
             raise ValueError(
                 f"transferred prefix bucket {bucket} exceeds engine "
                 f"max_len {self.max_len}")
-        if bucket % self.page_size:
-            raise ValueError(
-                f"transferred prefix bucket {bucket} is not a "
-                f"multiple of page_size {self.page_size}: configure the "
-                f"prefill server with min_bucket >= page_size")
         need = self._pages_needed(int(length), bucket, params.max_tokens)
         if need > self.num_pages - 1:
             raise ValueError(
@@ -998,12 +962,9 @@ class TPUEngine:
             # feed()/finish()/fail() wake the scheduler so a parked loop
             # adopts new pages immediately instead of on its poll tick
             kv_stream._wake = self._work.set
-        elif paged_form:
+        else:
             req.kv_pack = {"k_pages": list(k_pages), "v_pages": list(v_pages),
                            "length": int(length),
-                           "first_token": int(first_token)}
-        else:
-            req.kv_pack = {"k": k, "v": v, "length": int(length),
                            "first_token": int(first_token)}
         req.generated = 1  # the transferred first token counts
         self._waiting.put(req)
@@ -1047,18 +1008,11 @@ class TPUEngine:
                 req.finished = True
                 self._lora_release(req)
                 req.out_queue.put(marker)
-        for req in self._backlog:
-            self._lora_release(req)
-            req.out_queue.put(marker)
-        self._backlog.clear()
-        for req in self._prefilling:
-            self._lora_release(req)
-            req.out_queue.put(marker)
-        self._prefilling.clear()
-        for req in self._streaming:
-            self._lora_release(req)
-            req.out_queue.put(marker)
-        self._streaming.clear()
+        for reqs in (self._backlog, self._prefilling, self._streaming):
+            for req in reqs:
+                self._lora_release(req)
+                req.out_queue.put(marker)
+            reqs.clear()
         while True:
             try:
                 r = self._waiting.get_nowait()
@@ -1139,7 +1093,7 @@ class TPUEngine:
             n += self._reclaimable_pages()
         return n
 
-    def _alloc_pages(self, need: int) -> list | None:
+    def _grant_pages(self, need: int) -> list | None:
         """Take pages from the free list, evicting zero-ref cached blocks
         (LRU first) when the list runs short. None = infeasible now."""
         if need > self._available_pages():
@@ -1170,24 +1124,23 @@ class TPUEngine:
             n_pre += 1
         return n_pre
 
-    def _register_blocks(self, slot: int, tokens: list, hashes: list,
-                         n_pre: int, priv_pages: list) -> None:
+    def _register_blocks(self, req: _Request) -> None:
         """Make this request's freshly-computed full blocks available to
         future prompts: their pages move from private (freed on release)
         to shared (ref-counted, cached)."""
-        n = len(tokens)
-        shared = self._slot_shared.setdefault(slot, [])
-        still_private = list(priv_pages)
-        for i in range(n_pre, n // self.page_size):
-            if hashes[i] in self._prefix_cache:
+        shared, priv = self._slot_shared[req.slot], self._slot_pages[req.slot]
+        n_pre = len(shared)
+        still_private = list(priv)
+        for i in range(n_pre, len(req.tokens) // self.page_size):
+            if req.pf_hashes[i] in self._prefix_cache:
                 continue  # someone registered it first; keep ours private
-            page = priv_pages[i - n_pre]
-            self._prefix_cache[hashes[i]] = page
-            self._page_hash[page] = hashes[i]
+            page = priv[i - n_pre]
+            self._prefix_cache[req.pf_hashes[i]] = page
+            self._page_hash[page] = req.pf_hashes[i]
             self._page_refs[page] = self._page_refs.get(page, 0) + 1
             shared.append(page)
             still_private.remove(page)
-        self._slot_pages[slot] = still_private
+        self._slot_pages[req.slot] = still_private
 
     def _release_shared(self, slot: int) -> None:
         for p in self._slot_shared.pop(slot, ()):
@@ -1224,15 +1177,6 @@ class TPUEngine:
             first = decoding.sample(logits[None, :], sub,
                                     req.params.temperature, req.params.top_k)
             return first, first[0]
-
-    def _grant_pages(self, need: int) -> list | None:
-        """Grant `need` pool pages (evicting zero-ref cached blocks when
-        the prefix cache is on), or None when infeasible right now."""
-        if self.enable_prefix_cache:
-            return self._alloc_pages(need)
-        if need > len(self._free_pages):
-            return None
-        return [self._free_pages.pop() for _ in range(need)]
 
     def _grant_ring(self, slot: int, row_pages: int) -> None:
         """The row's pages of the window pool, once, for its life: a ring of
@@ -1282,14 +1226,12 @@ class TPUEngine:
                 (True, k_bucket,
                  "steps_top_k" if k_bucket else "steps_categorical"))
 
-    def _bind_slot(self, req: _Request, slot: int,
-                   length: int | None = None) -> None:
+    def _bind_slot(self, req: _Request, slot: int, length: int) -> None:
         """The slot-activation bookkeeping shared by every admission path:
         device sampling params, LoRA row, request registry. `length` is
         the row's device length at activation — mirrored host-side so the
         ragged decode step can bound its page sweep without a readback."""
-        if length is not None:
-            req.length0 = int(length)
+        req.length0 = int(length)
         req.dispatched = 1  # the prefill's token, or the one transferred
         self._count_live(req, +1)
         self._set_row_sampling(slot, req.params)
@@ -1334,103 +1276,69 @@ class TPUEngine:
             if self._phase_prefill is not None:
                 self._phase_prefill.observe(took)
 
-    def _insert(self, req: _Request, slot: int, kv, length: int, first_token):
-        """Grant the sequence's pages, write its prefilled kv into them and
-        activate the row. Returns False when the pool can't host the
-        sequence right now (caller backlogs)."""
-        bucket = kv["k"].shape[1]
-        need = self._pages_needed(length, bucket, req.params.max_tokens)
-        pages = self._grant_pages(need)
+    # ------------------------------------- prefilled elsewhere (PD planes)
+
+    def _grant_transferred(self, req: _Request, slot: int, n_pages: int) -> bool:
+        """Every page a sequence transferred in `n_pages` will EVER need,
+        granted up front like any admission. False when the pool can't host
+        the sequence right now (caller backlogs)."""
+        pages = self._grant_pages(self._pages_needed(
+            req.kv_pack["length"], n_pages * self.page_size, req.params.max_tokens))
         if pages is None:
             return False
         self._slot_pages[slot] = pages
-        self._grant_ring(slot, need)
-        ring = self._ring_row(slot)
-        with self._clock.dispatch("insert"):
-            self.state = dp.insert_sequence_paged(
-                self.state, slot, kv, jnp.int32(length),
-                jnp.asarray(first_token, jnp.int32),
-                jnp.asarray(self._granted_block_row(slot)), self.cfg, ring)
-        self._bind_slot(req, slot, length)
         return True
 
-    def _insert_transferred(self, req: _Request, slot: int) -> bool:
-        """PD admission: insert a kv_pack that arrived from a prefill
-        server. Page-granular packs adopt pages straight into the pool;
-        whole-array packs go through _insert. Returns False when the pool
-        can't host the sequence right now (caller backlogs)."""
+    def _activate_transferred(self, req: _Request, program: str) -> None:
+        """The row's pages hold its transferred prefix: take it live."""
         pack = req.kv_pack
-        if "k_pages" in pack:
-            return self._insert_pages(req, slot, pack)
-        dt = self.state["kp"].dtype
-        with self._clock.dispatch("h2d"):
-            kv = {"k": jnp.asarray(pack["k"], dt), "v": jnp.asarray(pack["v"], dt)}
-        if not self._insert(req, slot, kv, pack["length"], pack["first_token"]):
-            return False
-        self._note_prefill(0)
-        return True
+        with self._clock.dispatch(program):
+            self.state = dp.activate_slot(
+                self.state, req.slot, jnp.asarray(self._granted_block_row(req.slot)),
+                jnp.int32(pack["length"]), jnp.asarray(pack["first_token"], jnp.int32))
+        self._bind_slot(req, req.slot, pack["length"])
 
-    def _insert_pages(self, req: _Request, slot: int, pack: dict) -> bool:
-        """Adopt transferred KV pages directly into the paged pool: one
-        write_kv_pages scatter per page (a single [L, P, Hkv, Dh] compile
-        serves every transfer), then activate the row. The whole-bucket
-        [L, T, Hkv, Dh] array is never materialized on this path."""
-        k_pages, v_pages = pack["k_pages"], pack["v_pages"]
-        P = self.page_size
-        length = pack["length"]
-        need = self._pages_needed(length, len(k_pages) * P,
-                                  req.params.max_tokens)
-        pages = self._grant_pages(need)
-        if pages is None:
+    def _insert_pages(self, req: _Request, slot: int) -> bool:
+        """PD admission of pages that arrived whole from a prefill server:
+        adopt them directly into the paged pool, one write_kv_pages scatter
+        per page (a single [L, P, Hkv, Dh] compile serves every transfer),
+        then activate the row. The whole-bucket [L, T, Hkv, Dh] array is
+        never materialized on this path."""
+        pack = req.kv_pack
+        if not self._grant_transferred(req, slot, len(pack["k_pages"])):
             return False
-        self._slot_pages[slot] = pages
         dt = self.state["kp"].dtype
-        # prefix pages land in block-table order; the tail of `pages`
-        # (granted up front, like every admission) hosts the generation
+        # prefix pages land in block-table order; the tail of the grant
+        # hosts the generation
         dispatch = self._clock.dispatch
-        for pid, kp, vp in zip(pages, k_pages, v_pages):
+        for pid, kp, vp in zip(self._slot_pages[slot], pack["k_pages"], pack["v_pages"]):
             with dispatch("h2d"):
                 kv = {"k": jnp.asarray(np.asarray(kp), dt),
                       "v": jnp.asarray(np.asarray(vp), dt)}
                 ids = jnp.asarray(np.asarray([pid], np.int32))
             with dispatch("write_pages"):
                 self.state = dp.write_kv_pages(self.state, kv, ids)
-        with dispatch("activate"):
-            self.state = dp.activate_slot(
-                self.state, slot, jnp.asarray(self._granted_block_row(slot)),
-                jnp.int32(length), jnp.asarray(pack["first_token"], jnp.int32))
         self._note_prefill(0)
-        self._bind_slot(req, slot, length)
+        self._activate_transferred(req, "activate")
         return True
 
-    # ------------------------------------------------- streamed admission
-
     def _admit_stream(self, req: _Request, slot: int) -> bool:
-        """Streamed PD admission (tentpole: overlap transfer with decode):
-        grant the slot and every page the sequence will EVER need now;
-        pages are written into the pool as the transfer plane delivers
-        them (_drain_streams) and the row activates on the LAST page —
-        the decode loop keeps stepping other slots in between. Returns
-        False when the page pool can't host the sequence yet (caller
-        backlogs; arrived pages keep buffering host-side in the stream)."""
-        st = req.kv_stream
-        need = self._pages_needed(req.kv_pack["length"],
-                                  st.n_pages * self.page_size,
-                                  req.params.max_tokens)
-        pages = self._grant_pages(need)
-        if pages is None:
+        """Streamed PD admission (overlap transfer with decode): grant the
+        slot and every page now; pages are written into the pool as the
+        transfer plane delivers them (_drain_streams) and the row activates
+        on the LAST page, the decode loop stepping other slots in between.
+        While backlogged, arrived pages buffer host-side in the stream."""
+        if not self._grant_transferred(req, slot, req.kv_stream.n_pages):
             return False
-        self._slot_pages[slot] = pages
-        req.slot = slot
-        req.pf_done = 0
         self._scheduled(req)
         self._streaming.append(req)
         return True
 
     def _granted_block_row(self, slot: int) -> np.ndarray:
-        """Zero-padded block-table row over the slot's granted pages —
-        the activation layout shared by every page-granular admission."""
-        granted = self._slot_pages[slot]
+        """Zero-padded block-table row over the slot's pages, the cached
+        prefix's shared ones first — the activation layout of every
+        admission."""
+        granted = self._slot_shared.get(slot, []) + self._slot_pages[slot]
         row = np.zeros((self.max_pages_per_seq,), np.int32)
         row[:len(granted)] = granted
         return row
@@ -1521,14 +1429,7 @@ class TPUEngine:
                         req.pf_done += len(kps)
                 if req.pf_done >= st.n_pages:
                     self._streaming.remove(req)
-                    length = req.kv_pack["length"]
-                    with dispatch("adopt"):
-                        self.state = dp.activate_slot(
-                            self.state, req.slot,
-                            jnp.asarray(self._granted_block_row(req.slot)),
-                            jnp.int32(length),
-                            jnp.asarray(req.kv_pack["first_token"], jnp.int32))
-                    self._bind_slot(req, req.slot, length)
+                    self._activate_transferred(req, "adopt")
                     progressed = True
             except Exception as e:  # noqa: BLE001 — a malformed page must
                 # fail THIS request, not the scheduler (engine death would
@@ -1572,101 +1473,53 @@ class TPUEngine:
                 continue
             slot = self._free.pop()
             req.slot = slot
-            if req.kv_pack is not None:
-                if req.generated >= req.params.max_tokens:
-                    # budget already spent by the transferred first token
-                    self._free.append(slot)
-                    self._lora_release(req)
-                    req.out_queue.put(_SENTINEL)
-                    continue
-                if req.kv_stream is not None:
-                    # streamed PD admission: slot + pages granted now,
-                    # pages adopted as they arrive (_drain_streams). Pure
-                    # bookkeeping — no prefill compute — so it does NOT
-                    # count against the per-step prefill budget: a burst
-                    # of transfers grabs every free slot in one round
-                    if not self._admit_stream(req, slot):
-                        self._free.append(slot)
-                        self._backlog.append(req)
-                        return  # page pressure: stop admitting this round
-                    continue
-                # PD path: KV arrived from a prefill server (shm pages or
-                # legacy whole arrays)
-                if not self._insert_transferred(req, slot):
-                    self._free.append(slot)
-                    self._backlog.append(req)
-                    return  # page pressure: stop admitting this round
-                admitted += 1
+            if req.kv_pack is None:
+                granted = self._admit_prompt(req, slot)
+            elif req.generated >= req.params.max_tokens:
+                # budget already spent by the transferred first token
+                self._free.append(slot)
+                self._lora_release(req)
+                req.out_queue.put(_SENTINEL)
                 continue
-            if self.enable_prefix_cache or self.prefill_chunk:
-                if not self._admit_cached(req, slot):
-                    self._free.append(slot)
-                    self._backlog.append(req)
-                    return  # page pressure: stop admitting this round
-                admitted += 1
-                continue
-            n = len(req.tokens)
-            bucket = self._bucket(n)
-            # cheap feasibility check BEFORE paying for the prefill
-            if (self._pages_needed(n, bucket, req.params.max_tokens)
-                    > len(self._free_pages)):
+            elif req.kv_stream is not None:
+                granted = self._admit_stream(req, slot)
+            else:
+                granted = self._insert_pages(req, slot)
+            if not granted:
                 self._free.append(slot)
                 self._backlog.append(req)
-                return
-            t_sched = time.time()
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :n] = req.tokens
-            self._count_expert_tokens(bucket)
-            dispatch = self._clock.dispatch
-            with dispatch("h2d"):
-                padded = jnp.asarray(padded)
-            with dispatch("prefill"):
-                if self.lora_bank is not None:
-                    logits, kv = decoding.prefill(
-                        self.params, padded, jnp.int32(n), self.cfg,
-                        self.lora_bank, jnp.int32(req.lora_idx))
-                else:
-                    logits, kv = decoding.prefill(
-                        self.params, padded, jnp.int32(n), self.cfg)
-            self._take_expert_counts(kv)
-            self._note_prefill(bucket)
-            with dispatch("split"):
-                self.key, sub = jax.random.split(self.key)
-            first, token = self._sample_first(req, logits, sub)
-            if not self._insert(req, slot, kv, n, token):
-                self._free.append(slot)
-                self._backlog.append(req)
-                return
-            admitted += 1
-            # granted only now (a failed insert backlogs the request, and
-            # that wait is queue wait), as of before the prefill's dispatch
-            self._scheduled(req, t_sched)
-            self._first_unread(req, first, "admit_wait")
+                return  # page pressure: stop admitting this round
+            # a streamed admission is pure bookkeeping (slot + pages granted
+            # now, pages adopted as they arrive: _drain_streams), no prefill
+            # compute, so it does NOT count against the per-step prefill
+            # budget: a burst of transfers grabs every free slot in one round
+            admitted += req.kv_stream is None
 
-    def _admit_cached(self, req: _Request, slot: int):
-        """Paged admission with hash-block prefix reuse. False when the page
-        pool can't host the sequence right now (caller backlogs); else the
-        prompt is staged for chunked prefill, or prefilled with its first
-        token among the unread."""
+    def _admit_prompt(self, req: _Request, slot: int) -> bool:
+        """The one admission of a prompt prefilled here: match a cached
+        prefix (prefix cache on), grant every page the row will ever touch
+        BEFORE any device work, then stage the prompt for chunked prefill or
+        run its one span and take the row live. False when the pool can't
+        host the sequence now (caller backlogs: nothing was dispatched)."""
         tokens = req.tokens
         n = len(tokens)
         P = self.page_size
-        hashes = self._block_hashes(tokens)
-        n_pre = self._match_prefix(tokens, hashes)
-        # shrink the reused prefix if suffix-bucket roundup would overflow
-        # the static block table
-        while n_pre > 0 and (n_pre + self._bucket(n - n_pre * P) // P
-                             > self.max_pages_per_seq):
-            n_pre -= 1
+        n_pre = 0
+        if self.enable_prefix_cache:
+            if req.pf_hashes is None:  # not a retry out of the backlog
+                req.pf_hashes = self._block_hashes(tokens)
+            n_pre = self._match_prefix(tokens, req.pf_hashes)
+            # shrink the reused prefix if suffix-bucket roundup would
+            # overflow the static block table
+            while n_pre > 0 and (n_pre + self._bucket(n - n_pre * P) // P
+                                 > self.max_pages_per_seq):
+                n_pre -= 1
         pre_len = n_pre * P
         suffix = tokens[pre_len:]
-        suf_bucket = self._bucket(len(suffix))
-        last_pos = min(n + req.params.max_tokens, self.max_len - 1)
-        total_pages = max(n_pre + suf_bucket // P, last_pos // P + 1)
-        # pin the matched pages BEFORE allocating: _alloc_pages evicts
-        # zero-ref cached blocks, and the ones we just matched must not be
-        # among them
-        pre_pages = [self._prefix_cache[hashes[i]] for i in range(n_pre)]
+        # behind no prefix this is the prompt's own bucket: the count that
+        # submit() checked against the pool
+        total_pages = self._pages_needed(
+            n, pre_len + self._bucket(len(suffix)), req.params.max_tokens)
         chunk = self.prefill_chunk
         staged = chunk is not None and len(suffix) > chunk
         if staged:
@@ -1676,24 +1529,24 @@ class TPUEngine:
             # inflated count is committed ONLY if staging goes ahead — the
             # whole-prompt fallback must keep its own (table-fitting) need.
             rem = len(suffix) % chunk
-            tail_bucket = self._bucket(rem) if rem else 0
-            span = pre_len + (len(suffix) - rem) + tail_bucket
-            staged_pages = max(span // P, total_pages)
-            if staged_pages > self.max_pages_per_seq:
+            span = pre_len + (len(suffix) - rem) + (self._bucket(rem) if rem else 0)
+            if max(span // P, total_pages) > self.max_pages_per_seq:
                 staged = False  # bucket roundup overflow: whole-prompt path
             else:
-                total_pages = staged_pages
-        # pin matched blocks BEFORE allocating (eviction must not take them)
+                total_pages = max(span // P, total_pages)
+        # pin the matched pages BEFORE granting: the grant evicts zero-ref
+        # cached blocks, and the ones just matched must not be among them
+        pre_pages = [self._prefix_cache[h] for h in (req.pf_hashes or ())[:n_pre]]
         for p in pre_pages:
             self._page_refs[p] = self._page_refs.get(p, 0) + 1
-        priv = self._alloc_pages(total_pages - n_pre)
+        priv = self._grant_pages(total_pages - n_pre)
         if priv is None:
             for p in pre_pages:  # unpin; the request is backlogged
                 self._page_refs[p] = self._page_refs.get(p, 1) - 1
             return False
-        self._slot_pages[slot] = list(priv)
+        self._slot_pages[slot] = priv
         self._grant_ring(slot, total_pages)
-        self._slot_shared[slot] = list(pre_pages)
+        self._slot_shared[slot] = pre_pages
         self._scheduled(req)
         req.prefix_reused = pre_len
         if self.enable_prefix_cache:
@@ -1703,63 +1556,109 @@ class TPUEngine:
             else:
                 self.prefix_misses += 1
         if staged:
-            req.slot = slot
             req.pf_done = pre_len
             req.pf_pages = pre_pages + priv
-            req.pf_hashes = hashes
             self._staged_tokens += pre_len
             self._prefilling.append(req)
-            return True  # staged: no first token yet
-        padded = np.zeros((1, suf_bucket), np.int32)
-        padded[0, :len(suffix)] = suffix
-        self._count_expert_tokens(suf_bucket)
+            return True  # no first token yet
+        # a row with a ring has no cached prefix (the prefix cache is refused
+        # over window layers), so this span never gathers a window
+        logits, kv = self._run_prefill(req, suffix, pre_len, pre_pages)
+        self._go_live(req, logits, kv, "admit_wait")
+        return True
+
+    def _run_prefill(self, req: _Request, tokens: list, done: int, pages: list,
+                     ring=None, carried: dict | None = None) -> tuple:
+        """Run `tokens`, a span of the request's prompt, behind the `done`
+        tokens of it resident in the leading `pages` of its block table (a
+        cached prefix, the chunks before): (logits, kv) of the span, padded
+        to its bucket. `ring` is the row's window ring as the device takes
+        it, `carried` what the span before left (a recurrent state)."""
+        n = len(tokens)
+        P = self.page_size
+        bucket = self._bucket(n)
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, :n] = tokens
+        self._count_expert_tokens(bucket)
         dispatch = self._clock.dispatch
         with dispatch("h2d"):
             padded = jnp.asarray(padded)
-        if n_pre:
-            # pad the shared-page id list to a power of two so compile
+        if done == 0:
+            with dispatch("prefill"):
+                logits, kv = decoding.prefill(
+                    self.params, padded, jnp.int32(n), self.cfg,
+                    *(() if self.lora_bank is None
+                      else (self.lora_bank, jnp.int32(req.lora_idx))))
+        else:
+            # pad the resident pages' id list to a power of two so compile
             # count stays O(log(max_pages) × buckets); tail ids point at
             # scratch page 0, masked out by prefix_len
+            n_pre = done // P
             npad = 1
             while npad < n_pre:
                 npad *= 2
             padded_ids = np.zeros((npad,), np.int32)
-            padded_ids[:n_pre] = pre_pages
+            padded_ids[:n_pre] = pages[:n_pre]
             with dispatch("h2d"):
                 padded_ids = jnp.asarray(padded_ids)
             with dispatch("gather_prefix"):
                 k_pre, v_pre = dp.gather_prefix_pages(
                     self.state["kp"], self.state.get("vp"), padded_ids)
-            self.prefix_tokens_gathered += pre_len
-            self._note_continuation(pre_len, npad * P, suf_bucket, len(suffix))
+            self.prefix_tokens_gathered += done
+            window = ()
+            if ring is not None:
+                with dispatch("gather_window"):
+                    window = dp.gather_window_pages(
+                        self.state, ring, jnp.int32(done), self.cfg)
+            self._note_continuation(done, npad * P, bucket, n)
             with dispatch("prefill_with_prefix"):
                 logits, kv = dp.prefill_with_prefix(
-                    self.params, padded, k_pre, v_pre,
-                    jnp.int32(pre_len), jnp.int32(len(suffix)), self.cfg,
+                    self.params, padded, k_pre, v_pre, jnp.int32(done),
+                    jnp.int32(n), self.cfg, *window, **(carried or {}),
                     kernel=self._ragged_kernel)
-        else:
-            with dispatch("prefill"):
-                logits, kv = decoding.prefill(
-                    self.params, padded, jnp.int32(len(suffix)), self.cfg)
         self._take_expert_counts(kv)
-        self._note_prefill(suf_bucket)
+        self._note_prefill(bucket)
+        return logits, kv
+
+    def _go_live(self, req: _Request, logits, kv, wait: str) -> None:
+        """The one way a prompt prefilled here becomes a decode row: sample
+        its first token from the last span's `logits`, hand the device the
+        row, bind the slot, publish its blocks (prefix cache on), and leave
+        the token among the unread, its fetch timed as `wait`. Which program
+        takes the row live is decided here alone: a staged row's pages were
+        written chunk by chunk (`kv` None: `activate_slot`); an engine with
+        neither prefix cache nor chunks inserts the whole bucket; any other
+        inserts the span behind the block row's leading pages."""
+        slot, n, P = req.slot, len(req.tokens), self.page_size
+        dispatch = self._clock.dispatch
         with dispatch("split"):
             self.key, sub = jax.random.split(self.key)
         first, token = self._sample_first(req, logits, sub)
-        block_row = np.zeros((self.max_pages_per_seq,), np.int32)
-        block_row[:n_pre] = pre_pages
-        block_row[n_pre:n_pre + len(priv)] = priv
-        suf_pages = np.asarray(priv[:suf_bucket // P], np.int32)
+        block_row = self._granted_block_row(slot)
         ring = self._ring_row(slot)
-        with dispatch("insert"):
-            self.state = dp.insert_sequence_paged_prefix(
-                self.state, slot, kv, jnp.asarray(suf_pages),
-                jnp.asarray(block_row), jnp.int32(n), token, self.cfg, ring)
+        if kv is None:
+            with dispatch("activate"):
+                self.state = dp.activate_slot(
+                    self.state, slot, jnp.asarray(block_row), jnp.int32(n),
+                    token, ring, req.pf_state)
+            req.pf_state = None
+        elif not (self.enable_prefix_cache or self.prefill_chunk):
+            with dispatch("insert"):
+                self.state = dp.insert_sequence_paged(
+                    self.state, slot, kv, jnp.int32(n), token,
+                    jnp.asarray(block_row), self.cfg, ring)
+        else:
+            n_pre = len(self._slot_shared[slot])
+            span_pages = np.asarray(
+                self._slot_pages[slot][:self._bucket(n - n_pre * P) // P], np.int32)
+            with dispatch("insert"):
+                self.state = dp.insert_sequence_paged_prefix(
+                    self.state, slot, kv, jnp.asarray(span_pages),
+                    jnp.asarray(block_row), jnp.int32(n), token, self.cfg, ring)
         self._bind_slot(req, slot, n)
         if self.enable_prefix_cache:
-            self._register_blocks(slot, tokens, hashes, n_pre, priv)
-        self._first_unread(req, first, "admit_wait")
-        return True
+            self._register_blocks(req)
+        self._first_unread(req, first, wait)
 
     def _note_continuation(self, prefix_len: int, span: int, bucket: int,
                            length: int) -> None:
@@ -1786,87 +1685,38 @@ class TPUEngine:
         decode steps, so running requests keep emitting during a long
         admission — reference capability: vLLM chunked prefill)."""
         req = self._prefilling[0]
-        tokens = req.tokens
         P = self.page_size
         done = req.pf_done
-        chunk_toks = tokens[done:done + self.prefill_chunk]
-        is_last = done + len(chunk_toks) >= len(tokens)
-        bucket = self._bucket(len(chunk_toks))
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :len(chunk_toks)] = chunk_toks
-        self._count_expert_tokens(bucket)
-        chunk_pages = np.asarray(
-            req.pf_pages[done // P:(done + bucket) // P], np.int32)
+        chunk = req.tokens[done:done + self.prefill_chunk]
+        bucket = self._bucket(len(chunk))
         # the window layers' part goes through the row's ring, whose slots
-        # models/decoding_paged.py finds from the chunk's start
-        ring, window = self._ring_row(req.slot), ()
-        dispatch = self._clock.dispatch
-        with dispatch("h2d"):
-            padded = jnp.asarray(padded)
-            chunk_pages = jnp.asarray(chunk_pages)
-        # a recurrent state rides from chunk to chunk with the request and
-        # enters its slot when the row goes live
-        carried = {} if req.pf_state is None else {"row_state": req.pf_state}
-        if done == 0:
-            with dispatch("prefill"):
-                logits, kv = decoding.prefill(
-                    self.params, padded, jnp.int32(len(chunk_toks)), self.cfg)
-        else:
-            npad = 1
-            while npad < done // P:
-                npad *= 2
-            padded_ids = np.zeros((npad,), np.int32)
-            padded_ids[:done // P] = req.pf_pages[:done // P]
-            with dispatch("h2d"):
-                padded_ids = jnp.asarray(padded_ids)
-            with dispatch("gather_prefix"):
-                k_pre, v_pre = dp.gather_prefix_pages(
-                    self.state["kp"], self.state.get("vp"), padded_ids)
-            self.prefix_tokens_gathered += done
-            if ring is not None:
-                with dispatch("gather_window"):
-                    window = dp.gather_window_pages(
-                        self.state, ring, jnp.int32(done), self.cfg)
-            self._note_continuation(done, npad * P, bucket, len(chunk_toks))
-            with dispatch("prefill_with_prefix"):
-                logits, kv = dp.prefill_with_prefix(
-                    self.params, padded, k_pre, v_pre, jnp.int32(done),
-                    jnp.int32(len(chunk_toks)), self.cfg, *window, **carried,
-                    kernel=self._ragged_kernel)
+        # models/decoding_paged.py finds from the chunk's start; a recurrent
+        # state rides from chunk to chunk with the request and enters its
+        # slot when the row goes live
+        ring = self._ring_row(req.slot)
+        logits, kv = self._run_prefill(
+            req, chunk, done, req.pf_pages, ring,
+            None if req.pf_state is None else {"row_state": req.pf_state})
         if self.cfg.ssm:
             req.pf_state = {name: kv.pop(name) for name in ("ssm", "conv")}
-        self._take_expert_counts(kv)
+        dispatch = self._clock.dispatch
+        with dispatch("h2d"):
+            chunk_pages = jnp.asarray(np.asarray(
+                req.pf_pages[done // P:(done + bucket) // P], np.int32))
         with dispatch("write_pages"):
             self.state = dp.write_kv_pages(
                 self.state, kv, chunk_pages,
                 *(() if ring is None else (ring, jnp.int32(done))),
                 dense_layers=self.cfg.n_dense_layers)
-        self._note_prefill(bucket)
-        req.pf_done = done + len(chunk_toks)
+        req.pf_done = done + len(chunk)
         req.pf_chunks += 1
         self.prefill_chunks_run += 1
-        self._staged_tokens += len(chunk_toks)
-        if not is_last:
+        self._staged_tokens += len(chunk)
+        if req.pf_done < len(req.tokens):
             return
         self._prefilling.pop(0)
-        n = len(tokens)
-        self._staged_tokens -= n
-        with dispatch("split"):
-            self.key, sub = jax.random.split(self.key)
-        first, token = self._sample_first(req, logits, sub)
-        block_row = np.zeros((self.max_pages_per_seq,), np.int32)
-        block_row[:len(req.pf_pages)] = req.pf_pages
-        with dispatch("activate"):
-            self.state = dp.activate_slot(
-                self.state, req.slot, jnp.asarray(block_row), jnp.int32(n),
-                token, ring, req.pf_state)
-        req.pf_state = None
-        self._bind_slot(req, req.slot, n)
-        if self.enable_prefix_cache:
-            n_shared = len(self._slot_shared.get(req.slot, ()))
-            self._register_blocks(req.slot, tokens, req.pf_hashes, n_shared,
-                                  self._slot_pages[req.slot])
-        self._first_unread(req, first, "prefill_wait")
+        self._staged_tokens -= len(req.tokens)
+        self._go_live(req, logits, None, "prefill_wait")
 
     def _first_unread(self, req: _Request, first, wait: str) -> None:
         """A prefill's first token (`first` [1], on the device) joins the

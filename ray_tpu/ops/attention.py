@@ -32,8 +32,8 @@ def repeat_kv(k, *, n_rep: int):
 def _flash_ok(q) -> bool:
     if q.shape[1] % 256 != 0:  # seq must tile into flash blocks
         return False
-    # measured on v5e with the kernels as they were before PR 31
-    # (benchmarks/attn_bench.py, b8 h16 d128): the Pallas kernel won from seq
+    # measured on v5e with the kernels as they were before PR 31 (a sweep at
+    # b8 h16 d128 whose script went in PR 48): the Pallas kernel won from seq
     # 1024 up once fwd AND bwd were kernels — 2.4x at s2048 (12.96 vs 31.22 ms
     # fwd+bwd); PR 31's run 1.4 to 2.6 times faster than those and the
     # threshold was not measured again — and it is the only path that runs at

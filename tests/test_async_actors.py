@@ -7,6 +7,7 @@ task_execution/fiber.h; concurrency groups — concurrency_group_manager.h;
 
 from __future__ import annotations
 
+import os
 import time
 
 import pytest
@@ -62,15 +63,18 @@ def test_async_actor_state_is_shared(session):
     assert ray_tpu.get(c.total.remote(), timeout=30) == 10
 
 
-def test_concurrency_groups_isolate_pools(session):
+def test_concurrency_groups_isolate_pools(session, tmp_path):
+    """Events, no durations: both io tasks are running at once (each says so
+    and neither can end before the gate opens), the other groups answer
+    while the io pool is full, and the io tasks end when they are let."""
     @ray_tpu.remote(concurrency_groups={"io": 2, "compute": 1})
     class Grouped:
-        def __init__(self):
-            self.log = []
-
         @ray_tpu.method(concurrency_group="io")
-        def io_task(self, t):
-            time.sleep(t)
+        def io_task(self, started, gate):
+            open(started, "w").close()
+            deadline = time.monotonic() + 60.0
+            while not os.path.exists(gate) and time.monotonic() < deadline:
+                time.sleep(0.01)
             return "io"
 
         @ray_tpu.method(concurrency_group="compute")
@@ -81,14 +85,20 @@ def test_concurrency_groups_isolate_pools(session):
             return "default"
 
     g = Grouped.remote()
-    t0 = time.monotonic()
-    io_refs = [g.io_task.remote(1.0) for _ in range(2)]  # 2-wide io pool
-    # compute + default groups are NOT blocked behind the io sleeps
+    gate = str(tmp_path / "gate")
+    started = [str(tmp_path / f"started{i}") for i in range(2)]
+    io_refs = [g.io_task.remote(s, gate) for s in started]
+    deadline = time.monotonic() + 60.0
+    while not all(os.path.exists(s) for s in started):  # the io pool is 2 wide
+        assert time.monotonic() < deadline, "io group did not run 2-wide"
+        time.sleep(0.01)
+    # compute + default groups are NOT blocked behind the held io tasks
     assert ray_tpu.get(g.compute_task.remote(), timeout=30) == "compute"
     assert ray_tpu.get(g.default_task.remote(), timeout=30) == "default"
-    assert time.monotonic() - t0 < 0.9, "other groups blocked behind io"
+    ready, _ = ray_tpu.wait(io_refs, num_returns=2, timeout=0)
+    assert not ready, "an io task ended before the gate opened"
+    open(gate, "w").close()
     assert ray_tpu.get(io_refs, timeout=30) == ["io", "io"]
-    assert time.monotonic() - t0 < 1.9, "io group did not run 2-wide"
 
 
 def test_async_actor_error_propagates(session):

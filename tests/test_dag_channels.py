@@ -3,22 +3,23 @@
 The compiled plane provisions one exec loop per actor over mutable-shm
 channels; a step is one channel write + one read, no task submission
 (reference: python/ray/dag/compiled_dag_node.py do_exec_tasks +
-experimental channel tests). Covers: engagement + correctness, the ≥2×
-steady-state latency bound vs the `.remote()` chain (loose margin for CI
-noise; benchmarks/dag_bench.py measures the real ≥5×), fallback, error
+experimental channel tests). Covers: engagement + correctness, a step
+that submits no task where the `.remote()` chain submits one a stage (what
+the plane's lower step latency comes from; a latency is a chip-side or
+operator's measurement, not a test's), fallback, error
 propagation, oversized payloads, teardown with work in flight, actor death
 mid-loop, and the /dev/shm leak check.
 """
 
 import asyncio
-import glob
+import os
 import time
 
 import numpy as np
 import pytest
 
 import ray_tpu
-from ray_tpu._private.constants import SHM_CHANNEL_GLOB
+from ray_tpu.dag import channel_execution
 from ray_tpu.exceptions import RayChannelError, RayTaskError
 
 pytestmark = pytest.mark.dag
@@ -26,18 +27,29 @@ pytestmark = pytest.mark.dag
 N_STAGES = 4
 
 
-def _shm_chans():
-    return set(glob.glob(SHM_CHANNEL_GLOB))
+def _still_there(created: list) -> list:
+    return [path for path in created if os.path.exists(path)]
 
 
 @pytest.fixture
-def dag_cluster():
+def dag_cluster(monkeypatch):
+    """A session, and the channels its compiled DAGs created (the driver
+    creates every edge's): the leak check is of those, by name. A glob of
+    /dev/shm before and after would also count the channels that tests in
+    other processes hold open meanwhile."""
     ray_tpu.shutdown()
-    before = _shm_chans()
+    created, create = [], channel_execution.create_mutable_channel
+
+    def recording(buffer_bytes):
+        ch = create(buffer_bytes)
+        created.append(ch.path)
+        return ch
+
+    monkeypatch.setattr(channel_execution, "create_mutable_channel", recording)
     ray_tpu.init(num_cpus=32, num_workers=2, max_workers=8)
-    yield before
+    yield created
     ray_tpu.shutdown()
-    leaked = _shm_chans() - before
+    leaked = _still_there(created)
     assert not leaked, f"/dev/shm channel leak: {leaked}"
 
 
@@ -88,23 +100,23 @@ def test_channel_plane_engages_and_matches(dag_cluster):
     assert ray_tpu.get(actors[0].ncalls.remote(), timeout=30) == 26
 
 
-def test_channel_plane_beats_remote_chain(dag_cluster, monkeypatch, request):
-    """Tier-1 bound: steady-state compiled step ≥2× faster than the
-    equivalent .remote() chain (dag_bench.py tracks the ≥5× target).
-    MEDIAN per-step latency: the 1-2 core CI box has scheduling tails
-    that make means flaky. Instrumentation is pinned OFF so the already-
-    thin CI margin never couples to the observability defaults
-    (benchmarks/dag_bench.py owns the instrumented-overhead budget)."""
-    import statistics
+def test_channel_plane_beats_remote_chain(dag_cluster, monkeypatch):
+    """What the compiled plane saves, counted and not timed: a step of the
+    `.remote()` chain submits one actor task a stage through the driver's
+    worker (and the GCS behind it); a step of the compiled plane submits
+    none (one channel write, one read), and every stage still ran once a
+    step."""
+    from ray_tpu._private.api import _get_worker
 
-    from ray_tpu._private.ray_config import RayConfig
+    worker = _get_worker()
+    submitted = []
+    submit = worker.submit_actor_task
 
-    monkeypatch.setenv("RAY_TPU_DAG_METRICS", "0")
-    monkeypatch.setenv("RAY_TPU_DAG_SPAN_SAMPLE_EVERY", "0")
-    RayConfig.reset()
-    # drop the singleton again at teardown (runs before monkeypatch's env
-    # undo) so later tests re-read the restored env
-    request.addfinalizer(RayConfig.reset)
+    def counting(actor_id, method_name, *a, **kw):
+        submitted.append(method_name)
+        return submit(actor_id, method_name, *a, **kw)
+
+    monkeypatch.setattr(worker, "submit_actor_task", counting)
     actors = [Stage.remote(1) for _ in range(N_STAGES)]
 
     def chain_step(x):
@@ -113,30 +125,19 @@ def test_channel_plane_beats_remote_chain(dag_cluster, monkeypatch, request):
             ref = a.work.remote(ref)
         return ray_tpu.get(ref, timeout=60)
 
-    n = 60
-    for i in range(10):
-        chain_step(i)
-    remote_steps = []
+    n = 30
     for i in range(n):
-        t0 = time.perf_counter()
         assert chain_step(i) == i + N_STAGES
-        remote_steps.append(time.perf_counter() - t0)
+    assert submitted.count("work") == n * N_STAGES
 
     compiled = _pipeline(actors).experimental_compile()
     assert compiled.uses_channels, compiled.fallback_reason
-    for i in range(10):
-        compiled.execute(i).result(timeout=60)
-    chan_steps = []
+    del submitted[:]
     for i in range(n):
-        t0 = time.perf_counter()
         assert compiled.execute(i).result(timeout=60) == i + N_STAGES
-        chan_steps.append(time.perf_counter() - t0)
+    assert "work" not in submitted
     compiled.teardown()
-    remote_s = statistics.median(remote_steps)
-    chan_s = statistics.median(chan_steps)
-    assert chan_s * 2 <= remote_s, (
-        f"median channel step {chan_s*1e6:.0f}us vs remote chain "
-        f"{remote_s*1e6:.0f}us: <2x")
+    assert ray_tpu.get([a.ncalls.remote() for a in actors], timeout=30) == [2 * n] * N_STAGES
 
 
 def test_function_node_falls_back(dag_cluster):
@@ -249,7 +250,8 @@ def test_teardown_with_execution_in_flight(dag_cluster):
     for i in range(3):
         compiled.execute(i)  # never drained
     compiled.teardown()  # must join loops and unlink despite inflight work
-    assert not _shm_chans() - dag_cluster, "teardown leaked /dev/shm channels"
+    assert dag_cluster and not _still_there(dag_cluster), (
+        "teardown leaked /dev/shm channels")
     # idempotent + executes after teardown are refused
     compiled.teardown()
     with pytest.raises(Exception):
@@ -266,7 +268,7 @@ def test_actor_death_mid_loop(dag_cluster):
         for i in range(20):  # a step in the kill window may still complete
             compiled.execute(i).result(timeout=30)
     compiled.teardown()  # still clean: joins what it can, unlinks files
-    assert not _shm_chans() - dag_cluster, (
+    assert dag_cluster and not _still_there(dag_cluster), (
         "teardown after actor death leaked channels")
 
 

@@ -4,6 +4,8 @@ annotations on the JAX profiler's timeline, the compile log, and the
 benchmark reader that turns two `stats()` readings into per-layer metrics.
 All on the CPU at tiny widths: no number here is a device number."""
 
+import collections
+import functools
 import glob
 import json
 import os
@@ -975,3 +977,168 @@ def test_host_and_active_sums_partition_the_phases(tiny_model):
     assert loop["active_s"] == pytest.approx(
         sum(sec[p] for p in LOOP_PHASES if p != "parked"))
     assert 0 < loop["host_s"] < loop["active_s"] < loop["thread_s"]
+
+
+# ---------------------- one admission, one prefill runner, one go-live tail
+
+
+@functools.lru_cache(maxsize=None)
+def _form_model(kind: str) -> tuple:
+    """A tiny stand-in of a served family (built once a process)."""
+    from ray_tpu import models
+
+    sizes = dict(vocab_size=300, max_seq_len=1024, dtype=jnp.float32)
+    cfg = {
+        "dense": lambda: TransformerConfig(
+            d_model=32, n_layers=1, n_heads=2, n_kv_heads=2, d_ff=64, remat=False, **sizes),
+        "window": lambda: models.mellum_config("tiny", n_layers=4, **sizes),
+        "latent": lambda: models.kimi_vl_config("tiny", **sizes),
+        "looped": lambda: models.ouro_config("tiny", **sizes),
+        "state_space": lambda: models.granite_config("tiny", **sizes),
+        "held_experts_window": lambda: models.trinity_config(
+            "tiny", n_layers=6, experts_held=8, first_expert=16, **sizes),
+    }[kind]()
+    return cfg, transformer.init(jax.random.PRNGKey(3), cfg)
+
+
+# The shapes of engine the benchmark's seven serve configurations build (and
+# granite's with chunks, which its tests serve), each at a tiny stand-in of
+# the model: (model, engine options).
+ENGINE_FORMS = {
+    "plain": ("dense", {}),
+    "prefix_chunk": ("dense", dict(enable_prefix_cache=True, prefill_chunk=32)),
+    "window_chunk": ("window", dict(prefill_chunk=32)),
+    "latent_prefix_chunk": ("latent", dict(enable_prefix_cache=True, prefill_chunk=32)),
+    "looped_one_prefill_a_pass": ("looped", dict(max_prefills_per_step=1)),
+    "state_space": ("state_space", {}),
+    "state_space_chunk": ("state_space", dict(prefill_chunk=32)),
+    "held_experts_window_chunk": ("held_experts_window", dict(prefill_chunk=32)),
+}
+
+
+def _form_engine(form: str) -> TPUEngine:
+    model, opts = ENGINE_FORMS[form]
+    cfg, params = _form_model(model)
+    # 15 pages to grant: any request of the script alone, never two long ones
+    return TPUEngine(cfg, params, max_slots=2, max_len=256, min_bucket=16, page_size=16,
+                     num_pages=16, **opts)
+
+
+def _form_tokens(n: int, seed: int) -> list:
+    return np.random.default_rng(seed).integers(1, 300, n).tolist()
+
+
+GO_LIVE_PROGRAMS = ("insert_sequence_paged", "insert_sequence_paged_prefix", "activate_slot")
+STEP = " decode_step split sample commit"
+
+
+def _admission_tables(form: str, monkeypatch) -> tuple:
+    """The script of requests a form is given, one at a time on a quiet
+    engine, four tokens each: a prompt shorter than a chunk (`whole`); where
+    the engine has a chunk size one of three chunks (`staged`); where it also
+    has the prefix cache one that shares the first chunk with it and is staged
+    behind those two blocks (`staged_cached`), and the long one again
+    (`cached`: its last 11 tokens behind four cached blocks, unstaged).
+    Returns what each dispatched, in order (`STEP` for a decode step's four),
+    with the program that took the row live by name, and the tokens each was
+    answered with; then, with a long row holding all but one page, what a
+    request that has to wait for pages dispatched while it waited. The calls
+    that `stats()["loop"]["dispatch"]` counted are those."""
+    from ray_tpu.models import decoding_paged as dp
+
+    _, opts = ENGINE_FORMS[form]
+    long_ = _form_tokens(75, 2)
+    script = [("whole", _form_tokens(23, 1))]
+    if "prefill_chunk" in opts:
+        script.append(("staged", long_))
+        if "enable_prefix_cache" in opts:
+            script += [("staged_cached", long_[:32] + _form_tokens(43, 3)), ("cached", long_)]
+    went_live, order = [], []
+    for name in GO_LIVE_PROGRAMS:
+        def counted(*a, _real=getattr(dp, name), _name=name, **kw):
+            went_live.append(_name)
+            return _real(*a, **kw)
+        monkeypatch.setattr(dp, name, counted)
+    eng = _form_engine(form)
+    timed = eng._clock.dispatch
+    eng._clock.dispatch = lambda program: (order.append(program), timed(program))[1]
+
+    def table(before: dict, after: dict) -> str:
+        counted = _delta(before["loop"]["dispatch"], after["loop"]["dispatch"])
+        assert ({program: row["calls"] for program, row in counted.items() if row["calls"]}
+                == collections.Counter(order))
+        out = " ".join(order).replace(STEP, " STEP") + " by " + ",".join(went_live)
+        order.clear(), went_live.clear()
+        return out
+
+    tables, tokens = {}, {}
+    try:
+        for case, prompt in script:
+            before = _quiet_stats(eng)
+            tokens[case] = eng.generate(prompt, SamplingParams(max_tokens=4))
+            tables[case] = table(before, _quiet_stats(eng))
+        # 23 + 200 tokens reach into 14 of the 15 pages; the next needs two
+        holder = eng.submit(_form_tokens(23, 5), SamplingParams(max_tokens=200))
+        stream = iter(holder)
+        next(stream)
+        upto = len(order)
+        waiter = eng.submit(_form_tokens(20, 4), SamplingParams(max_tokens=4))
+        deadline = time.time() + 30.0
+        while not eng._backlog and time.time() < deadline:
+            time.sleep(0.001)
+        while_waiting = order[upto:]
+        assert not holder.finished and waiter.scheduled_ts == 0.0
+        eng.abort_request(holder.rid)
+        tokens["backlogged"] = list(waiter)
+        # the holder's decode steps went on beside it
+        tables["backlogged"] = " ".join(p for p in while_waiting if p not in STEP.split())
+        assert _quiet_stats(eng)["free_pages"] + len(eng._prefix_cache) == 15
+    finally:
+        eng.shutdown()
+    return tables, tokens
+
+
+# What the engine at PR 47 (9124fbf) dispatched for the script, program for
+# program (taken there with this file's `_admission_tables`); since PR 48 a
+# chunk's page ids travel in an `h2d` of their own, after its prefill and
+# before `write_pages`, and a staged row with a ring uploads the ring once
+# more before `activate`: every other row is the parent's.
+_LIVE = " bind STEP STEP STEP release by "
+_WHOLE = "h2d prefill split sample_first insert" + _LIVE
+_CHUNKS = ("h2d prefill h2d write_pages"
+           " h2d h2d gather_prefix prefill_with_prefix h2d write_pages"
+           " h2d h2d gather_prefix prefill_with_prefix h2d write_pages"
+           " split sample_first activate" + _LIVE + "activate_slot")
+_INSERTED = {"whole": _WHOLE + "insert_sequence_paged"}
+_CHUNKED = {"whole": _WHOLE + "insert_sequence_paged_prefix", "staged": _CHUNKS}
+_CHUNKED_CACHED = {
+    **_CHUNKED,
+    "staged_cached": _CHUNKS.removeprefix("h2d prefill h2d write_pages "),
+    "cached": ("h2d h2d gather_prefix prefill_with_prefix split sample_first insert"
+               + _LIVE + "insert_sequence_paged_prefix")}
+_CHUNKED_RING = {
+    "whole": ("h2d prefill split sample_first h2d insert" + _LIVE
+              + "insert_sequence_paged_prefix"),
+    "staged": ("h2d h2d prefill h2d write_pages"
+               " h2d h2d h2d gather_prefix gather_window prefill_with_prefix h2d write_pages"
+               " h2d h2d h2d gather_prefix gather_window prefill_with_prefix h2d write_pages"
+               " split sample_first h2d activate" + _LIVE + "activate_slot")}
+ADMISSIONS = {
+    "plain": _INSERTED, "prefix_chunk": _CHUNKED_CACHED, "window_chunk": _CHUNKED_RING,
+    "latent_prefix_chunk": _CHUNKED_CACHED, "looped_one_prefill_a_pass": _INSERTED,
+    "state_space": _INSERTED, "state_space_chunk": _CHUNKED,
+    "held_experts_window_chunk": _CHUNKED_RING}
+
+
+@pytest.mark.parametrize("form", sorted(ENGINE_FORMS))
+def test_every_form_of_engine_admits_a_prompt_by_the_same_programs(form, monkeypatch):
+    """A prompt goes live by one admission, one prefill runner and one tail,
+    whatever the engine: the programs each kind of admission dispatches, in
+    order, are the table's; a prompt answered out of the prefix cache gets the
+    tokens it got in chunks; a request that waits for pages has dispatched
+    nothing and been granted nothing, and is served when the pages come back."""
+    tables, tokens = _admission_tables(form, monkeypatch)
+    assert tables == {**ADMISSIONS[form], "backlogged": ""}
+    assert all(len(out) == 4 for out in tokens.values())
+    if "cached" in tokens:
+        assert tokens["cached"] == tokens["staged"]

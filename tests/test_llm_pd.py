@@ -250,9 +250,9 @@ def test_submit_prefilled_exact_fit_and_validation(tiny_model):
                 length=n, first_token=0,
                 params=SamplingParams(max_tokens=MAX_LEN - n + 1),
                 k_pages=k_pages, v_pages=v_pages)
-        with pytest.raises(ValueError, match="not both"):
-            dec.submit_prefilled(k_pages[0], v_pages[0], n, 0,
-                                 k_pages=k_pages, v_pages=v_pages)
+        # whole arrays are no form of input: nothing is taken by position
+        with pytest.raises(TypeError, match="positional"):
+            dec.submit_prefilled(k_pages[0], v_pages[0], n, 0)
         with pytest.raises(ValueError, match="equal-length"):
             dec.submit_prefilled(length=n, first_token=0,
                                  k_pages=k_pages, v_pages=[])
@@ -456,9 +456,15 @@ def test_submit_prefilled_kv_stream_validation(tiny_model):
         dec.shutdown()
 
 
+def _cut_into_pages(a, page_size=PAGE) -> list:
+    """A prefill's [L, T, Hkv, Dh] array as the transfer plane sends it."""
+    return [a[:, i:i + page_size] for i in range(0, a.shape[1], page_size)]
+
+
 def test_submit_prefilled_whole_arrays_land_in_pages(tiny_model):
-    """The decode engine accepts page-form packs and the legacy whole-array
-    form (one write of the bucket into granted pages) — both token-exact."""
+    """The decode engine takes pages, whoever cut them: the ones pulled off
+    the transfer plane, and a prefill's whole arrays cut here — both
+    token-exact."""
     cfg, params = tiny_model
     slot_ref = _paged_engine(cfg, params, max_slots=2)
     dec = _paged_engine(cfg, params, max_slots=2)
@@ -473,11 +479,11 @@ def test_submit_prefilled_whole_arrays_land_in_pages(tiny_model):
             length=ticket["length"], first_token=ticket["first_token"],
             params=sp, k_pages=k_pages, v_pages=v_pages)
         assert [ticket["first_token"]] + list(req) == want
-        # legacy whole-array form
         k = np.concatenate(k_pages, axis=1)
         v = np.concatenate(v_pages, axis=1)
-        req = dec.submit_prefilled(k, v, ticket["length"],
-                                   ticket["first_token"], sp)
+        req = dec.submit_prefilled(
+            length=ticket["length"], first_token=ticket["first_token"], params=sp,
+            k_pages=_cut_into_pages(k), v_pages=_cut_into_pages(v))
         assert [ticket["first_token"]] + list(req) == want
     finally:
         exporter.teardown()

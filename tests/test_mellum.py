@@ -666,7 +666,7 @@ def test_a_failed_stream_returns_both_kinds_of_page(model):
 @pytest.mark.parametrize("family", ["mixtral", "mellum"])
 def test_chunked_admission_without_the_prefix_cache_frees_its_pages(model, family):
     """ROADMAP D14's leak: with `prefill_chunk` set and the prefix cache off,
-    a prompt no longer than a chunk took `_admit_cached`'s unstaged branch,
+    a prompt no longer than a chunk took the admission's unstaged branch,
     which never recorded its pages, so release freed nothing and a pool of
     60 pages was gone after about 20 requests. 300 short requests, more
     than the pool could hold 10 times over, and every page is free after."""

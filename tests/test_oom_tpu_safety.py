@@ -11,6 +11,7 @@ specs or retained lineage.
 """
 
 import collections
+import time
 
 import pytest
 
@@ -194,8 +195,12 @@ def test_gcs_submit_clears_stale_publish_promise(gcs):
     assert gcs.objects[oid]["status"] == "pending"  # not errored
 
 
-def test_fn_eviction_pins_referenced_shas(gcs):
-    """fn: blobs referenced by pending specs / lineage survive eviction."""
+@pytest.mark.parametrize("since_boot", [10.0, 1e6])
+def test_fn_eviction_pins_referenced_shas(gcs, monkeypatch, since_boot):
+    """fn: blobs referenced by pending specs / lineage survive eviction,
+    and what nothing references or touched goes: also on a host that booted
+    seconds ago, where the monotonic clock is less than the freshness
+    window (keys that were never stamped must not look fresh there)."""
     conn = _FakeConn()
     # a pending task and a lineage entry each reference one sha
     gcs.pending_tasks.append({"kind": "task", "task_id": "tp",
@@ -207,8 +212,10 @@ def test_fn_eviction_pins_referenced_shas(gcs):
     for i in range(2048):
         gcs.kv[f"fn:bulk{i:05d}"] = b"x"
     # the overflowing put triggers eviction of (len - 2048) oldest keys
-    gcs._handle(conn, {"type": "kv_put", "rid": 1, "key": "fn:overflow",
-                       "value": b"o"}, None)
+    with monkeypatch.context() as clock:
+        clock.setattr(time, "monotonic", lambda: since_boot)
+        gcs._handle(conn, {"type": "kv_put", "rid": 1, "key": "fn:overflow",
+                           "value": b"o"}, None)
     assert "fn:sha-pending" in gcs.kv
     assert "fn:sha-lineage" in gcs.kv
     # eviction still happened — oldest unpinned keys went

@@ -69,16 +69,32 @@ def _wait_pool_restored(eng, timeout_s=10.0):
 
 
 def test_engine_abort_reclaims_mid_stream(tiny_model):
+    """The order, not a duration: once the row's first token is out the
+    scheduler's sweep waits for the abort to be queued, so the request cannot
+    end by count first, however late this thread runs."""
     cfg, params = tiny_model
     eng = _paged_engine(cfg, params)
+    queued, reqs, sweep = threading.Event(), [], eng._apply_aborts
+
+    def held_sweep():
+        if reqs and reqs[0].generated:
+            assert queued.wait(60.0)
+        sweep()
+
+    eng._apply_aborts = held_sweep
     try:
         req = eng.submit([1, 2, 3, 4], SamplingParams(max_tokens=48))
+        reqs.append(req)
         it = iter(req)
         next(it)  # at least one decode step has run: the slot is bound
         eng.abort_request(req.rid)
+        queued.set()
         with pytest.raises(RequestCancelledError):
             for _ in it:
                 pass
+        # the sweep after the first token applied it: the step in flight
+        # then was the row's last
+        assert req.generated <= 2 and req.dispatched <= 3
         st = _wait_pool_restored(eng)
         assert st["aborts"] == 1
         # the engine keeps serving after an abort

@@ -1,7 +1,7 @@
 """Sharded proxy plane units: the seqlock routing-table shm segment,
 SO_REUSEPORT / fd-passing port sharing, the HTTP body-size cap, the
-single-flight route refresh, batched phase telemetry, the zero-copy request
-envelope, and the section-preserving SERVE_BENCH merge writer.
+single-flight route refresh, batched phase telemetry, and the zero-copy
+request envelope.
 
 (integration: test_serve_chaos.py::test_proxy_shard_sigkill_under_traffic
 drives the whole plane — shard kill, controller replacement, shm leak
@@ -346,21 +346,3 @@ def test_build_request_escrows_large_body(tiny_cluster, monkeypatch):
     finally:
         monkeypatch.delenv("RAY_TPU_SERVE_ZERO_COPY_THRESHOLD_BYTES")
         RayConfig.reset()
-
-
-# ------------------------------------------------------- artifact merge write
-
-
-def test_merge_artifact_preserves_foreign_sections(tmp_path, monkeypatch):
-    from ray_tpu.scripts import _artifacts
-
-    monkeypatch.setattr(_artifacts, "repo_root", lambda: str(tmp_path))
-    _artifacts.merge_artifact("B.json", "results", [{"name": "a", "v": 1}])
-    _artifacts.merge_artifact("B.json", "sharded", {"num_proxies": 4})
-    # rewriting one section must not clobber the other
-    _artifacts.merge_artifact("B.json", "results", [{"name": "a", "v": 2}])
-    with open(tmp_path / "B.json") as f:
-        out = json.load(f)
-    assert out["results"] == [{"name": "a", "v": 2}]
-    assert out["sharded"] == {"num_proxies": 4}
-    assert "ts" in out

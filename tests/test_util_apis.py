@@ -5,6 +5,7 @@ data read_api from_huggingface — the small public utility APIs users
 reach for first when porting.)
 """
 
+import os
 import time
 
 import pytest
@@ -27,8 +28,11 @@ class Doubler:
     def double(self, v):
         return 2 * v
 
-    def slow_double(self, v):
-        time.sleep(0.1 if v == 0 else 0.0)
+    def held_double(self, v, gate):
+        """Value 0 ends when the gate file is there (or after a minute)."""
+        deadline = time.monotonic() + 60.0
+        while v == 0 and not os.path.exists(gate) and time.monotonic() < deadline:
+            time.sleep(0.01)
         return 2 * v
 
 
@@ -52,15 +56,20 @@ def test_actor_pool_map_ordered():
         _kill_all(actors)
 
 
-def test_actor_pool_map_unordered_completion_order():
+def test_actor_pool_map_unordered_completion_order(tmp_path):
     actors = [Doubler.remote(), Doubler.remote()]
     pool = ActorPool(actors)
+    gate = str(tmp_path / "gate")
     try:
-        out = list(pool.map_unordered(
-            lambda a, v: a.slow_double.remote(v), [0, 1, 2, 3]))
+        results = pool.map_unordered(
+            lambda a, v: a.held_double.remote(v, gate), [0, 1, 2, 3])
+        # value 0, submitted first, is held until the gate opens: the other
+        # actor's results overtake it, and it is yielded when it completes
+        out = [next(results)]
+        assert out != [0]
+        open(gate, "w").close()
+        out += list(results)
         assert sorted(out) == [0, 2, 4, 6]
-        # value 0 sleeps: something else should finish before it
-        assert out[-1] == 0 or out[0] != 0
     finally:
         _kill_all(actors)
 
