@@ -63,7 +63,9 @@ KERNELS = dict(flash=(2, 20, 1024, 64),
                    trinity_large_preview=dict(tokens=2048, top_k=4, experts=32, of=256,
                                               layers=4, d_model=3072, d_ff=3072)),
                # a row's recurrent state of granite-4.0-h-micro, 96 slots
-               state_update=dict(layers=3, slots=96, heads=64, head_dim=64, d_state=128))
+               state_update=dict(layers=3, slots=96, heads=64, head_dim=64, d_state=128),
+               # the gated delta rule's state of solar-open2-250b, 32 slots
+               kda_update=dict(layers=3, slots=32, heads=64, head_dim=128))
 
 
 class SmokeFailure(AssertionError):
@@ -236,7 +238,67 @@ def compare_state_update(shape: dict, interpret: bool = False) -> dict:
     return out
 
 
-def compare_kernels(flash, ragged, grouped=None, state_update=None,
+def compare_kda_update(shape: dict, interpret: bool = False) -> dict:
+    """`ops.kda_state_update`'s Pallas kernel against its `jax.numpy` form, as
+    `compare_state_update`: a state [layers, slots, heads, head_dim, head_dim]
+    of the gated delta rule, both updated where they lie, over steps whose live
+    rows are scattered and change, log decays down to -5 and beta up to 2. After
+    every step the WHOLE state: the stepped layer's live rows within float32
+    rounding, its other rows and every other layer bit for bit; o within
+    rounding on the live rows and 0 elsewhere."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu import ops
+
+    L, R, H, D = (shape[k] for k in ("layers", "slots", "heads", "head_dim"))
+    rng = np.random.default_rng(0)
+    f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    states = {"kernel": f32(L, R, H, D, D)}
+    states["reference"] = states["kernel"] + 0
+    step = {impl: jax.jit(functools.partial(ops.kda_state_update, impl=impl,
+                                            **({"interpret": interpret}
+                                               if impl == "kernel" else {})),
+                          donate_argnums=(0,))
+            for impl in states}
+    third = rng.permutation(R)[:R // 3]
+    again = np.concatenate([third[:len(third) // 2], rng.permutation(R)[:R // 4]])
+    lives = [third, np.unique(again), np.asarray([R - 1]), np.asarray([], int), np.arange(R)]
+    out, before = {"shape": dict(shape), "steps": []}, set()
+    for rows in lives:
+        live = np.zeros(R, bool)
+        live[rows] = True
+        taken = sorted(set(rows.tolist()) - before)
+        before = set(rows.tolist())
+        layer = jnp.int32(L // 2)
+        q, k, v = unit(f32(R, H, D)) * D ** -0.5, unit(f32(R, H, D)), f32(R, H, D)
+        g = -jnp.asarray(rng.uniform(0.0, 5.0, (R, H, D)), jnp.float32)
+        beta = jnp.asarray(rng.uniform(0.0, 2.0, (R, H)), jnp.float32)
+        fresh, os_ = f32(len(taken), H, D, D), {}
+        for impl in states:
+            s = states[impl].at[L // 2, jnp.asarray(taken, jnp.int32)].set(fresh)
+            states[impl], os_[impl] = step[impl](s, layer, q, k, v, g, beta,
+                                                 live=jnp.asarray(live))
+        got, want = (np.asarray(states[i]) for i in ("kernel", "reference"))
+        o_got, o_want = (np.asarray(os_[i]) for i in ("kernel", "reference"))
+        dead = np.ones((L, R), bool)
+        dead[L // 2, live] = False
+        out["steps"].append({
+            "live": int(live.sum()), "taken": len(taken),
+            "state_rel_err": float(np.abs(got - want).max() / np.abs(want).max()),
+            "o_rel_err": float(np.abs(o_got - o_want).max() / max(np.abs(o_want).max(), 1.0)),
+            "others_bit_equal": bool((got[dead] == want[dead]).all()),
+            "dead_o_zero": bool((o_got[~live] == 0).all())})
+    out["max_abs_err"] = max(max(s["state_rel_err"], s["o_rel_err"]) for s in out["steps"])
+    out["tol"] = D * 8 * float(np.finfo(np.float32).eps)   # sums of D products, twice
+    out["ok"] = bool(out["max_abs_err"] <= out["tol"]
+                     and all(s["others_bit_equal"] and s["dead_o_zero"] for s in out["steps"]))
+    return out
+
+
+def compare_kernels(flash, ragged, grouped=None, state_update=None, kda_update=None,
                     interpret: bool = False) -> dict:
     """The Pallas kernels against their pure-JAX references at the given
     shapes, bf16. `interpret` is for the CPU rehearsal only."""
@@ -295,6 +357,8 @@ def compare_kernels(flash, ragged, grouped=None, state_update=None,
     out.update(compare_grouped_matmul(grouped or {}, interpret))
     if state_update:
         out["state_update"] = compare_state_update(state_update, interpret)
+    if kda_update:
+        out["kda_update"] = compare_kda_update(kda_update, interpret)
     return out
 
 

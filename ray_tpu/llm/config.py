@@ -69,7 +69,7 @@ class PDConfig:
 class LLMConfig:
     model_loading_config: ModelLoadingConfig = field(default_factory=ModelLoadingConfig)
     # TransformerConfig kwargs for the built-in families (gpt2/llama/mixtral/
-    # kimi_vl/mellum/ouro/granite)
+    # kimi_vl/mellum/ouro/granite/trinity/solar_open2)
     model_family: str = "llama"
     model_kwargs: dict = field(default_factory=dict)
     engine_kwargs: dict = field(default_factory=dict)  # TPUEngine keywords:
@@ -114,7 +114,9 @@ class LLMConfig:
                    "kimi_vl": models.kimi_vl_config,
                    "mellum": models.mellum_config,
                    "ouro": models.ouro_config,
-                   "granite": models.granite_config}[self.model_family]
+                   "granite": models.granite_config,
+                   "trinity": models.trinity_config,
+                   "solar_open2": models.solar_open2_config}[self.model_family]
         cfg = factory(self.model_loading_config.model_id, **self.model_kwargs)
         src = self.model_loading_config.model_source
         if src:

@@ -531,6 +531,12 @@ class TPUEngine:
         self.continuations_kernel = 0
         self.continuations_xla = 0
         self.attended_pairs = 0
+        # recurrent layers: the positions their chunked scan ran over in the
+        # prefill programs dispatched (a span's bucket rounded up to whole
+        # chunks of the scan, a layer), and those of them past the span's
+        # real tokens; from what the host knows of a call, no device read
+        self.scan_positions = 0
+        self.scan_padded = 0
         # decode attention is one ragged-paged-attention launch over the
         # batch's live page tables (ops/ragged_paged_attention.py): the
         # Pallas kernel where the code can see a TPU and an unsharded pool,
@@ -1618,6 +1624,11 @@ class TPUEngine:
                     kernel=self._ragged_kernel)
         self._take_expert_counts(kv)
         self._note_prefill(bucket)
+        if self.cfg.ssm:
+            Q, layers = self.cfg.ssm.chunk, self.cfg.n_ssm_layers
+            ran = -(-bucket // Q) * Q
+            self.scan_positions += layers * ran
+            self.scan_padded += layers * (ran - n)
         return logits, kv
 
     def _go_live(self, req: _Request, logits, kv, wait: str) -> None:
@@ -2180,6 +2191,9 @@ class TPUEngine:
         out["prefill"] = {"continuations_kernel": self.continuations_kernel,
                           "continuations_xla": self.continuations_xla,
                           "attended_pairs": self.attended_pairs}
+        if self.cfg.ssm:
+            out["prefill"].update(scan_positions=self.scan_positions,
+                                  scan_padded=self.scan_padded)
         if self.prefill_chunk:
             out["prefill_chunk"] = self.prefill_chunk
             out["prefill_chunks_run"] = self.prefill_chunks_run

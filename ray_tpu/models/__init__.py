@@ -6,11 +6,13 @@ from ray_tpu.models.llama import llama_config
 from ray_tpu.models.mellum import mellum_config
 from ray_tpu.models.mixtral import mixtral_config
 from ray_tpu.models.ouro import ouro_config
+from ray_tpu.models.solar_open2 import solar_open2_config
 from ray_tpu.models.trinity import trinity_config
-from ray_tpu.models.transformer import MoEConfig, SSMConfig, TransformerConfig
+from ray_tpu.models.transformer import KDAConfig, MoEConfig, SSMConfig, TransformerConfig
 from ray_tpu.models.vit import ViTConfig, vit_config
 
 __all__ = [
+    "KDAConfig",
     "MoEConfig",
     "SSMConfig",
     "TransformerConfig",
@@ -22,6 +24,7 @@ __all__ = [
     "mellum_config",
     "mixtral_config",
     "ouro_config",
+    "solar_open2_config",
     "transformer",
     "trinity_config",
     "vit",

@@ -28,7 +28,8 @@ from ray_tpu import ops
 from ray_tpu.models.transformer import (TransformerConfig, _dense_mlp, _mla_expand,
                                         _mla_project, _moe_mlp, _norm, _residual,
                                         attn_gated, close_pass, embed_tokens, lm_logits,
-                                        mamba_mixer, qk_normed, rope_by_kind, scan_layers)
+                                        qk_normed, recurrent_mixer, rope_by_kind,
+                                        scan_layers)
 
 
 def _per_head_kv_only(cfg: TransformerConfig, what: str) -> None:
@@ -175,7 +176,8 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
     them a window layer keeps is the cache's business (decoding_paged.py).
     With state-space layers kv holds k, v [L_attn, T, Hkv, Dh] of the
     attention layers and, of the state-space layers, `ssm` [L_ssm, H, P, N]
-    (float32) and `conv` [L_ssm, d_conv - 1, conv_dim]: the recurrent state
+    (float32; the gated delta rule's [L_ssm, H, D, D]) and `conv` [L_ssm,
+    d_conv - 1, conv_dim]: the recurrent state
     and the convolution's tail AFTER position length - 1 (the bucket's
     padding does not advance them).
     With `lora_bank` + scalar `lora_idx`, applies that adapter's q/v
@@ -198,8 +200,10 @@ def prefill(params, tokens, length, cfg: TransformerConfig,
             lora_l = (aq, bq, av, bv)
         normed = _norm(h, layer_p["norm1"], cfg)
         if ssm:
-            out, state, tail = mamba_mixer(normed[0], layer_p["mixer"], cfg, length)
-            return _close_block(h, out[None], layer_p, cfg)[0], (state, tail)
+            mixer = recurrent_mixer(cfg)
+            out, state, tail = mixer(normed[0], layer_p["mixer"], cfg, length)
+            h, counts = _close_block(h, out[None], layer_p, cfg)
+            return h, (state, tail, *counts)
         if cfg.mla:
             out, rows = _mla_prefill_attn(normed, layer_p["attn"], cfg, cos, sin)
             h = h + out
@@ -238,13 +242,16 @@ def kv_tree(kv, cfg: TransformerConfig) -> dict:
     latent cache: {k}); with state-space layers also {ssm, conv}; for a model
     that `counts_experts` also {expert_counts: int32 [2]}, the layers'
     `ops.share_counts` summed (no page's: the writers of pages leave it out)."""
+    if cfg.ssm is not None:  # by kind: the attention layers', the recurrent layers'
+        (k, v, *counts), (state, tail, *more) = kv
+        out = {"k": k, "v": v, "ssm": state, "conv": tail}
+        if counts:
+            out["expert_counts"] = counts[0].sum(axis=0) + more[0].sum(axis=0)
+        return out
     if counts_experts(cfg):
         *kv, counts = kv
         return {**dict(zip("kv", kv)), "expert_counts": counts.sum(axis=0)}
-    if cfg.ssm is None:
-        return dict(zip("kv", kv))
-    (k, v), (state, tail) = kv
-    return {"k": k, "v": v, "ssm": state, "conv": tail}
+    return dict(zip("kv", kv))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))

@@ -59,7 +59,11 @@ last occupant left, the decode step updates every live row's state where it
 lies (an inactive row is stepped with dt = 0, which changes nothing), and a
 chunked prefill carries the state from chunk to chunk OUTSIDE the slots
 (`prefill_with_prefix(row_state=)`), so that it enters its slot only when
-the row goes live (`activate_slot(row_state=)`).
+the row goes live (`activate_slot(row_state=)`). Recurrent layers of the
+second kind (`KDAConfig`: the gated delta rule) keep the same two leaves
+under the same names, shaped by the config's `state_shape` and `conv_dim`:
+`ssm` [L_kda, max_slots, H, D, D] (float32: a matrix a head) and `conv`
+[L_kda, max_slots, d_conv - 1, 3 H D] (the tails of q, k and v).
 
 (reference capability: vLLM paged attention behind
 llm/_internal/serve/engines/vllm/vllm_engine.py:114; design here is
@@ -80,8 +84,9 @@ from ray_tpu.models.decoding import (_attn_qkv, _close_block, _mla_prefill_attn,
 from ray_tpu.models.transformer import (TransformerConfig, _mla_absorb_out, _mla_absorb_q,
                                         _mla_project, _norm, _residual, attn_gated,  # noqa: F401
                                         close_pass, embed_tokens, exit_distribution,
-                                        is_full_layer, kind_index, lm_logits, mamba_mixer,
-                                        mixer_out, mixer_project, mixer_split, rope_by_kind,
+                                        is_full_layer, kda_out, kda_project, kda_split,
+                                        kind_index, lm_logits, mixer_out, mixer_project,
+                                        mixer_split, recurrent_mixer, rope_by_kind,
                                         scan_layers)
 from ray_tpu import ops
 
@@ -131,8 +136,7 @@ def init_paged_state(cfg: TransformerConfig, max_slots: int, max_len: int,
     if cfg.ssm:  # a fixed state a slot beside the pages (module docstring)
         s = cfg.ssm
         pools.update(
-            ssm=jnp.zeros((cfg.n_ssm_layers, max_slots, s.n_heads, s.d_head, s.d_state),
-                          jnp.float32),
+            ssm=jnp.zeros((cfg.n_ssm_layers, max_slots, *s.state_shape), jnp.float32),
             conv=jnp.zeros((cfg.n_ssm_layers, max_slots, s.d_conv - 1, s.conv_dim),
                            cfg.dtype))
     return {
@@ -376,9 +380,10 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
 
     With state-space layers the scan also carries `ssm` and `conv` whole and
     a state-space layer updates its own plane of both where it lies
-    (`ops.ssm_state_update`: the Pallas kernel with `kernel`, `jax.numpy`
-    without); an inactive row keeps its state and its tail, and the kernel
-    does not touch it.
+    (`ops.ssm_state_update`, or `ops.kda_state_update` for the gated delta
+    rule's layers: the Pallas kernel with `kernel`, `jax.numpy` without); an
+    inactive row keeps its state and its tail, and the kernel does not touch
+    it.
 
     For a model whose expert layers hold a share of the experts
     (`decoding.counts_experts`) the returned state's `expert_counts` int32 [2]
@@ -445,7 +450,27 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
         """This step's K or V [B, 1, Hkv, Dh] as the pool stores a token's row."""
         return t[:, 0].reshape(B, *cfg.kv_row) if cfg.kv_packed else t[:, 0]
 
+    def kda_block(carry, layer_in):
+        h, gates, kp, vp, rec, conv = carry      # the recurrent state, whole
+        layer_p, i = layer_in                    # i: this layer's plane of it
+        p = layer_p["mixer"]
+        qkv, g, beta, gate = kda_project(_norm(h, layer_p["norm1"], cfg)[:, 0], p, cfg)
+        tail = jax.lax.dynamic_index_in_dim(conv, i, 0, keepdims=False)  # [B, K-1, C]
+        q, k, v = kda_split(jax.vmap(
+            lambda x, t: ops.causal_conv(x[None], t, p["conv_w"])[0])(
+                qkv.astype(jnp.float32), tail), cfg)
+        rec, o = ops.kda_state_update(
+            rec, i, q, k, v, g, beta, live=state["active"], schedule=schedule,
+            impl="kernel" if kernel else "reference")
+        moved = jnp.concatenate([tail[:, 1:], qkv[:, None].astype(tail.dtype)], axis=1)
+        conv = jax.lax.dynamic_update_index_in_dim(
+            conv, jnp.where(state["active"][:, None, None], moved, tail), i, 0)
+        h, counts = _close_block(h, kda_out(o, gate, p, cfg)[:, None], layer_p, cfg)
+        return (h, gates, kp, vp, rec, conv), (counts or None)
+
     def ssm_block(carry, layer_in):
+        if cfg.kda:
+            return kda_block(carry, layer_in)
         h, gates, kp, vp, rec, conv = carry      # the recurrent state, whole
         layer_p, i = layer_in                    # i: this layer's plane of it
         p = layer_p["mixer"]
@@ -529,7 +554,9 @@ def decode_step_paged_ragged(params, state, cfg: TransformerConfig,
     if gates is not None:
         state["exit_cdf"] = jnp.cumsum(exit_distribution(gates[..., 0]), axis=0).T
     if counts_experts(cfg):  # this step's, for the engine to take with its tokens
-        state["expert_counts"] = counts[0].sum(axis=0)
+        state["expert_counts"] = (
+            counts[0][0].sum(axis=0) + counts[1][0].sum(axis=0) if cfg.ssm  # by kind
+            else counts[0].sum(axis=0))
     state["length"] = jnp.where(state["active"], state["length"] + 1, state["length"])
     return state, logits.astype(jnp.float32)
 
@@ -641,9 +668,11 @@ def prefill_with_prefix(params, tokens, prefix_k, prefix_v, prefix_len,
     def block(h, layer_in, window=False, ssm=False):
         if ssm:
             layer_p, rec, tail = layer_in
-            out, rec, tail = mamba_mixer(_norm(h, layer_p["norm1"], cfg)[0],
-                                         layer_p["mixer"], cfg, length, rec, tail)
-            return _close_block(h, out[None], layer_p, cfg)[0], (rec, tail)
+            mixer = recurrent_mixer(cfg)
+            out, rec, tail = mixer(_norm(h, layer_p["norm1"], cfg)[0],
+                                   layer_p["mixer"], cfg, length, rec, tail)
+            h, counts = _close_block(h, out[None], layer_p, cfg)
+            return h, (rec, tail, *counts)
         if cfg.window:
             layer_p, i = layer_in
             pk, pv = (jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False)

@@ -22,7 +22,10 @@ limit on dt, the state's precision) the file of the benchmark's configuration
 says what was assumed (chipbench/configs/granite-4.0-h-micro.json `assumed`).
 
 Not built: the routed experts of the family's larger members
-(`num_local_experts` > 0), the prefix cache, preemption and resume of a row,
+(`num_local_experts` > 0: since PR 50 experts stand beside recurrent layers of
+the gated delta rule's kind, models/solar_open2.py, and `_check` still refuses
+them beside Mamba-2's, which no test has run), the prefix cache, preemption and
+resume of a row,
 a tensor-parallel mesh, LoRA and the PD transfer for a recurrent state
 (llm/engine.py refuses each at construction with its reason)."""
 

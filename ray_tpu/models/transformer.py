@@ -16,7 +16,10 @@ A stack may mix attention layers with state-space layers
 one attention layer at a fixed place in every period of layers. The two
 kinds are stacked apart (`params["layers"]`, `params["ssm_layers"]`: no layer
 carries weights of the other kind) and `scan_layers` scans whole periods,
-each kind static in its own trace of the block.
+each kind static in its own trace of the block. The recurrent layers may be
+of a second kind (`KDAConfig` in place of `SSMConfig`: a gated delta-rule
+linear attention, `kda_mixer`), and a stack of THAT kind may have routed
+experts in every layer and the attention layer's output gate.
 
 A stack may be looped (`TransformerConfig.n_passes`): the same stacked
 weights applied several times, the final norm closing every pass;
@@ -111,6 +114,47 @@ class SSMConfig:
         """Columns of the published in_proj: z | x B C | dt."""
         return self.d_inner + self.conv_dim + self.n_heads
 
+    @property
+    def state_shape(self) -> tuple:
+        """A row's recurrent state of one layer (float32)."""
+        return (self.n_heads, self.d_head, self.d_state)
+
+
+@dataclasses.dataclass(frozen=True)
+class KDAConfig:
+    """Gated delta-rule linear-attention layers (Kimi Delta Attention, arXiv
+    2510.26692) in the stack, in `TransformerConfig.ssm`'s place: every layer
+    but the one at `attn_at` of each `period` has `kda_mixer` in place of
+    attention. A head's state is a matrix [d_head (keys), d_head (values)],
+    decayed a KEY CHANNEL and corrected by the delta rule; q, k and v each go
+    through a causal depthwise convolution of width `d_conv` (no bias); the
+    decay's and the output gate's projections are low-rank pairs of inner
+    width `gate_rank`. A sibling of `SSMConfig`, not a mode of it: no field of
+    the one means anything in the other's mixer, and what the cache and the
+    layer scans ask of either is `period`, `attn_at`, `d_conv`, `chunk`,
+    `conv_dim` and `state_shape`."""
+    n_heads: int = 64
+    d_head: int = 128                      # of keys and of values
+    d_conv: int = 4
+    gate_rank: int = 128
+    chunk: int = 64                        # the prefill scan's chunk
+    period: int = 4
+    attn_at: int = 0                       # layer l attends iff l % period == attn_at
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.d_head
+
+    @property
+    def conv_dim(self) -> int:
+        """Columns the convolutions run over: q | k | v."""
+        return 3 * self.d_inner
+
+    @property
+    def state_shape(self) -> tuple:
+        """A row's recurrent state of one layer (float32)."""
+        return (self.n_heads, self.d_head, self.d_head)
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -170,8 +214,9 @@ class TransformerConfig:
     # sigmoid(w . x_t + b) on every pass's closed output: the probability of
     # leaving the loop after pass t (`exit_distribution`)
     exit_gate: bool = False
-    # state-space layers in place of attention on all but one layer a period
-    ssm: SSMConfig | None = None
+    # recurrent layers in place of attention on all but one layer a period:
+    # Mamba-2's (SSMConfig) or the gated delta rule's (KDAConfig)
+    ssm: SSMConfig | KDAConfig | None = None
     # scalar multipliers, each inert at its default: the embedding's output
     # times `embedding_multiplier`, every sublayer's output times
     # `residual_multiplier` before the residual add, the logits DIVIDED by
@@ -218,6 +263,11 @@ class TransformerConfig:
     @property
     def n_ssm_layers(self) -> int:
         return self.n_layers - self.n_attn_layers
+
+    @property
+    def kda(self) -> bool:
+        """Whether the recurrent layers are the gated delta rule's."""
+        return isinstance(self.ssm, KDAConfig)
 
     @property
     def n_planes(self) -> int:
@@ -419,11 +469,49 @@ def _mixer_params(cfg, key):
     }
 
 
+def _kda_params(cfg, key):
+    """A KDA mixer: the published q_proj, k_proj and v_proj as the three
+    column blocks of one matrix (`in_qkv`, q | k | v) and their three
+    depthwise convolutions as the column blocks of one (`conv_w`, no bias);
+    the decay's low-rank pair (`f_a`, `f_b`), its bias and a head's A =
+    -exp(A_log); beta's projection; the output gate's low-rank pair and bias
+    (`g_a`, `g_b`, `g_bias`); the gated norm's weight over a head's values;
+    out_proj. `dt_bias` and `A_log` stay float32 and are drawn so that a
+    step's decay exp(-exp(A_log) softplus(dt_bias)) spreads over about
+    0.9-0.999 at a zero input: softplus(dt_bias) log-uniform in [1e-3, 1e-1]
+    a channel (through the inverse softplus, as Mamba-2's dt) and exp(A_log)
+    uniform in [0.5, 1.5] a head."""
+    s, E = cfg.ssm, cfg.d_model
+    ks = jax.random.split(key, 10)
+    dt = jnp.exp(jax.random.uniform(ks[2], (s.d_inner,), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+
+    def normal(k, shape, std=0.02):
+        return jax.random.normal(k, shape, cfg.param_dtype) * std
+
+    return {
+        "in_qkv": normal(ks[0], (E, s.conv_dim)),
+        "conv_w": normal(ks[1], (s.d_conv, s.conv_dim), s.d_conv ** -0.5),
+        "f_a": normal(ks[5], (E, s.gate_rank)),
+        "f_b": normal(ks[6], (s.gate_rank, s.d_inner)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(jax.random.uniform(ks[3], (s.n_heads,), jnp.float32, 0.5, 1.5)),
+        "w_beta": normal(ks[7], (E, s.n_heads)),
+        "g_a": normal(ks[8], (E, s.gate_rank)),
+        "g_b": normal(ks[9], (s.gate_rank, s.d_inner)),
+        "g_bias": jnp.zeros((s.d_inner,), cfg.param_dtype),
+        "norm": jnp.ones((s.d_head,), cfg.param_dtype),
+        "out_proj": normal(ks[4], (s.d_inner, E), _out_std(cfg)),
+    }
+
+
 def _ssm_layer_params(cfg, key):
-    """One state-space layer: the mixer where an attention layer has `attn`."""
+    """One recurrent layer: the mixer where an attention layer has `attn`."""
     ks = jax.random.split(key, 6)
-    return {"norm1": _norm_params(cfg, ks[4]), "mixer": _mixer_params(cfg, ks[0]),
-            "norm2": _norm_params(cfg, ks[4]), "mlp": _dense_mlp_params(cfg, ks[5])}
+    mixer = _kda_params(cfg, ks[0]) if cfg.kda else _mixer_params(cfg, ks[0])
+    mlp = _moe_params(cfg, ks[5]) if cfg.moe else _dense_mlp_params(cfg, ks[5])
+    return {"norm1": _norm_params(cfg, ks[4]), "mixer": mixer,
+            "norm2": _norm_params(cfg, ks[4]), "mlp": mlp}
 
 
 def is_attn_layer(cfg: TransformerConfig, l: int) -> bool:
@@ -448,14 +536,23 @@ def _check(cfg: TransformerConfig) -> None:
     scaled = (cfg.residual_multiplier != 1.0 or cfg.logits_scaling != 1.0
               or cfg.attention_multiplier is not None)
     if (cfg.ssm or scaled) and (cfg.mla or cfg.window is not None or cfg.n_dense_layers
-                                or cfg.moe or cfg.n_passes > 1 or cfg.sandwich_norms
-                                or cfg.bias or cfg.attn_gate or cfg.qk_norm):
+                                or cfg.n_passes > 1 or cfg.sandwich_norms
+                                or cfg.bias or cfg.qk_norm):
         raise ValueError(
-            "state-space layers (ssm) and the scalar multipliers of the residual, "
-            "the logits and the softmax are built for a dense stack run once with "
+            "state-space layers (ssm: Mamba-2's, or the gated delta rule's) and the "
+            "scalar multipliers of the residual, "
+            "the logits and the softmax are built for a stack run once with "
             "per-head K and V and no biases: not with latent attention, window "
-            "layers, leading dense layers, experts, a looped stack, sandwich "
-            "norms, an attention gate or q/k norms")
+            "layers, leading dense layers, a looped stack, sandwich norms or q/k "
+            "norms")
+    if (cfg.ssm or scaled) and (cfg.moe or cfg.attn_gate) and (
+            scaled or not cfg.kda or (cfg.moe and not cfg.moe.dropless)):
+        raise ValueError(
+            "experts in every layer and an attention gate beside state-space layers "
+            "are built for the gated delta rule's layers (KDAConfig) with dropless "
+            "experts and no scalar multipliers: Mamba-2 layers and the multipliers "
+            "of the residual, the logits and the softmax go with a dense stack "
+            "without the gate, which is the one stack that has them")
     if cfg.kv_packed and (cfg.mla or cfg.window is not None or 128 % cfg.head_dim
                           or cfg.kv_heads * cfg.head_dim % 128):
         raise ValueError(
@@ -470,6 +567,9 @@ def _check(cfg: TransformerConfig) -> None:
                 f"state-space layers in periods of {s.period} (attention at "
                 f"{s.attn_at}) need whole periods in n_layers {cfg.n_layers}, "
                 "RMS norms and SwiGLU")
+        if cfg.kda and s.chunk % min(ops.ssm.KDA_SUB, s.chunk):
+            raise ValueError(
+                f"KDAConfig.chunk {s.chunk}: a multiple of {ops.ssm.KDA_SUB}, or under it")
     if cfg.mla and (cfg.bias or cfg.pos != "rope" or cfg.n_kv_heads is not None):
         raise ValueError("latent attention (kv_lora_rank) is built with rope, "
                          "without biases and without grouped KV heads")
@@ -668,13 +768,22 @@ def _scan_hybrid(block, carry, params, cfg: TransformerConfig, per_layer, ssm_pe
     static. Layers are taken out of the stack of their kind where they lie,
     by the scans' own counters (`_scan_periods` says why)."""
     period, at = cfg.ssm.period, cfg.ssm.attn_at
+    # the routed experts of a dropless stack stay whole, a kind, and the
+    # block is told its layer (`_scan_stack`)
+    stacks, whole = {}, {}
+    for ssm, name in ((False, "layers"), (True, "ssm_layers")):
+        stacks[ssm], mlp = params[name], params[name]["mlp"]
+        if "router" in mlp and cfg.moe.dropless:
+            whole[ssm] = {k: mlp[k] for k in ("gate", "up", "down")}
+            stacks[ssm] = {**params[name],
+                           "mlp": {k: v for k, v in mlp.items() if k not in whole[ssm]}}
 
     def one(c, i, ssm):
-        stack, extra = ((params["ssm_layers"], ssm_per_layer) if ssm
-                        else (params["layers"], per_layer))
         layer_p, *more = jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-            (stack, *extra))
+            (stacks[ssm], *(ssm_per_layer if ssm else per_layer)))
+        if ssm in whole:
+            layer_p = {**layer_p, "mlp": {**layer_p["mlp"], **whole[ssm], "layer": i}}
         return block(c, (layer_p, *more) if more else layer_p, ssm=ssm)
 
     def run(c, first, n):
@@ -836,9 +945,16 @@ def logical_axes(cfg: TransformerConfig):
                  "in_dt": ("embed", None), "conv_w": (None, None), "conv_b": (None,),
                  "dt_bias": (None,), "A_log": (None,), "D": (None,),
                  "norm": ("mlp",), "out_proj": ("mlp", "embed")}
+        if cfg.kda:  # the heads' width splits like an MLP's
+            mixer = {"in_qkv": ("embed", "mlp"), "conv_w": (None, "mlp"),
+                     "f_a": ("embed", None), "f_b": (None, "mlp"), "dt_bias": ("mlp",),
+                     "A_log": (None,), "w_beta": ("embed", None), "g_a": ("embed", None),
+                     "g_b": (None, "mlp"), "g_bias": ("mlp",), "norm": (None,),
+                     "out_proj": ("mlp", "embed")}
         out["ssm_layers"] = jax.tree.map(
             lambda t: ("layers",) + t,
-            {"norm1": norm, "mixer": mixer, "norm2": norm, "mlp": swiglu},
+            {"norm1": norm, "mixer": mixer, "norm2": norm,
+             "mlp": mlp if cfg.moe else swiglu},
             is_leaf=lambda x: isinstance(x, tuple))
     if cfg.pos == "learned":
         out["pos_embed"] = (None, "embed")
@@ -933,6 +1049,71 @@ def mamba_mixer(x, p, cfg, length=None, state=None, tail=None):
                                   chunk=s.chunk, state=state, dtype=cfg.dtype)
     return (mixer_out(y, xs, z, p, cfg), state,
             ops.conv_tail(xBC, tail, T if length is None else length))
+
+
+def kda_project(x, p, cfg):
+    """Normed x [T, E] -> (qkv [T, 3 H D] before the convolutions, g [T, H, D]
+    float32 < 0: a key channel's log decay, -exp(A_log) softplus(x W_fa W_fb +
+    dt_bias); beta [T, H] float32 = 2 sigmoid(x W_b), in (0, 2): negative
+    eigenvalues allowed; the output gate before its sigmoid [T, H, D]
+    float32)."""
+    s, dt, f32 = cfg.ssm, cfg.dtype, jnp.float32
+    heads = (*x.shape[:-1], s.n_heads, s.d_head)
+    low = ((x @ p["f_a"].astype(dt)) @ p["f_b"].astype(dt)).astype(f32)
+    g = -jnp.exp(p["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+        low + p["dt_bias"].astype(f32)).reshape(heads)
+    beta = 2.0 * jax.nn.sigmoid((x @ p["w_beta"].astype(dt)).astype(f32))
+    gate = (((x @ p["g_a"].astype(dt)) @ p["g_b"].astype(dt)).astype(f32)
+            + p["g_bias"].astype(f32)).reshape(heads)
+    return x @ p["in_qkv"].astype(dt), g, beta, gate
+
+
+def _l2_normed(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def kda_split(qkv, cfg):
+    """Convolved qkv [T, 3 H D] after its SiLU -> (q, k, v [T, H, D] float32):
+    q and k of unit length a head (eps 1e-6 under the root), q times D ** -0.5."""
+    s = cfg.ssm
+    qkv = jax.nn.silu(qkv).astype(jnp.float32)
+    q, k, v = (qkv[..., i * s.d_inner:(i + 1) * s.d_inner].reshape(
+        *qkv.shape[:-1], s.n_heads, s.d_head) for i in range(3))
+    return _l2_normed(q) * s.d_head ** -0.5, _l2_normed(k), v
+
+
+def kda_out(o, gate, p, cfg):
+    """o [T, H, D] float32 (S_t^T q_t) through the RMS norm over each head's
+    values, times sigmoid(gate), then out_proj -> [T, E]."""
+    y = ops.rms_norm(o, p["norm"], eps=cfg.norm_eps) * jax.nn.sigmoid(gate)
+    y = y.reshape(*y.shape[:-2], cfg.ssm.d_inner).astype(cfg.dtype)
+    return y @ p["out_proj"].astype(cfg.dtype)
+
+
+def kda_mixer(x, p, cfg, length=None, state=None, tail=None):
+    """The KDA mixer over one sequence, as `mamba_mixer`: normed x [T, E] ->
+    (its output before the residual [T, E], the state after position length -
+    1 [H, D, D] float32, the convolutions' tail there [d_conv - 1, 3 H D]).
+    Past `length` g and beta are 0, so padding leaves the state as it was."""
+    s, T = cfg.ssm, x.shape[0]
+    qkv, g, beta, gate = kda_project(x, p, cfg)
+    if tail is None:
+        tail = jnp.zeros((s.d_conv - 1, s.conv_dim), qkv.dtype)
+    if length is not None:
+        real = jnp.arange(T) < length
+        g, beta = jnp.where(real[:, None, None], g, 0.0), jnp.where(real[:, None], beta, 0.0)
+    # the convolutions' sums and their SiLU stay float32 (the tails are kept
+    # as projected, in the activations' dtype)
+    q, k, v = kda_split(ops.causal_conv(qkv.astype(jnp.float32), tail, p["conv_w"]), cfg)
+    o, state = ops.kda_chunk_scan(q, k, v, g, beta, chunk=s.chunk, state=state)
+    return (kda_out(o, gate, p, cfg), state,
+            ops.conv_tail(qkv, tail, T if length is None else length))
+
+
+def recurrent_mixer(cfg):
+    """The mixer of the stack's recurrent layers over one sequence:
+    `kda_mixer` or `mamba_mixer`, one signature."""
+    return kda_mixer if cfg.kda else mamba_mixer
 
 
 def _to_lanes(x, cfg):
@@ -1136,7 +1317,8 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
         h, aux, gates = carry
         normed = _norm(h, layer_p["norm1"], cfg)
         if ssm:
-            mixed = jax.vmap(lambda row: mamba_mixer(row, layer_p["mixer"], cfg)[0])(normed)
+            mixer = recurrent_mixer(cfg)
+            mixed = jax.vmap(lambda row: mixer(row, layer_p["mixer"], cfg)[0])(normed)
         else:
             mixed = _attn_block(normed, layer_p["attn"], cfg, *rope[window], sp_axis,
                                 attn_impl, cfg.window if window else None)

@@ -11,8 +11,8 @@ from ray_tpu.ops.norms import layer_norm, rms_norm
 from ray_tpu.ops.ragged_paged_attention import (
     ragged_decode_attention, ragged_decode_attention_reference)
 from ray_tpu.ops.rope import Yarn, apply_rope, rope_frequencies
-from ray_tpu.ops.ssm import (causal_conv, conv_tail, live_rows, ssm_chunk_scan,
-                             ssm_state_update)
+from ray_tpu.ops.ssm import (causal_conv, conv_tail, kda_chunk_scan, kda_state_update,
+                             live_rows, ssm_chunk_scan, ssm_state_update)
 
 __all__ = [
     "RoutingInfo",
@@ -29,6 +29,8 @@ __all__ = [
     "gelu",
     "grouped_matmul",
     "layer_norm",
+    "kda_chunk_scan",
+    "kda_state_update",
     "live_rows",
     "moe_apply",
     "held_slots",

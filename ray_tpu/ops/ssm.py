@@ -34,6 +34,39 @@ form:
 
 The state is float32 whatever the activations are: a running sum over
 thousands of steps with a decay near 1.
+
+A second recurrence lives beside it: the gated delta rule with a decay a KEY
+CHANNEL (Kimi Delta Attention, arXiv 2510.26692), a head's state a matrix
+S [dk, dv]:
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+with q_t, k_t [dk] (the caller's: normalised), v_t [dv], g_t [dk] <= 0 the log
+decay of each key channel, beta_t in (0, 2). One step is `S <- Diag(exp(g)) S;
+u = beta (v - S^T k); S <- S + k u^T; o = S^T q`. The same two forms:
+
+- `kda_chunk_scan`: a sequence in chunks of Q (plain XLA). With G the running
+  sum of g inside a chunk, S_i = Diag(exp(G_i)) S_0 + sum_{j<=i} Diag(exp(G_i -
+  G_j)) k_j u_j^T, where the corrected values u solve a unit lower-triangular
+  system (I + A) U = beta (V - (K exp(G)) S_0), A_ij = beta_i sum_c k_ic k_jc
+  exp(G_ic - G_jc) for j < i (the WY / UT transform). A does not depend on S_0,
+  so W_v = (I + A)^-1 beta V and W_k = (I + A)^-1 beta K exp(G) are made for
+  all chunks at once and the `lax.scan` over chunks is matrix products alone:
+  U = W_v - W_k S_0; O = (Q exp(G)) S_0 + tril(B) U; S_Q = Diag(exp(G_Q)) S_0
+  + (K exp(G_Q - G))^T U. The decays exp(G_i - G_j), i >= j, are never split
+  into exp(G_i) exp(-G_j) (exp of a POSITIVE sum overflows under strong
+  gates): inside a sub-block of `sub` positions they are taken pair by pair,
+  between sub-blocks through the earlier one's last position r, exp(G_i -
+  G_r) exp(G_r - G_j), both factors at most 1. The triangular system is
+  solved in float32 at precision HIGHEST by forward substitution (rows inside
+  a sub-block, then block by block). A position with g = 0 AND beta = 0
+  changes nothing: padding.
+- `kda_state_update`: one token a row for the LIVE rows, in place on
+  [layers, rows, H, dk, dv], through `live_rows`' schedule as
+  `ssm_state_update` (`kda_state_update` in a device trace; `jax.numpy` on
+  the layer's slice for `impl="reference"`, a row that is not live kept as it
+  is).
 """
 
 from __future__ import annotations
@@ -48,14 +81,14 @@ from jax.experimental.pallas import tpu as pltpu
 F32 = jnp.float32
 
 
-def causal_conv(x, tail, w, b):
+def causal_conv(x, tail, w, b=None):
     """Depthwise causal convolution of x [T, C] after the `K - 1` inputs
     `tail` [K - 1, C] that came before it (zeros at a row's start), weights w
-    [K, C] (w[K - 1] multiplies the current input), bias b [C]: K shifted
-    products, float32 sums. Returns [T, C] in x's dtype."""
+    [K, C] (w[K - 1] multiplies the current input), bias b [C] (None: no
+    bias): K shifted products, float32 sums. Returns [T, C] in x's dtype."""
     K, T = w.shape[0], x.shape[0]
     padded = jnp.concatenate([tail.astype(x.dtype), x], axis=0).astype(F32)
-    out = b.astype(F32)[None]
+    out = 0.0 if b is None else b.astype(F32)[None]
     for j in range(K):
         out = out + padded[j:j + T] * w[j].astype(F32)[None]
     return out.astype(x.dtype)
@@ -207,3 +240,240 @@ def ssm_state_update(state, layer, x, dt, A, B, C, *, live=None, schedule=None,
     else:
         raise ValueError(f"impl must be 'kernel' or 'reference', got {impl!r}")
     return state, jnp.where(live[:, None, None], y, 0.0)
+
+
+# --------------------------------------------- the gated delta rule (KDA)
+
+HIGHEST = jax.lax.Precision.HIGHEST
+# positions of a sub-block of the in-chunk decays: at the published sizes a
+# layer's 2,048-token chunk reads 13.4 ms with 8 and 17.4 ms with 16 on the
+# chip (PERF.md section 6, PR 50); only tests pass another, to run several
+# sub-blocks in a chunk of 8 or 16
+KDA_SUB = 8
+
+
+def _unit_lower_solve(A, rhs, sub: int):
+    """(I + A)^-1 rhs for A [..., Q, Q] strictly lower triangular, rhs
+    [..., Q, M], float32: the diagonal sub-blocks inverted row by row (for all
+    of them at once), then forward substitution block by block."""
+    Q = A.shape[-1]
+    n = Q // sub
+    eye = jnp.eye(sub, dtype=F32)
+    diag = jnp.stack([A[..., b * sub:(b + 1) * sub, b * sub:(b + 1) * sub]
+                      for b in range(n)], axis=-3)                  # [..., n, sub, sub]
+    # row i of a block's inverse from the rows before it, every block of
+    # every matrix in the lanes: multiply-adds on the VPU, exact float32
+    d = jnp.moveaxis(diag.reshape(-1, sub, sub), 0, -1)             # [i, j, blocks]
+    rows = []
+    for i in range(sub):
+        row = jnp.broadcast_to(eye[i][:, None], d.shape[1:])
+        for j in range(i):
+            row = row - d[i, j][None, :] * rows[j]
+        rows.append(row)
+    inv = jnp.moveaxis(jnp.stack(rows), -1, 0).reshape(diag.shape)
+    out = []
+    for b in range(n):
+        r = rhs[..., b * sub:(b + 1) * sub, :]
+        if b:
+            r = r - jnp.einsum("...ij,...jm->...im", A[..., b * sub:(b + 1) * sub, :b * sub],
+                               jnp.concatenate(out, axis=-2), precision=HIGHEST)
+        out.append(jnp.einsum("...ij,...jm->...im", inv[..., b, :, :], r, precision=HIGHEST))
+    return jnp.concatenate(out, axis=-2)
+
+
+def _decayed_grams(x, k, G, sub: int):
+    """sum_c x_ic k_jc exp(G_ic - G_jc) for i >= j (0 above the diagonal): x
+    [X, Q, H, D] (several left operands at once), k and G [Q, H, D] -> [X, H,
+    Q, Q] float32. No exponent is positive (module docstring)."""
+    Q, H, D = k.shape
+    n = Q // sub
+    xb = x.reshape(x.shape[0], n, sub, H, D)
+    kb, Gb = k.reshape(n, sub, H, D), G.reshape(n, sub, H, D)
+    # inside a sub-block: pair by pair
+    lower = jnp.tril(jnp.ones((sub, sub), bool))
+    pair = jnp.exp(jnp.where(lower[None, :, :, None, None],
+                             Gb[:, :, None] - Gb[:, None, :], -jnp.inf))  # [n, i, j, H, D]
+    inside = jnp.sum(xb[:, :, :, None] * (kb[:, None] * pair)[None], axis=-1)  # [X, n, i, j, H]
+    gram = jnp.zeros((x.shape[0], H, Q, Q), F32)
+    for b in range(n):
+        gram = gram.at[:, :, b * sub:(b + 1) * sub, b * sub:(b + 1) * sub].set(
+            inside[:, b].transpose(0, 3, 1, 2))
+    # between sub-blocks: through the earlier one's last position
+    for b in range(n - 1):
+        ref = Gb[b, -1]                                             # [H, D]
+        down = kb[b] * jnp.exp(ref[None] - Gb[b])                   # [sub, H, D]
+        later = slice((b + 1) * sub, Q)
+        up = x[:, later] * jnp.exp(G[later] - ref[None])[None]
+        gram = gram.at[:, :, later, b * sub:(b + 1) * sub].set(
+            jnp.einsum("xihd,jhd->xhij", up, down, preferred_element_type=F32,
+                       precision=HIGHEST))
+    return gram
+
+
+def _kda_prepare(q, k, v, G, beta, grams, sub: int):
+    """What the chunks of `kda_chunk_scan` need that does not depend on the
+    state before them, all chunks at once: q, k, G [n, Q, H, D], v [n, Q, H,
+    Dv], beta [n, Q, H], grams [n, 2, H, Q, Q] (`_decayed_grams` of q and of
+    k) -> (W_k | q exp(G) stacked on the rows [n, H, 2 Q, D], W_v [n, H, Q,
+    Dv], tril(B) [n, H, Q, Q], k exp(G_Q - G) [n, H, D, Q], exp(G_Q) [n, H, D])."""
+    Q, D = k.shape[1], k.shape[-1]
+    strict = jnp.tril(jnp.ones((Q, Q), bool), -1)
+    A = jnp.where(strict, grams[:, 1], 0.0) * beta.transpose(0, 2, 1)[..., None]  # [n, H, Q, Q]
+    decayed = jnp.exp(G)                                            # [n, Q, H, D]
+    rhs = jnp.concatenate([k * decayed, v], axis=-1) * beta[..., None]
+    W = _unit_lower_solve(A, rhs.transpose(0, 2, 1, 3), sub)        # [n, H, Q, D + Dv]
+    q_dec = (q * decayed).transpose(0, 2, 1, 3)                     # [n, H, Q, D]
+    k_end = (k * jnp.exp(G[:, -1:] - G)).transpose(0, 2, 3, 1)      # [n, H, D, Q]
+    return (jnp.concatenate([W[..., :D], q_dec], axis=2), W[..., D:], grams[:, 0], k_end,
+            decayed[:, -1])
+
+
+def kda_chunk_scan(q, k, v, g, beta, *, chunk: int, sub: int = KDA_SUB, state=None):
+    """q, k [T, H, dk] (float32, normalised by the caller), v [T, H, dv], g
+    [T, H, dk] (float32 log decay <= 0 a key channel), beta [T, H] (float32)
+    -> (o [T, H, dv] float32, the state after the last position [H, dk, dv]
+    float32). `state`: the state before position 0 (None: zeros). A padded
+    position has g = 0 and beta = 0. `chunk` positions a step of the scan,
+    `sub` (dividing it) a sub-block of the in-chunk decays. Everything is
+    float32, the matrix products at precision HIGHEST: the corrected values
+    are differences of near-equal terms and the state is read as an operand
+    in every chunk; the scan's products are a fortieth of the layer's
+    projections, and bfloat16 operands made the scan a tenth faster on the
+    chip (PERF.md section 6, PR 50)."""
+    T, H, D = k.shape
+    Dv = v.shape[-1]
+    Q, sub = chunk, min(sub, chunk)
+    if Q % sub:
+        raise ValueError(f"chunk {chunk} must be a multiple of the sub-block {sub}")
+    pad = -T % Q
+    if pad:  # g = 0 and beta = 0: steps that change nothing
+        q, k, v, g, beta = (jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+                            for a in (q, k, v, g, beta))
+    n = (T + pad) // Q
+    q, k, v, g, beta = (a.astype(F32) for a in (q, k, v, g, beta))
+
+    q, k, v, g, beta = (t.reshape(n, Q, *t.shape[1:]) for t in (q, k, v, g, beta))
+    G = jnp.cumsum(g, axis=1)                                       # [n, Q, H, D], inclusive
+    # the pairwise decays of a sub-block are [sub, sub, H, D] a sub-block:
+    # the Gram matrices are made a few chunks at a time, the rest at once
+    group = max(1, min(n, 512 // Q))
+    if n % group:
+        group = 1
+    grams = jax.lax.map(
+        jax.vmap(lambda one: _decayed_grams(jnp.stack(one[:2]), one[1], one[2], sub)),
+        tuple(t.reshape(n // group, group, *t.shape[1:]) for t in (q, k, G)))
+    prepared = _kda_prepare(q, k, v, G, beta, grams.reshape(n, *grams.shape[2:]), sub)
+    dot = functools.partial(jnp.einsum, preferred_element_type=F32, precision=HIGHEST)
+
+    def one(S, chunk_in):
+        Wk_q, W_v, B, k_end, decay_end = chunk_in
+        both = dot("hik,hkv->hiv", Wk_q, S)                         # W_k S | (q exp(G)) S
+        U = W_v - both[:, :Q]
+        o = both[:, Q:] + dot("hij,hjv->hiv", B, U)
+        S = decay_end[..., None] * S + dot("hkj,hjv->hkv", k_end, U)
+        return S, o
+
+    S0 = jnp.zeros((H, D, Dv), F32) if state is None else state.astype(F32)
+    S, o = jax.lax.scan(one, S0, prepared)                          # o [n, H, Q, Dv]
+    return o.transpose(0, 2, 1, 3).reshape(n * Q, H, Dv)[:T], S
+
+
+def _kda_step(s, decay, k, q, v, beta):
+    """One head's step: s [dk, dv], decay, k, q [dk, 1] (columns), v, beta
+    [1, dv] (rows) -> (the new state, o [1, dv]). The kernel and the reference
+    both call it, a head at a time, so that they agree bit for bit."""
+    s = decay * s
+    u = beta * (v - jnp.sum(k * s, axis=0, keepdims=True))
+    s = s + k * u
+    return s, jnp.sum(q * s, axis=0, keepdims=True)
+
+
+def _kda_update_kernel(layer_ref, rows_ref, count_ref, s_ref, decay_ref, k_ref, q_ref, v_ref,
+                       beta_ref, o_ref, y_ref):
+    """One row: s [H, dk, dv] -> `_kda_step` a head. `decay`, `k` and `q` come
+    transposed, [dk, H]: head h's values are a column, which broadcasts along
+    the lanes (dv) without a relayout; `v` and `beta` [H, dv] a row a head.
+    Past the schedule's live rows a grid step does nothing (`_update_kernel`)."""
+    del layer_ref, rows_ref
+
+    @pl.when(pl.program_id(0) < count_ref[0])
+    def _step():
+        H = s_ref.shape[2]
+        decay, k, q = decay_ref[0], k_ref[0], q_ref[0]              # [dk, H]
+        for h in range(H):  # static: a head is sixteen vregs
+            new, y = _kda_step(s_ref[0, 0, h], decay[:, h:h + 1], k[:, h:h + 1],
+                               q[:, h:h + 1], v_ref[0, pl.ds(h, 1), :],
+                               beta_ref[0, pl.ds(h, 1), :])
+            o_ref[0, 0, h] = new
+            y_ref[0, pl.ds(h, 1), :] = y
+
+
+def _kda_update_kernel_call(state, layer, rows, count, decay, k, q, v, beta, *,
+                            interpret: bool):
+    L, R, H, D, Dv = state.shape
+    col = lambda r, layer, rows, count: (rows[r], 0, 0)           # noqa: E731
+    block = lambda r, layer, rows, count: (layer[0], rows[r], 0, 0, 0)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # the layer, the rows' schedule, the live rows' count
+        grid=(R,),
+        in_specs=[pl.BlockSpec((1, 1, H, D, Dv), block),
+                  pl.BlockSpec((1, D, H), col), pl.BlockSpec((1, D, H), col),
+                  pl.BlockSpec((1, D, H), col),
+                  pl.BlockSpec((1, H, Dv), col), pl.BlockSpec((1, H, Dv), col)],
+        out_specs=[pl.BlockSpec((1, 1, H, D, Dv), block),
+                   pl.BlockSpec((1, H, Dv), col)])
+    return pl.pallas_call(
+        _kda_update_kernel,
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((R, H, Dv), F32)],
+        grid_spec=grid_spec,
+        # operand 3 (after the three prefetched) is the state: updated in place
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # a row's block in and out, double-buffered: 4 x H dk dv floats
+            vmem_limit_bytes=max(32 * 2**20, 6 * H * D * Dv * 4)),
+        interpret=interpret,
+        name="kda_state_update",
+    )(layer.reshape(1).astype(jnp.int32), rows, count, state, decay, k, q, v, beta)
+
+
+def kda_state_update(state, layer, q, k, v, g, beta, *, live=None, schedule=None,
+                     impl: str = "reference", interpret: bool = False):
+    """One token a row on layer `layer` of `state` [L, R, H, dk, dv]
+    (float32): q, k, g [R, H, dk], v [R, H, dv], beta [R, H] -> (the state,
+    that layer's LIVE rows updated where they lie and the others as they
+    were; o [R, H, dv] float32 = S_t^T q_t, 0 for a row that is not live).
+    `live` [R] bool (None: every row); `schedule` is `live_rows(live)` where
+    the caller has made it already."""
+    R, H, Dv = v.shape
+    if live is None:
+        live = jnp.ones((R,), bool)
+    q, k, v = (a.astype(F32) for a in (q, k, v))
+    # a row that is not live: no decay, no correction (the kernel steps only
+    # row 0 of a batch with none live, and so changes nothing)
+    decay = jnp.exp(jnp.where(live[:, None, None], g.astype(F32), 0.0))
+    beta = jnp.broadcast_to(jnp.where(live[:, None], beta.astype(F32), 0.0)[..., None],
+                            (R, H, Dv))
+    cols = tuple(a.transpose(0, 2, 1) for a in (decay, k, q))       # [R, dk, H]
+    if impl == "kernel":
+        rows, count = live_rows(live) if schedule is None else schedule
+        state, o = _kda_update_kernel_call(state, layer, rows, count, *cols, v, beta,
+                                           interpret=interpret)
+    elif impl == "reference":
+        s = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+
+        def head(one):  # a head at a time, as the kernel: the same bits (the
+            # products of a batched form were contracted otherwise, an ulp off)
+            s_h, decay_h, k_h, q_h, v_h, beta_h = one
+            return _kda_step(s_h, decay_h[:, None], k_h[:, None], q_h[:, None],
+                             v_h[None], beta_h[None])
+
+        new, o = jax.lax.map(lambda row: jax.lax.map(head, row), (
+            s, *(c.transpose(0, 2, 1) for c in cols), v, beta))
+        o = o[:, :, 0]
+        s = jnp.where(live[:, None, None, None], new, s)
+        state = jax.lax.dynamic_update_index_in_dim(state, s, layer, 0)
+    else:
+        raise ValueError(f"impl must be 'kernel' or 'reference', got {impl!r}")
+    return state, jnp.where(live[:, None, None], o, 0.0)

@@ -36,7 +36,8 @@ TINY_KERNELS = dict(flash=(1, 2, 128, 64), interpret=True,
                     ragged=dict(batch=2, kv_heads=2, group=2, head_dim=64,
                                 page=16, pages_per_seq=4),
                     grouped=dict(tiny=dict(tokens=64, top_k=2, experts=4, layers=2,
-                                           d_model=256, d_ff=128)))
+                                           d_model=256, d_ff=128)),
+                    kda_update=dict(layers=2, slots=4, heads=2, head_dim=128))
 
 
 def test_without_a_chip_the_script_fails_and_prints_no_result():
@@ -64,6 +65,7 @@ def test_rehearse_train_phase(cluster):
         kernels=TINY_KERNELS)
     chip_smoke.check_kernels(facts["kernels"])
     assert facts["kernels"]["ragged"]["bit_equal"]
+    assert len(facts["kernels"]["kda_update"]["steps"]) == 5
     assert sum(k.startswith("grouped_tiny_") for k in facts["kernels"]) == 4
     chip_smoke.check_train_run(facts["runs"][0], min_steps=3, want_kernel=False)
     # a CPU worker is what the script exists to refuse

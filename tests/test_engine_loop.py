@@ -997,11 +997,13 @@ def _form_model(kind: str) -> tuple:
         "state_space": lambda: models.granite_config("tiny", **sizes),
         "held_experts_window": lambda: models.trinity_config(
             "tiny", n_layers=6, experts_held=8, first_expert=16, **sizes),
+        "held_experts_state": lambda: models.solar_open2_config(
+            "tiny", n_layers=4, experts_held=8, first_expert=16, **sizes),
     }[kind]()
     return cfg, transformer.init(jax.random.PRNGKey(3), cfg)
 
 
-# The shapes of engine the benchmark's seven serve configurations build (and
+# The shapes of engine the benchmark's eight serve configurations build (and
 # granite's with chunks, which its tests serve), each at a tiny stand-in of
 # the model: (model, engine options).
 ENGINE_FORMS = {
@@ -1013,6 +1015,8 @@ ENGINE_FORMS = {
     "state_space": ("state_space", {}),
     "state_space_chunk": ("state_space", dict(prefill_chunk=32)),
     "held_experts_window_chunk": ("held_experts_window", dict(prefill_chunk=32)),
+    # a recurrent state a row AND a share of the experts (since PR 50)
+    "held_experts_state_chunk": ("held_experts_state", dict(prefill_chunk=32)),
 }
 
 
@@ -1127,7 +1131,7 @@ ADMISSIONS = {
     "plain": _INSERTED, "prefix_chunk": _CHUNKED_CACHED, "window_chunk": _CHUNKED_RING,
     "latent_prefix_chunk": _CHUNKED_CACHED, "looped_one_prefill_a_pass": _INSERTED,
     "state_space": _INSERTED, "state_space_chunk": _CHUNKED,
-    "held_experts_window_chunk": _CHUNKED_RING}
+    "held_experts_window_chunk": _CHUNKED_RING, "held_experts_state_chunk": _CHUNKED}
 
 
 @pytest.mark.parametrize("form", sorted(ENGINE_FORMS))
