@@ -65,7 +65,9 @@ KERNELS = dict(flash=(2, 20, 1024, 64),
                # a row's recurrent state of granite-4.0-h-micro, 96 slots
                state_update=dict(layers=3, slots=96, heads=64, head_dim=64, d_state=128),
                # the gated delta rule's state of solar-open2-250b, 32 slots
-               kda_update=dict(layers=3, slots=32, heads=64, head_dim=128))
+               kda_update=dict(layers=3, slots=32, heads=64, head_dim=128),
+               # ... and its chunked scan over a 2,048-token chunk of the prefill
+               kda_scan=dict(tokens=2048, tail=1024, heads=64, head_dim=128, chunk=64))
 
 
 class SmokeFailure(AssertionError):
@@ -298,8 +300,83 @@ def compare_kda_update(shape: dict, interpret: bool = False) -> dict:
     return out
 
 
+def compare_kda_scan(shape: dict, interpret: bool = False) -> dict:
+    """`ops.kda_chunk_scan`'s Pallas launch against its XLA form at a prefill
+    chunk's shape: outputs and the state after `tokens` positions from zeros,
+    then carried on over a `tail` of positions that ends in padding (g = 0,
+    beta = 0), log decays down to -5 and beta up to 2. Both timed (not
+    under `interpret`): the XLA form whole and by its parts (the decayed Gram
+    matrices, the rest of the state-free preparation with its triangular
+    solves, the scan over chunks), the launch whole (the op, as the model
+    calls it) and alone on [T, H D] operands."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import ssm
+
+    T, tail, H, D, Q = (shape[k] for k in ("tokens", "tail", "heads", "head_dim", "chunk"))
+    rng = np.random.default_rng(0)
+    f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+
+    def inputs(n, real):
+        g = -jnp.asarray(rng.uniform(0.0, 0.3, (n, H, D)) * (rng.random((n, H, 1)) < 0.9)
+                         + 5.0 * (rng.random((n, H, D)) < 0.02), jnp.float32)
+        beta = jnp.asarray(np.where(rng.random((n, H)) < 0.3, 2.0 - 1e-3 * rng.random((n, H)),
+                                    rng.uniform(0.0, 2.0, (n, H))), jnp.float32)
+        live = (jnp.arange(n) < real)
+        return (unit(f32(n, H, D)) * D ** -0.5, unit(f32(n, H, D)), f32(n, H, D),
+                jnp.where(live[:, None, None], g, 0.0), jnp.where(live[:, None], beta, 0.0))
+
+    forms = {
+        "launch": jax.jit(lambda *a: ssm._kda_chunk_scan_pallas(*a, Q, ssm.KDA_SUB, interpret)),
+        "xla": jax.jit(lambda *a: ssm._kda_chunk_scan_xla(*a, chunk=Q, sub=ssm.KDA_SUB))}
+    first, second = inputs(T, T), inputs(tail, tail - Q - Q // 3)
+    zeros = jnp.zeros((H, D, D), jnp.float32)
+    got, want = forms["launch"](*first, zeros), forms["xla"](*first, zeros)
+    got2, want2 = forms["launch"](*second, got[1]), forms["xla"](*second, want[1])
+    rel = lambda a, b: float(jnp.abs(a - b).max() / jnp.abs(b).max())  # noqa: E731
+    out = {"shape": dict(shape),
+           "o_rel_err": rel(got[0], want[0]), "state_rel_err": rel(got[1], want[1]),
+           "carried_o_rel_err": rel(got2[0], want2[0]),
+           "carried_state_rel_err": rel(got2[1], want2[1]),
+           "finite": bool(all(jnp.isfinite(x).all() for x in (*got, *got2)))}
+    out["max_abs_err"] = max(v for k, v in out.items() if k.endswith("_rel_err"))
+    out["tol"] = 2e-5   # tests/test_solar_open2.py's bound against the recurrence
+    out["ok"] = out["finite"] and out["max_abs_err"] <= out["tol"]
+    if interpret:
+        return out
+
+    def ms(fn, *a, n=10):
+        jax.block_until_ready(fn(*a))
+        t0 = time.perf_counter()
+        for _ in range(n):
+            r = fn(*a)
+        jax.block_until_ready(r)
+        return (time.perf_counter() - t0) / n * 1e3
+
+    chunks = lambda t: t.reshape(T // Q, Q, *t.shape[1:])  # noqa: E731
+    q, k, v, g, beta = (chunks(t) for t in first)
+    G = jnp.cumsum(g, axis=1)
+    grams_fn = jax.jit(lambda q, k, G: jax.lax.map(
+        jax.vmap(lambda one: ssm._decayed_grams(jnp.stack(one[:2]), one[1], one[2],
+                                                ssm.KDA_SUB)),
+        tuple(t.reshape(T // Q // 8, 8, *t.shape[1:]) for t in (q, k, G))))
+    grams = grams_fn(q, k, G).reshape(T // Q, 2, H, Q, Q)
+    prepare = jax.jit(functools.partial(ssm._kda_prepare, sub=ssm.KDA_SUB))
+    flat = tuple(t.reshape(T, -1) for t in first[:4])
+    launch = jax.jit(functools.partial(ssm._kda_scan_launch, chunk=Q, interpret=False))
+    out["ms"] = {"xla": ms(forms["xla"], *first, zeros),
+                 "xla_grams": ms(grams_fn, q, k, G),
+                 "xla_prepare": ms(prepare, q, k, v, G, beta, grams),
+                 "launch": ms(forms["launch"], *first, zeros),
+                 "launch_alone": ms(launch, *flat, first[4], zeros)}
+    return out
+
+
 def compare_kernels(flash, ragged, grouped=None, state_update=None, kda_update=None,
-                    interpret: bool = False) -> dict:
+                    kda_scan=None, interpret: bool = False) -> dict:
     """The Pallas kernels against their pure-JAX references at the given
     shapes, bf16. `interpret` is for the CPU rehearsal only."""
     import jax
@@ -359,6 +436,8 @@ def compare_kernels(flash, ragged, grouped=None, state_update=None, kda_update=N
         out["state_update"] = compare_state_update(state_update, interpret)
     if kda_update:
         out["kda_update"] = compare_kda_update(kda_update, interpret)
+    if kda_scan:
+        out["kda_scan"] = compare_kda_scan(kda_scan, interpret)
     return out
 
 

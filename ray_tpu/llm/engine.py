@@ -537,6 +537,11 @@ class TPUEngine:
         # real tokens; from what the host knows of a call, no device read
         self.scan_positions = 0
         self.scan_padded = 0
+        # ... and those whose scan ran in the Pallas launch: all of them or
+        # none, by what `ops.kda_chunk_scan` chooses for these shapes here
+        self.scan_kernel_positions = 0
+        self._scan_kernel = bool(self.cfg.kda and mesh is None and ops.ssm.kda_scan_in_kernel(
+            self.cfg.ssm.n_heads, self.cfg.ssm.d_head, self.cfg.ssm.d_head, self.cfg.ssm.chunk))
         # decode attention is one ragged-paged-attention launch over the
         # batch's live page tables (ops/ragged_paged_attention.py): the
         # Pallas kernel where the code can see a TPU and an unsharded pool,
@@ -1629,6 +1634,8 @@ class TPUEngine:
             ran = -(-bucket // Q) * Q
             self.scan_positions += layers * ran
             self.scan_padded += layers * (ran - n)
+            if self._scan_kernel:
+                self.scan_kernel_positions += layers * ran
         return logits, kv
 
     def _go_live(self, req: _Request, logits, kv, wait: str) -> None:
@@ -2193,7 +2200,8 @@ class TPUEngine:
                           "attended_pairs": self.attended_pairs}
         if self.cfg.ssm:
             out["prefill"].update(scan_positions=self.scan_positions,
-                                  scan_padded=self.scan_padded)
+                                  scan_padded=self.scan_padded,
+                                  scan_kernel_positions=self.scan_kernel_positions)
         if self.prefill_chunk:
             out["prefill_chunk"] = self.prefill_chunk
             out["prefill_chunks_run"] = self.prefill_chunks_run

@@ -46,22 +46,44 @@ with q_t, k_t [dk] (the caller's: normalised), v_t [dv], g_t [dk] <= 0 the log
 decay of each key channel, beta_t in (0, 2). One step is `S <- Diag(exp(g)) S;
 u = beta (v - S^T k); S <- S + k u^T; o = S^T q`. The same two forms:
 
-- `kda_chunk_scan`: a sequence in chunks of Q (plain XLA). With G the running
-  sum of g inside a chunk, S_i = Diag(exp(G_i)) S_0 + sum_{j<=i} Diag(exp(G_i -
-  G_j)) k_j u_j^T, where the corrected values u solve a unit lower-triangular
-  system (I + A) U = beta (V - (K exp(G)) S_0), A_ij = beta_i sum_c k_ic k_jc
-  exp(G_ic - G_jc) for j < i (the WY / UT transform). A does not depend on S_0,
-  so W_v = (I + A)^-1 beta V and W_k = (I + A)^-1 beta K exp(G) are made for
-  all chunks at once and the `lax.scan` over chunks is matrix products alone:
-  U = W_v - W_k S_0; O = (Q exp(G)) S_0 + tril(B) U; S_Q = Diag(exp(G_Q)) S_0
-  + (K exp(G_Q - G))^T U. The decays exp(G_i - G_j), i >= j, are never split
-  into exp(G_i) exp(-G_j) (exp of a POSITIVE sum overflows under strong
-  gates): inside a sub-block of `sub` positions they are taken pair by pair,
-  between sub-blocks through the earlier one's last position r, exp(G_i -
-  G_r) exp(G_r - G_j), both factors at most 1. The triangular system is
-  solved in float32 at precision HIGHEST by forward substitution (rows inside
-  a sub-block, then block by block). A position with g = 0 AND beta = 0
-  changes nothing: padding.
+- `kda_chunk_scan`: a sequence in chunks of Q. With G the running sum of g
+  inside a chunk, S_i = Diag(exp(G_i)) S_0 + sum_{j<=i} Diag(exp(G_i - G_j))
+  k_j u_j^T, where the corrected values u solve a unit lower-triangular system
+  (I + A) U = beta (V - (K exp(G)) S_0), A_ij = beta_i sum_c k_ic k_jc
+  exp(G_ic - G_jc) for j < i (the WY / UT transform); then O = (Q exp(G)) S_0
+  + tril(B) U with B the same decayed Gram matrix of q against k, and S_Q =
+  Diag(exp(G_Q)) S_0 + (K exp(G_Q - G))^T U. The decays exp(G_i - G_j), i >=
+  j, are never split into exp(G_i) exp(-G_j) (exp of a POSITIVE sum overflows
+  under strong gates): no exponent is ever positive. Everything is float32,
+  every matrix product at float32 accuracy. A position with g = 0 AND beta =
+  0 changes nothing: padding. Two forms, chosen inside the op from what it
+  can observe (`kda_scan_in_kernel`), as `ops.attention` chooses the flash
+  kernel:
+  - ONE Pallas launch (`kda_chunk_scan` in a device trace) on a TPU, outside
+    a mesh, where `kda_scan_tiles` (keys of 128, values of whole 128-lane
+    tiles, heads in pairs, a chunk of 8 to 64 that is a power of two): grid
+    (pairs of heads, steps of four chunks), the steps of a pair in order, its
+    two states carried in VMEM from chunk to chunk; q, k, g, v blocks of [T,
+    H D] as the projections leave them; nothing of a chunk but its inputs is
+    read from HBM and nothing but o and the last state written. The decays go
+    through a BINARY hierarchy of references (`_kda_scan_kernel`): a pair i >
+    j meets at the level where their groups of 2 m positions first join, as
+    exp(G_i - G_r) exp(G_r - G_j) through the earlier half's last position r,
+    both factors at most 1, one MXU product a level (of the later halves'
+    rows alone); (I + A)^-1 is joined by the same levels (T - T A21 T) and
+    applied to the right-hand side. There is no sub-block (it is ONE
+    position): on the v5e a product at six bfloat16 passes costs about a
+    quarter of a microsecond whatever its size up to 128 x 128, the VPU's
+    pair-by-pair part of sub-blocks of 8 cost four levels' worth, and forward
+    substitution on the VPU ten times the levels' (PERF.md section 6, PR 51).
+  - plain XLA elsewhere (the CPU, heads that do not tile; the reference the
+    launch is compared with, and what its gradient is taken through:
+    `custom_vjp`): W_v = (I + A)^-1 beta V and W_k = (I + A)^-1 beta K exp(G)
+    are made for all chunks at once (A does not depend on S_0) and the
+    `lax.scan` over chunks is matrix products alone: U = W_v - W_k S_0. Inside
+    a sub-block of `sub` positions the decays are taken pair by pair, between
+    sub-blocks through the earlier one's last position; the triangular system
+    by forward substitution (rows inside a sub-block, then block by block).
 - `kda_state_update`: one token a row for the LIVE rows, in place on
   [layers, rows, H, dk, dv], through `live_rows`' schedule as
   `ssm_state_update` (`kda_state_update` in a device trace; `jax.numpy` on
@@ -245,10 +267,11 @@ def ssm_state_update(state, layer, x, dt, A, B, C, *, live=None, schedule=None,
 # --------------------------------------------- the gated delta rule (KDA)
 
 HIGHEST = jax.lax.Precision.HIGHEST
-# positions of a sub-block of the in-chunk decays: at the published sizes a
-# layer's 2,048-token chunk reads 13.4 ms with 8 and 17.4 ms with 16 on the
-# chip (PERF.md section 6, PR 50); only tests pass another, to run several
-# sub-blocks in a chunk of 8 or 16
+# positions of a sub-block of the XLA form's in-chunk decays: at the published
+# sizes a layer's 2,048-token chunk reads 13.4 ms with 8 and 17.4 ms with 16
+# on the chip (PERF.md section 6, PR 50); only tests pass another, to run
+# several sub-blocks in a chunk of 8 or 16. The Pallas launch, which runs
+# those sizes on a TPU since PR 51, has no sub-block (module docstring)
 KDA_SUB = 8
 
 
@@ -328,30 +351,17 @@ def _kda_prepare(q, k, v, G, beta, grams, sub: int):
             decayed[:, -1])
 
 
-def kda_chunk_scan(q, k, v, g, beta, *, chunk: int, sub: int = KDA_SUB, state=None):
-    """q, k [T, H, dk] (float32, normalised by the caller), v [T, H, dv], g
-    [T, H, dk] (float32 log decay <= 0 a key channel), beta [T, H] (float32)
-    -> (o [T, H, dv] float32, the state after the last position [H, dk, dv]
-    float32). `state`: the state before position 0 (None: zeros). A padded
-    position has g = 0 and beta = 0. `chunk` positions a step of the scan,
-    `sub` (dividing it) a sub-block of the in-chunk decays. Everything is
-    float32, the matrix products at precision HIGHEST: the corrected values
-    are differences of near-equal terms and the state is read as an operand
-    in every chunk; the scan's products are a fortieth of the layer's
-    projections, and bfloat16 operands made the scan a tenth faster on the
-    chip (PERF.md section 6, PR 50)."""
+def _kda_chunk_scan_xla(q, k, v, g, beta, state, *, chunk: int, sub: int):
+    """`kda_chunk_scan` in plain XLA over whole chunks, all chunks' state-free
+    part at once (module docstring): the form of the CPU and of shapes that
+    do not tile, the reference the launch is compared with, and what its
+    gradient is taken through."""
     T, H, D = k.shape
     Dv = v.shape[-1]
     Q, sub = chunk, min(sub, chunk)
     if Q % sub:
         raise ValueError(f"chunk {chunk} must be a multiple of the sub-block {sub}")
-    pad = -T % Q
-    if pad:  # g = 0 and beta = 0: steps that change nothing
-        q, k, v, g, beta = (jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
-                            for a in (q, k, v, g, beta))
-    n = (T + pad) // Q
-    q, k, v, g, beta = (a.astype(F32) for a in (q, k, v, g, beta))
-
+    n = T // Q
     q, k, v, g, beta = (t.reshape(n, Q, *t.shape[1:]) for t in (q, k, v, g, beta))
     G = jnp.cumsum(g, axis=1)                                       # [n, Q, H, D], inclusive
     # the pairwise decays of a sub-block are [sub, sub, H, D] a sub-block:
@@ -373,9 +383,266 @@ def kda_chunk_scan(q, k, v, g, beta, *, chunk: int, sub: int = KDA_SUB, state=No
         S = decay_end[..., None] * S + dot("hkj,hjv->hkv", k_end, U)
         return S, o
 
-    S0 = jnp.zeros((H, D, Dv), F32) if state is None else state.astype(F32)
-    S, o = jax.lax.scan(one, S0, prepared)                          # o [n, H, Q, Dv]
-    return o.transpose(0, 2, 1, 3).reshape(n * Q, H, Dv)[:T], S
+    S, o = jax.lax.scan(one, state, prepared)                       # o [n, H, Q, Dv]
+    return o.transpose(0, 2, 1, 3).reshape(T, H, Dv), S
+
+
+# heads a grid step of the launch, stacked on the rows: their [Q, Q] matrices
+# are the diagonal blocks of one [2 Q, 2 Q] matrix (128 x 128 at the published
+# chunk of 64: one MXU tile), so each product of the chain serves both heads
+_KDA_STACK = 2
+
+
+def _dot32(a, b, contract=((1,), (0,))):
+    """A float32 matrix product at float32 accuracy (Mosaic's
+    `contract_precision<fp32>`: six bfloat16 passes)."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=HIGHEST,
+                               preferred_element_type=F32)
+
+
+def _kda_scan_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, s0_ref, o_ref, s_ref, st_ref,
+                     wide_ref, square_ref, *, Q: int, chunks: int):
+    """Two heads (`_KDA_STACK`) over `chunks` chunks of Q positions a grid
+    step; grid (head pairs, steps), the steps of a pair in order, the pair's
+    states carried transposed ([dv, dk]: a decay a key channel scales lanes)
+    in `st_ref`. Everything of a chunk stays in VMEM and vregs.
+
+    The decays exp(G_i - G_j), i > j, go through a position r with j <= r < i
+    as exp(G_i - G_r) exp(G_r - G_j), both factors at most 1: a binary
+    hierarchy of references. At level m (1, 2, 4, .. Q / 2) the positions are
+    groups of 2 m; the later half of a group sees the earlier half through
+    the earlier half's last position, one matrix product a level (every pair
+    i > j meets at exactly one level: where their groups first join). (I +
+    A)^-1 comes by the same hierarchy: T = diag(T1, T2) of a group's halves
+    becomes [[T1, 0], [-T2 A21 T1, T2]] = T - T A21 T. No loop runs over
+    sub-blocks: a level is whole-matrix products and masks."""
+    P, R = _KDA_STACK, _KDA_STACK * Q
+    pair, step = pl.program_id(0), pl.program_id(1)
+    D, Dv = q_ref.shape[-1] // P, v_ref.shape[-1] // P
+
+    @pl.when(step == 0)
+    def _first():
+        for h in range(P):
+            st_ref[h] = s0_ref[h].T
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (R, R), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (R, R), 1)
+    eye = row == col
+    running = ((row >= col) & (row // Q == col // Q)).astype(F32)
+    row_d = jax.lax.broadcasted_iota(jnp.int32, (R, D), 0)
+    levels = []  # (m, the rows of the later halves, [later half, earlier half] of a group)
+    m = 1
+    while m < Q:
+        levels.append((m, (row_d // m) % 2 == 1,
+                       ((row // m) % 2 == 1) & ((col // m) % 2 == 0)
+                       & (row // (2 * m) == col // (2 * m))))
+        m *= 2
+    head_lane = jax.lax.broadcasted_iota(jnp.int32, (Q, beta_ref.shape[-1]), 1)
+
+    def one_chunk(c, carry):
+        rows = pl.ds(pl.multiple_of(c * Q, Q), Q)
+
+        def stacked(ref, width):
+            return jnp.concatenate([ref[rows, h * width:(h + 1) * width] for h in range(P)],
+                                   axis=0)
+
+        q, k, g, v = stacked(q_ref, D), stacked(k_ref, D), stacked(g_ref, D), stacked(v_ref, Dv)
+        betas = beta_ref[rows, :]
+        beta = jnp.concatenate(
+            [jnp.sum(jnp.where(head_lane == pair * P + h, betas, 0.0), axis=1, keepdims=True)
+             for h in range(P)], axis=0)                                # [R, 1]
+        G = _dot32(running, g)                                          # inclusive, a head
+        by_eights = G.reshape(R // 8, 8, D)
+
+        def row_of_eight(p):  # row p of every eight rows, to all eight
+            return jnp.broadcast_to(by_eights[:, p:p + 1], by_eights.shape).reshape(R, D)
+
+        def later_halves(x, m, via):
+            """The rows of the later halves of every 2 m, packed [R / 2, .]:
+            whole tiles of eight rows by slices, rows inside a tile by strided
+            reads of `via`."""
+            if m >= 8:
+                return jnp.concatenate([x[s + m:s + 2 * m] for s in range(0, R, 2 * m)], axis=0)
+            via[...] = x
+            return jnp.concatenate([via[pl.ds(m + p, R // (2 * m), stride=2 * m), :]
+                                    for p in range(m)], axis=0)
+
+        def to_later_halves(x, m):
+            """... and [R / 2, R] back to those rows, zeros in the others."""
+            if m >= 8:
+                zero = jnp.zeros((m, R), F32)
+                return jnp.concatenate(
+                    [y for s in range(0, R // 2, m) for y in (zero, x[s:s + m])], axis=0)
+            n = R // (2 * m)
+            for p in range(m):
+                square_ref[pl.ds(m + p, n, stride=2 * m), :] = x[p * n:(p + 1) * n]
+            return jnp.where((row // m) % 2 == 1, square_ref[...], 0.0)
+
+        # the decayed Gram matrices of q and of k against k, below the diagonal;
+        # only the later halves' rows stream through a level's product
+        A = jnp.zeros((R, R), F32)
+        B = jnp.where(eye, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
+        for m, later, joins in levels:
+            if m == 1:
+                through = jnp.where(later, g, 0.0)                      # G_i - G_(i - 1)
+            else:
+                if m == 2:
+                    ref = jnp.where(row_d % 8 < 4, row_of_eight(1), row_of_eight(5))
+                elif m == 4:
+                    ref = row_of_eight(3)
+                else:
+                    ref = jnp.concatenate(
+                        [jnp.broadcast_to(G[s + m - 1:s + m], (2 * m, D))
+                         for s in range(0, R, 2 * m)], axis=0)
+                through = jnp.where(later, G - ref, ref - G)
+            decay = jnp.exp(through)
+            qd, kd = q * decay, k * decay
+            both = _dot32(jnp.concatenate([later_halves(qd, m, wide_ref),
+                                           later_halves(kd, m, wide_ref)], axis=0),
+                          kd, ((1,), (1,)))
+            B = B + jnp.where(joins, to_later_halves(both[:R // 2], m), 0.0)
+            A = A + jnp.where(joins, to_later_halves(both[R // 2:], m), 0.0)
+        A = A * beta
+        T = jnp.where(eye, 1.0, 0.0)
+        for m, _, joins in levels:
+            off = jnp.where(joins, A, 0.0)
+            if m == 1:
+                T = T - off
+            else:  # - T2 A21 T1: rows of the later halves in either product
+                inner = to_later_halves(_dot32(later_halves(off, m, square_ref), T), m)
+                T = T - to_later_halves(_dot32(later_halves(T, m, square_ref), inner), m)
+        # with the carried states: U = T beta (V - K exp(G) S), O = Q exp(G) S + B U
+        decayed = jnp.exp(G)
+        k_dec, q_dec = k * decayed, q * decayed
+        head = lambda x, h: x[h * Q:(h + 1) * Q]                        # noqa: E731
+        states = [st_ref[h] for h in range(P)]
+        seen = [_dot32(jnp.concatenate([head(k_dec, h), head(q_dec, h)], axis=0), states[h],
+                       ((1,), (1,))) for h in range(P)]                 # [2 Q, dv] a head
+        U = _dot32(T, beta * (v - jnp.concatenate([s[:Q] for s in seen], axis=0)))
+        o = jnp.concatenate([s[Q:] for s in seen], axis=0) + _dot32(B, U)
+        for h in range(P):
+            o_ref[rows, h * Dv:(h + 1) * Dv] = head(o, h)
+            G_h = head(G, h)
+            k_end = head(k, h) * jnp.exp(G_h[Q - 1:Q] - G_h)
+            st_ref[h] = (jnp.exp(G_h[Q - 1:Q]) * states[h]
+                         + _dot32(head(U, h), k_end, ((0,), (0,))))
+        return carry
+
+    jax.lax.fori_loop(0, chunks, one_chunk, 0)
+
+    @pl.when(step == pl.num_programs(1) - 1)
+    def _last():
+        for h in range(P):
+            s_ref[h] = st_ref[h].T
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"), inline=True)
+def _kda_scan_launch(q, k, v, g, beta, state, *, chunk: int, interpret: bool):
+    """The launch over q, k, g [T, H dk], v [T, H dv] as they come from the
+    projections (a head's columns side by side: no heads-major copy), beta [T,
+    H], state [H, dk, dv]; T whole chunks. Jitted for its trace cache alone:
+    a prefill program calls it in each of the two scans over its KDA layers
+    and a replica warms a dozen such programs of two or three lengths; the
+    body's few hundred operations, traced inside those programs' traces, took
+    1.2 s a call on the chip's host (PERF.md section 6, PR 51)."""
+    T, H = beta.shape
+    D, Dv = q.shape[1] // H, v.shape[1] // H
+    P, n = _KDA_STACK, T // chunk
+    chunks = next(c for c in (4, 2, 1) if n % c == 0)
+    rows = chunk * chunks
+    at = lambda pair, step: (step, pair)                                # noqa: E731
+    of_pair = lambda pair, step: (pair, 0, 0)                           # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_kda_scan_kernel, Q=chunk, chunks=chunks),
+        out_shape=[jax.ShapeDtypeStruct((T, H * Dv), F32),
+                   jax.ShapeDtypeStruct((H, D, Dv), F32)],
+        grid=(H // P, n // chunks),
+        in_specs=[pl.BlockSpec((rows, P * D), at), pl.BlockSpec((rows, P * D), at),
+                  pl.BlockSpec((rows, P * D), at), pl.BlockSpec((rows, P * Dv), at),
+                  pl.BlockSpec((rows, H), lambda pair, step: (step, 0)),
+                  pl.BlockSpec((P, D, Dv), of_pair)],
+        out_specs=[pl.BlockSpec((rows, P * Dv), at), pl.BlockSpec((P, D, Dv), of_pair)],
+        scratch_shapes=[pltpu.VMEM((P, Dv, D), F32), pltpu.VMEM((P * chunk, D), F32),
+                        pltpu.VMEM((P * chunk, P * chunk), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="kda_chunk_scan",
+    )(q, k, g, v, beta, state)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _kda_chunk_scan_pallas(q, k, v, g, beta, state, chunk, sub, interpret):
+    """`kda_chunk_scan` as the launch, over whole chunks; `sub` is the XLA
+    form's, for the gradient."""
+    T, H, _ = k.shape
+    o, state = _kda_scan_launch(*(a.reshape(T, -1) for a in (q, k, v, g)), beta, state,
+                                chunk=chunk, interpret=interpret)
+    return o.reshape(T, H, -1), state
+
+
+def _kda_chunk_scan_pallas_fwd(q, k, v, g, beta, state, chunk, sub, interpret):
+    return (_kda_chunk_scan_pallas(q, k, v, g, beta, state, chunk, sub, interpret),
+            (q, k, v, g, beta, state))
+
+
+def _kda_chunk_scan_pallas_bwd(chunk, sub, interpret, inputs, cotangents):
+    # the XLA form's own gradient, as `grouped_matmul` keeps `ragged_dot`'s
+    return jax.vjp(functools.partial(_kda_chunk_scan_xla, chunk=chunk, sub=sub),
+                   *inputs)[1](cotangents)
+
+
+_kda_chunk_scan_pallas.defvjp(_kda_chunk_scan_pallas_fwd, _kda_chunk_scan_pallas_bwd)
+
+
+def kda_scan_tiles(heads: int, dk: int, dv: int, chunk: int) -> bool:
+    """Whether the launch can take these shapes: keys of one 128-lane tile
+    and values of whole ones, heads in pairs, the chunk a power of two (the
+    hierarchy's halves) from 8 (a sublane tile) to 64 (a pair's chunks one
+    128 x 128 tile, which the strided reads of `_kda_scan_kernel` ask for)."""
+    return (dk == 128 and dv % 128 == 0 and heads % _KDA_STACK == 0
+            and 8 <= chunk <= 64 and chunk & (chunk - 1) == 0)
+
+
+def kda_scan_in_kernel(heads: int, dk: int, dv: int, chunk: int) -> bool:
+    """Whether `kda_chunk_scan` runs these shapes in its Pallas launch HERE:
+    on a TPU, outside a mesh (GSPMD cannot partition a Mosaic kernel), where
+    they tile; as `ops.attention._flash_ok` chooses the flash kernel. The
+    engine asks it once, for its counter."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return (jax.default_backend() == "tpu" and (mesh.empty or mesh.size == 1)
+            and kda_scan_tiles(heads, dk, dv, chunk))
+
+
+def kda_chunk_scan(q, k, v, g, beta, *, chunk: int, sub: int = KDA_SUB, state=None,
+                   interpret: bool = False):
+    """q, k [T, H, dk] (float32, normalised by the caller), v [T, H, dv], g
+    [T, H, dk] (float32 log decay <= 0 a key channel), beta [T, H] (float32)
+    -> (o [T, H, dv] float32, the state after the last position [H, dk, dv]
+    float32). `state`: the state before position 0 (None: zeros). A padded
+    position has g = 0 and beta = 0. `chunk` positions a step of the scan,
+    `sub` (dividing it) a sub-block of the XLA form's in-chunk decays.
+    Everything is float32, the matrix products at float32 accuracy: the
+    corrected values are differences of near-equal terms and the state is
+    read as an operand in every chunk.
+
+    One Pallas launch (`kda_chunk_scan` in a device trace) where
+    `kda_scan_in_kernel` says so, the XLA form elsewhere; `interpret=True`
+    runs the launch interpreted wherever the shapes tile (tests). Its
+    gradient is the XLA form's on either path."""
+    T, H, D = k.shape
+    q, k, v, g, beta = (a.astype(F32) for a in (q, k, v, g, beta))
+    pad = -T % chunk
+    if pad:  # g = 0 and beta = 0: steps that change nothing
+        q, k, v, g, beta = (jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+                            for a in (q, k, v, g, beta))
+    state = jnp.zeros((H, D, v.shape[-1]), F32) if state is None else state.astype(F32)
+    if kda_scan_in_kernel(H, D, v.shape[-1], chunk) or (
+            interpret and kda_scan_tiles(H, D, v.shape[-1], chunk)):
+        o, state = _kda_chunk_scan_pallas(q, k, v, g, beta, state, chunk, sub, interpret)
+    else:
+        o, state = _kda_chunk_scan_xla(q, k, v, g, beta, state, chunk=chunk, sub=sub)
+    return o[:T], state
 
 
 def _kda_step(s, decay, k, q, v, beta):

@@ -227,6 +227,136 @@ def test_padding_is_g_zero_and_beta_zero():
     assert _close(got, want) < 1e-6
 
 
+# ---- the Pallas launch, interpreted on the CPU, at heads whose keys and values
+# ---- are whole 128-lane tiles (the tests above run dk 8 / dv 12: the XLA form)
+
+
+def _launch_inputs(T, seed=1, strong=True, H=2, D=128):
+    q, k, v, g, beta = _kda_inputs(T, seed=seed, H=H, D=D, strong=strong)
+    return q, k, v[..., :D], g, beta
+
+
+def _launch(*a, **kw):
+    return ops.kda_chunk_scan(*a, interpret=True, **kw)
+
+
+@pytest.mark.parametrize("strong", [True, False], ids=["strong_gates", "weak_gates"])
+@pytest.mark.parametrize("T,chunk", [(64, 64), (100, 64), (256, 64), (40, 32), (8, 8)])
+def test_the_launch_is_the_recurrence_and_the_xla_form(T, chunk, strong):
+    """One chunk, a last chunk that is not full, the four chunks of a grid
+    step, smaller chunks (shallower hierarchies): against the token-by-token
+    recurrence at the XLA form's bound and against the XLA form itself, from
+    a state that is not zero."""
+    a = _launch_inputs(T, strong=strong)
+    s0 = jnp.asarray(np.random.default_rng(5).normal(size=(2, 128, 128)), jnp.float32)
+    want_o, want_s = reference.delta_rule(*a, state=s0)
+    o, s = _launch(*a, chunk=chunk, state=s0)
+    assert o.shape == want_o.shape and s.shape == want_s.shape == (2, 128, 128)
+    assert _close(o, want_o) < 2e-5 and _close(s, want_s) < 2e-5
+    xla_o, xla_s = ops.kda_chunk_scan(*a, chunk=chunk, state=s0)
+    assert _close(o, xla_o) < 2e-5 and _close(s, xla_s) < 2e-5
+
+
+def test_the_launch_carries_its_state_and_starts_from_zeros():
+    a = _launch_inputs(150, seed=3)
+    want_o, want_s = reference.delta_rule(*a)
+    first, second = (tuple(t[part] for t in a) for part in (slice(None, 70), slice(70, None)))
+    o1, s1 = _launch(*first, chunk=64)
+    o2, s2 = _launch(*second, chunk=64, state=s1)
+    assert _close(jnp.concatenate([o1, o2]), want_o) < 2e-5 and _close(s2, want_s) < 2e-5
+
+
+def test_the_launch_leaves_the_state_as_it_was_under_padding():
+    """g = 0 and beta = 0 past position 77: the state after a bucket of 128
+    is the state after 77, and a whole chunk of padding changes nothing."""
+    q, k, v, g, beta = _launch_inputs(128, seed=4)
+    _, want = _launch(q[:77], k[:77], v[:77], g[:77], beta[:77], chunk=64)
+    real = jnp.arange(128) < 77
+    g, beta = jnp.where(real[:, None, None], g, 0.0), jnp.where(real[:, None], beta, 0.0)
+    _, got = _launch(q, k, v, g, beta, chunk=64)
+    assert _close(got, want) < 1e-6
+    _, after = _launch(q[:64], k[:64], v[:64], 0 * g[:64], 0 * beta[:64], chunk=64, state=got)
+    assert bool((after == got).all())
+
+
+def test_the_launch_takes_no_exponent_of_a_positive_sum():
+    """Decays of e^-5 a step for whole chunks of 64 (exp(-G_j) would be
+    e^320) and beta within 1e-3 of 2 at every position: finite, and the
+    recurrence's."""
+    T = 128
+    q, k, v, _, beta = _launch_inputs(T, seed=6)
+    g = jnp.full((T, 2, 128), -5.0)
+    beta = 2.0 - 1e-3 * beta / 2
+    want_o, want_s = reference.delta_rule(q, k, v, g, beta)
+    o, s = _launch(q, k, v, g, beta, chunk=64)
+    assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(s).all())
+    assert _close(o, want_o) < 2e-5 and _close(s, want_s) < 2e-5
+    # ... and without any decay, the keys of a chunk nearly one direction:
+    # (I + A)^-1 has entries of alternating sign that do not die away
+    k = k[:1] + 0.05 * k
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    want_o, want_s = reference.delta_rule(q, k, v, 0 * g, beta)
+    o, s = _launch(q, k, v, 0 * g, beta, chunk=64)
+    assert _close(o, want_o) < 2e-5 and _close(s, want_s) < 2e-5
+
+
+def test_the_launch_is_chosen_from_backend_and_shape(monkeypatch):
+    """On the CPU the XLA form runs whatever the shape; the launch takes
+    keys of one 128-lane tile and values of whole ones, heads in pairs and a
+    chunk that is a power of two from 8 to 64; outside them `interpret` runs
+    the XLA form too."""
+    from ray_tpu.ops import ssm
+
+    assert ssm.kda_scan_tiles(64, 128, 128, 64) and not ssm.kda_scan_in_kernel(64, 128, 128, 64)
+    assert ssm.kda_scan_tiles(2, 128, 256, 8)
+    for heads, dk, dv, chunk in [(64, 64, 128, 64), (64, 256, 128, 64), (64, 128, 96, 64),
+                                 (3, 128, 128, 64), (64, 128, 128, 48), (64, 128, 128, 4),
+                                 (64, 128, 128, 128)]:
+        assert not ssm.kda_scan_tiles(heads, dk, dv, chunk)
+    launches = []
+    monkeypatch.setattr(ssm, "_kda_scan_launch", lambda *a, **kw: launches.append(kw) or 1 / 0)
+    a = _launch_inputs(16)
+    ops.kda_chunk_scan(*a, chunk=8)                          # the CPU: no launch
+    small = _kda_inputs(16)                                  # dk 8, dv 12
+    ops.kda_chunk_scan(*small, chunk=8, interpret=True)
+    ops.kda_chunk_scan(*_launch_inputs(16, H=3), chunk=8, interpret=True)
+    assert not launches
+    with pytest.raises(ZeroDivisionError):
+        ops.kda_chunk_scan(*a, chunk=8, interpret=True)
+    assert launches == [dict(chunk=8, interpret=True)]
+    with pytest.raises(ValueError, match="sub-block"):
+        ops.kda_chunk_scan(*a, chunk=20, interpret=True)
+
+
+def test_the_gradient_through_the_mixer_is_the_xla_forms(monkeypatch):
+    """`transformer.forward` differentiates the mixer in a train step: the
+    launch's `custom_vjp` hands back the XLA form's own gradient."""
+    import functools
+
+    cfg = _cfg(ssm=transformer.KDAConfig(n_heads=2, d_head=128, gate_rank=8, chunk=16))
+    p = jax.tree.map(lambda x: x.astype(jnp.float32),
+                     transformer._kda_params(cfg, jax.random.PRNGKey(0)))
+    x = jax.random.normal(jax.random.PRNGKey(1), (40, cfg.d_model), jnp.float32)
+
+    def loss(p, x):
+        y, state, _ = transformer.kda_mixer(x, p, cfg, length=jnp.int32(33))
+        return (y ** 2).sum() + (state ** 2).sum()
+
+    want_value, want = jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+    launches = []
+    real = ops.kda_chunk_scan
+
+    def launch(*a, **kw):
+        launches.append(kw)
+        return real(*a, interpret=True, **kw)
+
+    monkeypatch.setattr(ops, "kda_chunk_scan", launch)
+    got_value, got = jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+    assert len(launches) == 1 and float(abs(got_value - want_value)) < 1e-5 * float(want_value)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert _close(g, w) < 1e-4
+
+
 @pytest.mark.parametrize("n", [1, 2, 3, CHUNK - 1, CHUNK, CHUNK + 1, 45])
 def test_a_padded_bucket_leaves_state_and_tail_as_at_n(model, n):
     """Padding to a bucket must not advance the recurrent state, and the
@@ -547,6 +677,7 @@ def test_the_engine_serves_it_in_chunks_and_counts_what_it_ran(share):
     # 32 (11 real) for the long prompt, 32 (20 real) for the short one
     assert stats["prefill"]["scan_positions"] == LK * 128
     assert stats["prefill"]["scan_padded"] == LK * (128 - 95)
+    assert stats["prefill"]["scan_kernel_positions"] == 0      # the CPU: the XLA form
     experts = stats["experts"]
     assert 0 < experts["slots_held"] < experts["slots_routed"]
     assert experts["groups_with_rows"] > 0 and experts["calls"] % cfg.n_layers == 0

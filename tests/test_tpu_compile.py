@@ -464,6 +464,16 @@ def test_kernel_names_reach_the_compiled_program(chip):
     for shape in [(16, 8, 4, 64, 64, 32), (16, 16, 1, 128, 64, 16)]:   # either launch
         assert _kernel_calls(_ragged_launch(chip, shape).compile().as_text()) == [
             "ragged_paged_attention"]
+    # the chunked delta rule of a 2,048-token chunk, solar-open2-250b's heads
+    from ray_tpu.ops import ssm
+
+    def sds(*s):
+        return jax.ShapeDtypeStruct(s, jnp.float32, sharding=chip)
+
+    T, H, D = 2048, 64, 128
+    scan = jax.jit(lambda *a: ssm._kda_chunk_scan_pallas(*a, 64, ssm.KDA_SUB, False)).lower(
+        sds(T, H, D), sds(T, H, D), sds(T, H, D), sds(T, H, D), sds(T, H), sds(H, D, D))
+    assert _kernel_calls(scan.compile().as_text()) == ["kda_chunk_scan"]
 
 
 # ---- granite-4.0-h-micro.chat-saturated: a recurrent state a slot beside pages
@@ -657,6 +667,48 @@ def test_chunk_programs_attend_in_the_flash_launch(chip, cell, chunk, span, laun
     assert _kernel_calls(compiled.as_text()).count("flash_prefix_attention") == launches
     if launches:
         assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+
+
+def test_solar_chunk_program_scans_in_one_launch_and_drops_its_temporaries(chip, monkeypatch):
+    """`solar-open2-250b.reasondoc-saturated`: the 2,048-token continuation
+    over a 32,768-token prefix, as the CPU's backend traces it (the chunked
+    delta rule in plain XLA: the pairwise decays, the Gram matrices and the
+    triangular systems of all 32 chunks are temporaries in HBM) and as the
+    chip's does (one `kda_chunk_scan` launch in the body of the KDA layers'
+    scan, those kept in VMEM): 1,107,490,304 -> 601,015,808 bytes of
+    temporaries by the compiler's account."""
+    from chipbench import harness, program
+    from ray_tpu.models import decoding
+    from ray_tpu.models import decoding_paged as dp
+    from ray_tpu.ops import ssm
+
+    conf = harness.resolve_cell("solar-open2-250b.reasondoc-saturated")["config_file"]
+    cfg = program.transformer_config(conf["program"])
+    params, _ = _abstract_step_inputs(chip, cfg, 2, 128, 8, 64)
+
+    def sds(s, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(s, dt, sharding=chip)
+
+    tokens = sds((1, 2048))
+    kv = jax.eval_shape(lambda p, t, n: decoding.prefill(p, t, n, cfg)[1], params, tokens, sds(()))
+    row = jax.tree.map(lambda x: sds(x.shape, x.dtype), {"ssm": kv["ssm"], "conv": kv["conv"]})
+    prefix = sds((1, 32768, 8, 128), jnp.bfloat16)
+
+    def compiled():
+        dp.prefill_with_prefix.clear_cache()
+        return dp.prefill_with_prefix.lower(params, tokens, prefix, prefix, sds(()), sds(()),
+                                            cfg, row_state=row, kernel=True).compile()
+
+    xla = compiled()
+    assert "kda_chunk_scan" not in _kernel_calls(xla.as_text())
+    monkeypatch.setattr(ssm, "kda_scan_in_kernel", ssm.kda_scan_tiles)
+    try:
+        launch = compiled()
+    finally:
+        dp.prefill_with_prefix.clear_cache()
+    assert _kernel_calls(launch.as_text()).count("kda_chunk_scan") == 1
+    before, after = (c.memory_analysis().temp_size_in_bytes for c in (xla, launch))
+    assert before > 1.0e9 and after < 0.65e9
 
 
 def test_train_and_first_chunk_flash_programs_are_what_they_were():
