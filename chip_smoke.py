@@ -33,6 +33,7 @@ import functools
 import glob
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -67,7 +68,9 @@ KERNELS = dict(flash=(2, 20, 1024, 64),
                # the gated delta rule's state of solar-open2-250b, 32 slots
                kda_update=dict(layers=3, slots=32, heads=64, head_dim=128),
                # ... and its chunked scan over a 2,048-token chunk of the prefill
-               kda_scan=dict(tokens=2048, tail=1024, heads=64, head_dim=128, chunk=64))
+               kda_scan=dict(tokens=2048, tail=1024, heads=64, head_dim=128, chunk=64),
+               # ... and the mixer's elementwise work on either side of that scan
+               kda_mixer=dict(tokens=2048, heads=64, head_dim=128, d_conv=4, d_model=4096))
 
 
 class SmokeFailure(AssertionError):
@@ -375,8 +378,123 @@ def compare_kda_scan(shape: dict, interpret: bool = False) -> dict:
     return out
 
 
+def compare_kda_mixer(shape: dict, interpret: bool = False) -> dict:
+    """The KDA mixer's three launches around its scan (`kda_conv`,
+    `kda_split`, `kda_gate_norm`) against their XLA forms (`ops.causal_conv`
+    of a float32 copy, and the bodies of `transformer.kda_split` and
+    `kda_gated_norm` that the CPU and the decode step run) at a prefill chunk's
+    shape, the projection and the tail bfloat16, the tail not zero. Both
+    timed (not under `interpret`): each piece as the model calls it, on [T, H,
+    D] operands (alone, such an operand or result is re-laid from or to the
+    launches' [T, H D]; inside a program the reshapes cancel), each side of
+    the scan whole with its product (`in_qkv`, the convolutions and the split
+    before it; the gated norm and `out_proj` after it), and each launch alone
+    on [T, H D] operands."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import solar_open2_config, transformer
+    from ray_tpu.ops import ssm
+
+    T, H, D, K, E = (shape[k] for k in ("tokens", "heads", "head_dim", "d_conv", "d_model"))
+    cfg = solar_open2_config("tiny", d_model=E, dtype=jnp.bfloat16, ssm=transformer.KDAConfig(
+        n_heads=H, d_head=D, d_conv=K))
+    rng = np.random.default_rng(0)
+    arr = lambda *s, dt=jnp.bfloat16, by=1.0: jnp.asarray(  # noqa: E731
+        by * rng.standard_normal(s), dt)
+    x, tail = arr(T, E), arr(K - 1, 3 * H * D)
+    p = {"in_qkv": arr(E, 3 * H * D, by=E ** -0.5), "conv_w": arr(K, 3 * H * D, by=0.5),
+         "norm": arr(D, by=0.1) + 1, "out_proj": arr(H * D, E, by=0.02)}
+    o, gate = arr(T, H, D, dt=jnp.float32), arr(T, H, D, dt=jnp.float32, by=3.0)
+
+    def sides(launch: bool) -> dict:
+        """The jitted pieces, traced as the launches (`interpret` as given) or
+        as the XLA forms, and each one's operands and result."""
+        kw = dict(interpret=interpret and launch)
+
+        def conv(qkv, tail, w):
+            return transformer.kda_conv(qkv, tail, w, cfg, **kw)
+
+        def split(conved):
+            return transformer.kda_split(conved, cfg, **kw)
+
+        def gate_norm(o, gate, p):  # what out_proj multiplies
+            return transformer.kda_gated_norm(o, gate, p["norm"], cfg, **kw)
+
+        def before_scan(x, tail, p):
+            return split(conv(x @ p["in_qkv"], tail, p["conv_w"]))
+
+        def after_scan(o, gate, p):
+            return gate_norm(o, gate, p) @ p["out_proj"]
+
+        chooses = ssm.kda_mixer_in_kernel if launch else lambda *a: False
+        with mock.patch.object(ssm, "kda_mixer_in_kernel", chooses):
+            conved = jax.jit(conv)(qkv, tail, p["conv_w"])
+            made = {}
+            for fn, a in ((conv, (qkv, tail, p["conv_w"])), (split, (conved,)),
+                          (gate_norm, (o, gate, p)), (before_scan, (x, tail, p)),
+                          (after_scan, (o, gate, p))):
+                jitted = jax.jit(fn)
+                made[fn.__name__] = (jitted, a, jitted(*a))
+            made["jaxpr"] = str(jax.make_jaxpr(lambda: (before_scan(x, tail, p),
+                                                        after_scan(o, gate, p)))())
+        return made
+
+    qkv = jax.jit(lambda x, p: x @ p["in_qkv"])(x, p)
+    forms = {"launch": sides(True), "xla": sides(False)}
+    names = [n for n in forms["xla"] if n != "jaxpr"]
+    f32 = lambda t: jnp.asarray(t, jnp.float32)  # noqa: E731
+
+    def rel(name):
+        got, want = (jax.tree.leaves(forms[f][name][2]) for f in ("launch", "xla"))
+        return max(float(jnp.abs(f32(g) - f32(w)).max() / jnp.abs(f32(w)).max())
+                   for g, w in zip(got, want))
+
+    out = {"shape": dict(shape), "rel_err": {name: rel(name) for name in names},
+           "launched": {form: re.findall(r"name=(kda_conv|kda_split|kda_gate_norm)\b",
+                                         forms[form].pop("jaxpr")) for form in forms}}
+    # float32 sums on both sides: the order of a sum and a fused multiply-add.
+    # The gated norm's result is rounded to bfloat16 (an ulp: 2 ** -8), and
+    # behind a product so is what the launch reads: XLA hands its own consumer
+    # the product's float32 sums unrounded (`xla_allow_excess_precision`)
+    out["tol"] = {"conv": 1e-5, "split": 1e-5, "gate_norm": 2 ** -7,
+                  "before_scan": 2 ** -6, "after_scan": 2 ** -6}
+    out["max_abs_err"] = max(out["rel_err"].values())
+    out["ok"] = bool(all(out["rel_err"][n] <= out["tol"][n] for n in names)
+                     and out["launched"] == {
+                         "launch": ["kda_conv", "kda_split", "kda_gate_norm"], "xla": []})
+    if interpret:
+        return out
+
+    def ms(fn, *a, n=10):
+        jax.block_until_ready(fn(*a))
+        t0 = time.perf_counter()
+        for _ in range(n):
+            r = fn(*a)
+        jax.block_until_ready(r)
+        return (time.perf_counter() - t0) / n * 1e3
+
+    out["ms"] = {form: {name: ms(fn, *a) for name, (fn, a, _) in forms[form].items()}
+                 for form in forms}
+    conved = forms["launch"]["conv"][2]
+    out["ms"]["launch_alone"] = {
+        "conv": ms(jax.jit(functools.partial(ssm.kda_conv_launch, interpret=False)),
+                   qkv, tail, p["conv_w"]),
+        "split": ms(jax.jit(functools.partial(ssm.kda_split_launch, interpret=False)), conved),
+        "gate_norm": ms(jax.jit(functools.partial(
+            ssm.kda_gate_norm_launch, eps=cfg.norm_eps, dtype=cfg.dtype, interpret=False)),
+            o.reshape(T, -1), gate.reshape(T, -1), p["norm"])}
+    out["ms"]["in_qkv"] = ms(jax.jit(lambda x, p: x @ p["in_qkv"]), x, p)
+    out["ms"]["out_proj"] = ms(jax.jit(lambda y, p: y @ p["out_proj"]),
+                               forms["launch"]["gate_norm"][2], p)
+    return out
+
+
 def compare_kernels(flash, ragged, grouped=None, state_update=None, kda_update=None,
-                    kda_scan=None, interpret: bool = False) -> dict:
+                    kda_scan=None, kda_mixer=None, interpret: bool = False) -> dict:
     """The Pallas kernels against their pure-JAX references at the given
     shapes, bf16. `interpret` is for the CPU rehearsal only."""
     import jax
@@ -438,6 +556,8 @@ def compare_kernels(flash, ragged, grouped=None, state_update=None, kda_update=N
         out["kda_update"] = compare_kda_update(kda_update, interpret)
     if kda_scan:
         out["kda_scan"] = compare_kda_scan(kda_scan, interpret)
+    if kda_mixer:
+        out["kda_mixer"] = compare_kda_mixer(kda_mixer, interpret)
     return out
 
 

@@ -542,6 +542,12 @@ class TPUEngine:
         self.scan_kernel_positions = 0
         self._scan_kernel = bool(self.cfg.kda and mesh is None and ops.ssm.kda_scan_in_kernel(
             self.cfg.ssm.n_heads, self.cfg.ssm.d_head, self.cfg.ssm.d_head, self.cfg.ssm.chunk))
+        # ... and those whose mixer ran its elementwise work on either side of
+        # the scan in its three launches (`transformer.kda_mixer`): asked here
+        # at the smallest row block, and of each bucket where it is counted
+        self.mixer_kernel_positions = 0
+        self._mixer_kernel = bool(self.cfg.kda and mesh is None and ops.ssm.kda_mixer_in_kernel(
+            ops.ssm.MIXER_ROWS, self.cfg.ssm.d_head, self.cfg.ssm.d_conv))
         # decode attention is one ragged-paged-attention launch over the
         # batch's live page tables (ops/ragged_paged_attention.py): the
         # Pallas kernel where the code can see a TPU and an unsharded pool,
@@ -1636,6 +1642,9 @@ class TPUEngine:
             self.scan_padded += layers * (ran - n)
             if self._scan_kernel:
                 self.scan_kernel_positions += layers * ran
+            if self._mixer_kernel and ops.ssm.kda_mixer_tiles(
+                    bucket, self.cfg.ssm.d_head, self.cfg.ssm.d_conv):
+                self.mixer_kernel_positions += layers * ran
         return logits, kv
 
     def _go_live(self, req: _Request, logits, kv, wait: str) -> None:
@@ -2201,7 +2210,8 @@ class TPUEngine:
         if self.cfg.ssm:
             out["prefill"].update(scan_positions=self.scan_positions,
                                   scan_padded=self.scan_padded,
-                                  scan_kernel_positions=self.scan_kernel_positions)
+                                  scan_kernel_positions=self.scan_kernel_positions,
+                                  mixer_kernel_positions=self.mixer_kernel_positions)
         if self.prefill_chunk:
             out["prefill_chunk"] = self.prefill_chunk
             out["prefill_chunks_run"] = self.prefill_chunks_run

@@ -1072,22 +1072,68 @@ def _l2_normed(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
-def kda_split(qkv, cfg):
-    """Convolved qkv [T, 3 H D] after its SiLU -> (q, k, v [T, H, D] float32):
-    q and k of unit length a head (eps 1e-6 under the root), q times D ** -0.5."""
+def _kda_launches(T, cfg, interpret):
+    """Whether the mixer's elementwise work over T positions runs in
+    `ops.ssm`'s launches here (`interpret`: wherever the shapes tile: tests)."""
     s = cfg.ssm
-    qkv = jax.nn.silu(qkv).astype(jnp.float32)
-    q, k, v = (qkv[..., i * s.d_inner:(i + 1) * s.d_inner].reshape(
-        *qkv.shape[:-1], s.n_heads, s.d_head) for i in range(3))
-    return _l2_normed(q) * s.d_head ** -0.5, _l2_normed(k), v
+    return (ops.ssm.kda_mixer_in_kernel(T, s.d_head, s.d_conv)
+            or interpret and ops.ssm.kda_mixer_tiles(T, s.d_head, s.d_conv))
+
+
+def kda_conv(qkv, tail, w, cfg, *, interpret=False):
+    """The mixer's convolutions over a sequence: qkv [T, 3 H D] as projected,
+    after `tail` -> the float32 sums [T, 3 H D] before their SiLU. One Pallas
+    launch where `ops.ssm.kda_mixer_in_kernel` says so (`kda_conv` in a device
+    trace: the projection is read as it was written, in the activations'
+    dtype), `ops.causal_conv` of a float32 copy elsewhere."""
+    if _kda_launches(qkv.shape[0], cfg, interpret):
+        return ops.ssm.kda_conv(qkv, tail, w, interpret=interpret)
+    return ops.causal_conv(qkv.astype(jnp.float32), tail, w)
+
+
+def kda_split(qkv, cfg, *, interpret=False):
+    """Convolved qkv [T, 3 H D] after its SiLU -> (q, k, v [T, H, D] float32):
+    q and k of unit length a head (eps 1e-6 under the root), q times D ** -0.5.
+    A sequence's [T, 3 H D] float32 goes through one Pallas launch where
+    `ops.ssm.kda_mixer_in_kernel` says so (`kda_split` in a device trace; the
+    decode step's few rows never do)."""
+    s = cfg.ssm
+    heads = lambda x: x.reshape(*x.shape[:-1], s.n_heads, s.d_head)     # noqa: E731
+
+    def xla(qkv):
+        qkv = jax.nn.silu(qkv).astype(jnp.float32)
+        q, k, v = (heads(qkv[..., i * s.d_inner:(i + 1) * s.d_inner]) for i in range(3))
+        return _l2_normed(q) * s.d_head ** -0.5, _l2_normed(k), v
+
+    if qkv.ndim == 2 and qkv.dtype == jnp.float32 and _kda_launches(qkv.shape[0], cfg, interpret):
+        return ops.ssm.with_gradient_of(xla, lambda qkv: tuple(heads(x) for x in (
+            ops.ssm.kda_split_launch(qkv, interpret=interpret))), qkv)
+    return xla(qkv)
+
+
+def kda_gated_norm(o, gate, w, cfg, *, interpret=False):
+    """o [T, H, D] float32 (S_t^T q_t) through the RMS norm over each head's
+    values (weight w [D]), times sigmoid(gate) -> [T, H D] in the activations'
+    dtype: what out_proj multiplies. A sequence's goes through one Pallas
+    launch where `ops.ssm.kda_mixer_in_kernel` says so (`kda_gate_norm` in a
+    device trace), so that the product has no float32 producer fused in."""
+    flat = lambda x: x.reshape(*x.shape[:-2], cfg.ssm.d_inner)          # noqa: E731
+
+    def xla(o, gate, w):
+        return flat(ops.rms_norm(o, w, eps=cfg.norm_eps) * jax.nn.sigmoid(gate)).astype(cfg.dtype)
+
+    if (o.ndim == 3 and o.dtype == gate.dtype == jnp.float32
+            and _kda_launches(o.shape[0], cfg, interpret)):
+        return ops.ssm.with_gradient_of(xla, lambda o, gate, w: ops.ssm.kda_gate_norm_launch(
+            flat(o), flat(gate), w, eps=cfg.norm_eps, dtype=cfg.dtype, interpret=interpret),
+            o, gate, w)
+    return xla(o, gate, w)
 
 
 def kda_out(o, gate, p, cfg):
     """o [T, H, D] float32 (S_t^T q_t) through the RMS norm over each head's
     values, times sigmoid(gate), then out_proj -> [T, E]."""
-    y = ops.rms_norm(o, p["norm"], eps=cfg.norm_eps) * jax.nn.sigmoid(gate)
-    y = y.reshape(*y.shape[:-2], cfg.ssm.d_inner).astype(cfg.dtype)
-    return y @ p["out_proj"].astype(cfg.dtype)
+    return kda_gated_norm(o, gate, p["norm"], cfg) @ p["out_proj"].astype(cfg.dtype)
 
 
 def kda_mixer(x, p, cfg, length=None, state=None, tail=None):
@@ -1104,7 +1150,7 @@ def kda_mixer(x, p, cfg, length=None, state=None, tail=None):
         g, beta = jnp.where(real[:, None, None], g, 0.0), jnp.where(real[:, None], beta, 0.0)
     # the convolutions' sums and their SiLU stay float32 (the tails are kept
     # as projected, in the activations' dtype)
-    q, k, v = kda_split(ops.causal_conv(qkv.astype(jnp.float32), tail, p["conv_w"]), cfg)
+    q, k, v = kda_split(kda_conv(qkv, tail, p["conv_w"], cfg), cfg)
     o, state = ops.kda_chunk_scan(q, k, v, g, beta, chunk=s.chunk, state=state)
     return (kda_out(o, gate, p, cfg), state,
             ops.conv_tail(qkv, tail, T if length is None else length))

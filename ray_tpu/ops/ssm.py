@@ -89,6 +89,16 @@ u = beta (v - S^T k); S <- S + k u^T; o = S^T q`. The same two forms:
   `ssm_state_update` (`kda_state_update` in a device trace; `jax.numpy` on
   the layer's slice for `impl="reference"`, a row that is not live kept as it
   is).
+
+Around `kda_chunk_scan`'s launch the KDA mixer's elementwise work over a
+sequence runs in three more (`kda_conv`, `kda_split`, `kda_gate_norm` in a
+device trace), in the scan's own layout, where `kda_mixer_in_kernel` says so:
+the convolutions of the projection as it was written (bfloat16 in, float32
+sums out, the rows before a block read from the block before it), the SiLU
+with q's and k's norms a head, and after the scan the gated norm a head,
+written in the activations' dtype for `out_proj`. Their XLA forms are the
+callers' (`causal_conv`, and `kda_split` and `kda_out` of
+`models/transformer.py`), which choose.
 """
 
 from __future__ import annotations
@@ -604,14 +614,17 @@ def kda_scan_tiles(heads: int, dk: int, dv: int, chunk: int) -> bool:
             and 8 <= chunk <= 64 and chunk & (chunk - 1) == 0)
 
 
+def _on_one_tpu() -> bool:
+    """On a TPU, outside a mesh (GSPMD cannot partition a Mosaic kernel)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return jax.default_backend() == "tpu" and (mesh.empty or mesh.size == 1)
+
+
 def kda_scan_in_kernel(heads: int, dk: int, dv: int, chunk: int) -> bool:
     """Whether `kda_chunk_scan` runs these shapes in its Pallas launch HERE:
-    on a TPU, outside a mesh (GSPMD cannot partition a Mosaic kernel), where
-    they tile; as `ops.attention._flash_ok` chooses the flash kernel. The
-    engine asks it once, for its counter."""
-    mesh = jax.sharding.get_abstract_mesh()
-    return (jax.default_backend() == "tpu" and (mesh.empty or mesh.size == 1)
-            and kda_scan_tiles(heads, dk, dv, chunk))
+    on a TPU, outside a mesh, where they tile; as `ops.attention._flash_ok`
+    chooses the flash kernel. The engine asks it once, for its counter."""
+    return _on_one_tpu() and kda_scan_tiles(heads, dk, dv, chunk)
 
 
 def kda_chunk_scan(q, k, v, g, beta, *, chunk: int, sub: int = KDA_SUB, state=None,
@@ -643,6 +656,210 @@ def kda_chunk_scan(q, k, v, g, beta, *, chunk: int, sub: int = KDA_SUB, state=No
     else:
         o, state = _kda_chunk_scan_xla(q, k, v, g, beta, state, chunk=chunk, sub=sub)
     return o[:T], state
+
+
+# ---- the KDA mixer's elementwise work on either side of the scan's launch:
+# ---- three small launches in the layout the scan's launch takes, [T, H D]
+# ---- with a head's 128 columns one lane tile. What XLA makes of the same
+# ---- arithmetic writes the projection twice (bfloat16 and float32), re-lays
+# ---- the squares to [T / 8, 8, H, D] for each norm's sum and broadcasts the
+# ---- roots back through HBM (PERF.md section 6, PR 53). The XLA forms are the
+# ---- callers' (`causal_conv` here, `kda_split` and `kda_out` of
+# ---- models/transformer.py): the CPU's path, the decode step's, the reference
+# ---- of each launch and what its gradient is taken through.
+
+_LANES = 128       # a head of the launches: one lane tile
+MIXER_ROWS = 128   # the smallest row block of the launches
+_STRIP = 32        # rows a loop step of a launch's body holds in registers
+# a launch's block: the largest of these rows that divides T by as many of
+# these lane tiles as divide the columns. On the chip at [2048, 64 x 128] the
+# launches read 0.51, 0.65 and 0.28 ms alone, 590-620 GB/s each, and no block
+# from a quarter to four times these moved one by more than 5 % (PERF.md
+# section 6, PR 53)
+_BLOCK_ROWS, _BLOCK_TILES = (512, 256, MIXER_ROWS), (4, 2, 1)
+
+
+def kda_mixer_tiles(T: int, d_head: int, d_conv: int) -> bool:
+    """Whether the mixer's launches can take T positions of these heads: a
+    head one 128-lane tile, whole row blocks of at least 128 positions (every
+    prefill bucket from 128 up; a decode step's few rows are not), and a
+    convolution whose tail fits the eight rows a block sees before itself."""
+    return (d_head == _LANES and T >= MIXER_ROWS and T % MIXER_ROWS == 0
+            and 1 <= d_conv - 1 <= 8)
+
+
+def kda_mixer_in_kernel(T: int, d_head: int, d_conv: int) -> bool:
+    """Whether the KDA mixer's convolutions, split and gated norm run in
+    their Pallas launches HERE: on a TPU, outside a mesh, where they tile (as
+    `kda_scan_in_kernel`, which does not depend on T). The engine asks it
+    once, at the smallest block, for its counter."""
+    return _on_one_tpu() and kda_mixer_tiles(T, d_head, d_conv)
+
+
+def _largest(options, n: int) -> int:
+    return next(o for o in options if n % o == 0)
+
+
+def _block(T: int, width: int) -> tuple:
+    """(rows, columns) of a launch's block over [T, width]."""
+    return _largest(_BLOCK_ROWS, T), _LANES * _largest(_BLOCK_TILES, width // _LANES)
+
+
+def _tiles(ref):
+    """The lane tiles of a block: a head each."""
+    return (slice(h, h + _LANES) for h in range(0, ref.shape[1], _LANES))
+
+
+def _strips(rows: int, body, carry=0):
+    """`body(the strip's rows, carry)` over a block's rows, `_STRIP` at a time."""
+    def one(i, carry):
+        return body(pl.ds(pl.multiple_of(i * _STRIP, _STRIP), _STRIP), carry)
+
+    return jax.lax.fori_loop(0, rows // _STRIP, one, carry)
+
+
+def with_gradient_of(xla, launch, *operands):
+    """`launch(*operands)`, differentiated as `xla(*operands)` (which returns
+    the same shapes): the launches have no backward pass of their own, as
+    `kda_chunk_scan`'s has none."""
+    run = jax.custom_vjp(launch)
+    run.defvjp(lambda *operands: (launch(*operands), operands),
+               lambda operands, cotangents: jax.vjp(xla, *operands)[1](cotangents))
+    return run(*operands)
+
+
+def _kda_conv_kernel(x_ref, halo_ref, tail_ref, w_ref, o_ref):
+    """A block [rows, cols] of the depthwise causal convolution: position t
+    sums w[K - 1 - s] x[t - s] over s < K, the oldest input first as
+    `causal_conv` does. A strip's shifted inputs are sublane rotations of the
+    strip behind the eight rows before it; those come from the strip before
+    (carried), at a block's start from the block before (`halo_ref`: its last
+    sixteen rows) and at the row's start from `tail_ref` ([8, cols], the tail
+    in its last K - 1 rows)."""
+    K = w_ref.shape[0]
+    w = [w_ref[j:j + 1, :] for j in range(K)]
+    before = jnp.where(pl.program_id(1) == 0, tail_ref[...],
+                       halo_ref[...].astype(F32)[-8:])
+
+    def strip(rows, before):
+        x = x_ref[rows, :].astype(F32)
+        behind = jnp.concatenate([before, x], axis=0)
+        out = pltpu.roll(behind, K - 1, 0)[8:] * w[0]
+        for j in range(1, K - 1):
+            out = out + pltpu.roll(behind, K - 1 - j, 0)[8:] * w[j]
+        o_ref[rows, :] = out + x * w[K - 1]
+        return x[-8:]
+
+    _strips(x_ref.shape[0], strip, before)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",), inline=True)
+def kda_conv_launch(x, tail, w, *, interpret: bool):
+    """x [T, C] as projected (bfloat16 on the chip), tail [K - 1, C], w [K, C]
+    -> the convolution's float32 sums [T, C], before the SiLU. Grid (column
+    blocks of a few lane tiles, row blocks). Jitted for its trace cache, as `_kda_scan_launch`."""
+    T, C = x.shape
+    K = w.shape[0]
+    rows, cols = _block(T, C)
+    at = lambda c, r: (r, c)                                            # noqa: E731
+    top = lambda c, r: (0, c)                                           # noqa: E731
+    return pl.pallas_call(
+        _kda_conv_kernel,
+        out_shape=jax.ShapeDtypeStruct((T, C), F32),
+        grid=(C // cols, T // rows),
+        in_specs=[pl.BlockSpec((rows, cols), at),
+                  # the sixteen rows (a bfloat16 tile) before the block
+                  pl.BlockSpec((16, cols), lambda c, r: (jnp.maximum(r * (rows // 16) - 1, 0), c)),
+                  pl.BlockSpec((8, cols), top), pl.BlockSpec((K, cols), top)],
+        out_specs=pl.BlockSpec((rows, cols), at),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="kda_conv",
+    )(x, x, jnp.pad(tail.astype(F32), ((8 - (K - 1), 0), (0, 0))), w.astype(F32))
+
+
+def kda_conv(x, tail, w, *, interpret: bool = False):
+    """`causal_conv(x.astype(float32), tail, w)` of the KDA mixer's projection
+    as one Pallas launch (`kda_conv` in a device trace): x is read as
+    projected and the float32 sums are written once; no concatenation with
+    the tail and no float32 copy of x. For shapes that `kda_mixer_tiles`."""
+    return with_gradient_of(lambda x, tail, w: causal_conv(x.astype(F32), tail, w),
+                            functools.partial(kda_conv_launch, interpret=interpret), x, tail, w)
+
+
+def _kda_split_kernel(q_ref, k_ref, v_ref, qo_ref, ko_ref, vo_ref, *, scale: float):
+    """Blocks [rows, a few heads] of the convolved q, k and v: the SiLU, and q
+    and k divided by sqrt(|.|^2 + 1e-6) a head (a lane tile: the sum runs over
+    the lanes and its root goes back over them in registers), q times `scale`."""
+    def strip(rows, carry):
+        for x_ref, o_ref, by in ((q_ref, qo_ref, scale), (k_ref, ko_ref, None)):
+            for lanes in _tiles(x_ref):
+                x = jax.nn.silu(x_ref[rows, lanes])
+                x = x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+                o_ref[rows, lanes] = x if by is None else x * by
+        vo_ref[rows, :] = jax.nn.silu(v_ref[rows, :])
+        return carry
+
+    _strips(q_ref.shape[0], strip)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",), inline=True)
+def kda_split_launch(x, *, interpret: bool):
+    """x [T, 3 H D] float32 (q | k | v, convolved) -> q, k, v [T, H D]
+    float32, the layout `_kda_scan_launch` takes. Grid (row blocks, blocks of
+    heads): three blocks of x in, three out."""
+    T, HD = x.shape[0], x.shape[1] // 3
+    rows, cols = _block(T, HD)
+
+    def part(i):  # q, k or v: the i-th third of the columns
+        return pl.BlockSpec((rows, cols), lambda r, h: (r, i * (HD // cols) + h))
+
+    return pl.pallas_call(
+        functools.partial(_kda_split_kernel, scale=_LANES ** -0.5),
+        out_shape=[jax.ShapeDtypeStruct((T, HD), F32)] * 3,
+        grid=(T // rows, HD // cols),
+        in_specs=[part(0), part(1), part(2)],
+        out_specs=[part(0)] * 3,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="kda_split",
+    )(x, x, x)
+
+
+def _kda_gate_norm_kernel(o_ref, gate_ref, w_ref, y_ref, *, eps: float):
+    """Blocks [rows, a few heads]: the RMS norm over each head's values (a
+    lane tile) times the norm's weight, times sigmoid(gate), in float32; the
+    result in the activations' dtype."""
+    w = w_ref[...]
+
+    def strip(rows, carry):
+        for lanes in _tiles(o_ref):
+            o = o_ref[rows, lanes]
+            y = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) * w
+            y_ref[rows, lanes] = (y * jax.nn.sigmoid(gate_ref[rows, lanes])).astype(y_ref.dtype)
+        return carry
+
+    _strips(o_ref.shape[0], strip)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "dtype", "interpret"), inline=True)
+def kda_gate_norm_launch(o, gate, w, *, eps: float, dtype, interpret: bool):
+    """o, gate [T, H D] float32, w [D] -> [T, H D] in `dtype`: what `out_proj`
+    multiplies. Grid (row blocks, blocks of heads)."""
+    T, HD = o.shape
+    rows, cols = _block(T, HD)
+    at = lambda r, h: (r, h)                                            # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_kda_gate_norm_kernel, eps=eps),
+        out_shape=jax.ShapeDtypeStruct((T, HD), dtype),
+        grid=(T // rows, HD // cols),
+        in_specs=[pl.BlockSpec((rows, cols), at), pl.BlockSpec((rows, cols), at),
+                  pl.BlockSpec((1, _LANES), lambda r, h: (0, 0))],
+        out_specs=pl.BlockSpec((rows, cols), at),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="kda_gate_norm",
+    )(o, gate, w.astype(F32).reshape(1, _LANES))
 
 
 def _kda_step(s, decay, k, q, v, beta):

@@ -38,7 +38,8 @@ TINY_KERNELS = dict(flash=(1, 2, 128, 64), interpret=True,
                     grouped=dict(tiny=dict(tokens=64, top_k=2, experts=4, layers=2,
                                            d_model=256, d_ff=128)),
                     kda_update=dict(layers=2, slots=4, heads=2, head_dim=128),
-                    kda_scan=dict(tokens=128, tail=64, heads=2, head_dim=128, chunk=32))
+                    kda_scan=dict(tokens=128, tail=64, heads=2, head_dim=128, chunk=32),
+                    kda_mixer=dict(tokens=256, heads=2, head_dim=128, d_conv=4, d_model=64))
 
 
 def test_without_a_chip_the_script_fails_and_prints_no_result():
@@ -68,6 +69,7 @@ def test_rehearse_train_phase(cluster):
     assert facts["kernels"]["ragged"]["bit_equal"]
     assert len(facts["kernels"]["kda_update"]["steps"]) == 5
     assert facts["kernels"]["kda_scan"]["ok"] and "ms" not in facts["kernels"]["kda_scan"]
+    assert facts["kernels"]["kda_mixer"]["ok"] and "ms" not in facts["kernels"]["kda_mixer"]
     assert sum(k.startswith("grouped_tiny_") for k in facts["kernels"]) == 4
     chip_smoke.check_train_run(facts["runs"][0], min_steps=3, want_kernel=False)
     # a CPU worker is what the script exists to refuse

@@ -357,6 +357,222 @@ def test_the_gradient_through_the_mixer_is_the_xla_forms(monkeypatch):
         assert _close(g, w) < 1e-4
 
 
+# ---- the mixer's elementwise work on either side of the scan: three launches
+# ---- (`kda_conv`, `kda_split`, `kda_gate_norm`), interpreted on the CPU, and
+# ---- the XLA forms they stand for (`ops.causal_conv`, and the bodies of
+# ---- `kda_split` and `kda_out` that run wherever the launches do not)
+
+
+def _wide(H=2, D=128, dtype=jnp.float32):
+    """Heads of one 128-lane tile, which the launches take."""
+    return dataclasses.replace(_cfg(ssm=transformer.KDAConfig(
+        n_heads=H, d_head=D, gate_rank=8, chunk=16)), dtype=dtype)
+
+
+def _normal(seed, *shape, dtype=jnp.float32):
+    return jnp.asarray(np.random.default_rng(seed).normal(size=shape), dtype)
+
+
+@pytest.fixture
+def launches(monkeypatch):
+    """Counts the launches traced, by name."""
+    from ray_tpu.ops import ssm
+
+    seen = []
+    for name in ("kda_conv_launch", "kda_split_launch", "kda_gate_norm_launch"):
+        real = getattr(ssm, name)
+        monkeypatch.setattr(ssm, name, lambda *a, _real=real, _name=name, **kw: (
+            seen.append(_name[:-len("_launch")]), _real(*a, **kw))[1])
+    return seen
+
+
+@pytest.fixture
+def mixer_as_on_chip(monkeypatch, launches):
+    """`kda_mixer` as a TPU's backend traces it, its launches interpreted."""
+    from ray_tpu.ops import ssm
+
+    monkeypatch.setattr(ssm, "kda_mixer_in_kernel", ssm.kda_mixer_tiles)
+    for name in ("kda_conv_launch", "kda_split_launch", "kda_gate_norm_launch"):
+        counted = getattr(ssm, name)
+        monkeypatch.setattr(ssm, name, lambda *a, interpret, _counted=counted, **kw: _counted(
+            *a, interpret=True, **kw))
+    return launches
+
+
+@pytest.fixture
+def predicates_as_on_chip(monkeypatch):
+    """The predicates of `ops/ssm.py` see a TPU; nothing else does, and no
+    kernel runs."""
+    from ray_tpu.ops import ssm
+
+    monkeypatch.setattr(ssm, "jax", type("jax", (), {
+        "__getattr__": lambda _, name: getattr(jax, name),
+        "default_backend": staticmethod(lambda: "tpu")})())
+
+
+# (T, H, D): D = 128 in whole row blocks is a launch's; the rest (the tests'
+# own heads of 16, a bucket under a block, a bucket that is not whole blocks)
+# run the XLA form whatever `interpret` says
+MIXER_SHAPES = [(128, 2, 128), (256, 4, 128), (384, 3, 128), (1024, 1, 128),
+                (64, 4, 16), (64, 2, 128), (192, 2, 128)]
+
+
+def _launched(T, D):
+    return D == 128 and T % 128 == 0
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("T,H,D", MIXER_SHAPES)
+def test_the_convolutions_launch_is_causal_conv(launches, T, H, D, dtype):
+    """The projection as projected (either dtype), a tail that is not zero:
+    float32 sums, float32 out, `ops.causal_conv` of a float32 copy."""
+    cfg = _wide(H, D, dtype)
+    C = 3 * H * D
+    x, tail, w = _normal(1, T, C, dtype=dtype), _normal(2, 3, C, dtype=dtype), _normal(3, 4, C)
+    got = transformer.kda_conv(x, tail, w, cfg, interpret=True)
+    want = ops.causal_conv(x.astype(jnp.float32), tail, w)
+    assert got.dtype == want.dtype == jnp.float32 and got.shape == (T, C)
+    assert _close(got, want) < 1e-6
+    assert launches == ["kda_conv"] * _launched(T, D)
+    assert _close(transformer.kda_conv(x, tail, w, cfg), want) == 0.0   # the CPU: no launch
+    assert len(launches) == _launched(T, D)
+
+
+@pytest.mark.parametrize("T,H,D", MIXER_SHAPES)
+def test_the_split_launch_is_the_xla_form(launches, T, H, D):
+    cfg = _wide(H, D)
+    conved = 2.0 * _normal(4, T, 3 * H * D)
+    got = transformer.kda_split(conved, cfg, interpret=True)
+    want = transformer.kda_split(conved, cfg)
+    assert launches == ["kda_split"] * _launched(T, D)
+    for g, w in zip(got, want):
+        assert g.shape == (T, H, D) and g.dtype == w.dtype == jnp.float32
+        assert _close(g, w) < 1e-6
+    q, k, _ = got                                          # unit length a head, q times D^-1/2
+    assert _close(jnp.linalg.norm(k, axis=-1), jnp.ones((T, H))) < 1e-4
+    assert _close(jnp.linalg.norm(q, axis=-1) * D ** 0.5, jnp.ones((T, H))) < 1e-4
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("T,H,D", MIXER_SHAPES)
+def test_the_gated_norm_launch_is_the_xla_form(launches, T, H, D, dtype):
+    """... and what `out_proj` multiplies is in the activations' dtype: to
+    1e-6 in float32, to an ulp of the rounding in bfloat16."""
+    cfg = _wide(H, D, dtype)
+    o, gate = _normal(5, T, H, D), 3.0 * _normal(6, T, H, D)
+    w = 1.0 + 0.1 * _normal(7, D, dtype=dtype)
+    got = transformer.kda_gated_norm(o, gate, w, cfg, interpret=True)
+    want = transformer.kda_gated_norm(o, gate, w, cfg)
+    assert launches == ["kda_gate_norm"] * _launched(T, D)
+    assert got.dtype == want.dtype == dtype and got.shape == (T, H * D)
+    assert _close(got.astype(jnp.float32), want.astype(jnp.float32)) < (
+        1e-6 if dtype == jnp.float32 else 2 ** -8)
+
+
+def test_a_tail_carried_across_two_launches_is_one_launch(launches):
+    """The convolutions of a sequence in two spans, the second after the
+    first's last three inputs (`ops.conv_tail`), are those of the sequence in
+    one: across blocks of a launch (three of 128 rows), across launches, and
+    from a tail that ends inside the first span's padding."""
+    cfg = _wide(H=2, dtype=jnp.bfloat16)
+    C = 3 * 2 * 128
+    x, tail, w = (_normal(1, 640, C, dtype=jnp.bfloat16), _normal(2, 3, C, dtype=jnp.bfloat16),
+                  _normal(3, 4, C))
+    conv = lambda x, tail: transformer.kda_conv(x, tail, w, cfg, interpret=True)   # noqa: E731
+    whole = conv(x, tail)
+    first = conv(x[:384], tail)
+    second = conv(x[384:], ops.conv_tail(x[:384], tail, 384))
+    assert bool((jnp.concatenate([first, second]) == whole).all())
+    # the first span padded: 300 real positions in a bucket of 384
+    second = conv(x[300:556], ops.conv_tail(x[:384], tail, 300))
+    assert bool((second == whole[300:556]).all())
+    assert launches == ["kda_conv"] * 4
+
+
+def test_the_mixers_launches_are_chosen_from_shape(launches):
+    """On the CPU the XLA forms run whatever the shape; the launches take
+    heads of one 128-lane tile over whole row blocks of at least 128
+    positions; outside them `interpret` runs the XLA forms too, and so does
+    a decode step's batch of rows."""
+    from ray_tpu.ops import ssm
+
+    assert ssm.kda_mixer_tiles(2048, 128, 4) and ssm.kda_mixer_tiles(128, 128, 2)
+    assert not ssm.kda_mixer_in_kernel(2048, 128, 4)                 # the CPU
+    for T, D, K in [(32, 128, 4), (64, 128, 4), (192, 128, 4), (2048, 64, 4), (2048, 256, 4),
+                    (2048, 128, 1), (2048, 128, 10)]:
+        assert not ssm.kda_mixer_tiles(T, D, K)
+    cfg = _wide()
+    p = jax.tree.map(lambda x: x.astype(jnp.float32),
+                     transformer._kda_params(cfg, jax.random.PRNGKey(0)))
+    x = _normal(1, 128, cfg.d_model)
+    transformer.kda_mixer(x, p, cfg)                                  # the CPU: no launch
+    transformer.kda_mixer(x[:64], p, cfg)
+    # a decode step's rows [B, .] of a few slots, and a batch of rows [B, T, .]
+    transformer.kda_split(_normal(2, 32, 3 * 256), cfg, interpret=True)
+    transformer.kda_split(_normal(2, 2, 128, 3 * 256), cfg, interpret=True)
+    transformer.kda_gated_norm(_normal(3, 32, 2, 128), _normal(4, 32, 2, 128), p["norm"], cfg,
+                               interpret=True)
+    assert not launches
+    transformer.kda_split(_normal(2, 128, 3 * 256), cfg, interpret=True)
+    assert launches == ["kda_split"]
+
+
+def test_the_mixers_launches_are_chosen_from_backend_and_mesh(predicates_as_on_chip):
+    """Where the predicates see a TPU: whole row blocks outside a mesh, and
+    nothing inside one (GSPMD cannot partition a Mosaic kernel)."""
+    from ray_tpu.ops import ssm
+
+    assert ssm.kda_mixer_in_kernel(2048, 128, 4) and ssm.kda_mixer_in_kernel(128, 128, 4)
+    assert not ssm.kda_mixer_in_kernel(32, 128, 4) and not ssm.kda_mixer_in_kernel(2048, 64, 4)
+    with jax.set_mesh(jax.make_mesh((2,), ("tp",))):
+        assert not ssm.kda_mixer_in_kernel(2048, 128, 4)
+
+
+def test_length_inside_a_bucket_leaves_state_and_tail_as_at_length(mixer_as_on_chip):
+    """The mixer through its launches over a bucket of 128 with 77 real
+    positions: the state and the tail are those after 77 positions (the XLA
+    forms over exactly 77), the outputs of the real positions agree, and a
+    second span carries both on."""
+    cfg = _wide()
+    p = jax.tree.map(lambda x: x.astype(jnp.float32) + 0.05 * _normal(9, *x.shape),
+                     transformer._kda_params(cfg, jax.random.PRNGKey(0)))
+    x = _normal(1, 256, cfg.d_model)
+    want_y, want_state, want_tail = transformer.kda_mixer(x[:77], p, cfg)
+    assert not mixer_as_on_chip                                       # 77: the XLA forms
+    y, state, tail = transformer.kda_mixer(x[:128], p, cfg, length=jnp.int32(77))
+    assert mixer_as_on_chip == ["kda_conv", "kda_split", "kda_gate_norm"]
+    assert _close(y[:77], want_y) < 1e-5 and _close(state, want_state) < 1e-5
+    assert bool((tail == want_tail).all())
+    whole_y, whole_state, whole_tail = transformer.kda_mixer(x[:205], p, cfg)
+    y2, state2, tail2 = transformer.kda_mixer(x[77:205], p, cfg, None, state, tail)
+    assert len(mixer_as_on_chip) == 6
+    assert _close(y2, whole_y[77:]) < 1e-5 and _close(state2, whole_state) < 1e-5
+    assert bool((tail2 == whole_tail).all())
+
+
+def test_the_gradient_through_the_mixers_launches_is_the_xla_forms(request):
+    """As `test_the_gradient_through_the_mixer_is_the_xla_forms` for the three
+    launches around the scan (the scan in its XLA form here): each
+    `custom_vjp` hands back its XLA form's own gradient, also under the `vmap`
+    over a batch's rows that `transformer.forward` takes the mixer in."""
+    cfg = _wide()
+    p = jax.tree.map(lambda x: x.astype(jnp.float32),
+                     transformer._kda_params(cfg, jax.random.PRNGKey(0)))
+    x = _normal(1, 2, 128, cfg.d_model)
+
+    def loss(p, x):
+        y, state, _ = jax.vmap(lambda row: transformer.kda_mixer(row, p, cfg, jnp.int32(101)))(x)
+        return (y ** 2).sum() + (state ** 2).sum()
+
+    want_value, want = jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+    launches = request.getfixturevalue("mixer_as_on_chip")
+    got_value, got = jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+    assert sorted(set(launches)) == ["kda_conv", "kda_gate_norm", "kda_split"]
+    assert float(abs(got_value - want_value)) < 1e-5 * float(want_value)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert _close(g, w) < 1e-4
+
+
 @pytest.mark.parametrize("n", [1, 2, 3, CHUNK - 1, CHUNK, CHUNK + 1, 45])
 def test_a_padded_bucket_leaves_state_and_tail_as_at_n(model, n):
     """Padding to a bucket must not advance the recurrent state, and the
@@ -682,3 +898,54 @@ def test_the_engine_serves_it_in_chunks_and_counts_what_it_ran(share):
     assert 0 < experts["slots_held"] < experts["slots_routed"]
     assert experts["groups_with_rows"] > 0 and experts["calls"] % cfg.n_layers == 0
     assert stats["decode_slot_steps"] >= 10 and stats["decode_steps"] >= 5
+
+
+# ---- what a TPU's backend would choose, asked on the CPU: the predicates of
+# ---- `ops/ssm.py` see a TPU, nothing else does (no kernel runs)
+
+
+@pytest.mark.parametrize("family", ["dense", "mixtral_style", "state_space", "kda"])
+def test_engines_of_other_families_build_where_the_predicates_see_a_tpu(
+        predicates_as_on_chip, family):
+    """What a replica of every configuration runs in `TPUEngine.__init__` on
+    the chip and no CPU test saw (ledger, PR 52: the first serve cell's
+    replica, Mixtral's, did not come up): with the backend test of the KDA
+    launches' predicates forced true, a dense, a Mixtral-style and a
+    state-space (not KDA) configuration build their engines, their counters
+    of the KDA launches stay 0 and off, and a KDA configuration of heads that
+    tile turns both on."""
+    from ray_tpu.models import llama_config
+    from ray_tpu.ops import ssm
+
+    sizes = dict(vocab_size=VOCAB, max_seq_len=1024, dtype=jnp.float32)
+    cfg = {
+        "dense": lambda: llama_config("tiny", **sizes),
+        "mixtral_style": lambda: dataclasses.replace(
+            llama_config("tiny", **sizes),
+            moe=transformer.MoEConfig(num_experts=4, top_k=2, capacity_factor=None)),
+        "state_space": lambda: granite_config("tiny", **sizes),
+        "kda": lambda: _wide(H=2),
+    }[family]()
+    assert ssm.kda_mixer_in_kernel(128, 128, 4) and ssm.kda_scan_in_kernel(2, 128, 128, 64)
+    eng = _engine(cfg, transformer.init(jax.random.PRNGKey(0), cfg))
+    try:
+        stats = eng.stats()
+        assert (eng._scan_kernel, eng._mixer_kernel) == ((family == "kda"),) * 2
+        assert eng.mixer_kernel_positions == eng.scan_kernel_positions == 0
+        assert stats["prefill"].get("mixer_kernel_positions", 0) == 0
+        assert ("mixer_kernel_positions" in stats["prefill"]) == (cfg.ssm is not None)
+    finally:
+        eng.shutdown()
+
+
+def test_importing_the_ops_initialises_no_backend():
+    """Nothing of `ray_tpu.ops` runs at import: a replica's process asks for
+    its chip when its engine is built, not when a module is loaded."""
+    import subprocess
+
+    code = ("import ray_tpu.ops, ray_tpu.models.transformer; from jax._src import xla_bridge; "
+            "assert not xla_bridge.backends_are_initialized(), 'a backend was initialised'")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", code], cwd=root, capture_output=True, text=True,
+                       timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0, r.stderr[-2000:]

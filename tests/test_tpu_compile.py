@@ -711,6 +711,60 @@ def test_solar_chunk_program_scans_in_one_launch_and_drops_its_temporaries(chip,
     assert before > 1.0e9 and after < 0.65e9
 
 
+def test_solar_chunk_program_runs_the_mixer_around_its_scan_in_three_launches(chip, monkeypatch):
+    """The same continuation program with the mixer's predicate steered too,
+    as the chip's backend traces it since PR 53: the body of the KDA layers'
+    scan calls `kda_conv`, `kda_split`, `kda_chunk_scan` and `kda_gate_norm`
+    once each; `in_qkv` writes its bfloat16 product alone (the one float32
+    [2048, 24576] of the program is what `kda_conv` writes: XLA's product had
+    written both, 302 MB a layer); the temporaries are no larger than the
+    601,015,808 bytes they were (512,207,872). The decode program at the
+    cell's 32 slots is not touched: its rows are under a block of the
+    launches, whatever the backend."""
+    import re
+
+    from chipbench import harness, program
+    from ray_tpu.models import decoding
+    from ray_tpu.models import decoding_paged as dp
+    from ray_tpu.ops import ssm
+
+    conf = harness.resolve_cell("solar-open2-250b.reasondoc-saturated")["config_file"]
+    cfg, eng = program.transformer_config(conf["program"]), conf["engine"]
+    params, state = _abstract_step_inputs(chip, cfg, eng["max_slots"], 128, 8, eng["page_size"])
+
+    def sds(s, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(s, dt, sharding=chip)
+
+    tokens = sds((1, eng["prefill_chunk"]))
+    kv = jax.eval_shape(lambda p, t, n: decoding.prefill(p, t, n, cfg)[1], params, tokens, sds(()))
+    row = jax.tree.map(lambda x: sds(x.shape, x.dtype), {"ssm": kv["ssm"], "conv": kv["conv"]})
+    prefix = sds((1, 32768, 8, 128), jnp.bfloat16)
+    monkeypatch.setattr(ssm, "kda_scan_in_kernel", ssm.kda_scan_tiles)
+    monkeypatch.setattr(ssm, "kda_mixer_in_kernel", ssm.kda_mixer_tiles)
+    dp.prefill_with_prefix.clear_cache()
+    dp.decode_step_paged_ragged.clear_cache()
+    try:
+        chunk = dp.prefill_with_prefix.lower(params, tokens, prefix, prefix, sds(()), sds(()),
+                                             cfg, row_state=row, kernel=True).compile()
+        step = dp.decode_step_paged_ragged.lower(
+            params, state, cfg, eng["max_slots"], True).compile()
+    finally:
+        dp.prefill_with_prefix.clear_cache()
+        dp.decode_step_paged_ragged.clear_cache()
+    text = chunk.as_text()
+    assert _kernel_calls(text) == [
+        "kda_conv", "kda_split", "kda_chunk_scan", "kda_gate_norm",        # the KDA layers' scan
+        "grouped_matmul", "grouped_matmul", "grouped_matmul",
+        "flash_prefix_attention", "grouped_matmul", "grouped_matmul", "grouped_matmul"]
+    T, C = eng["prefill_chunk"], cfg.ssm.conv_dim
+    assert [op.split(".")[0] for op in re.findall(rf"%([\w.\-]+) = f32\[{T},{C}\]", text)] == [
+        "kda_conv"]
+    assert chunk.memory_analysis().temp_size_in_bytes <= 601_015_808
+    assert _kernel_calls(step.as_text()) == [
+        "kda_state_update", "grouped_matmul", "grouped_matmul", "grouped_matmul",
+        "ragged_paged_attention", "grouped_matmul", "grouped_matmul", "grouped_matmul"]
+
+
 def test_train_and_first_chunk_flash_programs_are_what_they_were():
     """The continuation's launch is a sibling, not a variant: the train
     step's three kernels (forward and backward at GPT-2 774M's heads) and the
