@@ -174,8 +174,6 @@ class TransformerConfig:
     bias: bool = False                     # attn/mlp biases (GPT-2 style)
     moe: MoEConfig | None = None
     remat: bool = True                     # checkpoint each layer (HBM for FLOPs)
-    remat_policy: str = "nothing"          # "nothing" | "dots" (save matmul outputs)
-                                           # | "pairs" (checkpoint every other layer)
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     norm_eps: float = 1e-6                 # RMSNorm epsilon of the block norms
@@ -1334,6 +1332,14 @@ def _moe_mlp(x, p, cfg):
     return y.reshape(B, T, E), aux
 
 
+# What a layer's checkpoint (cfg.remat) keeps for the backward pass: its carry
+# and, where its attention ran the flash kernels, their output and log-sum-exp
+# (ops/attention.py `_flash` names them), so the backward pass runs the layer
+# again but not the forward kernel. Every other layer names nothing and keeps
+# its carry alone. One object: a jaxpr's text carries the policy's identity
+_LAYER_KEEPS = jax.checkpoint_policies.save_only_these_names(*ops.FLASH_KEPT)
+
+
 def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = None,
             attn_impl: str | None = None, return_hidden: bool = False,
             return_exit: bool = False):
@@ -1377,53 +1383,22 @@ def forward(params, tokens, cfg: TransformerConfig, *, sp_axis: str | None = Non
             delta = _dense_mlp(normed, layer_p["mlp"], cfg)
         return (_residual(h, delta, layer_p, "post_mlp_norm", cfg), aux, gates), None
 
-    if cfg.remat and cfg.remat_policy == "pairs" and (
-            cfg.n_layers % 2 or cfg.moe or cfg.n_dense_layers or cfg.window
-            or cfg.n_passes > 1 or cfg.exit_gate or cfg.ssm):
-        raise ValueError(
-            "remat_policy='pairs' needs an even n_layers and a dense (non-"
-            "MoE) stack of one kind of layer; falling back silently would "
-            "misattribute benchmark results to selective remat")
-    if cfg.remat and cfg.remat_policy == "pairs":
-        # selective remat: scan over layer PAIRS, checkpointing only the
-        # first of each pair. Backward recomputes half the layers (full
-        # per-layer remat recomputes all of them — a 4-pass step with an
-        # MFU ceiling of 0.75), at the cost of keeping one layer's
-        # activations per pair live. Picked by on-hardware sweeps.
-        ck = jax.checkpoint(block, policy=jax.checkpoint_policies.nothing_saveable)
+    if cfg.remat:
+        inner = block
 
-        def pair(carry, pair_p):
-            a = jax.tree.map(lambda t: t[0], pair_p)
-            b = jax.tree.map(lambda t: t[1], pair_p)
-            carry, _ = ck(carry, a)
-            carry, _ = block(carry, b)
-            return carry, None
+        def block(carry, layer_p, **kind):
+            return jax.checkpoint(functools.partial(inner, **kind),
+                                  policy=_LAYER_KEEPS)(carry, layer_p)
+    gates = (jnp.zeros((cfg.n_passes,) + tokens.shape, jnp.float32)
+             if cfg.exit_gate else None)
 
-        stacked = jax.tree.map(
-            lambda t: t.reshape(t.shape[0] // 2, 2, *t.shape[1:]),
-            params["layers"])
-        (x, aux_total, gates), _ = jax.lax.scan(pair, (x, aux_total, None), stacked)
-        x, gates = close_pass(x, gates, 0, params, cfg)
-    else:
-        if cfg.remat:
-            policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                      if cfg.remat_policy == "dots"
-                      else jax.checkpoint_policies.nothing_saveable)
-            inner = block
+    def close(carry, t):
+        h, aux, gates = carry
+        h, gates = close_pass(h, gates, t, params, cfg)
+        return h, aux, gates
 
-            def block(carry, layer_p, **kind):
-                return jax.checkpoint(functools.partial(inner, **kind),
-                                      policy=policy)(carry, layer_p)
-        gates = (jnp.zeros((cfg.n_passes,) + tokens.shape, jnp.float32)
-                 if cfg.exit_gate else None)
-
-        def close(carry, t):
-            h, aux, gates = carry
-            h, gates = close_pass(h, gates, t, params, cfg)
-            return h, aux, gates
-
-        (x, aux_total, gates), _ = scan_layers(block, (x, aux_total, gates), params, cfg,
-                                               close=close)
+    (x, aux_total, gates), _ = scan_layers(block, (x, aux_total, gates), params, cfg,
+                                           close=close)
     leave = (exit_distribution(gates),) if return_exit else ()
     if return_hidden:
         return (x, aux_total) + leave

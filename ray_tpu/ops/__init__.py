@@ -1,5 +1,5 @@
 from ray_tpu.ops.activations import geglu, gelu, swiglu
-from ray_tpu.ops.attention import attention, repeat_kv
+from ray_tpu.ops.attention import FLASH_KEPT, attention, repeat_kv
 from ray_tpu.ops.flash_attention import (flash_attention, flash_attention_forward,
                                          flash_prefix_attention, prefix_blocks)
 from ray_tpu.ops.grouped_matmul import grouped_matmul
@@ -15,6 +15,7 @@ from ray_tpu.ops.ssm import (causal_conv, conv_tail, kda_chunk_scan, kda_state_u
                              live_rows, ssm_chunk_scan, ssm_state_update)
 
 __all__ = [
+    "FLASH_KEPT",
     "RoutingInfo",
     "Yarn",
     "apply_rope",

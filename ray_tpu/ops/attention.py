@@ -13,11 +13,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu._private.constants import (MESH_AXIS_DP, MESH_AXIS_FSDP,
                                         MESH_AXIS_TP)
-from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.flash_attention import (_fwd_call, flash_attention,
+                                         flash_attention_backward)
 from ray_tpu.parallel.ring_attention import reference_attention, ring_attention
 
 
@@ -27,6 +29,12 @@ def repeat_kv(k, *, n_rep: int):
         return k
     B, T, Hkv, D = k.shape
     return jnp.repeat(k, n_rep, axis=2)
+
+
+# what `_flash`'s forward rule names (jax.ad_checkpoint.checkpoint_name) for
+# a layer's checkpoint to keep: the kernel's output and log-sum-exp, all the
+# backward kernels need of the forward besides q, k and v
+FLASH_KEPT = ("flash_out", "flash_lse")
 
 
 def _flash_ok(q) -> bool:
@@ -41,11 +49,44 @@ def _flash_ok(q) -> bool:
     return jax.default_backend() == "tpu" and q.shape[1] >= 1024
 
 
-def _flash(q, k, v, *, causal: bool, scale: float | None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal: bool, scale: float | None, interpret: bool = False):
+    """The flash kernels on the model's own [B, T, H, D]. Where nothing
+    differentiates this is `flash_attention` between two transpositions; where
+    something does, the rule below is the kernels' differentiation boundary,
+    so that what the backward pass needs of the forward has a name a layer's
+    checkpoint can keep (models/transformer.py `forward`)."""
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     # block sizes: the kernels' own choice for this T (flash_attention.py)
-    out = flash_attention(qt, kt, vt, causal, scale)
+    out = flash_attention(qt, kt, vt, causal, scale, None, None, interpret)
     return out.transpose(0, 2, 1, 3)
+
+
+def _flash_fwd(q, k, v, causal, scale, interpret):
+    B, T, H, D = q.shape
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out, lse = _fwd_call(
+        qt, kt, vt, causal=causal, scale=D ** -0.5 if scale is None else scale,
+        block_q=None, block_k=None, interpret=interpret)
+    # named lane-dense: in the kernel's [B, H, T, 64] a stack of layers pads
+    # 64 lanes to 128, and the log-sum-exp as [B, H, T, 1] one lane to 128
+    out = checkpoint_name(out.transpose(0, 2, 1, 3).reshape(B, T, H * D), FLASH_KEPT[0])
+    lse = checkpoint_name(lse.reshape(B, H, T), FLASH_KEPT[1])
+    return out.reshape(B, T, H, D), (q, k, v, out, lse)
+
+
+def _flash_bwd(causal, scale, interpret, res, g):
+    q, k, v, out, lse = res
+    B, T, H, D = q.shape
+    qt, kt, vt, ot, gt = (x.transpose(0, 2, 1, 3)
+                          for x in (q, k, v, out.reshape(B, T, H, D), g))
+    grads = flash_attention_backward(
+        qt, kt, vt, ot, lse.reshape(B, H, T, 1), gt, causal=causal,
+        scale=D ** -0.5 if scale is None else scale, interpret=interpret)
+    return tuple(x.transpose(0, 2, 1, 3) for x in grads)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _flash_per_shard(q, k, v, *, causal: bool, scale: float | None):
@@ -60,7 +101,7 @@ def _flash_per_shard(q, k, v, *, causal: bool, scale: float | None):
     # shard_map already made manual (pp, sp programs) stay as they are
     names = frozenset(mesh.axis_names) - frozenset(mesh.manual_axes)
     if mesh.empty or mesh.size == 1 or not names:
-        return _flash(q, k, v, causal=causal, scale=scale)
+        return _flash(q, k, v, causal, scale)
     batch = tuple(a for a in (MESH_AXIS_DP, MESH_AXIS_FSDP) if a in names)
     heads = MESH_AXIS_TP if MESH_AXIS_TP in names else None
     spec = P(batch or None, None, heads, None)

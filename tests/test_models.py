@@ -8,6 +8,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import gpt2_config, llama_config, mixtral_config, transformer, vit, vit_config
 from ray_tpu.parallel import MeshSpec, param_shardings, shard_map
+from tests.test_ops import _walk_eqns
 
 
 def tiny_gpt2():
@@ -92,3 +93,55 @@ def test_param_counts_sane():
     cfg = gpt2_config("124m")
     n = cfg.num_params()
     assert 120e6 < n < 130e6, n
+
+
+@pytest.fixture
+def flash_layers(monkeypatch):
+    """A two-layer GPT-2 whose attention runs the flash kernels, interpreted
+    (`attn_impl="flash"`, the dispatcher's `_flash` with its trailing flag
+    set), as a TPU's layers do from 1,024 tokens up."""
+    import sys
+
+    attn = sys.modules["ray_tpu.ops.attention"]
+    real = attn._flash
+    monkeypatch.setattr(attn, "_flash", lambda q, k, v, causal, scale: real(
+        q, k, v, causal, scale, True))
+    cfg = gpt2_config("124m", vocab_size=256, max_seq_len=256, d_model=128,
+                      n_layers=2, n_heads=2, d_ff=256, dtype=jnp.float32)
+    params = transformer.init(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 257), 0, cfg.vocab_size)
+    return cfg, params, tokens[:, :-1].shape, lambda p, c=cfg: transformer.loss_fn(
+        p, tokens, c, attn_impl="flash")
+
+
+def test_layer_checkpoint_keeps_the_flash_output_and_runs_the_forward_kernel_once(
+        flash_layers):
+    """What the layer's checkpoint keeps: its carry and, of a layer whose
+    attention ran the flash kernels, their output and log-sum-exp. The
+    gradient program holds the forward kernel once (in the forward scan, which
+    hands both stacks to the backward scan) and not again under remat."""
+    cfg, params, (B, T), loss = flash_layers
+    L, E, H = cfg.n_layers, cfg.d_model, cfg.n_heads
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+    calls = [e.params["name"] for e in _walk_eqns(jaxpr) if e.primitive.name == "pallas_call"]
+    assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    forward, = [e for e in jaxpr.eqns if e.primitive.name == "scan" and any(
+        i.primitive.name == "pallas_call" and i.params["name"] == "flash_fwd"
+        for i in _walk_eqns(e.params["jaxpr"].jaxpr))]
+    stacked = [(v.aval.shape, str(v.aval.dtype)) for v in forward.outvars]
+    # the layers' carries and flash_out, lane-dense (H * D is E here), and
+    # flash_lse: nothing else of a layer's size is handed to the backward scan
+    assert sorted(shape for shape, _ in stacked if len(shape) == 4) == [
+        (L, B, H, T), (L, B, T, E), (L, B, T, E)]
+
+
+def test_layer_checkpoint_gradients_equal_those_without_it(flash_layers):
+    import dataclasses
+
+    cfg, params, _, loss = flash_layers
+    kept = jax.grad(loss)(params)
+    plain = jax.grad(loss)(params, dataclasses.replace(cfg, remat=False))
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(kept), jax.tree.leaves(plain)):
+        scale = float(jnp.abs(b).max()) or 1.0
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5 * scale, rtol=1e-5,
+                                   err_msg=jax.tree_util.keystr(path))

@@ -220,6 +220,69 @@ def test_flash_kernels_multiply_bf16_operands_as_stored():
             assert result == "float32", (name, result)
 
 
+def _model_layout_qkv(dtype):
+    """[B, T, H, D] operands of the dispatcher's `_flash` and the cotangent."""
+    B, T, H, D = 2, 256, 2, 64
+    return [jax.random.normal(k, (B, T, H, D), jnp.float32).astype(dtype)
+            for k in jax.random.split(jax.random.PRNGKey(7), 4)]
+
+
+@_DTYPES
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_rule_in_the_models_layout_equals_flash_attention(causal, dtype):
+    """`ops.attention._flash` is the kernels' differentiation boundary on
+    [B, T, H, D]: the same three launches as `flash_attention`'s own rule on
+    the transposed operands, so output and gradients are equal bit for bit,
+    differentiated or not."""
+    import sys
+
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    _flash = sys.modules["ray_tpu.ops.attention"]._flash
+    q, k, v, g = _model_layout_qkv(dtype)
+
+    def in_heads_major(q, k, v):
+        qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+        return flash_attention(qt, kt, vt, causal, None, None, None, True).transpose(0, 2, 1, 3)
+
+    def rule(q, k, v):
+        return _flash(q, k, v, causal, None, True)
+
+    want, want_vjp = jax.vjp(in_heads_major, q, k, v)
+    got, got_vjp = jax.vjp(rule, q, k, v)
+    assert got.dtype == dtype and got.shape == q.shape
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    np.testing.assert_array_equal(np.asarray(rule(q, k, v), np.float32),
+                                  np.asarray(want, np.float32))
+    for a, b, name in zip(got_vjp(g), want_vjp(g), "qkv"):
+        assert a.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32),
+                                      err_msg=f"d{name}")
+
+
+def test_flash_rule_names_what_the_backward_keeps_lane_dense():
+    """The forward rule names the output as [B, T, H * D] and the log-sum-exp
+    as [B, H, T]: what a layer's checkpoint keeps, in shapes whose last
+    dimension fills a 128-lane tile."""
+    import sys
+
+    _flash = sys.modules["ray_tpu.ops.attention"]._flash
+    q, k, v, _ = _model_layout_qkv(jnp.bfloat16)
+    B, T, H, D = q.shape
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: _flash(q, k, v, True, None, True).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))(q, k, v).jaxpr
+    named = {e.params["name"]: e.outvars[0].aval for e in _walk_eqns(jaxpr)
+             if e.primitive.name == "name"}
+    assert set(named) == set(ops.FLASH_KEPT) == {"flash_out", "flash_lse"}
+    assert (named["flash_out"].shape, str(named["flash_out"].dtype)) == (
+        (B, T, H * D), "bfloat16")
+    assert (named["flash_lse"].shape, str(named["flash_lse"].dtype)) == ((B, H, T), "float32")
+    assert sorted(e.params["name"] for e in _walk_eqns(jaxpr)
+                  if e.primitive.name == "pallas_call") == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+
 def test_attention_dispatcher_gqa():
     B, T, H, Hkv, D = 2, 32, 8, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(1), 3)

@@ -23,9 +23,9 @@ from ray_tpu.ops.ragged_paged_attention import ragged_decode_attention
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e chip, with the persistent compilation cache off:
-    an entry compiled for an absent chip is written but cannot be read back."""
+def topo():
+    """A described v5e:2x2, with the persistent compilation cache off: an
+    entry compiled for an absent chip is written but cannot be read back."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -36,9 +36,26 @@ def chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One chip of it."""
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def flash_as_on_chip(monkeypatch):
+    """`ops.attention._flash_ok` asks jax.default_backend(), the CPU here:
+    steered to what it answers on the chip."""
+    import sys
+
+    import ray_tpu.ops.attention  # noqa: F401
+    monkeypatch.setattr(sys.modules["ray_tpu.ops.attention"], "_flash_ok",
+                        lambda q: q.shape[1] % 256 == 0 and q.shape[1] >= 1024)
 
 
 def _compiled_text(fn, *shapes) -> str:
@@ -577,7 +594,7 @@ def test_granite_prefill_fits_beside_the_state(chip):
     assert "ssm_state_update" not in prefill.as_text()
 
 
-def test_trinity_chunk_and_check_prefill_fit_beside_the_weights(chip, monkeypatch):
+def test_trinity_chunk_and_check_prefill_fit_beside_the_weights(chip, flash_as_on_chip):
     """`trinity-large-preview.agent-saturated`: the largest chunk program (2,048
     tokens over a 32,768-token prefix, scores one KV head's group of 6 at a
     time) beside both pools, and the check's own unchunked prefill of a
@@ -585,14 +602,10 @@ def test_trinity_chunk_and_check_prefill_fit_beside_the_weights(chip, monkeypatc
     head's group at a time (1.6 GB of scores, not 12.9), the full layer in the
     flash kernel. chipbench/tests/test_trinity.py holds the whole account."""
     import math
-    import sys
 
-    import ray_tpu.ops.attention  # noqa: F401
     from chipbench import harness, program
     from ray_tpu.models import decoding, decoding_paged as dp
 
-    monkeypatch.setattr(sys.modules["ray_tpu.ops.attention"], "_flash_ok",
-                        lambda q: q.shape[1] % 256 == 0 and q.shape[1] >= 1024)
     conf = harness.resolve_cell("trinity-large-preview.agent-saturated")["config_file"]
     cfg, eng, aot = program.transformer_config(conf["program"]), conf["engine"], conf["aot"]
     params, state = _abstract_step_inputs(
@@ -763,6 +776,54 @@ def test_solar_chunk_program_runs_the_mixer_around_its_scan_in_three_launches(ch
     assert _kernel_calls(step.as_text()) == [
         "kda_state_update", "grouped_matmul", "grouped_matmul", "grouped_matmul",
         "ragged_paged_attention", "grouped_matmul", "grouped_matmul", "grouped_matmul"]
+
+
+@pytest.mark.parametrize("mesh_axes,chips", [({}, 1), ({"dp": 2, "fsdp": 2}, 4)],
+                         ids=["one_chip", "mesh_2x2"])
+def test_gpt2_large_train_step_runs_the_flash_forward_once_and_keeps_its_output(
+        topo, flash_as_on_chip, mesh_axes, chips):
+    """The step of `gpt2-large.train-1chip` (batch 4 x 1,024, AdamW): a
+    layer's checkpoint keeps the flash kernel's output and log-sum-exp
+    (`ops.FLASH_KEPT`), so the program holds three Pallas calls, the forward
+    once, under the signatures the benchmark's reader finds them by. On one
+    chip its bytes say that both stacks are kept and kept lane-dense: 15.18e9
+    with neither (the step before PR 56), 16.05e9 as built, 16.79e9 with the
+    output kept in the kernel's [B, H, T, 64], whose 64 lanes pad to 128.
+    Under a mesh the kernels run per shard in `_flash_per_shard`'s shard_map,
+    and the names have to reach the checkpoint through it."""
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from chipbench.trace_reduce import kernel_ops_from_hlo
+    from ray_tpu.models import gpt2_config, transformer
+    from ray_tpu.parallel import DEFAULT_RULES, MeshSpec, param_shardings
+    from ray_tpu.train.spmd import make_train_step
+
+    cfg = gpt2_config("774m")
+    mesh = MeshSpec(**mesh_axes).build(list(topo.devices[:chips]))
+    axes, opt = transformer.logical_axes(cfg), optax.adamw(3e-4)
+    step, _, batch_sharding = make_train_step(
+        lambda p, t: transformer.loss_fn(p, t, cfg), axes, mesh, opt)
+
+    def abstract(tree, shardings):
+        return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+                            tree, shardings)
+
+    p_sh = param_shardings(mesh, axes, DEFAULT_RULES)
+    params = abstract(jax.eval_shape(lambda k: transformer.init(k, cfg),
+                                     jax.random.PRNGKey(0)), p_sh)
+    o_shape = jax.eval_shape(opt.init, params)
+    o_sh = optax.tree_map_params(opt, lambda _, s: s, o_shape, p_sh,
+                                 transform_non_params=lambda _: NamedSharding(mesh, P()))
+    batch = jax.ShapeDtypeStruct((4, 1025), jnp.int32, sharding=batch_sharding)
+    compiled = step.lower(params, abstract(o_shape, o_sh), batch).compile()
+    assert sorted(kernel_ops_from_hlo(compiled.as_text()).values()) == [
+        "3in_2out", "6in_1out", "6in_2out"]
+    if chips == 1:
+        m = compiled.memory_analysis()
+        total = (m.argument_size_in_bytes + m.output_size_in_bytes
+                 - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert 15.9e9 < total < 16.3e9
 
 
 def test_train_and_first_chunk_flash_programs_are_what_they_were():
