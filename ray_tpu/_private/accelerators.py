@@ -125,27 +125,96 @@ def export_compile_cache_env(env=os.environ) -> str:
     return env.setdefault(COMPILE_CACHE_ENV, DEFAULT_COMPILE_CACHE_DIR)
 
 
+# JAX's three stages of making a program, by the duration event of each
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend"}
 _cache_events: collections.Counter | None = None
-_recent_compiles: collections.deque = collections.deque(maxlen=16)
-_compiling = threading.local()  # .activity: what this thread is doing
+_stage_seconds = dict.fromkeys(("trace", "lower", "backend", "retrieval"), 0.0)
+_programs: dict = {}  # "jit(<name>)" -> its row of compile_cache_counts()
+_compile_lock = threading.Lock()  # the two above: several threads compile
+# .activity: what this thread is doing; and the listeners' notes from one
+# event of a compilation to the next (.outcome, .retrieval, .counted)
+_compiling = threading.local()
+_MAX_COUNTED = 1 << 16  # outermost intervals a thread remembers
 
 
 def note_thread_activity(activity) -> None:
     """`activity()` names what the calling thread is doing (the engine
     thread gives the loop phase that is open): stamped on every compilation
-    this thread makes from now on, in `compile_cache_counts()["recent"]`."""
+    this thread makes from now on, as its program's `last` in
+    `compile_cache_counts()["programs"]`."""
     _compiling.activity = activity
 
 
+def _count_stage(stage: str, name: str, seconds: float) -> None:
+    """One duration event of JAX's, on the thread that did the work. The
+    interval ends now and began `seconds` ago on this thread, so what lies
+    inside it (every `jit` and `jnp` call of a trace fires a trace event of
+    its own, before the outer one) was reported already: its seconds leave
+    the totals again, and each second is counted once, under the outermost
+    stage that was open."""
+    note, end = vars(_compiling), time.time()
+    start = end - seconds
+    fetched = note.pop("retrieval", 0.0) if stage == "backend" else 0.0
+    # this thread's outermost intervals so far, in order: [start, end, stage,
+    # seconds, the cache's seconds, the bare name of an unclaimed trace]
+    counted = note.setdefault("counted", [])
+    with _compile_lock:
+        while counted and (counted[-1][0] + counted[-1][1]) / 2 > start:
+            _, _, inner, took, read, _ = counted.pop()
+            _stage_seconds[inner] -= took
+            _stage_seconds["retrieval"] -= read
+        _stage_seconds[stage] += seconds
+        _stage_seconds["retrieval"] += fetched
+        if stage != "trace":
+            row = _programs.setdefault(name, {
+                "traces": 0, "trace_s": 0.0, "lowers": 0, "lower_s": 0.0,
+                "compiles": 0, "backend_s": 0.0, "hits": 0, "misses": 0,
+                "last": None})
+        if stage == "lower":
+            row["lowers"] += 1
+            row["lower_s"] += seconds
+            # the trace that led here ended just before, under the bare name
+            if counted and counted[-1][5] == name.partition("(")[2][:-1]:
+                row["traces"] += 1
+                row["trace_s"] += counted[-1][3]
+                counted[-1][5] = None
+        elif stage == "backend":
+            row["compiles"] += 1
+            row["backend_s"] += seconds
+            outcome = note.pop("outcome", None)
+            if outcome:
+                row[outcome] += 1
+            activity = note.get("activity")
+            row["last"] = {"t": end, "thread": threading.current_thread().name,
+                           "phase": activity() if activity else None}
+        counted.append([start, end, stage, seconds, fetched,
+                        name if stage == "trace" else None])
+        # an interval this many events back is taken to be closed: nothing
+        # still open began before it (one with more intervals directly inside
+        # it than this would count the oldest of them twice)
+        if len(counted) > _MAX_COUNTED:
+            del counted[:_MAX_COUNTED // 2]
+
+
 def compile_cache_counts() -> dict:
-    """This process's traffic on JAX's persistent compilation cache since
-    the first call (so call once before compiling): programs looked up,
-    found (hits) and compiled then written (misses). A second run on a kept
-    cache directory shows hits and no misses. `recent` is the last 16
-    compilations: when each ended, how long it took, hit or miss, the
-    program as JAX names it (`jit(<function>)`, from the
-    `backend_compile_duration` event; the cache events carry no name) and
-    what the calling thread was doing."""
+    """What this process spent on making programs since the first call (so
+    call once before compiling), from JAX's monitoring events, which fire
+    only when JAX traces, lowers or compiles. `requests`, `hits`, `misses`:
+    programs looked up in the persistent compilation cache, found, and
+    compiled then written (a second run on a kept directory shows hits and
+    no misses). `seconds`: in tracing, in lowering and in the backend
+    (compiling, or reading from the cache and loading; `retrieval` is the
+    cache's own part of that), each second once (`_count_stage`), so
+    trace + lower + backend is never more than the compiling threads' wall
+    time. `programs`: a row for every program that was lowered, under the
+    name the lowering and the compile events give it (`jit(<function>)`):
+    events and seconds by stage, `trace_s` the outermost trace that led to
+    the lowering (a nested trace has no row: it is in its caller's), hits
+    and misses, and `last`: when its last compilation ended, on which
+    thread, and what that thread was doing (`note_thread_activity`)."""
     global _cache_events
     if _cache_events is None:
         import jax.monitoring
@@ -159,28 +228,43 @@ def compile_cache_counts() -> dict:
                 # the listeners run on the compiling thread, and the
                 # duration event of the same compilation follows
                 if event.endswith("cache_hits"):
-                    _compiling.outcome = "hit"
+                    _compiling.outcome = "hits"
                 elif event.endswith("cache_misses"):
-                    _compiling.outcome = "miss"
+                    _compiling.outcome = "misses"
 
         def on_duration(event: str, seconds: float, **kw) -> None:
-            if event == "/jax/core/compile/backend_compile_duration":
-                activity = getattr(_compiling, "activity", None)
-                _recent_compiles.append({
-                    "t": time.time(), "seconds": seconds,
-                    "cache": getattr(_compiling, "outcome", None),
-                    "program": kw.get("fun_name"),
-                    "thread": threading.current_thread().name,
-                    "phase": activity() if activity else None})
-                _compiling.outcome = None
+            if event in _COMPILE_STAGES:
+                _count_stage(_COMPILE_STAGES[event],
+                             kw.get("fun_name") or "?", seconds)
+            elif event == prefix + "cache_retrieval_time_sec":
+                _compiling.retrieval = seconds
 
         jax.monitoring.register_event_listener(on_event)
         jax.monitoring.register_event_duration_secs_listener(on_duration)
+    with _compile_lock:
+        seconds = dict(_stage_seconds)
+        programs = {name: dict(row) for name, row in _programs.items()}
     return {"dir": os.environ.get(COMPILE_CACHE_ENV),
             "requests": _cache_events["compile_requests_use_cache"],
             "hits": _cache_events["cache_hits"],
             "misses": _cache_events["cache_misses"],
-            "recent": list(_recent_compiles)}
+            "seconds": seconds, "programs": programs,
+            # `last` by program, newest 16: only what
+            # chipbench/tests/test_rehearsal_loop.py still asks for, a file a
+            # `benchmark` issue alone may edit; nothing else reads it
+            "recent": sorted(({"program": name, **row["last"]}
+                              for name, row in programs.items() if row["last"]),
+                             key=lambda e: e["t"])[-16:]}
+
+
+def process_start_time() -> float:
+    """When the OS started this process, on the wall clock (`time.time()`,
+    the clock of `tracing._emit_span`, which every process of the host
+    shares), to the kernel's tick of 10 ms."""
+    with open("/proc/self/stat") as f:
+        ticks = int(f.read().rsplit(")", 1)[1].split()[19])  # since boot
+    age = time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf("SC_CLK_TCK")
+    return time.time() - age
 
 
 def device_report() -> dict:

@@ -680,6 +680,7 @@ class TPUEngine:
         self._work = threading.Event()
         self._stop = False
         self._error: BaseException | None = None
+        self._setup: dict | None = None  # from_config's record of the start
         # cancellation plane: abort_request() is called from request
         # threads; rids land here and the scheduler applies them at the
         # top of its next pass (slot + pages reclaimed in one step).
@@ -739,8 +740,13 @@ class TPUEngine:
 
     @classmethod
     def from_config(cls, llm_config) -> "TPUEngine":
-        """Single construction point for server/PD/batch paths."""
+        """Single construction point for server/PD/batch paths. It times
+        the replica's start by stage (`stats()["setup"]`): each stamp says
+        when the HOST got past, and no device work is waited for."""
+        t = [accelerators.process_start_time(), time.time()]
+        accelerators.compile_cache_counts()  # build_model compiles too
         backend = jax.default_backend()
+        t.append(time.time())
         if llm_config.accelerator_type == "TPU" and backend != "tpu":
             raise RuntimeError(
                 f"LLMConfig.accelerator_type='TPU' but this process computes "
@@ -757,6 +763,8 @@ class TPUEngine:
                     "removed; the engine serves from the paged KV cache "
                     "with the ragged decode step and does not speculate")
         cfg, params = llm_config.build_model()
+        t.append(time.time())
+        compile_at = {"weights": accelerators.compile_cache_counts()["seconds"]}
         # the constructor's defaults are the defaults here too
         kw = {k: ek[k] for k in (
             "max_slots", "max_len", "min_bucket", "seed", "page_size",
@@ -766,7 +774,30 @@ class TPUEngine:
         if lora_cfg:
             kw.setdefault("max_loras", lora_cfg.max_num_adapters_per_replica)
             kw.setdefault("lora_rank", lora_cfg.lora_rank)
-        return cls(cfg, params, **kw)
+        engine = cls(cfg, params, **kw)
+        t.append(time.time())
+        compile_at["engine"] = accelerators.compile_cache_counts()["seconds"]
+        engine._setup = {
+            "t_process": t[0],
+            "seconds": {**{stage: b - a for stage, a, b in zip(
+                ("process", "backend", "weights", "engine"), t, t[1:])},
+                "to_first_request": None},
+            "compile_at": compile_at}
+        return engine
+
+    def _note_first_request(self, now: float) -> None:
+        """The first request closes the record of the start: ready reported,
+        the controller's probe, the route and the proxy lie between the
+        constructor and now. (Two first requests at once both write, a
+        moment apart.)"""
+        setup = self._setup
+        if not setup or setup["seconds"]["to_first_request"] is not None:
+            return
+        setup["compile_at"]["first_request"] = (
+            accelerators.compile_cache_counts()["seconds"])
+        setup["seconds"]["to_first_request"] = (
+            now - setup["t_process"] - sum(
+                v for v in setup["seconds"].values() if v is not None))
 
     def _check_alive(self):
         if self._error is not None:
@@ -899,6 +930,7 @@ class TPUEngine:
         req = _Request(next(self._rid), token_ids, params, lora_idx=lora_idx,
                        deadline_ts=float(deadline_ts or 0.0))
         req.submitted_ts = time.time()
+        self._note_first_request(req.submitted_ts)
         req.trace_ctx = tracing.current_context()
         self._waiting.put(req)
         self._work.set()
@@ -972,6 +1004,7 @@ class TPUEngine:
         req = _Request(next(self._rid), [], params,
                        deadline_ts=float(deadline_ts or 0.0))
         req.submitted_ts = time.time()
+        self._note_first_request(req.submitted_ts)
         if kv_stream is not None:
             req.kv_stream = kv_stream
             req.kv_pack = {"length": int(length),
@@ -2143,6 +2176,12 @@ class TPUEngine:
                        "bytes_in_use", "peak_bytes_in_use", "bytes_limit")},
                "worker_chips": accelerators.current_worker_chips(),
                "compile_cache": accelerators.compile_cache_counts(),
+               # the start by stage, process start to first request, with the
+               # compile seconds as they stood at three of the stamps; None
+               # for an engine that from_config did not build
+               "setup": self._setup and {
+                   **self._setup, "seconds": dict(self._setup["seconds"]),
+                   "compile_at": dict(self._setup["compile_at"])},
                "decode_steps": self.decode_steps,
                # live rows summed over decode steps
                "decode_slot_steps": self.decode_slot_steps,

@@ -245,8 +245,15 @@ def test_stats_stay_json_plain(tiny_model):
         assert set(row) == {"calls", "seconds"}
     assert set(back["loop"]["requests"]) == {
         "requests_scheduled", "queue_wait_s", "first_tokens", "prefill_s"}
-    recent = back["compile_cache"]["recent"]
-    assert isinstance(recent, list) and len(recent) <= 16
+    # the compile table rides along: a row a program, plain numbers
+    programs = back["compile_cache"]["programs"]
+    assert programs == st["compile_cache"]["programs"] and programs
+    for row in programs.values():
+        assert set(row) == {"traces", "trace_s", "lowers", "lower_s", "compiles",
+                            "backend_s", "hits", "misses", "last"}
+    assert set(back["compile_cache"]["seconds"]) == {
+        "trace", "lower", "backend", "retrieval"}
+    assert back["setup"] is None  # not from_config's engine
 
 
 def test_the_plain_admission_keeps_the_clock(tiny_model):
@@ -267,22 +274,27 @@ def test_the_plain_admission_keeps_the_clock(tiny_model):
 
 
 def test_compile_log_names_the_program_and_the_phase(tiny_model):
-    """`compile_cache_counts()["recent"]`: the last compilations with the
-    program's name as JAX gives it and what the compiling thread was doing
-    (the engine thread: the loop phase that was open)."""
-    accelerators.compile_cache_counts()
+    """`compile_cache_counts()["programs"]`: every program lowered, under
+    the name JAX gives it, with the thread of its last compilation and what
+    that thread was doing (the engine thread: the loop phase that was
+    open). Which programs compiled in a window: the rows whose counts moved
+    between two readings."""
+    before = accelerators.compile_cache_counts()["programs"]
     eng = _engine(tiny_model, page_size=32, min_bucket=32, prefill_chunk=32)
     try:
         _burst(eng, 1, 40, 3)  # shapes no earlier test compiled
-        recent = eng.stats()["compile_cache"]["recent"]
+        after = eng.stats()["compile_cache"]["programs"]
     finally:
         eng.shutdown()
-    assert 0 < len(recent) <= 16
-    for e in recent:
-        assert set(e) == {"t", "seconds", "cache", "program", "thread", "phase"}
-    mine = [e for e in recent if e["thread"] == "tpu-engine"]
+    moved = {name: row for name, row in after.items()
+             if row["compiles"] > before.get(name, {"compiles": 0})["compiles"]}
+    assert moved and all(name.startswith(("jit(", "pjit(")) for name in moved)
+    for row in moved.values():
+        assert set(row["last"]) == {"t", "thread", "phase"}
+        assert row["lowers"] >= row["compiles"] >= 1 and row["backend_s"] > 0
+    mine = [row["last"] for row in moved.values()
+            if row["last"]["thread"] == "tpu-engine"]
     assert mine and all(e["phase"] in LOOP_PHASES for e in mine)
-    assert all(e["program"].startswith(("jit(", "pjit(")) for e in mine)
     assert any(e["phase"] in ("decode", "prefill", "admit") for e in mine)
 
     done = threading.Event()
@@ -295,7 +307,8 @@ def test_compile_log_names_the_program_and_the_phase(tiny_model):
     t.start()
     t.join()
     assert done.is_set()
-    last = accelerators.compile_cache_counts()["recent"][-1]
+    last = max((row["last"] for row in accelerators.compile_cache_counts()[
+        "programs"].values() if row["last"]), key=lambda e: e["t"])
     assert last["thread"] == "not-the-engine" and last["phase"] is None
 
 

@@ -181,6 +181,13 @@ def test_cli_list_tasks_objects_workers(session):
 
     assert ray_tpu.get(work.remote(1), timeout=30) == 2
     big = ray_tpu.put(np.zeros(300_000))
+    # `put` reports the object one-way on the driver's connection and the
+    # CLI lists through a connection of its own, which the GCS serves on
+    # another thread: a round trip on the driver's connection, in order
+    # behind the report, says that the row is in the table
+    from ray_tpu.util import state
+
+    assert state.list_objects(filters=[("object_id", "=", big.hex())])
     sd = session["session_dir"]
     out = _run_cli(["--session", sd, "list", "objects"])
     rows = _json.loads(out)
