@@ -69,9 +69,14 @@ def _flash_fwd(q, k, v, causal, scale, interpret):
         qt, kt, vt, causal=causal, scale=D ** -0.5 if scale is None else scale,
         block_q=None, block_k=None, interpret=interpret)
     # named lane-dense: in the kernel's [B, H, T, 64] a stack of layers pads
-    # 64 lanes to 128, and the log-sum-exp as [B, H, T, 1] one lane to 128
+    # 64 lanes to 128. The log-sum-exp is lane-dense as the kernel writes it,
+    # rows [B, H, 1, T], and is named, stacked and handed back to the backward
+    # kernels as that very array: as [B, H, T] the compiler re-tiled it twice
+    # a backward layer, and as the column [B, H, T, 1] it had until PR 58 (one
+    # value a 128-lane row, 42 MB for 0.33) the re-laying cost 127 us a layer
+    # of gpt2-large's step (PERF.md section 5)
     out = checkpoint_name(out.transpose(0, 2, 1, 3).reshape(B, T, H * D), FLASH_KEPT[0])
-    lse = checkpoint_name(lse.reshape(B, H, T), FLASH_KEPT[1])
+    lse = checkpoint_name(lse, FLASH_KEPT[1])
     return out.reshape(B, T, H, D), (q, k, v, out, lse)
 
 
@@ -81,7 +86,7 @@ def _flash_bwd(causal, scale, interpret, res, g):
     qt, kt, vt, ot, gt = (x.transpose(0, 2, 1, 3)
                           for x in (q, k, v, out.reshape(B, T, H, D), g))
     grads = flash_attention_backward(
-        qt, kt, vt, ot, lse.reshape(B, H, T, 1), gt, causal=causal,
+        qt, kt, vt, ot, lse, gt, causal=causal,
         scale=D ** -0.5 if scale is None else scale, interpret=interpret)
     return tuple(x.transpose(0, 2, 1, 3) for x in grads)
 

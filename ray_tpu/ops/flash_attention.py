@@ -13,15 +13,33 @@ they cost the forward kernel as much again as all the rest of it (v5e, PR
 forward also emits the per-row logsumexp so the backward never rebuilds the
 softmax normalizer.
 
+The per-query side arrays (the log-sum-exp, and the backward's delta) cross
+every kernel boundary as rows, [B, H, 1, T] float32, dense in HBM. Until PR 58
+the forward wrote and the dq kernel read them as columns [B, H, T, 1]: one
+value a 128-lane row, 42 MB an array at [4, 20, 1024] where the values are
+0.33 MB, and GPT-2 large's train step spent 127 us a layer turning them
+between that form and the rows that the dk/dv kernel and a layer's checkpoint
+want (PR 56's traced copies, PERF.md section 5). Now the kernels turn them
+where they hold them anyway on all 128 lanes: the forward transposes its
+[block_q, 128] plane of m + log l once a q block, at `_finalize` (`_as_row`),
+and the dq kernel spreads the two rows it is given over two [block_q, 128]
+scratch planes once a q block, at j == 0 (`_on_lanes`), which its kv steps
+read as the forward reads its running max (`_lanes`). A transposition moves
+values and rounds none. With the columns gone that step is 7.1 ms shorter
+(241.5 -> 234.4 ms on a v5e, PR 58: the copies, and the memory-bound ops
+beside them a few us each).
+
 Backward is the standard two-kernel flash decomposition (no [T, T] score
 tensor is ever materialized):
   - dkv kernel, grid (B, H, nk, nq): for a fixed kv block, sweep q blocks
     accumulating dv += p^T dO and dk += ds^T q in VMEM scratch. It computes
     the scores already transposed (k q^T, [block_k, block_q]; lse and delta
-    arrive as rows), so both accumulating products are plain and what is
-    transposed for the MXU is a [block, D] operand, never a score block.
+    arrive as rows and are read as rows), so both accumulating products are
+    plain and what is transposed for the MXU is a [block, D] operand, never a
+    score block.
   - dq kernel, grid (B, H, nq, nk): for a fixed q block, sweep kv blocks
-    accumulating dq += ds k.
+    accumulating dq += ds k; the same two rows, spread over lanes once a q
+    block.
 where p = exp(s - lse) is recomputed blockwise from the saved logsumexp and
 delta = rowsum(dO * O) folds the softmax Jacobian into ds = p * (dp - delta).
 
@@ -101,6 +119,19 @@ def _lanes(x, n: int):
     if n <= x.shape[1]:
         return x[:, :n]
     return pltpu.repeat(x, n // x.shape[1], axis=1)
+
+
+def _as_row(x):
+    """x: [rows, 128] holding a row's value on every lane -> [1, rows], the
+    form in which a per-query array crosses a kernel's boundary. One aligned
+    float32 transposition: values move, none is rounded."""
+    return x.T[:1, :]
+
+
+def _on_lanes(row):
+    """row: [1, rows] -> [rows, 128] holding a row's value on every lane
+    (`_as_row`'s inverse, the form `_lanes` reads)."""
+    return jnp.broadcast_to(row, (128, row.shape[1])).T
 
 
 _DIAG_STRIP = 256
@@ -184,7 +215,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
     def _finalize():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0, 0] = (acc_scr[:] / _lanes(l, acc_scr.shape[1])).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_scr[:] + jnp.log(l))[:, :1]
+        # turned once a q block, after the last kv step: [1, block_q]
+        lse_ref[0, 0] = _as_row(m_scr[:] + jnp.log(l))
 
 
 def _fwd_call(q, k, v, *, causal: bool, scale: float, block_q: int | None,
@@ -204,7 +236,7 @@ def _fwd_call(q, k, v, *, causal: bool, scale: float, block_q: int | None,
         return (b, h, j, 0)
 
     def lse_map(b, h, i, j):
-        return (b, h, i, 0)
+        return (b, h, 0, i)
 
     kwargs = {} if interpret else dict(memory_space=pltpu.VMEM)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -218,7 +250,7 @@ def _fwd_call(q, k, v, *, causal: bool, scale: float, block_q: int | None,
         kernel,
         out_shape=(
             jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1, T), jnp.float32),
         ),
         grid=grid,
         in_specs=[
@@ -228,7 +260,7 @@ def _fwd_call(q, k, v, *, causal: bool, scale: float, block_q: int | None,
         ],
         out_specs=(
             pl.BlockSpec((1, 1, block_q, D), qo_map, **kwargs),
-            pl.BlockSpec((1, 1, block_q, 1), lse_map, **kwargs),
+            pl.BlockSpec((1, 1, 1, block_q), lse_map, **kwargs),
         ),
         scratch_shapes=scratch,
         interpret=interpret,
@@ -286,7 +318,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr, *,
+               dq_ref, dq_scr, lse_scr, delta_scr, *,
                scale: float, causal: bool, block_q: int, block_k: int):
     i = pl.program_id(2)   # q block (outer)
     j = pl.program_id(3)   # kv block (inner sweep)
@@ -295,6 +327,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(j == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        # lse, delta: [1, block_q] rows, turned once a q block and not at
+        # every kv step into planes that hold a query's value on every lane
+        lse_scr[:] = _on_lanes(lse_ref[0, 0])
+        delta_scr[:] = _on_lanes(delta_ref[0, 0])
 
     @_when_live(i, j, causal=causal, block_q=block_q, block_k=block_k)
     def _compute(tiles, masked):
@@ -304,9 +340,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             if masked:
                 s = _causal_mask(s, i * block_q + rows.start,
                                  j * block_k + keys.start, q_axis=0)
-            p = jnp.exp(s - lse_ref[0, 0, rows, :])         # lse, delta: [rows, 1]
+            p = jnp.exp(s - _lanes(lse_scr[rows, :], s.shape[1]))
             dp = _dot(do_ref[0, 0, rows, :], v_ref[0, 0, keys, :], _NT)
-            ds = p * (dp - delta_ref[0, 0, rows, :]) * scale
+            ds = p * (dp - _lanes(delta_scr[rows, :], s.shape[1])) * scale
             dq_scr[rows, :] = dq_scr[rows, :] + _dot(ds.astype(k.dtype), k)
 
     @pl.when(j == nk - 1)
@@ -319,11 +355,13 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool,
                              block_q: int | None = None,
                              block_k: int | None = None,
                              interpret: bool = False):
-    """Gradients (dq, dk, dv) for [B,H,T,D] flash attention."""
+    """Gradients (dq, dk, dv) for [B,H,T,D] flash attention; `lse` as the
+    forward kernel wrote it, [B, H, 1, T]."""
     B, H, T, D = q.shape
     # delta_t = sum_d dO * O — folds the softmax Jacobian; tiny elementwise op,
-    # XLA fuses it, no need for a kernel.
-    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1, keepdims=True)  # [B,H,T,1]
+    # XLA fuses it, no need for a kernel. Born [B, H, T] and handed to both
+    # kernels as the row [B, H, 1, T] that the log-sum-exp is.
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)[:, :, None, :]
 
     kwargs = {} if interpret else dict(memory_space=pltpu.VMEM)
 
@@ -337,6 +375,9 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool,
 
     def inner_row_map(b, h, o_idx, inner):
         return (b, h, 0, inner)
+
+    def outer_row_map(b, h, o_idx, inner):
+        return (b, h, 0, o_idx)
 
     block_q, block_k = _blocks(T, block_q, block_k)
     dkv = pl.pallas_call(
@@ -366,8 +407,9 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool,
         interpret=interpret,
         name="flash_bwd_dkv",
     )
-    # its scores are transposed, so a query's lse and delta lie along a row
-    dk, dv = dkv(q, k, v, do, lse.reshape(B, H, 1, T), delta.reshape(B, H, 1, T))
+    # its scores are transposed, so a query's lse and delta lie along a row,
+    # as they arrive
+    dk, dv = dkv(q, k, v, do, lse, delta)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -379,11 +421,13 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool,
             pl.BlockSpec((1, 1, block_k, D), inner_map, **kwargs),
             pl.BlockSpec((1, 1, block_k, D), inner_map, **kwargs),
             pl.BlockSpec((1, 1, block_q, D), outer_map, **kwargs),
-            pl.BlockSpec((1, 1, block_q, 1), outer_map, **kwargs),
-            pl.BlockSpec((1, 1, block_q, 1), outer_map, **kwargs),
+            pl.BlockSpec((1, 1, 1, block_q), outer_row_map, **kwargs),
+            pl.BlockSpec((1, 1, 1, block_q), outer_row_map, **kwargs),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), outer_map, **kwargs),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32),
+                        pltpu.VMEM((block_q, 128), jnp.float32),
+                        pltpu.VMEM((block_q, 128), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)

@@ -162,6 +162,79 @@ def test_flash_attention_diagonal_strips(dtype):
                               loss=lambda o: (o ** 2).sum())
 
 
+# d_head, T, the block asked for (both kernels' axes; None: the kernels' own
+# choice), causal: one block at T = 256 by choice, 2 x 2 to 16 x 16 blocks
+# elsewhere, so a q block's log-sum-exp is written after several kv steps and
+# the dq kernel's once-a-q-block turn of its rows is read across kv steps
+_ROWS_GRID = [
+    pytest.param(d, T, block, causal,
+                 id=f"d{d}-t{T}-{'auto' if block is None else block}-"
+                    f"{'causal' if causal else 'full'}")
+    for d in (64, 128) for T in (256, 1024, 2048) for block in (None, 128, 256)
+    for causal in (True, False)]
+
+
+@pytest.mark.parametrize("d_head,T,block,causal", _ROWS_GRID)
+def test_flash_forward_hands_over_its_log_sum_exp_as_rows(d_head, T, block, causal):
+    """The forward kernel's second result is [B, H, 1, T] (PR 58: rows at
+    every kernel boundary, never the column [B, H, T, 1]) and is the float32
+    reference's log-sum-exp of the scaled, masked scores."""
+    from ray_tpu.ops.flash_attention import _NEG_INF, _fwd_call
+
+    B, H = 1, 2
+    q, k, v = _flash_qkv(T + d_head, (B, H, T, d_head), jnp.float32)
+    scale = d_head ** -0.5
+    out, lse = _fwd_call(q, k, v, causal=causal, scale=scale, block_q=block,
+                         block_k=block, interpret=True)
+    assert (lse.shape, str(lse.dtype)) == ((B, H, 1, T), "float32")
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, _NEG_INF)
+    np.testing.assert_allclose(np.asarray(lse[:, :, 0]),
+                               np.asarray(jax.nn.logsumexp(s, axis=-1)),
+                               atol=1e-5, rtol=1e-5)
+    _assert_flash_close(out, _reference_f32(q, k, v, causal=causal), jnp.float32, 2e-5)
+
+
+@_DTYPES
+@pytest.mark.parametrize("d_head,T,block,causal", _ROWS_GRID)
+def test_flash_attention_gradients_over_rows_match_reference(d_head, T, block, causal, dtype):
+    """`jax.grad` through `flash_attention`, whose residual is the row form:
+    both backward kernels read the forward's [B, H, 1, T] and one `delta`."""
+    _flash_grads_vs_reference((1, 1, T, d_head), dtype, causal=causal,
+                              block_q=block, block_k=block, seed=T + d_head,
+                              loss=lambda o: (o ** 2).sum())
+
+
+@_DTYPES
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("T", [256, 1024, 2048])
+@pytest.mark.parametrize("d_head", [64, 128])
+def test_flash_rule_gradients_over_rows_match_reference(d_head, T, causal, dtype):
+    """The same through `ops.attention._flash`, the train step's rule on
+    [B, T, H, D], which names the rows and hands them back (blocks: the
+    kernels' own choice, 2 x 2 of 1,024 at T = 2,048)."""
+    import sys
+
+    _flash = sys.modules["ray_tpu.ops.attention"]._flash
+    q, k, v = _flash_qkv(T + d_head, (1, T, 2, d_head), dtype)
+
+    def heads_major(x):
+        return x.transpose(0, 2, 1, 3)
+
+    def f_rule(q, k, v):
+        return (_flash(q, k, v, causal, None, True).astype(jnp.float32) ** 2).sum()
+
+    def f_ref(q, k, v):
+        return (_reference_f32(*map(heads_major, (q, k, v)), causal=causal) ** 2).sum()
+
+    g_rule = jax.grad(f_rule, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(*(x.astype(jnp.float32) for x in (q, k, v)))
+    for gf, gr, name in zip(g_rule, g_ref, "qkv"):
+        assert gf.dtype == dtype and gf.shape == gr.shape
+        _assert_flash_close(gf, gr, dtype, 5e-4, err_msg=f"d{name}")
+
+
 def test_flash_attention_blocks_chosen_from_t():
     # no block size named: the kernels' best, cut to what tiles T
     from ray_tpu.ops.flash_attention import _blocks
@@ -262,8 +335,8 @@ def test_flash_rule_in_the_models_layout_equals_flash_attention(causal, dtype):
 
 def test_flash_rule_names_what_the_backward_keeps_lane_dense():
     """The forward rule names the output as [B, T, H * D] and the log-sum-exp
-    as [B, H, T]: what a layer's checkpoint keeps, in shapes whose last
-    dimension fills a 128-lane tile."""
+    as the rows [B, H, 1, T] the kernel writes (PR 58): what a layer's
+    checkpoint keeps, in shapes whose last dimension fills a 128-lane tile."""
     import sys
 
     _flash = sys.modules["ray_tpu.ops.attention"]._flash
@@ -277,7 +350,7 @@ def test_flash_rule_names_what_the_backward_keeps_lane_dense():
     assert set(named) == set(ops.FLASH_KEPT) == {"flash_out", "flash_lse"}
     assert (named["flash_out"].shape, str(named["flash_out"].dtype)) == (
         (B, T, H * D), "bfloat16")
-    assert (named["flash_lse"].shape, str(named["flash_lse"].dtype)) == ((B, H, T), "float32")
+    assert (named["flash_lse"].shape, str(named["flash_lse"].dtype)) == ((B, H, 1, T), "float32")
     assert sorted(e.params["name"] for e in _walk_eqns(jaxpr)
                   if e.primitive.name == "pallas_call") == [
         "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]

@@ -10,6 +10,7 @@ GPT-2 774M attention at s1024, Llama-1B decode (page 64).
 from __future__ import annotations
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -787,8 +788,13 @@ def test_gpt2_large_train_step_runs_the_flash_forward_once_and_keeps_its_output(
     (`ops.FLASH_KEPT`), so the program holds three Pallas calls, the forward
     once, under the signatures the benchmark's reader finds them by. On one
     chip its bytes say that both stacks are kept and kept lane-dense: 15.18e9
-    with neither (the step before PR 56), 16.05e9 as built, 16.79e9 with the
-    output kept in the kernel's [B, H, T, 64], whose 64 lanes pad to 128.
+    with neither (the step before PR 56), 16.05e9 as PR 56 built it, 15.85e9
+    since PR 58 (the log-sum-exp and `delta` cross every kernel boundary as
+    rows [B, H, 1, T], so the five padded 42 MB columns [B, H, T, 1] that were
+    live at the peak are gone, and the window's floor came down from 15.9e9
+    with them), 16.79e9 with the output kept in the kernel's [B, H, T, 64],
+    whose 64 lanes pad to 128. No float32 column [.., 1024, 1] is left in the
+    compiled text.
     Under a mesh the kernels run per shard in `_flash_per_shard`'s shard_map,
     and the names have to reach the checkpoint through it."""
     import optax
@@ -817,13 +823,17 @@ def test_gpt2_large_train_step_runs_the_flash_forward_once_and_keeps_its_output(
                                  transform_non_params=lambda _: NamedSharding(mesh, P()))
     batch = jax.ShapeDtypeStruct((4, 1025), jnp.int32, sharding=batch_sharding)
     compiled = step.lower(params, abstract(o_shape, o_sh), batch).compile()
-    assert sorted(kernel_ops_from_hlo(compiled.as_text()).values()) == [
+    text = compiled.as_text()
+    assert sorted(kernel_ops_from_hlo(text).values()) == [
         "3in_2out", "6in_1out", "6in_2out"]
+    # no per-query array as a column (one float32 a 128-lane row), whole or
+    # per shard: f32[4,20,1024,1] on one chip
+    assert not re.findall(r"f32\[[0-9,]*1024,1\]", text)
     if chips == 1:
         m = compiled.memory_analysis()
         total = (m.argument_size_in_bytes + m.output_size_in_bytes
                  - m.alias_size_in_bytes + m.temp_size_in_bytes)
-        assert 15.9e9 < total < 16.3e9
+        assert 15.7e9 < total < 16.3e9
 
 
 def test_train_and_first_chunk_flash_programs_are_what_they_were():
@@ -832,7 +842,12 @@ def test_train_and_first_chunk_flash_programs_are_what_they_were():
     forward a prompt's first chunk takes (trinity's 48 heads of 128 over
     2,048) trace to the jaxprs they had before it came (PR 45's tree, read in
     PR 47). A change to those kernels changes these digests on purpose and
-    says so here."""
+    says so here. PR 58 moved both on purpose: the forward kernel writes its
+    log-sum-exp as rows [B, H, 1, T] (one transposition of a [block_q, 128]
+    plane at `_finalize`), the dq kernel reads the rows of it and of `delta`
+    and turns them once a q block into two [block_q, 128] scratch planes, and
+    `delta` is born [B, H, T]: the column [B, H, T, 1] is gone from all three
+    (before: (59767, "df9f178b79978772") and (21687, "46245d7ec9aa1222"))."""
     import hashlib
 
     from ray_tpu.ops.flash_attention import flash_attention_forward
@@ -847,6 +862,6 @@ def test_train_and_first_chunk_flash_programs_are_what_they_were():
             jnp.float32).sum()
 
     assert digest(jax.grad(loss, argnums=(0, 1, 2)), (4, 20, 1024, 64)) == (
-        59767, "df9f178b79978772")
+        61300, "e2c6d64a285ea644")
     assert digest(lambda q, k, v: flash_attention_forward(q, k, v, scale=0.1),
-                  (1, 48, 2048, 128)) == (21687, "46245d7ec9aa1222")
+                  (1, 48, 2048, 128)) == (21757, "f8044aa5b940753e")
